@@ -37,4 +37,15 @@ from .api.types import (  # noqa: F401
     TopologySpreadConstraint,
     UnsatisfiableAction,
 )
-from .sched.cycle import BatchScheduler, CycleResult  # noqa: F401
+
+
+def __getattr__(name):
+    # BatchScheduler / CycleResult live in sched/cycle.py, which imports jax.
+    # Resolved on first use so that `import kubernetes_tpu` — and with it
+    # every launcher that only wants a helper module (bench.py's parent) —
+    # stays off jax: one process per chip, and the launcher is not it.
+    if name in ("BatchScheduler", "CycleResult"):
+        from .sched import cycle
+
+        return getattr(cycle, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

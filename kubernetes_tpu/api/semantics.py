@@ -304,8 +304,11 @@ def topology_spread_fits(
             if ex_node is None or c.topology_key not in ex_node.labels:
                 continue
             val = ex_node.labels[c.topology_key]
-            if val not in counts:
-                continue  # node not eligible for this pod
+            if val not in counts or not pod_matches_node_selector(pod, ex_node):
+                # only pods ON eligible nodes count (metadata.go processNode
+                # returns before counting a node the pod's selector rejects),
+                # even where an eligible node shares the topology value
+                continue
             if ex.namespace == pod.namespace and selector_matches(c.selector, ex.labels):
                 counts[val] += 1
         if not counts:

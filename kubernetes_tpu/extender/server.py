@@ -114,6 +114,9 @@ class ExtenderServer:
         return f"http://{host}:{port}{self.url_prefix}"
 
     def start(self) -> "ExtenderServer":
+        from ..utils.platform import enable_compile_cache
+
+        enable_compile_cache()  # before the first verb compiles
         self._thread = threading.Thread(target=self._httpd.serve_forever, daemon=True)
         self._thread.start()
         return self

@@ -126,10 +126,9 @@ def dispatch_fleet(tables, pending, keys, D, existing, engine, quota,
         compiled = prewarmer.lookup(dims, engine, (), False, mesh=mesh,
                                     rc=rc, fleet=fleet_signature(K))
         if compiled is not None:
-            try:
-                return FleetResult(*compiled(tables, pending, keys,
-                                             existing, quota, hw, ecfg))
-            except TypeError:
-                pass  # aval/pytree drift — take the ordinary jit path
+            ok, out = prewarmer.call(compiled, tables, pending, keys,
+                                     existing, quota, hw, ecfg)
+            if ok:
+                return FleetResult(*out)
     return _fleet_cycle_impl(tables, pending, keys, D, existing, engine,
                              quota, hw, ecfg, rc, explain)

@@ -398,7 +398,9 @@ class FleetServer:
         from ..sched.prewarm import BucketPrewarmer
         from ..sched.supervisor import DispatchSupervisor
         from ..utils.envparse import env_int
+        from ..utils.platform import enable_compile_cache
 
+        enable_compile_cache()  # before the first tick compiles
         self.batch_size = batch_size
         self.clock = clock
         self.scheduler_name = scheduler_name

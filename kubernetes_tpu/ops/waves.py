@@ -531,7 +531,11 @@ def assign_waves(
         used2 = state.used + jnp.einsum("cn,cr->nr", Ai, req_by_class)
         CNT2 = state.CNT + cyc.TM.astype(jnp.int32) @ Ai
         HOLD2 = state.HOLD + cyc.has_anti.T.astype(jnp.int32) @ Ai
-        WSYM2 = state.WSYM + cyc.WCOLS @ Ai.astype(jnp.float32)
+        # HIGHEST: at the TPU's default precision an f32 matmul rounds its
+        # operands to bf16, and a weight sum such as 301 is not a bf16 — the
+        # scan engine's exact adds of the same WCOLS entries would diverge
+        WSYM2 = state.WSYM + jnp.matmul(cyc.WCOLS, Ai.astype(jnp.float32),
+                                        precision=lax.Precision.HIGHEST)
         state2 = AssignState(
             used=used2,
             ppa=state.ppa | orp, ppw=state.ppw | orw, ppt=state.ppt | ort,

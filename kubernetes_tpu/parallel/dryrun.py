@@ -13,11 +13,8 @@ mesh and assert sharded == single-device bit-for-bit, at three rungs:
 XLA GSPMD inserts the ICI collectives (argmax/any/sort movements over the
 sharded node axis) from the sharding annotations alone.
 
-This module is the ONE home for the dryrun (ISSUE 3 satellite: the driver
-logic used to live duplicated in __graft_entry__.py): `bench.py --stage`
-runs it as the budgeted `multichip` stage emitting the MULTICHIP_OUT
-artifact, and __graft_entry__.py delegates here for the historical
-entry-point behavior.
+This module is the ONE home for the dryrun: `bench.py --stage` runs it as
+the budgeted `multichip` stage emitting the MULTICHIP_OUT artifact.
 """
 
 from __future__ import annotations

@@ -371,11 +371,10 @@ def _schedule_batch(tables, pending, keys, D, existing,
         compiled = prewarmer.lookup(dims, engine, extra_plugins,
                                     gang is not None, mesh=mesh, rc=rc)
         if compiled is not None:
-            try:
-                return compiled(tables, pending, keys, existing, hw, ecfg,
-                                extra_weights, gang)
-            except TypeError:
-                pass  # aval/pytree drift — take the ordinary jit path
+            ok, out = prewarmer.call(compiled, tables, pending, keys,
+                                     existing, hw, ecfg, extra_weights, gang)
+            if ok:
+                return out
     return _schedule_batch_impl(tables, pending, keys, D, existing, engine,
                                 hw, ecfg,
                                 extra_plugins, extra_weights, gang,

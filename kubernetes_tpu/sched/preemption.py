@@ -157,9 +157,9 @@ class Preemptor:
     def preempt_burst(self, scheduler, burst: Sequence[Tuple[Pod, int]],
                       snap: Snapshot, now: float) -> Set[str]:
         """The whole wave's preemption pass as ONE fused device dispatch
-        (chunked at PREEMPT_BURST lanes): evaluate every unschedulable
-        priority pod's what-if against the same snapshot, then commit
-        host-side in batch order. Returns the keys that preempted (victims
+        (chunked at PREEMPT_BURST lanes, one lane per DISTINCT preemptor
+        template): evaluate every unschedulable priority pod's what-if
+        against the same snapshot, then commit host-side in batch order. Returns the keys that preempted (victims
         evicted, pod nominated + requeued); the caller requeues the rest as
         plain unschedulable.
 
@@ -214,19 +214,25 @@ class Preemptor:
         pend_cls = np.asarray(jax.device_get(snap.pending.cls))
         pend_nnr = np.asarray(jax.device_get(snap.pending.node_name_req))
 
-        handled: Set[str] = set()
-        retry_soon: Set[str] = set()  # candidates whose space another lane
-                                      # freed this burst: retry promptly
+        # Lanes are evaluated against the PRE-burst snapshot, so preemptors
+        # that agree on (class, nodeName pin, priority) get the identical
+        # what-if: evaluate each distinct one once and share the answer. A
+        # backlog's unschedulable tail is thousands of replicas of a few
+        # templates — on the chip one 8-lane dispatch at the 5k×50k shape
+        # takes 0.7–1.9 s, so a lane per pod there is an hour of what-ifs.
+        lane_of = [(int(pend_cls[row]), int(pend_nnr[row]), int(pod.priority))
+                   for pod, _attempts, row in eligible]
+        distinct = list(dict.fromkeys(lane_of))
+        # lane → (node index, victim pod keys, PDB violations)
+        verdict: dict = {}
         supervisor = getattr(scheduler, "supervisor", None)
         B = PREEMPT_BURST
-        for lo in range(0, len(eligible), B):
-            chunk = eligible[lo: lo + B]
+        for lo in range(0, len(distinct), B):
+            chunk = distinct[lo: lo + B]
             pad = chunk + [chunk[-1]] * (B - len(chunk))
-            rows = [r for _, _, r in pad]
-            cls_b = jnp.asarray(pend_cls[rows], jnp.int32)
-            nnr_b = jnp.asarray(pend_nnr[rows], jnp.int32)
-            prio_b = jnp.asarray(
-                np.array([p.priority for p, _, _ in pad], np.int32))
+            cls_b = jnp.asarray([c for c, _, _ in pad], jnp.int32)
+            nnr_b = jnp.asarray([n for _, n, _ in pad], jnp.int32)
+            prio_b = jnp.asarray(np.array([p for _, _, p in pad], np.int32))
 
             def _readback(res: PreemptResult):
                 return (np.asarray(jax.device_get(res.node)),
@@ -242,12 +248,11 @@ class Preemptor:
                                                     mesh=snap.mesh) \
                     if prewarmer is not None else None
                 if compiled is not None:
-                    try:
-                        return _readback(compiled(
-                            snap.tables, snap.existing, cls_b, nnr_b,
-                            prio_b, (uk, ev), pdb_dev, hw, ecfg))
-                    except TypeError:
-                        pass  # aval/pytree drift — ordinary jit path
+                    ok, out = prewarmer.call(
+                        compiled, snap.tables, snap.existing, cls_b, nnr_b,
+                        prio_b, (uk, ev), pdb_dev, hw, ecfg)
+                    if ok:
+                        return _readback(out)
                 return _readback(_preempt(
                     snap.tables, snap.existing, cls_b, nnr_b, prio_b,
                     snap.dims.D, (uk, ev), pdb_dev, hw, ecfg))
@@ -288,57 +293,65 @@ class Preemptor:
                 except DispatchAbandonedError:
                     # both backends refused the burst: NOTHING in this chunk
                     # (or the remaining ones) was evaluated, so nothing is
-                    # evicted — every un-handled pod takes the ordinary
-                    # unschedulable/requeue path upstream. Crash-consistent:
-                    # evictions only ever happen after a successful readback.
+                    # evicted for them — every pod without a verdict takes
+                    # the ordinary unschedulable/requeue path upstream.
+                    # Crash-consistent: evictions only ever happen after a
+                    # successful readback.
                     break
             else:
                 nodes_b, victims_b, npdb_b = _primary()
+            for i, lane in enumerate(chunk):
+                verdict[lane] = (
+                    int(nodes_b[i]),
+                    [snap.existing_keys[e] for e in np.flatnonzero(
+                        victims_b[i][: len(snap.existing_keys)])],
+                    int(npdb_b[i]))
 
-            for lane, (pod, attempts, _row) in enumerate(chunk):
-                node_idx = int(nodes_b[lane])
-                if node_idx < 0:
-                    continue
-                victim_keys = [
-                    snap.existing_keys[i]
-                    for i in np.flatnonzero(
-                        victims_b[lane][: len(snap.existing_keys)])
-                ]
-                if not victim_keys:
-                    # a candidate with zero victims: the pod should simply
-                    # fit. Once per pod that is burst staleness (an earlier
-                    # lane/wave freed the space after the what-if's
-                    # snapshot) — retry promptly. A repeat means a real
-                    # host/device filter discrepancy: evicting nothing and
-                    # nominating would only mask it, so it takes the
-                    # normal backoff + FailedScheduling path.
-                    if self._zero_victim_retries.get(pod.key, 0) < 1:
-                        if len(self._zero_victim_retries) > 4096:
-                            # bound the ledger by dropping the OLDEST half
-                            # (dict preserves insertion order) — clearing
-                            # wholesale would forget the pod just recorded
-                            # and re-arm the hot loop this cap prevents
-                            for k in list(self._zero_victim_retries)[:2048]:
-                                del self._zero_victim_retries[k]
-                        self._zero_victim_retries[pod.key] = 1
-                        retry_soon.add(pod.key)
-                    continue
-                evicted_any = False
-                for vk in victim_keys:
-                    evicted_any |= self.evictor.evict(scheduler, vk)
-                if not evicted_any:
-                    # every victim was already evicted for an earlier lane:
-                    # that lane's commit freed this space — the pod is
-                    # expected to fit next wave; exponential backoff here
-                    # would serialize the whole burst at seconds per round
+        # ---- host commit, in batch order ---- #
+        handled: Set[str] = set()
+        retry_soon: Set[str] = set()  # candidates whose space another lane
+                                      # freed this burst: retry promptly
+        for (pod, attempts, _row), lane in zip(eligible, lane_of):
+            if lane not in verdict:
+                continue
+            node_idx, victim_keys, n_pdb = verdict[lane]
+            if node_idx < 0:
+                continue
+            if not victim_keys:
+                # a candidate with zero victims: the pod should simply
+                # fit. Once per pod that is burst staleness (an earlier
+                # lane/wave freed the space after the what-if's
+                # snapshot) — retry promptly. A repeat means a real
+                # host/device filter discrepancy: evicting nothing and
+                # nominating would only mask it, so it takes the
+                # normal backoff + FailedScheduling path.
+                if self._zero_victim_retries.get(pod.key, 0) < 1:
+                    if len(self._zero_victim_retries) > 4096:
+                        # bound the ledger by dropping the OLDEST half
+                        # (dict preserves insertion order) — clearing
+                        # wholesale would forget the pod just recorded
+                        # and re-arm the hot loop this cap prevents
+                        for k in list(self._zero_victim_retries)[:2048]:
+                            del self._zero_victim_retries[k]
+                    self._zero_victim_retries[pod.key] = 1
                     retry_soon.add(pod.key)
-                    continue
-                self.last_pdb_violations = int(npdb_b[lane])
-                scheduler.queue.add_nominated(pod.key,
-                                              snap.node_order[node_idx])
-                handled.add(pod.key)
-                self._zero_victim_retries.pop(pod.key, None)
-                self.successes += 1
+                continue
+            evicted_any = False
+            for vk in victim_keys:
+                evicted_any |= self.evictor.evict(scheduler, vk)
+            if not evicted_any:
+                # every victim was already evicted for an earlier lane:
+                # that lane's commit freed this space — the pod is
+                # expected to fit next wave; exponential backoff here
+                # would serialize the whole burst at seconds per round
+                retry_soon.add(pod.key)
+                continue
+            self.last_pdb_violations = n_pdb
+            scheduler.queue.add_nominated(pod.key,
+                                          snap.node_order[node_idx])
+            handled.add(pod.key)
+            self._zero_victim_retries.pop(pod.key, None)
+            self.successes += 1
 
         if not handled:
             # no lane evicted anything: a zero-victim candidate here is a

@@ -203,9 +203,12 @@ class BucketPrewarmer:
         # freshly recovered backend (recovery flap)
         self._epoch = 0
         # dispatch supervisor (sched/supervisor.py): background compile
-        # failures that look like backend loss are reported so the health
-        # machinery reacts to them exactly as to a failed live dispatch
+        # failures are reported there to be counted and narrated
         self.supervisor = None
+        # stored executables the dispatch layer actually ran (`call`), and
+        # the ones it had to drop for the jit path on aval/pytree drift
+        self.hits = 0
+        self.type_error_drops = 0
 
     @staticmethod
     def _mesh_sig(mesh):
@@ -304,8 +307,8 @@ class BucketPrewarmer:
         except Exception as e:
             # prewarming is an optimization: a failed background compile
             # must never take down the scheduling loop; the live path will
-            # compile on demand exactly as without a prewarmer. A failure
-            # that smells like backend loss IS reported to the supervisor.
+            # compile on demand exactly as without a prewarmer. The
+            # supervisor counts it.
             with self._mu:
                 self._warmed.discard(key)
             if self.supervisor is not None:
@@ -324,6 +327,20 @@ class BucketPrewarmer:
         return self.compiled.get(
             (replace(d, has_node_name=False), engine, extras, gang,
              rc, fleet, self._mesh_sig(mesh)))
+
+    def call(self, compiled, *args):
+        """Run a stored executable: (True, result), or (False, None) when
+        its avals/pytree no longer match the live arguments (TypeError) and
+        the caller must take the ordinary jit path. Both outcomes are
+        counted, so a run can say whether prewarmed executables were
+        actually used or silently dropped."""
+        try:
+            out = compiled(*args)
+        except TypeError:
+            self.type_error_drops += 1
+            return False, None
+        self.hits += 1
+        return True, out
 
     def invalidate(self) -> None:
         """Drop every stored executable and warm record, and fence out
@@ -422,8 +439,7 @@ class BucketPrewarmer:
                 try:
                     cache.warm_patch_ladder(snap, mesh=mesh)
                 except Exception as e:  # noqa: BLE001 - warm is an
-                    # optimization (see _compile); backend-loss-shaped
-                    # failures still reach the supervisor
+                    # optimization (see _compile)
                     with self._mu:
                         self._warmed.discard(key)
                     if self.supervisor is not None:
@@ -497,9 +513,7 @@ class BucketPrewarmer:
                 self.compiled[key] = compiled
             self.warm_log.append((d, "preempt"))
         except Exception as e:
-            # same contract as _compile: never takes down the loop, but a
-            # device-class failure is a backend-loss signal the supervisor
-            # must hear
+            # same contract as _compile: never takes down the loop
             with self._mu:
                 self._warmed.discard(key)
             if self.supervisor is not None:

@@ -586,6 +586,9 @@ class Scheduler:
         snap, keys = (self._micro_snapshot_keys(pending) if micro
                       else self._snapshot_keys(pending))
         span.mark("snapshot")
+        # how the snapshot this wave dispatches on was produced
+        # ("full" | "patch" | "cached") rides the wave's record
+        snap_mode = self.cache.last_snapshot_mode
         extras = tuple(p for p, _ in self._extra_score)
         extra_w = tuple(w for _, w in self._extra_score)
         from dataclasses import replace as _dc_replace
@@ -944,10 +947,12 @@ class Scheduler:
             from .metrics import MICRO_WAVES
 
             MICRO_WAVES.inc(scheduler=self.scheduler_name)
+        extra = {"snapshot_mode": snap_mode}
+        if explain_rec:
+            extra["explain"] = explain_rec
         self.telemetry.finish_wave(
             span, stats=stats, engine=wave_engine, dims=snap.dims, rc=rc,
-            micro=micro,
-            extra={"explain": explain_rec} if explain_rec else None)
+            micro=micro, extra=extra)
         return stats
 
     def _schedule_one_with_extenders(

@@ -429,6 +429,10 @@ class SchedulerServer:
         self._crashed = False
         self.total_scheduled = 0
         self.total_unschedulable_events = 0
+        # waves that raised (the loop survives them; a caller that must not
+        # miss one reads these)
+        self.wave_errors = 0
+        self.last_wave_error: Optional[BaseException] = None
         # scheduler-side /metrics + /debug/flightrecorder exposition
         # (TelemetryGateway): None = off, 0 = ephemeral port, N = fixed
         self.telemetry_port = telemetry_port
@@ -494,6 +498,9 @@ class SchedulerServer:
             f"{m.get('namespace', 'default')}/{m.get('name', '')}", None)
 
     def start(self) -> "SchedulerServer":
+        from kubernetes_tpu.utils.platform import enable_compile_cache
+
+        enable_compile_cache()  # before the loop's first compile
         if self.scheduler.preemptor is not None \
                 and getattr(self.scheduler.preemptor, "pdb_source", None) \
                 is not None:
@@ -663,7 +670,9 @@ class SchedulerServer:
         with self._mu:
             try:
                 stats = self.scheduler.schedule_pending()
-            except Exception:  # noqa: BLE001 — the loop never dies
+            except Exception as e:  # noqa: BLE001 — the loop never dies
+                self.wave_errors += 1
+                self.last_wave_error = e
                 return None
             # depths() carries the deferred lane too — the governor's own
             # control signals become scrapeable gauges

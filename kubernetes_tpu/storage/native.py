@@ -67,9 +67,14 @@ _build_error: Optional[str] = None  # why native is unavailable (surfaced
 
 
 def _build_lib(force: bool = False) -> Optional[str]:
+    """Path of libkvstore.so — a build product, never committed: built from
+    native/kvstore.cpp by native/Makefile on first use, and again whenever
+    the source is newer than the library."""
     global _build_error
     so = os.path.join(_NATIVE_DIR, "libkvstore.so")
-    if os.path.exists(so) and not force:
+    src = os.path.join(_NATIVE_DIR, "kvstore.cpp")
+    if os.path.exists(so) and not force \
+            and os.path.getmtime(so) >= os.path.getmtime(src):
         return so
     try:
         cmd = ["make", "-C", _NATIVE_DIR] + (["-B"] if force else [])

@@ -389,6 +389,47 @@ class TestFusedPreemptionBurst:
                                                     "default/victim1"}
 
 
+    def test_replicas_of_one_template_share_one_what_if(self, monkeypatch):
+        """Preemptors that agree on (class, nodeName pin, priority) get the
+        identical what-if against the pre-burst snapshot, so a burst of 40
+        replicas of two templates is ONE 8-lane dispatch, not five — and
+        commits exactly as the lane-per-pod burst did (same-verdict pods
+        overlap on the victim; one is nominated, the rest retry)."""
+        import kubernetes_tpu.sched.preemption as pm
+        from kubernetes_tpu.sched.scheduler import (
+            RecordingBinder, Scheduler)
+
+        calls = []
+        real = pm._preempt
+
+        def counting(*a, **k):
+            calls.append(1)
+            return real(*a, **k)
+
+        monkeypatch.setattr(pm, "_preempt", counting)
+        s = Scheduler(binder=RecordingBinder(), clock=lambda: 0.0,
+                      preemptor=pm.Preemptor())
+        s.prewarmer.enabled = False  # the counted jit path, not an AOT one
+        for i in range(2):
+            s.on_node_add(mknode(f"n{i}", cpu=1))
+            s.on_pod_add(bound(f"victim{i}", f"n{i}", cpu="800m",
+                               priority=0, idx=i))
+        for i in range(40):
+            # two templates: evictable space for one, none for the other
+            big = i % 2 == 1
+            s.on_pod_add(Pod(
+                name=f"vip{i}", priority=100,
+                requests=Resources.make(cpu="8" if big else "800m",
+                                        memory="128Mi"),
+                creation_index=10 + i))
+        st = s.schedule_pending()
+        assert st.scheduled == 0
+        assert len(calls) == 1
+        assert s.preemptor.attempts == 40
+        assert s.preemptor.successes == 1
+        assert len(s.preemptor.evictor.evicted) == 1
+
+
 class TestSatellites:
     def test_csr_stamping_keyed_on_path_not_kind(self):
         """POSTing to the CSR collection with `kind` omitted must still get
