@@ -19,12 +19,11 @@ from kubernetes_tpu.component.metrics import (
     Histogram,
     Registry,
 )
-from kubernetes_tpu.component.trace import Trace, device_step_marker
+from kubernetes_tpu.component.trace import Trace
 
 VERSION = {"gitVersion": "v1.17.0-tpu.1", "major": "1", "minor": "17+",
            "platform": "jax/xla-tpu"}
 
 __all__ = ["ALPHA", "BETA", "Counter", "DEFAULT_FEATURE_GATES",
            "DEFAULT_REGISTRY", "FeatureGate", "FeatureSpec", "GA", "Gauge",
-           "Histogram", "Registry", "Trace", "VERSION",
-           "device_step_marker"]
+           "Histogram", "Registry", "Trace", "VERSION"]

@@ -128,19 +128,26 @@ class Histogram(_Metric):
         self._totals: Dict[Tuple[str, ...], int] = {}
 
     def observe(self, value: float, **labels) -> None:
+        self.observe_at(self._key(labels), value)
+
+    def observe_at(self, key: Tuple[str, ...], value: float) -> None:
+        """`observe` for a caller that holds its label VALUES as a tuple in
+        `label_names` order (one observation per apiserver request or
+        store transaction: no kwargs dict, no key rebuilt per call)."""
         # counts are stored PER BUCKET (non-cumulative) and accumulated at
         # expose/quantile time: observe is on the per-pod hot path (the
         # e2e latency histogram fires once per Binding), and a Python loop
         # over every bucket per observation was a measurable slice of the
         # telemetry overhead budget — one bisect is not
         with self._mu:
-            k = self._key(labels)
-            counts = self._counts.setdefault(k, [0] * len(self.buckets))
+            counts = self._counts.get(key)
+            if counts is None:
+                counts = self._counts[key] = [0] * len(self.buckets)
             i = bisect.bisect_left(self.buckets, value)
             if i < len(counts):
                 counts[i] += 1
-            self._sums[k] = self._sums.get(k, 0.0) + value
-            self._totals[k] = self._totals.get(k, 0) + 1
+            self._sums[key] = self._sums.get(key, 0.0) + value
+            self._totals[key] = self._totals.get(key, 0) + 1
 
     def observe_many(self, values: Sequence[float], **labels) -> None:
         """Batch observe: one lock acquisition (and one dict resolve) for a

@@ -3,21 +3,75 @@
 Analog of `vendor/k8s.io/utils/trace/trace.go` (utiltrace) as used by the
 scheduler (`core/generic_scheduler.go:188-217` Step/LogIfLong): a Trace
 collects timed steps; if the whole operation exceeds a threshold, the steps
-are emitted so slow cycles are explainable. Also the hook point for JAX
-profiler ranges on device-dispatch steps.
+are emitted so slow cycles are explainable.
+
+Child accounting (ISSUE 24): below its steps a Trace keeps a tree of
+AGGREGATES — `[count, total_s, max_s]` per path, never one record per call,
+so a wave of 13,600 Bindings costs a dict lookup and three updates per span.
+A path's segments are its parents: `bind-commit/bind-call/apiserver.bind`.
+The step that closes a phase names the top segment (children recorded while
+the phase ran are filed under the step's message); `begin`/`end` nest below
+it; `child` adds a leaf. A layer's self time is its total less its
+children's — readers compute it, the program does not.
+
+`current()` is the trace of the operation running on THIS thread (a
+`contextvars` slot): a callee several layers down — the binder, the
+in-process apiserver, the store — adds its time to the span that caused it
+without a new argument through every signature between. Off that thread,
+after the operation, or with tracing off, it is `None`: one check.
 """
 
 from __future__ import annotations
 
+import contextvars
 import logging
 import time
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 logger = logging.getLogger("kubernetes_tpu.trace")
 
 
 #: default LogIfLong threshold (the reference's 100ms scheduler trace bound)
 DEFAULT_THRESHOLD = 0.1
+
+
+class _Node:
+    """One path's aggregate and the paths below it."""
+
+    __slots__ = ("count", "total", "max", "kids")
+
+    def __init__(self) -> None:
+        self.count = 0
+        self.total = 0.0
+        self.max = 0.0
+        self.kids: Dict[str, "_Node"] = {}
+
+    def kid(self, name: str) -> "_Node":
+        node = self.kids.get(name)
+        if node is None:
+            node = self.kids[name] = _Node()
+        return node
+
+    def add(self, seconds: float) -> None:
+        self.count += 1
+        self.total += seconds
+        if seconds > self.max:
+            self.max = seconds
+
+    def merge(self, other: "_Node") -> None:
+        self.count += other.count
+        self.total += other.total
+        if other.max > self.max:
+            self.max = other.max
+        for name, node in other.kids.items():
+            self.kid(name).merge(node)
+
+    def flatten(self, prefix: str, out: Dict[str, List[float]]) -> None:
+        for name, node in self.kids.items():
+            path = prefix + name
+            if node.count:
+                out[path] = [node.count, node.total, node.max]
+            node.flatten(path + "/", out)
 
 
 class Trace:
@@ -30,9 +84,51 @@ class Trace:
         self.start = clock()
         self.steps: List[Tuple[float, str]] = []
         self._ended: Optional[float] = None
+        # child accounting: `_open` collects what runs before the next
+        # step names it; `_cur` is the span new children nest under
+        self._root = _Node()
+        self._open = _Node()
+        self._cur = self._open
 
     def step(self, msg: str) -> None:
         self.steps.append((self.clock(), msg))
+        if self._open.kids:
+            self._root.kid(msg).merge(self._open)
+            self._open = _Node()
+        self._cur = self._open
+
+    def begin(self, name: str) -> _Node:
+        """Open span `name` below the current one; children recorded until
+        `end` nest under it. Returns the token `end` takes."""
+        parent = self._cur
+        self._cur = parent.kid(name)
+        return parent
+
+    def end(self, token: _Node, seconds: float) -> None:
+        """Close the span `begin` opened, adding one call of `seconds`."""
+        self._cur.add(seconds)
+        self._cur = token
+
+    def child(self, path: str, seconds: float) -> None:
+        """Add one call of `seconds` under `path`, below the current span.
+        A `/` in the path names parents: `child("a/b", s)` files `b`
+        below `a` without counting a call of `a`."""
+        node = self._cur
+        if "/" in path:
+            for name in path.split("/"):
+                node = node.kid(name)
+        else:
+            node = node.kid(path)
+        node.add(seconds)
+
+    def children(self) -> Dict[str, List[float]]:
+        """`{path: [count, total_s, max_s]}` of every span that was called,
+        in the order first seen. Children of a phase no step has closed
+        yet are listed without a phase segment."""
+        out: Dict[str, List[float]] = {}
+        self._root.flatten("", out)
+        self._open.flatten("", out)
+        return out
 
     def duration(self) -> float:
         return (self._ended or self.clock()) - self.start
@@ -67,12 +163,18 @@ class Trace:
             self.log_if_long(self.threshold)
 
 
-def device_step_marker(name: str):
-    """JAX profiler named scope for device-dispatch steps — shows up in TPU
-    profiler timelines (the jax.profiler analog of the reference's pprof)."""
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — profiling must never break the op
-        import contextlib
-        return contextlib.nullcontext()
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "kubernetes_tpu_trace", default=None)
+
+#: the Trace of the operation running on this thread, or None
+current = _CURRENT.get
+
+
+def activate(trace: Trace):
+    """Make `trace` this thread's `current()`; returns the token
+    `deactivate` takes. A thread started meanwhile sees None."""
+    return _CURRENT.set(trace)
+
+
+def deactivate(token) -> None:
+    _CURRENT.reset(token)

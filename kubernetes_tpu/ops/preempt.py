@@ -197,7 +197,9 @@ def preempt_for_pod(
         return (used, conflict, victim), None
 
     init = (used_wo, conflict_wo, jnp.zeros((E,), bool))
-    (used_f, conf_f, victim), _ = jax.lax.scan(step, init, order)
+    # named for the profiler's name-scope line: the what-if's long pole
+    with jax.named_scope("reprieve_scan"):
+        (used_f, conf_f, victim), _ = jax.lax.scan(step, init, order)
 
     # ---- pickOneNodeForPreemption (:903): lexicographic over
     # (1) PDB violations, (2) highest victim priority, (3) priority sum,

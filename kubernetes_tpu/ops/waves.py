@@ -109,6 +109,9 @@ def interaction_graph(tables: ClusterTables, cyc: CycleArrays) -> Array:
     return G & ~jnp.eye(G.shape[0], dtype=bool)
 
 
+# the device program's stages carry `jax.named_scope` names, so a profiler
+# trace groups its fusions by stage (no run-time cost: metadata only)
+@jax.named_scope("class_mask_score")
 def _class_mask_score(tables, cyc, state):
     """[SC, N] Filter mask + Score for every class against `state` — the
     dense analog of findNodesThatFit + prioritizeNodes, once per class.
@@ -142,6 +145,7 @@ def _class_mask_score(tables, cyc, state):
             scores.reshape(-1, scores.shape[-1])[:SC])
 
 
+@jax.named_scope("domain_quota_pass")
 def _domain_quota_pass(tables, cyc, state, mask, order_n, allowed_sorted):
     """AND per-domain admission quotas into `allowed_sorted` [SC, N] (nodes in
     per-class score order). Quotas keep same-wave same-class admissions from
@@ -527,21 +531,22 @@ def assign_waves(
         total = m.sum()
 
         # ---- commit ----
-        Ai = A_final.astype(jnp.int32)
-        used2 = state.used + jnp.einsum("cn,cr->nr", Ai, req_by_class)
-        CNT2 = state.CNT + cyc.TM.astype(jnp.int32) @ Ai
-        HOLD2 = state.HOLD + cyc.has_anti.T.astype(jnp.int32) @ Ai
-        # HIGHEST: at the TPU's default precision an f32 matmul rounds its
-        # operands to bf16, and a weight sum such as 301 is not a bf16 — the
-        # scan engine's exact adds of the same WCOLS entries would diverge
-        WSYM2 = state.WSYM + jnp.matmul(cyc.WCOLS, Ai.astype(jnp.float32),
-                                        precision=lax.Precision.HIGHEST)
-        state2 = AssignState(
-            used=used2,
-            ppa=state.ppa | orp, ppw=state.ppw | orw, ppt=state.ppt | ort,
-            CNT=CNT2, HOLD=HOLD2, WSYM=WSYM2,
-            vol_any=state.vol_any | orva, vol_rw=state.vol_rw | orvr,
-        )
+        with jax.named_scope("wave_commit"):
+            Ai = A_final.astype(jnp.int32)
+            used2 = state.used + jnp.einsum("cn,cr->nr", Ai, req_by_class)
+            CNT2 = state.CNT + cyc.TM.astype(jnp.int32) @ Ai
+            HOLD2 = state.HOLD + cyc.has_anti.T.astype(jnp.int32) @ Ai
+            # HIGHEST: at the TPU's default precision an f32 matmul rounds its
+            # operands to bf16, and a weight sum such as 301 is not a bf16 — the
+            # scan engine's exact adds of the same WCOLS entries would diverge
+            WSYM2 = state.WSYM + jnp.matmul(cyc.WCOLS, Ai.astype(jnp.float32),
+                                            precision=lax.Precision.HIGHEST)
+            state2 = AssignState(
+                used=used2,
+                ppa=state.ppa | orp, ppw=state.ppw | orw, ppt=state.ppt | ort,
+                CNT=CNT2, HOLD=HOLD2, WSYM=WSYM2,
+                vol_any=state.vol_any | orva, vol_rw=state.vol_rw | orvr,
+            )
 
         # ---- map admissions back to pods (rank among kept, score order) ----
         sck = jnp.where(A_final, score, -jnp.inf)

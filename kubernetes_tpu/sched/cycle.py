@@ -161,9 +161,11 @@ def _schedule_batch_impl(
     from ..ops.waves import assign_waves
 
     uk, ev = keys
-    cyc = build_cycle(tables, existing, uk, ev, D, hard_weight, ecfg)
-    cyc = _apply_extra_plugins(tables, cyc, extra_plugins, extra_weights)
-    init = initial_state(tables, cyc)
+    # stage names for the profiler's name-scope line (metadata only)
+    with jax.named_scope("build_cycle"):
+        cyc = build_cycle(tables, existing, uk, ev, D, hard_weight, ecfg)
+        cyc = _apply_extra_plugins(tables, cyc, extra_plugins, extra_weights)
+        init = initial_state(tables, cyc)
     # `rc` is the run-collapsed engine's static run capacity (ops/runs.py
     # plan_runs); it also bounds every gang rejection round's run count
     # (masking merges/shrinks runs, never splits them)
@@ -199,9 +201,10 @@ def _schedule_batch_impl(
         # explain=False traces the byte-for-byte pre-provenance program.
         from ..ops.assign import explain_assignments
 
-        exp = explain_assignments(
-            tables, cyc, pending, res,
-            granularity="pod" if engine == "scan" else "class")
+        with jax.named_scope("explain"):
+            exp = explain_assignments(
+                tables, cyc, pending, res,
+                granularity="pod" if engine == "scan" else "class")
         return res, exp
     return (res, waves) if return_waves else res
 
@@ -215,9 +218,11 @@ def _gang_prep_impl(tables, keys, D, existing, hard_weight, ecfg,
     round reuses the device-resident CycleArrays (VERDICT r4 weakness 2: each
     round used to re-pay build_cycle)."""
     uk, ev = keys
-    cyc = build_cycle(tables, existing, uk, ev, D, hard_weight, ecfg)
-    cyc = _apply_extra_plugins(tables, cyc, extra_plugins, extra_weights)
-    init = initial_state(tables, cyc)
+    # stage names for the profiler's name-scope line (metadata only)
+    with jax.named_scope("build_cycle"):
+        cyc = build_cycle(tables, existing, uk, ev, D, hard_weight, ecfg)
+        cyc = _apply_extra_plugins(tables, cyc, extra_plugins, extra_weights)
+        init = initial_state(tables, cyc)
     return cyc, init
 
 

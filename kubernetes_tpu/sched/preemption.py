@@ -16,14 +16,17 @@ from __future__ import annotations
 
 import functools
 import os
+import time
 from typing import Callable, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..api.types import Pod
+from ..component import trace
 from ..ops.preempt import PreemptResult, preempt_batch
 from ..state.cache import Snapshot
+from .metrics import PREEMPTION_ATTEMPTS, PREEMPTION_VICTIMS
 
 # preemptor lanes per fused dispatch: bursts larger than this chunk. ONE
 # fixed size keeps the compile-signature count at one per Dims bucket (and
@@ -198,6 +201,7 @@ class Preemptor:
         if not eligible:
             return set()
         self.attempts += len(eligible)
+        PREEMPTION_ATTEMPTS.inc(len(eligible))
 
         enc = scheduler.encoder
         uk = jnp.int32(enc.vocabs.label_keys.get(UNSCHEDULABLE_TAINT_KEY))
@@ -278,6 +282,10 @@ class Preemptor:
                     return _readback(_preempt(tb, ex, cb, nb, pb,
                                               snap.dims.D, ky, pd, hw_f, ec))
 
+            # one what-if burst, dispatch and readback (the supervisor
+            # runs both on its worker): a child of the traced wave's pass
+            tr = trace.current()
+            tw0 = time.perf_counter()
             if supervisor is not None:
                 from dataclasses import replace as _dc_replace
 
@@ -300,6 +308,8 @@ class Preemptor:
                     break
             else:
                 nodes_b, victims_b, npdb_b = _primary()
+            if tr is not None:
+                tr.child("what-if", time.perf_counter() - tw0)
             for i, lane in enumerate(chunk):
                 verdict[lane] = (
                     int(nodes_b[i]),
@@ -338,7 +348,9 @@ class Preemptor:
                 continue
             evicted_any = False
             for vk in victim_keys:
-                evicted_any |= self.evictor.evict(scheduler, vk)
+                if self.evictor.evict(scheduler, vk):
+                    evicted_any = True
+                    PREEMPTION_VICTIMS.inc()
             if not evicted_any:
                 # every victim was already evicted for an earlier lane:
                 # that lane's commit freed this space — the pod is

@@ -27,10 +27,12 @@ import jax
 import jax.numpy as jnp
 
 from ..api.types import DEFAULT_SCHEDULER_NAME, Node, Pod
+from ..component import trace
 from ..state.cache import SchedulerCache, Snapshot
 from ..state.dims import Dims
 from ..state.encode import Encoder
 from .cycle import UNSCHEDULABLE_TAINT_KEY, _schedule_batch
+from .metrics import BINDING_DURATION
 from .queue import PriorityQueue
 
 
@@ -444,6 +446,10 @@ class Scheduler:
         # phase that just ran; the record feeds the per-operation histogram
         # and the flight-recorder ring (no-op span when KTPU_TELEMETRY=0)
         span = self.telemetry.wave_span()
+        # the wave's Trace is `trace.current()` on this thread while it
+        # runs: the binder, the in-process apiserver and the store add
+        # their time to it as children of the phase that called them
+        token = trace.activate(span.trace) if span.enabled else None
         ctx: Dict[str, object] = {}
         try:
             return self._run_wave(span, now, t0, ctx,
@@ -461,10 +467,13 @@ class Scheduler:
             self.telemetry.finish_wave(
                 span, stats=stats, engine=ctx.get("engine", ""),
                 dims=ctx.get("dims"), rc=ctx.get("rc", 0),
-                extra={"exception": True})
+                extra={**ctx.get("extra", {}), "exception": True})
             if self.telemetry.enabled:
                 self.telemetry.dump("exception")
             raise
+        finally:
+            if token is not None:
+                trace.deactivate(token)
 
     def _drain_idle_events(self, span, stats, engine: str = "idle") -> None:
         """Supervisor events (a prewarm compile failure, a prober
@@ -536,6 +545,19 @@ class Scheduler:
         else:
             batch = self.queue.pop_batch(pop_limit, now=now)
         cycle = self.queue.current_cycle()
+        # what the popped pods waited for (`waits` on the wave's record):
+        # pop instant less first-seen stamp, read without consuming the
+        # stamps; and the Binding confirmations the informer delivered
+        # since the previous wave, beside the assumes still unconfirmed
+        wave_extra: Dict[str, object] = {}
+        ctx["extra"] = wave_extra
+        if batch and span.enabled:
+            confirm, outstanding = self.cache.drain_confirm_waits()
+            wave_extra["waits"] = {
+                "queue": self.telemetry.tracker.waits(
+                    [p.key for p, _ in batch], self.clock()),
+                "confirm": confirm}
+            wave_extra["assumed_outstanding"] = outstanding
         span.mark("pop")
         # ---- priority-aware shedding (SHED_LOW/TRICKLE): park sheddable
         # pods in the deferred lane — deferred, never dropped, no failure
@@ -579,7 +601,8 @@ class Scheduler:
             # an extender-only wave did REAL work (per-pod dispatches that
             # can degrade/abandon): it gets its own record, never "idle"
             span.mark("extenders")
-            self.telemetry.finish_wave(span, stats=stats, engine="extenders")
+            self.telemetry.finish_wave(span, stats=stats, engine="extenders",
+                                       extra=wave_extra)
             return stats
 
         pending = [p for p, _ in batch]
@@ -824,7 +847,7 @@ class Scheduler:
                 self.telemetry.finish_wave(span, stats=stats,
                                            engine=wave_engine,
                                            dims=snap.dims, rc=rc,
-                                           micro=micro)
+                                           micro=micro, extra=wave_extra)
                 return stats
         finally:
             # the dispatch no longer holds the snapshot's arrays — the
@@ -874,6 +897,7 @@ class Scheduler:
             intent = None
         span.mark("intent-write")
         bound_keys: List[str] = []
+        bind_times: List[float] = []
         for ci, (pod, node_name, attempts) in enumerate(commits):
             if self.governor is not None \
                     and not self.governor.commit_allowed():
@@ -889,7 +913,7 @@ class Scheduler:
                                                 now=now)
                 break
             self._commit(pod, node_name, attempts, now, cycle, stats,
-                         latency_keys=bound_keys)
+                         latency_keys=bound_keys, bind_times=bind_times)
         # e2e watch→bind spans close in ONE batched call per wave (the
         # per-pod scalar path was most of the measured telemetry
         # overhead); the clock reading is the end of the commit loop —
@@ -898,6 +922,7 @@ class Scheduler:
         # constant across a wave, so virtual latencies are unchanged
         if bound_keys:
             self.telemetry.record_bound_many(bound_keys, self.clock())
+        BINDING_DURATION.observe_many(bind_times)
         span.mark("bind-commit")
         self._retire_intent(intent)
         span.mark("retire")
@@ -916,14 +941,25 @@ class Scheduler:
             # coscheduling ecosystems gate preemption on the whole group)
             eligible = [(p, a) for p, a in failures if not p.pod_group]
             if eligible:
+                # children of `requeue` on a traced wave: the fresh
+                # snapshot, then the pass (what-if dispatches, evictions)
+                tr = trace.current()
+                tok = tr.begin("snapshot") if tr is not None else None
+                tp0 = time.perf_counter()
                 fresh = self.cache.snapshot(
                     self.encoder, [p for p, _ in failures], self.base_dims,
                     extra_intern=(UNSCHEDULABLE_TAINT_KEY,),
                     device=self.supervisor.snapshot_device(),
                     mesh=self.supervisor.snapshot_mesh(),
                 )
+                tp1 = time.perf_counter()
+                if tr is not None:
+                    tr.end(tok, tp1 - tp0)
+                    tok = tr.begin("preempt")
                 handled_keys = self.preemptor.preempt_burst(
                     self, eligible, fresh, now)
+                if tr is not None:
+                    tr.end(tok, time.perf_counter() - tp1)
         for pod, attempts in failures:
             if pod.key in handled_keys:
                 continue
@@ -947,7 +983,7 @@ class Scheduler:
             from .metrics import MICRO_WAVES
 
             MICRO_WAVES.inc(scheduler=self.scheduler_name)
-        extra = {"snapshot_mode": snap_mode}
+        extra = {"snapshot_mode": snap_mode, **wave_extra}
         if explain_rec:
             extra["explain"] = explain_rec
         self.telemetry.finish_wave(
@@ -1252,9 +1288,15 @@ class Scheduler:
         stats: CycleStats,
         binder_ext: Optional["object"] = None,
         latency_keys: Optional[List[str]] = None,
+        bind_times: Optional[List[float]] = None,
     ) -> None:
         fw = self.framework
         state = None
+        # the inside of a Binding, as children of the phase that called
+        # (`bind-commit/assume`, `/bind-call`, `/finish`): one clock read
+        # at each seam, aggregated per wave on the wave's Trace
+        tr = trace.current()
+        ta0 = time.perf_counter()
         self.cache.assume_pod(pod, node_name)
         self.queue.delete_nominated(pod.key)
 
@@ -1296,14 +1338,26 @@ class Scheduler:
                 rollback(as_bind_error=False)
                 return
         tb0 = time.perf_counter()
+        if tr is not None:
+            tr.child("assume", tb0 - ta0)
+            tok = tr.begin("bind-call")
         ok = self._run_bind(state, pod, node_name, binder_ext)
+        tb1 = time.perf_counter()
+        if tr is not None:
+            tr.end(tok, tb1 - tb0)
         if self.governor is not None:
             # commit-path breaker feed: outcome + wall latency of the
             # Binding write (wall time, not the injected clock — the SLO
             # is about real apiserver round-trips)
-            self.governor.note_commit(ok, time.perf_counter() - tb0)
+            self.governor.note_commit(ok, tb1 - tb0)
 
         if ok:
+            # scheduler_binding_duration_seconds: one sample per Binding
+            # written, fed per wave where the caller batches
+            if bind_times is not None:
+                bind_times.append(tb1 - tb0)
+            else:
+                BINDING_DURATION.observe(tb1 - tb0)
             self.cache.finish_binding(pod.key, now)
             # e2e watch→bind: close the pod's first-seen span (stamped at
             # queue admission) in the scheduler's clock domain — at the
@@ -1324,6 +1378,8 @@ class Scheduler:
                 fw.run_post_bind_plugins(state, pod, node_name)
         else:
             rollback(as_bind_error=True)
+        if tr is not None:
+            tr.child("finish", time.perf_counter() - tb1)
 
     def _run_bind(self, state, pod: Pod, node_name: str,
                   binder_ext: Optional["object"]) -> bool:

@@ -35,6 +35,28 @@ from kubernetes_tpu.sched.scheduler import Scheduler
 Obj = Dict[str, Any]
 
 
+class _HandlerLock:
+    """One informer handler's turn on `SchedulerServer._mu`, timed: the
+    seconds it waited for the lock (a wave holds it from pop to requeue)
+    and the seconds it held it, on the telemetry's clock."""
+
+    __slots__ = ("mu", "tel", "t0", "t1")
+
+    def __init__(self, mu, tel) -> None:
+        self.mu = mu
+        self.tel = tel
+
+    def __enter__(self) -> None:
+        self.t0 = self.tel.clock()
+        self.mu.acquire()
+        self.t1 = self.tel.clock()
+
+    def __exit__(self, *exc) -> None:
+        held = self.tel.clock() - self.t1
+        self.mu.release()
+        self.tel.note_handler(self.t1 - self.t0, held)
+
+
 class APIBinder:
     """Binder over POST pods/{name}/binding (scheduler.go:565). When volume
     binding is wired, BindPodVolumes runs first (scheduler.go:660,517) and a
@@ -453,30 +475,38 @@ class SchedulerServer:
 
     # -- event handlers (eventhandlers.go:335-441) --------------------------- #
 
+    def _handling(self):
+        """`with` target of an informer handler: `_mu`, and with telemetry
+        on the handler's wait for it and hold of it are counted onto the
+        next wave's record (`loop.handlers`). The PDB handlers take no
+        lock and are not counted."""
+        tel = self.scheduler.telemetry
+        return _HandlerLock(self._mu, tel) if tel.enabled else self._mu
+
     def _on_pod_add(self, obj: Obj) -> None:
         if not self._schedulable(obj):
             return
-        with self._mu:
+        with self._handling():
             self.scheduler.on_pod_add(self._to_pod(obj))
 
     def _on_pod_update(self, old: Obj, new: Obj) -> None:
-        with self._mu:
+        with self._handling():
             apply_pod_update_v1(self.scheduler, old, new, self._to_pod)
 
     def _on_pod_delete(self, obj: Obj) -> None:
-        with self._mu:
+        with self._handling():
             self.scheduler.on_pod_delete(pod_from_v1(obj))
 
     def _on_node_add(self, obj: Obj) -> None:
-        with self._mu:
+        with self._handling():
             self.scheduler.on_node_add(node_from_v1(obj))
 
     def _on_node_update(self, old: Obj, new: Obj) -> None:
-        with self._mu:
+        with self._handling():
             self.scheduler.on_node_update(node_from_v1(new))
 
     def _on_node_delete(self, obj: Obj) -> None:
-        with self._mu:
+        with self._handling():
             self.scheduler.on_node_delete(meta.name(obj))
 
     # -- lifecycle ----------------------------------------------------------- #
@@ -501,6 +531,9 @@ class SchedulerServer:
         from kubernetes_tpu.utils.platform import enable_compile_cache
 
         enable_compile_cache()  # before the loop's first compile
+        # the loop's account of the time between waves begins here: the
+        # informers' list+sync below is the first wave's `start` phase
+        self.scheduler.telemetry.loop_reset()
         if self.scheduler.preemptor is not None \
                 and getattr(self.scheduler.preemptor, "pdb_source", None) \
                 is not None:
@@ -613,6 +646,11 @@ class SchedulerServer:
     # -- the loop (wait.Until(scheduleOne) → batched waves) ------------------ #
 
     def _loop(self) -> None:
+        # every second between two waves that attempted pods goes to one
+        # named stretch (telemetry.loop_lap) and rides the later wave's
+        # flight-recorder record as `loop`: where the chip sat idle
+        lap = self.scheduler.telemetry.loop_lap
+        lap("start")
         while not self._stop.is_set():
             if not self._active.is_set():
                 # warm standby: the next activation must find compiled
@@ -628,6 +666,7 @@ class SchedulerServer:
                         except Exception:  # noqa: BLE001 - standby warmth
                             pass           # is an optimization, never fatal
                 self._stop.wait(0.2)
+                lap("standby")
                 continue
             if self._needs_recover:
                 # first led beat (process start, or a takeover): replay
@@ -648,7 +687,9 @@ class SchedulerServer:
                         # the next one; scheduling proceeds (pods are
                         # requeued by informer truth regardless)
                         self.last_recovery_error = e
+                lap("recover")
             with self._mu:
+                lap("lock-wait")  # behind the informer handlers
                 pending = self.scheduler.queue.lengths()[0]
             if pending and self.batch_window:
                 # coalesce STORMS into few large waves with the full
@@ -660,14 +701,18 @@ class SchedulerServer:
                 w = self.batch_window if pending >= 32 \
                     else min(0.05, self.batch_window)
                 self._stop.wait(w)  # let the batch fill
+                lap("batch-wait")
             stats = self.run_one_wave()
             if stats is None or stats.attempted == 0:
                 self._stop.wait(self.cycle_interval)
+                lap("idle-wait")  # the active queue was empty
 
     def run_one_wave(self):
         from kubernetes_tpu.sched import metrics as sched_metrics
 
+        lap = self.scheduler.telemetry.loop_lap
         with self._mu:
+            lap("lock-wait")
             try:
                 stats = self.scheduler.schedule_pending()
             except Exception as e:  # noqa: BLE001 — the loop never dies
@@ -702,6 +747,8 @@ class SchedulerServer:
             if obj is not None:
                 self.recorder.event(obj, "Warning", "FailedScheduling",
                                     "no nodes available to schedule pod")
+        # an empty call (no pod popped) is part of the wait for pods
+        lap("post-wave" if stats.attempted else "idle-wait")
         return stats
 
     def wait_until_idle(self, timeout: float = 30.0) -> bool:

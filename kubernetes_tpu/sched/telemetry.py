@@ -30,6 +30,16 @@ Three tiers (ISSUE 7; docs/OBSERVABILITY.md is the operator-facing manual):
      ratio; `KTPU_PROFILE=<dir>` additionally starts a `jax.profiler`
      trace with per-wave `TraceAnnotation` markers.
 
+Where the chip sits idle (ISSUE 24) rides the same record, on the same
+clock: `children` (what ran INSIDE a phase — `bind-commit/bind-call/
+apiserver.bind/store.txn` — as `[count, total_s, max_s]` aggregates from
+the wave's Trace, which is `component.trace.current()` while the wave
+runs), `loop` (what the server loop did between the previous wave and
+this one: `post-wave`, `lock-wait`, `batch-wait`, `idle-wait`, plus the
+informer handlers' calls, waits for and holds of the server's lock) and
+`waits` (how long the popped pods had queued; how long Binding
+confirmations took to come back through the informer).
+
 Kill switch: ``KTPU_TELEMETRY=0`` turns every tier into a no-op (the
 `latency` bench stage uses it to bound telemetry overhead at <2% of the
 untelemetered flagship pods/s).
@@ -174,6 +184,24 @@ class PodLatencyTracker:
                     out.append(max(now - t0, 0.0))
         return out
 
+    def waits(self, keys, now: float) -> List[float]:
+        """`[count, sum_s, max_s]` of `now` minus the first-seen stamp over
+        `keys`, WITHOUT consuming the stamps (the pop-time read: how long
+        the batch had queued; the commit-time `pop_latencies` still closes
+        each span). One lock round-trip for the batch."""
+        n, total, worst = 0, 0.0, 0.0
+        with self._mu:
+            get = self._first_seen.get
+            for k in keys:
+                t0 = get(k)
+                if t0 is not None:
+                    dt = now - t0
+                    n += 1
+                    total += dt
+                    if dt > worst:
+                        worst = dt
+        return [n, round(max(total, 0.0), 6), round(worst, 6)]
+
     def __len__(self) -> int:
         with self._mu:
             return len(self._first_seen)
@@ -304,6 +332,14 @@ class SchedulerTelemetry:
         # the token OBJECT (strong ref — an id() key could be reused by a
         # GC'd span), bounded below so abandoned waves' entries can't leak
         self._device_split: Dict[object, Dict[str, float]] = {}
+        # the server loop's account of the time since the last wave that
+        # attempted pods (loop_reset/loop_lap; None until a loop starts),
+        # and the informer handlers' [calls, wait_s, held_s] on the
+        # server's lock over the same interval — both drained onto the
+        # next such wave's record
+        self._loop: Optional[Trace] = None
+        self._loop_lap_t = 0.0
+        self._handlers: List[float] = [0, 0.0, 0.0]
         self.last_dump: Optional[Dict[str, Any]] = None
         self.dumps = 0
         # KTPU_PROFILE=<dir>: jax.profiler trace capture around dispatches
@@ -379,6 +415,40 @@ class SchedulerTelemetry:
         with self._mu:
             self._pending_events.append((kind, str(detail)[:200]))
 
+    def loop_reset(self) -> None:
+        """The server starts: its loop's account of the time between waves
+        begins here (sched/server.py laps it; a Scheduler driven without
+        a server loop never calls this and its records carry no `loop`)."""
+        if not self.enabled:
+            return
+        with self._mu:
+            self._new_loop(self.clock())
+
+    def _new_loop(self, t: float) -> None:
+        self._loop = Trace("loop", clock=lambda: t)  # its start IS t
+        self._loop_lap_t = t
+        self._handlers = [0, 0.0, 0.0]
+
+    def loop_lap(self, name: str) -> None:
+        """The server loop (one thread) closes the stretch since its last
+        lap — or since the last wave that attempted pods ended — under
+        `name`. Laps are contiguous, so the phases on a wave's `loop` sum
+        to the gap between the previous wave's end and its own start."""
+        loop = self._loop
+        if loop is None:
+            return
+        now = self.clock()
+        loop.child(name, now - self._loop_lap_t)
+        self._loop_lap_t = now
+
+    def note_handler(self, wait_s: float, held_s: float) -> None:
+        """One informer handler call on the server's lock (any thread)."""
+        with self._mu:
+            h = self._handlers
+            h[0] += 1
+            h[1] += wait_s
+            h[2] += held_s
+
     def note_device_split(self, launch: float, execute: float,
                           readback: float, token: object = None) -> None:
         """Tier 3 readings from the dispatch worker: XLA launch vs device
@@ -412,7 +482,22 @@ class SchedulerTelemetry:
         phases = span.phases()
         for phase, dt in phases:
             SCHEDULING_DURATION.observe(dt, operation=phase)
+        t_end = self.clock()
+        loop_rec = None
         with self._mu:
+            if self._loop is not None and stats is not None \
+                    and stats.attempted:
+                # what the server loop did since the last wave like this
+                # one; its account starts over where this wave ends
+                calls, wait_s, held_s = self._handlers
+                loop_rec = {
+                    "t_start": round(self._loop.start, 6),
+                    "phases": [[n, round(v[1], 6)] for n, v
+                               in self._loop.children().items()],
+                    "handlers": {"calls": calls,
+                                 "wait_s": round(wait_s, 6),
+                                 "held_s": round(held_s, 6)}}
+                self._new_loop(t_end)
             events, self._pending_events = self._pending_events, []
             # this wave's own reading (or an untokened caller's); entries
             # keyed to OTHER spans are abandoned waves' zombie reports —
@@ -422,7 +507,7 @@ class SchedulerTelemetry:
         rec: Dict[str, Any] = {
             "recorder": self.name,
             "t_start": round(span.trace.start, 6),
-            "duration_s": round(span.trace.duration(), 6),
+            "duration_s": round(t_end - span.trace.start, 6),
             "phases": [(p, round(dt, 6)) for p, dt in phases],
             "engine": engine,
             "rc": rc,
@@ -450,6 +535,12 @@ class SchedulerTelemetry:
             rec["supervisor_events"] = events
         if split is not None:
             rec["device_split"] = split
+        children = span.trace.children()
+        if children:
+            rec["children"] = {p: [c, round(t, 6), round(m, 6)]
+                               for p, (c, t, m) in children.items()}
+        if loop_rec is not None:
+            rec["loop"] = loop_rec
         if fleet is not None:
             rec["fleet"] = fleet
         if extra:

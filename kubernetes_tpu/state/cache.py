@@ -34,6 +34,7 @@ from __future__ import annotations
 import functools
 import os
 import threading
+import time
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
@@ -42,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..api.types import Node, Pod
+from ..component import trace
 from .arrays import ClusterTables, NodeArrays, PodArrays
 from .dims import Dims
 from .encode import Encoder
@@ -136,6 +138,8 @@ class _PodState:
     assumed: bool = False
     binding_finished: bool = False
     deadline: Optional[float] = None  # set by finish_binding; None = no expiry
+    bound_at: Optional[float] = None  # finish_binding's instant on the
+                                      # cache's lag clock (confirm_waits)
 
 
 class CacheError(RuntimeError):
@@ -187,6 +191,16 @@ class SchedulerCache:
         self._ttl = ttl
         self._nodes: Dict[str, Node] = {}
         self._pods: Dict[str, _PodState] = {}
+        # what a Binding waits for after it is written: the informer's
+        # confirmation. Both ends are read HERE on one clock of the
+        # cache's own (time.perf_counter, the flight recorder's), since
+        # add_pod is handed no `now` and finish_binding's `now` may be a
+        # per-tick virtual clock. [count, sum_s, max_s] since the last
+        # drain, and the assumed pods not yet confirmed — a counter kept
+        # wherever an assumed state enters or leaves, not a walk.
+        self.lag_clock = time.perf_counter
+        self._confirm_waits: List[float] = [0, 0.0, 0.0]
+        self._assumed_outstanding = 0
         self._generation = 0
         self._snapshot: Optional[Snapshot] = None
         # ---- incremental snapshot state (cache.go:204-255 analog) ----
@@ -297,6 +311,7 @@ class SchedulerCache:
                 raise CacheError(f"pod {key} is already in the cache")
             p = replace(pod, node_name=node_name)
             self._pods[key] = _PodState(pod=p, assumed=True)
+            self._assumed_outstanding += 1
             self._pod_placed(p)
             self._generation += 1
 
@@ -310,6 +325,7 @@ class SchedulerCache:
                 return  # finished binding for a pod no longer assumed: no-op
             st.binding_finished = True
             st.deadline = now + self._ttl
+            st.bound_at = self.lag_clock()
 
     def forget_pod(self, key: str) -> None:
         """ForgetPod (cache.go:328): bind/permit/volume failure rollback."""
@@ -320,6 +336,7 @@ class SchedulerCache:
             if not st.assumed:
                 raise CacheError(f"pod {key} is bound, cannot forget")
             del self._pods[key]
+            self._assumed_outstanding -= 1
             self._pod_unplaced(st.pod)
             self._generation += 1
 
@@ -334,6 +351,14 @@ class SchedulerCache:
                 # (cache.go:404-410 logs and corrects)
                 self._pod_unplaced(st.pod)
                 self._pods[key] = _PodState(pod=pod)
+                self._assumed_outstanding -= 1
+                if st.bound_at is not None:
+                    lag = self.lag_clock() - st.bound_at
+                    w = self._confirm_waits
+                    w[0] += 1
+                    w[1] += lag
+                    if lag > w[2]:
+                        w[2] = lag
             elif st is None:
                 self._pods[key] = _PodState(pod=pod)
             else:
@@ -360,6 +385,8 @@ class SchedulerCache:
             if st is None:
                 raise CacheError(f"pod {key} is not in the cache")
             del self._pods[key]
+            if st.assumed:
+                self._assumed_outstanding -= 1
             self._pod_unplaced(st.pod)
             self._generation += 1
 
@@ -384,8 +411,20 @@ class SchedulerCache:
                     self._pod_unplaced(st.pod)
                     dropped.append(st.pod)
             if dropped:
+                self._assumed_outstanding -= len(dropped)
                 self._generation += 1
         return dropped
+
+    def drain_confirm_waits(self) -> Tuple[List[float], int]:
+        """`([count, sum_s, max_s], assumed_outstanding)`: the lag from
+        `finish_binding` to the informer's confirming `add_pod` over the
+        confirmations since the last call (and resets it), and the assumed
+        pods still unconfirmed now. The wave reads it at pop."""
+        with self._mu:
+            (n, total, worst), self._confirm_waits = \
+                self._confirm_waits, [0, 0.0, 0.0]
+            return ([n, round(total, 6), round(worst, 6)],
+                    self._assumed_outstanding)
 
     def pods_on_node(self, name: str) -> List[Pod]:
         """All pods (bound + assumed) occupying one node — the host-side
@@ -459,6 +498,7 @@ class SchedulerCache:
                     self._pod_unplaced(st.pod)
                     expired.append(key)
             if expired:
+                self._assumed_outstanding -= len(expired)
                 self._generation += 1
         return expired
 
@@ -519,6 +559,11 @@ class SchedulerCache:
         namespace/name (queue.update), and scheduling it against the cached
         encoding of the old spec would pin it unschedulable forever."""
         pending_keys = tuple((p.key, id(p)) for p in pending)
+        # on a traced wave the snapshot's parts are children of the phase
+        # that asked for it: `prepare` (interning, slots, capacities),
+        # then `full` or `patch`, and below those `upload` (_put)
+        tr = trace.current()
+        t0 = time.perf_counter()
         with self._mu:
             gen = self._generation
             snap = self._snapshot
@@ -680,12 +725,21 @@ class SchedulerCache:
                 or replace(d, has_node_name=False)
                 != replace(snap.dims, has_node_name=False)
             )
-            if full:
-                return self._full_snapshot(encoder, pending, pending_keys,
-                                           gen, d, base_dims, device, mesh)
-            return self._patch_snapshot(encoder, pending, pending_keys,
-                                        gen, d, snap, released_nodes,
-                                        device, mesh)
+            if tr is not None:
+                t1 = time.perf_counter()
+                tr.child("prepare", t1 - t0)
+                tok = tr.begin("full" if full else "patch")
+            try:
+                if full:
+                    return self._full_snapshot(
+                        encoder, pending, pending_keys, gen, d, base_dims,
+                        device, mesh)
+                return self._patch_snapshot(
+                    encoder, pending, pending_keys, gen, d, snap,
+                    released_nodes, device, mesh)
+            finally:
+                if tr is not None:
+                    tr.end(tok, time.perf_counter() - t1)
 
     def micro_graft(self, encoder: Encoder, pending: Sequence[Pod],
                     base: Snapshot, micro_p: int,
@@ -875,9 +929,15 @@ class SchedulerCache:
     def _put(self, tree, device, mesh):
         """Route host arrays to their serving placement: replicated across
         the mesh when one is active, else onto `device` (None = default)."""
+        tr = trace.current()
+        t0 = time.perf_counter() if tr is not None else 0.0
         if mesh is not None:
-            return jax.device_put(tree, self._replicated(mesh))
-        return jax.device_put(tree, device)
+            out = jax.device_put(tree, self._replicated(mesh))
+        else:
+            out = jax.device_put(tree, device)
+        if tr is not None:
+            tr.child("upload", time.perf_counter() - t0)
+        return out
 
     def _full_snapshot(self, encoder, pending, pending_keys, gen, d,
                        base_dims: Optional[Dims] = None,
@@ -1296,5 +1356,6 @@ class FakeCache(SchedulerCache):
                 st = self._pods.pop(k)
                 self._pod_unplaced(st.pod)
             if expired:
+                self._assumed_outstanding -= len(expired)
                 self._generation += 1
         return expired

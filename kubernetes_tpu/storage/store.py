@@ -34,6 +34,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from kubernetes_tpu.component import trace
 from kubernetes_tpu.component.metrics import DEFAULT_REGISTRY as _REG
 from kubernetes_tpu.machinery import errors, meta
 from kubernetes_tpu.machinery import watch as mwatch
@@ -62,6 +63,31 @@ WATCH_BOOKMARKS_SENT = _REG.counter(
     "BOOKMARK events sent to opted-in watchers, by trigger "
     "(timer, compaction)",
     labels=("trigger",))
+# one write through the store (the etcd3 store's
+# `etcd_request_duration_seconds` seat): read, transform, encode, CAS put,
+# retries included. The watch fan-out runs on the dispatch thread, outside.
+TXN_DURATION = _REG.histogram(
+    "storage_txn_duration_seconds",
+    "One write transaction through the store, by operation",
+    labels=("op",),
+    buckets=(0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+             0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5))
+_OP_CREATE, _OP_UPDATE, _OP_DELETE = ("create",), ("update",), ("delete",)
+
+
+def _txn_done(op: Tuple[str], t0: float, kv_s: float = 0.0) -> None:
+    """Close one transaction: the histogram, and — when the caller's
+    thread runs a traced operation (a scheduling wave) — a `store.txn`
+    child of the span that caused it, with the seconds of it spent inside
+    the KV backend's calls as `store.txn/kv` (the rest is this module's
+    Python: decode, copies, the caller's transform, encode)."""
+    dt = time.perf_counter() - t0
+    TXN_DURATION.observe_at(op, dt)
+    tr = trace.current()
+    if tr is not None:
+        tr.child("store.txn", dt)
+        if kv_s:
+            tr.child("store.txn/kv", kv_s)
 
 
 def _parse_watch_buffer(value, default: int = 8192) -> int:
@@ -215,12 +241,20 @@ class Storage:
     # ------------------------------------------------------------------ #
 
     def create(self, key: str, obj: Obj, resource: str = "object") -> Obj:
-        rev = self.kv.txn_put(key, 0, _encode(obj))
-        if rev < 0:
-            raise errors.new_already_exists(resource, meta.name(obj))
-        out = meta.deep_copy(obj)
-        meta.set_resource_version(out, str(rev))
-        return out
+        t0 = time.perf_counter()
+        kv_s = 0.0
+        try:
+            data = _encode(obj)
+            t1 = time.perf_counter()
+            rev = self.kv.txn_put(key, 0, data)
+            kv_s = time.perf_counter() - t1
+            if rev < 0:
+                raise errors.new_already_exists(resource, meta.name(obj))
+            out = meta.deep_copy(obj)
+            meta.set_resource_version(out, str(rev))
+            return out
+        finally:
+            _txn_done(_OP_CREATE, t0, kv_s)
 
     def get(self, key: str, resource: str = "object", name: str = "") -> Obj:
         rec = self.kv.get(key)
@@ -242,6 +276,14 @@ class Storage:
 
     def delete(self, key: str, resource: str = "object", name: str = "",
                expected_rv: Optional[str] = None) -> Obj:
+        t0 = time.perf_counter()
+        try:
+            return self._delete(key, resource, name, expected_rv)
+        finally:
+            _txn_done(_OP_DELETE, t0)
+
+    def _delete(self, key: str, resource: str, name: str,
+                expected_rv: Optional[str]) -> Obj:
         while True:
             rec = self.kv.get(key)
             if rec is None:
@@ -265,10 +307,24 @@ class Storage:
         update_fn receives a deep copy (with resourceVersion set) and returns
         the new object, or raises to abort.
         """
+        t0 = time.perf_counter()
+        kv_s = [0.0]
+        try:
+            return self._guaranteed_update(key, update_fn, resource, name,
+                                           ignore_not_found, expected_rv,
+                                           kv_s)
+        finally:
+            _txn_done(_OP_UPDATE, t0, kv_s[0])
+
+    def _guaranteed_update(self, key: str, update_fn: Callable[[Obj], Obj],
+                           resource: str, name: str, ignore_not_found: bool,
+                           expected_rv: Optional[str], kv_s: List[float]) -> Obj:
         chaos_cas = False  # at most one injected conflict per call: the
         # retry loop must converge even under FAULT_SPEC=store.cas_conflict@1.0
         while True:
+            t1 = time.perf_counter()
             rec = self.kv.get(key)
+            kv_s[0] += time.perf_counter() - t1
             if rec is None:
                 if not ignore_not_found:
                     raise errors.new_not_found(resource, name or key)
@@ -309,7 +365,10 @@ class Storage:
                 # CAS race — skip the put and take the re-read/retry path
                 chaos_cas = True
                 continue
-            rev = self.kv.txn_put(key, cur_mod if cur_mod else 0, _encode(updated))
+            data = _encode(updated)
+            t1 = time.perf_counter()
+            rev = self.kv.txn_put(key, cur_mod if cur_mod else 0, data)
+            kv_s[0] += time.perf_counter() - t1
             if rev > 0:
                 out = meta.deep_copy(updated)
                 meta.set_resource_version(out, str(rev))

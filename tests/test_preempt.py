@@ -42,15 +42,23 @@ def mksched(clock=None):
 
 
 def test_preempts_lower_priority_and_schedules_after_eviction():
+    from kubernetes_tpu.sched.metrics import (PREEMPTION_ATTEMPTS,
+                                              PREEMPTION_VICTIMS)
+
     s, clock = mksched()
     s.on_node_add(mknode("n0", cpu=1))
     s.on_pod_add(bound("victim", "n0", cpu="800m", priority=0))
     s.on_pod_add(Pod(name="vip", priority=100,
                      requests=Resources.make(cpu="800m", memory="256Mi")))
+    attempts, victims = PREEMPTION_ATTEMPTS.value(), PREEMPTION_VICTIMS.value()
     st = s.schedule_pending()
     assert st.scheduled == 0
     # preemption ran: victim evicted, vip nominated on n0, requeued
     assert s.preemptor.evictor.evicted == ["default/victim"]
+    # and the two series the catalogue lists are fed (declared, never
+    # incremented, until ISSUE 24)
+    assert PREEMPTION_ATTEMPTS.value() == attempts + 1
+    assert PREEMPTION_VICTIMS.value() == victims + 1
     assert s.queue.nominated_node("default/vip") == "n0"
     assert s.cache.get_pod("default/victim") is None
     clock.t = 5.0

@@ -560,3 +560,445 @@ class TestQuantilesAndSplit:
             h.observe(v)
         assert h.quantile(0.5) == 0.005   # bucket upper bound
         assert h.quantile(0.99) == 1.0
+
+
+# --------------------------------------------------------------------- #
+# ISSUE 24: spans where the chip sits idle — children of a phase, the
+# server loop between waves, what a pod waited for
+# --------------------------------------------------------------------- #
+
+from kubernetes_tpu.component import trace as ktrace  # noqa: E402
+
+
+class _SpyBinder(RecordingBinder):
+    """Records what `trace.current()` is where a callee would ask: inside
+    the Binding, on the wave's thread and on a thread started there."""
+
+    def __init__(self):
+        super().__init__()
+        self.seen = []
+
+    def bind(self, pod, node_name):
+        here = ktrace.current()
+        other = []
+        t = threading.Thread(target=lambda: other.append(ktrace.current()))
+        t.start()
+        t.join()
+        self.seen.append((here, other[0]))
+        return super().bind(pod, node_name)
+
+
+class TestChildSpans:
+    def test_paths_nest_and_self_time_is_total_less_children(self):
+        tr = Trace("wave", clock=lambda: 0.0)
+        for _ in range(3):
+            tok = tr.begin("bind-call")
+            inner = tr.begin("apiserver.bind")
+            tr.child("store.txn", 0.2)
+            tr.end(inner, 0.5)
+            tr.end(tok, 0.75)
+            tr.child("finish", 0.25)
+        tr.step("bind-commit")      # the step that closes the phase names it
+        tr.child("orphan", 1.0)     # no step yet: listed without a phase
+        ch = tr.children()
+        assert list(ch) == [
+            "bind-commit/bind-call",
+            "bind-commit/bind-call/apiserver.bind",
+            "bind-commit/bind-call/apiserver.bind/store.txn",
+            "bind-commit/finish", "orphan"]
+        assert ch["bind-commit/bind-call"] == [3, 2.25, 0.75]
+        assert ch["bind-commit/bind-call/apiserver.bind/store.txn"] == \
+            pytest.approx([3, 0.6, 0.2])
+        # a layer's self time: its total less its direct children's
+        api = ch["bind-commit/bind-call/apiserver.bind"][1]
+        assert api - ch["bind-commit/bind-call/apiserver.bind/store.txn"][1] \
+            == pytest.approx(0.9)
+
+    def test_slash_path_files_below_a_parent_without_calling_it(self):
+        tr = Trace("op", clock=lambda: 0.0)
+        tr.child("store.txn", 1.0)
+        tr.child("store.txn/kv", 0.25)
+        assert tr.children() == {"store.txn": [1, 1.0, 1.0],
+                                 "store.txn/kv": [1, 0.25, 0.25]}
+
+    def test_same_phase_twice_merges(self):
+        tr = Trace("op", clock=lambda: 0.0)
+        tr.child("x", 1.0)
+        tr.step("phase")
+        tr.child("x", 3.0)
+        tr.step("phase")
+        assert tr.children() == {"phase/x": [2, 4.0, 3.0]}
+
+    def test_ten_thousand_commits_leave_a_fixed_small_record(self):
+        clk = {"t": 0.0}
+        s = _scheduler(clk)
+        nodes = [n.name for n in s.cache.nodes()]
+        span = s.telemetry.wave_span()
+        token = ktrace.activate(span.trace)
+        try:
+            from kubernetes_tpu.sched.scheduler import CycleStats
+            stats = CycleStats(attempted=10_000)
+            for i in range(10_000):
+                s._commit(_pod(i), nodes[i % len(nodes)], 1, 0.0, 1, stats)
+            span.mark("bind-commit")
+        finally:
+            ktrace.deactivate(token)
+        rec = s.telemetry.finish_wave(span, stats=stats)
+        assert stats.scheduled == 10_000
+        assert sorted(rec["children"]) == [
+            "bind-commit/assume", "bind-commit/bind-call",
+            "bind-commit/finish"]
+        assert all(v[0] == 10_000 for v in rec["children"].values())
+        # the three parts and the phase's own remainder make up the phase
+        phase = dict(rec["phases"])["bind-commit"]
+        parts = sum(v[1] for v in rec["children"].values())
+        assert 0 <= phase - parts < phase
+
+    def test_current_is_the_waves_trace_only_on_its_thread(self):
+        clk = {"t": 0.0}
+        s = _scheduler(clk)
+        s.binder = spy = _SpyBinder()
+        assert ktrace.current() is None
+        s.on_pod_add(_pod(0))
+        assert s.schedule_pending().scheduled == 1
+        (here, elsewhere), = spy.seen
+        assert isinstance(here, Trace) and elsewhere is None
+        assert ktrace.current() is None          # and gone after the wave
+        rec = s.telemetry.recorder.records()[-1]
+        assert rec["children"]["bind-commit/bind-call"][0] == 1
+
+    def test_current_is_cleared_when_the_wave_raises(self):
+        clk = {"t": 0.0}
+        s = _scheduler(clk)
+        s.on_pod_add(_pod(0))
+        s._retire_intent = lambda intent: 1 / 0
+        with pytest.raises(ZeroDivisionError):
+            s.schedule_pending()
+        assert ktrace.current() is None
+        rec = s.telemetry.recorder.records()[-1]
+        assert rec["exception"] and "waits" in rec   # the pop's readings kept
+
+    def test_kill_switch_no_trace_no_field(self, monkeypatch):
+        monkeypatch.setenv("KTPU_TELEMETRY", "0")
+        clk = {"t": 0.0}
+        s = _scheduler(clk)
+        assert not s.telemetry.enabled
+        s.binder = spy = _SpyBinder()
+        s.telemetry.loop_reset()
+        s.telemetry.loop_lap("idle-wait")
+        s.on_pod_add(_pod(0))
+        assert s.schedule_pending().scheduled == 1
+        assert spy.seen == [(None, None)]    # a callee pays one None check
+        assert s.telemetry.recorder.records() == []
+        assert s.telemetry._loop is None
+
+    def test_no_server_loop_no_loop_field(self):
+        clk = {"t": 0.0}
+        s = _scheduler(clk)
+        s.on_pod_add(_pod(0))
+        s.schedule_pending()
+        rec = s.telemetry.recorder.records()[-1]
+        assert "loop" not in rec
+        assert set(rec) >= {"children", "waits", "assumed_outstanding"}
+
+    def test_record_fields_the_benchmark_reads_are_what_they_were(self):
+        clk = {"t": 0.0}
+        s = _scheduler(clk)
+        tick = {"n": 0}
+
+        def tel_clock():
+            tick["n"] += 1
+            return tick["n"] * 0.001
+
+        s.telemetry.clock = tel_clock
+        for i in range(4):
+            s.on_pod_add(_pod(i))
+        s.schedule_pending()
+        rec = s.telemetry.recorder.records()[-1]
+        # one clock read opens the span, one closes each phase, one ends
+        # the wave (and log_if_long reads one after the record is made):
+        # nothing this issue added reads the telemetry's clock in a wave
+        assert rec["t_start"] == 0.001
+        assert [d for _, d in rec["phases"]] == [0.001] * 10
+        assert rec["duration_s"] == 0.011
+        assert tick["n"] == 13
+        assert set(rec["device_split"]) == {"launch_s", "execute_s",
+                                            "readback_s"}
+        assert rec["snapshot_mode"] == "full"
+        assert rec["stats"]["scheduled"] == 4
+
+
+class TestWaits:
+    def test_queue_wait_is_read_at_pop_and_keeps_the_stamp(self):
+        from kubernetes_tpu.sched.metrics import POD_E2E_LATENCY
+
+        clk = {"t": 0.0}
+        s = _scheduler(clk)
+        s.on_pod_add(_pod(0))
+        clk["t"] = 1.0
+        s.on_pod_add(_pod(1))
+        s.on_pod_add(_pod(2))
+        clk["t"] = 3.0
+        before = POD_E2E_LATENCY.count()
+        s.schedule_pending()
+        rec = s.telemetry.recorder.records()[-1]
+        assert rec["waits"]["queue"] == [3, 7.0, 3.0]
+        # the commit still closed each pod's watch-to-bind span
+        assert POD_E2E_LATENCY.count() == before + 3
+        assert len(s.telemetry.tracker) == 0
+
+    def test_tracker_waits_skips_unstamped_keys(self):
+        tr = PodLatencyTracker()
+        tr.stamp("a", 1.0)
+        tr.stamp("b", 2.5)
+        assert tr.waits(["a", "b", "never"], 4.0) == [2, 4.5, 3.0]
+        assert tr.first_seen("a") == 1.0 and len(tr) == 2
+
+    def test_confirm_lag_and_assumed_outstanding(self):
+        from kubernetes_tpu.state.cache import SchedulerCache
+
+        t = {"now": 100.0}
+        cache = SchedulerCache(ttl=30.0)
+        cache.lag_clock = lambda: t["now"]
+        for n in make_nodes(2):
+            cache.add_node(n)
+        names = [n.name for n in cache.nodes()]
+        pods = [_pod(i) for i in range(5)]
+        for p in pods:
+            cache.assume_pod(p, names[0])
+        assert cache.drain_confirm_waits() == ([0, 0.0, 0.0], 5)
+        for p in pods[:3]:
+            cache.finish_binding(p.key, now=0.0)
+        t["now"] = 100.5
+        cache.add_pod(_pod(0, node_name=names[0]))      # confirmed
+        t["now"] = 102.0
+        cache.add_pod(_pod(1, node_name=names[1]))      # onto another node
+        cache.forget_pod(pods[3].key)                   # bind failed
+        cache.remove_pod(pods[4].key)                   # deleted while assumed
+        assert cache.drain_confirm_waits() == ([2, 2.5, 2.0], 1)
+        assert cache.cleanup(now=1000.0) == [pods[2].key]   # TTL expiry
+        assert cache.drain_confirm_waits() == ([0, 0.0, 0.0], 0)
+        assert cache.counts()[2] == 0
+        cache.assume_pod(_pod(7), names[0])
+        assert len(cache.forget_assumed()) == 1
+        assert cache.drain_confirm_waits()[1] == 0
+
+    def test_wave_record_carries_confirmations_since_the_last_wave(self):
+        clk = {"t": 0.0}
+        s = _scheduler(clk)
+        lag = {"now": 10.0}
+        s.cache.lag_clock = lambda: lag["now"]
+        s.on_pod_add(_pod(0))
+        s.on_pod_add(_pod(1))
+        s.schedule_pending()
+        first = s.telemetry.recorder.records()[-1]
+        assert first["assumed_outstanding"] == 0
+        assert first["waits"]["confirm"] == [0, 0.0, 0.0]
+        lag["now"] = 10.25
+        node = dict(s.binder.bound)["default/p0"]
+        s.on_pod_add(_pod(0, node_name=node))           # the informer's echo
+        s.on_pod_add(_pod(2))
+        s.schedule_pending()
+        rec = s.telemetry.recorder.records()[-1]
+        assert rec["waits"]["confirm"] == [1, 0.25, 0.25]
+        assert rec["assumed_outstanding"] == 1          # p1 still unconfirmed
+
+
+class _ScriptedStop:
+    """Stands in for SchedulerServer._stop: `wait(t)` advances the injected
+    clock by t (nothing sleeps) and runs the next scripted action; the loop
+    ends when the script does."""
+
+    def __init__(self, clk, script):
+        self.clk, self.script = clk, list(script)
+
+    def is_set(self):
+        return not self.script
+
+    def wait(self, timeout=None):
+        self.clk["t"] += timeout or 0.0
+        if self.script:
+            self.script.pop(0)()
+
+    def set(self):
+        self.script = []
+
+
+def _loop_server(clk, batch_window=0.15):
+    from kubernetes_tpu.apiserver import APIServer
+    from kubernetes_tpu.client import Client
+    from kubernetes_tpu.sched.server import SchedulerServer
+
+    s = _scheduler(clk)
+    s.telemetry.clock = lambda: clk["t"]
+
+    class _SlowBinder(RecordingBinder):
+        def bind(self, pod, node_name):
+            clk["t"] += 0.002          # a Binding takes time on this clock
+            return super().bind(pod, node_name)
+
+    s.binder = _SlowBinder()
+    srv = SchedulerServer(Client.local(APIServer()), scheduler=s,
+                          cycle_interval=0.02, batch_window=batch_window)
+    return srv, s
+
+
+class TestServerLoopSpans:
+    def test_loop_phases_sum_to_the_gap_between_waves(self):
+        clk = {"t": 50.0}
+        srv, s = _loop_server(clk)
+
+        def add(lo, hi):
+            return lambda: [s.on_pod_add(_pod(i)) for i in range(lo, hi)]
+
+        nothing = lambda: None
+        # batch-wait of wave 1, three empty polls, pods for wave 2 arrive
+        # on the fourth, its (short: < 32 pending) batch-wait, two polls
+        srv._stop = _ScriptedStop(clk, [nothing, nothing, nothing, nothing,
+                                        add(40, 45), nothing, nothing,
+                                        nothing])
+        for i in range(40):
+            s.on_pod_add(_pod(i))
+        s.telemetry.loop_reset()
+        clk["t"] += 1.5                 # list + sync, before the loop runs
+        srv._loop()
+        w1, w2 = [r for r in s.telemetry.recorder.records()
+                  if r["stats"]["attempted"]]
+        assert [w1["stats"]["scheduled"], w2["stats"]["scheduled"]] == [40, 5]
+        # wave 1: everything since the server started
+        assert w1["loop"]["t_start"] == 50.0
+        assert dict(map(tuple, w1["loop"]["phases"])) == pytest.approx(
+            {"start": 1.5, "lock-wait": 0.0, "batch-wait": 0.15})
+        assert sum(d for _, d in w1["loop"]["phases"]) == pytest.approx(
+            w1["t_start"] - 50.0)
+        # wave k+1: the gap since wave k ended, every second of it named
+        gap = w2["t_start"] - (w1["t_start"] + w1["duration_s"])
+        assert w2["loop"]["t_start"] == pytest.approx(
+            w1["t_start"] + w1["duration_s"])
+        phases = dict(map(tuple, w2["loop"]["phases"]))
+        assert sum(phases.values()) == pytest.approx(gap, abs=1e-9)
+        assert phases == pytest.approx(
+            {"post-wave": 0.0, "lock-wait": 0.0, "idle-wait": 0.08,
+             "batch-wait": 0.05})
+        # the wave's own span is untouched by the loop's account
+        assert w1["duration_s"] == pytest.approx(40 * 0.002)
+
+    def test_handlers_are_counted_onto_the_next_wave(self):
+        from kubernetes_tpu.models.workloads import make_nodes as mk
+
+        clk = {"t": 0.0}
+        srv, s = _loop_server(clk)
+        s.telemetry.loop_reset()
+        real = s.on_node_update
+
+        def slow_update(node):
+            clk["t"] += 0.004
+            real(node)
+
+        s.on_node_update = slow_update
+        node = mk(1)[0]
+        obj = {"metadata": {"name": node.name, "labels": dict(node.labels)},
+               "status": {"allocatable": {"cpu": "4", "memory": "8Gi",
+                                          "pods": "110"}}}
+        for _ in range(3):
+            srv._on_node_update(obj, obj)
+        s.on_pod_add(_pod(0))
+        srv.run_one_wave()
+        rec = s.telemetry.recorder.records()[-1]
+        assert rec["loop"]["handlers"] == {
+            "calls": 3, "wait_s": 0.0, "held_s": pytest.approx(0.012)}
+        s.on_pod_add(_pod(1))
+        srv.run_one_wave()
+        again = s.telemetry.recorder.records()[-1]
+        assert again["loop"]["handlers"]["calls"] == 0   # drained, not kept
+
+    def test_idle_record_leaves_the_loop_account_to_the_next_wave(self):
+        clk = {"t": 0.0}
+        srv, s = _loop_server(clk)
+        s.telemetry.loop_reset()
+        s.telemetry.loop_lap("start")
+        s.telemetry.note_supervisor_event("rewarm", "x")
+        srv.run_one_wave()                       # empty wave, event drained
+        idle = s.telemetry.recorder.records()[-1]
+        assert idle["engine"] == "idle" and "loop" not in idle
+        clk["t"] += 0.5
+        s.on_pod_add(_pod(0))
+        srv.run_one_wave()
+        rec = s.telemetry.recorder.records()[-1]
+        assert sum(d for _, d in rec["loop"]["phases"]) == \
+            pytest.approx(rec["t_start"])
+
+
+class TestRequestAndTxnMetrics:
+    def test_observe_at_is_observe_with_the_key_in_hand(self):
+        h = Histogram("at_test", "", label_names=("verb", "resource"))
+        h.observe(0.003, verb="create", resource="pods")
+        h.observe_at(("create", "pods"), 0.004)
+        assert h.count(verb="create", resource="pods") == 2
+        assert h.sum_value(verb="create", resource="pods") == \
+            pytest.approx(0.007)
+        assert h.quantile(0.99, verb="create", resource="pods") == 0.005
+
+    def test_create_and_bind_feed_the_three_families_and_the_trace(self):
+        from kubernetes_tpu.apiserver import APIServer
+        from kubernetes_tpu.apiserver.server import REQUEST_DURATION
+        from kubernetes_tpu.client import Client
+        from kubernetes_tpu.sched.metrics import BINDING_DURATION
+        from kubernetes_tpu.storage.store import TXN_DURATION
+
+        client = Client.local(APIServer())
+        client.nodes.create({"apiVersion": "v1", "kind": "Node",
+                             "metadata": {"name": "n0"}})
+        n_create = REQUEST_DURATION.count(verb="create", resource="pods",
+                                          subresource="")
+        n_bind = REQUEST_DURATION.count(verb="create", resource="pods",
+                                        subresource="binding")
+        n_txn_c = TXN_DURATION.count(op="create")
+        n_txn_u = TXN_DURATION.count(op="update")
+        tr = Trace("op", clock=lambda: 0.0)
+        token = ktrace.activate(tr)
+        try:
+            for i in range(3):
+                client.pods.create({
+                    "apiVersion": "v1", "kind": "Pod",
+                    "metadata": {"name": f"p{i}", "namespace": "default"},
+                    "spec": {"containers": [{"name": "c", "image": "x"}]}})
+            tr.step("create")
+            for i in range(3):
+                client.pods.bind(f"p{i}", "n0", "default")
+            tr.step("bind")
+        finally:
+            ktrace.deactivate(token)
+        assert REQUEST_DURATION.count(verb="create", resource="pods",
+                                      subresource="") == n_create + 3
+        assert REQUEST_DURATION.count(verb="create", resource="pods",
+                                      subresource="binding") == n_bind + 3
+        assert TXN_DURATION.count(op="create") == n_txn_c + 3
+        assert TXN_DURATION.count(op="update") == n_txn_u + 3
+        ch = tr.children()
+        assert sorted(ch) == [
+            "bind/apiserver.bind", "bind/apiserver.bind/store.txn",
+            "bind/apiserver.bind/store.txn/kv", "create/apiserver.create",
+            "create/apiserver.create/store.txn",
+            "create/apiserver.create/store.txn/kv"]
+        for outer in ("bind/apiserver.bind", "create/apiserver.create"):
+            assert ch[outer][0] == 3
+            assert ch[outer + "/store.txn/kv"][1] \
+                <= ch[outer + "/store.txn"][1] <= ch[outer][1]
+        # a refused request is one observation too, and closes its span
+        with pytest.raises(Exception):
+            client.pods.create({"apiVersion": "v1", "kind": "Pod",
+                                "metadata": {"name": "p0",
+                                             "namespace": "default"},
+                                "spec": {"containers": [
+                                    {"name": "c", "image": "x"}]}})
+        assert REQUEST_DURATION.count(verb="create", resource="pods",
+                                      subresource="") == n_create + 4
+        # scheduler_binding_duration_seconds: one sample per Binding written
+        clk = {"t": 0.0}
+        s = _scheduler(clk)
+        before = BINDING_DURATION.count()
+        for i in range(6):
+            s.on_pod_add(_pod(i))
+        assert s.schedule_pending().scheduled == 6
+        assert BINDING_DURATION.count() == before + 6
