@@ -3,7 +3,7 @@
 TPU-native analog of SURVEY.md layer 5 (`staging/src/k8s.io/client-go`).
 """
 
-from kubernetes_tpu.client.events import EventRecorder
+from kubernetes_tpu.client.events import EventBroadcaster, EventRecorder
 from kubernetes_tpu.client.informers import (
     Indexer,
     InformerFactory,
@@ -34,9 +34,9 @@ from kubernetes_tpu.client.workqueue import (
 )
 
 __all__ = [
-    "Client", "DelayingQueue", "EventRecorder", "HTTPTransport", "Indexer",
-    "InformerFactory", "LeaderElectionConfig", "LeaderElector", "Lister",
-    "LocalTransport", "MuxRoute", "RateLimiter", "RateLimitingQueue",
-    "ResourceClient", "SharedInformer", "TENANT_LABEL", "WatchMux",
-    "WorkQueue", "pods_by_node_index",
+    "Client", "DelayingQueue", "EventBroadcaster", "EventRecorder",
+    "HTTPTransport", "Indexer", "InformerFactory", "LeaderElectionConfig",
+    "LeaderElector", "Lister", "LocalTransport", "MuxRoute", "RateLimiter",
+    "RateLimitingQueue", "ResourceClient", "SharedInformer", "TENANT_LABEL",
+    "WatchMux", "WorkQueue", "pods_by_node_index",
 ]

@@ -141,11 +141,15 @@ SCORE_SHARE = REG.counter(
     labels=("component",))
 FAILED_EVENTS = REG.counter(
     "scheduler_failed_scheduling_events_total",
-    "FailedScheduling event dispositions from the decision-provenance "
+    "FailedScheduling event dispositions. The decision-provenance "
     "pipeline: emitted (written through the apiserver), deduped (suppressed "
     "by the per-(pod, fingerprint) exponential backoff), capped (deferred "
     "by the per-wave write budget; re-qualifies next occurrence), error "
-    "(write failed past the retry budget), unsinked (no sink attached)",
+    "(write failed past the retry budget), unsinked (no sink attached). "
+    "The server loop's generic Events (client/events.py EventBroadcaster): "
+    "queued, coalesced (added to the count of one still queued), dropped "
+    "(refused at the queue's bound, or unwritten when stop() ran out of "
+    "time), then emitted / error once the sink thread has written",
     labels=("outcome",))
 # ISSUE 13 fleet watch plane (fleet/server.py FleetWatchPlane): how far
 # behind live watch truth each tenant's serving state is. ~0 on a healthy

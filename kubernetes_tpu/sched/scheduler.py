@@ -240,6 +240,10 @@ class Scheduler:
 
         self.explainer = build_explainer(name=scheduler_name,
                                          clock=self.clock)
+        # Events queued and not yet written (the server's broadcaster's
+        # `pending`): read at every pop onto the wave's record. None = a
+        # scheduler no server drives, which queues none.
+        self.events_pending: Optional[Callable[[], int]] = None
         # streaming micro-waves (ISSUE 18): when the live backlog is
         # nothing but a handful of FRESH watch deltas, admit them through
         # a small fixed-capacity wave grafted onto the resident snapshot
@@ -558,6 +562,8 @@ class Scheduler:
                     [p.key for p, _ in batch], self.clock()),
                 "confirm": confirm}
             wave_extra["assumed_outstanding"] = outstanding
+            if self.events_pending is not None:
+                wave_extra["events_pending"] = self.events_pending()
         span.mark("pop")
         # ---- priority-aware shedding (SHED_LOW/TRICKLE): park sheddable
         # pods in the deferred lane — deferred, never dropped, no failure

@@ -23,13 +23,14 @@ from kubernetes_tpu.api.types import (
     Pod,
 )
 from kubernetes_tpu.api.v1 import node_from_v1, pod_from_v1
-from kubernetes_tpu.client.events import EventRecorder
+from kubernetes_tpu.client.events import EventBroadcaster
 from kubernetes_tpu.client.informers import SharedInformer
 from kubernetes_tpu.client.leaderelection import (
     LeaderElectionConfig,
     LeaderElector,
 )
 from kubernetes_tpu.machinery import errors, meta
+from kubernetes_tpu.sched.metrics import FAILED_EVENTS
 from kubernetes_tpu.sched.scheduler import Scheduler
 
 Obj = Dict[str, Any]
@@ -350,7 +351,11 @@ class SchedulerServer:
             leader_elect = leader_elect or self.config.leader_election.leader_elect
 
         self.client = client
-        self.recorder = EventRecorder(client, component=scheduler_name)
+        # FailedScheduling Events leave the loop through a queue: one sink
+        # thread writes them, and it never takes `_mu`
+        self.recorder = EventBroadcaster(
+            client, component=scheduler_name,
+            observe=lambda outcome, n: FAILED_EVENTS.inc(n, outcome=outcome))
         self.scheduler = scheduler or Scheduler(
             binder=APIBinder(client), scheduler_name=scheduler_name,
             queue=queue,
@@ -363,6 +368,7 @@ class SchedulerServer:
             base_dims=base_dims or Dims(N=64, P=128, E=512))
         if self.scheduler.binder is None:
             self.scheduler.binder = APIBinder(client)
+        self.scheduler.events_pending = self.recorder.pending
         if self.config is not None:
             if self.config.decision_provenance:
                 # config-file switch for the provenance pipeline (the env
@@ -597,6 +603,7 @@ class SchedulerServer:
                 inf.stop()
         for t in self._threads:
             t.join(timeout=2)
+        self.recorder.stop(timeout=10)
         if self.telemetry_gateway is not None:
             self.telemetry_gateway.stop()
             self.telemetry_gateway = None
@@ -619,6 +626,7 @@ class SchedulerServer:
                 inf.stop()
         for t in self._threads:
             t.join(timeout=2)
+        self.recorder.abandon()
 
     def _on_stopped_leading(self) -> None:
         """Any leadership loss re-arms the reconciliation pass HERE, on the
@@ -729,7 +737,9 @@ class SchedulerServer:
         if stats.unschedulable:
             self.total_unschedulable_events += stats.unschedulable
         # FailedScheduling events, as scheduler.go:436-448 records on
-        # FitError. With decision provenance on, the explainer already
+        # FitError: queued here (the involved object resolved now, while
+        # the pod is known to exist), written by the recorder's sink
+        # thread. With decision provenance on, the explainer already
         # emitted the rich per-predicate events from inside the wave for
         # every pod it ATTRIBUTED — the generic message would double-post
         # a weaker duplicate for those. But failure paths the attribution
@@ -738,6 +748,7 @@ class SchedulerServer:
         # still get the generic event: gate per pod on whether an
         # attribution doc exists, not on the explainer's mere presence.
         explainer = self.scheduler.explainer
+        failed = []
         for key in stats.failed_keys:
             if explainer is not None and explainer.why(key) is not None:
                 continue
@@ -745,8 +756,10 @@ class SchedulerServer:
             obj = self.pod_informer.lister.get(ns, name) \
                 if self.pod_informer else None
             if obj is not None:
-                self.recorder.event(obj, "Warning", "FailedScheduling",
-                                    "no nodes available to schedule pod")
+                failed.append(obj)
+        if failed:
+            self.recorder.events(failed, "Warning", "FailedScheduling",
+                                 "no nodes available to schedule pod")
         # an empty call (no pod popped) is part of the wait for pods
         lap("post-wave" if stats.attempted else "idle-wait")
         return stats

@@ -12,6 +12,7 @@ import pytest
 from kubernetes_tpu.apiserver import APIServer, HTTPGateway
 from kubernetes_tpu.client import (
     Client,
+    EventBroadcaster,
     EventRecorder,
     InformerFactory,
     LeaderElectionConfig,
@@ -223,6 +224,146 @@ class TestEvents:
         assert evs[0]["count"] == 2
         assert evs[0]["reason"] == "FailedScheduling"
         assert evs[0]["source"]["component"] == "scheduler"
+
+
+class _GatedEvents:
+    """A client whose Event creates wait at a gate (open it to let the sink
+    write), a broadcaster on it, and what the broadcaster's counter would
+    read. `hold_sink()` parks the sink thread at the gate on a plug Event,
+    so that whatever is queued next stays queued."""
+
+    def __init__(self, api):
+        self.client = Client.local(api)
+        self.gate = threading.Event()
+        self.writers = []       # idents of the threads that wrote an Event
+        self.outcomes = []
+        create = self.client.events.create
+
+        def held(obj, ns=None):
+            self.writers.append(threading.get_ident())
+            assert self.gate.wait(10)
+            return create(obj, ns)
+
+        self.client.events.create = held
+        self.broadcaster = EventBroadcaster(
+            self.client, component="scheduler",
+            observe=lambda outcome, n: self.outcomes.extend([outcome] * n))
+
+    def hold_sink(self):
+        self.broadcaster.event(mkpod("plug"), "Normal", "Plug", "held")
+        deadline = time.monotonic() + 5
+        while not self.writers and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert self.writers and self.broadcaster.pending() == 1
+
+    def written(self):
+        return [e for e in self.client.events.list("default")["items"]
+                if e["reason"] != "Plug"]
+
+
+@pytest.fixture
+def gated(api):
+    g = _GatedEvents(api)
+    yield g
+    g.gate.set()
+    g.broadcaster.stop(timeout=5)
+
+
+class TestEventBroadcaster:
+    @pytest.mark.parametrize("pods, repeats, batched", [
+        (1, 1, False), (40, 1, False), (40, 1, True), (1, 2, False),
+        (3, 3, False), (3, 3, True)])
+    def test_event_returns_at_once_and_the_sink_writes_later(
+            self, gated, pods, repeats, batched):
+        b = gated.broadcaster
+        gated.hold_sink()
+        objs = [gated.client.pods.create(mkpod(f"ev{i}"))
+                for i in range(pods)]
+        for _ in range(repeats):
+            if batched:     # a wave's failed pods, under one lock hold
+                b.events(objs, "Warning", "FailedScheduling", "0/3 nodes")
+                continue
+            for obj in objs:
+                b.event(obj, "Warning", "FailedScheduling", "0/3 nodes")
+        # back on the caller's thread with nothing written: one queue entry
+        # a key, and no apiserver call from this thread
+        assert b.pending() == 1 + pods
+        assert gated.written() == []
+        assert threading.get_ident() not in gated.writers
+        assert not b.flush(timeout=0.05)
+        gated.gate.set()
+        assert b.flush(timeout=10) and b.pending() == 0
+        evs = gated.written()
+        assert sorted(e["involvedObject"]["name"] for e in evs) == \
+            sorted(f"ev{i}" for i in range(pods))
+        assert {e["count"] for e in evs} == {repeats}
+        assert all(e["involvedObject"]["uid"] and e["type"] == "Warning"
+                   and e["source"]["component"] == "scheduler" for e in evs)
+        counts = {o: gated.outcomes.count(o) for o in set(gated.outcomes)}
+        assert counts == {"queued": 1 + pods, "emitted": 1 + pods,
+                          **({"coalesced": pods * (repeats - 1)}
+                             if repeats > 1 else {})}
+
+    def test_a_repeat_after_the_write_bumps_the_same_object(self, gated):
+        b = gated.broadcaster
+        gated.gate.set()
+        pod = gated.client.pods.create(mkpod("again"))
+        for _ in range(2):
+            b.event(pod, "Warning", "FailedScheduling", "0/3 nodes")
+            assert b.flush(timeout=10)
+        (ev,) = gated.written()
+        assert ev["count"] == 2
+
+    def test_past_the_bound_events_are_dropped_and_nothing_blocks(
+            self, gated, monkeypatch):
+        monkeypatch.setattr(EventBroadcaster, "QUEUE_BOUND", 8)
+        b = gated.broadcaster
+        gated.hold_sink()
+        t0 = time.monotonic()
+        b.events([mkpod(f"over{i}") for i in range(13)], "Warning",
+                 "FailedScheduling", "full")
+        assert time.monotonic() - t0 < 1.0
+        assert gated.outcomes.count("dropped") == 5
+        assert b.pending() == 1 + 8
+        # a repeat of a queued key still coalesces at the bound
+        b.event(mkpod("over0"), "Warning", "FailedScheduling", "full")
+        assert gated.outcomes.count("dropped") == 5
+        gated.gate.set()
+        assert b.flush(timeout=10)
+        assert sorted(e["involvedObject"]["name"] for e in gated.written()) \
+            == [f"over{i}" for i in range(8)]
+        assert gated.outcomes.count("emitted") == 1 + 8
+
+    def test_stop_counts_what_it_could_not_write_as_dropped(self, gated):
+        b = gated.broadcaster
+        gated.hold_sink()
+        for i in range(3):
+            b.event(mkpod(f"late{i}"), "Warning", "FailedScheduling", "x")
+        b.stop(timeout=0.05)         # the sink is still held at the gate
+        assert gated.outcomes.count("dropped") == 3 and b.pending() <= 1
+        gated.gate.set()
+        assert b.flush(timeout=10) and gated.written() == []
+
+    def test_an_idle_sink_thread_sleeps_without_a_timeout(self, gated):
+        b = gated.broadcaster
+        waits = []
+        wait = b._work.wait
+
+        def counted(timeout=None):
+            waits.append(timeout)
+            return wait(timeout)
+
+        b._work.wait = counted
+        gated.gate.set()
+        assert b._thread is None     # no Event yet: no thread at all
+        b.event(mkpod("one"), "Normal", "Scheduled", "ok")
+        assert b.flush(timeout=10)
+        sink = b._thread
+        time.sleep(0.3)
+        # one wait since the queue emptied, never timed, still asleep in it
+        assert waits == [None] and sink.is_alive()
+        b.stop(timeout=5)
+        assert not sink.is_alive() and b._thread is None
 
 
 class TestInformerFactoryKeys:

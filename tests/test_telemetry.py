@@ -8,6 +8,7 @@ eviction, first-seen-across-requeue and dump-on-abandon are all asserted
 exactly — no sleeps, no wall-time flakes.
 """
 
+import dataclasses
 import json
 import logging
 import threading
@@ -927,6 +928,137 @@ class TestServerLoopSpans:
         rec = s.telemetry.recorder.records()[-1]
         assert sum(d for _, d in rec["loop"]["phases"]) == \
             pytest.approx(rec["t_start"])
+
+
+def _event_server(clk, write_s=0.0):
+    """`_loop_server` whose FailedScheduling Events can be watched: a lister
+    that knows every pod, and Event creates that wait at `gate` and then
+    take `write_s` seconds (off the interpreter, as the store's calls do)."""
+    import time
+    import types
+
+    srv, s = _loop_server(clk)
+    srv.pod_informer = types.SimpleNamespace(
+        stop=lambda: None, lister=types.SimpleNamespace(
+            get=lambda ns, name: {"kind": "Pod", "metadata": {
+                "name": name, "namespace": ns, "uid": f"uid-{name}"}}))
+    gate = threading.Event()
+    create = srv.client.events.create
+
+    def held(obj, ns=None):
+        assert gate.wait(10)
+        time.sleep(write_s)
+        return create(obj, ns)
+
+    srv.client.events.create = held
+
+    def failed_events():
+        return [e for e in srv.client.events.list("default")["items"]
+                if e["reason"] == "FailedScheduling"]
+
+    return srv, s, gate, failed_events
+
+
+def _nofit(i):
+    return Pod(name=f"nofit{i}", creation_index=i,
+               requests=Resources.make(cpu="4096", memory="8Mi"))
+
+
+class TestServerEventsLeaveTheLoop:
+    def test_run_one_wave_only_queues_its_failed_scheduling_events(self):
+        from kubernetes_tpu.sched.metrics import FAILED_EVENTS
+
+        k = 12
+        before = {o: FAILED_EVENTS.value(outcome=o)
+                  for o in ("queued", "emitted", "dropped")}
+        srv, s, gate, failed_events = _event_server({"t": 0.0})
+        s.telemetry.loop_reset()
+        for i in range(k):
+            s.on_pod_add(_nofit(i))
+        stats = srv.run_one_wave()
+        assert stats.unschedulable == k and len(stats.failed_keys) == k
+        # the wave is over and not one Event is at the apiserver
+        assert failed_events() == [] and srv.recorder.pending() == k
+        assert s.telemetry.recorder.records()[-1]["events_pending"] == 0
+        s.on_pod_add(_pod(100))
+        srv.run_one_wave()          # the next wave pops over the backlog
+        rec = s.telemetry.recorder.records()[-1]
+        assert rec["stats"]["scheduled"] == 1 and rec["events_pending"] == k
+        gate.set()
+        assert srv.recorder.flush(timeout=10)
+        assert sorted(e["involvedObject"]["name"] for e in failed_events()) \
+            == sorted(f"nofit{i}" for i in range(k))
+        assert all(e["involvedObject"]["uid"] == "uid-" +
+                   e["involvedObject"]["name"] and e["count"] == 1
+                   and e["message"] == "no nodes available to schedule pod"
+                   for e in failed_events())
+        after = {o: FAILED_EVENTS.value(outcome=o) for o in before}
+        assert {o: after[o] - before[o] for o in before} == {
+            "queued": k, "emitted": k, "dropped": 0}
+        srv.stop()
+
+    @pytest.mark.parametrize("how, landed", [("stop", "all"),
+                                             ("crash", "few")])
+    def test_stop_flushes_the_queue_and_crash_abandons_it(self, how, landed):
+        k = 20
+        srv, s, gate, failed_events = _event_server({"t": 0.0},
+                                                    write_s=0.005)
+        gate.set()
+        for i in range(k):
+            s.on_pod_add(_nofit(i))
+        srv.run_one_wave()
+        getattr(srv, how)()
+        assert srv.recorder.pending() == 0
+        # 5 ms a write: stop() waited for all 20; crash() came back while
+        # at most the one in flight and its successor could have landed
+        n = len(failed_events())
+        assert n == k if landed == "all" else n <= 2
+
+    def test_wave_two_schedules_against_unconfirmed_assumes(self):
+        """Back-to-back waves, as the drain now runs them: wave 2's pods
+        need wave 1's (in-zone affinity, host anti-affinity) before the
+        informer has confirmed one Binding of wave 1."""
+        from kubernetes_tpu.api import semantics as sem
+        from kubernetes_tpu.api.types import (Affinity, LabelSelector,
+                                              PodAffinityTerm)
+        from kubernetes_tpu.models.workloads import HOSTNAME, ZONE
+
+        srv, s = _loop_server({"t": 0.0})
+        for n in make_nodes(24, zones=8)[8:]:   # three hosts to a zone
+            s.on_node_add(n)
+        nodes = {n.name: n for n in s.cache.nodes()}
+        web = LabelSelector.of(match_labels={"app": "web"})
+        on_host = (PodAffinityTerm(selector=web, topology_key=HOSTNAME),)
+        in_zone = (PodAffinityTerm(selector=web, topology_key=ZONE),)
+        small = Resources.make(cpu="10m", memory="8Mi")
+        wave1 = [Pod(name=f"web{i}", labels={"app": "web"}, requests=small,
+                     affinity=Affinity(anti_required=on_host),
+                     creation_index=i) for i in range(2)]
+        wave2 = [Pod(name=f"cache{i}", labels={"app": "cache"},
+                     requests=small, creation_index=10 + i,
+                     affinity=Affinity(pod_required=in_zone,
+                                       anti_required=on_host))
+                 for i in range(3)]
+        s.telemetry.loop_reset()
+        for p in wave1:
+            s.on_pod_add(p)
+        assert srv.run_one_wave().scheduled == 2
+        for p in wave2:             # no confirmation in between
+            s.on_pod_add(p)
+        assert srv.run_one_wave().scheduled == 3
+        rec = s.telemetry.recorder.records()[-1]
+        assert rec["assumed_outstanding"] == 2
+        where = dict(s.binder.bound)
+        existing = [dataclasses.replace(p, node_name=where[p.key])
+                    for p in wave1]
+        assert len({where[p.key] for p in wave1}) == 2
+        for p in wave2:
+            assert sem.interpod_affinity_fits(p, nodes[where[p.key]], nodes,
+                                              existing)
+            # and it had to use them: with no web pod in sight it fits
+            # nowhere
+            assert not any(sem.interpod_affinity_fits(p, n, nodes, [])
+                           for n in nodes.values())
 
 
 class TestRequestAndTxnMetrics:
