@@ -439,13 +439,18 @@ _KNOWN = {
 class Client:
     """The clientset: `client.pods.create(...)`, `client.resource(...)`."""
 
-    def __init__(self, transport):
+    def __init__(self, transport, store_counters=None):
         self.transport = transport
+        # where the store runs in the client's own process (`local`): a
+        # factory of readers of its watch-plane counters
+        # (`Storage.watch_plane_reader`); None over the wire
+        self.store_counters = store_counters
         self._cache: Dict[Tuple[str, str, str], ResourceClient] = {}
 
     @staticmethod
     def local(api, retry: Optional[RetryPolicy] = None) -> "Client":
-        return Client(LocalTransport(api, retry=retry))
+        return Client(LocalTransport(api, retry=retry),
+                      store_counters=api.storage.watch_plane_reader)
 
     @staticmethod
     def http(base_url: str, token: str = "", binary: bool = False,

@@ -46,12 +46,6 @@ class Watch:
         self._stopped = threading.Event()
         self._term_mu = threading.Lock()
         self._terminal: Optional[Event] = None
-        # True iff a producer stopped this stream because the buffer was
-        # FULL — the deaf-consumer case. Lets the dispatcher distinguish a
-        # real backpressure eviction from a consumer that closed its own
-        # stream a moment before the send (which must not be counted or
-        # terminated as deaf).
-        self.overflowed = False
 
     def send(self, event: Event, timeout: Optional[float] = 5.0) -> bool:
         """Producer side. Returns False if the watcher is gone/slow: the
@@ -66,8 +60,20 @@ class Watch:
                 self._q.put(event, timeout=timeout)
             return True
         except queue.Full:
-            self.overflowed = True
             self.stop()
+            return False
+
+    def offer(self, event: Event) -> bool:
+        """Producer side, for a producer that keeps its own place in the
+        stream (the store's pump): put the event if there is room and say
+        whether it went in. A full buffer costs the consumer nothing — the
+        producer comes back with the same event later."""
+        if self._stopped.is_set():
+            return False
+        try:
+            self._q.put_nowait(event)
+            return True
+        except queue.Full:
             return False
 
     def terminate(self, event: Event) -> None:

@@ -244,6 +244,11 @@ class Scheduler:
         # `pending`): read at every pop onto the wave's record. None = a
         # scheduler no server drives, which queues none.
         self.events_pending: Optional[Callable[[], int]] = None
+        # the watch plane since the previous wave's end (the server's
+        # `_watch_plane`): `informer_relists` of its informers and, where
+        # the store is in this process, the pump's `pump_lag_max` and the
+        # `watch_evictions`. Read at a wave's END onto its record.
+        self.watch_plane: Optional[Callable[[], Dict[str, int]]] = None
         # streaming micro-waves (ISSUE 18): when the live backlog is
         # nothing but a handful of FRESH watch deltas, admit them through
         # a small fixed-capacity wave grafted onto the resident snapshot
@@ -990,6 +995,8 @@ class Scheduler:
 
             MICRO_WAVES.inc(scheduler=self.scheduler_name)
         extra = {"snapshot_mode": snap_mode, **wave_extra}
+        if self.watch_plane is not None and span.enabled:
+            extra.update(self.watch_plane())
         if explain_rec:
             extra["explain"] = explain_rec
         self.telemetry.finish_wave(

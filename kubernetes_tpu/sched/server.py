@@ -369,6 +369,12 @@ class SchedulerServer:
         if self.scheduler.binder is None:
             self.scheduler.binder = APIBinder(client)
         self.scheduler.events_pending = self.recorder.pending
+        self.scheduler.watch_plane = self._watch_plane
+        self._relists_seen = 0
+        # the store's side of it, where the client can read the store's
+        # counters (`Client.local`); None over HTTP
+        counters = getattr(client, "store_counters", None)
+        self._store_counters = counters() if counters is not None else None
         if self.config is not None:
             if self.config.decision_provenance:
                 # config-file switch for the provenance pipeline (the env
@@ -515,6 +521,24 @@ class SchedulerServer:
         with self._handling():
             self.scheduler.on_node_delete(meta.name(obj))
 
+    def _watch_plane(self) -> Dict[str, int]:
+        """What the watch plane did since the previous call, for a wave's
+        record: `informer_relists` of this server's informers (full
+        list+replace rounds; a healthy wave costs none) and, where the
+        client reads the store's counters (`Client.local`: the store runs
+        in this process), the store's `watch_evictions` and the largest lag
+        of its pump, `pump_lag_max`. A store in another process leaves its
+        two out. `start()` resets it once the informers' initial lists are
+        in."""
+        now = sum(inf.relists for inf in (
+            self.pod_informer, self.node_informer, self.pdb_informer)
+            if inf is not None)
+        out = {"informer_relists": now - self._relists_seen}
+        self._relists_seen = now
+        if self._store_counters is not None:
+            out.update(self._store_counters())
+        return out
+
     # -- lifecycle ----------------------------------------------------------- #
 
     def _on_pdb(self, obj: Obj) -> None:
@@ -563,6 +587,7 @@ class SchedulerServer:
         self.node_informer.wait_for_sync()
         self.pod_informer.start()
         self.pod_informer.wait_for_sync()
+        self._watch_plane()  # the initial lists are no wave's relists
         if self.elector is not None:
             self.elector.start()
         # SIGUSR2 cache dump/compare (internal/cache/debugger/debugger.go:55)

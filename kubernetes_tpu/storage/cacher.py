@@ -18,6 +18,7 @@ from `since >= horizon` is served fully from memory.
 
 from __future__ import annotations
 
+import itertools
 import threading
 from collections import deque
 from typing import Any, Deque, Dict, List, NamedTuple, Optional
@@ -65,13 +66,18 @@ class WatchCache:
                 self._ring.popleft()
             self._horizon = max(self._horizon, at_rev)
 
-    def events_since(self, since: int, prefix: str) -> Optional[List[CachedEvent]]:
-        """Events with rev > since under prefix, from memory — or None when
-        `since` predates the ring's horizon (caller falls back to storage)."""
+    def events_since(self, since: int, prefix: str, limit: int = 0,
+                     count: bool = True) -> Optional[List[CachedEvent]]:
+        """Events with rev > since under prefix, oldest first and at most
+        `limit` of them (0 = all), from memory — or None when `since`
+        predates the ring's horizon (caller falls back to storage).
+        `count=False` leaves `hits` / `storage_fallbacks` alone: a catch-up
+        brought in several refills is one catch-up."""
         with self._mu:
             if since < self._horizon:
-                self.storage_fallbacks += 1
+                self.storage_fallbacks += count
                 return None
-            self.hits += 1
-            return [e for e in self._ring
-                    if e.rev > since and e.key.startswith(prefix)]
+            self.hits += count
+            found = (e for e in self._ring
+                     if e.rev > since and e.key.startswith(prefix))
+            return list(itertools.islice(found, limit or None))

@@ -10,7 +10,9 @@ fallback path produces byte-identical logs and recovers into either backend.
 
 from __future__ import annotations
 
+import bisect
 import ctypes
+import itertools
 import logging
 import os
 import struct
@@ -147,7 +149,7 @@ def _load_lib() -> Optional[ctypes.CDLL]:
                           ctypes.POINTER(ctypes.c_int64)], ctypes.c_int64),
             ("kv_count", [ctypes.c_void_p, ctypes.c_char_p], ctypes.c_int64),
             ("kv_events_since", [ctypes.c_void_p, ctypes.c_int64,
-                                 ctypes.c_char_p,
+                                 ctypes.c_char_p, ctypes.c_int64,
                                  ctypes.POINTER(ctypes.c_char_p),
                                  ctypes.POINTER(ctypes.c_int64)], ctypes.c_int64),
             ("kv_wait", [ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64],
@@ -253,11 +255,15 @@ class NativeKV:
     def count(self, prefix: str) -> int:
         return int(self._lib.kv_count(self._h, prefix.encode()))
 
-    def events_since(self, since_rev: int, prefix: str = "") -> List[KVEvent]:
+    def events_since(self, since_rev: int, prefix: str = "",
+                     limit: int = 0) -> List[KVEvent]:
+        """Events past `since_rev` under `prefix`, oldest first; at most
+        `limit` of them (0 = all)."""
         out = ctypes.c_char_p()
         out_len = ctypes.c_int64()
         n = self._lib.kv_events_since(self._h, since_rev, prefix.encode(),
-                                      ctypes.byref(out), ctypes.byref(out_len))
+                                      limit, ctypes.byref(out),
+                                      ctypes.byref(out_len))
         if n < 0:
             raise CompactedError(f"revision {since_rev} already compacted")
         try:
@@ -365,12 +371,16 @@ class PyKV:
         with self._mu:
             return sum(1 for k in self._data if k.startswith(prefix))
 
-    def events_since(self, since_rev: int, prefix: str = "") -> List[KVEvent]:
+    def events_since(self, since_rev: int, prefix: str = "",
+                     limit: int = 0) -> List[KVEvent]:
         with self._mu:
             if since_rev < self._compacted:
                 raise CompactedError(f"revision {since_rev} already compacted")
-            return [e for e in self._events
-                    if e.rev > since_rev and e.key.startswith(prefix)]
+            start = bisect.bisect_right(self._events, since_rev,
+                                        key=lambda e: e.rev)
+            found = (e for e in itertools.islice(self._events, start, None)
+                     if e.key.startswith(prefix))
+            return list(itertools.islice(found, limit or None))
 
     def wait(self, rev: int, timeout: float) -> int:
         with self._mu:
@@ -578,8 +588,9 @@ class DurableKV:
     def count(self, prefix: str) -> int:
         return self._backend.count(prefix)
 
-    def events_since(self, since_rev: int, prefix: str = "") -> List[KVEvent]:
-        return self._backend.events_since(since_rev, prefix)
+    def events_since(self, since_rev: int, prefix: str = "",
+                     limit: int = 0) -> List[KVEvent]:
+        return self._backend.events_since(since_rev, prefix, limit)
 
     def wait(self, rev: int, timeout: float) -> int:
         return self._backend.wait(rev, timeout)

@@ -11,11 +11,18 @@ Cacher, storage/cacher/cacher.go:309).
 
 Watch-plane contract (ISSUE 13, the cacher's delivery discipline):
 
-  * every watcher owns a BOUNDED buffer (`KTPU_WATCH_BUFFER`, default 8192);
-    a consumer that stops draining is terminated — that ONE stream gets a
-    410 "too old resource version" terminal Status (so the client knows to
-    resume/relist) and the broadcast loop never blocks or balloons for it
-    (cacher.go forgetWatcher);
+  * every watcher owns a BOUNDED buffer (`KTPU_WATCH_BUFFER`, default 8192)
+    and its own place in the stream (`_Watcher.since`). The pump hands a
+    watcher what its buffer has room for and comes back with the rest:
+    from the cacher ring, or beneath the ring's horizon from the KV
+    history. A consumer that is slow, or blocked for a while (a scheduler's
+    informer behind a long wave), is delivered LATE, never less, and is
+    never made to relist; the broadcast never blocks or balloons for it;
+  * a consumer whose buffer stays full for `DEAF_AFTER_S` is deaf and is
+    cut off (cacher.go forgetWatcher): a terminal 504 Status it can RESUME
+    from by resourceVersion while the events it still needs exist, a 410
+    "too old resource version" (relist) only when compaction has taken
+    them — the one real gap;
   * BOOKMARK events carry the dispatched revision on a timer AND immediately
     on every compaction-boundary crossing (`compact_to`), so a quiet
     stream's resume token stays above the compaction floor and reconnects
@@ -55,9 +62,16 @@ WATCH_BUFFER_DEPTH = _REG.gauge(
     labels=("resource",))
 WATCH_DEAF_EVICTIONS = _REG.counter(
     "apiserver_watch_deaf_evictions_total",
-    "Watch streams terminated with a too-old error because the consumer "
-    "stopped draining its bounded buffer (cacher forgetWatcher contract)",
+    "Watch streams cut off because the consumer left its bounded buffer "
+    "full for DEAF_AFTER_S (cacher forgetWatcher contract)",
     labels=("resource",))
+WATCH_PUMP_LAG = _REG.gauge(
+    "storage_watch_pump_lag_events",
+    "Store head revision less the revision the watch pump had broadcast, "
+    "read at each turn of the pump: writes no watcher has been offered yet")
+WATCH_PUMP_BATCH_MAX = _REG.gauge(
+    "storage_watch_pump_batch_max_events",
+    "Largest number of events one turn of the watch pump broadcast")
 WATCH_BOOKMARKS_SENT = _REG.counter(
     "apiserver_watch_bookmarks_sent_total",
     "BOOKMARK events sent to opted-in watchers, by trigger "
@@ -73,14 +87,24 @@ TXN_DURATION = _REG.histogram(
     buckets=(0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
              0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5))
 _OP_CREATE, _OP_UPDATE, _OP_DELETE = ("create",), ("update",), ("delete",)
+#: a watcher whose buffer has been full this long, with events waiting, is
+#: deaf. Waiting costs the pump nothing (it never blocks on a watcher, and a
+#: lagging one costs a bounded read a turn), so the budget is generous: a
+#: consumer that is alive but kept from reading for tens of seconds (an
+#: informer whose handler waits on its owner's lock) is not cut off.
+DEAF_AFTER_S = 60.0
+#: the longest one write waits for the watch pump (`Storage._pace`)
+PACE_WAIT_S = 0.1
 
 
-def _txn_done(op: Tuple[str], t0: float, kv_s: float = 0.0) -> None:
+def _txn_done(op: Tuple[str], t0: float, kv_s: float = 0.0,
+              paced_s: float = 0.0) -> None:
     """Close one transaction: the histogram, and — when the caller's
     thread runs a traced operation (a scheduling wave) — a `store.txn`
     child of the span that caused it, with the seconds of it spent inside
-    the KV backend's calls as `store.txn/kv` (the rest is this module's
-    Python: decode, copies, the caller's transform, encode)."""
+    the KV backend's calls as `store.txn/kv` and those it waited for the
+    watch pump as `store.txn/pace` (the rest is this module's Python:
+    decode, copies, the caller's transform, encode)."""
     dt = time.perf_counter() - t0
     TXN_DURATION.observe_at(op, dt)
     tr = trace.current()
@@ -88,6 +112,8 @@ def _txn_done(op: Tuple[str], t0: float, kv_s: float = 0.0) -> None:
         tr.child("store.txn", dt)
         if kv_s:
             tr.child("store.txn/kv", kv_s)
+        if paced_s:
+            tr.child("store.txn/pace", paced_s)
 
 
 def _parse_watch_buffer(value, default: int = 8192) -> int:
@@ -131,9 +157,14 @@ def _resource_of(prefix: str) -> str:
 class _Watcher:
     """One registered watch stream: the delivery buffer plus its horizon.
 
-    `since` is the revision at/below which events are before this watcher's
-    catch-up replay and must never be re-delivered; `bookmarks` opts the
-    stream into BOOKMARK events (allowWatchBookmarks)."""
+    `since` is this watcher's place in the stream: every event at/below it
+    has been handed over (or filtered out) and must never be re-delivered.
+    A watcher whose `since` is behind the pump's dispatched revision LAGS;
+    `full_since` is the instant its buffer was first found full since it
+    last made room (0.0: it has room); `counted` says the watch cache has
+    counted the catch-up under way as a hit or a storage fallback (once an
+    episode, however many refills it takes). `bookmarks` opts the stream into
+    BOOKMARK events (allowWatchBookmarks)."""
 
     prefix: str
     watch: mwatch.Watch
@@ -141,6 +172,8 @@ class _Watcher:
     since: int
     bookmarks: bool
     resource: str = field(default="")
+    full_since: float = 0.0
+    counted: bool = False  # this lagging episode is on the cache's counters
 
     def __post_init__(self):
         if not self.resource:
@@ -184,6 +217,22 @@ class Storage:
         self._depth_resources: set = set()
         # watch-plane counters the bench/chaos drills assert against
         self.deaf_evictions = 0
+        self.deaf_after_s = DEAF_AFTER_S
+        # the pump's lag (store head less dispatched revision) as each
+        # reader's own high-water mark (`watch_plane_reader`), and the
+        # largest batch one turn broadcast
+        self._lag_marks: Tuple[List[int], ...] = ()
+        self.pump_batch_max = 0
+        self._lagging = False  # some live watcher is behind the pump
+        # flow control at the source (`_pace`): a write that finds the pump
+        # more than `_pace_high` events behind waits for it to come within
+        # half of that. An eighth of a watcher's buffer: the pump never
+        # comes back to a level watcher with more than its buffer holds.
+        self._pace_high = max(self._watch_buffer // 8, 1)
+        self._pace_cv = threading.Condition()
+        self._pace_waiting = 0
+        self._pace_stuck_at = -1  # pump revision a whole wait saw unmoved
+        self.paced_writes = 0
         self.bookmarks_sent = 0
         self.compaction_bookmarks = 0
         self._stop = threading.Event()
@@ -227,6 +276,54 @@ class Storage:
         compacts at this revision, not the kv head."""
         return self._dispatched_rev
 
+    def _pace(self, rev: int) -> float:
+        """Flow control at the source, after a write landed at `rev`: a
+        writer that outruns the watch pump waits, at most PACE_WAIT_S, until
+        the pump is within half of `_pace_high` events of it. A loop of
+        short writes on one interpreter (a 30,000-Binding wave) otherwise
+        starves the pump for as long as it runs — each write's native call
+        frees the interpreter for microseconds and the writer has it back
+        before the pump wakes — and every watcher sees the whole wave
+        seconds late, in one burst. Writers wait only for a pump that
+        moves: one that is dead, or that a whole wait saw stand still,
+        costs them nothing more until it moves again. Returns the seconds
+        waited."""
+        at = self._dispatched_rev
+        if rev - at <= self._pace_high or at == self._pace_stuck_at \
+                or not self._pump.is_alive():
+            return 0.0
+        t0 = time.perf_counter()
+        low = self._pace_high // 2
+        with self._pace_cv:
+            self._pace_waiting += 1
+            self._pace_cv.wait_for(
+                lambda: rev - self._dispatched_rev <= low
+                or self._stop.is_set(), timeout=PACE_WAIT_S)
+            self._pace_waiting -= 1
+        if self._dispatched_rev == at:
+            self._pace_stuck_at = at
+        self.paced_writes += 1
+        return time.perf_counter() - t0
+
+    def watch_plane_reader(self) -> Callable[[], Dict[str, int]]:
+        """A reader of the watch plane's counters with a baseline of its
+        own: each call gives `watch_evictions`, the streams cut off as deaf
+        since its previous call, and `pump_lag_max`, the largest pump lag
+        since then (the lag right now included). A scheduler calls it at
+        each wave's end, so the wave's record says how far the broadcast
+        fell behind the writes while it ran; two readers on one store (an
+        active and a standby scheduler) do not take each other's maxima."""
+        mark, seen = [0], [self.deaf_evictions]
+        self._lag_marks += (mark,)
+
+        def read() -> Dict[str, int]:
+            now = max(self.kv.rev() - self._dispatched_rev, 0)
+            top, mark[0] = max(mark[0], now), 0
+            cut, seen[0] = self.deaf_evictions - seen[0], self.deaf_evictions
+            return {"watch_evictions": cut, "pump_lag_max": top}
+
+        return read
+
     def live_watchers(self, prefix: str = "") -> int:
         """Registered, not-yet-stopped streams under prefix — the bench's
         `upstream_watches_per_resource` reads this (one mux stream per
@@ -242,7 +339,7 @@ class Storage:
 
     def create(self, key: str, obj: Obj, resource: str = "object") -> Obj:
         t0 = time.perf_counter()
-        kv_s = 0.0
+        kv_s = paced_s = 0.0
         try:
             data = _encode(obj)
             t1 = time.perf_counter()
@@ -252,9 +349,10 @@ class Storage:
                 raise errors.new_already_exists(resource, meta.name(obj))
             out = meta.deep_copy(obj)
             meta.set_resource_version(out, str(rev))
+            paced_s = self._pace(rev)
             return out
         finally:
-            _txn_done(_OP_CREATE, t0, kv_s)
+            _txn_done(_OP_CREATE, t0, kv_s, paced_s)
 
     def get(self, key: str, resource: str = "object", name: str = "") -> Obj:
         rec = self.kv.get(key)
@@ -277,13 +375,16 @@ class Storage:
     def delete(self, key: str, resource: str = "object", name: str = "",
                expected_rv: Optional[str] = None) -> Obj:
         t0 = time.perf_counter()
+        paced_s = 0.0
         try:
-            return self._delete(key, resource, name, expected_rv)
+            out, rev = self._delete(key, resource, name, expected_rv)
+            paced_s = self._pace(rev)
+            return out
         finally:
-            _txn_done(_OP_DELETE, t0)
+            _txn_done(_OP_DELETE, t0, paced_s=paced_s)
 
     def _delete(self, key: str, resource: str, name: str,
-                expected_rv: Optional[str]) -> Obj:
+                expected_rv: Optional[str]) -> Tuple[Obj, int]:
         while True:
             rec = self.kv.get(key)
             if rec is None:
@@ -293,7 +394,7 @@ class Storage:
                                           "the object has been modified")
             rv = self.kv.txn_delete(key, rec.mod_rev)
             if rv > 0:
-                return _decode(rec.value, rec.mod_rev)
+                return _decode(rec.value, rec.mod_rev), rv
             if rv == 0:
                 raise errors.new_not_found(resource, name or key)
             # lost a race with a concurrent update; retry
@@ -309,16 +410,20 @@ class Storage:
         """
         t0 = time.perf_counter()
         kv_s = [0.0]
+        paced_s = 0.0
         try:
-            return self._guaranteed_update(key, update_fn, resource, name,
-                                           ignore_not_found, expected_rv,
-                                           kv_s)
+            out, rev = self._guaranteed_update(
+                key, update_fn, resource, name, ignore_not_found,
+                expected_rv, kv_s)
+            paced_s = self._pace(rev)
+            return out
         finally:
-            _txn_done(_OP_UPDATE, t0, kv_s[0])
+            _txn_done(_OP_UPDATE, t0, kv_s[0], paced_s)
 
     def _guaranteed_update(self, key: str, update_fn: Callable[[Obj], Obj],
                            resource: str, name: str, ignore_not_found: bool,
-                           expected_rv: Optional[str], kv_s: List[float]) -> Obj:
+                           expected_rv: Optional[str],
+                           kv_s: List[float]) -> Tuple[Obj, int]:
         chaos_cas = False  # at most one injected conflict per call: the
         # retry loop must converge even under FAULT_SPEC=store.cas_conflict@1.0
         while True:
@@ -372,7 +477,7 @@ class Storage:
             if rev > 0:
                 out = meta.deep_copy(updated)
                 meta.set_resource_version(out, str(rev))
-                return out
+                return out, rev
             # CAS failure → re-read and retry
 
     # ------------------------------------------------------------------ #
@@ -405,8 +510,9 @@ class Storage:
         since_rv ""/"0" = from now. Raises Gone(410) if since_rv predates
         compaction — the caller must relist (reflector relist semantics).
         `buffer` bounds this watcher's delivery queue (default
-        KTPU_WATCH_BUFFER); a consumer that stops draining it is evicted
-        with a too-old terminal error, never allowed to stall the pump.
+        KTPU_WATCH_BUFFER); a consumer that leaves it full is delivered
+        late, and cut off once it has for DEAF_AFTER_S; it never stalls
+        the pump.
         """
         if faultline.should("store.compact", "watch"):
             # chaos: a REAL compaction at the current revision — stale
@@ -421,35 +527,30 @@ class Storage:
         # UNBOUNDED — un-evictable deaf consumers
         w = mwatch.Watch(capacity=_parse_watch_buffer(
             buffer, default=self._watch_buffer))
-        wr = _Watcher(prefix=prefix, watch=w, predicate=predicate,
-                      since=0, bookmarks=bookmarks)
         with self._watch_mu:
             # "" / "0" = from NOW: the current store revision, regardless of
             # how far the dispatch pump has gotten
             since = int(since_rv) if since_rv not in ("", "0") else self.kv.rev()
-            # catch-up: replay history before going live under the same lock
-            # the pump uses, so no event is missed or duplicated; the pump
-            # delivers everything > max(since, _dispatched_rev). The replay
-            # is served from the watch cache whenever `since` is within its
-            # horizon — no storage read per watcher (cacher.go:369-374)
-            cached = self.watch_cache.events_since(since, prefix)
-            if cached is not None:
-                for ce in cached:
-                    if ce.rev > self._dispatched_rev:
-                        break
-                    self._deliver(wr, ce)
-            else:
-                try:
-                    history = self.kv.events_since(since, prefix)
-                except native.CompactedError:
-                    raise errors.new_gone(
-                        f"too old resource version: {since} "
-                        f"(compacted at {self.kv.compacted_rev()})")
-                for ev in history:
-                    if ev.rev > self._dispatched_rev:
-                        break  # the pump will deliver the rest
-                    self._deliver(wr, self._to_cached(ev))
-            wr.since = max(since, self._dispatched_rev)
+            wr = _Watcher(prefix=prefix, watch=w, predicate=predicate,
+                          since=since, bookmarks=bookmarks)
+            # catch-up: replay history up to the pump's revision, as much as
+            # the buffer holds, under the same lock the pump uses, so no
+            # event is missed or duplicated; the pump brings the rest, then
+            # everything newer. Served from the watch cache whenever `since`
+            # is within its horizon — no storage read per watcher
+            # (cacher.go:369-374)
+            try:
+                if since < self.watch_cache.horizon \
+                        and since < self.kv.compacted_rev():
+                    # neither the ring nor the log holds what follows
+                    # `since`, wherever the pump is
+                    raise native.CompactedError(since)
+                self._top_up(wr)
+            except native.CompactedError:
+                raise errors.new_gone(
+                    f"too old resource version: {since} "
+                    f"(compacted at {self.kv.compacted_rev()})")
+            self._lagging |= wr.since < self._dispatched_rev
             self._watchers.append(wr)
         return w
 
@@ -461,72 +562,112 @@ class Storage:
         return CachedEvent(rev=ev.rev, type=typ, key=ev.key,
                            obj=_decode(ev.value, ev.rev))
 
-    def _deliver(self, wr: _Watcher, ce: CachedEvent,
-                 timeout: float = 0.0) -> None:
-        if wr.predicate is not None and not wr.predicate(ce.obj):
-            return
+    def _feed(self, wr: _Watcher, events, upto: int) -> None:
+        """Hand `events` (revision order) to one watcher, in order, until
+        its buffer is full; `since` moves with every event handed over or
+        filtered out, and on to `upto` once none is left. If the buffer
+        fills first the watcher lags from `since`. Never blocks: the event
+        path for everyone else never stalls on one consumer (cacher.go
+        dispatchEvent's non-blocking first pass)."""
         w = wr.watch
-        if w.stopped:
-            return
-        # watchers receive a copy so one consumer's mutation can't leak into
-        # another's view of the shared decoded event
-        obj = meta.deep_copy(ce.obj)
-        # non-blocking from the dispatcher: a watcher that cannot keep up is
-        # terminated with a too-old terminal error — it alone pays, and the
-        # event path for everyone else never stalls (cacher.go
-        # forgetWatcher). The terminal Status survives the full buffer
-        # (machinery/watch.Watch.terminate), so a slow-but-alive consumer
-        # drains its backlog and THEN learns it must resume/relist.
-        if not w.send(mwatch.Event(ce.type, obj), timeout=timeout):
-            self._evict_if_deaf(wr, at_rev=ce.rev)
+        free = w.capacity - w.depth()
+        for ce in events:
+            if ce.rev <= wr.since or not ce.key.startswith(wr.prefix):
+                continue
+            if wr.predicate is not None and not wr.predicate(ce.obj):
+                wr.since = ce.rev
+                continue
+            # watchers receive a copy so one consumer's mutation can't leak
+            # into another's view of the shared decoded event
+            if free <= 0 or not w.offer(
+                    mwatch.Event(ce.type, meta.deep_copy(ce.obj))):
+                return
+            free -= 1
+            wr.since = ce.rev
+        wr.since = max(wr.since, upto)
 
-    def _evict_if_deaf(self, wr: _Watcher, at_rev: int) -> None:
-        """A failed send is a DEAF eviction only when the buffer actually
-        overflowed (Watch.overflowed); a consumer that closed its own
-        stream a moment before the send gets neither a bogus too-old
-        terminal nor a tick on the eviction metric."""
+    def _top_up(self, wr: _Watcher) -> None:
+        """Bring a watcher that is behind the pump what its buffer has room
+        for: from the cacher ring, beneath the ring's horizon from the KV
+        history (CompactedError when compaction has taken what it needs).
+        One that has not drained to half waits — each read of the ring or
+        the log then fills at least half a buffer, and reads no more than
+        the buffer has room for. Watch lock held."""
         w = wr.watch
-        if not w.overflowed:
+        if wr.since >= self._dispatched_rev or w.stopped:
+            wr.counted = False
             return
-        w.terminate(mwatch.Event(
-            mwatch.ERROR,
-            _too_old_status(f"{at_rev} (watcher evicted: delivery "
-                            f"buffer of {w.capacity} exhausted)")))
+        free = w.capacity - w.depth()
+        if free <= 0:
+            now = time.monotonic()
+            if not wr.full_since:
+                wr.full_since = now
+            elif now - wr.full_since >= self.deaf_after_s:
+                self._evict(wr)
+            return
+        wr.full_since = 0.0  # it made room: slow, not deaf
+        if 2 * free < w.capacity:
+            return
+        # one event more than there is room for says whether this refill
+        # brings the watcher level
+        events = self.watch_cache.events_since(
+            wr.since, wr.prefix, limit=free + 1, count=not wr.counted)
+        wr.counted = True
+        if events is None:
+            # the log may run ahead of the pump: those are the pump's to bring
+            events = [self._to_cached(ev) for ev in self.kv.events_since(
+                wr.since, wr.prefix, free + 1)
+                if ev.rev <= self._dispatched_rev]
+        whole = len(events) <= free
+        self._feed(wr, events[:free], self._dispatched_rev if whole else 0)
+
+    def _evict(self, wr: _Watcher) -> None:
+        """Cut off a deaf consumer. The terminal Status survives the full
+        buffer (machinery/watch.Watch.terminate), so a slow-but-alive
+        consumer drains its backlog and THEN learns why and what to do:
+        resume from its resourceVersion (504) while the events past
+        `since` still exist, relist (410) once compaction has taken them."""
+        w = wr.watch
+        floor = self.kv.compacted_rev()
+        if wr.since < floor:
+            status = _too_old_status(
+                f"{wr.since} (watcher evicted: delivery buffer of "
+                f"{w.capacity} exhausted, compacted at {floor})")
+        else:
+            status = errors.new_timeout(
+                f"watcher evicted: delivery buffer of {w.capacity} full for "
+                f"{self.deaf_after_s:g}s; resume from resourceVersion "
+                f"{wr.since}").status()
+        w.terminate(mwatch.Event(mwatch.ERROR, status))
         self.deaf_evictions += 1
         WATCH_DEAF_EVICTIONS.inc(resource=wr.resource)
 
     def _send_bookmarks(self, trigger: str = "timer") -> None:
         with self._watch_mu:
             for wr in self._watchers:
-                if wr.bookmarks and not wr.watch.stopped:
-                    # never below the watcher's own horizon (a bookmark at
-                    # the pump's lagging revision would hand a resuming
-                    # reflector an RV it has already consumed past,
-                    # replaying duplicates) and never ABOVE the pump's
-                    # dispatched revision: advertising the compaction
-                    # floor itself when it outran the pump would hand out
-                    # a resume token that silently skips events destroyed
-                    # before they were ever broadcast. For a compaction at
-                    # <= dispatched_rev (compact_to's contract for the
-                    # seam and drills) this value already sits at/above
-                    # the new floor, which is what makes the reconnect a
+                # a watcher that lags has events of its own still to come:
+                # a bookmark at the pump's revision would let it resume
+                # past them. It gets its bookmarks again once it is level.
+                if wr.bookmarks and not wr.watch.stopped \
+                        and wr.since >= self._dispatched_rev:
+                    # `since` is at/above the pump's dispatched revision
+                    # here and never ABOVE what was broadcast to this
+                    # watcher: advertising the compaction floor itself
+                    # when it outran the pump would hand out a resume
+                    # token that silently skips events destroyed before
+                    # they were ever broadcast. For a compaction at <=
+                    # dispatched_rev (compact_to's contract for the seam
+                    # and drills) this value already sits at/above the
+                    # new floor, which is what makes the reconnect a
                     # resume; a floor beyond the pump leaves tokens below
                     # it, and the next resume earns its honest 410.
-                    rv = max(wr.since, self._dispatched_rev)
-                    if wr.watch.send(mwatch.Event(mwatch.BOOKMARK, {
+                    if wr.watch.offer(mwatch.Event(mwatch.BOOKMARK, {
                             "kind": "Bookmark", "apiVersion": "v1",
-                            "metadata": {"resourceVersion": str(rv)}}),
-                            timeout=0):
+                            "metadata": {"resourceVersion": str(wr.since)}})):
                         self.bookmarks_sent += 1
                         if trigger == "compaction":
                             self.compaction_bookmarks += 1
                         WATCH_BOOKMARKS_SENT.inc(trigger=trigger)
-                    else:
-                        # a bookmark landing on a FULL buffer is the same
-                        # deaf consumer _deliver evicts — it must get the
-                        # same too-old terminal + metric, not a silent
-                        # stop that reads as a clean EOF
-                        self._evict_if_deaf(wr, at_rev=rv)
 
     def _export_depths(self) -> None:
         """Deepest live delivery buffer per resource → watch_buffer_depth.
@@ -546,7 +687,10 @@ class Storage:
     def _dispatch_loop(self) -> None:
         last_bm = time.monotonic()
         while not self._stop.is_set():
-            rev = self.kv.wait(self._dispatched_rev, timeout=0.25)
+            # a watcher behind the pump is topped up as its consumer
+            # makes room, writes or no writes: a short turn while one lags
+            rev = self.kv.wait(self._dispatched_rev,
+                               timeout=0.02 if self._lagging else 0.25)
             if faultline.should("watch.compact", "floor"):
                 # chaos (ISSUE 13): a compaction storm hitting mid-stream —
                 # a REAL compaction at the pump's own dispatched revision
@@ -560,43 +704,78 @@ class Storage:
             if time.monotonic() - last_bm >= self._bookmark_interval:
                 last_bm = time.monotonic()
                 self._send_bookmarks(trigger="timer")
-            if rev <= self._dispatched_rev:
-                continue
-            try:
-                events = self.kv.events_since(self._dispatched_rev, "")
-            except native.CompactedError:
-                # the pump fell behind compaction: watchers have an
-                # unrecoverable gap — error them all out so clients relist
-                # (the reference terminates such watchers, cacher.go)
-                with self._watch_mu:
-                    gone = errors.new_gone(
-                        "watch events compacted away; relist required")
-                    for wr in self._watchers:
-                        wr.watch.terminate(
-                            mwatch.Event(mwatch.ERROR, gone.status()))
-                    self._watchers.clear()
-                    self._dispatched_rev = self.kv.rev()
-                    # the compacted-away events never reached the ring: the
-                    # cache has a GAP, so its window must restart at now —
-                    # otherwise a later resume would be served an incomplete
-                    # history instead of falling through to a 410
-                    self.watch_cache = WatchCache(
-                        horizon=self._dispatched_rev)
-                continue
+            events = self._read_log(rev) if rev > self._dispatched_rev else ()
             with self._watch_mu:
-                cached = [self._to_cached(ev) for ev in events]  # decode ONCE
-                for ce in cached:
-                    self.watch_cache.add(ce)
+                if events:
+                    self._broadcast(events)
                 live = []
                 for wr in self._watchers:
-                    if wr.watch.stopped:
-                        continue
-                    for ce in cached:
-                        if ce.rev > wr.since and ce.key.startswith(wr.prefix):
-                            self._deliver(wr, ce)
+                    try:
+                        self._top_up(wr)
+                    except native.CompactedError:
+                        # compaction took events this watcher was still
+                        # owed: the one real gap, and only its own
+                        wr.watch.terminate(mwatch.Event(
+                            mwatch.ERROR, _too_old_status(
+                                f"{wr.since} (compacted at "
+                                f"{self.kv.compacted_rev()})")))
                     if not wr.watch.stopped:
                         live.append(wr)
                 self._watchers = live
+                self._lagging = any(wr.since < self._dispatched_rev
+                                    for wr in live)
                 self._export_depths()
-                if events:
-                    self._dispatched_rev = max(e.rev for e in events)
+            if self._pace_waiting:
+                with self._pace_cv:
+                    self._pace_cv.notify_all()
+
+    def _read_log(self, head: int):
+        """Every event past the dispatched revision, and the pump's lag as
+        this turn found it. Empty when compaction outran the pump: every
+        watcher has then been sent to relist."""
+        lag = head - self._dispatched_rev
+        for mark in self._lag_marks:
+            mark[0] = max(mark[0], lag)
+        WATCH_PUMP_LAG.set(lag)
+        try:
+            events = self.kv.events_since(self._dispatched_rev, "")
+        except native.CompactedError:
+            # the pump fell behind compaction: watchers have an
+            # unrecoverable gap — error them all out so clients relist
+            # (the reference terminates such watchers, cacher.go)
+            with self._watch_mu:
+                gone = errors.new_gone(
+                    "watch events compacted away; relist required")
+                for wr in self._watchers:
+                    wr.watch.terminate(
+                        mwatch.Event(mwatch.ERROR, gone.status()))
+                self._watchers.clear()
+                self._dispatched_rev = self.kv.rev()
+                # the compacted-away events never reached the ring: the
+                # cache has a GAP, so its window must restart at now —
+                # otherwise a later resume would be served an incomplete
+                # history instead of falling through to a 410
+                self.watch_cache = WatchCache(
+                    horizon=self._dispatched_rev)
+            return ()
+        if len(events) > self.pump_batch_max:
+            self.pump_batch_max = len(events)
+            WATCH_PUMP_BATCH_MAX.set(len(events))
+        return events
+
+    def _broadcast(self, events) -> None:
+        """One turn's events, decoded once, into the cacher ring and to each
+        watcher that is level with the pump, as far as its buffer has room
+        (one that fills lags from there and is topped up in turns of its
+        own). Watch lock held."""
+        cached = [self._to_cached(ev) for ev in events]
+        for ce in cached:
+            self.watch_cache.add(ce)
+        head = cached[-1].rev
+        for wr in self._watchers:
+            # level with the pump (or registered "from now", ahead of it):
+            # this batch is its next; one that lags is owed older events
+            # first
+            if wr.since >= self._dispatched_rev and not wr.watch.stopped:
+                self._feed(wr, cached, head)
+        self._dispatched_rev = head

@@ -22,6 +22,7 @@
 // events. Integers are host-endian int64. Buffers are malloc'd; callers free
 // via kv_buf_free.
 
+#include <algorithm>
 #include <condition_variable>
 #include <cstdint>
 #include <cstring>
@@ -216,23 +217,29 @@ int64_t kv_count(void* h, const char* prefix) {
   return n;
 }
 
-// Events with rev > since_rev matching prefix. Returns count, or -1 if
-// since_rev predates compaction (watcher must relist — the 410 Gone path).
+// Events with rev > since_rev matching prefix, oldest first, at most `limit`
+// of them (0 = all). Returns count, or -1 if since_rev predates compaction
+// (watcher must relist — the 410 Gone path). Revisions rise along the log,
+// so the scan starts at since_rev by bisection and a bounded read costs
+// what it returns, not the log's length.
 int64_t kv_events_since(void* h, int64_t since_rev, const char* prefix,
-                        char** out, int64_t* out_len) {
+                        int64_t limit, char** out, int64_t* out_len) {
   Store* s = static_cast<Store*>(h);
   std::lock_guard<std::mutex> lk(s->mu);
   if (since_rev < s->compacted_rev) return -1;
   BufWriter w;
   int64_t n = 0;
-  for (const Event& e : s->events) {
-    if (e.rev <= since_rev) continue;
+  auto it = std::upper_bound(
+      s->events.begin(), s->events.end(), since_rev,
+      [](int64_t rev, const Event& e) { return rev < e.rev; });
+  for (; it != s->events.end(); ++it) {
+    const Event& e = *it;
     if (!has_prefix(e.key, prefix)) continue;
     w.i64(e.rev);
     w.i64(e.type);
     w.bytes(e.key);
     w.bytes(e.value);
-    n++;
+    if (++n == limit) break;
   }
   *out = w.out(out_len);
   return n;

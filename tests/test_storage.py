@@ -335,6 +335,12 @@ class TestWatchCache:
             for i in range(4):
                 storage.create(f"/registry/pods/default/p{i}", _pod(f"p{i}"),
                                "pods")
+            # the resume is of a stream the pump had broadcast in full
+            # (behind the pump there is nothing to catch up on yet)
+            deadline = time.monotonic() + 5
+            while storage.dispatched_rev < storage.kv.rev() \
+                    and time.monotonic() < deadline:
+                time.sleep(0.01)
             # shrink the window so rev 1 predates the horizon
             storage.watch_cache = WatchCache(horizon=storage.kv.rev())
             before = storage.watch_cache.storage_fallbacks

@@ -939,7 +939,7 @@ def _event_server(clk, write_s=0.0):
 
     srv, s = _loop_server(clk)
     srv.pod_informer = types.SimpleNamespace(
-        stop=lambda: None, lister=types.SimpleNamespace(
+        stop=lambda: None, relists=0, lister=types.SimpleNamespace(
             get=lambda ns, name: {"kind": "Pod", "metadata": {
                 "name": name, "namespace": ns, "uid": f"uid-{name}"}}))
     gate = threading.Event()

@@ -27,6 +27,7 @@ import time
 
 import pytest
 
+from kubernetes_tpu.machinery import errors
 from kubernetes_tpu.machinery import watch as mwatch
 from kubernetes_tpu.storage.native import PyKV
 from kubernetes_tpu.storage.store import Storage
@@ -117,26 +118,54 @@ class TestStorageWatchPlane:
         yield st
         st.close()
 
-    def test_deaf_watcher_evicted_with_too_old(self, st):
+    @pytest.mark.parametrize("compacted, code, word", [
+        (False, 504, "resume from resourceVersion"),
+        (True, 410, "too old")])
+    def test_deaf_watcher_cut_off_resumably_unless_compacted(
+            self, st, compacted, code, word):
+        """A consumer that never reads is cut off once its buffer has been
+        full for the deaf budget: with a Status it can RESUME from while
+        the events it is owed exist, with 410 only beneath the floor."""
+        st.deaf_after_s = 3600
         w = st.watch("/registry/pods/", buffer=4)
         for i in range(20):
             st.create(f"/registry/pods/default/p{i}",
                       {"metadata": {"name": f"p{i}"}})
+        assert wait_until(lambda: st.dispatched_rev >= st.kv.rev(), 5)
+        assert not w.stopped and st.deaf_evictions == 0  # late, not cut off
+        if compacted:
+            st.compact_to(st.kv.rev())
+        st.deaf_after_s = 0.1
         assert wait_until(lambda: w.stopped, 5), "deaf watcher not evicted"
-        assert st.deaf_evictions >= 1
-        # drain: buffered events, then the terminal too-old ERROR
+        assert st.deaf_evictions == 1
+        # drain: the buffered events, then the terminal ERROR
         evs = []
         while True:
             ev = w.next(timeout=0.2)
             if ev is None:
                 break
             evs.append(ev)
-        assert evs, "buffered events lost"
+        assert [e.object["metadata"]["name"] for e in evs[:-1]] == \
+            [f"p{i}" for i in range(4)], "buffered events lost"
         assert evs[-1].type == mwatch.ERROR
-        assert evs[-1].object.get("code") == 410
-        assert "too old" in evs[-1].object.get("message", "")
+        assert evs[-1].object.get("code") == code
+        assert word in evs[-1].object.get("message", "")
+        rv = evs[-2].object["metadata"]["resourceVersion"]
+        if compacted:
+            with pytest.raises(errors.StatusError) as ei:
+                st.watch("/registry/pods/", since_rv=rv)
+            assert ei.value.code == 410
+            return
+        # the resume the Status pointed at: the other 16, once, in order
+        w2 = st.watch("/registry/pods/", since_rv=rv, buffer=64)
+        got = [w2.next(timeout=2).object["metadata"]["name"]
+               for _ in range(16)]
+        assert got == [f"p{i}" for i in range(4, 20)]
+        assert w2.next(timeout=0.1) is None
+        w2.stop()
 
     def test_broadcast_survives_deaf_sibling(self, st):
+        st.deaf_after_s = 0.2
         deaf = st.watch("/registry/pods/", buffer=4)
         live = st.watch("/registry/pods/", buffer=1024)
         got = []
@@ -148,9 +177,155 @@ class TestStorageWatchPlane:
                       {"metadata": {"name": f"q{i}"}})
         assert wait_until(lambda: len(got) >= 50, 10), \
             f"live watcher starved behind deaf sibling: {len(got)}/50"
-        assert deaf.stopped and st.deaf_evictions >= 1
+        assert wait_until(lambda: deaf.stopped, 5) \
+            and st.deaf_evictions >= 1
         live.stop()
         t.join(timeout=3)
+
+    @pytest.mark.parametrize("source", ["ring", "log"])
+    @pytest.mark.parametrize("kv", ["py", "native"])
+    @pytest.mark.parametrize("start", ["live", "resume"])
+    def test_burst_of_ten_buffers_arrives_late_never_less(
+            self, kv, source, start):
+        """A burst of 10 x the buffer while the consumer drains at its own
+        pace: every event once, in order, nobody cut off, no 410 — from
+        the cacher ring, and beneath its horizon from the KV log; for a
+        watcher that was live through the burst and for one that resumes
+        from before it (the catch-up comes in slices too)."""
+        from kubernetes_tpu.storage import native
+        from kubernetes_tpu.storage.cacher import WatchCache
+
+        st = Storage(kv=PyKV() if kv == "py" else native.new_kv(),
+                     watch_buffer=64, bookmark_interval=3600)
+        try:
+            if source == "log":
+                st.watch_cache = WatchCache(capacity=16,
+                                            horizon=st.dispatched_rev)
+            st.create("/registry/nodes/n0", {"metadata": {"name": "n0"}})
+            rv0 = str(st.kv.rev())   # "0" would mean "from now"
+            read, other = st.watch_plane_reader(), st.watch_plane_reader()
+            reads = []   # what each read of the KV log asked for and got
+            kv_events_since = st.kv.events_since
+
+            def counting(since, prefix="", limit=0):
+                out = kv_events_since(since, prefix, limit)
+                if prefix:   # a watcher's refill, not the pump's own read
+                    reads.append((limit, len(out)))
+                return out
+
+            st.kv.events_since = counting
+            caught_up = st.watch_cache.hits + st.watch_cache.storage_fallbacks
+            fast = st.watch("/registry/pods/", buffer=4096)
+            w = st.watch("/registry/pods/") if start == "live" else None
+            for i in range(640):
+                st.create(f"/registry/pods/default/b{i}",
+                          {"metadata": {"name": f"b{i}"}})
+            if w is None:
+                assert wait_until(lambda: st.dispatched_rev >= st.kv.rev(), 5)
+                w = st.watch("/registry/pods/", since_rv=rv0)
+            got = []
+            while len(got) < 640:
+                ev = w.next(timeout=5)
+                assert ev is not None, f"stalled at {len(got)}"
+                assert ev.type != mwatch.ERROR, ev.object
+                got.append(ev.object["metadata"]["name"])
+                if len(got) % 50 == 0:
+                    time.sleep(0.01)   # its own pace
+            assert got == [f"b{i}" for i in range(640)]
+            assert w.next(timeout=0.1) is None and not w.stopped
+            assert st.deaf_evictions == 0
+            # the sibling with room never waited for the slow one
+            assert fast.depth() == 640
+            # and the stream goes on where the catch-up ended
+            st.create("/registry/pods/default/after",
+                      {"metadata": {"name": "after"}})
+            assert w.next(timeout=5).object["metadata"]["name"] == "after"
+            # ten refills and more are ONE catch-up on the cache's counters,
+            # and beneath the ring's horizon none read more of the log than
+            # the buffer had room for (and one, to know whether it is level)
+            assert st.watch_cache.hits + st.watch_cache.storage_fallbacks \
+                == caught_up + 1
+            assert (len(reads) >= 10) == (source == "log")
+            assert all(0 < limit <= 65 and n <= limit for limit, n in reads)
+            # each reader keeps its own high-water mark of the pump's lag
+            assert st.pump_batch_max >= 1
+            first = read()
+            assert first["watch_evictions"] == 0 and first["pump_lag_max"] >= 1
+            assert read()["pump_lag_max"] == 0
+            assert other()["pump_lag_max"] >= 1
+        finally:
+            st.close()
+
+    def test_a_writer_that_outruns_the_pump_waits_for_it_bounded(self):
+        """Flow control at the source: with the pump held back, a write
+        past the high-water mark waits — once, at most PACE_WAIT_S: a pump
+        that a whole wait saw stand still costs writers nothing more until
+        it moves — and goes on at once when the pump is level again."""
+        from kubernetes_tpu.storage import store as store_mod
+
+        st = Storage(kv=PyKV(), watch_buffer=64, bookmark_interval=3600)
+        try:
+            assert st._pace_high == 8
+            w = st.watch("/registry/pods/", buffer=1024)
+            for episode in (1, 2):   # the pump moved in between: armed again
+                took = []
+                with st._watch_mu:   # the pump cannot broadcast
+                    for i in range(12):
+                        t0 = time.perf_counter()
+                        st.create(f"/registry/pods/default/w{episode}-{i}",
+                                  {"metadata": {"name": f"w{episode}-{i}"}})
+                        took.append(time.perf_counter() - t0)
+                assert st.paced_writes == episode        # the ninth write
+                assert store_mod.PACE_WAIT_S * 0.9 <= took[8] < 1.0
+                assert max(took[:8] + took[9:]) < store_mod.PACE_WAIT_S / 2
+                assert wait_until(lambda: w.depth() == 12 * episode, 5)
+            t0 = time.perf_counter()
+            st.create("/registry/pods/default/level",
+                      {"metadata": {"name": "level"}})
+            assert time.perf_counter() - t0 < store_mod.PACE_WAIT_S / 2
+            assert st.paced_writes == 2
+        finally:
+            st.close()
+
+    def test_a_dead_pump_costs_writers_nothing(self):
+        st = Storage(kv=PyKV(), watch_buffer=64, bookmark_interval=3600)
+        try:
+            st._stop.set()
+            st._pump.join(timeout=2)
+            assert not st._pump.is_alive()
+            t0 = time.perf_counter()
+            for i in range(100):
+                st.create(f"/registry/pods/default/d{i}",
+                          {"metadata": {"name": f"d{i}"}})
+            assert time.perf_counter() - t0 < 0.05 * 100 / 10
+            assert st.paced_writes == 0
+        finally:
+            st.close()
+
+    def test_compaction_gap_costs_only_the_watcher_that_is_owed(self, st):
+        """A real gap still gives 410: compaction takes events a lagging
+        watcher had not been handed yet. It alone is sent to relist."""
+        st.deaf_after_s = 3600
+        slow = st.watch("/registry/pods/", buffer=4)
+        live = st.watch("/registry/pods/", buffer=1024)
+        for i in range(20):
+            st.create(f"/registry/pods/default/g{i}",
+                      {"metadata": {"name": f"g{i}"}})
+        assert wait_until(lambda: live.depth() == 20, 5)
+        st.compact_to(st.kv.rev())
+        names = []
+        while True:   # it drains what it holds, then learns of the gap
+            ev = slow.next(timeout=2)
+            assert ev is not None
+            if ev.type == mwatch.ERROR:
+                assert ev.object["code"] == 410
+                break
+            names.append(ev.object["metadata"]["name"])
+        assert names == [f"g{i}" for i in range(4)]
+        assert st.deaf_evictions == 0   # a gap is not deafness
+        st.create("/registry/pods/default/later",
+                  {"metadata": {"name": "later"}})
+        assert wait_until(lambda: live.depth() == 21, 5) and not live.stopped
 
     def test_compaction_boundary_bookmark(self, st):
         wb = st.watch("/registry/pods/", bookmarks=True)
@@ -859,3 +1034,122 @@ class TestFleetWatchPlane:
             w.stop()
         finally:
             st.close()
+
+
+# --------------------------------------------------------------------- #
+# one long wave through the watch plane (ISSUE 26): the density deployment
+# at its rehearsal size, the store's buffers smaller than the wave
+# --------------------------------------------------------------------- #
+
+
+class TestOneLongWaveThroughTheWatchPlane:
+    def test_wave_larger_than_the_buffers_costs_no_relist(self):
+        """800 plain pods on 64 nodes bind in ONE wave whose Bindings
+        outnumber every watcher's buffer six times over. The scheduler's
+        own informer is blocked behind the wave for all of it and a
+        client's watch drains as it can: neither relists, neither is cut
+        off, every pod is bound once, validly, and the wave's record says
+        what the watch plane did."""
+        from kubernetes_tpu.api import semantics as sem
+        from kubernetes_tpu.api.types import Resources
+        from kubernetes_tpu.api.v1 import node_from_v1, pod_from_v1
+        from kubernetes_tpu.apiserver import APIServer
+        from kubernetes_tpu.client import Client
+        from kubernetes_tpu.sched.ledger import BindIntentLedger
+        from kubernetes_tpu.sched.scheduler import Scheduler
+        from kubernetes_tpu.sched.server import APIBinder, SchedulerServer
+        from kubernetes_tpu.state.dims import Dims
+
+        n_nodes, n_pods, buffer = 64, 800, 128
+        tiers = [("100m", "128Mi"), ("250m", "512Mi"), ("500m", "1Gi"),
+                 ("1", "2Gi")]
+        api = APIServer(watch_buffer=buffer)
+        client = Client.local(api)
+        srv = None
+        try:
+            for i in range(n_nodes):
+                client.nodes.create({
+                    "apiVersion": "v1", "kind": "Node",
+                    "metadata": {"name": f"node-{i}"},
+                    "status": {"allocatable": {
+                        "cpu": "32", "memory": "128Gi", "pods": "110"}}})
+            for i in range(n_pods):
+                cpu, mem = tiers[i % 4]
+                client.pods.create({
+                    "apiVersion": "v1", "kind": "Pod",
+                    "metadata": {"name": f"job-{i}", "namespace": "default"},
+                    "spec": {"containers": [{
+                        "name": "c", "image": "i", "resources": {
+                            "requests": {"cpu": cpu, "memory": mem}}}]}})
+            # a client's own watch, as the benchmark's: a stream the store
+            # cuts off would end it, and a relist would be the only way on
+            listing = client.pods.list("default")
+            cw = client.pods.watch(
+                "default",
+                resource_version=listing["metadata"]["resourceVersion"])
+            seen, stream_errors = [], []
+
+            def consume():
+                for ev in cw:
+                    if ev.type == mwatch.ERROR:
+                        stream_errors.append(ev.object)
+                    elif ev.object.get("spec", {}).get("nodeName"):
+                        seen.append((ev.object["metadata"]["name"],
+                                     ev.object["spec"]["nodeName"]))
+
+            t = threading.Thread(target=consume, daemon=True)
+            t.start()
+            dims = Dims(N=64, D=64, P=1024, SC=64, SL=64).grown_for(E=4096)
+            sched = Scheduler(binder=APIBinder(client), batch_size=dims.P,
+                              base_dims=dims)
+            srv = SchedulerServer(
+                client, scheduler=sched, cycle_interval=0.02,
+                batch_window=0.15,
+                ledger=BindIntentLedger(api.storage, identity="t"))
+            srv.start()
+            relists0 = srv.pod_informer.relists + srv.node_informer.relists
+            assert wait_until(lambda: len(seen) >= n_pods, 120, 0.05), \
+                f"{len(seen)} of {n_pods} Bindings reached the client"
+            # the informer's confirmations: every assume confirmed
+            assert wait_until(
+                lambda: sched.cache.drain_confirm_waits()[1] == 0, 30, 0.05)
+            waves = [r for r in sched.telemetry.recorder.records()
+                     if (r.get("stats") or {}).get("attempted")]
+            assert [w["stats"]["scheduled"] for w in waves] == [n_pods], \
+                "the backlog was to drain in ONE wave"
+            rec = waves[0]
+            assert rec["informer_relists"] == 0
+            assert rec["watch_evictions"] == 0
+            assert rec["pump_lag_max"] >= 0
+            # across the wave and the catch-up after it: nobody relisted,
+            # nobody was cut off, and the client's stream never ended
+            assert srv.pod_informer.relists + srv.node_informer.relists \
+                == relists0
+            assert api.storage.deaf_evictions == 0
+            assert not stream_errors and not cw.stopped
+            assert srv.pod_informer.resumes == 0
+            # every pod bound once, where the apiserver says, validly
+            assert len(seen) == n_pods == len({n for n, _ in seen})
+            pods = client.pods.list("default")["items"]
+            assert {(p["metadata"]["name"], p["spec"].get("nodeName"))
+                    for p in pods} == set(seen)
+            nodes = {n["metadata"]["name"]: node_from_v1(n)
+                     for n in client.nodes.list()["items"]}
+            used = {n: (0, 0, 0) for n in nodes}
+            for obj in pods:
+                pod = pod_from_v1(obj)
+                cpu, mem, count = used[pod.node_name]
+                ok, why = sem.pod_fits_resources(
+                    pod, nodes[pod.node_name],
+                    Resources(milli_cpu=cpu, memory_kib=mem), count)
+                assert ok, (pod.key, why)
+                used[pod.node_name] = (cpu + pod.requests.milli_cpu,
+                                       mem + pod.requests.memory_kib,
+                                       count + 1)
+            assert sched.ledger.unretired() == []
+            cw.stop()
+            t.join(timeout=3)
+        finally:
+            if srv is not None:
+                srv.stop()
+            api.close()
