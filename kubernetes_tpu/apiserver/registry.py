@@ -418,9 +418,12 @@ class Store:
                 return False
             return True
 
-        return self.storage.watch(self.prefix_for(namespace),
-                                  since_rv=resource_version, predicate=pred,
-                                  bookmarks=allow_bookmarks)
+        # a stream that selects nothing away has no predicate: the store
+        # then never decodes an event on its behalf
+        return self.storage.watch(
+            self.prefix_for(namespace), since_rv=resource_version,
+            predicate=pred if lsel is not None or freqs else None,
+            bookmarks=allow_bookmarks)
 
 
 def _spec_changed(old: Obj, new: Obj) -> bool:

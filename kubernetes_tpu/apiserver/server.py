@@ -435,7 +435,7 @@ class APIServer:
         def apply(o: Obj) -> Obj:
             if not o:
                 raise errors.new_not_found("namespaces", name)
-            o.setdefault("spec", {})["finalizers"] = fins
+            o.setdefault("spec", {})["finalizers"] = list(fins)
             return o
 
         out = st.storage.guaranteed_update(st.key_for("", name), apply,

@@ -305,8 +305,13 @@ class VolumeExpansionController(Controller):
         if not want or want <= have or not pv_name:
             return
         pv = self.pv_informer.lister.get(None, pv_name)
-        if pv is not None and _qty_kib(pv.get("spec", {}).get("capacity", {})
-                                       .get("storage")) < want:
+        if pv is None:
+            # the claim's event can outrun the volume's (each informer has a
+            # stream of its own): marking the claim grown now would leave
+            # the volume small for good. The worker retries, rate-limited.
+            raise LookupError(f"volume {pv_name} of claim {key} not seen yet")
+        if _qty_kib(pv.get("spec", {}).get("capacity", {})
+                    .get("storage")) < want:
             pv = dict(pv)
             pv.setdefault("spec", {}).setdefault("capacity", {})
             pv["spec"]["capacity"]["storage"] = f"{want}Ki"

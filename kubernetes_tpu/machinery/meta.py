@@ -150,9 +150,22 @@ def owner_reference(owner: Obj, controller: bool = True,
     }
 
 
+_ATOMS = frozenset((str, int, float, bool, type(None)))
+
+
 def deep_copy(obj: Obj) -> Obj:
     """DeepCopyObject — generated per-type in the reference; one generic
-    implementation suffices for dict-shaped objects."""
+    implementation suffices for dict-shaped objects. API objects are JSON
+    trees (dicts, lists, scalars), which a plain walk copies several times
+    faster than `copy.deepcopy`; anything else in the tree is deep-copied
+    the general way."""
+    c = obj.__class__
+    if c is dict:
+        return {k: deep_copy(v) for k, v in obj.items()}
+    if c is list:
+        return [deep_copy(v) for v in obj]
+    if c in _ATOMS:
+        return obj
     return copy.deepcopy(obj)
 
 

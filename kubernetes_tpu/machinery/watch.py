@@ -11,6 +11,11 @@ it leave a terminal Status event (e.g. 410 "too old resource version") that
 the consumer receives after draining whatever it had buffered — so even a
 slow-but-alive client learns WHY its stream died instead of seeing a bare
 socket EOF.
+
+A producer may buffer, in place of an `Event`, anything with an `event()`
+method that builds one (the store's `CachedEvent`): the consumer's side
+calls it as it takes the item, on the consumer's own thread, so what each
+stream receives is built once per reader and only when it reads.
 """
 
 from __future__ import annotations
@@ -63,11 +68,12 @@ class Watch:
             self.stop()
             return False
 
-    def offer(self, event: Event) -> bool:
+    def offer(self, event: Any) -> bool:
         """Producer side, for a producer that keeps its own place in the
-        stream (the store's pump): put the event if there is room and say
-        whether it went in. A full buffer costs the consumer nothing — the
-        producer comes back with the same event later."""
+        stream (the store's pump): put the event (or what builds it, see
+        the module's note) if there is room and say whether it went in. A
+        full buffer costs the consumer nothing — the producer comes back
+        with the same event later."""
         if self._stopped.is_set():
             return False
         try:
@@ -108,6 +114,10 @@ class Watch:
         dispatcher exports as `watch_buffer_depth`."""
         return self._q.qsize()
 
+    @staticmethod
+    def _taken(item: Any) -> Event:
+        return item if item.__class__ is Event else item.event()
+
     def __iter__(self) -> Iterator[Event]:
         while True:
             item = self._q.get()
@@ -116,7 +126,7 @@ class Watch:
                 if t is not None:
                     yield t
                 return
-            yield item
+            yield self._taken(item)
             if self._stopped.is_set() and self._q.empty():
                 t = self._take_terminal()
                 if t is not None:
@@ -134,4 +144,4 @@ class Watch:
             return None
         if item is self._SENTINEL:
             return self._take_terminal()
-        return item
+        return self._taken(item)

@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Protocol, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Protocol, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -246,9 +246,10 @@ class Scheduler:
         self.events_pending: Optional[Callable[[], int]] = None
         # the watch plane since the previous wave's end (the server's
         # `_watch_plane`): `informer_relists` of its informers and, where
-        # the store is in this process, the pump's `pump_lag_max` and the
+        # the store is in this process, the pump's `pump_lag_max`, what it
+        # cost (`pump_busy_s`, `pump_turns`, `pump_events`) and the
         # `watch_evictions`. Read at a wave's END onto its record.
-        self.watch_plane: Optional[Callable[[], Dict[str, int]]] = None
+        self.watch_plane: Optional[Callable[[], Dict[str, Any]]] = None
         # streaming micro-waves (ISSUE 18): when the live backlog is
         # nothing but a handful of FRESH watch deltas, admit them through
         # a small fixed-capacity wave grafted onto the resident snapshot

@@ -521,15 +521,16 @@ class SchedulerServer:
         with self._handling():
             self.scheduler.on_node_delete(meta.name(obj))
 
-    def _watch_plane(self) -> Dict[str, int]:
+    def _watch_plane(self) -> Dict[str, Any]:
         """What the watch plane did since the previous call, for a wave's
         record: `informer_relists` of this server's informers (full
         list+replace rounds; a healthy wave costs none) and, where the
         client reads the store's counters (`Client.local`: the store runs
-        in this process), the store's `watch_evictions` and the largest lag
-        of its pump, `pump_lag_max`. A store in another process leaves its
-        two out. `start()` resets it once the informers' initial lists are
-        in."""
+        in this process), the store's `watch_evictions`, the largest lag of
+        its pump, `pump_lag_max`, and what the pump cost: `pump_busy_s` (its
+        thread's CPU seconds), `pump_turns`, `pump_events`. A store in
+        another process leaves these out. `start()` resets it once the
+        informers' initial lists are in."""
         now = sum(inf.relists for inf in (
             self.pod_informer, self.node_informer, self.pdb_informer)
             if inf is not None)

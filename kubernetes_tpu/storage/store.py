@@ -9,6 +9,15 @@ a given revision with 410-Gone on compaction. One dispatcher thread pumps kv
 events to all registered watchers (role of etcd watch streams + the apiserver
 Cacher, storage/cacher/cacher.go:309).
 
+Who owns an object (ISSUE 28). The store keeps BYTES, never a reference to
+an object: what `get` / `list` / `delete` return, what `guaranteed_update`
+hands `update_fn` and what it returns are freshly decoded or the caller's
+own, so nothing here copies an object. `create(key, obj)` and the object
+`update_fn` returns are given UP to the store for the length of the call:
+it encodes them, stamps the new resourceVersion on them and returns them.
+A watch stream's consumer receives an object of its own, decoded from the
+event's bytes as the consumer takes it (`storage/cacher.py`).
+
 Watch-plane contract (ISSUE 13, the cacher's delivery discipline):
 
   * every watcher owns a BOUNDED buffer (`KTPU_WATCH_BUFFER`, default 8192)
@@ -29,7 +38,13 @@ Watch-plane contract (ISSUE 13, the cacher's delivery discipline):
     resume instead of relisting;
   * `drop_watchers` (the apiserver-restart seam) emits a terminal 503
     Status BEFORE closing each stream — clients resume by resourceVersion
-    rather than discovering death by socket EOF and blind-relisting.
+    rather than discovering death by socket EOF and blind-relisting;
+  * the pump's fixed cost is paid per TURN, and a turn is many events long
+    while writes stream in (ISSUE 28): a write on a quiet store is
+    broadcast at once; a turn that follows the previous one within
+    `GATHER_S` found a writer at work and, unless `GATHER_EVENTS` wait
+    already, sleeps `GATHER_S` to let the log gather first — off the
+    interpreter the writer needs.
 """
 
 from __future__ import annotations
@@ -47,6 +62,7 @@ from kubernetes_tpu.machinery import errors, meta
 from kubernetes_tpu.machinery import watch as mwatch
 from kubernetes_tpu.storage import native
 from kubernetes_tpu.storage.cacher import CachedEvent, WatchCache
+from kubernetes_tpu.storage.cacher import decode as _decode
 from kubernetes_tpu.utils import faultline
 
 Obj = Dict[str, Any]
@@ -72,6 +88,19 @@ WATCH_PUMP_LAG = _REG.gauge(
 WATCH_PUMP_BATCH_MAX = _REG.gauge(
     "storage_watch_pump_batch_max_events",
     "Largest number of events one turn of the watch pump broadcast")
+# what the broadcast costs and how well it batches: the pump thread's own CPU
+# seconds, its turns that broadcast, and the events they carried (events per
+# turn is how often the gathering engages)
+WATCH_PUMP_BUSY = _REG.counter(
+    "storage_watch_pump_busy_seconds_total",
+    "CPU seconds of the watch pump's own thread (time.thread_time), "
+    "read once per turn")
+WATCH_PUMP_TURNS = _REG.counter(
+    "storage_watch_pump_turns_total",
+    "Turns of the watch pump that broadcast at least one event")
+WATCH_PUMP_EVENTS = _REG.counter(
+    "storage_watch_pump_events_total",
+    "Events the watch pump broadcast")
 WATCH_BOOKMARKS_SENT = _REG.counter(
     "apiserver_watch_bookmarks_sent_total",
     "BOOKMARK events sent to opted-in watchers, by trigger "
@@ -87,6 +116,9 @@ TXN_DURATION = _REG.histogram(
     buckets=(0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
              0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5))
 _OP_CREATE, _OP_UPDATE, _OP_DELETE = ("create",), ("update",), ("delete",)
+_EVENT_TYPES = {native.EVENT_CREATE: mwatch.ADDED,
+                native.EVENT_PUT: mwatch.MODIFIED,
+                native.EVENT_DELETE: mwatch.DELETED}
 #: a watcher whose buffer has been full this long, with events waiting, is
 #: deaf. Waiting costs the pump nothing (it never blocks on a watcher, and a
 #: lagging one costs a bounded read a turn), so the budget is generous: a
@@ -95,6 +127,16 @@ _OP_CREATE, _OP_UPDATE, _OP_DELETE = ("create",), ("update",), ("delete",)
 DEAF_AFTER_S = 60.0
 #: the longest one write waits for the watch pump (`Storage._pace`)
 PACE_WAIT_S = 0.1
+#: a turn of the pump that starts less than this long after the previous one
+#: ended found a stream of writes; unless GATHER_EVENTS events wait already,
+#: it lets the log gather this long before it reads. A watcher under a
+#: stream of writes hears of one this much later, in exchange for a pump that
+#: costs the writer's interpreter a fixed price per turn and not per event.
+GATHER_S = 0.005
+GATHER_EVENTS = 64
+#: the buffer-depth gauges are scraped, not awaited: exported by quiet turns
+#: and, while events flow, no more often than this
+EXPORT_EVERY_S = 0.1
 
 
 def _txn_done(op: Tuple[str], t0: float, kv_s: float = 0.0,
@@ -104,7 +146,7 @@ def _txn_done(op: Tuple[str], t0: float, kv_s: float = 0.0,
     child of the span that caused it, with the seconds of it spent inside
     the KV backend's calls as `store.txn/kv` and those it waited for the
     watch pump as `store.txn/pace` (the rest is this module's Python:
-    decode, copies, the caller's transform, encode)."""
+    decode, the caller's transform, encode)."""
     dt = time.perf_counter() - t0
     TXN_DURATION.observe_at(op, dt)
     tr = trace.current()
@@ -128,18 +170,16 @@ def _parse_watch_buffer(value, default: int = 8192) -> int:
     return max(1, min(n, 1 << 20))
 
 
+# json.dumps with these arguments builds this encoder anew on every call
+_dumps = json.JSONEncoder(separators=(",", ":"), sort_keys=True).encode
+
+
 def _encode(obj: Obj) -> bytes:
     obj = dict(obj)
     md = dict(obj.get("metadata") or {})
     md.pop("resourceVersion", None)
     obj["metadata"] = md
-    return json.dumps(obj, separators=(",", ":"), sort_keys=True).encode()
-
-
-def _decode(data: bytes, rev: int) -> Obj:
-    obj = json.loads(data)
-    meta.set_resource_version(obj, str(rev))
-    return obj
+    return _dumps(obj).encode()
 
 
 def _resource_of(prefix: str) -> str:
@@ -207,7 +247,7 @@ class Storage:
             bookmark_interval if bookmark_interval is not None
             else os.environ.get("KTPU_WATCH_BOOKMARK_INTERVAL", "10"))
         self._dispatched_rev = self.kv.rev()
-        # Cacher tier (storage/cacher.py ⇔ cacher.go:309): the pump decodes
+        # Cacher tier (storage/cacher.py ⇔ cacher.go:309): the pump puts
         # each event once into this ring; watcher catch-up replays from it so
         # storage reads stay independent of watcher count
         self.watch_cache = WatchCache(horizon=self._dispatched_rev)
@@ -223,6 +263,11 @@ class Storage:
         # largest batch one turn broadcast
         self._lag_marks: Tuple[List[int], ...] = ()
         self.pump_batch_max = 0
+        # the pump's own CPU seconds, its turns that broadcast and the
+        # events they carried, written by the pump once per turn
+        self.pump_busy_s = 0.0
+        self.pump_turns = 0
+        self.pump_events = 0
         self._lagging = False  # some live watcher is behind the pump
         # flow control at the source (`_pace`): a write that finds the pump
         # more than `_pace_high` events behind waits for it to come within
@@ -305,22 +350,33 @@ class Storage:
         self.paced_writes += 1
         return time.perf_counter() - t0
 
-    def watch_plane_reader(self) -> Callable[[], Dict[str, int]]:
+    def watch_plane_reader(self) -> Callable[[], Dict[str, Any]]:
         """A reader of the watch plane's counters with a baseline of its
         own: each call gives `watch_evictions`, the streams cut off as deaf
-        since its previous call, and `pump_lag_max`, the largest pump lag
-        since then (the lag right now included). A scheduler calls it at
-        each wave's end, so the wave's record says how far the broadcast
-        fell behind the writes while it ran; two readers on one store (an
-        active and a standby scheduler) do not take each other's maxima."""
+        since its previous call, `pump_lag_max`, the largest pump lag
+        since then (the lag right now included), and what the broadcast
+        cost since then: `pump_busy_s`, the pump thread's own CPU seconds
+        (on one interpreter they are seconds the writer did not have),
+        `pump_turns`, its turns that broadcast, and `pump_events`, the
+        events they carried. A scheduler calls it at each wave's end, so
+        the wave's record says how far the broadcast fell behind the writes
+        while it ran and what it took from them; two readers on one store
+        (an active and a standby scheduler) do not take each other's
+        maxima."""
         mark, seen = [0], [self.deaf_evictions]
         self._lag_marks += (mark,)
+        pump = [self.pump_busy_s, self.pump_turns, self.pump_events]
 
-        def read() -> Dict[str, int]:
+        def read() -> Dict[str, Any]:
             now = max(self.kv.rev() - self._dispatched_rev, 0)
             top, mark[0] = max(mark[0], now), 0
             cut, seen[0] = self.deaf_evictions - seen[0], self.deaf_evictions
-            return {"watch_evictions": cut, "pump_lag_max": top}
+            was = tuple(pump)
+            pump[:] = self.pump_busy_s, self.pump_turns, self.pump_events
+            return {"watch_evictions": cut, "pump_lag_max": top,
+                    "pump_busy_s": pump[0] - was[0],
+                    "pump_turns": pump[1] - was[1],
+                    "pump_events": pump[2] - was[2]}
 
         return read
 
@@ -338,6 +394,9 @@ class Storage:
     # ------------------------------------------------------------------ #
 
     def create(self, key: str, obj: Obj, resource: str = "object") -> Obj:
+        """Store `obj` under a key that must not exist. The caller gives
+        `obj` up: it is returned, with its new resourceVersion set, and is
+        the caller's again. The store keeps the bytes, never the object."""
         t0 = time.perf_counter()
         kv_s = paced_s = 0.0
         try:
@@ -347,10 +406,9 @@ class Storage:
             kv_s = time.perf_counter() - t1
             if rev < 0:
                 raise errors.new_already_exists(resource, meta.name(obj))
-            out = meta.deep_copy(obj)
-            meta.set_resource_version(out, str(rev))
+            meta.set_resource_version(obj, str(rev))
             paced_s = self._pace(rev)
-            return out
+            return obj
         finally:
             _txn_done(_OP_CREATE, t0, kv_s, paced_s)
 
@@ -405,8 +463,14 @@ class Storage:
                           expected_rv: Optional[str] = None) -> Obj:
         """Retry loop: read → user transform → CAS write (store.go:219-300).
 
-        update_fn receives a deep copy (with resourceVersion set) and returns
-        the new object, or raises to abort.
+        update_fn receives the current object (resourceVersion set; `{}`
+        where `ignore_not_found` found none) and returns the new one, or
+        raises to abort. What it is given is decoded for this attempt
+        alone: it may change it, and return it. What it returns is the
+        store's until the call ends: encoded, stamped with the new
+        resourceVersion and returned, the caller's own from then on. An
+        attempt that loses its CAS reads and decodes again, so a changed
+        object is never handed out twice.
         """
         t0 = time.perf_counter()
         kv_s = [0.0]
@@ -463,7 +527,7 @@ class Storage:
                 import time as _time
 
                 _time.sleep(float(_os.environ.get("KTPU_SLOW_S", "0.2")))
-            updated = update_fn(meta.deep_copy(cur))
+            updated = update_fn(cur)
             if not chaos_cas and faultline.should("store.cas_conflict",
                                                   "guaranteed_update"):
                 # chaos: behave exactly as if a concurrent writer won the
@@ -475,9 +539,8 @@ class Storage:
             rev = self.kv.txn_put(key, cur_mod if cur_mod else 0, data)
             kv_s[0] += time.perf_counter() - t1
             if rev > 0:
-                out = meta.deep_copy(updated)
-                meta.set_resource_version(out, str(rev))
-                return out, rev
+                meta.set_resource_version(updated, str(rev))
+                return updated, rev
             # CAS failure → re-read and retry
 
     # ------------------------------------------------------------------ #
@@ -556,11 +619,7 @@ class Storage:
 
     @staticmethod
     def _to_cached(ev: native.KVEvent) -> CachedEvent:
-        typ = {native.EVENT_CREATE: mwatch.ADDED,
-               native.EVENT_PUT: mwatch.MODIFIED,
-               native.EVENT_DELETE: mwatch.DELETED}[ev.type]
-        return CachedEvent(rev=ev.rev, type=typ, key=ev.key,
-                           obj=_decode(ev.value, ev.rev))
+        return CachedEvent(ev.rev, _EVENT_TYPES[ev.type], ev.key, ev.value)
 
     def _feed(self, wr: _Watcher, events, upto: int) -> None:
         """Hand `events` (revision order) to one watcher, in order, until
@@ -577,10 +636,9 @@ class Storage:
             if wr.predicate is not None and not wr.predicate(ce.obj):
                 wr.since = ce.rev
                 continue
-            # watchers receive a copy so one consumer's mutation can't leak
-            # into another's view of the shared decoded event
-            if free <= 0 or not w.offer(
-                    mwatch.Event(ce.type, meta.deep_copy(ce.obj))):
+            # every buffer holds the one CachedEvent; the consumer's side
+            # decodes an object of its own from it (cacher.CachedEvent.event)
+            if free <= 0 or not w.offer(ce):
                 return
             free -= 1
             wr.since = ce.rev
@@ -686,11 +744,22 @@ class Storage:
 
     def _dispatch_loop(self) -> None:
         last_bm = time.monotonic()
+        turn_end = export_at = 0.0  # last broadcast's end; next depth export
         while not self._stop.is_set():
+            at = self._dispatched_rev
             # a watcher behind the pump is topped up as its consumer
             # makes room, writes or no writes: a short turn while one lags
-            rev = self.kv.wait(self._dispatched_rev,
-                               timeout=0.02 if self._lagging else 0.25)
+            rev = self.kv.wait(at, timeout=0.02 if self._lagging else 0.25)
+            now = time.monotonic()
+            if 0 < rev - at < GATHER_EVENTS and now - turn_end < GATHER_S:
+                # writes stream in (the previous turn has only just ended):
+                # let them gather, asleep and off the writer's interpreter,
+                # and pay this turn once for all. A plain sleep: waiting in
+                # the KV for the count to fill wakes this thread at every
+                # write, which costs the writer a quarter of a Binding
+                self._stop.wait(GATHER_S)
+                rev = self.kv.rev()
+                now = time.monotonic()
             if faultline.should("watch.compact", "floor"):
                 # chaos (ISSUE 13): a compaction storm hitting mid-stream —
                 # a REAL compaction at the pump's own dispatched revision
@@ -701,33 +770,59 @@ class Storage:
                 # resumable. The drill asserts resumes, not relists,
                 # survive this.
                 self.compact_to(self._dispatched_rev)
-            if time.monotonic() - last_bm >= self._bookmark_interval:
-                last_bm = time.monotonic()
+            if now - last_bm >= self._bookmark_interval:
+                last_bm = now
                 self._send_bookmarks(trigger="timer")
-            events = self._read_log(rev) if rev > self._dispatched_rev else ()
+            events = self._read_log(rev) if rev > at else ()
             with self._watch_mu:
-                if events:
-                    self._broadcast(events)
-                live = []
-                for wr in self._watchers:
-                    try:
-                        self._top_up(wr)
-                    except native.CompactedError:
-                        # compaction took events this watcher was still
-                        # owed: the one real gap, and only its own
-                        wr.watch.terminate(mwatch.Event(
-                            mwatch.ERROR, _too_old_status(
-                                f"{wr.since} (compacted at "
-                                f"{self.kv.compacted_rev()})")))
-                    if not wr.watch.stopped:
-                        live.append(wr)
-                self._watchers = live
-                self._lagging = any(wr.since < self._dispatched_rev
-                                    for wr in live)
-                self._export_depths()
+                # what a turn owes every watcher it pays only when it has
+                # to: one that lags or stopped (a broadcast says so), or on
+                # a turn with nothing else to do
+                tend = self._broadcast(events) if events else True
+                if tend or self._lagging:
+                    self._tend()
+                if not events or now >= export_at:
+                    export_at = now + EXPORT_EVERY_S
+                    self._export_depths()
             if self._pace_waiting:
                 with self._pace_cv:
                     self._pace_cv.notify_all()
+            busy = time.thread_time()
+            WATCH_PUMP_BUSY.inc(busy - self.pump_busy_s)
+            self.pump_busy_s = busy
+            if events:
+                self.pump_turns += 1
+                self.pump_events += len(events)
+                WATCH_PUMP_TURNS.inc()
+                WATCH_PUMP_EVENTS.inc(len(events))
+                turn_end = time.monotonic()
+
+    def _tend(self) -> None:
+        """Bring each watcher that is behind the pump what its buffer has
+        room for, drop the streams that stopped, and say whether any still
+        lags. Watch lock held."""
+        at = self._dispatched_rev
+        live, lagging = [], False
+        for wr in self._watchers:
+            if wr.since < at or wr.watch.stopped:
+                try:
+                    self._top_up(wr)
+                except native.CompactedError:
+                    # compaction took events this watcher was still
+                    # owed: the one real gap, and only its own
+                    wr.watch.terminate(mwatch.Event(
+                        mwatch.ERROR, _too_old_status(
+                            f"{wr.since} (compacted at "
+                            f"{self.kv.compacted_rev()})")))
+                if wr.watch.stopped:
+                    continue
+                if wr.since < at:
+                    lagging = True
+                else:
+                    wr.counted = False  # level: its next catch-up is new
+            live.append(wr)
+        self._watchers = live
+        self._lagging = lagging
 
     def _read_log(self, head: int):
         """Every event past the dispatched revision, and the pump's lag as
@@ -763,19 +858,24 @@ class Storage:
             WATCH_PUMP_BATCH_MAX.set(len(events))
         return events
 
-    def _broadcast(self, events) -> None:
-        """One turn's events, decoded once, into the cacher ring and to each
-        watcher that is level with the pump, as far as its buffer has room
-        (one that fills lags from there and is topped up in turns of its
-        own). Watch lock held."""
+    def _broadcast(self, events) -> bool:
+        """One turn's events into the cacher ring and to each watcher that
+        is level with the pump, as far as its buffer has room (one that
+        fills lags from there and is topped up in turns of its own). True
+        when the turn has a watcher to tend: one whose buffer filled, or
+        whose stream stopped. Watch lock held."""
         cached = [self._to_cached(ev) for ev in events]
-        for ce in cached:
-            self.watch_cache.add(ce)
+        self.watch_cache.extend(cached)
         head = cached[-1].rev
+        tend = False
         for wr in self._watchers:
             # level with the pump (or registered "from now", ahead of it):
             # this batch is its next; one that lags is owed older events
             # first
-            if wr.since >= self._dispatched_rev and not wr.watch.stopped:
+            if wr.watch.stopped:
+                tend = True
+            elif wr.since >= self._dispatched_rev:
                 self._feed(wr, cached, head)
+                tend |= wr.since < head
         self._dispatched_rev = head
+        return tend

@@ -113,6 +113,27 @@ def _pod(name, ns="default", **spec):
             "metadata": {"name": name, "namespace": ns}, "spec": spec}
 
 
+# what the tree at PR 26 stored for the pod of the golden test below
+_GOLDEN_CREATED = (
+    b'{"apiVersion":"v1","kind":"Pod","metadata":{"creationTimestamp":"2'
+    b'026-01-01T00:00:00Z","generation":1,"labels":{"app":"app-1"},"name'
+    b'":"golden","namespace":"default","uid":"00000000-0000-4000-8000-00'
+    b'0000000028"},"spec":{"affinity":{"podAntiAffinity":{"requiredDurin'
+    b'gSchedulingIgnoredDuringExecution":[{"labelSelector":{"matchExpres'
+    b'sions":[{"key":"app","operator":"In","values":["app-1"]}]},"topolo'
+    b'gyKey":"kubernetes.io/hostname"}]}},"containers":[{"image":"regist'
+    b'ry/app:v1","imagePullPolicy":"IfNotPresent","name":"main","ports":'
+    b'[],"resources":{"requests":{"cpu":"100m","memory":"500Mi"}},"termi'
+    b'nationMessagePath":"/dev/termination-log"}],"dnsPolicy":"ClusterFi'
+    b'rst","enableServiceLinks":true,"priority":1,"restartPolicy":"Alway'
+    b's","schedulerName":"default-scheduler","serviceAccountName":"defau'
+    b'lt","terminationGracePeriodSeconds":30,"tolerations":[{"effect":"N'
+    b'oExecute","key":"node.kubernetes.io/not-ready","operator":"Exists"'
+    b',"tolerationSeconds":300},{"effect":"NoExecute","key":"node.kubern'
+    b'etes.io/unreachable","operator":"Exists","tolerationSeconds":300}]'
+    b'},"status":{"phase":"Pending"}}')
+
+
 class TestStorage:
     def test_create_get_conflict(self, storage):
         out = storage.create("/registry/pods/default/a", _pod("a"), "pods")
@@ -161,6 +182,124 @@ class TestStorage:
         for t in ts:
             t.join()
         assert storage.get("/registry/x")["n"] == n_threads * per
+
+    @pytest.mark.parametrize("via", ["create", "get", "list", "update",
+                                     "update_fn_lost_cas", "delete_then_create"])
+    def test_what_the_store_hands_out_is_the_callers_own(self, storage, via):
+        """The store keeps bytes and copies nothing (ISSUE 28): changing what
+        `create` / `get` / `list` / `guaranteed_update` returned, or what
+        `update_fn` was given on an attempt that then loses its CAS,
+        changes neither the stored bytes nor a later read."""
+        import json
+
+        key = "/registry/pods/default/own"
+        made = storage.create(key, _pod("own", labels={"a": "1"},
+                                        containers=[{"name": "c"}]), "pods")
+
+        def scribble(obj):
+            obj["spec"]["labels"]["a"] = "scribbled"
+            obj["spec"]["containers"].append({"name": "intruder"})
+            obj["metadata"]["name"] = "renamed"
+            obj["junk"] = True
+
+        def bind(obj):
+            obj["spec"]["nodeName"] = "n1"
+            return obj
+
+        if via == "create":
+            handed = made
+        elif via == "get":
+            handed = storage.get(key, "pods", "own")
+        elif via == "list":
+            (handed,), _ = storage.list("/registry/pods/default/")
+        elif via == "update":
+            handed = storage.guaranteed_update(key, bind, "pods", "own")
+        elif via == "update_fn_lost_cas":
+            given = []
+
+            def racing(obj):
+                given.append(json.loads(json.dumps(obj)))
+                if len(given) == 1:
+                    # this attempt changes what it was given, then loses
+                    # its CAS to a writer that got in first
+                    scribble(obj)
+                    storage.guaranteed_update(
+                        key, lambda o: {**o, "winner": True}, "pods", "own")
+                    return obj
+                return bind(obj)
+
+            handed = storage.guaranteed_update(key, racing, "pods", "own")
+            # the second attempt was given the winner's object, fresh:
+            # nothing of what the first did to its own
+            assert len(given) == 2 and given[1]["winner"] is True
+            assert "junk" not in given[1]
+            assert given[1]["metadata"]["name"] == "own"
+            assert given[1]["spec"]["labels"] == {"a": "1"}
+            assert handed["winner"] is True and "junk" not in handed
+        else:
+            # what delete returns is the caller's too, and so is the
+            # object a create was given once it is returned
+            gone = storage.delete(key, "pods", "own")
+            scribble(gone)
+            handed = storage.create(key, _pod("own", labels={"a": "1"},
+                                              containers=[{"name": "c"}]),
+                                    "pods")
+        stored = storage.kv.get(key).value
+        before = storage.get(key, "pods", "own")
+        assert handed == before   # resourceVersion and all
+        scribble(handed)
+        assert storage.kv.get(key).value == stored
+        assert storage.get(key, "pods", "own") == before
+        (listed,), _ = storage.list("/registry/pods/default/")
+        assert listed == before
+        assert "junk" not in before and b"junk" not in stored
+
+    def test_stored_bytes_of_a_create_and_a_binding_are_the_parents(
+            self, monkeypatch):
+        """Byte for byte what the tree before ISSUE 28 stored for the same
+        create and the same Binding (what the WAL and the PyKV parity
+        compare): key order, separators, no resourceVersion."""
+        from kubernetes_tpu.apiserver import APIServer
+        from kubernetes_tpu.client import Client
+        from kubernetes_tpu.machinery import meta
+
+        monkeypatch.setattr(meta, "new_uid",
+                            lambda: "00000000-0000-4000-8000-000000000028")
+        monkeypatch.setattr(meta, "now_rfc3339",
+                            lambda: "2026-01-01T00:00:00Z")
+        api = APIServer()
+        try:
+            client = Client.local(api)
+            client.pods.create({
+                "apiVersion": "v1", "kind": "Pod",
+                "metadata": {"name": "golden", "namespace": "default",
+                             "labels": {"app": "app-1"}},
+                "spec": {"schedulerName": "default-scheduler", "priority": 1,
+                         "containers": [{
+                             "name": "main", "image": "registry/app:v1",
+                             "resources": {"requests": {"cpu": "100m",
+                                                        "memory": "500Mi"}},
+                             "ports": []}],
+                         "affinity": {"podAntiAffinity": {
+                             "requiredDuringSchedulingIgnoredDuringExecution":
+                             [{"labelSelector": {"matchExpressions": [
+                                 {"key": "app", "operator": "In",
+                                  "values": ["app-1"]}]},
+                               "topologyKey": "kubernetes.io/hostname"}]}}}})
+            key = "/registry/core/pods/default/golden"
+            created = _GOLDEN_CREATED
+            assert api.storage.kv.get(key).value == created
+            client.pods.bind("golden", "node-7", "default")
+            bound = created.replace(
+                b'"enableServiceLinks":true,',
+                b'"enableServiceLinks":true,"nodeName":"node-7",').replace(
+                b'"status":{"phase":"Pending"}',
+                b'"status":{"conditions":[{"lastTransitionTime":'
+                b'"2026-01-01T00:00:00Z","status":"True","type":'
+                b'"PodScheduled"}],"phase":"Pending"}')
+            assert api.storage.kv.get(key).value == bound
+        finally:
+            api.close()
 
     def test_delete(self, storage):
         storage.create("/registry/pods/default/a", _pod("a"), "pods")
@@ -238,6 +377,58 @@ class TestStorage:
         ev = w.next(timeout=2)
         assert ev.object["metadata"]["name"] == "b"
         w.stop()
+
+    @pytest.mark.parametrize("other", ["sibling", "resume", "predicate"])
+    def test_what_a_stream_receives_is_its_own(self, storage, other):
+        """Two streams on one prefix: changing the object one received
+        leaves intact what a sibling stream receives, what a resume is
+        replayed from the ring, and what a predicate is shown (ISSUE 28:
+        one object per reader, none shared)."""
+        import json
+
+        shown = []
+
+        def pred(obj):
+            shown.append(json.loads(json.dumps(obj)))
+            return True
+
+        key = "/registry/pods/default/s"
+        storage.create("/registry/nodes/n0", {"metadata": {"name": "n0"}})
+        rv0 = str(storage.kv.rev())   # "0" would mean "from now"
+        first = storage.watch("/registry/pods/")
+        second = storage.watch(
+            "/registry/pods/", predicate=pred if other == "predicate" else None)
+        storage.create(key, _pod("s", labels={"a": "1"}), "pods")
+        storage.guaranteed_update(
+            key, lambda o: {**o, "spec": {**o["spec"], "nodeName": "n1"}})
+        mine = [first.next(timeout=2) for _ in range(2)]
+        want = [json.loads(json.dumps(e.object)) for e in mine]
+        for e in mine:
+            e.object["spec"]["labels"]["a"] = "scribbled"
+            e.object["metadata"]["name"] = "renamed"
+            e.object["junk"] = True
+        if other == "resume":
+            # a catch-up replayed from the cacher ring, after the scribble
+            second.stop()
+            second = storage.watch("/registry/pods/", since_rv=rv0)
+        theirs = [second.next(timeout=2) for _ in range(2)]
+        assert [e.type for e in theirs] == [mwatch.ADDED, mwatch.MODIFIED]
+        assert [e.object for e in theirs] == want
+        assert all(a.object is not b.object for a, b in zip(mine, theirs))
+        if other == "predicate":
+            assert shown == want
+            # what a stream with a predicate received is its own as well:
+            # a later stream's predicate is shown the event unchanged
+            for e in theirs:
+                e.object["junk"] = True
+            del shown[:]
+            third = storage.watch("/registry/pods/", since_rv=rv0,
+                                  predicate=pred)
+            assert [third.next(timeout=2).object for _ in range(2)] == want
+            assert shown == want
+            third.stop()
+        first.stop()
+        second.stop()
 
     def test_watch_gone_after_compaction(self, storage):
         from kubernetes_tpu.storage.cacher import WatchCache
