@@ -2973,12 +2973,6 @@ def _stage_main(n_nodes, n_pods, kind):
         t_launch = time.perf_counter() - t0 - t_snap  # async dispatch enqueue
         node_idx = jax.device_get(r.node)             # blocks: device + copy
         t_device = time.perf_counter() - t0 - t_snap - t_launch
-        if kind == "gang":
-            # the host-rounds gang path blocks on device_get inside the
-            # dispatch call, so the launch/device boundary is meaningless
-            # there — report the sum as device time
-            t_device += t_launch
-            t_launch = 0.0
         placements = [s.node_order[i] if i >= 0 else None
                       for i in node_idx[: len(pending)]]
         t_total = time.perf_counter() - t0

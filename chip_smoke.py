@@ -953,10 +953,9 @@ def programs_census(n_nodes: int, n_pods: int) -> dict:
     """AOT-compile, through the prewarmer's own path, the programs the legs
     do not reach, at the flagship Dims: the `runs` and `scan` engines, the
     explain tail, the fleet cycle at the bench `fleet` stage's shape, and the
-    gang program at n_nodes x 2*n_pods — which is also RUN both ways (host
-    rounds, and the single device-loop program the host-rounds threshold
-    exists to avoid). Per program: compiled or the compiler's refusal,
-    compile seconds, memory_analysis() bytes."""
+    gang program at n_nodes x 2*n_pods — which is also RUN (the single
+    device-loop program, ops/gang.py assign_gang). Per program: compiled or
+    the compiler's refusal, compile seconds, memory_analysis() bytes."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1037,7 +1036,7 @@ def programs_census(n_nodes: int, n_pods: int) -> dict:
     census("fleet cycle K=16", fd,
            lambda: via_prewarmer(fd, "waves", fleet=16))
 
-    # ---- the gang program, compiled and run both ways ---- #
+    # ---- the gang program, compiled and run ---- #
     gang_pods = gang_workload_pods(2 * n_pods)
     enc, tables, ex, pe, gd, keys = encode(
         gang_pods, Dims(N=bucket(n_nodes), P=bucket(2 * n_pods)))
@@ -1046,33 +1045,17 @@ def programs_census(n_nodes: int, n_pods: int) -> dict:
            lambda: via_prewarmer(gd, "waves", gang=True))
     tables, ex, pe, gang = (jax.device_put(t) for t in (tables, ex, pe, gang))
 
-    def run_gang(threshold):
-        saved = cycle._GANG_HOST_THRESHOLD
-        cycle._GANG_HOST_THRESHOLD = threshold
-        try:
-            times = []
-            for _ in range(2):   # first call compiles
-                t0 = time.perf_counter()
-                res = cycle._schedule_batch(tables, pe, keys, gd.D, ex,
-                                            gang=gang)
-                node = np.asarray(jax.device_get(res.node))
-                times.append(round(time.perf_counter() - t0, 3))
-            return node, times
-        finally:
-            cycle._GANG_HOST_THRESHOLD = saved
-
     gang_run: dict = {"pods": 2 * n_pods, "groups": int(gd.GR)}
     try:
-        host_node, gang_run["host_rounds_seconds"] = run_gang(0)
-        gang_run["scheduled"] = int((host_node >= 0).sum())
-        log(f"programs: gang host rounds {gang_run}")
-        if gang_run["host_rounds_seconds"][-1] < 60:
-            loop_node, gang_run["device_loop_seconds"] = run_gang(1 << 62)
-            gang_run["placements_equal"] = bool(
-                (loop_node == host_node).all())
-        else:
-            gang_run["device_loop_seconds"] = "not run: host rounds took " \
-                "over 60 s warm, one execution would run longer"
+        times = []
+        for _ in range(2):   # first call compiles
+            t0 = time.perf_counter()
+            res = cycle._schedule_batch(tables, pe, keys, gd.D, ex,
+                                        gang=gang)
+            node = np.asarray(jax.device_get(res.node))
+            times.append(round(time.perf_counter() - t0, 3))
+        gang_run["device_loop_seconds"] = times
+        gang_run["scheduled"] = int((node >= 0).sum())
     except Exception as e:  # noqa: BLE001 - record what the runtime said
         gang_run["error"] = repr(e)[:600]
     log(f"programs: gang {gang_run}")

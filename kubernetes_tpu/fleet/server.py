@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import configured_engine
 from ..sched.scheduler import CycleStats, Scheduler
 from ..state.dims import Dims
 from ..utils import faultline
@@ -296,7 +297,7 @@ def tenant_ledger(storage, tenant: str,
 class FleetTenant:
     """One virtual cluster: a full Scheduler whose DISPATCH the fleet owns.
     The wrapped Scheduler contributes its cache/queue/encoder, the commit
-    path (`_commit`, `_write_intent`/`_retire_intent`), intent replay
+    stage (`commit_wave`: intent write, Bindings, retire), intent replay
     (`recover`) and the event handlers — everything except the device
     cycle, which `FleetServer.tick` runs stacked."""
 
@@ -497,9 +498,7 @@ class FleetServer:
     # ------------------------------------------------------------------ #
 
     def _engine_for(self, name: str) -> str:
-        from ..sched.cycle import _engine
-
-        return self.engines.get(name) or _engine()
+        return self.engines.get(name) or configured_engine()
 
     def _stack_for(self, engine: str) -> FleetStack:
         st = self.stacks.get(engine)
@@ -512,11 +511,9 @@ class FleetServer:
         """The default-engine group's stack — THE stack of a
         uniform-engine fleet (back-compat accessor for tests/bench
         reading restack/donation counters)."""
-        from ..sched.cycle import _engine
-
         if len(self.stacks) == 1:
             return next(iter(self.stacks.values()))
-        return self._stack_for(_engine())
+        return self._stack_for(configured_engine())
 
     def _invalidate_stacks(self) -> None:
         for st in self.stacks.values():
@@ -1132,34 +1129,12 @@ class FleetServer:
                         failures.append((pod, attempts))
                         continue
                     commits.append((pod, order[ni], attempts))
-                try:
-                    intent = s._write_intent(cycle, commits)
-                except Exception:  # noqa: BLE001 - ledger storage unavailable
-                    for pod, _node, attempts in commits:
-                        st.aborted += 1
-                        st.requeued += 1
-                        s.queue.add_prompt_retry(pod, attempts=attempts,
-                                                 now=now)
-                    commits = []
-                    intent = None
-                bound_keys: List[str] = []
-                for ci, (pod, node_name, attempts) in enumerate(commits):
-                    if s.governor is not None and not s.governor.commit_allowed():
-                        # this tenant's breaker opened mid-commit: its
-                        # remaining commits requeue promptly (the other
-                        # tenants' loops are untouched — per-tenant breakers)
-                        for pod2, _n2, attempts2 in commits[ci:]:
-                            st.requeued += 1
-                            s.queue.add_prompt_retry(pod2, attempts=attempts2,
-                                                     now=now)
-                        break
-                    s._commit(pod, node_name, attempts, now, cycle, st,
-                              latency_keys=bound_keys)
-                # one batched span-close per tenant per tick (the scalar
-                # per-pod path was most of the measured telemetry cost)
-                if bound_keys:
-                    s.telemetry.record_bound_many(bound_keys, s.clock())
-                s._retire_intent(intent)
+                # the tenant's own Scheduler's commit stage, under ITS
+                # breaker (one tenant's opening mid-commit leaves the other
+                # tenants' loops untouched). On a fleet tick the commits a
+                # failed intent write aborted count as requeued too.
+                unwritten = s.commit_wave(commits, now, cycle, st)
+                st.requeued += len(unwritten)
                 for pod, attempts in failures:
                     st.unschedulable += 1
                     st.failed_keys.append(pod.key)

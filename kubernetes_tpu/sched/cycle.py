@@ -12,7 +12,6 @@ state/dims.py), so steady-state cycles pay one dispatch, zero recompiles.
 from __future__ import annotations
 
 import functools
-import os
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -20,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from ..api.types import Node, Pod
-from ..ops.assign import AssignResult, assign_batch, initial_state
+from ..ops import configured_engine
+from ..ops.assign import assign_batch, initial_state
 from ..ops.lattice import build_cycle, default_engine_config
 from ..state.arrays import ClusterTables, PodArrays
 from ..state.dims import Dims
@@ -91,21 +91,24 @@ def _taint_scalars(encoder: Encoder, device, mesh):
     return uk, ev
 
 
-def _engine() -> str:
-    """Assignment engine: 'waves' (default — wave-parallel dense admission,
-    ops/waves.py), 'runs' (run-length-collapsed sequential admission,
-    ops/runs.py; KTPU_ASSIGN=runs — bit-equal to the scan with the serial
-    chain shrunk from P pod-steps to #class-runs steps), or 'scan' (the
-    literal sequential-assume lax.scan, ops/assign.py; KTPU_ASSIGN=scan)
-    kept for debugging and as the executable spec both other engines are
-    tested against. Unrecognized KTPU_ASSIGN values normalize to 'waves':
-    downstream routing keys on exact engine names (e.g. nodeName-bearing
-    batches reroute 'waves' to the scan), so a typo must land on a known
-    engine, not fall through the dispatch untyped."""
-    import os
-
-    eng = os.environ.get("KTPU_ASSIGN", "waves")
-    return eng if eng in ("waves", "runs", "scan") else "waves"
+def plan_engine(has_node_name: bool, runs=None) -> Tuple[str, int]:
+    """`(engine, rc)` for one wave: the program it dispatches and the
+    prewarm / supervisor key it is looked up under — the single home of
+    both routing rules. A nodeName-bearing batch reroutes 'waves' to the
+    literal scan: spec.nodeName is a per-POD (not per-class) host
+    constraint the class-granular wave path cannot express, and in the
+    reference such pods bypass the scheduler entirely (kubelet consumes
+    them), so a batch containing one is rare. (The runs engine splits runs
+    on nodeName and falls back per-pod for pinned stretches, so it keeps
+    such batches.) The flag comes from Dims (computed host-side at encode
+    time) so the hot path never blocks on a device readback. `rc`, the
+    run-collapsed engine's static scan length, is the snapshot's RunPlan's
+    where the engine is 'runs' and the cache emitted one, else 0."""
+    engine = configured_engine()
+    if engine == "waves" and has_node_name:
+        engine = "scan"
+    rc = runs.rc if (engine == "runs" and runs is not None) else 0
+    return engine, rc
 
 
 def _apply_extra_plugins(tables, cyc, extra_plugins, extra_weights):
@@ -209,89 +212,6 @@ def _schedule_batch_impl(
     return (res, waves) if return_waves else res
 
 
-@functools.partial(jax.jit, static_argnums=(2, 6))
-def _gang_prep_impl(tables, keys, D, existing, hard_weight, ecfg,
-                    extra_plugins, extra_weights):
-    """The per-CYCLE half of a gang solve: interaction graph + score lattice
-    + initial admission state. Depends only on cluster/existing state — NOT
-    on the rejection mask — so the host-rounds loop builds it ONCE and every
-    round reuses the device-resident CycleArrays (VERDICT r4 weakness 2: each
-    round used to re-pay build_cycle)."""
-    uk, ev = keys
-    # stage names for the profiler's name-scope line (metadata only)
-    with jax.named_scope("build_cycle"):
-        cyc = build_cycle(tables, existing, uk, ev, D, hard_weight, ecfg)
-        cyc = _apply_extra_plugins(tables, cyc, extra_plugins, extra_weights)
-        init = initial_state(tables, cyc)
-    return cyc, init
-
-
-@jax.jit
-def _gang_round_impl(tables, cyc, init, pending, gang, rejected):
-    """One gang round as its own dispatch: wave fixpoint over the batch with
-    `rejected` groups' pods masked out, plus the per-group fill counts the
-    host rejection policy consumes. See `_schedule_gang_host_rounds`."""
-    from ..ops.gang import _placed_per_group
-    from ..ops.waves import assign_waves
-
-    GR = gang.needed.shape[0]
-    ok = (gang.group < 0) | ~rejected[jnp.clip(gang.group, 0, GR - 1)]
-    masked = pending._replace(valid=pending.valid & ok)
-    res, waves = assign_waves(tables, cyc, masked, init, return_waves=True)
-    placed = _placed_per_group(gang, masked, res.feasible)
-    under = gang.valid & ~rejected & (placed < gang.needed)
-    return res, waves, placed, under
-
-
-# device-loop gang programs above this batch size run as HOST-driven rounds:
-# a single XLA execution carrying GR+2 wave fixpoints runs for minutes at
-# the 5k×100k shape and trips the TPU runtime's execution watchdog (worker
-# 'crash'); one dispatch per round keeps each execution bounded while the
-# fixpoint itself stays on device (≤ GR+2 extra host round-trips total)
-_GANG_HOST_THRESHOLD = int(os.environ.get(
-    "KTPU_GANG_HOST_ROUNDS_ABOVE", "65536"))
-
-
-def _schedule_gang_host_rounds(tables, pending, keys, D, existing,
-                               hard_weight, ecfg, extra_plugins,
-                               extra_weights, gang, soft_rounds=4):
-    """Host-driven mirror of ops/gang.py assign_gang's rejection policy:
-    zero-placed underfilled groups reject in bulk, partially-filled ones one
-    per round (lowest rank first) until `soft_rounds`, then in bulk."""
-    import numpy as np
-
-    GR = int(gang.needed.shape[0])
-    rank = np.asarray(jax.device_get(gang.rank))
-    rejected = np.zeros((GR,), bool)
-    rounds = 0
-    cyc, init = _gang_prep_impl(
-        tables, keys, D, existing, jnp.float32(hard_weight),
-        ecfg or default_engine_config(), extra_plugins, extra_weights)
-    while True:
-        res, waves, placed_d, under_d = _gang_round_impl(
-            tables, cyc, init, pending, gang, jnp.asarray(rejected))
-        under = np.asarray(jax.device_get(under_d))
-        placed = np.asarray(jax.device_get(placed_d))
-        rounds += 1
-        if not under.any() or rounds >= GR + 2:
-            break
-        zero = under & (placed == 0)
-        partial = under & (placed > 0)
-        if rounds > soft_rounds or not partial.any():
-            newly = zero | partial
-        else:
-            worst = int(np.argmax(np.where(partial, rank, -1)))
-            newly = zero.copy()
-            newly[worst] = True
-        rejected |= newly
-    dead = rejected | under
-    GRc = jnp.clip(gang.group, 0, GR - 1)
-    ok = (gang.group < 0) | ~jnp.asarray(dead)[GRc]
-    res = AssignResult(node=jnp.where(ok, res.node, -1),
-                       feasible=res.feasible & ok, state=res.state)
-    return res, waves
-
-
 def _resolve_rc(pending, runs):
     """The run-collapsed engine's static scan length: the snapshot-supplied
     RunPlan when the cache emitted one (no readback), else derived from the
@@ -321,35 +241,20 @@ def _schedule_batch(tables, pending, keys, D, existing,
                     prewarmer=None,
                     mesh=None,
                     runs=None,
-                    explain: bool = False):
+                    explain: bool = False,
+                    engine: Optional[str] = None,
+                    rc: int = 0):
     # the two opt-in result tails are mutually exclusive by contract:
     # return_waves callers unpack (res, waves) and would silently read an
     # ExplainResult as the wave-index array
     assert not (explain and return_waves), \
         "explain and return_waves cannot be combined"
-    engine = _engine()
-    if gang is not None and engine == "waves" and not has_node_name \
-            and pending.valid.shape[0] >= _GANG_HOST_THRESHOLD:
-        out = _schedule_gang_host_rounds(
-            tables, pending, keys, D, existing, hard_weight, ecfg,
-            extra_plugins, extra_weights, gang)
-        if explain:
-            # the host-rounds gang path re-dispatches per rejection round;
-            # attribution is not folded into it (observability never costs
-            # the giant-gang path extra dispatches) — callers get None
-            return out[0], None
-        return out if return_waves else out[0]
-    if engine == "waves" and has_node_name:
-        # spec.nodeName pods carry a per-POD (not per-class) host constraint
-        # the class-granular wave path cannot express; in the reference such
-        # pods bypass the scheduler entirely (kubelet consumes them), so a
-        # batch containing one is rare — route it through the literal scan.
-        # (The runs engine splits runs on nodeName and falls back per-pod
-        # for pinned stretches, so it keeps such batches.) The flag comes
-        # from Dims (computed host-side at encode time) so the hot path
-        # never blocks on a device readback before dispatch.
-        engine = "scan"
-    rc = _resolve_rc(pending, runs) if engine == "runs" else 0
+    # a wave passes the plan it keyed its prewarm and supervisor budget
+    # on; a direct caller (tests, bench) passes none and gets the same one
+    if engine is None:
+        engine, rc = plan_engine(has_node_name, runs)
+    if engine == "runs" and runs is None:
+        rc = _resolve_rc(pending, None)
     # hardPodAffinitySymmetricWeight (apis/config/types.go:70) and the
     # EngineConfig plugin composition ride as traced f32 scalars so config
     # changes never recompile

@@ -20,7 +20,8 @@ does (scheduler.go:436-448); bind errors roll back via cache.forget_pod
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Protocol, Sequence, Tuple
 
 import jax
@@ -28,12 +29,17 @@ import jax.numpy as jnp
 
 from ..api.types import DEFAULT_SCHEDULER_NAME, Node, Pod
 from ..component import trace
+from ..parallel.mesh import mesh_key
 from ..state.cache import SchedulerCache, Snapshot
 from ..state.dims import Dims
 from ..state.encode import Encoder
-from .cycle import UNSCHEDULABLE_TAINT_KEY, _schedule_batch
-from .metrics import BINDING_DURATION
+from .cycle import (UNSCHEDULABLE_TAINT_KEY, _schedule_batch,
+                    micro_snapshot_with_keys, plan_engine,
+                    snapshot_with_keys)
+from .metrics import BINDING_DURATION, MICRO_WAVES
 from .queue import PriorityQueue
+from .supervisor import DispatchAbandonedError
+from .telemetry import _NULL_SPAN
 
 
 class Binder(Protocol):
@@ -105,6 +111,39 @@ class CycleStats:
     failed_keys: List[str] = field(default_factory=list)
 
 
+@dataclass
+class Wave:
+    """What one wave's stages hand each other (`Scheduler._run_wave`): a
+    stage reads what earlier stages set and sets its own fields."""
+
+    now: float
+    t0: float                       # perf_counter at the wave's start
+    span: Any = _NULL_SPAN          # sched/telemetry.py wave span
+    # ---- set by admit ---- #
+    cycle: int = 0
+    micro: bool = False
+    batch: List[Tuple[Pod, int]] = field(default_factory=list)
+    # the pods an extender claimed: per pod, after the batched wave
+    ext_batch: List[Tuple[Pod, int]] = field(default_factory=list)
+    stats: CycleStats = field(default_factory=CycleStats)
+    extra: Dict[str, Any] = field(default_factory=dict)  # onto the record
+    # ---- set by decide ---- #
+    snap: Optional[Snapshot] = None
+    keys: Any = None
+    snap_mode: str = ""             # "full" | "patch" | "cached"
+    engine: str = ""
+    rc: int = 0
+    node_order: Sequence[str] = ()  # of the snapshot ACTUALLY dispatched
+    node_idx: Any = None            # node row per batch pod, -1 = none
+    attribution: Any = None         # the dispatch's ExplainResult, on host
+    # ---- set by commit ---- #
+    explain: Optional[Dict[str, Any]] = None   # the explainer's rendering
+
+    @property
+    def dims(self) -> Optional[Dims]:
+        return self.snap.dims if self.snap is not None else None
+
+
 class Scheduler:
     """Single-profile scheduler. `schedule_pending` is the wave analog of
     scheduleOne; call it from a loop (or `run_until_idle`)."""
@@ -165,7 +204,9 @@ class Scheduler:
         # as a static per-class bias (framework/plugins.py extra_score_plugins)
         from ..framework.plugins import extra_score_plugins
 
-        self._extra_score = extra_score_plugins(framework)
+        extra_score = extra_score_plugins(framework)
+        self._extras = tuple(p for p, _ in extra_score)
+        self._extra_w = tuple(w for _, w in extra_score)
         # gang mechanism selection: the device gang engine (ops/gang.py)
         # owns pod groups UNLESS the Coscheduling Permit plugin is enabled —
         # then the host waiting-map path does (one mechanism per config;
@@ -391,8 +432,6 @@ class Scheduler:
     # ------------------------------------------------------------------ #
 
     def _snapshot_keys(self, pending: List[Pod]):
-        from .cycle import snapshot_with_keys
-
         # degraded mode routes the snapshot (and the interned-key scalars)
         # onto the CPU fallback device: host staging is the ground truth,
         # so nothing on this path touches the lost backend's buffers.
@@ -409,8 +448,6 @@ class Scheduler:
         # machinery), then graft a small fixed-P pending block for just
         # these deltas — the bulk-P pending buffer is never rebuilt for a
         # handful of pods
-        from .cycle import micro_snapshot_with_keys
-
         return micro_snapshot_with_keys(
             self.cache, self.encoder, pending, self.base_dims,
             self._micro_p,
@@ -460,10 +497,9 @@ class Scheduler:
         # runs: the binder, the in-process apiserver and the store add
         # their time to it as children of the phase that called them
         token = trace.activate(span.trace) if span.enabled else None
-        ctx: Dict[str, object] = {}
+        wave = Wave(now, t0, span)
         try:
-            return self._run_wave(span, now, t0, ctx,
-                                  micro_only=micro_only)
+            return self._run_wave(wave, micro_only)
         except Exception:
             # a wave that DIES mid-flight is exactly the tick the flight
             # recorder exists to explain: record what ran before the raise
@@ -471,13 +507,11 @@ class Scheduler:
             # the next wave's record), dump, and re-raise. InjectedCrash
             # (BaseException — the SIGKILL analog) punches through
             # unrecorded, as a real kill would.
-            stats = ctx.get("stats") or CycleStats()
-            stats.cycle_seconds = time.perf_counter() - t0
+            wave.stats.cycle_seconds = time.perf_counter() - t0
             span.mark("exception")
             self.telemetry.finish_wave(
-                span, stats=stats, engine=ctx.get("engine", ""),
-                dims=ctx.get("dims"), rc=ctx.get("rc", 0),
-                extra={**ctx.get("extra", {}), "exception": True})
+                span, stats=wave.stats, engine=wave.engine, dims=wave.dims,
+                rc=wave.rc, extra={**wave.extra, "exception": True})
             if self.telemetry.enabled:
                 self.telemetry.dump("exception")
             raise
@@ -496,9 +530,81 @@ class Scheduler:
             span.mark(engine)
             self.telemetry.finish_wave(span, stats=stats, engine=engine)
 
-    def _run_wave(self, span, now: float, t0: float,
-                  ctx: Dict[str, object],
-                  micro_only: bool = False) -> CycleStats:
+    def _no_wave(self, wave: Wave, stats: CycleStats,
+                 engine: str = "idle") -> bool:
+        """Finish a wave that pops nothing (paused, held, not micro-ready)."""
+        wave.stats = stats
+        stats.cycle_seconds = time.perf_counter() - wave.t0
+        self._drain_idle_events(wave.span, stats, engine=engine)
+        return False
+
+    def _run_wave(self, wave: Wave, micro_only: bool) -> CycleStats:
+        """The wave's driver: admit, decide, commit, preempt + requeue,
+        record, and the early returns. What a stage may touch, and which
+        phases it closes on the wave's span, is in its docstring."""
+        if not self._admit(wave, micro_only):
+            return wave.stats  # paused, held or empty: nothing to decide
+        span, stats = wave.span, wave.stats
+        if not wave.batch:
+            self._schedule_extender_pods(wave)  # an extender-only wave
+            stats.cycle_seconds = time.perf_counter() - wave.t0
+            if self.governor is not None:
+                self.governor.end_wave(wave.now, stats.attempted,
+                                       stats.cycle_seconds)
+            # an extender-only wave did REAL work (per-pod dispatches that
+            # can degrade/abandon): it gets its own record, never "idle"
+            span.mark("extenders")
+            self.telemetry.finish_wave(span, stats=stats, engine="extenders",
+                                       extra=wave.extra)
+            return stats
+        if not self._decide(wave):
+            # crash-consistent wave abort: the dispatch died on BOTH
+            # backends before any readback, so nothing was assumed and
+            # nothing may be committed — forget the wave cleanly and
+            # requeue every popped pod (attempts preserved, prompt retry:
+            # the pods are fine, the backend wasn't). Without this, a
+            # dispatch death mid-wave would silently LOSE the whole batch.
+            stats.aborted += self._abort(wave.batch, wave.now)
+            stats.aborted += self._abort(wave.ext_batch, wave.now)
+            span.mark("requeue")
+            stats.cycle_seconds = time.perf_counter() - wave.t0
+            # the supervisor's "abandoned" event auto-dumps the ring: the
+            # dead tick is reconstructable from the artifact
+            self.telemetry.finish_wave(
+                span, stats=stats, engine=wave.engine, dims=wave.dims,
+                rc=wave.rc, micro=wave.micro, extra=wave.extra)
+            return stats
+        failures = self._commit_stage(wave)
+        self._preempt_and_requeue(wave, failures)
+        span.mark("requeue")
+        return self._record(wave)
+
+    def _abort(self, pods, now: float) -> int:
+        """Send `(pod, attempts)` pairs back with no verdict (attempts kept,
+        prompt retry); returns how many, for the caller's counter: `aborted`
+        (the wave could not run) or `requeued` (the breaker cut it short)."""
+        n = 0
+        for pod, attempts in pods:
+            self.queue.add_prompt_retry(pod, attempts=attempts, now=now)
+            n += 1
+        return n
+
+    def _schedule_extender_pods(self, wave: Wave) -> None:
+        # after the batched wave: they must see its assumes
+        for pod, attempts in wave.ext_batch:
+            self._schedule_one_with_extenders(pod, attempts, wave.now,
+                                              wave.cycle, wave.stats)
+
+    def _admit(self, wave: Wave, micro_only: bool) -> bool:
+        """Stage 1, admit: pump, governor gate, micro / bulk arbitration,
+        pop, shed, extender split. False = nothing popped (paused, held,
+        not micro-ready, empty) and `wave.stats` is the finished result.
+
+        Touches the queue (pump, release_deferred, pop, park_deferred),
+        the governor, the Permit waiters it expires, and the cache only
+        through `cleanup` and `drain_confirm_waits`. No snapshot, device,
+        assume or Binding. Closes `pump` and `pop`."""
+        span, now = wave.span, wave.now
         self.queue.pump(now)
         self.cache.cleanup(now)
         self.expire_waiting(now)
@@ -519,13 +625,10 @@ class Scheduler:
                     self.telemetry.note_supervisor_event(
                         "deferred_release", f"{released} pods re-admitted")
             if not decision.dispatch_allowed:
-                stats = CycleStats(commit_paused=1)
-                ctx["stats"] = stats
-                stats.cycle_seconds = time.perf_counter() - t0
                 # only the transition wave records (the breaker_open event
                 # rides it); a long pause must not flood the ring
-                self._drain_idle_events(span, stats, engine="paused")
-                return stats
+                return self._no_wave(wave, CycleStats(commit_paused=1),
+                                     engine="paused")
             if decision.wave_limit:
                 pop_limit = min(pop_limit, decision.wave_limit)
         # ---- micro/bulk arbitration (ISSUE 18): AFTER the governor gate,
@@ -535,41 +638,31 @@ class Scheduler:
         if micro_only and mode != "micro":
             # fleet interleave probe (schedule_micro): the lane isn't
             # micro-ready — leave the backlog to the bulk cadence
-            stats = CycleStats()
-            ctx["stats"] = stats
-            stats.cycle_seconds = time.perf_counter() - t0
-            self._drain_idle_events(span, stats)
-            return stats
+            return self._no_wave(wave, CycleStats())
         if mode == "hold":
             # coalesce window open: near-simultaneous deltas share the
             # next micro dispatch instead of paying one wave each
-            stats = CycleStats()
-            ctx["stats"] = stats
-            stats.cycle_seconds = time.perf_counter() - t0
-            self._drain_idle_events(span, stats, engine="hold")
-            return stats
-        micro = mode == "micro"
+            return self._no_wave(wave, CycleStats(), engine="hold")
+        micro = wave.micro = mode == "micro"
         if micro:
             batch = self.queue.pop_micro(
                 min(pop_limit, self.micro_max_batch), now=now)
         else:
             batch = self.queue.pop_batch(pop_limit, now=now)
-        cycle = self.queue.current_cycle()
+        wave.cycle = self.queue.current_cycle()
         # what the popped pods waited for (`waits` on the wave's record):
         # pop instant less first-seen stamp, read without consuming the
         # stamps; and the Binding confirmations the informer delivered
         # since the previous wave, beside the assumes still unconfirmed
-        wave_extra: Dict[str, object] = {}
-        ctx["extra"] = wave_extra
         if batch and span.enabled:
             confirm, outstanding = self.cache.drain_confirm_waits()
-            wave_extra["waits"] = {
+            wave.extra["waits"] = {
                 "queue": self.telemetry.tracker.waits(
                     [p.key for p, _ in batch], self.clock()),
                 "confirm": confirm}
-            wave_extra["assumed_outstanding"] = outstanding
+            wave.extra["assumed_outstanding"] = outstanding
             if self.events_pending is not None:
-                wave_extra["events_pending"] = self.events_pending()
+                wave.extra["events_pending"] = self.events_pending()
         span.mark("pop")
         # ---- priority-aware shedding (SHED_LOW/TRICKLE): park sheddable
         # pods in the deferred lane — deferred, never dropped, no failure
@@ -587,81 +680,72 @@ class Scheduler:
             batch = kept
             if shed_n:
                 gov.note_shed(shed_n)
-        stats = CycleStats(attempted=len(batch), shed=shed_n,
-                           micro=1 if micro else 0)
-        ctx["stats"] = stats
-
+        wave.stats = CycleStats(attempted=len(batch), shed=shed_n,
+                                micro=1 if micro else 0)
         # pods an extender is interested in take the per-pod extender path
         # after the batched wave (they must see the wave's assumes)
-        ext_batch: List[Tuple[Pod, int]] = []
         if self.extenders:
             ext_keys = {p.key for p, _ in batch
                         if any(e.is_interested(p) for e in self.extenders)}
-            ext_batch = [(p, a) for p, a in batch if p.key in ext_keys]
+            wave.ext_batch = [(p, a) for p, a in batch if p.key in ext_keys]
             batch = [(p, a) for p, a in batch if p.key not in ext_keys]
+        wave.batch = batch
+        if not batch and not wave.ext_batch:
+            self._drain_idle_events(span, wave.stats)
+            return False
+        return True
 
-        if not batch and not ext_batch:
-            self._drain_idle_events(span, stats)
-            return stats
-        if not batch:
-            for pod, attempts in ext_batch:
-                self._schedule_one_with_extenders(pod, attempts, now, cycle, stats)
-            stats.cycle_seconds = time.perf_counter() - t0
-            if self.governor is not None:
-                self.governor.end_wave(now, stats.attempted,
-                                       stats.cycle_seconds)
-            # an extender-only wave did REAL work (per-pod dispatches that
-            # can degrade/abandon): it gets its own record, never "idle"
-            span.mark("extenders")
-            self.telemetry.finish_wave(span, stats=stats, engine="extenders",
-                                       extra=wave_extra)
-            return stats
+    def _decide(self, wave: Wave) -> bool:
+        """Stage 2, decide: snapshot, engine plan, prewarm bookkeeping,
+        the supervised dispatch with its CPU fallback, the prestage
+        overlap, readback. False = the dispatch was abandoned on both
+        backends and nothing may commit.
 
-        pending = [p for p, _ in batch]
-        snap, keys = (self._micro_snapshot_keys(pending) if micro
-                      else self._snapshot_keys(pending))
+        Touches the cache only to snapshot it (and to bracket, with
+        `mark_dispatch_start/done`, the time a worker holds the arrays),
+        the encoder, the prewarmer, the supervisor and the device; reads
+        the queue through `peek_active` only. Writes NO queue state and
+        assumes / forgets NO pod: all it leaves is on the Wave. Closes
+        `snapshot`, `prewarm`, `dispatch` and `readback`."""
+        span, stats = wave.span, wave.stats
+        pending = [p for p, _ in wave.batch]
+        wave.snap, wave.keys = (
+            self._micro_snapshot_keys(pending) if wave.micro
+            else self._snapshot_keys(pending))
+        snap = wave.snap
         span.mark("snapshot")
         # how the snapshot this wave dispatches on was produced
         # ("full" | "patch" | "cached") rides the wave's record
-        snap_mode = self.cache.last_snapshot_mode
-        extras = tuple(p for p, _ in self._extra_score)
-        extra_w = tuple(w for _, w in self._extra_score)
-        from dataclasses import replace as _dc_replace
-
-        from .cycle import _engine
-
-        eng = _engine()
-        # nodeName-bearing batches reroute the wave engine to the literal
-        # scan; the runs engine keeps them (it splits runs on nodeName and
-        # falls back per-pod for pinned stretches)
-        wave_engine = "scan" if (snap.dims.has_node_name
-                                 and eng == "waves") else eng
-        gang_arg = snap.gang if self._device_gangs else None
-        rc = 0
-        if wave_engine == "runs" and snap.runs is not None:
-            rc = snap.runs.rc
+        wave.snap_mode = self.cache.last_snapshot_mode
+        # the commit stage must map node indices through the node_order of
+        # the snapshot that was ACTUALLY dispatched: a fallback re-encode
+        # reflects newer cluster state (an informer event may have landed
+        # between the two snapshots), and indexing the old order would
+        # silently bind pods to the wrong nodes
+        wave.node_order = snap.node_order
+        wave.engine, wave.rc = plan_engine(snap.dims.has_node_name,
+                                           snap.runs)
+        if wave.rc:  # nonzero exactly when a RunPlan drives the wave
             stats.class_runs = snap.runs.n_runs
             stats.collapse_ratio = round(snap.runs.collapse_ratio, 2)
-        ctx.update(engine=wave_engine, dims=snap.dims, rc=rc)
+        gang = self._gang_of(snap) is not None
         self.prewarmer.observe(
             snap.dims, n_nodes=self.cache.node_count,
             n_existing=self.cache.pod_count,
-            engine=wave_engine,
-            extras=extras,
-            gang=self._device_gangs and snap.gang is not None,
-            mesh=snap.mesh, rc=rc)
+            engine=wave.engine, extras=self._extras, gang=gang,
+            mesh=snap.mesh, rc=wave.rc)
         self.supervisor.note_cycle_signature(
-            snap.dims, wave_engine, extras, gang_arg is not None, rc=rc)
-        if self.microwave and not micro and snap.runs is None:
+            snap.dims, wave.engine, self._extras, gang, rc=wave.rc)
+        if self.microwave and not wave.micro and snap.runs is None:
             # keep the micro signature warm from the bulk cadence: the
             # first delta after a quiet period must not pay a compile on
             # the latency path. (The runs engine's rc varies per micro
             # batch, so its micro programs compile on first use — small-P
             # traces are cheap.)
+            micro_engine, _ = plan_engine(False)  # no nodeName, no RunPlan
             self.prewarmer.ensure_warm(
-                _dc_replace(snap.dims, P=self._micro_p,
-                            has_node_name=False),
-                eng, extras, False, mesh=snap.mesh, rc=0)
+                replace(snap.dims, P=self._micro_p, has_node_name=False),
+                micro_engine, self._extras, False, mesh=snap.mesh, rc=0)
         if self.microwave:
             # the patch-scatter ladder is the OTHER compile micro-waves
             # cannot amortize: a fresh dirty-row bucket mid-churn stalls a
@@ -670,116 +754,6 @@ class Scheduler:
             self.prewarmer.ensure_patch_ladder(self.cache, snap,
                                                mesh=snap.mesh)
         span.mark("prewarm")
-
-        explain_on = self.explainer is not None
-
-        def _get_exp(exp_dev):
-            # attribution readback must never take down a wave: a zombie
-            # worker's arrays may live on a dead backend
-            if exp_dev is None:
-                return None
-            try:
-                return jax.device_get(exp_dev)
-            except Exception:  # noqa: BLE001 - observability, not placement
-                return None
-
-        def _dispatch():
-            out = _schedule_batch(
-                snap.tables, snap.pending, keys, snap.dims.D, snap.existing,
-                has_node_name=snap.dims.has_node_name,
-                hard_weight=self.hard_pod_affinity_weight,
-                ecfg=self.engine_config,
-                extra_plugins=extras, extra_weights=extra_w,
-                gang=gang_arg, dims=snap.dims, prewarmer=self.prewarmer,
-                mesh=snap.mesh, runs=snap.runs, explain=explain_on)
-            if explain_on:
-                res, exp = out
-                return res.node, exp
-            return out.node, None
-
-        def _primary():
-            tel = self.telemetry
-            if not tel.enabled:
-                node, exp = _dispatch()
-                return jax.device_get(node), _get_exp(exp)
-            # tier-3 device-time split (runs on the watchdog worker):
-            # launch (trace + async enqueue) vs XLA execution
-            # (block_until_ready) vs host readback (device_get) — the
-            # encode/upload half of the ratio is the wave's snapshot span.
-            # KTPU_PROFILE additionally brackets this in a jax.profiler
-            # TraceAnnotation inside a lazily-started profiler trace.
-            with tel.device_annotation("ktpu-wave-dispatch"):
-                tp0 = time.perf_counter()
-                node, exp = _dispatch()
-                tp1 = time.perf_counter()
-                jax.block_until_ready(node)
-                tp2 = time.perf_counter()
-                out = jax.device_get(node)
-                exp_h = _get_exp(exp)
-            tel.note_device_split(tp1 - tp0, tp2 - tp1,
-                                  time.perf_counter() - tp2, token=span)
-            return out, exp_h
-
-        # the commit loop must map node indices through the node_order of
-        # the snapshot that was ACTUALLY dispatched: a fallback re-encode
-        # reflects newer cluster state (an informer event may have landed
-        # between the two snapshots), and indexing the old order would
-        # silently bind pods to the wrong nodes
-        wave_ctx = {"node_order": snap.node_order}
-
-        def _fallback(dev, hung=False):
-            # degrade to the CPU backend. Preferred: ship the SAME encoded
-            # wave (device_put of the primary-resident arrays — the cheap
-            # direction when they are still reachable, e.g. an injected
-            # fault or a computation-only failure). A wedged runtime's
-            # buffers are untouchable (hung=True: a transfer would block
-            # forever with no watchdog) and a dead one's raise — in both
-            # cases the wave RE-ENCODES onto the fallback from the cache's
-            # host staging, the ground truth the device arrays derive
-            # from. No prewarmer — its executables belong to the primary.
-            tb = None
-            dd = snap.dims
-            rn = snap.runs
-            if not hung:
-                try:
-                    tb, pe, ex, ky, gg = jax.device_put(
-                        (snap.tables, snap.pending, snap.existing, keys,
-                         gang_arg), dev)
-                except Exception:  # noqa: BLE001 - dead-source transfer
-                    tb = None
-            if tb is None:
-                # supervisor already marked unhealthy → snapshot_device()
-                # is the fallback device: full host re-encode onto it
-                fsnap, fkeys = self._snapshot_keys(pending)
-                tb, pe, ex, ky, dd = (fsnap.tables, fsnap.pending,
-                                      fsnap.existing, fkeys, fsnap.dims)
-                gg = fsnap.gang if self._device_gangs else None
-                rn = fsnap.runs
-                wave_ctx["node_order"] = fsnap.node_order
-            with jax.default_device(dev):
-                out = _schedule_batch(
-                    tb, pe, ky, dd.D, ex,
-                    has_node_name=dd.has_node_name,
-                    hard_weight=self.hard_pod_affinity_weight,
-                    ecfg=self.engine_config,
-                    extra_plugins=extras, extra_weights=extra_w,
-                    gang=gg, runs=rn, explain=explain_on)
-                if explain_on:
-                    res, exp = out
-                    # degraded waves stay explainable: the chaos drill
-                    # reconstructs a degraded wave's failures from the
-                    # flight recorder, so the fallback attributes too
-                    return jax.device_get(res.node), _get_exp(exp)
-                return jax.device_get(out.node), None
-
-        # the budget key carries the PROGRAM signature, not just the shape:
-        # a gang-bearing or scan-routed wave at a warm shape traces a new
-        # XLA program whose cold compile must get the cold budget — keying
-        # on dims alone would misread that compile as a hang and falsely
-        # mark a healthy backend lost. The mesh signature is part of it:
-        # the GSPMD-partitioned program is a different compile.
-        from ..parallel.mesh import mesh_key as _mesh_key
-
         # the dispatch worker is about to hold this snapshot's arrays: the
         # prestage snapshot below must take the copy path (back buffer),
         # never donate buffers a thread is handing to XLA. EVERYTHING from
@@ -789,96 +763,186 @@ class Scheduler:
         # spot).
         self.cache.mark_dispatch_start()
         try:
+            # the budget key carries the PROGRAM signature, not just the
+            # shape: a gang-bearing or scan-routed wave at a warm shape
+            # traces a new XLA program whose cold compile must get the
+            # cold budget — keying on dims alone would misread that
+            # compile as a hang and falsely mark a healthy backend lost.
+            # The mesh signature is part of it: the GSPMD-partitioned
+            # program is a different compile.
             handle = self.supervisor.submit(
                 "cycle",
-                (_dc_replace(snap.dims, has_node_name=False), wave_engine,
-                 extras, gang_arg is not None, _mesh_key(snap.mesh), rc),
-                _primary, _fallback)
-            # ---- double-buffered host/device overlap: the dispatch above
-            # runs on the watchdog worker, so while the device evaluates
-            # THIS wave, the host interns the NEXT wave's backlog (the
-            # dominant host cost of the next snapshot). By the time
-            # handle.result() blocks, cycle N+1's pod rows are already
-            # memoized — encode of N+1 overlapped dispatch of N.
-            if self.preemptor is not None:
-                from .preemption import PREEMPT_BURST
-
-                # preemption storms compile their own fused program: warm
-                # it in the background at the current dims before the
-                # first storm
-                self.prewarmer.observe_preempt(snap.dims, PREEMPT_BURST,
-                                               mesh=snap.mesh)
-            # a micro wave skips the prestage overlap: its dispatch is
-            # sub-cycle, and interning a bulk backlog under it would put
-            # the bulk cost back on the latency path it exists to dodge
-            backlog = [] if micro \
-                else self.queue.peek_active(self.batch_size)
-            if backlog:
-                self.encoder.intern_pods(backlog)
-                if snap.mesh is not None:
-                    # mesh double-buffer, upload half: scatter the deltas
-                    # that accrued since the dispatched snapshot (informer
-                    # events, prior-wave confirms) into the BACK resident
-                    # buffer while the device evaluates THIS wave. The
-                    # post-readback snapshot then ships only the wave's
-                    # own assumes — the delta upload of cycle N+1
-                    # overlapped the dispatch of cycle N. Purely an
-                    # optimization: any failure here leaves the on-path
-                    # snapshot to do the same work after readback.
-                    try:
-                        self._snapshot_keys(backlog)
-                    except Exception:  # noqa: BLE001 - prestage must never
-                        pass           # take down the wave
-            from .supervisor import DispatchAbandonedError
-
+                (replace(snap.dims, has_node_name=False), wave.engine,
+                 self._extras, gang, mesh_key(snap.mesh), wave.rc),
+                partial(self._dispatch_primary, wave),
+                partial(self._dispatch_fallback, wave))
+            self._prestage(wave)
             span.mark("dispatch")
             try:
-                node_idx, wave_exp = handle.result()
-                span.mark("readback")
+                wave.node_idx, wave.attribution = handle.result()
             except DispatchAbandonedError:
                 span.mark("readback")
-                # crash-consistent wave abort: the dispatch died on BOTH
-                # backends before any readback, so nothing was assumed and
-                # nothing may be committed — forget the wave cleanly and
-                # requeue every popped pod (attempts preserved, prompt
-                # retry: the pods are fine, the backend wasn't). Without
-                # this, a dispatch death mid-wave would silently LOSE the
-                # whole batch.
-                for pod, attempts in batch:
-                    stats.aborted += 1
-                    self.queue.add_prompt_retry(pod, attempts=attempts,
-                                                now=now)
-                for pod, attempts in ext_batch:
-                    stats.aborted += 1
-                    self.queue.add_prompt_retry(pod, attempts=attempts,
-                                                now=now)
-                span.mark("requeue")
-                stats.cycle_seconds = time.perf_counter() - t0
-                # the supervisor's "abandoned" event auto-dumps the ring:
-                # the dead tick is reconstructable from the artifact
-                self.telemetry.finish_wave(span, stats=stats,
-                                           engine=wave_engine,
-                                           dims=snap.dims, rc=rc,
-                                           micro=micro, extra=wave_extra)
-                return stats
+                return False
+            span.mark("readback")
+            return True
         finally:
             # the dispatch no longer holds the snapshot's arrays — the
             # next on-path mesh patch may donate the resident buffers
             self.cache.mark_dispatch_done()
 
-        failures: List[Tuple[Pod, int]] = []
-        commits: List[Tuple[Pod, str, int]] = []
-        wave_order = wave_ctx["node_order"]  # set by a fallback re-encode
+    def _gang_of(self, snap):
+        return snap.gang if self._device_gangs else None
+
+    def _engine_call(self, tables, pending, keys, existing, gang, dims,
+                     runs, engine: str, rc: int, prewarmer=None, mesh=None):
+        """The wave's one call into the engine (primary and fallback):
+        `(node, attribution or None)`, still on the device."""
+        explain = self.explainer is not None
+        out = _schedule_batch(
+            tables, pending, keys, dims.D, existing,
+            has_node_name=dims.has_node_name,
+            hard_weight=self.hard_pod_affinity_weight,
+            ecfg=self.engine_config,
+            extra_plugins=self._extras, extra_weights=self._extra_w,
+            gang=gang, dims=dims, prewarmer=prewarmer, mesh=mesh,
+            runs=runs, explain=explain, engine=engine, rc=rc)
+        if explain:
+            res, exp = out
+            return res.node, exp
+        return out.node, None
+
+    @staticmethod
+    def _get_attribution(exp_dev):
+        # attribution readback must never take down a wave: a zombie
+        # worker's arrays may live on a dead backend
+        if exp_dev is None:
+            return None
+        try:
+            return jax.device_get(exp_dev)
+        except Exception:  # noqa: BLE001 - observability, not placement
+            return None
+
+    def _dispatch_primary(self, wave: Wave):
+        """The wave's dispatch on the serving backend, run by the
+        supervisor's watchdog worker: engine call, then readback."""
+        snap = wave.snap
+        call = partial(
+            self._engine_call, snap.tables, snap.pending, wave.keys,
+            snap.existing, self._gang_of(snap), snap.dims, snap.runs,
+            wave.engine, wave.rc, prewarmer=self.prewarmer, mesh=snap.mesh)
+        tel = self.telemetry
+        if not tel.enabled:
+            node, exp = call()
+            return jax.device_get(node), self._get_attribution(exp)
+        # tier-3 device-time split (runs on the watchdog worker):
+        # launch (trace + async enqueue) vs XLA execution
+        # (block_until_ready) vs host readback (device_get) — the
+        # encode/upload half of the ratio is the wave's snapshot span.
+        # KTPU_PROFILE additionally brackets this in a jax.profiler
+        # TraceAnnotation inside a lazily-started profiler trace.
+        with tel.device_annotation("ktpu-wave-dispatch"):
+            tp0 = time.perf_counter()
+            node, exp = call()
+            tp1 = time.perf_counter()
+            jax.block_until_ready(node)
+            tp2 = time.perf_counter()
+            out = jax.device_get(node)
+            exp_h = self._get_attribution(exp)
+        tel.note_device_split(tp1 - tp0, tp2 - tp1,
+                              time.perf_counter() - tp2, token=wave.span)
+        return out, exp_h
+
+    def _dispatch_fallback(self, wave: Wave, dev, hung: bool = False):
+        """Degrade the wave to the CPU backend. Preferred: ship the SAME
+        encoded wave (device_put of the primary-resident arrays — the
+        cheap direction when they are still reachable, e.g. an injected
+        fault or a computation-only failure). A wedged runtime's buffers
+        are untouchable (hung=True: a transfer would block forever with no
+        watchdog) and a dead one's raise — in both cases the wave
+        RE-ENCODES onto the fallback from the cache's host staging, the
+        ground truth the device arrays derive from, and is planned again
+        for what it then holds. No prewarmer — its executables belong to
+        the primary."""
+        snap, keys = wave.snap, wave.keys
+        engine, rc = wave.engine, wave.rc
+        arrays = None
+        if not hung:
+            try:
+                arrays = jax.device_put(
+                    (snap.tables, snap.pending, keys, snap.existing,
+                     self._gang_of(snap)), dev)
+            except Exception:  # noqa: BLE001 - dead-source transfer
+                arrays = None
+        if arrays is None:
+            # supervisor already marked unhealthy → snapshot_device()
+            # is the fallback device: full host re-encode onto it
+            snap, keys = self._snapshot_keys([p for p, _ in wave.batch])
+            arrays = (snap.tables, snap.pending, keys, snap.existing,
+                      self._gang_of(snap))
+            engine, rc = plan_engine(snap.dims.has_node_name, snap.runs)
+            wave.node_order = snap.node_order
+        with jax.default_device(dev):
+            # degraded waves stay explainable: the chaos drill
+            # reconstructs a degraded wave's failures from the flight
+            # recorder, so the fallback attributes too
+            node, exp = self._engine_call(*arrays, snap.dims, snap.runs,
+                                          engine, rc)
+            return jax.device_get(node), self._get_attribution(exp)
+
+    def _prestage(self, wave: Wave) -> None:
+        """Double-buffered host/device overlap: the dispatch runs on the
+        watchdog worker, so while the device evaluates THIS wave, the host
+        interns the NEXT wave's backlog (the dominant host cost of the
+        next snapshot). By the time handle.result() blocks, cycle N+1's
+        pod rows are already memoized — encode of N+1 overlapped dispatch
+        of N."""
+        snap = wave.snap
+        if self.preemptor is not None:
+            from .preemption import PREEMPT_BURST
+
+            # preemption storms compile their own fused program: warm it
+            # in the background at the current dims before the first storm
+            self.prewarmer.observe_preempt(snap.dims, PREEMPT_BURST,
+                                           mesh=snap.mesh)
+        # a micro wave skips the prestage overlap: its dispatch is
+        # sub-cycle, and interning a bulk backlog under it would put
+        # the bulk cost back on the latency path it exists to dodge
+        backlog = [] if wave.micro \
+            else self.queue.peek_active(self.batch_size)
+        if not backlog:
+            return
+        self.encoder.intern_pods(backlog)
+        if snap.mesh is not None:
+            # mesh double-buffer, upload half: scatter the deltas that
+            # accrued since the dispatched snapshot (informer events,
+            # prior-wave confirms) into the BACK resident buffer while the
+            # device evaluates THIS wave. The post-readback snapshot then
+            # ships only the wave's own assumes — the delta upload of
+            # cycle N+1 overlapped the dispatch of cycle N. Purely an
+            # optimization: any failure here leaves the on-path snapshot
+            # to do the same work after readback.
+            try:
+                self._snapshot_keys(backlog)
+            except Exception:  # noqa: BLE001 - prestage must never
+                pass           # take down the wave
+
+    def _commit_stage(self, wave: Wave) -> List[Tuple[Pod, int]]:
+        """Stage 3, commit: the wave's placements (`node_idx` through the
+        dispatched snapshot's `node_order`), less stale queue entries,
+        through `commit_wave`, whose touch contract is this stage's (plus
+        the explainer). Returns the pods the engine placed nowhere."""
+        batch, node_idx, order = wave.batch, wave.node_idx, wave.node_order
         # ---- decision provenance: render the attribution that rode the
         # dispatch (events/metrics/latest-attribution inside observe_wave;
         # the returned dict rides this wave's flight-recorder record) ---- #
-        explain_rec = None
-        if self.explainer is not None and wave_exp is not None:
+        if self.explainer is not None and wave.attribution is not None:
             try:
-                explain_rec = self.explainer.observe_wave(
-                    batch, node_idx, wave_exp, wave_order, now=now)
+                wave.explain = self.explainer.observe_wave(
+                    batch, node_idx, wave.attribution, order, now=wave.now)
             except Exception:  # noqa: BLE001 - provenance must never
-                explain_rec = None  # take down a wave
+                wave.explain = None  # take down a wave
+        failures: List[Tuple[Pod, int]] = []
+        commits: List[Tuple[Pod, str, int]] = []
         for i, (pod, attempts) in enumerate(batch):
             ni = int(node_idx[i])
             if ni < 0:
@@ -889,43 +953,59 @@ class Scheduler:
                 # already assumed/bound (e.g. an update raced the informer
                 # confirmation) — do not double-assume
                 continue
-            commits.append((pod, wave_order[ni], attempts))
-        # write-ahead intent: the whole wave's placements go durable in ONE
-        # CAS create before the first Binding write; retired after the last.
-        # A crash at pre_intent leaves nothing (pods re-deliver as pending),
-        # at post_intent leaves an intent recover() completes-or-releases,
-        # at post_bind leaves an intent recover() simply retires against
-        # informer truth (docs/RESILIENCE.md restart matrix).
+            commits.append((pod, order[ni], attempts))
+        self.commit_wave(commits, wave.now, wave.cycle, wave.stats,
+                         span=wave.span)
+        return failures
+
+    def commit_wave(self, commits: List[Tuple[Pod, str, int]], now: float,
+                    cycle: int, stats: CycleStats,
+                    span=_NULL_SPAN) -> List[Tuple[Pod, str, int]]:
+        """Commit one wave's `(pod, node_name, attempts)` placements: intent
+        write → assume + Binding per pod → one batched span close → intent
+        retire. The ONE commit stage: a wave of this scheduler and a fleet
+        tenant's share of a tick (fleet/server.py) both come through here.
+
+        The write-ahead intent makes the whole wave's placements durable
+        in ONE CAS create before the first Binding write, and is retired
+        after the last. A crash at pre_intent leaves nothing (pods
+        re-deliver as pending), at post_intent leaves an intent recover()
+        completes-or-releases, at post_bind leaves an intent recover()
+        simply retires against informer truth (docs/RESILIENCE.md restart
+        matrix). Returns the commits a failed intent write aborted (else
+        none), for a caller that counts those its own way.
+
+        Touches the cache (assume / finish / forget), the queue (requeue
+        of what did not bind), the ledger, the binder and the governor.
+        Closes `intent-write`, `bind-commit` and `retire`."""
+        aborted: List[Tuple[Pod, str, int]] = []
         try:
             intent = self._write_intent(cycle, commits)
         except Exception:  # noqa: BLE001 - ledger storage unavailable
             # no durable intent → no Binding may commit (the write-ahead
             # contract). The pods are fine: prompt-requeue the would-be
             # commits, crash-consistently like an abandoned dispatch.
-            for pod, _node, attempts in commits:
-                stats.aborted += 1
-                self.queue.add_prompt_retry(pod, attempts=attempts, now=now)
-            commits = []
-            intent = None
+            stats.aborted += self._abort(
+                ((p, a) for p, _node, a in commits), now)
+            aborted, commits, intent = commits, [], None
         span.mark("intent-write")
         bound_keys: List[str] = []
         bind_times: List[float] = []
+        gov = self.governor
+        commit = self._commit
         for ci, (pod, node_name, attempts) in enumerate(commits):
-            if self.governor is not None \
-                    and not self.governor.commit_allowed():
+            if gov is not None and not gov.commit_allowed():
                 # the breaker OPENED mid-wave (this wave's own commits
                 # tripped it): stop burning the commit path — the rest of
                 # the wave requeues promptly, no failure verdict. The
                 # intent stays valid (write-ahead covers the whole wave;
                 # unbound entries replay safely against informer truth)
                 # and is retired below as usual.
-                for pod2, _n2, attempts2 in commits[ci:]:
-                    stats.requeued += 1
-                    self.queue.add_prompt_retry(pod2, attempts=attempts2,
-                                                now=now)
+                stats.requeued += self._abort(
+                    ((p, a) for p, _node, a in commits[ci:]), now)
                 break
-            self._commit(pod, node_name, attempts, now, cycle, stats,
-                         latency_keys=bound_keys, bind_times=bind_times)
+            commit(pod, node_name, attempts, now, cycle, stats,
+                   latency_keys=bound_keys, bind_times=bind_times)
         # e2e watch→bind spans close in ONE batched call per wave (the
         # per-pod scalar path was most of the measured telemetry
         # overhead); the clock reading is the end of the commit loop —
@@ -938,13 +1018,24 @@ class Scheduler:
         span.mark("bind-commit")
         self._retire_intent(intent)
         span.mark("retire")
+        return aborted
 
-        # ---- preemption pass: AFTER commits, against ONE fresh snapshot so
-        # the what-if sees pods assumed earlier in this very wave (otherwise
-        # a preemptor could evict victims for space the wave already
-        # consumed). The whole burst of unschedulable pods is evaluated in a
-        # single fused dispatch (sched/preemption.py preempt_burst) instead
-        # of one snapshot+dispatch per pod.
+    def _preempt_and_requeue(self, wave: Wave,
+                             failures: List[Tuple[Pod, int]]) -> None:
+        """Stage 4, preempt + requeue: the preemption pass, AFTER commits
+        and against ONE fresh snapshot so the what-if sees pods assumed
+        earlier in this very wave (otherwise a preemptor could evict
+        victims for space the wave already consumed) — the whole burst in
+        a single fused dispatch (sched/preemption.py preempt_burst), not
+        one snapshot+dispatch per pod; the verdict for the rest; then the
+        extender's pods, one by one.
+
+        Touches the cache (snapshot; nominate / evict in the preemptor;
+        the extender pods' assumes), the queue (add_unschedulable), the
+        device and the apiserver (evictions, the extender pods'
+        Bindings). Its time is the `requeue` phase the driver closes, with
+        `requeue/snapshot` and `requeue/preempt` beneath it."""
+        now, stats = wave.now, wave.stats
         handled_keys: set = set()
         if failures and self.preemptor is not None:
             # gang pods never preempt individually: evicting victims to place
@@ -953,8 +1044,6 @@ class Scheduler:
             # coscheduling ecosystems gate preemption on the whole group)
             eligible = [(p, a) for p, a in failures if not p.pod_group]
             if eligible:
-                # children of `requeue` on a traced wave: the fresh
-                # snapshot, then the pass (what-if dispatches, evictions)
                 tr = trace.current()
                 tok = tr.begin("snapshot") if tr is not None else None
                 tp0 = time.perf_counter()
@@ -977,32 +1066,33 @@ class Scheduler:
                 continue
             stats.unschedulable += 1
             stats.failed_keys.append(pod.key)
-            self.queue.add_unschedulable(pod, attempts, now, cycle=cycle)
+            self.queue.add_unschedulable(pod, attempts, now,
+                                         cycle=wave.cycle)
+        self._schedule_extender_pods(wave)
 
-        for pod, attempts in ext_batch:
-            self._schedule_one_with_extenders(pod, attempts, now, cycle, stats)
-
-        span.mark("requeue")
-        stats.cycle_seconds = time.perf_counter() - t0
+    def _record(self, wave: Wave) -> CycleStats:
+        """Stage 5, record: the governor's end-of-wave reading, the micro
+        counter, the watch plane since the previous wave, and the wave's
+        flight-recorder record. Touches telemetry only."""
+        stats = wave.stats
+        stats.cycle_seconds = time.perf_counter() - wave.t0
         if self.governor is not None:
             # micro=True keeps the ingest estimate fed but fences micro
             # timings out of the slow-streak/wave-sizing control loop —
             # sub-cycle micro waves say nothing about bulk deadlines
-            self.governor.end_wave(now, stats.attempted,
-                                   stats.cycle_seconds, micro=micro)
-        if micro:
+            self.governor.end_wave(wave.now, stats.attempted,
+                                   stats.cycle_seconds, micro=wave.micro)
+        if wave.micro:
             self.micro_waves += 1
-            from .metrics import MICRO_WAVES
-
             MICRO_WAVES.inc(scheduler=self.scheduler_name)
-        extra = {"snapshot_mode": snap_mode, **wave_extra}
-        if self.watch_plane is not None and span.enabled:
+        extra = {"snapshot_mode": wave.snap_mode, **wave.extra}
+        if self.watch_plane is not None and wave.span.enabled:
             extra.update(self.watch_plane())
-        if explain_rec:
-            extra["explain"] = explain_rec
+        if wave.explain:
+            extra["explain"] = wave.explain
         self.telemetry.finish_wave(
-            span, stats=stats, engine=wave_engine, dims=snap.dims, rc=rc,
-            micro=micro, extra=extra)
+            wave.span, stats=stats, engine=wave.engine, dims=wave.dims,
+            rc=wave.rc, micro=wave.micro, extra=extra)
         return stats
 
     def _schedule_one_with_extenders(
@@ -1021,13 +1111,9 @@ class Scheduler:
         snap, keys = self._snapshot_keys([pod])
         # one dispatch: infeasible nodes are -inf in the score matrix; the
         # extender path must see the SAME composed scores as the fused path
-        from dataclasses import replace as _dc_replace
-
         from ..ops.lattice import default_engine_config
-        from .supervisor import DispatchAbandonedError
 
-        extras = tuple(p for p, _ in self._extra_score)
-        extra_w = tuple(w for _, w in self._extra_score)
+        extras, extra_w = self._extras, self._extra_w
         # the feasible/score iteration below must walk the node_order (and
         # use the D) of the snapshot that actually dispatched — a fallback
         # re-encode reflects newer cluster state (see the wave path)
@@ -1061,12 +1147,10 @@ class Scheduler:
                 return _score_on(args, score_ctx["D"])
 
         try:
-            from ..parallel.mesh import mesh_key as _mesh_key
-
             raw = self.supervisor.run(
                 "scores",
-                (_dc_replace(snap.dims, has_node_name=False), extras,
-                 _mesh_key(snap.mesh)),
+                (replace(snap.dims, has_node_name=False), extras,
+                 mesh_key(snap.mesh)),
                 lambda: _score_on((snap.tables, snap.pending, keys,
                                    snap.existing), snap.dims.D),
                 _score_fallback)
@@ -1267,15 +1351,9 @@ class Scheduler:
         backlog = self.queue.peek_active(self.batch_size)
         self.encoder.intern_pods(backlog)
         snap, _keys = self._snapshot_keys(backlog)
-        from .cycle import _engine
-
-        eng = _engine()
-        wave_engine = "scan" if (snap.dims.has_node_name
-                                 and eng == "waves") else eng
-        extras = tuple(p for p, _ in self._extra_score)
-        gang = self._device_gangs and snap.gang is not None
-        rc = snap.runs.rc if (wave_engine == "runs"
-                              and snap.runs is not None) else 0
+        wave_engine, rc = plan_engine(snap.dims.has_node_name, snap.runs)
+        extras = self._extras
+        gang = self._gang_of(snap) is not None
         # compile the signature the first led wave WILL dispatch (idempotent
         # per signature), and keep the growth-boundary lookahead running so
         # a takeover into a growing cluster doesn't stall either

@@ -769,10 +769,10 @@ class SchedulerServer:
         # emitted the rich per-predicate events from inside the wave for
         # every pod it ATTRIBUTED — the generic message would double-post
         # a weaker duplicate for those. But failure paths the attribution
-        # never sees (extender rejections, framework rollbacks, the
-        # gang-host-rounds route, a failed attribution readback) must
-        # still get the generic event: gate per pod on whether an
-        # attribution doc exists, not on the explainer's mere presence.
+        # never sees (extender rejections, framework rollbacks, a failed
+        # attribution readback) must still get the generic event: gate per
+        # pod on whether an attribution doc exists, not on the explainer's
+        # mere presence.
         explainer = self.scheduler.explainer
         failed = []
         for key in stats.failed_keys:

@@ -44,6 +44,7 @@ import numpy as np
 
 from ..api.types import Node, Pod
 from ..component import trace
+from ..ops import configured_engine
 from .arrays import ClusterTables, NodeArrays, PodArrays
 from .dims import Dims
 from .encode import Encoder
@@ -877,7 +878,7 @@ class SchedulerCache:
 
     @staticmethod
     def _runs_wanted() -> bool:
-        return os.environ.get("KTPU_ASSIGN") == "runs"
+        return configured_engine() == "runs"
 
     @staticmethod
     def _run_plan_from_cols(cls, priority, creation, valid, nnr):

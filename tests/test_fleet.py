@@ -409,6 +409,86 @@ class TestTenantLedger:
             api.close()
 
 
+class TestOneCommitStage:
+    """The commit stage is one (`Scheduler.commit_wave`): a fleet tenant's
+    share of a tick and a single-cluster wave come out of it alike, fault
+    for fault — same CycleStats, same binder, same queue, same ledger."""
+
+    @staticmethod
+    def _wave(fleet: bool, fault: str, storage):
+        class Refusing(RecordingBinder):
+            def bind(self, pod, node_name):
+                return False
+
+        binder = Refusing() if fault == "breaker_opens_mid_wave" \
+            else RecordingBinder()
+        ledger = tenant_ledger(storage, "fleet" if fleet else "plain")
+        written = []
+        write = ledger.write_intent
+
+        def spy(**kw):
+            written.append(kw["bindings"])
+            if fault == "intent_write_fails":
+                raise OSError("ledger storage unavailable")
+            return write(**kw)
+
+        ledger.write_intent = spy
+        if fleet:
+            srv = det_server(batch_size=16)
+            target = srv.add_tenant("t", binder=binder, ledger=ledger)
+            sched = target.sched
+        else:
+            target = sched = Scheduler(binder=binder, ledger=ledger,
+                                       batch_size=16, clock=lambda: 0.0)
+        for i in range(2):
+            target.on_node_add(mknode(i))
+        feed(target, "t", 8)
+        stats = srv.tick().per_tenant["t"] if fleet \
+            else sched.schedule_pending()
+        return stats, sched, binder, ledger, written
+
+    @pytest.mark.parametrize("fault", ["intent_write_fails",
+                                       "breaker_opens_mid_wave"])
+    def test_tenant_and_plain_scheduler_agree(self, fault):
+        import dataclasses
+
+        from kubernetes_tpu.apiserver import APIServer
+
+        api = APIServer()
+        try:
+            got = {fleet: self._wave(fleet, fault, api.storage)
+                   for fleet in (False, True)}
+            for fleet, (st, sched, binder, ledger, written) in got.items():
+                assert st.attempted == 8 and st.scheduled == 0
+                assert binder.bound == []
+                assert len(written) == 1 and len(written[0]) == 8
+                assert ledger.unretired() == []
+                if fault == "intent_write_fails":
+                    # no intent, no Binding: the whole wave is back in the
+                    # active queue with no verdict and its attempts kept
+                    # (a fleet tick counts those as requeued as well)
+                    assert st.aborted == 8 and st.bind_errors == 0
+                    assert st.requeued == (8 if fleet else 0)
+                    assert sched.queue.lengths() == (8, 0, 0)
+                    assert {a for _, a in sched.queue.pop_batch(16)} == {2}
+                else:
+                    # five refused Bindings open the breaker: they carry
+                    # their verdict, the tail requeues promptly with none,
+                    # and the intent that covered all eight is retired
+                    assert st.bind_errors == 5 and st.requeued == 3
+                    assert st.aborted == 0 and len(st.failed_keys) == 5
+                    assert sched.queue.lengths()[0] == 3
+                    assert sched.cache.counts()[1] == 0   # nothing assumed
+            plain, tenant = (dataclasses.asdict(got[f][0])
+                             for f in (False, True))
+            for d in (plain, tenant):
+                del d["cycle_seconds"], d["requeued"]   # checked above
+            assert plain == tenant
+            assert got[False][1].queue.depths() == got[True][1].queue.depths()
+        finally:
+            api.close()
+
+
 class TestTenantStorm:
     @pytest.mark.chaos
     def test_storm_degrades_only_the_stormed_tenant(self):

@@ -417,6 +417,55 @@ def test_plan_runs_counts_and_bound():
     # runs 0/1/0 = 3
 
 
+@pytest.mark.parametrize("configured, has_node_name, engine", [
+    ("waves", False, "waves"),
+    ("waves", True, "scan"),     # spec.nodeName is per pod: the literal scan
+    ("runs", False, "runs"),
+    ("runs", True, "runs"),      # runs splits on the pin and keeps the batch
+    ("scan", False, "scan"),
+    ("scan", True, "scan"),
+    ("wavs", False, "waves"),    # a typo lands on a known engine ...
+    ("wavs", True, "scan"),      # ... and is then routed like it
+    (None, False, "waves"),      # unset
+])
+def test_plan_engine_table(monkeypatch, configured, has_node_name, engine):
+    """`plan_engine` is the one place a wave's program is chosen: the
+    configured engine, the nodeName reroute, and `rc` from the RunPlan
+    only where the engine is `runs` — and the cache emits a RunPlan
+    exactly when the plan says `runs`."""
+    from kubernetes_tpu.ops import configured_engine
+    from kubernetes_tpu.ops.runs import RunPlan
+    from kubernetes_tpu.sched.cycle import plan_engine
+    from kubernetes_tpu.state.cache import SchedulerCache
+
+    if configured is None:
+        monkeypatch.delenv("KTPU_ASSIGN", raising=False)
+    else:
+        monkeypatch.setenv("KTPU_ASSIGN", configured)
+    known = configured if configured in ("waves", "runs", "scan") \
+        else "waves"
+    assert configured_engine() == known
+    plan = RunPlan(rc=64, n_runs=3, n_valid=40)
+    assert plan_engine(has_node_name) == (engine, 0)
+    assert plan_engine(has_node_name, plan) == \
+        (engine, 64 if engine == "runs" else 0)
+
+    cache = SchedulerCache()
+    cache.add_node(Node(name="n0", labels={HOSTNAME: "n0"},
+                        allocatable=Resources.make(cpu="4", memory="8Gi",
+                                                   pods=10)))
+    pods = [Pod(name=f"p{i}", creation_index=i,
+                node_name="n0" if has_node_name and i == 1 else "",
+                requests=Resources.make(cpu="100m", memory="64Mi"))
+            for i in range(3)]
+    snap = cache.snapshot(Encoder(), pods, None,
+                          extra_intern=(UNSCHEDULABLE_TAINT_KEY,))
+    assert snap.dims.has_node_name == has_node_name
+    assert (snap.runs is not None) == (engine == "runs")
+    assert plan_engine(snap.dims.has_node_name, snap.runs) == \
+        (engine, snap.runs.rc if engine == "runs" else 0)
+
+
 def test_plan_runs_extreme_negative_priority_matches_device_order():
     """INT32_MIN priorities wrap identically host- and device-side (the
     scan's own queue_order semantics) — the host bound must not undercount

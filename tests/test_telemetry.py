@@ -185,6 +185,87 @@ class TestWaveSpans:
         assert len(s.telemetry.latency_samples) == 0
 
 
+# the record of each kind of wave, as benchmarks/harness reads it: phase
+# names in order, the children paths beneath them, and the record's keys
+# in order. What the stages of `Scheduler._run_wave` must keep writing.
+_BULK = ["pump", "pop", "snapshot", "prewarm", "dispatch", "readback",
+         "intent-write", "bind-commit", "retire", "requeue"]
+_BINDING = ["bind-commit/assume", "bind-commit/bind-call",
+            "bind-commit/finish"]
+_FIRST_SNAPSHOT = ["snapshot/full", "snapshot/full/upload",
+                   "snapshot/prepare"]
+_HEAD = ["recorder", "t_start", "duration_s", "phases", "engine", "rc"]
+_WAVE_SHAPES = {
+    # kind: (phases, children, keys)
+    "bulk": (_BULK,
+             _BINDING + ["requeue/preempt", "requeue/preempt/what-if",
+                         "requeue/snapshot", "requeue/snapshot/patch",
+                         "requeue/snapshot/patch/upload",
+                         "requeue/snapshot/prepare"] + _FIRST_SNAPSHOT,
+             _HEAD + ["bucket", "stats", "device_split", "children",
+                      "snapshot_mode", "waits", "assumed_outstanding",
+                      "seq"]),
+    "micro": (_BULK,
+              _BINDING + _FIRST_SNAPSHOT + ["snapshot/upload"],
+              _HEAD + ["micro", "bucket", "stats", "device_split",
+                       "children", "snapshot_mode", "waits",
+                       "assumed_outstanding", "seq"]),
+    "paused": (["pump", "paused"], None,
+               _HEAD + ["stats", "supervisor_events", "seq"]),
+    "abandoned": (["pump", "pop", "snapshot", "prewarm", "dispatch",
+                   "readback", "requeue"],
+                  _FIRST_SNAPSHOT,
+                  _HEAD + ["bucket", "stats", "supervisor_events",
+                           "children", "waits", "assumed_outstanding",
+                           "seq"]),
+    "raises": (_BULK[:8] + ["exception"],
+               _BINDING + _FIRST_SNAPSHOT,
+               _HEAD + ["bucket", "stats", "device_split", "children",
+                        "waits", "assumed_outstanding", "exception",
+                        "seq"]),
+}
+
+
+class TestRecordShape:
+    @pytest.mark.parametrize("kind", sorted(_WAVE_SHAPES))
+    def test_each_kind_of_wave_keeps_its_record(self, kind):
+        from kubernetes_tpu.sched.preemption import Preemptor
+
+        phases, children, keys = _WAVE_SHAPES[kind]
+        clk = {"t": 0.0}
+        s = Scheduler(binder=RecordingBinder(), batch_size=64,
+                      clock=lambda: clk["t"], microwave=(kind == "micro"),
+                      preemptor=Preemptor() if kind == "bulk" else None)
+        for n in make_nodes(8):
+            s.on_node_add(n)
+        for i in range(3):
+            s.on_pod_add(_pod(i))
+        if kind == "bulk":
+            # one pod no node holds: the preemption pass runs under requeue
+            s.on_pod_add(Pod(name="huge", creation_index=9, priority=5,
+                             requests=Resources.make(cpu="4000",
+                                                     memory="8Mi")))
+        elif kind == "paused":
+            for _ in range(5):
+                s.governor.note_commit(False, 0.01)
+        elif kind == "abandoned":
+            faultline.install("device.error@cycle:1,device.fallback@cycle:1")
+        if kind == "raises":
+            s._retire_intent = lambda intent: 1 / 0
+            with pytest.raises(ZeroDivisionError):
+                s.schedule_pending()
+        else:
+            st = s.schedule_pending()
+            assert st.micro == (1 if kind == "micro" else 0)
+        rec = s.telemetry.recorder.records()[-1]
+        assert [p for p, _ in rec["phases"]] == phases
+        assert (sorted(rec["children"]) if "children" in rec else None) \
+            == children
+        assert list(rec) == keys
+        if "waits" in rec:
+            assert set(rec["waits"]) == {"queue", "confirm"}
+
+
 class TestFirstSeenAcrossRequeue:
     def test_stamp_survives_unschedulable_backoff_round_trip(self):
         """A pod that parks unschedulable, waits out a cluster event and
