@@ -33,6 +33,7 @@ the cycle are visible to later pods — the device analog of the assume cache
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..state.arrays import (
@@ -65,20 +66,30 @@ def class_term_membership(term_ids: Array, S: int) -> Array:
     return hot.any(axis=1)  # [SC, S]
 
 
-def per_node_counts(TM_or_membership: Array, pods: PodArrays, N: int) -> Array:
-    """[S, E]-style values scattered by each existing pod's node → [S, N] i32.
-    TM_or_membership: [S, SC] (term matches class). Counts matching existing
-    pods per node — the node-axis source of truth for all domain aggregations."""
-    vals = TM_or_membership  # [S, SC]
-    node_e = pods.node_id  # [E]
-    on_node = (node_e >= 0) & pods.valid
-    per_e = jnp.take_along_axis(
-        vals, jnp.maximum(pods.cls, 0)[None, :], axis=1
-    ) & on_node[None, :]  # [S, E]
-    idx = jnp.where(on_node, node_e, N)[None, :].repeat(vals.shape[0], axis=0)
-    out = jnp.zeros((vals.shape[0], N + 1), jnp.int32)
-    out = out.at[jnp.arange(vals.shape[0])[:, None], idx].add(per_e.astype(jnp.int32))
-    return out[:, :N]
+def class_node_hist(pods: PodArrays, SC: int, N: int) -> Array:
+    """M [SC, N] i32: how many valid existing pods of class c sit (bound or
+    assumed) on node n. An existing pod enters every per-node seed only
+    through (cls, node_id), so this one scatter-add of E ones — column N the
+    discard slot for unbound / invalid rows, sliced off — is all that reads
+    the E axis; per_node_counts and scores.weighted_per_node are products
+    against it. A row with cls -1 counts under class 0, as every class gather
+    of an existing pod does (preempt.py's cls_e)."""
+    on_node = (pods.node_id >= 0) & pods.valid
+    idx = jnp.where(on_node, pods.node_id, N)
+    with jax.named_scope("class_node_hist"):
+        M = jnp.zeros((SC, N + 1), jnp.int32).at[
+            jnp.maximum(pods.cls, 0), idx].add(1)
+    return M[:, :N]
+
+
+def per_node_counts(TM_or_membership: Array, M: Array) -> Array:
+    """[S, SC] bool (term matches class) × M [SC, N] (class_node_hist) →
+    [S, N] i32: matching existing pods per node — the node-axis source of
+    truth for all domain aggregations. An integer dot, so exact whatever the
+    chip's default matmul precision (bf16, wrong above 256) would make of
+    a float one."""
+    return jnp.dot(TM_or_membership.astype(jnp.int32), M,
+                   preferred_element_type=jnp.int32)
 
 
 def domain_of_term(nodes: NodeArrays, topo_key: Array) -> tuple[Array, Array]:

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..state.arrays import Array, ClusterTables, PodArrays
-from .interpod import class_term_membership, per_node_counts, term_class_matrix
+from .interpod import (class_node_hist, class_term_membership, per_node_counts,
+                       term_class_matrix)
 from .labels import node_term_matrix
 from .scores import image_locality_static, symmetric_weight_cols, weighted_per_node
 from .taints import taint_matrices, taint_toleration_score
@@ -227,10 +228,12 @@ def build_cycle(
     S = TM.shape[0]
     N = tables.nodes.valid.shape[0]
     has_anti = class_term_membership(tables.classes.anti_terms, S)
-    CNT = per_node_counts(TM, existing, N)
-    HOLD = per_node_counts(has_anti.T, existing, N)
+    # the three per-node seeds share one pass over the existing pods
+    M = class_node_hist(existing, TM.shape[1], N)
+    CNT = per_node_counts(TM, M)
+    HOLD = per_node_counts(has_anti.T, M)
     ELD = eligible_domains(static.node_match, tables.classes, tables.nodes, D)
     WCOLS = symmetric_weight_cols(tables.classes, S, hard_weight)
-    WSYM = weighted_per_node(WCOLS, existing, N)
+    WSYM = weighted_per_node(WCOLS, M)
     return CycleArrays(static=static, TM=TM, has_anti=has_anti, CNT=CNT,
                        HOLD=HOLD, ELD=ELD, WCOLS=WCOLS, WSYM=WSYM, ecfg=ecfg)

@@ -12,7 +12,10 @@ Reference semantics (core/generic_scheduler.go):
 
 TPU re-design — everything is one jitted dispatch:
   * "remove all potential victims" is a scatter-subtract of victim request rows
-    and term-count contributions over the node axis (no per-node loop);
+    over the node axis (no per-node loop); the term counts without them are
+    seeded as a cycle's are — a class × node histogram of the lane's
+    survivors (one scalar update per existing pod), then [S, SC] @ [SC, N]
+    integer products (interpod.class_node_hist / per_node_counts: exact);
   * port what-ifs avoid bitset un-OR-ing (not invertible) by precomputing the
     pairwise pod-vs-existing-pod conflict vector [E] and scatter-maxing it;
   * the reprieve loop is a single lax.scan over existing pods in global
@@ -44,7 +47,8 @@ import jax.numpy as jnp
 from ..state.arrays import Array, ClusterTables, PodArrays
 from .assign import AssignState
 from .fit import _fit
-from .interpod import affinity_rows, domain_of_term, per_node_counts
+from .interpod import (affinity_rows, class_node_hist, domain_of_term,
+                       per_node_counts)
 from .lattice import CycleArrays
 from .topospread import spread_row
 
@@ -132,14 +136,12 @@ def preempt_for_pod(
         -jnp.where((node_e_safe < N)[:, None], vict_req, 0)
     )
 
-    survivors = PodArrays(
-        valid=existing.valid & ~vict_pot,
-        name_id=existing.name_id, ns=existing.ns, cls=existing.cls,
-        priority=existing.priority, creation=existing.creation,
-        node_id=existing.node_id, node_name_req=existing.node_name_req,
-    )
-    CNT_wo = per_node_counts(cyc.TM, survivors, N)             # [S, N]
-    HOLD_wo = per_node_counts(cyc.has_anti.T, survivors, N)
+    # the survivors' term counts, seeded as build_cycle seeds the cycle's
+    M_wo = class_node_hist(
+        existing._replace(valid=existing.valid & ~vict_pot),
+        cyc.TM.shape[1], N)                                    # [SC, N]
+    CNT_wo = per_node_counts(cyc.TM, M_wo)                     # [S, N]
+    HOLD_wo = per_node_counts(cyc.has_anti.T, M_wo)
 
     # ports: conflict[n] = any surviving pod on n whose ports clash with ours
     c_e = _pairwise_port_conflict(tables, cls, cls_e)          # [E]

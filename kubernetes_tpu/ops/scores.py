@@ -19,13 +19,13 @@ Pure-Python reference semantics: api/semantics.py (golden-tested).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..state.arrays import (
     Array,
     ClusterTables,
     NodeArrays,
-    PodArrays,
     PodClassTable,
     TermTable,
 )
@@ -72,19 +72,16 @@ def symmetric_weight_cols(
     return out * classes.valid[None, :]
 
 
-def weighted_per_node(WCOLS: Array, pods: PodArrays, N: int) -> Array:
+def weighted_per_node(WCOLS: Array, M: Array) -> Array:
     """WSYM seed [S, N] f32: Σ over existing pods of their class's signed
-    symmetric weights, scattered by node — the cycle-start counterpart of
-    processExistingPod (interpod_affinity.go:124-185)."""
-    per_e = WCOLS[:, jnp.maximum(pods.cls, 0)]  # [S, E]
-    on_node = (pods.node_id >= 0) & pods.valid
-    per_e = jnp.where(on_node[None, :], per_e, 0.0)
-    idx = jnp.where(on_node, pods.node_id, N)
-    S = WCOLS.shape[0]
-    out = jnp.zeros((S, N + 1), jnp.float32)
-    out = out.at[jnp.arange(S)[:, None],
-                 jnp.broadcast_to(idx[None, :], per_e.shape)].add(per_e)
-    return out[:, :N]
+    symmetric weights, per node — the cycle-start counterpart of
+    processExistingPod (interpod_affinity.go:124-185) — as WCOLS [S, SC] ×
+    M [SC, N] (interpod.class_node_hist). Precision.HIGHEST keeps every f32
+    bit of both sides (the chip's default would round them to bf16): the
+    weights are integer-valued and the counts below 2^16, so each sum is the
+    exact integer a scatter-add over the pods would reach, in any order."""
+    return jnp.dot(WCOLS, M.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def sym_affinity_contrib(

@@ -184,6 +184,109 @@ def bound(name, node, cpu="500m", mem="256Mi", priority=0, idx=0):
     return p
 
 
+def _labelled(name, node, app, priority, cpu="200m", idx=0, **aff):
+    p = bound(name, node, cpu=cpu, priority=priority, idx=idx)
+    p.labels = {"app": app}
+    if aff:
+        p.affinity = Affinity(**aff)
+    return p
+
+
+def _term(app, key=HOSTNAME):
+    return PodAffinityTerm(selector=LabelSelector.of(
+        match_labels={"app": app}), topology_key=key)
+
+
+def _what_if_case(case):
+    """(nodes, existing, pending, PDB-blocked keys) of one pinned what-if."""
+    if case == "burst-lanes":      # also test_burst_lanes_match_single_…
+        rng = random.Random(3)
+        nodes = [mknode(f"n{i}") for i in range(4)]
+        existing = [bound(f"e{i}", f"n{rng.randrange(4)}",
+                          cpu=rng.choice(["400m", "900m", "1500m"]),
+                          priority=rng.randrange(4), idx=i)
+                    for i in range(10)]
+        pending = [Pod(name=f"vip{i}", priority=10 + i,
+                       requests=Resources.make(cpu="1200m", memory="128Mi"),
+                       creation_index=100 + i) for i in range(3)]
+        return nodes, existing, pending, set()
+    if case == "pdb":              # a trial of test_burst_vs_pick_one_node_…
+        rng = random.Random(11)
+        nodes = [mknode(f"n{i}", cpu=2) for i in range(4)]
+        existing = [bound(f"e{i}", f"n{i % 4}",
+                          cpu=rng.choice(["300m", "600m"]),
+                          priority=rng.randrange(5), idx=i)
+                    for i in range(12)]
+        pdb = {e.key for e in existing if rng.random() < 0.4}
+        pending = [Pod(name="vip", priority=50, creation_index=99,
+                       requests=Resources.make(cpu="1500m",
+                                               memory="128Mi"))]
+        return nodes, existing, pending, pdb
+    nodes = [mknode(f"n{i}", cpu=1) for i in range(3)]
+    nodes[0].labels["zone"] = nodes[1].labels["zone"] = "a"
+    nodes[2].labels["zone"] = "b"
+    if case == "anti-affinity-holder":   # tests/test_preempt.py's blocker
+        existing = [
+            _labelled("blocker", "n0", "blue", 1, anti_required=(_term("red"),)),
+            _labelled("peer", "n1", "blue", 200,
+                      anti_required=(_term("red"),)),
+            _labelled("fill", "n2", "grey", 1, cpu="900m")]
+        pending = [Pod(name="vip", priority=100, labels={"app": "red"},
+                       creation_index=50,
+                       requests=Resources.make(cpu="300m", memory="64Mi"))]
+        return nodes, existing, pending, set()
+    if case == "two-priorities-one-burst":
+        # lane 0 (priority 5) may evict only the priority-1 holder, lane 1
+        # (priority 50) both holders: two survivor histograms in one burst
+        existing = [
+            _labelled("lo", "n0", "blue", 1, anti_required=(_term("red"),)),
+            _labelled("hi", "n1", "blue", 10, anti_required=(_term("red"),)),
+            _labelled("top", "n2", "blue", 99, anti_required=(_term("red"),)),
+            _labelled("red0", "n1", "red", 2, cpu="100m", idx=1),
+            _labelled("red1", "n2", "red", 20, cpu="100m", idx=2),
+            _labelled("fill2", "n2", "grey", 1, cpu="500m", idx=3)]
+        pending = [
+            Pod(name="low", priority=5, labels={"app": "red"},
+                creation_index=50,
+                requests=Resources.make(cpu="300m", memory="64Mi")),
+            Pod(name="high", priority=50, labels={"app": "red"},
+                creation_index=51,
+                requests=Resources.make(cpu="300m", memory="64Mi")),
+            # required affinity: the match it needs must SURVIVE the what-if
+            Pod(name="joiner", priority=15, labels={"app": "grey"},
+                creation_index=52,
+                requests=Resources.make(cpu="650m", memory="64Mi"),
+                affinity=Affinity(pod_required=(_term("red"),)))]
+        return nodes, existing, pending, {"default/lo"}
+    if case == "hard-spread":
+        spread = (TopologySpreadConstraint(
+            max_skew=1, topology_key="zone",
+            when_unsatisfiable=UnsatisfiableAction.DO_NOT_SCHEDULE,
+            selector=LabelSelector.of(match_labels={"app": "web"})),)
+        existing = [
+            _labelled("w0", "n0", "web", 1, cpu="600m"),
+            _labelled("w1", "n1", "web", 1, cpu="600m", idx=1),
+            _labelled("w2", "n0", "web", 30, cpu="300m", idx=2),
+            _labelled("w3", "n2", "web", 30, cpu="100m", idx=3),
+            _labelled("g", "n2", "grey", 5, cpu="900m", idx=4)]
+        pending = [Pod(name="w-new", priority=20, labels={"app": "web"},
+                       creation_index=60, topology_spread=spread,
+                       requests=Resources.make(cpu="500m", memory="64Mi"))]
+        return nodes, existing, pending, set()
+    raise KeyError(case)
+
+
+_WHAT_IF_PINNED = {   # per lane: (node, victims, n_candidates, n_pdb_violations)
+    "anti-affinity-holder": [("n0", ["default/blocker"], 2, 0)],
+    "burst-lanes": [("n0", ["default/e2"], 4, 0)] * 3,
+    "hard-spread": [("n1", ["default/w1"], 3, 0)],
+    "pdb": [("n1", ["default/e1", "default/e5"], 4, 0)],
+    "two-priorities-one-burst": [("n0", ["default/lo"], 1, 1),
+                                 ("n1", ["default/hi"], 2, 0),
+                                 ("n2", ["default/fill2"], 1, 0)],
+}
+
+
 class TestFusedPreemptionBurst:
     def _snapshot(self, nodes, existing, pending):
         from kubernetes_tpu.sched.cycle import snapshot_with_keys
@@ -208,16 +311,7 @@ class TestFusedPreemptionBurst:
             build_cycle, default_engine_config)
         from kubernetes_tpu.ops.preempt import preempt_batch, preempt_for_pod
 
-        rng = random.Random(3)
-        nodes = [mknode(f"n{i}") for i in range(4)]
-        existing = [bound(f"e{i}", f"n{rng.randrange(4)}",
-                          cpu=rng.choice(["400m", "900m", "1500m"]),
-                          priority=rng.randrange(4), idx=i)
-                    for i in range(10)]
-        pending = [Pod(name=f"vip{i}", priority=10 + i,
-                       requests=Resources.make(cpu="1200m", memory="128Mi"),
-                       creation_index=100 + i)
-                   for i in range(3)]
+        nodes, existing, pending, _pdb = _what_if_case("burst-lanes")
         _cache, _enc, snap, keys = self._snapshot(nodes, existing, pending)
         uk, ev = keys
         cyc = build_cycle(snap.tables, snap.existing, uk, ev, snap.dims.D,
@@ -342,6 +436,42 @@ class TestFusedPreemptionBurst:
                 f"trial {trial}: node {got_node} != oracle {want_node}")
             assert got_victims == want_victims, (
                 f"trial {trial}: victims {got_victims} != {want_victims}")
+
+    @pytest.mark.parametrize("case", sorted(_WHAT_IF_PINNED))
+    def test_what_if_result_pinned(self, case):
+        """PreemptResult of every lane — node, victims, n_candidates,
+        n_pdb_violations — as the what-if gave it when its survivors' term
+        counts were scattered pod by pod (recorded on that tree): the
+        histogram-and-product seeds (interpod.class_node_hist) hand the
+        affinity, anti-affinity and spread filters the same counts."""
+        import jax
+        import jax.numpy as jnp
+
+        from kubernetes_tpu.ops.lattice import (
+            build_cycle, default_engine_config)
+        from kubernetes_tpu.ops.preempt import preempt_batch
+
+        nodes, existing, pending, pdb = _what_if_case(case)
+        _cache, _enc, snap, (uk, ev) = self._snapshot(nodes, existing,
+                                                      pending)
+        cyc = build_cycle(snap.tables, snap.existing, uk, ev, snap.dims.D,
+                          jnp.float32(1.0), default_engine_config())
+        pdb_arr = np.array([k in pdb for k in snap.existing_keys]
+                           + [False] * (snap.existing.valid.shape[0]
+                                        - len(snap.existing_keys)))
+        res = jax.device_get(preempt_batch(
+            snap.tables, cyc, snap.existing, snap.pending.cls,
+            snap.pending.node_name_req, snap.pending.priority, snap.dims.D,
+            jnp.asarray(pdb_arr)))
+        got = []
+        for lane in range(len(pending)):
+            n = int(res.node[lane])
+            got.append((snap.node_order[n] if n >= 0 else None,
+                        sorted(snap.existing_keys[i] for i in
+                               np.flatnonzero(res.victims[lane])),
+                        int(res.n_candidates[lane]),
+                        int(res.n_pdb_violations[lane])))
+        assert got == _WHAT_IF_PINNED[case]
 
     def test_scheduler_burst_evicts_and_nominates(self):
         """End-to-end through Scheduler.schedule_pending: several failed

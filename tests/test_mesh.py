@@ -37,13 +37,19 @@ pytestmark = [
 ]
 
 
-def _encode(n_nodes, n_pods):
+def _encode(n_nodes, n_pods, n_bound=0):
+    """`n_bound` further flagship pods sit on nodes already (several to a
+    node, so the cycle's per-node seeds CNT / HOLD / WSYM are not zero)."""
     nodes = make_nodes(n_nodes, zones=min(8, n_nodes), racks_per_zone=4)
-    pods = flagship_pods(n_pods, groups=min(12, n_pods))
+    pods = flagship_pods(n_pods + n_bound, groups=min(12, n_pods))
+    existing = pods[n_pods:]
+    for i, p in enumerate(existing):
+        p.node_name = nodes[(i * 7) % (n_nodes // 2)].name
     enc = Encoder()
     enc.vocabs.label_keys.intern(UNSCHEDULABLE_TAINT_KEY)
     enc.vocabs.label_vals.intern("")
-    tables, ex, pe, d = enc.encode_cluster(nodes, [], pods, Dims(N=n_nodes, P=n_pods))
+    tables, ex, pe, d = enc.encode_cluster(
+        nodes, existing, pods[:n_pods], Dims(N=n_nodes, P=n_pods))
     uk = jnp.int32(enc.vocabs.label_keys.get(UNSCHEDULABLE_TAINT_KEY))
     ev = jnp.int32(enc.vocabs.label_vals.get(""))
     return tables, pe, ex, uk, ev, d
@@ -62,16 +68,25 @@ def cluster():
     return _encode(64, 96)
 
 
+@pytest.fixture(scope="module")
+def populated():
+    return _encode(64, 96, n_bound=160)
+
+
 def test_mesh_requires_enough_devices():
     with pytest.raises(RuntimeError, match="devices visible"):
         make_mesh(len(jax.devices()) + 1)
 
 
 @pytest.mark.parametrize("engine", ["waves", "scan"])
-def test_sharded_cycle_matches_unsharded(cluster, engine):
+@pytest.mark.parametrize("which", ["cluster", "populated"])
+def test_sharded_cycle_matches_unsharded(request, which, engine):
     """Both engines — `waves` (the production default) and `scan` (the
-    executable spec) — must be bit-identical sharded vs unsharded."""
-    tables, pending, existing, uk, ev, d = cluster
+    executable spec) — must be bit-identical sharded vs unsharded, on an
+    empty cluster and on one whose existing pods seed the per-node counts
+    (the class × node histogram scatters into a node-sharded array and the
+    seeds' products shard along N)."""
+    tables, pending, existing, uk, ev, d = request.getfixturevalue(which)
     D = d.D
 
     fn = jax.jit(lambda t, p, e, u, v: _cycle(t, p, e, u, v, D, engine))
@@ -96,6 +111,25 @@ def test_sharded_cycle_matches_unsharded(cluster, engine):
     np.testing.assert_array_equal(got_feas, ref_feas)
     np.testing.assert_array_equal(got_used, ref_used)
     np.testing.assert_array_equal(got_mat, ref_mat)
+
+
+def test_sharded_seeds_match_unsharded(populated):
+    """build_cycle's CNT, HOLD and WSYM under the node-sharded mesh, bit for
+    bit those of one device — and not all zero."""
+    tables, _pending, existing, uk, ev, d = populated
+
+    @jax.jit
+    def fn(t, e):
+        cyc = build_cycle(t, e, uk, ev, d.D)
+        return cyc.CNT, cyc.HOLD, cyc.WSYM
+
+    ref = jax.tree.map(np.asarray, fn(tables, existing))
+    mesh = make_mesh(8)
+    got = jax.tree.map(np.asarray, fn(shard_tables(tables, mesh),
+                                      replicate(existing, mesh)))
+    assert all(a.any() for a in ref)
+    for a, b in zip(got, ref):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_sharded_tables_placement(cluster):

@@ -137,6 +137,18 @@ def oracle_score(pod, node, nodes, existing, used_by_node):
     return least_s + balanced + taint_s + soft_ip + even_soft + ssel + img
 
 
+def _encode(nodes, existing, pending, E):
+    base = Dims(N=8, P=8, E=E, R=8, SC=64, S=64, SR=64, SL=64, SN=32, D=8,
+                PAT=2, PAN=2, TS=2, SS=2, CI=4, IMG=8, K=4)
+    enc = Encoder()
+    enc.vocabs.label_keys.intern(UNSCHEDULABLE_TAINT_KEY)
+    enc.vocabs.label_vals.intern("")
+    tables, ex, pe, d = enc.encode_cluster(nodes, existing, pending, base)
+    uk = jnp.int32(enc.vocabs.label_keys.get(UNSCHEDULABLE_TAINT_KEY))
+    ev = jnp.int32(enc.vocabs.label_vals.get(""))
+    return tables, ex, pe, d, uk, ev
+
+
 @pytest.mark.parametrize("seed", range(8))
 def test_score_matrix_matches_oracle(seed):
     rng = random.Random(1000 + seed)
@@ -146,14 +158,7 @@ def test_score_matrix_matches_oracle(seed):
                 for i in range(rng.randint(0, 8))]
     pending = [rand_pod(rng, i) for i in range(rng.randint(1, 6))]
 
-    base = Dims(N=8, P=8, E=16, R=8, SC=64, S=64, SR=64, SL=64, SN=32, D=8,
-                PAT=2, PAN=2, TS=2, SS=2, CI=4, IMG=8, K=4)
-    enc = Encoder()
-    enc.vocabs.label_keys.intern(UNSCHEDULABLE_TAINT_KEY)
-    enc.vocabs.label_vals.intern("")
-    tables, ex, pe, d = enc.encode_cluster(nodes, existing, pending, base)
-    uk = jnp.int32(enc.vocabs.label_keys.get(UNSCHEDULABLE_TAINT_KEY))
-    ev = jnp.int32(enc.vocabs.label_vals.get(""))
+    tables, ex, pe, d, uk, ev = _encode(nodes, existing, pending, E=16)
     got = np.asarray(_scores(jax.device_put(tables), jax.device_put(pe),
                              (uk, ev), d.D, jax.device_put(ex)))
 
@@ -178,3 +183,82 @@ def test_score_matrix_matches_oracle(seed):
                 f"seed={seed} pod={pod.name} node={node.name}: "
                 f"device={got[pi, ni]:.4f} oracle={want:.4f}\n"
                 f"pod={pod}")
+
+
+def _seed_cluster(rng, n_hot):
+    """Existing pods with every kind of term that reaches a seed: preferred
+    (anti-)affinity (± weights in WCOLS), required affinity (hard weight),
+    required anti-affinity (HOLD) — and `n_hot` replicas of one class on n0."""
+    nodes = [rand_node(rng, i) for i in range(5)]
+    existing = [rand_pod(rng, 1000 + i, bound_to=rng.choice(nodes).name)
+                for i in range(40)]
+    for p in existing[::3]:
+        p.affinity = Affinity(
+            pod_required=p.affinity.pod_required,
+            pod_preferred=p.affinity.pod_preferred,
+            anti_preferred=p.affinity.anti_preferred,
+            anti_required=(PodAffinityTerm(
+                selector=LabelSelector.of(
+                    match_labels={"app": rng.choice(APPS)}),
+                topology_key=HOSTNAME),))
+    hot_sel = LabelSelector.of(match_labels={"app": "web"})
+    for i in range(n_hot):
+        existing.append(Pod(
+            name=f"hot{i}", labels={"app": "web"},
+            requests=Resources.make(cpu="1m", memory="1Mi"),
+            affinity=Affinity(
+                anti_preferred=(WeightedPodAffinityTerm(
+                    term=PodAffinityTerm(selector=hot_sel, topology_key=ZONE),
+                    weight=97),),
+                anti_required=(PodAffinityTerm(
+                    selector=LabelSelector.of(match_labels={"app": "db"}),
+                    topology_key=HOSTNAME),)),
+            node_name="n0", creation_index=2000 + i))
+    return nodes, existing
+
+
+@pytest.mark.parametrize("case", ["as-encoded", "over-256-on-one-node",
+                                  "rows-tampered", "tampered-over-256"])
+def test_cycle_seeds_match_numpy_loop(case):
+    """CNT, HOLD and WSYM — products against the class × node histogram
+    (interpod.class_node_hist) — equal a plain loop over the existing pods,
+    bit for bit: with pods on nodes, unbound pods, invalid rows, cls -1
+    (counted under class 0, as every class gather does), a node-term count
+    above 256 (where a bf16 product would round) and negative weights."""
+    from kubernetes_tpu.ops.lattice import build_cycle
+
+    nodes, existing = _seed_cluster(random.Random(case),
+                                    n_hot=301 if "256" in case else 7)
+    tables, ex, _pe, d, uk, ev = _encode(nodes, existing, [], E=512)
+    valid, cls, node_id = (np.array(a) for a in (ex.valid, ex.cls,
+                                                 ex.node_id))
+    if "tampered" in case:
+        pick = np.random.default_rng(7).permutation(np.flatnonzero(valid))
+        valid[pick[:9]] = False          # invalid rows that keep cls / node
+        node_id[pick[9:18]] = -1         # unbound
+        cls[pick[18:24]] = -1            # classless
+        ex = ex._replace(valid=valid, cls=cls, node_id=node_id)
+    cyc = jax.jit(build_cycle, static_argnums=(4,))(
+        jax.device_put(tables), jax.device_put(ex), uk, ev, d.D)
+    TM, has_anti, WCOLS = (np.asarray(a) for a in (cyc.TM, cyc.has_anti,
+                                                   cyc.WCOLS))
+    S, N = TM.shape[0], np.asarray(tables.nodes.valid).shape[0]
+    CNT = np.zeros((S, N), np.int32)
+    HOLD = np.zeros((S, N), np.int32)
+    WSYM = np.zeros((S, N), np.float32)
+    for e in range(valid.shape[0]):
+        if valid[e] and node_id[e] >= 0:
+            c, n = max(int(cls[e]), 0), int(node_id[e])
+            CNT[:, n] += TM[:, c]
+            HOLD[:, n] += has_anti[c, :]
+            WSYM[:, n] += WCOLS[:, c]
+    assert (WCOLS < 0).any() and (WCOLS > 0).any() and HOLD.any()
+    if "256" in case:
+        assert CNT.max() > 256 and HOLD.max() > 256
+        assert np.abs(WSYM).max() > 256 * 97 - 1
+    for name, got, want in (("CNT", cyc.CNT, CNT), ("HOLD", cyc.HOLD, HOLD),
+                            ("WSYM", cyc.WSYM, WSYM)):
+        got = np.asarray(got)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.array_equal(got, want), (
+            f"{case}: {name} differs at {np.argwhere(got != want)[:5]}")
