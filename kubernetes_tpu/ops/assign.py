@@ -22,7 +22,7 @@ selectHost (generic_scheduler.go:290-311).
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +53,9 @@ class AssignResult(NamedTuple):
     node: Array       # [P] i32 — chosen node index, -1 unschedulable
     feasible: Array   # [P] bool
     state: AssignState
+    # ops/gang.py GangVerdict on a gang-bearing batch; None (no pytree
+    # leaf: the gang-free programs are unchanged) everywhere else
+    gang: Any = None
 
 
 def queue_order(pods: PodArrays) -> Array:

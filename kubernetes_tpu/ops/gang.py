@@ -63,8 +63,20 @@ class GangArrays(NamedTuple):
     rank: Array    # [GR] i32 — rejection priority; argmax rejects first
 
 
+class GangVerdict(NamedTuple):
+    """What the loop decided, per group, for the host's record, counters
+    and FailedScheduling Events (sched/scheduler.py)."""
+
+    rejected: Array    # [GR] bool — refused this dispatch: none placed
+    placed: Array      # [GR] i32 — members that fit in the run that
+    #                    refused the group (0 where it was not refused)
+    rounds: Array      # scalar i32 — wave fixpoints this dispatch ran
+    groups: Array      # scalar i32 — groups with a member in the batch
+
+
 class _GangCarry(NamedTuple):
     rejected: Array    # [GR] bool
+    short: Array       # [GR] i32 — `placed` of the run that rejected it
     under: Array       # [GR] bool — underfilled in the latest run
     placed: Array      # [GR] i32 — members placed in the latest run
     rounds: Array      # scalar i32
@@ -92,9 +104,9 @@ def assign_gang(
     soft_rounds: int = 4,
     engine_fn=None,
     return_waves: bool = False,
-) -> tuple[AssignResult, Array]:
+) -> tuple[AssignResult, GangVerdict]:
     """Wave assignment with group-atomic admission. Returns the result plus
-    the [GR] rejected-group mask (host surfaces per-group events from it).
+    the GangVerdict (host surfaces per-group events from it).
     Pods of rejected groups come back node=-1/infeasible.
 
     engine_fn(tables, cyc, pods, init) -> AssignResult lets a sequential
@@ -143,8 +155,10 @@ def assign_gang(
         newly = zero | jnp.where(c.rounds > soft_rounds, partial, one)
         newly = newly & (c.rounds > 0)
         rejected = c.rejected | newly
+        short = jnp.where(newly, c.placed, c.short)
         res, waves, under, placed = run(rejected)
-        return _GangCarry(rejected=rejected, under=under, placed=placed,
+        return _GangCarry(rejected=rejected, short=short, under=under,
+                          placed=placed,
                           rounds=c.rounds + 1, node=res.node,
                           feasible=res.feasible, waves=waves, state=res.state)
 
@@ -155,6 +169,7 @@ def assign_gang(
     # first loop iteration BE the initial run instead.
     final = lax.while_loop(cond, body, _GangCarry(
         rejected=jnp.zeros((GR,), bool),
+        short=jnp.zeros((GR,), jnp.int32),
         under=jnp.ones((GR,), bool),
         placed=jnp.zeros((GR,), jnp.int32),
         rounds=jnp.int32(0),
@@ -171,6 +186,11 @@ def assign_gang(
     ok = (gang.group < 0) | ~dead[jnp.clip(gang.group, 0, GR - 1)]
     result = AssignResult(node=jnp.where(ok, final.node, -1),
                           feasible=final.feasible & ok, state=final.state)
+    verdict = GangVerdict(
+        rejected=dead & gang.valid,
+        placed=jnp.where(final.rejected, final.short, final.placed),
+        rounds=final.rounds,
+        groups=gang.valid.sum(dtype=jnp.int32))
     if return_waves:
-        return result, dead, final.waves
-    return result, dead
+        return result, verdict, final.waves
+    return result, verdict

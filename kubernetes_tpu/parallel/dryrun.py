@@ -198,8 +198,8 @@ def run_dryrun(n_devices: int,
     def gang_step(tables, pending, existing, gang, uk, ev):
         cyc = build_cycle(tables, existing, uk, ev, D2)
         init = initial_state(tables, cyc)
-        res, dead = assign_gang(tables, cyc, pending, init, gang)
-        return res.node, res.feasible, dead
+        res, verdict = assign_gang(tables, cyc, pending, init, gang)
+        return res.node, res.feasible, verdict.rejected
 
     ref = jax.tree.map(np.asarray, gang_step(
         tables, pending, existing, gang, keys[0], keys[1]))

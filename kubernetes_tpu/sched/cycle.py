@@ -178,12 +178,13 @@ def _schedule_batch_impl(
         # group-atomic admission (ops/gang.py); gang=None traces the plain
         # engines, so gang-free batches compile/run exactly as before
         if return_waves and engine == "waves":
-            res, _, waves = assign_gang(tables, cyc, pending, init, gang,
-                                        return_waves=True)
+            res, verdict, waves = assign_gang(
+                tables, cyc, pending, init, gang, return_waves=True)
         else:
             engine_fn = {"scan": assign_batch, "runs": runs_fn}.get(engine)
-            res, _ = assign_gang(
+            res, verdict = assign_gang(
                 tables, cyc, pending, init, gang, engine_fn=engine_fn)
+        res = res._replace(gang=verdict)
     elif engine == "scan":
         res = assign_batch(tables, cyc, pending, init)
     elif engine == "runs":

@@ -33,6 +33,18 @@ PREEMPTION_ATTEMPTS = REG.counter(
 WAVE_SIZE = REG.histogram(
     "scheduler_wave_batch_size", "Pods per batched device wave",
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192))
+# gang admission (ops/gang.py), counted on gang-bearing waves only: how
+# often the wave fixpoint ran (one run a wave is the gang-free cost; every
+# further one is a rejection round), and what became of the pod groups
+GANG_ROUNDS = REG.counter(
+    "scheduler_gang_wave_rounds_total",
+    "Wave fixpoints run by gang-bearing dispatches (rejection rounds "
+    "restart the fixpoint)")
+GANG_GROUPS = REG.counter(
+    "scheduler_gang_groups_total",
+    "Pod groups in gang-bearing waves, by result (admitted: at least "
+    "min-available members placed; rejected: none placed)",
+    labels=("result",))
 # cache-consistency sweep (sched/debugger.py ConsistencySweeper — the kube
 # cacheComparer made periodic): divergences found between the resident
 # encoded state and informer truth, and self-heal re-encodes taken
@@ -208,6 +220,11 @@ def observe_wave(stats, queue_lengths, cache_counts) -> None:
         POD_SCHEDULE_ATTEMPTS.inc(stats.unschedulable, result="unschedulable")
     if stats.bind_errors:
         POD_SCHEDULE_ATTEMPTS.inc(stats.bind_errors, result="error")
+    if stats.gang_rounds:
+        GANG_ROUNDS.inc(stats.gang_rounds)
+        GANG_GROUPS.inc(stats.gang_groups - stats.gang_groups_rejected,
+                        result="admitted")
+        GANG_GROUPS.inc(stats.gang_groups_rejected, result="rejected")
     if isinstance(queue_lengths, dict):
         observe_queue_depths(queue_lengths)
     else:

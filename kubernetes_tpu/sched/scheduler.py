@@ -109,6 +109,14 @@ class CycleStats:
     assignments: Dict[str, str] = field(default_factory=dict)
     # pod keys that failed this wave (feeds FailedScheduling events)
     failed_keys: List[str] = field(default_factory=list)
+    # gang admission (ops/gang.py), on a gang-bearing wave only: the wave
+    # fixpoints the dispatch ran, the pod groups in the batch, those it
+    # refused, and for each member of a refused group why (the message of
+    # its FailedScheduling Event, by pod key)
+    gang_rounds: int = 0
+    gang_groups: int = 0
+    gang_groups_rejected: int = 0
+    gang_refusals: Dict[str, str] = field(default_factory=dict)
 
 
 @dataclass
@@ -136,6 +144,7 @@ class Wave:
     node_order: Sequence[str] = ()  # of the snapshot ACTUALLY dispatched
     node_idx: Any = None            # node row per batch pod, -1 = none
     attribution: Any = None         # the dispatch's ExplainResult, on host
+    gang_verdict: Any = None        # ops/gang.py GangVerdict, on host
     # ---- set by commit ---- #
     explain: Optional[Dict[str, Any]] = None   # the explainer's rendering
 
@@ -575,6 +584,7 @@ class Scheduler:
                 rc=wave.rc, micro=wave.micro, extra=wave.extra)
             return stats
         failures = self._commit_stage(wave)
+        self._account_gangs(wave, failures)
         self._preempt_and_requeue(wave, failures)
         span.mark("requeue")
         return self._record(wave)
@@ -779,7 +789,8 @@ class Scheduler:
             self._prestage(wave)
             span.mark("dispatch")
             try:
-                wave.node_idx, wave.attribution = handle.result()
+                wave.node_idx, wave.attribution, wave.gang_verdict = \
+                    handle.result()
             except DispatchAbandonedError:
                 span.mark("readback")
                 return False
@@ -796,7 +807,8 @@ class Scheduler:
     def _engine_call(self, tables, pending, keys, existing, gang, dims,
                      runs, engine: str, rc: int, prewarmer=None, mesh=None):
         """The wave's one call into the engine (primary and fallback):
-        `(node, attribution or None)`, still on the device."""
+        `(node, attribution or None, gang verdict or None)`, still on the
+        device."""
         explain = self.explainer is not None
         out = _schedule_batch(
             tables, pending, keys, dims.D, existing,
@@ -806,10 +818,8 @@ class Scheduler:
             extra_plugins=self._extras, extra_weights=self._extra_w,
             gang=gang, dims=dims, prewarmer=prewarmer, mesh=mesh,
             runs=runs, explain=explain, engine=engine, rc=rc)
-        if explain:
-            res, exp = out
-            return res.node, exp
-        return out.node, None
+        res, exp = out if explain else (out, None)
+        return res.node, exp, res.gang
 
     @staticmethod
     def _get_attribution(exp_dev):
@@ -822,6 +832,12 @@ class Scheduler:
         except Exception:  # noqa: BLE001 - observability, not placement
             return None
 
+    def _read_back(self, node, exp, verdict):
+        """A dispatch's results on the host: `(node_idx, attribution,
+        gang verdict)`; the verdict rides the placements' own transfer."""
+        node, verdict = jax.device_get((node, verdict))
+        return node, self._get_attribution(exp), verdict
+
     def _dispatch_primary(self, wave: Wave):
         """The wave's dispatch on the serving backend, run by the
         supervisor's watchdog worker: engine call, then readback."""
@@ -832,8 +848,7 @@ class Scheduler:
             wave.engine, wave.rc, prewarmer=self.prewarmer, mesh=snap.mesh)
         tel = self.telemetry
         if not tel.enabled:
-            node, exp = call()
-            return jax.device_get(node), self._get_attribution(exp)
+            return self._read_back(*call())
         # tier-3 device-time split (runs on the watchdog worker):
         # launch (trace + async enqueue) vs XLA execution
         # (block_until_ready) vs host readback (device_get) — the
@@ -842,15 +857,14 @@ class Scheduler:
         # TraceAnnotation inside a lazily-started profiler trace.
         with tel.device_annotation("ktpu-wave-dispatch"):
             tp0 = time.perf_counter()
-            node, exp = call()
+            node, exp, verdict = call()
             tp1 = time.perf_counter()
             jax.block_until_ready(node)
             tp2 = time.perf_counter()
-            out = jax.device_get(node)
-            exp_h = self._get_attribution(exp)
+            out = self._read_back(node, exp, verdict)
         tel.note_device_split(tp1 - tp0, tp2 - tp1,
                               time.perf_counter() - tp2, token=wave.span)
-        return out, exp_h
+        return out
 
     def _dispatch_fallback(self, wave: Wave, dev, hung: bool = False):
         """Degrade the wave to the CPU backend. Preferred: ship the SAME
@@ -885,9 +899,8 @@ class Scheduler:
             # degraded waves stay explainable: the chaos drill
             # reconstructs a degraded wave's failures from the flight
             # recorder, so the fallback attributes too
-            node, exp = self._engine_call(*arrays, snap.dims, snap.runs,
-                                          engine, rc)
-            return jax.device_get(node), self._get_attribution(exp)
+            return self._read_back(*self._engine_call(
+                *arrays, snap.dims, snap.runs, engine, rc))
 
     def _prestage(self, wave: Wave) -> None:
         """Double-buffered host/device overlap: the dispatch runs on the
@@ -1019,6 +1032,51 @@ class Scheduler:
         self._retire_intent(intent)
         span.mark("retire")
         return aborted
+
+    def _account_gangs(self, wave: Wave,
+                       failures: List[Tuple[Pod, int]]) -> None:
+        """The gang loop's verdict (ops/gang.py), on a gang-bearing wave
+        only: onto the wave's stats (the `scheduler_gang_*` counters), its
+        record (`gang_rounds`, `gang_groups`, `gang_groups_rejected`) and,
+        for every member of a refused group, why, for its FailedScheduling
+        Event. Group ids are looked up now: they are those of the snapshot
+        that was dispatched last, and a refused group's bound count is not
+        moved by this wave's commits. Touches stats and the record only."""
+        verdict = wave.gang_verdict
+        if verdict is None:
+            return
+        stats = wave.stats
+        rejected = verdict.rejected
+        stats.gang_rounds = int(verdict.rounds)
+        stats.gang_groups = int(verdict.groups)
+        stats.gang_groups_rejected = int(rejected.sum())
+        wave.extra.update(gang_rounds=stats.gang_rounds,
+                          gang_groups=stats.gang_groups,
+                          gang_groups_rejected=stats.gang_groups_rejected)
+        if not stats.gang_groups_rejected:
+            return
+        # every member of a refused group is among the failures
+        members: Dict[str, List[Pod]] = {}
+        for pod, _ in failures:
+            if pod.pod_group:
+                members.setdefault(pod.group_key, []).append(pod)
+        enc = self.encoder
+        for key, pods in members.items():
+            g = enc.pod_groups.get(key)
+            if not (0 <= g < len(rejected) and rejected[g]):
+                continue
+            least = enc.group_min.get(g, 0)
+            need = max(least - self.cache.group_bound_count(key), 0)
+            if len(pods) < need:
+                msg = (f"pod group {key}: {len(pods)} of the {need} members "
+                       f"still needed are pending (min-available {least}); "
+                       "none placed")
+            else:
+                msg = (f"pod group {key}: {int(verdict.placed[g])} of the "
+                       f"{need} members still needed fit a node "
+                       f"(min-available {least}); none placed")
+            for pod in pods:
+                stats.gang_refusals[pod.key] = msg
 
     def _preempt_and_requeue(self, wave: Wave,
                              failures: List[Tuple[Pod, int]]) -> None:

@@ -773,19 +773,23 @@ class SchedulerServer:
         # attribution readback) must still get the generic event: gate per
         # pod on whether an attribution doc exists, not on the explainer's
         # mere presence.
+        # A member of a refused gang gets its group's verdict in place of
+        # the generic message: which group, and the members pending or
+        # fitting against those still needed.
         explainer = self.scheduler.explainer
-        failed = []
+        failed: dict = {}   # message -> the pods it is said of
         for key in stats.failed_keys:
             if explainer is not None and explainer.why(key) is not None:
                 continue
+            msg = stats.gang_refusals.get(
+                key, "no nodes available to schedule pod")
             ns, name = meta.split_key(key)
             obj = self.pod_informer.lister.get(ns, name) \
                 if self.pod_informer else None
             if obj is not None:
-                failed.append(obj)
-        if failed:
-            self.recorder.events(failed, "Warning", "FailedScheduling",
-                                 "no nodes available to schedule pod")
+                failed.setdefault(msg, []).append(obj)
+        for msg, objs in failed.items():
+            self.recorder.events(objs, "Warning", "FailedScheduling", msg)
         # an empty call (no pod popped) is part of the wait for pods
         lap("post-wave" if stats.attempted else "idle-wait")
         return stats

@@ -900,13 +900,19 @@ class SchedulerCache:
     def _gang_arrays(self, encoder: Encoder, pending, d: Dims,
                      mesh: object = None):
         """Per-cycle GangArrays for the pending batch, netting each group's
-        `needed` against members already bound/assumed in this cache."""
+        `needed` against members already bound/assumed in this cache. Host
+        Python over every pending pod: on a gang-bearing batch its time is
+        the `gang` child of the phase that took the snapshot."""
+        tr = trace.current()
+        t0 = time.perf_counter() if tr is not None else 0.0
         bound = {encoder.pod_groups.get(gk): c
                  for gk, c in self._group_bound.items()
                  if encoder.pod_groups.get(gk) >= 0}
         g = encoder.build_gang_arrays(list(pending), d, bound)
         if g is not None and mesh is not None:
             g = self._put(g, None, mesh)  # replicate: read by every shard
+        if g is not None and tr is not None:
+            tr.child("gang", time.perf_counter() - t0)
         return g
 
     def _existing_pod_arrays(self, d: Dims) -> PodArrays:

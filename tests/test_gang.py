@@ -356,3 +356,174 @@ def test_gang_annotations_round_trip_v1():
 def test_podgroup_object_key():
     g = PodGroup(name="train", namespace="ml", min_member=8)
     assert g.key == "ml/train"
+
+
+# --------------------------------------------------------------------------- #
+# the served path: Client.local -> apiserver -> SchedulerServer, with jobs that
+# can and jobs that cannot reach their min-available (ISSUE 32); the pods, the
+# wiring and the two checks are the benchmark's own (benchmarks/harness)
+# --------------------------------------------------------------------------- #
+
+SERVED_CFG = {
+    "nodes": 64, "zones": 4, "racks_per_zone": 4, "node_cpu": "32000m",
+    "node_memory": "134217728Ki", "node_pods": 110,
+    "job_sizes": [4, 8], "request_tiers": [["1000m", "2097152Ki"],
+                                          ["4000m", "8388608Ki"]],
+    "job_priorities": [0, 1, 2], "complete_jobs_per_shape": 3,
+    "incomplete_jobs_per_shape": 2, "incomplete_members_share": 0.75,
+    "oversized_jobs_per_size": 1, "oversized_request": ["64000m", "1Gi"],
+    "backlog_pods": 72, "waiting_pods": 48, "batch_pods": 256,
+    "existing_capacity_pods": 512, "dims": {"GR": 32},
+    "preemption": True, "bind_intent_ledger": True,
+    "assumed": {"cycle_interval_s": 0.02, "batch_window_s": 0.05},
+}
+
+
+def _serve(seed, strip_groups=False):
+    """One served run over SERVED_CFG's jobs: every pod is at the apiserver
+    when the scheduler starts; then one plain pod on its own. Returns what
+    the apiserver lists, the nodes, the wave records and the Events."""
+    from benchmarks.harness.probes import wait_until
+    from benchmarks.harness.shapes import gang_jobs
+    from benchmarks.harness.wirings import local
+
+    pop = gang_jobs.Population(SERVED_CFG, seed, 0)
+    must = pop.pending(SERVED_CFG["backlog_pods"], seed, "job")
+    must_not = pop.waiting(seed, "job")
+    pods = must + must_not
+    if strip_groups:
+        pods = [{**p, "metadata": {**p["metadata"], "annotations": {}}}
+                for p in pods]
+    cluster = local.Cluster(SERVED_CFG)
+    try:
+        client = cluster.client
+        nodes = gang_jobs.make_nodes(SERVED_CFG)
+        for o in nodes:
+            client.nodes.create(o)
+        for o in pods:
+            client.pods.create(o)
+        server = cluster.new_server()
+        server.start()
+
+        def bound(names):
+            items = client.pods.list("default")["items"]
+            return sum(1 for p in items if p["metadata"]["name"] in names
+                       and p["spec"].get("nodeName"))
+
+        want = {p["metadata"]["name"] for p in must}
+        assert wait_until(lambda: bound(want) == len(want), 60), \
+            (bound(want), server.last_wave_error)
+        server.recorder.flush(10)
+        listing = client.pods.list("default")["items"]
+        events = client.events.list("default")["items"]
+        # a wave of its own for a pod of no group: the refused jobs leave
+        for p in must_not:
+            client.pods.delete(p["metadata"]["name"], "default")
+        assert wait_until(lambda: not any(
+            server.scheduler.queue.depths().values()), 60)
+        plain = {**must[0], "metadata": {
+            "name": "plain-0", "namespace": "default",
+            "uid": "default/plain-0", "labels": {"app": "plain"}}}
+        client.pods.create(plain)
+        assert wait_until(lambda: bound({"plain-0"}) == 1, 60)
+        records = [r for r in server.scheduler.telemetry.recorder.records()
+                   if (r.get("stats") or {}).get("attempted")]
+        return {"listing": listing, "nodes": nodes, "must": want,
+                "must_not": {p["metadata"]["name"] for p in must_not},
+                "sent": {p["metadata"]["name"]: p for p in must + must_not},
+                "records": records, "events": events}
+    finally:
+        cluster.close()
+
+
+@pytest.fixture(scope="module", params=[3, 2 ** 31 + 11])
+def served(request):
+    return _serve(request.param)
+
+
+def _bound_names(run):
+    return {p["metadata"]["name"] for p in run["listing"]
+            if p["spec"].get("nodeName")}
+
+
+def test_served_complete_jobs_wholly_bound_the_others_wholly_unbound(served):
+    got = _bound_names(served)
+    assert served["must"] <= got
+    assert not served["must_not"] & got
+
+
+def test_served_run_passes_the_benchmarks_checks(served):
+    from benchmarks.harness.checks import gangs, placement
+
+    ctx = {"cfg": SERVED_CFG, "check_spread": True}
+    assert gangs.final_state(served["nodes"], served["listing"], ctx) == []
+    assert placement.final_state(served["nodes"], served["listing"],
+                                 ctx) == []
+
+
+def test_served_wave_record_carries_the_gang_loops_verdict(served):
+    first = served["records"][0]
+    # 8 incomplete + 2 oversized jobs among 22: the first fixpoint, then
+    # rejection rounds
+    assert first["gang_groups"] == 22
+    assert first["gang_groups_rejected"] == 10
+    assert first["gang_rounds"] >= 2
+    assert any(path.endswith("/gang") and path.startswith("snapshot/")
+               for path in first["children"])
+
+
+def test_served_gang_free_wave_carries_no_gang_field(served):
+    last = served["records"][-1]
+    assert last["stats"]["attempted"] == 1
+    assert not [k for k in last if k.startswith("gang")]
+    assert not any(path.endswith("/gang") for path in last["children"])
+
+
+def test_served_refused_gangs_events_name_the_group_and_why(served):
+    said = {}
+    for ev in served["events"]:
+        if ev["reason"] == "FailedScheduling":
+            said[ev["involvedObject"]["name"]] = ev["message"]
+    assert set(said) >= served["must_not"]
+    by_name = {p["metadata"]["name"]: p for p in served["listing"]}
+    pending = needed = 0
+    for name in served["must_not"]:
+        job = by_name[name]["metadata"]["labels"]["app"]
+        assert f"pod group default/{job}:" in said[name]
+        assert "none placed" in said[name]
+        pending += "are pending" in said[name]
+        needed += "fit a node" in said[name]
+    # incomplete jobs lack members; no member of an oversized job fits
+    assert pending == 36 and needed == 12
+    assert all("0 of the" in said[n] for n in served["must_not"]
+               if "fit a node" in said[n])
+
+
+def test_served_gang_counters_count_rounds_and_groups():
+    from kubernetes_tpu.sched.metrics import GANG_GROUPS, GANG_ROUNDS
+
+    rounds0 = GANG_ROUNDS.total()
+    rejected0 = GANG_GROUPS.value(result="rejected")
+    admitted0 = GANG_GROUPS.value(result="admitted")
+    run = _serve(5)
+    gang_waves = [r for r in run["records"] if "gang_rounds" in r]
+    assert GANG_ROUNDS.total() - rounds0 == sum(
+        r["gang_rounds"] for r in gang_waves) >= 2
+    assert GANG_GROUPS.value(result="rejected") - rejected0 == sum(
+        r["gang_groups_rejected"] for r in gang_waves) >= 10
+    assert GANG_GROUPS.value(result="admitted") - admitted0 >= 12
+
+
+@pytest.mark.parametrize("seed", [3, 2 ** 31 + 11])
+def test_served_without_the_annotations_jobs_are_partly_bound(seed):
+    from benchmarks.harness.checks import gangs
+
+    run = _serve(seed, strip_groups=True)
+    assert all("gang_rounds" not in r for r in run["records"])
+    # the scheduler saw no group, so the incomplete jobs' members are
+    # bound; the check reads the groups off the pods as they were meant
+    sent = run["sent"]
+    listing = [{**p, "metadata": sent[p["metadata"]["name"]]["metadata"]}
+               for p in run["listing"] if p["metadata"]["name"] in sent]
+    partly = gangs.final_state(run["nodes"], listing, {})
+    assert len(partly) == 8   # every incomplete job
