@@ -146,7 +146,9 @@ class APIServer:
                  durability: Optional[str] = None):
         from kubernetes_tpu.apiserver.admission import AdmissionChain
         from kubernetes_tpu.apiserver.crd import install_crd_hook
+        from kubernetes_tpu.utils.platform import steady_heap
 
+        steady_heap()  # before the store and the first request allocate
         # max-inflight request gate (maxinflight.go analog): explicit
         # ctor limits win; env KTPU_MAX_INFLIGHT / KTPU_MAX_MUTATING_
         # INFLIGHT otherwise; unset/0 = unlimited (the historical shape)

@@ -114,9 +114,10 @@ class ExtenderServer:
         return f"http://{host}:{port}{self.url_prefix}"
 
     def start(self) -> "ExtenderServer":
-        from ..utils.platform import enable_compile_cache
+        from ..utils.platform import enable_compile_cache, steady_heap
 
         enable_compile_cache()  # before the first verb compiles
+        steady_heap()  # before the first request is served
         self._thread = threading.Thread(target=self._httpd.serve_forever, daemon=True)
         self._thread.start()
         return self

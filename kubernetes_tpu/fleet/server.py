@@ -399,9 +399,10 @@ class FleetServer:
         from ..sched.prewarm import BucketPrewarmer
         from ..sched.supervisor import DispatchSupervisor
         from ..utils.envparse import env_int
-        from ..utils.platform import enable_compile_cache
+        from ..utils.platform import enable_compile_cache, steady_heap
 
         enable_compile_cache()  # before the first tick compiles
+        steady_heap()  # before the first tick commits
         self.batch_size = batch_size
         self.clock = clock
         self.scheduler_name = scheduler_name

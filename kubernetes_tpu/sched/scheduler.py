@@ -19,6 +19,7 @@ does (scheduler.go:436-448); bind errors roll back via cache.forget_pod
 
 from __future__ import annotations
 
+import resource
 import time
 from dataclasses import dataclass, field, replace
 from functools import partial
@@ -127,6 +128,7 @@ class Wave:
     now: float
     t0: float                       # perf_counter at the wave's start
     span: Any = _NULL_SPAN          # sched/telemetry.py wave span
+    minor_faults0: int = 0          # _minor_faults() at the wave's start
     # ---- set by admit ---- #
     cycle: int = 0
     micro: bool = False
@@ -151,6 +153,12 @@ class Wave:
     @property
     def dims(self) -> Optional[Dims]:
         return self.snap.dims if self.snap is not None else None
+
+
+def _minor_faults() -> int:
+    """Page faults the process has taken so far that needed no I/O, over
+    all its threads: the pump's and the informers' beside the wave's own."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
 
 
 class Scheduler:
@@ -506,7 +514,8 @@ class Scheduler:
         # runs: the binder, the in-process apiserver and the store add
         # their time to it as children of the phase that called them
         token = trace.activate(span.trace) if span.enabled else None
-        wave = Wave(now, t0, span)
+        wave = Wave(now, t0, span,
+                    minor_faults0=_minor_faults() if span.enabled else 0)
         try:
             return self._run_wave(wave, micro_only)
         except Exception:
@@ -1130,8 +1139,9 @@ class Scheduler:
 
     def _record(self, wave: Wave) -> CycleStats:
         """Stage 5, record: the governor's end-of-wave reading, the micro
-        counter, the watch plane since the previous wave, and the wave's
-        flight-recorder record. Touches telemetry only."""
+        counter, the process's minor faults over the wave, the watch plane
+        since the previous wave, and the wave's flight-recorder record.
+        Touches telemetry only."""
         stats = wave.stats
         stats.cycle_seconds = time.perf_counter() - wave.t0
         if self.governor is not None:
@@ -1144,8 +1154,10 @@ class Scheduler:
             self.micro_waves += 1
             MICRO_WAVES.inc(scheduler=self.scheduler_name)
         extra = {"snapshot_mode": wave.snap_mode, **wave.extra}
-        if self.watch_plane is not None and wave.span.enabled:
-            extra.update(self.watch_plane())
+        if wave.span.enabled:
+            extra["minor_faults"] = _minor_faults() - wave.minor_faults0
+            if self.watch_plane is not None:
+                extra.update(self.watch_plane())
         if wave.explain:
             extra["explain"] = wave.explain
         self.telemetry.finish_wave(

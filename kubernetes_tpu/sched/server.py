@@ -559,9 +559,11 @@ class SchedulerServer:
             f"{m.get('namespace', 'default')}/{m.get('name', '')}", None)
 
     def start(self) -> "SchedulerServer":
-        from kubernetes_tpu.utils.platform import enable_compile_cache
+        from kubernetes_tpu.utils.platform import (enable_compile_cache,
+                                                   steady_heap)
 
         enable_compile_cache()  # before the loop's first compile
+        steady_heap()  # before the first wave commits
         # the loop's account of the time between waves begins here: the
         # informers' list+sync below is the first wave's `start` phase
         self.scheduler.telemetry.loop_reset()
@@ -680,6 +682,9 @@ class SchedulerServer:
     # -- the loop (wait.Until(scheduleOne) → batched waves) ------------------ #
 
     def _loop(self) -> None:
+        from kubernetes_tpu.utils.platform import steady_heap
+
+        steady_heap()  # this thread commits the waves: room in ITS arena
         # every second between two waves that attempted pods goes to one
         # named stretch (telemetry.loop_lap) and rides the later wave's
         # flight-recorder record as `loop`: where the chip sat idle

@@ -204,12 +204,12 @@ _WAVE_SHAPES = {
                          "requeue/snapshot/prepare"] + _FIRST_SNAPSHOT,
              _HEAD + ["bucket", "stats", "device_split", "children",
                       "snapshot_mode", "waits", "assumed_outstanding",
-                      "seq"]),
+                      "minor_faults", "seq"]),
     "micro": (_BULK,
               _BINDING + _FIRST_SNAPSHOT + ["snapshot/upload"],
               _HEAD + ["micro", "bucket", "stats", "device_split",
                        "children", "snapshot_mode", "waits",
-                       "assumed_outstanding", "seq"]),
+                       "assumed_outstanding", "minor_faults", "seq"]),
     "paused": (["pump", "paused"], None,
                _HEAD + ["stats", "supervisor_events", "seq"]),
     "abandoned": (["pump", "pop", "snapshot", "prewarm", "dispatch",
