@@ -90,8 +90,11 @@ class Trace:
         self._open = _Node()
         self._cur = self._open
 
-    def step(self, msg: str) -> None:
-        self.steps.append((self.clock(), msg))
+    def step(self, msg: str, at: Optional[float] = None) -> None:
+        """Close the phase that just ran. `at` is the instant it ended, on
+        this trace's clock, where the caller read it earlier (a request's
+        arrival, read before the operation it belongs to was known)."""
+        self.steps.append((self.clock() if at is None else at, msg))
         if self._open.kids:
             self._root.kid(msg).merge(self._open)
             self._open = _Node()

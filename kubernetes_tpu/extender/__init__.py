@@ -5,6 +5,7 @@ Reference: pkg/scheduler/core/extender.go + apis/extender/v1/types.go."""
 from .backend import ExtenderBackend
 from .client import ExtenderConfig, ExtenderError, HTTPExtender
 from .server import ExtenderServer
+from .served import ServedExtender
 from .wire import (
     ExtenderArgs,
     ExtenderBindingArgs,
@@ -20,7 +21,7 @@ from .wire import (
 
 __all__ = [
     "ExtenderBackend", "ExtenderConfig", "ExtenderError", "HTTPExtender",
-    "ExtenderServer", "ExtenderArgs", "ExtenderBindingArgs",
+    "ExtenderServer", "ServedExtender", "ExtenderArgs", "ExtenderBindingArgs",
     "ExtenderBindingResult", "ExtenderFilterResult", "ExtenderPreemptionArgs",
     "ExtenderPreemptionResult", "HostPriority", "MAX_EXTENDER_PRIORITY",
     "MetaVictims", "Victims",

@@ -21,19 +21,35 @@ Verb semantics mirrored from the reference's HTTPExtender client
 The backend is also 'cache capable' in the reference sense (extender.go:454
 IsInterested / managedResources): `interested()` lets deployments scope us to
 pods carrying a managed resource.
+
+The mirror's feed is `observe_pod` / `forget_pod` / `observe_node` /
+`forget_node`, one informer event each (extender/served.py wires them);
+`sync_*` are whole-set reconciles for tests. `bind` with a binder assumes
+the pod in the cache before it writes and the informer's echo confirms it
+(state/cache.py assume / finish / expire), so the next pod's `filter` sees
+the placement whether or not the echo has arrived.
+
+One flight-recorder record per POD (docs/OBSERVABILITY.md): the `filter` ->
+`prioritize` -> `bind` of one UID, in the wave record's shape, closed after
+the `bind`'s answer (`answered`) or by the next pod's first verb.
 """
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import threading
+import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 
 from ..api.types import Node, Pod
 from ..api.v1 import node_from_v1, pod_from_v1
-from ..sched.cycle import UNSCHEDULABLE_TAINT_KEY, _diagnose, _feasible, _scores
+from ..component import trace
+from ..sched.cycle import (_diagnose, _feasible, _scores,
+                           snapshot_with_keys)
+from ..sched.telemetry import SchedulerTelemetry
 from ..state.cache import SchedulerCache
 from ..state.dims import Dims
 from ..state.encode import Encoder
@@ -63,6 +79,47 @@ _REASONS = (
 )
 
 
+class _PodStats:
+    """What `SchedulerTelemetry.finish_wave` reads of a wave's stats, for the
+    one pod a record is about."""
+
+    __slots__ = ("attempted", "scheduled", "unschedulable", "bind_errors",
+                 "aborted")
+
+    def __init__(self, scheduled: int, unschedulable: int,
+                 bind_errors: int) -> None:
+        self.attempted = 1
+        self.scheduled = scheduled
+        self.unschedulable = unschedulable
+        self.bind_errors = bind_errors
+        self.aborted = 0
+
+
+class _PodRecord:
+    """One pod's passage through the verbs: the span its phases are marked
+    on (its Trace is `trace.current()` while a verb of this pod runs, so
+    the cache, the binder, the in-process apiserver and the store file
+    their time below the phase that called them) and what is counted
+    beside it."""
+
+    __slots__ = ("uid", "key", "pod", "span", "verbs", "dispatches",
+                 "snapshots", "split", "assumed_outstanding", "confirm",
+                 "feasible", "bound", "bind_error", "dims", "mode")
+
+    def __init__(self, uid: str, key: str, span) -> None:
+        self.uid, self.key, self.span = uid, key, span
+        self.pod: Optional[Pod] = None
+        self.verbs: List[str] = []
+        self.dispatches = self.snapshots = 0
+        self.split = [0.0, 0.0, 0.0]   # launch, execute, readback seconds
+        self.assumed_outstanding: Optional[int] = None
+        self.confirm: Optional[List[float]] = None
+        self.feasible: Optional[int] = None
+        self.bound = self.bind_error = False
+        self.dims = None
+        self.mode = ""
+
+
 class ExtenderBackend:
     """Watch-fed mirror + lattice evaluation for one extender deployment."""
 
@@ -72,26 +129,69 @@ class ExtenderBackend:
         base_dims: Optional[Dims] = None,
         managed_resources: Sequence[str] = (),
         binder: Optional[Callable[[Pod, str], bool]] = None,
+        pod_lookup: Optional[Callable[[str, str], Optional[Pod]]] = None,
     ) -> None:
         self.cache = cache or SchedulerCache()
         self.encoder = Encoder()
         self.base_dims = base_dims
         self.managed_resources = frozenset(managed_resources)
         self.binder = binder
+        # (namespace, name) -> the pending Pod, for a `bind` whose pod this
+        # backend never filtered (the served extender: its pod informer)
+        self.pod_lookup = pod_lookup
+        # KTPU_TELEMETRY=0 turns the per-pod record off like the rest
+        self.telemetry = SchedulerTelemetry(name="extender")
+        # () -> what the watch plane did since the last call, onto the pod's
+        # record (the served extender: its informers' relists, the store's
+        # counters), as Scheduler.watch_plane is
+        self.watch_plane: Optional[Callable[[], dict]] = None
         self._mu = threading.Lock()
+        self._rec: Optional[_PodRecord] = None
+        self._arrival = threading.local()
         self.bound: List[Tuple[str, str]] = []  # (pod key, node) — audit trail
 
     # ------------------------------------------------------------------ #
-    # mirror feed (in production: informer events; in tests: direct calls)
+    # mirror feed: one informer event each (extender/served.py), and the
+    # whole-set reconciles tests call by hand
     # ------------------------------------------------------------------ #
+
+    def observe_pod(self, pod: Pod, live: bool = True) -> None:
+        """A pod informer's add or update. A pod on a node is mirrored (an
+        assumed one confirmed: the echo of this backend's own Binding);
+        one that has terminated or is being deleted (`live` false) frees
+        its node; a pending pod is the caller's, not the mirror's."""
+        cache = self.cache
+        if not pod.node_name:
+            return
+        known = cache.get_pod(pod.key) is not None
+        if not live:
+            if known:
+                cache.remove_pod(pod.key)
+        elif known and not cache.is_assumed(pod.key):
+            cache.update_pod(pod)
+        else:
+            cache.add_pod(pod)
+
+    def forget_pod(self, key: str) -> None:
+        """A pod informer's delete."""
+        if self.cache.get_pod(key) is not None:
+            self.cache.remove_pod(key)
+
+    def observe_node(self, node: Node) -> None:
+        if self.cache.get_node(node.name) is None:
+            self.cache.add_node(node)
+        else:
+            self.cache.update_node(node)
+
+    def forget_node(self, name: str) -> None:
+        if self.cache.get_node(name) is not None:
+            self.cache.remove_node(name)
 
     def sync_nodes(self, nodes: Sequence[Node]) -> None:
         """Full reconcile: `nodes` is the complete node set (informer relist)."""
         known = {n.name for n in self.cache.nodes()}
-        incoming = {n.name for n in nodes}
-        for n in nodes:
-            (self.cache.update_node if n.name in known else self.cache.add_node)(n)
-        for gone in known - incoming:
+        self.upsert_nodes(nodes)
+        for gone in known - {n.name for n in nodes}:
             self.cache.remove_node(gone)
 
     def upsert_nodes(self, nodes: Sequence[Node]) -> None:
@@ -99,9 +199,8 @@ class ExtenderBackend:
         riding a non-cache-capable ExtenderArgs, which carry just the subset
         that survived the caller's earlier predicates for one pod and must NOT
         prune the rest of the mirror."""
-        known = {n.name for n in self.cache.nodes()}
         for n in nodes:
-            (self.cache.update_node if n.name in known else self.cache.add_node)(n)
+            self.observe_node(n)
 
     def sync_scheduled_pods(self, pods: Sequence[Pod]) -> None:
         known = {p.key for p in self.cache.scheduled_pods()}
@@ -130,14 +229,147 @@ class ExtenderBackend:
         return False
 
     # ------------------------------------------------------------------ #
-    # verb: Filter
+    # the per-pod record (callers hold self._mu)
     # ------------------------------------------------------------------ #
 
-    def _snapshot_for(self, pod: Pod, cache: Optional[SchedulerCache] = None):
-        from ..sched.cycle import snapshot_with_keys
+    def arrived(self, t: float) -> None:
+        """The server read the clock when this thread's request arrived,
+        before it knew the verb or the pod: the verb it then calls starts
+        its `decode` there."""
+        self._arrival.t = t
 
-        return snapshot_with_keys(cache or self.cache, self.encoder, [pod],
-                                  self.base_dims)
+    def _enter(self, uid: str, key: str, verb: str) -> Optional[_PodRecord]:
+        """The record of the pod this verb is about: the open one if it is
+        this pod's (the stretch since its last mark was the caller's:
+        transport and the stock scheduler's own work), else a new one, the
+        other pod's closed first. None with telemetry off."""
+        tel = self.telemetry
+        if not tel.enabled:
+            return None
+        t_in = getattr(self._arrival, "t", None)
+        self._arrival.t = None
+        rec = self._rec
+        if rec is not None and rec.uid == uid:
+            rec.span.trace.step("caller", at=t_in)
+        else:
+            if rec is not None:
+                self._finish(rec)
+            span = tel.wave_span("extender-pod")
+            if t_in is not None:
+                span.trace.start = t_in
+            rec = self._rec = _PodRecord(uid, key, span)
+        rec.verbs.append(verb)
+        return rec
+
+    @contextlib.contextmanager
+    def _serving(self, uid: str, key: str, verb: str):
+        """`_enter`, with the record's Trace this thread's `trace.current()`
+        while the verb runs."""
+        rec = self._enter(uid, key, verb)
+        token = trace.activate(rec.span.trace) if rec else None
+        try:
+            yield rec
+        finally:
+            if token is not None:
+                trace.deactivate(token)
+
+    def _finish(self, rec: _PodRecord) -> None:
+        self._rec = None
+        extra = {"pod": rec.key, "verbs": rec.verbs,
+                 "dispatches": rec.dispatches, "snapshots": rec.snapshots,
+                 "snapshot_mode": rec.mode}
+        if rec.feasible is not None:
+            extra["feasible"] = rec.feasible
+        if rec.assumed_outstanding is not None:
+            extra["assumed_outstanding"] = rec.assumed_outstanding
+            extra["waits"] = {"confirm": rec.confirm}
+        if self.watch_plane is not None:
+            extra.update(self.watch_plane())
+        if rec.dispatches:
+            self.telemetry.note_device_split(*rec.split, token=rec.span)
+        self.telemetry.finish_wave(
+            rec.span, engine="extender", dims=rec.dims, extra=extra,
+            stats=_PodStats(int(rec.bound), int(rec.feasible == 0),
+                            int(rec.bind_error)))
+
+    def answered(self, verb: str) -> None:
+        """The server has this verb's reply encoded and is about to send it:
+        the stretch since the last mark (candidate loops, reasons, JSON out)
+        was the `answer`; a `bind`'s answer closes the pod's record."""
+        with self._mu:
+            rec = self._rec
+            if rec is None or not rec.verbs or rec.verbs[-1] != verb:
+                return
+            rec.span.mark("answer")
+            if verb == "bind":
+                self._finish(rec)
+
+    def flush_record(self) -> None:
+        """Close the open record, if any (shutdown; a caller that drives
+        the backend without a server)."""
+        with self._mu:
+            if self._rec is not None:
+                self._finish(self._rec)
+
+    # ------------------------------------------------------------------ #
+    # snapshot + dispatch, shared by the verbs
+    # ------------------------------------------------------------------ #
+
+    def _snapshot_for(self, pod: Pod, cache: Optional[SchedulerCache] = None,
+                      rec: Optional[_PodRecord] = None):
+        cache = cache or self.cache
+        snap, keys = snapshot_with_keys(cache, self.encoder, [pod],
+                                        self.base_dims)
+        if rec is not None:
+            rec.snapshots += 1
+            rec.dims, rec.mode = snap.dims, cache.last_snapshot_mode
+            rec.span.mark("snapshot")
+        return snap, keys
+
+    @staticmethod
+    def _dispatch(program, snap, keys, rec: Optional[_PodRecord] = None):
+        """One device call of a verb's program over the snapshot, read back
+        to the host; on a record, its launch / execute / readback split is
+        added to the pod's."""
+        t0 = time.perf_counter()
+        out = program(snap.tables, snap.pending, keys, snap.dims.D,
+                      snap.existing)
+        if rec is None:
+            return jax.device_get(out)
+        t1 = time.perf_counter()
+        jax.block_until_ready(out)
+        t2 = time.perf_counter()
+        rec.span.mark("dispatch")
+        host = jax.device_get(out)
+        t3 = time.perf_counter()
+        rec.span.mark("readback")
+        rec.dispatches += 1
+        for i, dt in enumerate((t1 - t0, t2 - t1, t3 - t2)):
+            rec.split[i] += dt
+        return host
+
+    def compile_ahead(self) -> list:
+        """Run the three verbs' programs once over the mirror as it stands,
+        and the cache's patch-scatter ladder, so that no later verb at these
+        capacities compiles (upstream's `httpTimeout` defaults to 5 s; the
+        flagship's programs compile for a minute). A real call at the live
+        shapes, as `SchedulerCache.warm_patch_ladder` is: what seeds the
+        cache the verbs' dispatch consults. Returns [(Dims, program)]."""
+        with self._mu:
+            snap, keys = self._snapshot_for(
+                Pod(name="compile-ahead", namespace="kube-system"))
+            warmed = []
+            for name, program in (("filter", _feasible),
+                                  ("diagnose", _diagnose),
+                                  ("prioritize", _scores)):
+                self._dispatch(program, snap, keys)
+                warmed.append((snap.dims, name))
+            self.cache.warm_patch_ladder(snap)
+            return warmed
+
+    # ------------------------------------------------------------------ #
+    # verb: Filter
+    # ------------------------------------------------------------------ #
 
     def filter(self, args: ExtenderArgs) -> ExtenderFilterResult:
         with self._mu:
@@ -145,58 +377,69 @@ class ExtenderBackend:
                 pod = pod_from_v1(args.pod)
             except Exception as e:  # noqa: BLE001 — wire boundary
                 return ExtenderFilterResult(error=f"bad pod: {e}")
+            with self._serving(pod.uid or pod.key, pod.key, "filter") as rec:
+                return self._filter(args, pod, rec)
 
-            cache_capable = args.node_names is not None
-            if not cache_capable and args.nodes is not None:
-                # non-cache-capable callers ship full node objects; refresh the
-                # mirror from them so the lattice reflects the caller's view
-                self.upsert_nodes([node_from_v1(n) for n in args.nodes])
+    def _filter(self, args: ExtenderArgs, pod: Pod,
+                rec: Optional[_PodRecord]) -> ExtenderFilterResult:
+        cache_capable = args.node_names is not None
+        if not cache_capable and args.nodes is not None:
+            # non-cache-capable callers ship full node objects; refresh the
+            # mirror from them so the lattice reflects the caller's view
+            self.upsert_nodes([node_from_v1(n) for n in args.nodes])
+        if rec is not None:
+            rec.pod = pod
+            rec.span.mark("decode")
+        # what the last Bindings waited for their echo, and the assumed pods
+        # whose echo is still out: one whose echo never comes expires here
+        confirm, outstanding = self.cache.drain_confirm_waits()
+        if outstanding:
+            self.cache.cleanup(self.telemetry.clock())
+        if rec is not None:
+            rec.confirm, rec.assumed_outstanding = confirm, outstanding
 
-            snap, keys = self._snapshot_for(pod)
-            mask = jax.device_get(
-                _feasible(snap.tables, snap.pending, keys, snap.dims.D, snap.existing)
-            )[0]
+        snap, keys = self._snapshot_for(pod, rec=rec)
+        mask = self._dispatch(_feasible, snap, keys, rec)[0]
 
-            if cache_capable:
-                candidates = args.node_names or []
-            elif args.nodes is not None:
-                candidates = [n["metadata"]["name"] for n in args.nodes]
+        if cache_capable:
+            candidates = args.node_names or []
+        elif args.nodes is not None:
+            candidates = [n["metadata"]["name"] for n in args.nodes]
+        else:
+            # neither form present: evaluate every mirrored node
+            candidates = list(snap.node_order)
+        index = {name: i for i, name in enumerate(snap.node_order)}
+
+        passing: List[str] = []
+        failed: Dict[str, str] = {}
+        for name in candidates:
+            i = index.get(name)
+            if i is not None and bool(mask[i]):
+                passing.append(name)
             else:
-                # neither form present: evaluate every mirrored node
-                candidates = list(snap.node_order)
-            index = {name: i for i, name in enumerate(snap.node_order)}
+                failed[name] = ""
+        if rec is not None:
+            rec.feasible = len(passing)
 
-            passing: List[str] = []
-            failed: Dict[str, str] = {}
-            need_reasons = False
-            for name in candidates:
+        if failed:
+            comp = self._dispatch(_diagnose, snap, keys, rec)
+            for name in failed:
                 i = index.get(name)
-                if i is not None and bool(mask[i]):
-                    passing.append(name)
-                else:
-                    failed[name] = ""
-                    need_reasons = True
+                if i is None:
+                    failed[name] = "node not found in extender cache"
+                    continue
+                reasons = [
+                    _REASONS[j] for j, part in enumerate(comp) if not bool(part[0][i])
+                ]
+                failed[name] = "; ".join(reasons) or "node is not feasible"
 
-            if need_reasons:
-                comp = jax.device_get(_diagnose(
-                    snap.tables, snap.pending, keys, snap.dims.D, snap.existing))
-                for name in failed:
-                    i = index.get(name)
-                    if i is None:
-                        failed[name] = "node not found in extender cache"
-                        continue
-                    reasons = [
-                        _REASONS[j] for j, part in enumerate(comp) if not bool(part[0][i])
-                    ]
-                    failed[name] = "; ".join(reasons) or "node is not feasible"
-
-            if cache_capable:
-                return ExtenderFilterResult(node_names=passing, failed_nodes=failed)
-            by_name = {n["metadata"]["name"]: n for n in (args.nodes or [])}
-            return ExtenderFilterResult(
-                nodes=[by_name[n] for n in passing if n in by_name],
-                failed_nodes=failed,
-            )
+        if cache_capable:
+            return ExtenderFilterResult(node_names=passing, failed_nodes=failed)
+        by_name = {n["metadata"]["name"]: n for n in (args.nodes or [])}
+        return ExtenderFilterResult(
+            nodes=[by_name[n] for n in passing if n in by_name],
+            failed_nodes=failed,
+        )
 
     # ------------------------------------------------------------------ #
     # verb: Prioritize
@@ -205,34 +448,41 @@ class ExtenderBackend:
     def prioritize(self, args: ExtenderArgs) -> List[HostPriority]:
         with self._mu:
             pod = pod_from_v1(args.pod)
-            snap, keys = self._snapshot_for(pod)
-            raw = jax.device_get(
-                _scores(snap.tables, snap.pending, keys, snap.dims.D, snap.existing)
-            )[0]
+            with self._serving(pod.uid or pod.key, pod.key,
+                               "prioritize") as rec:
+                return self._prioritize(args, pod, rec)
 
-            candidates = (args.node_names if args.node_names is not None
-                          else [n["metadata"]["name"] for n in (args.nodes or [])])
-            index = {name: i for i, name in enumerate(snap.node_order)}
-            vals: List[Tuple[str, float]] = []
-            for name in candidates or []:
-                i = index.get(name)
-                s = float(raw[i]) if i is not None else float("-inf")
-                vals.append((name, s))
+    def _prioritize(self, args: ExtenderArgs, pod: Pod,
+                    rec: Optional[_PodRecord]) -> List[HostPriority]:
+        if rec is not None:
+            rec.pod = pod
+            rec.span.mark("decode")
+        snap, keys = self._snapshot_for(pod, rec=rec)
+        raw = self._dispatch(_scores, snap, keys, rec)[0]
 
-            finite = [s for _, s in vals if s != float("-inf")]
-            hi = max(finite) if finite else 0.0
-            lo = min(finite) if finite else 0.0
-            span = (hi - lo) or 1.0
-            out: List[HostPriority] = []
-            for name, s in vals:
-                if s == float("-inf"):
-                    out.append(HostPriority(host=name, score=0))
-                else:
-                    out.append(HostPriority(
-                        host=name,
-                        score=round((s - lo) / span * MAX_EXTENDER_PRIORITY),
-                    ))
-            return out
+        candidates = (args.node_names if args.node_names is not None
+                      else [n["metadata"]["name"] for n in (args.nodes or [])])
+        index = {name: i for i, name in enumerate(snap.node_order)}
+        vals: List[Tuple[str, float]] = []
+        for name in candidates or []:
+            i = index.get(name)
+            s = float(raw[i]) if i is not None else float("-inf")
+            vals.append((name, s))
+
+        finite = [s for _, s in vals if s != float("-inf")]
+        hi = max(finite) if finite else 0.0
+        lo = min(finite) if finite else 0.0
+        span = (hi - lo) or 1.0
+        out: List[HostPriority] = []
+        for name, s in vals:
+            if s == float("-inf"):
+                out.append(HostPriority(host=name, score=0))
+            else:
+                out.append(HostPriority(
+                    host=name,
+                    score=round((s - lo) / span * MAX_EXTENDER_PRIORITY),
+                ))
+        return out
 
     # ------------------------------------------------------------------ #
     # verb: ProcessPreemption (extender.go:166-230)
@@ -289,16 +539,67 @@ class ExtenderBackend:
     def bind(self, args: ExtenderBindingArgs) -> ExtenderBindingResult:
         with self._mu:
             key = f"{args.pod_namespace}/{args.pod_name}"
-            ok = True
-            if self.binder is not None:
-                pod = self.cache.get_pod(key) or Pod(
-                    name=args.pod_name, namespace=args.pod_namespace, uid=args.pod_uid
-                )
-                try:
-                    ok = self.binder(pod, args.node)
-                except Exception as e:  # noqa: BLE001 — wire boundary
-                    return ExtenderBindingResult(error=str(e))
-            if not ok:
-                return ExtenderBindingResult(error=f"bind {key} -> {args.node} failed")
+            with self._serving(args.pod_uid or key, key, "bind") as rec:
+                if rec is not None:
+                    rec.span.mark("decode")
+                res = self._bind(args, key, rec)
+                if rec is not None:
+                    rec.bound, rec.bind_error = not res.error, bool(res.error)
+                    rec.span.mark("bind-commit")
+                return res
+
+    def _bind(self, args: ExtenderBindingArgs, key: str,
+              rec: Optional[_PodRecord]) -> ExtenderBindingResult:
+        """With a binder the Binding is this backend's to write: the pod is
+        assumed on its node BEFORE the write, so that the next `filter`
+        counts it whether or not the informer has echoed the Binding back
+        yet; the echo confirms it (`observe_pod`), a refused write forgets
+        it, an echo that never comes expires it (`filter`'s cleanup). The
+        inside of the Binding rides the pod's record as `bind-commit`'s
+        children (`assume`, `bind-call`, `finish`), as a wave's does."""
+        if self.binder is None:
             self.bound.append((key, args.node))
             return ExtenderBindingResult()
+        pod = rec.pod if rec is not None and rec.pod is not None \
+            and rec.pod.key == key else None
+        if pod is None and self.pod_lookup is not None:
+            pod = self.pod_lookup(args.pod_namespace, args.pod_name)
+        if pod is None:
+            pod = self.cache.get_pod(key)
+        if pod is None:
+            # assuming a pod of unknown requests and labels would mirror
+            # nothing of it: refuse rather than bind what cannot be counted
+            return ExtenderBindingResult(
+                error=f"bind {key}: the pod is unknown to the extender")
+        if args.pod_uid and pod.uid != args.pod_uid:
+            # the Binding names the caller's pod (extender.go:397 sends its
+            # UID): the apiserver refuses it if that pod is gone
+            pod = dataclasses.replace(pod, uid=args.pod_uid)
+        tr = trace.current()
+        t0 = time.perf_counter()
+        assumed = self.cache.get_pod(key) is None
+        if assumed:
+            self.cache.assume_pod(pod, args.node)
+        t1 = time.perf_counter()
+        tok = None
+        if tr is not None:
+            tr.child("assume", t1 - t0)
+            tok = tr.begin("bind-call")
+        try:
+            ok, err = self.binder(pod, args.node), ""
+        except Exception as e:  # noqa: BLE001 — wire boundary
+            ok, err = False, str(e)
+        t2 = time.perf_counter()
+        if tr is not None:
+            tr.end(tok, t2 - t1)
+        if ok:
+            self.cache.finish_binding(key, self.telemetry.clock())
+            self.bound.append((key, args.node))
+        elif assumed and self.cache.is_assumed(key):
+            self.cache.forget_pod(key)
+        if tr is not None:
+            tr.child("finish", time.perf_counter() - t2)
+        if not ok:
+            return ExtenderBindingResult(
+                error=err or f"bind {key} -> {args.node} failed")
+        return ExtenderBindingResult()

@@ -13,6 +13,12 @@ binary's policy at us with::
 
 and every Filter/Prioritize call is answered from the device lattice.
 A /healthz endpoint mirrors the reference's healthz mux (server.go:216-227).
+
+Each request is counted (`extender_requests_total{verb,code}`) and timed from
+its arrival to the end of its reply (`extender_request_duration_seconds
+{verb}`); the arrival instant and the reply's end also bound the pod's
+flight-recorder record (backend.py: `decode` starts at the arrival, `answer`
+ends with the reply). `KTPU_TELEMETRY=0` turns both off.
 """
 
 from __future__ import annotations
@@ -22,12 +28,22 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from ..component.metrics import DEFAULT_REGISTRY as REG
 from .backend import ExtenderBackend
 from .wire import (
     ExtenderArgs,
     ExtenderBindingArgs,
     ExtenderPreemptionArgs,
 )
+
+EXTENDER_REQUESTS = REG.counter(
+    "extender_requests_total",
+    "Scheduler Extender requests answered, by verb and HTTP status",
+    labels=("verb", "code"))
+EXTENDER_REQUEST_DURATION = REG.histogram(
+    "extender_request_duration_seconds",
+    "A Scheduler Extender request from its arrival to the end of its reply",
+    labels=("verb",))
 
 DEFAULT_VERBS = {
     "filter": "filter",
@@ -52,6 +68,7 @@ class ExtenderServer:
         self.backend = backend
         self.url_prefix = url_prefix.rstrip("/")
         self.verbs = dict(DEFAULT_VERBS, **(verbs or {}))
+        self.by_path = {path: verb for verb, path in self.verbs.items()}
         self.requests_served = 0
 
         server = self
@@ -60,8 +77,10 @@ class ExtenderServer:
             def log_message(self, fmt, *args):  # quiet
                 pass
 
-            def _reply(self, code: int, obj) -> None:
+            def _reply(self, code: int, obj, encoded=None) -> None:
                 body = json.dumps(obj).encode()
+                if encoded is not None:
+                    encoded()   # before a byte leaves: see do_POST
                 self.send_response(code)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Content-Length", str(len(body)))
@@ -78,32 +97,47 @@ class ExtenderServer:
                     self._reply(404, {"Error": "not found"})
 
             def do_POST(self):
+                tel = server.backend.telemetry
+                t_in = tel.clock()
                 length = int(self.headers.get("Content-Length", 0))
                 try:
                     payload = json.loads(self.rfile.read(length) or b"{}")
                 except json.JSONDecodeError as e:
                     self._reply(400, {"Error": f"bad json: {e}"})
                     return
-                verb = self.path[len(server.url_prefix):].strip("/")
+                path = self.path[len(server.url_prefix):].strip("/")
+                verb = server.by_path.get(path)
                 server.requests_served += 1
+                backend = server.backend
+                backend.arrived(t_in)
                 try:
-                    if verb == server.verbs["filter"]:
-                        res = server.backend.filter(ExtenderArgs.from_json(payload))
-                        self._reply(200, res.to_json())
-                    elif verb == server.verbs["prioritize"]:
-                        prios = server.backend.prioritize(ExtenderArgs.from_json(payload))
-                        self._reply(200, [p.to_json() for p in prios])
-                    elif verb == server.verbs["preemption"]:
-                        res = server.backend.process_preemption(
-                            ExtenderPreemptionArgs.from_json(payload))
-                        self._reply(200, res.to_json())
-                    elif verb == server.verbs["bind"]:
-                        res = server.backend.bind(ExtenderBindingArgs.from_json(payload))
-                        self._reply(200, res.to_json())
+                    if verb == "filter":
+                        code, obj = 200, backend.filter(
+                            ExtenderArgs.from_json(payload)).to_json()
+                    elif verb == "prioritize":
+                        code, obj = 200, [p.to_json() for p in backend.prioritize(
+                            ExtenderArgs.from_json(payload))]
+                    elif verb == "preemption":
+                        code, obj = 200, backend.process_preemption(
+                            ExtenderPreemptionArgs.from_json(payload)).to_json()
+                    elif verb == "bind":
+                        code, obj = 200, backend.bind(
+                            ExtenderBindingArgs.from_json(payload)).to_json()
                     else:
-                        self._reply(404, {"Error": f"unknown verb {verb!r}"})
+                        code, obj = 404, {"Error": f"unknown verb {path!r}"}
                 except Exception as e:  # noqa: BLE001 — wire boundary
-                    self._reply(500, {"Error": str(e)})
+                    code, obj = 500, {"Error": str(e)}
+                if verb is None or not tel.enabled:
+                    self._reply(code, obj)
+                    return
+                # the record's `answer` ends once the reply is encoded and
+                # BEFORE a byte of it leaves: the caller's next verb can
+                # arrive on another thread as soon as it has read this one
+                self._reply(code, obj,
+                            encoded=lambda: backend.answered(verb))
+                EXTENDER_REQUESTS.inc(verb=verb, code=str(code))
+                EXTENDER_REQUEST_DURATION.observe(tel.clock() - t_in,
+                                                  verb=verb)
 
         self._httpd = ThreadingHTTPServer((host, port), Handler)
         self._thread: Optional[threading.Thread] = None

@@ -193,3 +193,160 @@ def test_every_metric_has_its_file_and_its_reader(entry):
         cell.HARNESS_DIR, "sources", spec["source"]["kind"] + ".py"))
     cells = {w["name"] for w in BENCH["workloads"]}
     assert set(entry.get("workloads", cells)) <= cells
+
+
+# --------------------------------------------------------------------------- #
+# ISSUE 34: the extender cell behind the seams: its wiring and kind TOGETHER,
+# the stand-in's choice, the answers check, the roofline reader
+# --------------------------------------------------------------------------- #
+
+from benchmarks.harness import reference  # noqa: E402
+from benchmarks.harness.checks import extender_answers  # noqa: E402
+from benchmarks.harness.kinds import extender_loop  # noqa: E402
+from benchmarks.harness.sources import extender_roofline  # noqa: E402
+
+EXT = cell.load_json(cell.ROOT, "benchmarks", "configs", "extender-5k.json")
+EXT_CELL = "extender-5k.filter-prioritize"
+
+
+def test_the_extender_cell_names_its_modules_and_they_are_there():
+    entry, cfg, tr = cell.find_cell(BENCH, EXT_CELL)
+    assert (entry["chips"], entry["traffic"]) == (1, "scheduleone-backlog")
+    plugs = cell.plug_ins(BENCH, "per_layer", EXT_CELL, cfg, tr)
+    assert plugs["kind"] is extender_loop
+    assert plugs["wiring"].__name__.endswith("wirings.extender")
+    assert plugs["shapes"].__name__.endswith("shapes.equal_groups")
+    assert [n for n, _m in plugs["checks"]] == ["placement",
+                                                "extender_answers"]
+    d = plugs["wiring"].serving_dims(cfg)
+    assert (d.N, d.P, d.E, d.SC, d.SL) == (5120, 8, 65536, 64, 64)
+    # the source's shapes are not cut: the flagship's cluster and groups,
+    # its 50,000 pods bound, every node's name with every filter
+    flagship = cell.load_json(cell.ROOT, "benchmarks", "configs",
+                              "flagship-5k.json")
+    for key in ("nodes", "zones", "racks_per_zone", "node_cpu",
+                "node_memory", "node_pods", "groups", "roles",
+                "request_tiers", "zone_spread", "existing_pods"):
+        assert cfg[key] == flagship[key], key
+    assert cfg["candidate_names_per_filter"] == cfg["nodes"] == 5000
+    policy = cfg["extender_policy"]
+    assert (policy["nodeCacheCapable"], policy["ignorable"],
+            policy["httpTimeout_s"], policy["weight"]) == (True, False, 5, 1)
+    assert cfg["backlog_pods"] % cfg["groups"] == 0 \
+        and cfg["backlog_pods"] >= 100
+    kind = extender_loop.Kind(tr, cfg, 40.0)
+    assert (kind.prebound, kind.work, kind.check_spread) == (
+        50000, 50000, True)
+    assert extender_loop.KEPT.every == 10
+    # what the cell reports: the drain rate, and the stand-in's own share
+    assert EXT_CELL in next(m for m in BENCH["end_to_end"] if m["name"]
+                            == "drain_pods_per_s")["workloads"]
+    named = {m["name"] for m in cell.metrics_of(BENCH, "per_layer", EXT_CELL)}
+    assert {"standin_self_ms_per_pod", "extender_dispatches_per_pod",
+            "extender_engine_roofline_pct", "bind_commit_ms_per_pod",
+            "device_idle_pct.backlog"} <= named
+
+
+@pytest.mark.parametrize("answer, malformed, chosen", [
+    ([("a", 3), ("b", 10), ("c", 10)], "", {"b", "c"}),
+    ([("a", 3), ("b", 10)], "candidates unscored", {"b"}),
+    ([("a", 3), ("b", 9), ("c", 2), ("b", 9)], "scored twice", {"b"}),
+    ([("a", 3), ("b", 9), ("c", 2), ("d", 10)], "hosts not asked", {"b"}),
+    ([("a", 11), ("b", 4), ("c", 2)], "scored 11", {"b"}),
+    ([("a", 2.5), ("b", 1), ("c", 0)], "scored 2.5", {"b"}),
+])
+def test_the_stand_in_takes_the_highest_score_and_holds_answers_to_form(
+        answer, malformed, chosen):
+    kept = extender_loop.Kept()
+    standin = extender_loop.StandIn(None, {"httpTimeout_s": 5})
+    standin.reseed(3, kept)
+    prio = [{"Host": h, "Score": s} for h, s in answer]
+    hosts = {standin._select_host("p", ["a", "b", "c"], prio)
+             for _ in range(16)}
+    assert hosts == chosen    # ties: every one of them comes up, no other
+    assert len(kept.malformed) == (16 if malformed else 0)
+    assert all(malformed in m for m in kept.malformed)
+
+
+def _world_with_one_anti_pod():
+    cfg = {"nodes": 4, "zones": 2, "racks_per_zone": 1, "node_cpu": "4000m",
+           "node_memory": "8388608Ki", "node_pods": 110, "groups": 1,
+           "roles": {"anti": 1}, "zone_spread": False,
+           "request_tiers": [["1000m", "1048576Ki"]]}
+    from benchmarks.harness import objects
+
+    groups = objects.Groups(cfg, 1, 4)
+    nodes = objects.make_nodes(cfg)
+    world = reference.World(nodes, [groups.pod(0, "shape")])
+    world.add(groups.pod(0, "there", "node-2"), "node-2")
+    return world, groups.pod(0, "asks"), [n["metadata"]["name"]
+                                          for n in nodes]
+
+
+@pytest.mark.parametrize("passed, failed, wrong", [
+    (["node-0", "node-1", "node-3"], {"node-2": "anti-affinity"}, []),
+    (["node-0", "node-1", "node-2", "node-3"], {},
+     ["passed node-2, the reference refuses it"]),
+    (["node-0", "node-3"], {"node-1": "?", "node-2": "anti-affinity"},
+     ["refused node-1"]),
+    (["node-0", "node-1", "node-3"], {},
+     ["0 FailedNodes for 1 nodes not passed"]),
+    (["node-0", "node-1", "node-3"], {"node-2": "x", "node-3": "y"},
+     ["2 FailedNodes for 1 nodes not passed"]),
+])
+def test_a_filter_answer_is_held_to_the_reference_node_by_node(
+        passed, failed, wrong):
+    world, pod, asked = _world_with_one_anti_pod()
+    found = extender_answers.compare(world, pod, asked, passed, failed)
+    assert len(found) == len(wrong)
+    assert all(w in f for w, f in zip(wrong, found))
+
+
+def test_the_answers_check_rebuilds_the_cluster_at_each_kept_call():
+    world, _pod, asked = _world_with_one_anti_pod()
+    cfg_nodes = [{"metadata": {"name": n, "labels": world.labels[n]},
+                  "status": {"allocatable": {"cpu": "4000m",
+                                             "memory": "8388608Ki",
+                                             "pods": "110"}}} for n in asked]
+    shape = world.placed["there"][1]
+    mk = lambda name, node="": {   # noqa: E731
+        **shape, "metadata": {**shape["metadata"], "name": name},
+        "spec": {**shape["spec"], "nodeName": node}}
+    prebound = [mk("there", "node-2")]
+    by_name = {n: mk(n) for n in ("job-1", "job-2", "warm")}
+    history = [("bound", "warm-x", "node-0"), ("deleted", "warm-x", ""),
+               ("bound", "job-1", "node-0"), ("bound", "job-2", "node-1")]
+    extender_loop.KEPT.reset(every=1)
+    try:
+        # job-2 was filtered AFTER job-1's Binding: node-0 and node-2 held
+        # pods of the group by then; an answer from a stale mirror passed
+        # node-0
+        extender_loop.KEPT.filters["job-2"] = (
+            asked, ["node-0", "node-1", "node-3"], {"node-2": "anti"})
+        extender_loop.KEPT.malformed.append("prioritize job-1: scored 11")
+        looked, bad = extender_answers.replay(
+            cfg_nodes, prebound, history, by_name, [shape], {})
+        assert looked == 1 and len(bad) == 1
+        assert "passed node-0, the reference refuses it" in bad[0]
+        assert extender_answers.final_state(cfg_nodes, [], {}) == [
+            "prioritize job-1: scored 11"]
+    finally:
+        extender_loop.KEPT.reset()
+    assert extender_answers.final_state(cfg_nodes, [], {}) == []
+
+
+def test_the_extender_roofline_counts_a_cycles_bytes_once_a_dispatch():
+    from benchmarks.harness import roofline
+
+    dims = {"N": 5120, "P": 8, "E": 65536, "R": 4, "L": 8, "K": 4, "SC": 64}
+    obs = {"trace": {"busy_s": 2.0, "window_s": 20.0}, "rehearse": False,
+           "dims": dims, "device": {"kind": "TPU v5 lite"},
+           "waves": [{"dispatches": 3}, {"dispatches": 2}, {}]}
+    got = extender_roofline.read(obs, {"kind": "extender_roofline"})
+    assert got == pytest.approx(
+        100 * 5 * roofline.cycle_bytes(dims) / 819e9 / 2.0)
+    assert 0 < got < 100
+    # a program that counts no dispatches (the parent), a CPU, no trace
+    assert extender_roofline.read({**obs, "waves": [{}]}, {}) is None
+    assert extender_roofline.read({**obs, "rehearse": True}, {}) is None
+    assert extender_roofline.read({**obs, "trace": None}, {}) is None

@@ -281,3 +281,292 @@ def test_scheduler_with_extender_in_cycle():
         # extender's bind verb (not the local binder)
         assert all(n == "allowed" for _, n in ext_be.bound)
         assert len(ext_be.bound) == 3 and binder.bound == []
+
+
+# --------------------------------------------------------------------------- #
+# the served extender: informers feed the mirror, bind assumes and writes
+# through the apiserver, the verbs compile ahead, one record a pod (ISSUE 34)
+# --------------------------------------------------------------------------- #
+
+import contextlib  # noqa: E402
+import time  # noqa: E402
+
+import pytest  # noqa: E402
+
+from benchmarks.harness import objects  # noqa: E402
+from kubernetes_tpu.extender import ServedExtender  # noqa: E402
+from kubernetes_tpu.state.dims import Dims  # noqa: E402
+
+#: a seeded 64-node cluster of the flagship's shape: all four roles, two
+#: groups each, 16 replicas of every group bound by the flagship's rule
+SEEDED = {"nodes": 64, "zones": 4, "racks_per_zone": 2, "node_cpu": "8000m",
+          "node_memory": "33554432Ki", "node_pods": 110, "groups": 8,
+          "roles": {"plain": 2, "spread": 2, "anti": 2, "affinity": 2},
+          "zone_spread": True,
+          "request_tiers": [["100m", "131072Ki"], ["500m", "1048576Ki"]]}
+
+
+@contextlib.contextmanager
+def served_cluster(seed=7, bound=128):
+    from kubernetes_tpu.apiserver import APIServer
+    from kubernetes_tpu.client import Client
+
+    api = APIServer()
+    client = Client.local(api)
+    groups = objects.Groups(SEEDED, seed, bound // SEEDED["groups"])
+    for n in objects.make_nodes(SEEDED):
+        client.nodes.create(n)
+    for p in objects.prebound_pods(groups, SEEDED["nodes"], bound):
+        client.pods.create(p)
+    # capacities provisioned ahead, as an operator's are: no pod of a test
+    # crosses a bucket (the 129th bound pod would double E and recompile)
+    served = ServedExtender(client, base_dims=Dims(N=64, E=512)).start()
+    try:
+        yield client, served, groups
+    finally:
+        served.stop()
+        api.close()
+
+
+def _wait(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline and not cond():
+        time.sleep(0.01)
+    return cond()
+
+
+def _mirror(backend):
+    return ({n.name: (tuple(sorted(n.labels.items())), n.allocatable)
+             for n in backend.cache.nodes()},
+            {p.key: (p.node_name, tuple(sorted(p.labels.items())), p.requests)
+             for p in backend.cache.scheduled_pods()})
+
+
+def _post(url, verb, body, timeout=5.0):
+    req = urllib.request.Request(
+        f"{url}/{verb}", data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"}, method="POST")
+    with urllib.request.urlopen(req, timeout=timeout) as resp:
+        return resp.status, json.loads(resp.read())
+
+
+def _role_group(groups, role):
+    return next(g for g in range(groups.n) if groups.role[g] == role)
+
+
+def test_the_informer_fed_mirror_equals_a_synced_one():
+    with served_cluster() as (client, served, groups):
+        be = served.backend
+        assert be.cache.node_count == 64 and be.cache.pod_count == 128
+        # adds, updates and deletes, one watch event each
+        extra = objects.make_nodes({**SEEDED, "nodes": 66})[64:]
+        for n in extra:
+            client.nodes.create(n)
+        relabelled = client.nodes.get("node-3", "")
+        relabelled["metadata"]["labels"]["tier"] = "gold"
+        client.nodes.update(relabelled)
+        client.nodes.delete("node-65", "")
+        client.pods.create(groups.pod(0, "late-bound", "node-64"))
+        client.pods.create(groups.pod(1, "pending"))       # not the mirror's
+        client.pods.bind("pending", "node-5", "default")   # now it is
+        moved = client.pods.get("base-g2-0", "default")
+        moved["metadata"]["labels"]["track"] = "canary"
+        client.pods.update(moved)
+        client.pods.delete("base-g3-1", "default")
+        assert _wait(lambda: be.cache.pod_count == 129
+                     and be.cache.get_node("node-65") is None
+                     and "track" in be.cache.get_pod("default/base-g2-0").labels
+                     and "tier" in be.cache.get_node("node-3").labels)
+        # one relist: the watch is down while the cluster changes, and the
+        # token it would resume from is gone
+        inf = served.pod_informer
+        inf.stop()
+        client.pods.delete("base-g4-2", "default")
+        client.pods.create(groups.pod(5, "during-the-gap", "node-9"))
+        gone = client.pods.get("base-g6-3", "default")
+        gone["metadata"]["labels"]["track"] = "stable"
+        client.pods.update(gone)
+        relists = inf.relists
+        inf.last_sync_rv = ""
+        inf.start()
+        assert _wait(lambda: inf.relists == relists + 1
+                     and be.cache.get_pod("default/during-the-gap") is not None
+                     and be.cache.get_pod("default/base-g4-2") is None)
+
+        synced = ExtenderBackend()
+        synced.sync_nodes([node_from_v1(n)
+                           for n in client.nodes.list()["items"]])
+        synced.sync_scheduled_pods([pod_from_v1(p) for p in
+                                    client.pods.list("default")["items"]])
+        assert _mirror(be) == _mirror(synced)
+        assert len(_mirror(be)[0]) == 65 and len(_mirror(be)[1]) == 129
+
+
+def test_a_filter_after_a_bind_sees_the_pod_before_its_echo():
+    with served_cluster() as (client, served, groups):
+        be, anti = served.backend, _role_group(groups, "anti")
+        names = [f"node-{i}" for i in range(64)]
+        # a stock scheduler sends the pod as the apiserver holds it
+        first = client.pods.create(groups.pod(anti, "anti-a"))
+        second = client.pods.create(groups.pod(anti, "anti-b"))
+        assert _wait(lambda: served.pod_informer.lister.get(
+            "default", "anti-b") is not None)
+        _, flt = _post(served.url, "filter", {"Pod": first, "NodeNames": names})
+        host = flt["NodeNames"][0]
+        served.pod_informer.stop()     # the echo of the Binding is held back
+        code, res = _post(served.url, "bind", {
+            "PodName": "anti-a", "PodNamespace": "default",
+            "PodUID": first["metadata"]["uid"], "Node": host})
+        assert (code, res["Error"]) == (200, "")
+        # written through the apiserver, assumed in the mirror, unconfirmed
+        assert client.pods.get("anti-a", "default")["spec"]["nodeName"] == host
+        assert be.cache.is_assumed("default/anti-a")
+        _, flt = _post(served.url, "filter", {"Pod": second, "NodeNames": names})
+        assert host not in flt["NodeNames"]
+        assert "anti-affinity" in flt["FailedNodes"][host]
+        # the echo confirms the assumed pod: one pod, no longer assumed
+        served.pod_informer.start()
+        assert _wait(lambda: not be.cache.is_assumed("default/anti-a"))
+        assert be.cache.get_pod("default/anti-a").node_name == host
+        # a Binding the apiserver refuses is forgotten, and says so
+        code, res = _post(served.url, "bind", {
+            "PodName": "anti-b", "PodNamespace": "default",
+            "PodUID": "not-its-uid", "Node": host})
+        assert code == 200 and res["Error"]
+        assert be.cache.get_pod("default/anti-b") is None
+
+
+@pytest.mark.parametrize("role", ["plain", "spread", "anti", "affinity"])
+def test_served_answers_equal_the_oracle(role):
+    import chip_smoke
+
+    with served_cluster(seed=11) as (client, served, groups):
+        nodes = [node_from_v1(n) for n in client.nodes.list()["items"]]
+        by_name = {n.name: n for n in nodes}
+        world = [pod_from_v1(p) for p in client.pods.list("default")["items"]]
+        names = [n.name for n in nodes] + ["ghost"]
+        v1 = groups.pod(_role_group(groups, role), f"asks-{role}")
+        pod = pod_from_v1(v1)
+        code, flt = _post(served.url, "filter", {"Pod": v1, "NodeNames": names})
+        assert (code, flt["Error"]) == (200, "")
+        passing = set(flt["NodeNames"])
+        want = {n.name for n in nodes
+                if chip_smoke.oracle_fits(pod, n, nodes, world, by_name)}
+        assert passing == want and 0 < len(want)
+        assert set(flt["FailedNodes"]) == set(names) - passing
+        assert all(flt["FailedNodes"].values())
+        code, prio = _post(served.url, "prioritize",
+                           {"Pod": v1, "NodeNames": flt["NodeNames"]})
+        assert code == 200
+        assert [h["Host"] for h in prio] == flt["NodeNames"]
+        assert all(isinstance(h["Score"], int) and 0 <= h["Score"] <= 10
+                   for h in prio)
+
+
+def test_one_record_a_pod_with_its_phases_counters_and_children():
+    from kubernetes_tpu.extender.server import (EXTENDER_REQUEST_DURATION,
+                                                EXTENDER_REQUESTS)
+
+    with served_cluster() as (client, served, groups):
+        tel = served.backend.telemetry
+        names = [f"node-{i}" for i in range(64)]
+        served0 = EXTENDER_REQUESTS.value(verb="bind", code="200")
+        pods = [client.pods.create(groups.pod(_role_group(groups, r),
+                                              f"rec-{r}"))
+                for r in ("anti", "spread")]
+        for v1 in pods:
+            _, flt = _post(served.url, "filter",
+                           {"Pod": v1, "NodeNames": names})
+            _, prio = _post(served.url, "prioritize",
+                            {"Pod": v1, "NodeNames": flt["NodeNames"]})
+            _post(served.url, "bind", {
+                "PodName": v1["metadata"]["name"], "PodNamespace": "default",
+                "PodUID": v1["metadata"]["uid"], "Node": prio[0]["Host"]})
+        assert _wait(lambda: len(tel.recorder.records()) == 2)
+        anti, spread = tel.recorder.records()
+        assert anti["pod"] == "default/rec-anti" and anti["seq"] == 1
+        assert anti["verbs"] == ["filter", "prioritize", "bind"]
+        assert anti["stats"]["attempted"] == 1
+        assert anti["stats"]["scheduled"] == 1
+        phases = [name for name, _dt in anti["phases"]]
+        assert phases == [
+            "decode", "snapshot", "dispatch", "readback",   # filter
+            "dispatch", "readback", "answer",               # its reasons
+            "caller", "decode", "snapshot", "dispatch", "readback", "answer",
+            "caller", "decode", "bind-commit", "answer"]
+        assert anti["duration_s"] == pytest.approx(
+            sum(dt for _name, dt in anti["phases"]), abs=2e-3)
+        # an anti-affinity pod is refused somewhere: three dispatches; a
+        # pod every node takes needs no reasons: two
+        assert (anti["dispatches"], anti["snapshots"]) == (3, 2)
+        assert (spread["dispatches"], spread["snapshots"]) == (2, 2)
+        assert spread["feasible"] == 64 > anti["feasible"]
+        assert set(anti["device_split"]) == {"launch_s", "execute_s",
+                                             "readback_s"}
+        for path in ("bind-commit/assume", "bind-commit/bind-call",
+                     "bind-commit/bind-call/apiserver.bind",
+                     "bind-commit/bind-call/apiserver.bind/store.txn",
+                     "bind-commit/finish", "snapshot/prepare"):
+            assert anti["children"][path][0] >= 1, path
+        # the second pod's filter came after the first's Binding: either
+        # the echo was in (0) or it was still out (1); never lost
+        assert spread["assumed_outstanding"] in (0, 1)
+        assert spread["informer_relists"] == 0 and "pump_lag_max" in spread
+        # counted once the reply's last byte is out: the client may be ahead
+        assert _wait(lambda: EXTENDER_REQUESTS.value(
+            verb="bind", code="200") == served0 + 2)
+        assert EXTENDER_REQUEST_DURATION.count(verb="filter") >= 2
+
+
+def test_telemetry_off_records_nothing_and_answers_the_same(monkeypatch):
+    monkeypatch.setenv("KTPU_TELEMETRY", "0")
+    with served_cluster() as (client, served, groups):
+        v1 = client.pods.create(groups.pod(_role_group(groups, "anti"),
+                                           "quiet"))
+        names = [f"node-{i}" for i in range(64)]
+        _, flt = _post(served.url, "filter", {"Pod": v1, "NodeNames": names})
+        assert 0 < len(flt["NodeNames"]) < 64
+        _, res = _post(served.url, "bind", {
+            "PodName": "quiet", "PodNamespace": "default",
+            "PodUID": v1["metadata"]["uid"], "Node": flt["NodeNames"][0]})
+        assert res["Error"] == ""
+        assert served.backend.telemetry.recorder.records() == []
+
+
+def test_no_verb_compiles_after_start_returns():
+    import jax.monitoring
+
+    events, armed = [], [False]
+
+    def listen(event, duration, **kw):   # jax keeps a listener for good
+        if armed[0] and event == "/jax/core/compile/backend_compile_duration":
+            events.append(kw.get("fun_name", "?"))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+
+    with served_cluster() as (client, served, groups):
+        assert [name for _d, name in served.warm_log] == [
+            "filter", "diagnose", "prioritize"]
+        assert [name for name, _s in served.start_log] == [
+            "nodes-sync", "pods-sync", "compile-ahead"]
+        names = [f"node-{i}" for i in range(64)]
+        armed[0] = True
+        try:
+            # pods of every role, each bound before the next is filtered:
+            # the snapshot after a Binding is a patch of the resident planes
+            for role in ("anti", "affinity", "spread", "plain"):
+                v1 = client.pods.create(
+                    groups.pod(_role_group(groups, role), f"cold-{role}"))
+                _, flt = _post(served.url, "filter",
+                               {"Pod": v1, "NodeNames": names})
+                _, prio = _post(served.url, "prioritize",
+                                {"Pod": v1, "NodeNames": flt["NodeNames"]})
+                _, res = _post(served.url, "bind", {
+                    "PodName": v1["metadata"]["name"],
+                    "PodNamespace": "default",
+                    "PodUID": v1["metadata"]["uid"],
+                    "Node": prio[0]["Host"]})
+                assert res["Error"] == ""
+        finally:
+            armed[0] = False
+        assert events == []

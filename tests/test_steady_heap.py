@@ -249,4 +249,7 @@ def test_the_metric_is_data_over_that_field():
     moved = next(e for e in bench["end_to_end"]
                  if e["name"] == entry["moves"])
     assert entry["moves"] == "drain_pods_per_s"
-    assert set(entry["workloads"]) == set(moved["workloads"])
+    # every drain cell whose record is a scheduler's wave (the extender
+    # cell's per-pod record, ISSUE 34, carries no `minor_faults`)
+    assert set(entry["workloads"]) == set(moved["workloads"]) - {
+        "extender-5k.filter-prioritize"}
