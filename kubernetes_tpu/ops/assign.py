@@ -28,8 +28,10 @@ import jax
 import jax.numpy as jnp
 
 from ..state.arrays import Array, ClusterTables, PodArrays
+from ..state.dims import affinity_agg
 from .fit import fit_row, resource_scores_row
-from .interpod import affinity_rows, soft_affinity_row
+from .interpod import (TermCounts, affinity_rows, soft_affinity_row,
+                       term_domain_counts)
 from .lattice import CycleArrays
 from .ports import port_conflict_row
 from .scores import even_spread_soft_row, selector_spread_row
@@ -146,6 +148,23 @@ def assign_batch(
     return AssignResult(node=node_out, feasible=feas_out, state=final)
 
 
+def state_affinity_table(
+    tables: ClusterTables, cyc: CycleArrays, state: AssignState, rows: int
+) -> TermCounts | None:
+    """What the row functions below take as `table`: `state`'s [S, N]
+    in-domain count table (interpod.term_domain_counts) where a program that
+    evaluates `rows` classes against it would ask for at least as many
+    aggregates row by row (state/dims.py affinity_agg: "term"), else None
+    and each row aggregates its own slots ("row")."""
+    classes = tables.classes
+    slots = (classes.aff_terms.shape[1] + classes.anti_terms.shape[1]
+             + classes.paff_terms.shape[1] + classes.panti_terms.shape[1])
+    if affinity_agg(rows, slots, cyc.TM.shape[0]) == "row":
+        return None
+    return term_domain_counts(tables.terms, state.CNT, tables.nodes,
+                              cyc.ELD.shape[2] - 1)
+
+
 def mask_context_row(
     tables: ClusterTables,
     cyc: CycleArrays,
@@ -153,20 +172,22 @@ def mask_context_row(
     cls: Array,
     node_name_req: Array,
     valid: Array,
+    table: TermCounts | None = None,
 ) -> Array:
     """The Filter components that are CONSTANT across a run of same-class
     replicas when the class is self-interaction-free (ops/runs.py): the
     static lattice, inter-pod affinity/anti-affinity (counts only move at
     placed nodes, through terms such a class never reads), hard topology
     spread, spec.nodeName, and pod validity. The run-collapsed engine
-    evaluates this once per RUN; pod_mask_row recomposes it per pod."""
+    evaluates this once per RUN; pod_mask_row recomposes it per pod.
+    `table` is `state_affinity_table(state)` where the caller built one."""
     from .lattice import _on
 
     nodes, classes, terms = tables.nodes, tables.classes, tables.terms
     ecfg = cyc.ecfg
     D = cyc.ELD.shape[2] - 1
     aff_ok, anti_ok = affinity_rows(
-        cls, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D
+        cls, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D, table
     )
     interpod_ok = (aff_ok & anti_ok) | ~_on(ecfg.f_interpod)
     spread_ok = spread_row(
@@ -245,6 +266,7 @@ def pod_mask_row(
     cls: Array,
     node_name_req: Array,
     valid: Array,
+    table: TermCounts | None = None,
 ) -> Array:
     """Full Filter mask [N] for one pod against a given assume-state — the
     tensor analog of podFitsOnNode (generic_scheduler.go:628-706). Shared by
@@ -254,7 +276,8 @@ def pod_mask_row(
     run-constant context half and the per-placement dynamic half — boolean
     conjunction, so the regrouping is exact."""
     return (
-        mask_context_row(tables, cyc, state, cls, node_name_req, valid)
+        mask_context_row(tables, cyc, state, cls, node_name_req, valid,
+                         table)
         & mask_dynamic_row(tables, cyc, cls, state.used,
                            state.ppa, state.ppw, state.ppt,
                            state.vol_any, state.vol_rw)
@@ -276,11 +299,12 @@ def score_context_row(
     cyc: CycleArrays,
     state: AssignState,
     cls: Array,
+    table: TermCounts | None = None,
 ) -> ScoreContext:
     nodes, classes, terms = tables.nodes, tables.classes, tables.terms
     D = cyc.ELD.shape[2] - 1
     soft_ip = soft_affinity_row(cls, classes, terms, state.CNT, nodes, D,
-                                TM=cyc.TM, WSYM=state.WSYM)
+                                TM=cyc.TM, WSYM=state.WSYM, table=table)
     even_soft = even_spread_soft_row(
         cls, classes, terms, state.CNT, nodes, cyc.static.node_match[cls], D)
     ssel = selector_spread_row(
@@ -316,6 +340,7 @@ def score_row(
     cyc: CycleArrays,
     state: AssignState,
     cls: Array,
+    table: TermCounts | None = None,
 ) -> Array:
     """Full Score row [N] for one pod class against a live assume-state —
     prioritizeNodes' weighted sum (generic_scheduler.go:714-869) with the
@@ -323,7 +348,7 @@ def score_row(
     score-matrix surface."""
     return score_combine_row(
         tables, cyc, cls, state.used,
-        score_context_row(tables, cyc, state, cls))
+        score_context_row(tables, cyc, state, cls, table))
 
 
 def feasible_matrix(
@@ -333,8 +358,9 @@ def feasible_matrix(
     (no assignment feedback) — findNodesThatFit (generic_scheduler.go:473) as
     one vmapped tensor, used for golden tests and the extender Filter verb."""
     state = initial_state(tables, cyc)
+    table = state_affinity_table(tables, cyc, state, pods.valid.shape[0])
     return jax.vmap(
-        lambda c, nnr, v: pod_mask_row(tables, cyc, state, c, nnr, v)
+        lambda c, nnr, v: pod_mask_row(tables, cyc, state, c, nnr, v, table)
     )(pods.cls, pods.node_name_req, pods.valid)
 
 
@@ -361,6 +387,7 @@ def mask_components(
     state = initial_state(tables, cyc)
     nodes, classes, terms = tables.nodes, tables.classes, tables.terms
     D = cyc.ELD.shape[2] - 1
+    table = state_affinity_table(tables, cyc, state, pods.valid.shape[0])
 
     def row(c, nnr, v):
         req_vec = tables.reqs.vec[classes.rid[c]]
@@ -375,7 +402,7 @@ def mask_components(
         )
         port_ok = (ps < 0) | ~conflict
         aff_ok, anti_ok = affinity_rows(
-            c, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D
+            c, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D, table
         )
         spread_ok = spread_row(
             c, classes, terms, cyc.TM, state.CNT, cyc.ELD,
@@ -431,7 +458,8 @@ class ExplainResult(NamedTuple):
 
 
 def _explain_mask_row(tables: ClusterTables, cyc: CycleArrays,
-                      state: AssignState, c: Array):
+                      state: AssignState, c: Array,
+                      table: TermCounts | None = None):
     """The cheap half of attribution for ONE class against `state`: the 8
     class-granular predicate planes reduced to rejected-node counts
     (host/spec.nodeName is per-pod and folded by the caller) plus the
@@ -457,7 +485,7 @@ def _explain_mask_row(tables: ClusterTables, cyc: CycleArrays,
     # interpod/spread decomposed: mask_context_row conjoins (aff ∧ anti)
     # under one flag — KEEP the flag composition in sync with it
     aff_ok, anti_ok = affinity_rows(
-        c, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D)
+        c, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D, table)
     aff_ok = aff_ok | ~_on(ecfg.f_interpod)
     anti_ok = anti_ok | ~_on(ecfg.f_interpod)
     spread_ok = spread_row(
@@ -473,13 +501,14 @@ def _explain_mask_row(tables: ClusterTables, cyc: CycleArrays,
 
 
 def _explain_score_row(tables: ClusterTables, cyc: CycleArrays,
-                       state: AssignState, c: Array):
+                       state: AssignState, c: Array,
+                       table: TermCounts | None = None):
     """The EXPENSIVE half for one class: the composed score row and the
     context score components (soft inter-pod affinity's min/max
     normalization, even-spread, selector-spread — one extra full score
     pass per class, ~an engine wave-iteration's worth of work). Only
     evaluated under the failure-gated branch of explain_assignments."""
-    ctxs = score_context_row(tables, cyc, state, c)
+    ctxs = score_context_row(tables, cyc, state, c, table)
     ctx = jnp.stack([ctxs.soft_ip, ctxs.even_soft, ctxs.ssel])  # [3, N]
     score = score_combine_row(tables, cyc, c, state.used, ctxs)
     return score, ctx
@@ -548,6 +577,8 @@ def explain_assignments(
     validn_scalar = jnp.sum(nv).astype(jnp.int32)
     i32 = jnp.int32
     any_failed = ((chosen < 0) & pods.valid).any()
+    table = state_affinity_table(tables, cyc, state,
+                                 P if granularity == "pod" else SC)
 
     def host_plane(nnr):
         return (nnr < 0) | (nodes.name_id == nnr) | ~_on(cyc.ecfg.f_name)
@@ -577,7 +608,7 @@ def explain_assignments(
 
     if granularity == "pod":
         def mrow(c, nnr):
-            r8, m8 = _explain_mask_row(tables, cyc, state, c)
+            r8, m8 = _explain_mask_row(tables, cyc, state, c, table)
             host_ok = host_plane(nnr)
             host_rej = jnp.sum(nv & ~host_ok).astype(i32)
             reasons = jnp.concatenate([r8[:7], host_rej[None], r8[7:]])
@@ -588,9 +619,9 @@ def explain_assignments(
 
         def pod_score(_):
             def row(c, nnr, ch):
-                _r8, m8 = _explain_mask_row(tables, cyc, state, c)
+                _r8, m8 = _explain_mask_row(tables, cyc, state, c, table)
                 full = m8 & host_plane(nnr)
-                sc_row, cx = _explain_score_row(tables, cyc, state, c)
+                sc_row, cx = _explain_score_row(tables, cyc, state, c, table)
                 topn, tops = _row_topk(
                     jnp.where(full, sc_row, -jnp.inf), K)
                 pn = jnp.where(ch >= 0, ch, topn[0])
@@ -605,7 +636,7 @@ def explain_assignments(
             any_failed, pod_score, cheap_score, None)
     else:
         r8, m8 = jax.vmap(
-            lambda c: _explain_mask_row(tables, cyc, state, c)
+            lambda c: _explain_mask_row(tables, cyc, state, c, table)
         )(jnp.arange(SC, dtype=jnp.int32))
         reasons9_c = jnp.concatenate(
             [r8[:, :7], jnp.zeros((SC, 1), i32), r8[:, 7:]], axis=1)
@@ -633,7 +664,7 @@ def explain_assignments(
 
         def class_score(_):
             sc_rows, cx = jax.vmap(
-                lambda c: _explain_score_row(tables, cyc, state, c)
+                lambda c: _explain_score_row(tables, cyc, state, c, table)
             )(jnp.arange(SC, dtype=jnp.int32))
             masked_c = jnp.where(m8, sc_rows, -jnp.inf)
             topn_c, tops_c = jax.vmap(
@@ -682,12 +713,12 @@ def score_matrix(
     least-requested/balanced-allocation plus soft inter-pod affinity, all
     weight-1 summed. Infeasible nodes score -inf."""
     state = initial_state(tables, cyc)
-    nodes, classes, terms = tables.nodes, tables.classes, tables.terms
-    D = cyc.ELD.shape[2] - 1
+    table = state_affinity_table(tables, cyc, state, pods.valid.shape[0])
 
     def row(c, nnr, v):
-        mask = pod_mask_row(tables, cyc, state, c, nnr, v)
-        return jnp.where(mask, score_row(tables, cyc, state, c), -jnp.inf)
+        mask = pod_mask_row(tables, cyc, state, c, nnr, v, table)
+        return jnp.where(mask, score_row(tables, cyc, state, c, table),
+                         -jnp.inf)
 
     return jax.vmap(row)(pods.cls, pods.node_name_req, pods.valid)
 

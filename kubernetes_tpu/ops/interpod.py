@@ -12,11 +12,24 @@ two quotients:
 
 Live state is carried as per-NODE counts (CNT_node[S, N]: matching pods of term
 s on node n; HOLD_node[S, N]: holders of anti-term s on node n) and aggregated
-over topology domains on demand by scatter-add — because different consumers
-aggregate differently: inter-pod affinity counts pods on ALL nodes carrying the
-key (metadata.go:407-437 has no node filter), while topology spread counts only
+over topology domains on demand — because different consumers aggregate
+differently: inter-pod affinity counts pods on ALL nodes carrying the key
+(metadata.go:407-437 has no node filter), while topology spread counts only
 pods on nodes *eligible* for the incoming pod (metadata.go:145-151). Keeping the
 node axis as the source of truth makes both exact.
+
+The inter-pod aggregate of term s over the domains of term s's own key depends
+on s and the state alone, never on the class that asks. `in_domain_counts` is
+that aggregate (one scatter-add into [A, D+1], one gather back to [A, N]) in
+one of two parameterisations, chosen from static shapes at trace time
+(ops/assign.py state_affinity_table, by state/dims.py affinity_agg): a caller
+that evaluates many classes against ONE state (the waves round over SC
+classes) asks it once for every term — `term_domain_counts`, the [S, N] table
+— and every class SELECTS its slots' rows ("term"); a caller with fewer
+rows x slots than terms (a verb's P pods, a what-if lane's one preemptor, a
+scan step) asks it for its own slots ("row"). A scatter is serial in its
+updates, so rows aggregated is the whole cost. Integer counts either way: the
+two are bit-equal.
 
 The predicate semantics (satisfiesPodsAffinityAntiAffinity :1421-1520):
   * affinity:  ∀ term: node-has-key ∧ domain-count > 0, with the first-pod
@@ -32,6 +45,8 @@ the cycle are visible to later pods — the device analog of the assume cache
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -118,6 +133,50 @@ def domain_agg(
     return seg.at[jnp.arange(A)[:, None], idx].add(vals)
 
 
+class TermCounts(NamedTuple):
+    """What pod (anti-)affinity reads of one state, once per TERM."""
+
+    cnt: Array  # [S, N] i32: pods matching term s in node n's domain of
+    #             term s's key; 0 where n lacks the key
+    tot: Array  # [S] i32: pods matching term s on nodes carrying the key
+
+
+def _in_domain(rows: Array, topo_key: Array, nodes: NodeArrays,
+               D: int) -> TermCounts:
+    """rows [A, N] per-node counts of A terms with keys topo_key [A] →
+    their TermCounts: one scatter-add into [A, D+1], one gather back."""
+    dom, has_key = domain_of_term(nodes, topo_key)           # [A, N]
+    seg = domain_agg(rows, dom, D)                           # [A, D+1]
+    cnt = jnp.take_along_axis(seg, jnp.where(has_key, dom, D), axis=1)
+    return TermCounts(cnt=jnp.where(has_key, cnt, 0),
+                      tot=jnp.sum(jnp.where(has_key, rows, 0), axis=1))
+
+
+def term_domain_counts(
+    terms: TermTable, CNT_node: Array, nodes: NodeArrays, D: int
+) -> TermCounts:
+    """The table: every term of the state aggregated once."""
+    with jax.named_scope("term_domain_counts"):
+        return _in_domain(CNT_node, terms.topo_key, nodes, D)
+
+
+def in_domain_counts(
+    term_slots: Array,       # [A] term ids, -1 pads read term 0
+    terms: TermTable,
+    CNT_node: Array,         # [S, N]
+    nodes: NodeArrays,
+    D: int,
+    table: TermCounts | None = None,
+) -> TermCounts:
+    """(cnt [A, N], tot [A]) for the terms in `term_slots`: their rows of
+    `table` where the caller built one for this state, else aggregated here
+    from the slots' own CNT rows."""
+    s = jnp.maximum(term_slots, 0)
+    if table is not None:
+        return TermCounts(cnt=table.cnt[s], tot=table.tot[s])
+    return _in_domain(CNT_node[s], terms.topo_key[s], nodes, D)
+
+
 def affinity_rows(
     cls: Array,              # scalar class id
     classes: PodClassTable,
@@ -127,19 +186,17 @@ def affinity_rows(
     HOLD_node: Array,        # [S, N]
     nodes: NodeArrays,
     D: int,
+    table: TermCounts | None = None,   # term_domain_counts of CNT_node
 ) -> tuple[Array, Array]:
     """(affinity_ok [N], anti_ok [N]) for one pod against live counts."""
 
     # --- required affinity (satisfiesPodsAffinityAntiAffinity :1431-1444) ---
     ats = classes.aff_terms[cls]  # [AT]
     s = jnp.maximum(ats, 0)
-    dom, has_key = domain_of_term(nodes, terms.topo_key[s])  # [AT, N]
-    seg = domain_agg(CNT_node[s], dom, D)                    # [AT, D+1]
-    cnt = jnp.take_along_axis(seg, jnp.where(dom >= 0, dom, D), axis=1)  # [AT, N]
-    term_ok = has_key & (cnt > 0)
+    cnt, tot = in_domain_counts(ats, terms, CNT_node, nodes, D, table)
     active = ats >= 0
-    all_terms = (~active[:, None] | term_ok).all(0)  # [N]
-    total = jnp.sum(jnp.where(active[:, None] & has_key, CNT_node[s], 0))
+    all_terms = (~active[:, None] | (cnt > 0)).all(0)  # [N]
+    total = jnp.sum(jnp.where(active, tot, 0))
     self_all = (~active | TM[s, cls]).all()
     escape = (total == 0) & self_all
     has_any = active.any()
@@ -147,18 +204,13 @@ def affinity_rows(
 
     # --- incoming pod's anti-affinity (nodeMatchesAnyTopologyTerm :1447-1456) ---
     ans = classes.anti_terms[cls]  # [AN]
-    sa = jnp.maximum(ans, 0)
-    dom_a, has_key_a = domain_of_term(nodes, terms.topo_key[sa])
-    seg_a = domain_agg(CNT_node[sa], dom_a, D)
-    cnt_a = jnp.take_along_axis(seg_a, jnp.where(dom_a >= 0, dom_a, D), axis=1)
-    blocked_own = ((ans >= 0)[:, None] & has_key_a & (cnt_a > 0)).any(0)  # [N]
+    cnt_a, _ = in_domain_counts(ans, terms, CNT_node, nodes, D, table)
+    blocked_own = ((ans >= 0)[:, None] & (cnt_a > 0)).any(0)  # [N]
 
     # --- existing pods' anti-affinity symmetry (:1319-1360) ---
-    S = TM.shape[0]
-    dom_s, _ = domain_of_term(nodes, terms.topo_key)  # [S, N]
-    seg_h = domain_agg(HOLD_node, dom_s, D)           # [S, D+1]
-    hold = jnp.take_along_axis(seg_h, jnp.where(dom_s >= 0, dom_s, D), axis=1)
-    blocked_sym = (TM[:, cls][:, None] & (dom_s >= 0) & (hold > 0)).any(0)  # [N]
+    # per TERM and not per class: computed once under a vmap over classes
+    hold = _in_domain(HOLD_node, terms.topo_key, nodes, D).cnt  # [S, N]
+    blocked_sym = (TM[:, cls][:, None] & (hold > 0)).any(0)  # [N]
 
     return aff_ok, ~(blocked_own | blocked_sym)
 
@@ -172,6 +224,7 @@ def soft_affinity_row(
     D: int,
     TM: Array | None = None,
     WSYM: Array | None = None,
+    table: TermCounts | None = None,   # term_domain_counts of CNT_node
 ) -> Array:
     """Preferred inter-pod (anti)affinity score [N] f32, 0..100 after min/max
     normalization (interpod_affinity.go:119-215). Both directions: the incoming
@@ -182,12 +235,9 @@ def soft_affinity_row(
     array is."""
 
     def contrib(term_slots: Array, weights: Array, sign: float) -> Array:
-        s = jnp.maximum(term_slots, 0)
-        dom, has_key = domain_of_term(nodes, terms.topo_key[s])
-        seg = domain_agg(CNT_node[s], dom, D)
-        cnt = jnp.take_along_axis(seg, jnp.where(dom >= 0, dom, D), axis=1)
+        cnt, _ = in_domain_counts(term_slots, terms, CNT_node, nodes, D, table)
         w = jnp.where(term_slots >= 0, weights, 0).astype(jnp.float32)
-        return sign * (w[:, None] * jnp.where(has_key, cnt, 0)).sum(0)
+        return sign * (w[:, None] * cnt).sum(0)
 
     raw = contrib(classes.paff_terms[cls], classes.paff_w[cls], 1.0) + contrib(
         classes.panti_terms[cls], classes.panti_w[cls], -1.0

@@ -57,9 +57,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..state.arrays import Array, ClusterTables, PodArrays
-from .assign import AssignResult, AssignState, pod_mask_row, score_row
+from .assign import (AssignResult, AssignState, pod_mask_row, score_row,
+                     state_affinity_table)
 from .fit import _fit
-from .interpod import class_term_membership, domain_agg
+from .interpod import class_term_membership, domain_agg, in_domain_counts
 from .lattice import CycleArrays
 
 # plain Python ints only: a module-level jnp scalar would be captured as a
@@ -112,9 +113,12 @@ def interaction_graph(tables: ClusterTables, cyc: CycleArrays) -> Array:
 # the device program's stages carry `jax.named_scope` names, so a profiler
 # trace groups its fusions by stage (no run-time cost: metadata only)
 @jax.named_scope("class_mask_score")
-def _class_mask_score(tables, cyc, state):
+def _class_mask_score(tables, cyc, state, table):
     """[SC, N] Filter mask + Score for every class against `state` — the
     dense analog of findNodesThatFit + prioritizeNodes, once per class.
+    `table` is the round's `state_affinity_table`: built once, outside the
+    class axis (and outside the class blocks below), every class selecting
+    its terms' rows.
 
     Long-context tiling (SURVEY §5 "blockwise tiles over the pod axis"):
     vmapping the full row over SC materializes per-class intermediates like
@@ -129,8 +133,8 @@ def _class_mask_score(tables, cyc, state):
 
     def row(c):
         mask = pod_mask_row(tables, cyc, state, c, jnp.int32(-1),
-                            classes.valid[c])
-        score = score_row(tables, cyc, state, c)
+                            classes.valid[c], table)
+        score = score_row(tables, cyc, state, c, table)
         return mask, jnp.where(mask, score, -jnp.inf)
 
     if SC <= _CLASS_BLOCK:
@@ -242,23 +246,20 @@ def _domain_quota_pass(tables, cyc, state, mask, order_n, allowed_sorted):
     return allowed_sorted
 
 
-def _escape_cap(tables, cyc, state, r):
+def _escape_cap(tables, cyc, state, r, table):
     """Required-affinity first-pod escape: a class whose required terms have
     zero potential matches (predicates.go:1436-1440) admits at most ONE pod
-    this wave, so the followers see its counts next wave."""
+    this wave, so the followers see its counts next wave. The totals are
+    affinity_rows' own (`table`: the round's `state_affinity_table`)."""
     classes = tables.classes
-    terms = tables.terms
-    nodes = tables.nodes
+    D = cyc.ELD.shape[2] - 1
 
     def one(c):
         ats = classes.aff_terms[c]
-        s = jnp.maximum(ats, 0)
         active = ats >= 0
-        k = terms.topo_key[s]
-        has_key = (k[:, None] >= 0) & nodes.valid[None, :]
-        total = jnp.sum(jnp.where(active[:, None] & has_key,
-                                  state.CNT[s], 0))
-        return active.any() & (total == 0)
+        tot = in_domain_counts(ats, tables.terms, state.CNT, tables.nodes,
+                               D, table).tot
+        return active.any() & (jnp.sum(jnp.where(active, tot, 0)) == 0)
 
     escape = jax.vmap(one)(jnp.arange(classes.valid.shape[0]))
     return jnp.where(escape, jnp.minimum(r, 1), r)
@@ -344,7 +345,8 @@ def assign_waves(
         )
         r = jnp.where(nxt_ok, jnp.minimum(remaining, run_cnt), 0)
 
-        mask, score = _class_mask_score(tables, cyc, state)
+        table = state_affinity_table(tables, cyc, state, SC)
+        mask, score = _class_mask_score(tables, cyc, state, table)
         mask = mask & nxt_ok[:, None]
         # score-window admission (EngineConfig.w_window): a class only
         # admits on nodes within the window of its per-class feasible max
@@ -357,7 +359,7 @@ def assign_waves(
         best = jnp.max(jnp.where(mask, score, -jnp.inf), axis=1,
                        keepdims=True)
         adm_mask = mask & (score >= best - cyc.ecfg.w_window)
-        r = _escape_cap(tables, cyc, state, r)
+        r = _escape_cap(tables, cyc, state, r, table)
 
         # independent set over the interaction graph, queue-rank order:
         # a class yields to any earlier-ranked ACTIVE class it interacts

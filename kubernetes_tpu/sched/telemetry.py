@@ -520,6 +520,11 @@ class SchedulerTelemetry:
         if dims is not None:
             rec["bucket"] = {"N": dims.N, "P": dims.P, "E": dims.E,
                              "D": dims.D}
+            # which parameterisation of the pod-affinity aggregate this
+            # record's compiled program runs (state/dims.py affinity_agg)
+            agg = dims.affinity_agg(engine)
+            if agg is not None:
+                rec["affinity_agg"] = agg
         if stats is not None:
             rec["stats"] = {
                 "attempted": stats.attempted,

@@ -34,6 +34,16 @@ def bucket(n: int, minimum: int = 1, align: int = 1) -> int:
     return p
 
 
+def affinity_agg(rows: int, slots: int, S: int) -> str:
+    """How a program that evaluates `rows` classes against ONE state
+    aggregates pod (anti-)affinity counts over topology domains
+    (ops/interpod.py in_domain_counts): "term" — the [S, N] table of all S
+    terms once a state, every row selecting its slots from it — once the rows
+    would ask for at least as many aggregates themselves (`slots` a row),
+    else "row". Static shapes only: the choice is per compiled program."""
+    return "term" if rows * slots >= S else "row"
+
+
 @dataclass(frozen=True)
 class Dims:
     """All array capacities. Fields are hashable/static for jit."""
@@ -86,6 +96,19 @@ class Dims:
     # host-side facts about the encoded batch (not capacities): lets the
     # dispatch layer pick an engine without a device round-trip
     has_node_name: bool = False  # any pending pod sets spec.nodeName
+
+    def affinity_agg(self, engine: str) -> Optional[str]:
+        """`affinity_agg` of the program `engine` runs at these capacities,
+        for the flight recorder: the waves round evaluates SC classes
+        against one state, an extender verb its P pods, a scan or runs step
+        one class. None where the record is not one program's (a fleet tick
+        runs each tenant group's own engine)."""
+        rows = {"waves": self.SC, "extender": self.P, "scan": 1,
+                "runs": 1}.get(engine)
+        if rows is None:
+            return None
+        return affinity_agg(rows, self.AT + self.AN + self.PAT + self.PAN,
+                            self.S)
 
     def union(self, other: Optional["Dims"]) -> "Dims":
         """Field-wise max of two capacity sets — the shared FLEET bucket K

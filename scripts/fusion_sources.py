@@ -6,10 +6,14 @@ fusion whose name or result type matches, the innermost frames of the
 program's own code that its instructions carry (`stack_frame_id` in the
 compiled module's text). At the flagship's capacities the fusion names are
 the ones the chip's trace shows (`breakdown.device_ops`: checked on PR 30's
-traced runs), so a ledger line's `fusion.944 s32[655360]` becomes
-`interpod.py:affinity_rows:138`. Takes 5-6 minutes for the flagship cycle.
+traced runs, and again on PR 35's), so a line's `fusion.823 s32[368640]` becomes
+`interpod.py:_in_domain:150`, the gather that ends the round's `[S, N]`
+in-domain count table (a `jit(take_along_axis)` traced twice keeps its FIRST
+caller's frame: the `hold` gather beside it reads the same line), and
+`'s32\\[6553'`, the per-class aggregates PR 35 took out, finds nothing. Takes
+2-6 minutes for the flagship cycle.
 
-    JAX_PLATFORMS=cpu python3 scripts/fusion_sources.py 's32\\[6553' fusion.913
+    JAX_PLATFORMS=cpu python3 scripts/fusion_sources.py 's32\\[3686' 'f32\\[3686'
     JAX_PLATFORMS=cpu python3 scripts/fusion_sources.py --preempt 8 'pred\\[8,65536\\]'
     ... --dims N=1024,D=1024,P=30720,E=32768,SC=64,SL=64,S=8   (density-1k)
 """

@@ -114,14 +114,18 @@ def test_sharded_cycle_matches_unsharded(request, which, engine):
 
 
 def test_sharded_seeds_match_unsharded(populated):
-    """build_cycle's CNT, HOLD and WSYM under the node-sharded mesh, bit for
-    bit those of one device — and not all zero."""
+    """build_cycle's CNT, HOLD and WSYM, and the in-domain count table built
+    from CNT (interpod.term_domain_counts: cnt [S, N], tot [S]), under the
+    node-sharded mesh, bit for bit those of one device — and not all zero."""
+    from kubernetes_tpu.ops.interpod import term_domain_counts
+
     tables, _pending, existing, uk, ev, d = populated
 
     @jax.jit
     def fn(t, e):
         cyc = build_cycle(t, e, uk, ev, d.D)
-        return cyc.CNT, cyc.HOLD, cyc.WSYM
+        table = term_domain_counts(t.terms, cyc.CNT, t.nodes, d.D)
+        return cyc.CNT, cyc.HOLD, cyc.WSYM, table.cnt, table.tot
 
     ref = jax.tree.map(np.asarray, fn(tables, existing))
     mesh = make_mesh(8)

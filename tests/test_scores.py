@@ -8,6 +8,7 @@ ScheduleAnyway score, SelectorSpread (host+zone), ImageLocality — is compared
 against api/semantics.py on randomized clusters, feasible entries only.
 """
 
+import dataclasses
 import random
 
 import jax
@@ -262,3 +263,278 @@ def test_cycle_seeds_match_numpy_loop(case):
         assert got.dtype == want.dtype and got.shape == want.shape
         assert np.array_equal(got, want), (
             f"{case}: {name} differs at {np.argwhere(got != want)[:5]}")
+
+
+# --------------------------------------------------------------------------- #
+# the [S, N] in-domain count table (ops/interpod.py term_domain_counts): what
+# required and preferred pod (anti-)affinity read of a state, once per TERM;
+# every class selects its slots' rows where a program evaluates many classes
+# against one state ("term"), and aggregates its own slots where it evaluates
+# few ("row"). Integer counts: the two are bit-equal.
+# --------------------------------------------------------------------------- #
+
+def _req(app, key=ZONE):
+    return PodAffinityTerm(
+        selector=LabelSelector.of(match_labels={"app": app}),
+        topology_key=key)
+
+
+def _plain_pod(name, app, i, node="", **aff):
+    return Pod(name=name, labels={"app": app},
+               requests=Resources.make(cpu="10m", memory="16Mi"),
+               affinity=Affinity(**aff), node_name=node, creation_index=i)
+
+
+def _table_cluster(case):
+    """(nodes, existing, pending) for one parametrised case."""
+    from kubernetes_tpu.models.workloads import (
+        density_pods, flagship_pods, make_nodes)
+
+    rng = random.Random(case)
+    if case == "flagship-roles":
+        # spread / host anti-affinity / in-zone affinity to the anti partner
+        nodes = make_nodes(16, zones=4, racks_per_zone=2)
+        pods = flagship_pods(96, groups=6)
+        for i, p in enumerate(pods[48:]):
+            p.node_name = nodes[(i * 4) % 16].name      # zone-0 hosts only
+        return nodes, pods[48:], pods[:48]
+    if case == "no-term":
+        nodes = make_nodes(8, zones=2, racks_per_zone=2)
+        pods = density_pods(40, groups=5)
+        for i, p in enumerate(pods[20:]):
+            p.node_name = nodes[i % 8].name
+        return nodes, pods[20:], pods[:20]
+    if case == "over-256-on-one-domain":
+        nodes, existing = _seed_cluster(rng, n_hot=301)
+        pending = [_plain_pod(f"q{i}", "db", i,
+                              pod_required=(_req("web", HOSTNAME),),
+                              anti_required=(_req("db", HOSTNAME),))
+                   for i in range(3)]
+        return nodes, existing, pending + [rand_pod(rng, 50 + i)
+                                           for i in range(8)]
+    if case == "key-missing-on-some-nodes":
+        nodes = [rand_node(rng, i) for i in range(8)]
+        nodes[0].labels.pop(ZONE, None)
+        nodes[1].labels[ZONE] = "z0"
+        existing = [rand_pod(rng, 100 + i, bound_to=rng.choice(nodes).name)
+                    for i in range(40)]
+        return nodes, existing, [rand_pod(rng, i, bound_to=None)
+                                 for i in range(16)]
+    nodes = [Node(name=f"n{i}",
+                  labels={HOSTNAME: f"n{i}", ZONE: f"z{i % 3}"},
+                  allocatable=Resources.make(cpu="4", memory="8Gi", pods=50))
+             for i in range(6)]
+    existing = [_plain_pod(f"e{i}", APPS[i % 4], 100 + i,
+                           node=f"n{(i * 5) % 6}") for i in range(14)]
+    if case == "slots-half-padded":
+        # AT = 2: classes with one required term leave a -1 slot, classes
+        # with two fill both; one anti term on some (AN = 1)
+        pending = [_plain_pod(f"p{i}", APPS[i % 4], i,
+                              pod_required=(_req(APPS[(i + 1) % 4]),)
+                              + ((_req(APPS[(i + 2) % 4], HOSTNAME),)
+                                 if i % 2 else ()),
+                              anti_required=(_req("queue", HOSTNAME),)
+                              if i % 3 == 0 else ())
+                   for i in range(8)]
+    elif case == "two-classes-share-a-term":
+        # different labels -> different classes; the SAME (selector,
+        # namespaces, key) -> one interned term both select
+        pending = [_plain_pod(f"p{i}", APPS[i % 2], i,
+                              pod_required=(_req("cache", HOSTNAME),),
+                              pod_preferred=(WeightedPodAffinityTerm(
+                                  term=_req("cache", HOSTNAME),
+                                  weight=10 + i),))
+                   for i in range(4)]
+    elif case == "first-pod-escape":
+        # "solo": no pod matches anywhere (total 0) and the class matches its
+        # own term -> passes everywhere. "keyless": the only matching pod
+        # sits on a node WITHOUT the key, which the total must not count
+        nodes.append(Node(name="bare", labels={HOSTNAME: "bare"},
+                          allocatable=Resources.make(cpu="4", memory="8Gi",
+                                                     pods=50)))
+        existing.append(_plain_pod("lost", "keyless", 200, node="bare"))
+        pending = [_plain_pod("p0", "solo", 0,
+                              pod_required=(_req("solo"),)),
+                   _plain_pod("p1", "keyless", 1,
+                              pod_required=(_req("keyless"),)),
+                   _plain_pod("p2", "web", 2,
+                              pod_required=(_req("nobody"),)),
+                   _plain_pod("p3", "db", 3,
+                              pod_required=(_req("cache"),))]
+    else:
+        raise AssertionError(case)
+    return nodes, existing, pending
+
+
+_TABLE_CASES = ["flagship-roles", "no-term", "slots-half-padded",
+                "two-classes-share-a-term", "key-missing-on-some-nodes",
+                "first-pod-escape", "over-256-on-one-domain"]
+
+
+def _table_state(case):
+    from kubernetes_tpu.ops.lattice import build_cycle
+
+    nodes, existing, pending = _table_cluster(case)
+    tables, ex, pe, d, uk, ev = _encode(nodes, existing, pending, E=512)
+    tables, ex, pe = (jax.device_put(x) for x in (tables, ex, pe))
+    cyc = jax.jit(build_cycle, static_argnums=(4,))(tables, ex, uk, ev, d.D)
+    return tables, cyc, pe, d
+
+
+@pytest.mark.parametrize("case", _TABLE_CASES)
+def test_term_table_matches_numpy_loop(case):
+    """SEG [S, D+1], the table's cnt [S, N] and tot [S] against a plain loop
+    over terms and nodes, bit for bit."""
+    from kubernetes_tpu.ops.interpod import (
+        domain_agg, domain_of_term, term_domain_counts)
+
+    tables, cyc, _pe, d = _table_state(case)
+
+    @jax.jit
+    def device(t, CNT):
+        dom, _ = domain_of_term(t.nodes, t.terms.topo_key)
+        return (domain_agg(CNT, dom, d.D),
+                term_domain_counts(t.terms, CNT, t.nodes, d.D))
+
+    seg, table = jax.tree.map(np.asarray, device(tables, cyc.CNT))
+    CNT = np.asarray(cyc.CNT)
+    key = np.asarray(tables.terms.topo_key)
+    domain = np.asarray(tables.nodes.domain)
+    valid = np.asarray(tables.nodes.valid)
+    S, N = CNT.shape
+    SEG = np.zeros((S, d.D), np.int32)
+    DCNT = np.zeros((S, N), np.int32)
+    TOT = np.zeros((S,), np.int32)
+    for s in range(S):
+        on_key = [n for n in range(N) if key[s] >= 0 and valid[n]
+                  and domain[n, key[s]] >= 0]
+        for n in on_key:
+            SEG[s, domain[n, key[s]]] += CNT[s, n]
+            TOT[s] += CNT[s, n]
+        for n in on_key:
+            DCNT[s, n] = SEG[s, domain[n, key[s]]]
+    assert np.array_equal(seg[:, :d.D], SEG)
+    assert table.cnt.dtype == np.int32 and np.array_equal(table.cnt, DCNT)
+    assert np.array_equal(table.tot, TOT)
+    if case == "no-term":
+        assert not (key >= 0).any() and not DCNT.any()
+    else:
+        assert DCNT.any() and TOT.any()
+    if case == "over-256-on-one-domain":
+        assert DCNT.max() > 256
+    if case in ("key-missing-on-some-nodes", "first-pod-escape"):
+        # a node without the key reads 0 although pods on it match
+        keyless = (key[:, None] >= 0) & valid[None, :] \
+            & (domain[:, np.maximum(key, 0)].T < 0)
+        assert (keyless & (CNT > 0)).any() and not DCNT[keyless].any()
+
+
+@pytest.mark.parametrize("case", _TABLE_CASES)
+def test_affinity_rows_with_table_equal_per_row(case):
+    """affinity_rows and soft_affinity_row for EVERY class: selecting from
+    the state's table ("term") against aggregating the class's own slots
+    ("row"), bit for bit."""
+    from kubernetes_tpu.ops.interpod import (
+        affinity_rows, soft_affinity_row, term_domain_counts)
+
+    tables, cyc, pe, d = _table_state(case)
+    classes, terms, nodes = tables.classes, tables.terms, tables.nodes
+    SC = classes.valid.shape[0]
+
+    def rows(table):
+        def one(c):
+            aff, anti = affinity_rows(c, classes, terms, cyc.TM, cyc.CNT,
+                                      cyc.HOLD, nodes, d.D, table)
+            soft = soft_affinity_row(c, classes, terms, cyc.CNT, nodes, d.D,
+                                     TM=cyc.TM, WSYM=cyc.WSYM, table=table)
+            return aff, anti, soft
+        return jax.vmap(one)(jnp.arange(SC))
+
+    per_row = jax.tree.map(np.asarray, jax.jit(lambda: rows(None))())
+    from_table = jax.tree.map(np.asarray, jax.jit(lambda: rows(
+        term_domain_counts(terms, cyc.CNT, nodes, d.D)))())
+    for name, a, b in zip(("affinity_ok", "anti_ok", "soft"),
+                          per_row, from_table):
+        assert a.dtype == b.dtype and np.array_equal(a, b), (case, name)
+
+    aff_ok, anti_ok, soft = per_row
+    nv = np.asarray(nodes.valid)
+    live = np.asarray(classes.valid)
+    has_aff = (np.asarray(classes.aff_terms) >= 0).any(1) & live
+    if case == "no-term":
+        assert not has_aff.any() and aff_ok.all() and anti_ok[:, nv].all()
+    else:
+        # the predicates bite: some class is refused somewhere
+        assert not aff_ok[has_aff][:, nv].all()
+    if case == "slots-half-padded":
+        n_slots = (np.asarray(classes.aff_terms)[live] >= 0).sum(1)
+        assert {1, 2} <= set(n_slots.tolist())
+    if case == "two-classes-share-a-term":
+        ats = np.asarray(classes.aff_terms)[has_aff]
+        assert len(ats) >= 2 and len({tuple(a) for a in ats}) == 1
+        assert soft.any()
+    if case == "first-pod-escape":
+        # solo and keyless escape (every node passes, the keyless one too);
+        # "web" requires a pod nobody is and matches no term of its own: no
+        # node; "db" finds a cache pod in every zone: every node with the key
+        passing = aff_ok[np.asarray(pe.cls)[:4]][:, nv].sum(1).tolist()
+        assert passing == [nv.sum(), nv.sum(), 0, nv.sum() - 1]
+    if case in ("flagship-roles", "over-256-on-one-domain"):
+        assert not anti_ok[live][:, nv].all()
+
+
+def test_few_rows_path_equals_many_rows_path():
+    """A verb's P = 8 pods aggregate their own slots ("row": 8 rows x 7
+    slots under S = 64 terms); the waves round's SC = 64 classes build the
+    table ("term"). Same state, same answers: the Filter mask, its
+    components and the Score matrix."""
+    from kubernetes_tpu.ops import assign
+
+    tables, cyc, pe, d = _table_state("flagship-roles")
+    pe = jax.tree.map(lambda a: a[:8], pe)
+    assert dataclasses.replace(d, P=8).affinity_agg("extender") == "row"
+    assert d.affinity_agg("waves") == "term"
+    assert d.affinity_agg("scan") == d.affinity_agg("runs") == "row"
+    state = assign.initial_state(tables, cyc)
+    assert assign.state_affinity_table(tables, cyc, state, 8) is None
+    table = assign.state_affinity_table(
+        tables, cyc, state, tables.classes.valid.shape[0])
+    assert table is not None
+
+    @jax.jit
+    def few():
+        return (assign.feasible_matrix(tables, cyc, pe),
+                assign.score_matrix(tables, cyc, pe),
+                assign.mask_components(tables, cyc, pe))
+
+    @jax.jit
+    def many():
+        def row(c, nnr, v):
+            mask = assign.pod_mask_row(tables, cyc, state, c, nnr, v, table)
+            score = assign.score_row(tables, cyc, state, c, table)
+            return mask, jnp.where(mask, score, -jnp.inf)
+        return jax.vmap(row)(pe.cls, pe.node_name_req, pe.valid)
+
+    feas, score, comps = jax.tree.map(np.asarray, few())
+    mask_t, score_t = jax.tree.map(np.asarray, many())
+    assert np.array_equal(feas, mask_t) and np.array_equal(score, score_t)
+    assert feas.any() and not feas.all()
+    assert not comps.affinity.all() and not comps.anti.all()
+
+
+@pytest.mark.parametrize("dims,engine,want", [
+    # the flagship's capacities (benchmarks: N 5,120, SC 64, S 72)
+    (dict(SC=64, S=72, P=53248), "waves", "term"),
+    (dict(SC=64, S=72, P=8), "extender", "row"),
+    (dict(SC=64, S=72, P=53248), "scan", "row"),
+    # density-1k: S 8, nothing to aggregate either way
+    (dict(SC=64, S=8, P=30720), "waves", "term"),
+    # more terms than the round's classes would ask for: rows stay cheaper
+    (dict(SC=8, S=64, P=8), "waves", "row"),
+    # a fleet tick's record is not one program's: each tenant group's engine
+    (dict(SC=64, S=72, P=53248), "fleet", None),
+])
+def test_affinity_agg_is_chosen_from_dims(dims, engine, want):
+    """rows x (AT + AN + PAT + PAN) aggregates against S: the choice the
+    flight recorder reports beside `bucket`."""
+    assert Dims(**dims).affinity_agg(engine) == want

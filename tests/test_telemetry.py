@@ -202,27 +202,27 @@ _WAVE_SHAPES = {
                          "requeue/snapshot", "requeue/snapshot/patch",
                          "requeue/snapshot/patch/upload",
                          "requeue/snapshot/prepare"] + _FIRST_SNAPSHOT,
-             _HEAD + ["bucket", "stats", "device_split", "children",
-                      "snapshot_mode", "waits", "assumed_outstanding",
-                      "minor_faults", "seq"]),
+             _HEAD + ["bucket", "affinity_agg", "stats", "device_split",
+                      "children", "snapshot_mode", "waits",
+                      "assumed_outstanding", "minor_faults", "seq"]),
     "micro": (_BULK,
               _BINDING + _FIRST_SNAPSHOT + ["snapshot/upload"],
-              _HEAD + ["micro", "bucket", "stats", "device_split",
-                       "children", "snapshot_mode", "waits",
+              _HEAD + ["micro", "bucket", "affinity_agg", "stats",
+                       "device_split", "children", "snapshot_mode", "waits",
                        "assumed_outstanding", "minor_faults", "seq"]),
     "paused": (["pump", "paused"], None,
                _HEAD + ["stats", "supervisor_events", "seq"]),
     "abandoned": (["pump", "pop", "snapshot", "prewarm", "dispatch",
                    "readback", "requeue"],
                   _FIRST_SNAPSHOT,
-                  _HEAD + ["bucket", "stats", "supervisor_events",
-                           "children", "waits", "assumed_outstanding",
-                           "seq"]),
+                  _HEAD + ["bucket", "affinity_agg", "stats",
+                           "supervisor_events", "children", "waits",
+                           "assumed_outstanding", "seq"]),
     "raises": (_BULK[:8] + ["exception"],
                _BINDING + _FIRST_SNAPSHOT,
-               _HEAD + ["bucket", "stats", "device_split", "children",
-                        "waits", "assumed_outstanding", "exception",
-                        "seq"]),
+               _HEAD + ["bucket", "affinity_agg", "stats", "device_split",
+                        "children", "waits", "assumed_outstanding",
+                        "exception", "seq"]),
 }
 
 

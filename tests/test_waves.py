@@ -318,3 +318,79 @@ def test_class_axis_tiling_bit_identical(monkeypatch):
     finally:
         monkeypatch.undo()
         jax.clear_caches()
+
+
+def _affinity_cluster(seed):
+    """Random cluster whose pending pods carry every kind of pod-affinity
+    term, several replicas a spec (so classes hold runs) and bound pods
+    (so the counts are not zero)."""
+    rng = random.Random(seed)
+    nodes = [rand_node(rng, i) for i in range(10)]
+    existing = [rand_pod(rng, 500 + i, bound_to=rng.choice(nodes).name)
+                for i in range(20)]
+    pending = []
+    for i in range(12):
+        p = rand_pod(rng, i)
+        for j in range(3):
+            pending.append(dataclasses.replace(
+                p, name=f"{p.name}-{j}", creation_index=10 * i + j))
+    return nodes, existing, pending
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_waves_with_table_equal_waves_per_row(seed, monkeypatch):
+    """The round's [S, N] in-domain count table against every class
+    aggregating its own slots (the few-rows parameterisation, forced onto
+    the waves round): placements, admission waves and final counts
+    identical."""
+    from kubernetes_tpu.ops import waves as waves_mod
+
+    nodes, existing, pending = _affinity_cluster(seed)
+    tables, ex, pe, uk, ev, d = _encode(nodes, existing, pending)
+    assert d.affinity_agg("waves") == "term"
+    res_t, waves_t = _run("waves", tables, ex, pe, uk, ev, d.D)
+
+    monkeypatch.setattr(waves_mod, "state_affinity_table",
+                        lambda *a, **k: None)
+    jax.clear_caches()
+    try:
+        res_r, waves_r = _run("waves", tables, ex, pe, uk, ev, d.D)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    for a, b in ((res_t.node, res_r.node), (waves_t, waves_r),
+                 (res_t.state.CNT, res_r.state.CNT),
+                 (res_t.state.used, res_r.state.used)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert (np.asarray(res_t.node) >= 0).any()
+
+
+def test_escape_cap_counts_what_the_predicate_counts():
+    """The first-pod escape's total counts matching pods on nodes that CARRY
+    the term's key (predicates.go:1436-1440 through the topology-pair map,
+    which has no entry for a keyless node). A matching pod on a keyless node
+    therefore leaves the escape open, and the cap must hold the class to one
+    pod that wave: its followers have to land in the first one's zone."""
+    zone = "topology.kubernetes.io/zone"
+    nodes = [Node(name=f"n{i}", labels={zone: f"z{i % 3}"},
+                  allocatable=Resources.make(cpu="4", memory="8Gi", pods=110))
+             for i in range(6)]
+    nodes.append(Node(name="bare", allocatable=Resources.make(
+        cpu="4", memory="8Gi", pods=110)))
+    from kubernetes_tpu.api.types import (
+        Affinity, LabelSelector, PodAffinityTerm)
+    together = Affinity(pod_required=(PodAffinityTerm(
+        selector=LabelSelector.of(match_labels={"app": "herd"}),
+        topology_key=zone),))
+    mk = lambda name, i, node="": Pod(
+        name=name, labels={"app": "herd"}, affinity=together,
+        requests=Resources.make(cpu="1", memory="1Gi"), node_name=node,
+        creation_index=i)
+    tables, ex, pe, uk, ev, d = _encode(
+        nodes, [mk("lost", 0, "bare")], [mk(f"p{i}", 1 + i) for i in range(4)])
+    res, waves = _run("waves", tables, ex, pe, uk, ev, d.D)
+    node = np.asarray(res.node)[:4]
+    assert (node >= 0).all() and (node < 6).all()
+    assert len({int(n) % 3 for n in node}) == 1, node
+    w = np.asarray(waves)[:4]
+    assert (w == w.min()).sum() == 1, w
