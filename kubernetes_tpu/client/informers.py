@@ -14,6 +14,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from kubernetes_tpu.component import trace
 from kubernetes_tpu.component.metrics import DEFAULT_REGISTRY as _REG
 from kubernetes_tpu.machinery import errors, meta
 from kubernetes_tpu.machinery import watch as mwatch
@@ -37,6 +38,15 @@ INFORMER_RELISTS = _REG.counter(
     "informer_relists_total",
     "Full list+replace rounds (initial sync, 410 Gone, deaf watch)",
     labels=("resource",))
+# what a list+replace round cost, by its stage (`list`: the request;
+# `index`: the indexer's replace; `handlers`: the synthesized deltas): a
+# relist storm's price beside its count (SharedInformer.last_sync has the
+# last round's split below the stages)
+INFORMER_SYNC_DURATION = _REG.histogram(
+    "informer_sync_duration_seconds",
+    "One stage of a list+replace round (list, index, handlers)",
+    labels=("resource", "stage"),
+    buckets=(0.001, 0.005, 0.025, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0))
 # ISSUE 13 watch plane: bookmarks keep a quiet stream's resume token fresh,
 # and resumes are the relists we DIDN'T pay — the ratio of these two series
 # against informer_relists_total is the watch plane's health at a glance.
@@ -213,6 +223,21 @@ class SharedInformer:
         self._thread: Optional[threading.Thread] = None
         self._watch: Optional[mwatch.Watch] = None
         self.last_sync_rv = ""
+        #: the last list+replace round (the initial list, a relist after a
+        #: 410 or a deaf watch), None before the first has ended:
+        #: {"resource", "t_start" (time.perf_counter), "duration_s",
+        #: "items", "synced" (it ran to its last handler), "children":
+        #: {path: [count, total_s, max_s]} of its stages `list`, `index`,
+        #: `handlers` and of what filed itself below them}
+        self.last_sync: Optional[Dict[str, Any]] = None
+        #: whether a round's Trace is `trace.current()` on this thread
+        #: while it runs, so that the in-process apiserver, the store and
+        #: the handlers file their time below its stages (per listed
+        #: object a clock-read pair and an aggregate update in a handler
+        #: that files its `decode`). The three stages are timed either
+        #: way: a handful of clock reads a round. A server whose
+        #: telemetry is off turns it off (`sched/server.py start_informer`)
+        self.trace_below = True
         # watch-plane bookkeeping (ISSUE 13): how the resume token last
         # advanced, and the resume/relist split the bench budgets read
         self._rv_from_bookmark = False
@@ -308,47 +333,99 @@ class SharedInformer:
         except (AttributeError, TypeError, ValueError):
             return 0
 
+    def _list_and_replace(self, rnd: trace.Trace) -> List[Obj]:
+        """One list+replace round, in three stages on `rnd`, whose fields
+        take how many `items` the list returned and, when the last handler
+        has returned, `synced`. Returns the listed objects."""
+        clock = rnd.clock
+        t0 = rnd.start
+        tok = rnd.begin("list")
+        lst = self.rc.list(self.namespace, self.label_selector,
+                           self.field_selector)
+        t1 = clock()
+        rnd.end(tok, t1 - t0)
+        items = lst.get("items", [])
+        rnd.fields["items"] = len(items)
+        rv = lst.get("metadata", {}).get("resourceVersion", "")
+        old_keys = set(self.indexer.keys())
+        # last-known objects become delete tombstones (DeltaFIFO
+        # DeletedFinalStateUnknown carries the final object, not a key)
+        old_objs = {k: self.indexer.get(k) for k in old_keys}
+        self.indexer.replace(items)
+        self.last_sync_rv = rv
+        self._rv_from_bookmark = False
+        self.last_signal = time.monotonic()
+        # ANY successful list+replace collapses the relist ladder to
+        # its first rung (the old after-a-healthy-round-only reset
+        # left a watch that died right after the initial list
+        # retrying at the decayed cap forever); the full reset
+        # happens below, once the watch actually delivers a signal
+        self.backoff.collapse()
+        # synthesize deltas for the replace (DeltaFIFO Replace)
+        new_keys = {meta.namespaced_key(o) for o in items}
+        t2 = clock()
+        rnd.child("index", t2 - t1)
+        tok = rnd.begin("handlers")
+        with self._handler_mu:
+            handlers = list(self._handlers)
+        for o in items:
+            k = meta.namespaced_key(o)
+            for add, upd, _ in handlers:
+                if k in old_keys:
+                    # deliver the pre-gap cached object as old so
+                    # diffing handlers see changes that happened during
+                    # the watch gap (DeltaFIFO Replace semantics)
+                    upd(old_objs.get(k) or o, o)
+                else:
+                    add(o)
+        for k in old_keys - new_keys:
+            tomb = old_objs.get(k) or {"metadata": dict(zip(
+                ("namespace", "name"), meta.split_key(k)))}
+            for _, _, dele in handlers:
+                dele(tomb)
+        rnd.end(tok, clock() - t2)
+        rnd.fields["synced"] = True
+        return items
+
+    def _note_sync(self, rnd: trace.Trace) -> None:
+        """A round has ended, whole or not: `last_sync`, and one
+        observation a stage that ran."""
+        children = rnd.record()
+        resource = self.rc.resource
+        for stage, (_count, total, _max) in children.items():
+            if "/" not in stage:
+                INFORMER_SYNC_DURATION.observe(total, resource=resource,
+                                               stage=stage)
+        self.last_sync = {
+            "resource": resource, "t_start": round(rnd.start, 6),
+            "duration_s": round(rnd.duration(), 6),
+            "items": rnd.fields.get("items", 0),
+            "synced": rnd.fields.get("synced", False),
+            "children": children}
+
     def _list_and_watch(self, skip_list: bool = False) -> None:
         if not skip_list:
             INFORMER_RELISTS.inc(resource=self.rc.resource)
             self.relists += 1
-            lst = self.rc.list(self.namespace, self.label_selector,
-                               self.field_selector)
-            items = lst.get("items", [])
-            rv = lst.get("metadata", {}).get("resourceVersion", "")
-            old_keys = set(self.indexer.keys())
-            # last-known objects become delete tombstones (DeltaFIFO
-            # DeletedFinalStateUnknown carries the final object, not a key)
-            old_objs = {k: self.indexer.get(k) for k in old_keys}
-            self.indexer.replace(items)
-            self.last_sync_rv = rv
-            self._rv_from_bookmark = False
-            self.last_signal = time.monotonic()
-            # ANY successful list+replace collapses the relist ladder to
-            # its first rung (the old after-a-healthy-round-only reset
-            # left a watch that died right after the initial list
-            # retrying at the decayed cap forever); the full reset
-            # happens below, once the watch actually delivers a signal
-            self.backoff.collapse()
-            # synthesize deltas for the replace (DeltaFIFO Replace)
-            new_keys = {meta.namespaced_key(o) for o in items}
-            with self._handler_mu:
-                handlers = list(self._handlers)
-            for o in items:
-                k = meta.namespaced_key(o)
-                for add, upd, _ in handlers:
-                    if k in old_keys:
-                        # deliver the pre-gap cached object as old so
-                        # diffing handlers see changes that happened during
-                        # the watch gap (DeltaFIFO Replace semantics)
-                        upd(old_objs.get(k) or o, o)
-                    else:
-                        add(o)
-            for k in old_keys - new_keys:
-                tomb = old_objs.get(k) or {"metadata": dict(zip(
-                    ("namespace", "name"), meta.split_key(k)))}
-                for _, _, dele in handlers:
-                    dele(tomb)
+            # the round runs under a Trace that is `trace.current()` on
+            # this thread for its length (`trace_below`): the in-process
+            # apiserver, the store and the handlers file their time below
+            # its stages, as they do below a wave's phases; a later watch
+            # event finds None
+            rnd = trace.Trace("informer-sync", clock=time.perf_counter)
+            token = trace.activate(rnd if self.trace_below else None)
+            # `listed` stays this frame's for the watch's length: a list
+            # made BEFORE its elements and held by a running frame is where
+            # a full collection meets the listed objects first and finds
+            # them reachable; held by the indexer's (younger) dict alone,
+            # each is set aside as unreachable and fetched back, and a full
+            # collection over 50,000 listed pods takes a third longer
+            # (PERF.md section 6, PR 37)
+            try:
+                listed = self._list_and_replace(rnd)  # noqa: F841
+            finally:
+                trace.deactivate(token)
+                self._note_sync(rnd)   # before a waiter is let go
             self._synced.set()
 
         # Watch, RESUMING across clean stream ends: bookmarks keep

@@ -52,17 +52,28 @@ class _Node:
             node = self.kids[name] = _Node()
         return node
 
+    def at(self, path: str) -> "_Node":
+        """The node `path` names below this one, a `/` between parents."""
+        node = self
+        for name in path.split("/"):
+            node = node.kid(name)
+        return node
+
     def add(self, seconds: float) -> None:
         self.count += 1
         self.total += seconds
         if seconds > self.max:
             self.max = seconds
 
+    def add_many(self, count: int, total: float, longest: float) -> None:
+        """`count` calls that took `total` seconds, the longest `longest`."""
+        self.count += count
+        self.total += total
+        if longest > self.max:
+            self.max = longest
+
     def merge(self, other: "_Node") -> None:
-        self.count += other.count
-        self.total += other.total
-        if other.max > self.max:
-            self.max = other.max
+        self.add_many(other.count, other.total, other.max)
         for name, node in other.kids.items():
             self.kid(name).merge(node)
 
@@ -116,13 +127,17 @@ class Trace:
         """Add one call of `seconds` under `path`, below the current span.
         A `/` in the path names parents: `child("a/b", s)` files `b`
         below `a` without counting a call of `a`."""
-        node = self._cur
-        if "/" in path:
-            for name in path.split("/"):
-                node = node.kid(name)
-        else:
-            node = node.kid(path)
-        node.add(seconds)
+        cur = self._cur
+        (cur.at(path) if "/" in path else cur.kid(path)).add(seconds)
+
+    def graft(self, path: str, children: Dict[str, List[float]]) -> None:
+        """File another trace's `children()` below `path` under the current
+        span: what a stage did on a thread of its own (an informer's
+        list+replace round), merged by the thread that waited for it, once
+        it has ended."""
+        under = self._cur.at(path)
+        for sub, (count, total, longest) in children.items():
+            under.at(sub).add_many(count, total, longest)
 
     def children(self) -> Dict[str, List[float]]:
         """`{path: [count, total_s, max_s]}` of every span that was called,
@@ -132,6 +147,11 @@ class Trace:
         self._root.flatten("", out)
         self._open.flatten("", out)
         return out
+
+    def record(self) -> Dict[str, List[float]]:
+        """`children()` as a record carries it: seconds to the microsecond."""
+        return {path: [count, round(total, 6), round(longest, 6)]
+                for path, (count, total, longest) in self.children().items()}
 
     def duration(self) -> float:
         return (self._ended or self.clock()) - self.start

@@ -354,17 +354,30 @@ class ExtenderBackend:
         capacities compiles (upstream's `httpTimeout` defaults to 5 s; the
         flagship's programs compile for a minute). A real call at the live
         shapes, as `SchedulerCache.warm_patch_ladder` is: what seeds the
-        cache the verbs' dispatch consults. Returns [(Dims, program)]."""
+        cache the verbs' dispatch consults. Returns [(Dims, program)]; on a
+        traced start (`trace.current()`) each call is a span under its
+        name."""
+        tr = trace.current()   # a start's account: each call a span on it
+
+        def timed(name: str, call, *args):
+            if tr is None:
+                return call(*args)
+            tok, t0 = tr.begin(name), time.perf_counter()
+            try:
+                return call(*args)
+            finally:
+                tr.end(tok, time.perf_counter() - t0)
+
         with self._mu:
-            snap, keys = self._snapshot_for(
-                Pod(name="compile-ahead", namespace="kube-system"))
+            snap, keys = timed("snapshot", self._snapshot_for, Pod(
+                name="compile-ahead", namespace="kube-system"))
             warmed = []
             for name, program in (("filter", _feasible),
                                   ("diagnose", _diagnose),
                                   ("prioritize", _scores)):
-                self._dispatch(program, snap, keys)
+                timed(name, self._dispatch, program, snap, keys)
                 warmed.append((snap.dims, name))
-            self.cache.warm_patch_ladder(snap)
+            timed("patch-ladder", self.cache.warm_patch_ladder, snap)
             return warmed
 
     # ------------------------------------------------------------------ #

@@ -11,7 +11,10 @@ mirror its feed and the backend its Binding write, as `SchedulerServer`
   * the three verbs' programs and the patch-scatter ladder are run once at
     `start()`, after the initial lists are in and before the socket opens,
     so that the first request answers inside upstream's `httpTimeout` (5 s
-    by default; a non-ignorable extender that misses it fails the pod).
+    by default; a non-ignorable extender that misses it fails the pod);
+  * `start()` is accounted as `SchedulerServer.start()` is, on the
+    backend's telemetry: a `start` lap with its stages below it, on the
+    first pod's record (`loop`, `loop.children`).
 
 Point a stock kube-scheduler's Policy at `.url` (server.py has the entry).
 """
@@ -23,8 +26,14 @@ from typing import Any, Dict, Optional, Sequence
 from ..api.types import Pod
 from ..api.v1 import node_from_v1, pod_from_v1
 from ..client.informers import SharedInformer
+from ..component import trace
 from ..machinery import meta
-from ..sched.server import APIBinder, pod_schedulable_v1
+from ..sched.server import (
+    APIBinder,
+    decoded,
+    pod_schedulable_v1,
+    start_informer,
+)
 from ..state.dims import Dims
 from .backend import ExtenderBackend
 from .server import ExtenderServer
@@ -57,25 +66,36 @@ class ServedExtender:
         self._store_counters = counters() if counters is not None else None
         #: [(Dims, program)] that `start()` ran ahead of the first request
         self.warm_log: list = []
-        #: [(stretch, seconds)] of `start()`: the two initial lists as the
-        #: handlers fed them to the mirror, the compile-ahead, the socket
-        self.start_log: list = []
+        # the start's account as `start()` closed it (telemetry.loop_account)
+        self._start_account: Dict[str, list] = {}
 
     @property
     def url(self) -> str:
         return self.http.url
 
+    @property
+    def start_log(self) -> list:
+        """[(stretch, seconds)] of `start()`, read off its account (the
+        `start/*` stages of the first pod's `loop.children`): the two
+        initial lists as the handlers fed them to the mirror, the
+        compile-ahead, the socket. Empty with telemetry off."""
+        return [(path.split("/")[1], round(v[1], 6))
+                for path, v in self._start_account.items()
+                if path.count("/") == 1]
+
     # -- the mirror's feed --------------------------------------------------- #
 
     def _on_pod(self, obj: Obj) -> None:
-        self.backend.observe_pod(pod_from_v1(obj),
+        # inside the informer's first list the conversion is the round's
+        # `handlers/decode` (`decoded`); the rest of `handlers` is the mirror
+        self.backend.observe_pod(decoded(pod_from_v1, obj),
                                  live=pod_schedulable_v1(obj))
 
     def _on_pod_delete(self, obj: Obj) -> None:
         self.backend.forget_pod(meta.namespaced_key(obj))
 
     def _on_node(self, obj: Obj) -> None:
-        self.backend.observe_node(node_from_v1(obj))
+        self.backend.observe_node(decoded(node_from_v1, obj))
 
     def _lookup_pod(self, namespace: str, name: str) -> Optional[Pod]:
         obj = self.pod_informer.lister.get(namespace, name) \
@@ -101,6 +121,7 @@ class ServedExtender:
 
         enable_compile_cache()  # before the compile-ahead
         steady_heap()
+        self.backend.telemetry.loop_reset()
         self.node_informer = SharedInformer(self.client.nodes)
         self.node_informer.add_handlers(
             on_add=self._on_node,
@@ -111,24 +132,24 @@ class ServedExtender:
             on_add=self._on_pod,
             on_update=lambda old, new: self._on_pod(new),
             on_delete=self._on_pod_delete)
-        clock = self.backend.telemetry.clock
-        t = [clock()]
-
-        def lap(stretch: str) -> None:
-            now = clock()
-            self.start_log.append((stretch, round(now - t[0], 6)))
-            t[0] = now
-
-        self.node_informer.start()
-        self.node_informer.wait_for_sync()
-        lap("nodes-sync")
-        self.pod_informer.start()
-        self.pod_informer.wait_for_sync()
-        lap("pods-sync")
+        tel = self.backend.telemetry
+        start_informer(self.node_informer, tel, "start/nodes-sync",
+                       "extender")
+        start_informer(self.pod_informer, tel, "start/pods-sync", "extender")
         self._watch_plane()  # the initial lists are no pod's relists
-        self.warm_log = self.backend.compile_ahead()
-        lap("compile-ahead")
+        # the compile-ahead files each program it runs on `trace.current()`
+        # (None with telemetry off)
+        ahead = trace.Trace("compile-ahead", clock=tel.clock)
+        token = trace.activate(ahead if tel.enabled else None)
+        try:
+            self.warm_log = self.backend.compile_ahead()
+        finally:
+            trace.deactivate(token)
+        tel.loop_stage("start/compile-ahead", below=ahead.record())
         self.http.start()
+        tel.loop_stage("start/socket")
+        tel.loop_lap("start")
+        self._start_account = tel.loop_account()
         return self
 
     def stop(self) -> None:

@@ -151,6 +151,13 @@ SCORE_SHARE = REG.counter(
     "components) — the explainability signal the learned-scoring roadmap "
     "items train against",
     labels=("component",))
+START_UNSYNCED = REG.counter(
+    "scheduler_start_unsynced_total",
+    "Starts that went on though an informer's initial list had not synced "
+    "within `wait_for_sync`'s timeout: the server's first decisions were "
+    "made over a partial view, by component (scheduler, extender) and "
+    "resource",
+    labels=("component", "resource"))
 FAILED_EVENTS = REG.counter(
     "scheduler_failed_scheduling_events_total",
     "FailedScheduling event dispositions. The decision-provenance "

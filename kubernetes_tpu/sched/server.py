@@ -10,11 +10,11 @@ leader election like the reference binary (:254-260).
 
 from __future__ import annotations
 
+import dataclasses
+import logging
 import threading
 import time
 from typing import Any, Dict, Optional
-
-import dataclasses
 
 from kubernetes_tpu.api.types import (
     Affinity,
@@ -29,11 +29,50 @@ from kubernetes_tpu.client.leaderelection import (
     LeaderElectionConfig,
     LeaderElector,
 )
+from kubernetes_tpu.component import trace
 from kubernetes_tpu.machinery import errors, meta
-from kubernetes_tpu.sched.metrics import FAILED_EVENTS
+from kubernetes_tpu.sched.metrics import FAILED_EVENTS, START_UNSYNCED
 from kubernetes_tpu.sched.scheduler import Scheduler
 
+logger = logging.getLogger("kubernetes_tpu.sched.server")
+
 Obj = Dict[str, Any]
+
+
+def start_informer(informer: SharedInformer, telemetry, stage: str,
+                   component: str) -> bool:
+    """Start `informer` and wait for its initial list, as one stage of a
+    server's start: the wait is filed under `stage` of the telemetry's loop
+    account (`start/pods-sync`), the round's own stages (`last_sync`:
+    `list`, `index`, `handlers` and what filed itself below them) under
+    it. A start goes on over an informer that has not synced in time, as
+    it always has; that is logged, counted and kept on the account."""
+    informer.trace_below = telemetry.enabled  # off: nothing below a stage
+    synced = informer.start().wait_for_sync()
+    sync = informer.last_sync if synced else None
+    telemetry.loop_stage(stage, synced=synced,
+                         below=sync["children"] if sync else None)
+    if not synced:
+        resource = informer.rc.resource
+        logger.warning("%s: the %s informer had not synced when its wait "
+                       "ended: the start goes on over a partial view",
+                       component, resource)
+        START_UNSYNCED.inc(component=component, resource=resource)
+    return synced
+
+
+def decoded(convert, obj: Obj):
+    """`convert(obj)`, a v1 dict to the scheduler's type. Inside an
+    informer's list+replace round (its Trace is `trace.current()` on the
+    informer's thread) the conversion is the round's `handlers/decode`;
+    a later watch event finds None and pays that one check."""
+    tr = trace.current()
+    if tr is None:
+        return convert(obj)
+    t0 = tr.clock()
+    out = convert(obj)
+    tr.child("decode", tr.clock() - t0)
+    return out
 
 
 class _HandlerLock:
@@ -499,7 +538,7 @@ class SchedulerServer:
         if not self._schedulable(obj):
             return
         with self._handling():
-            self.scheduler.on_pod_add(self._to_pod(obj))
+            self.scheduler.on_pod_add(decoded(self._to_pod, obj))
 
     def _on_pod_update(self, old: Obj, new: Obj) -> None:
         with self._handling():
@@ -511,7 +550,7 @@ class SchedulerServer:
 
     def _on_node_add(self, obj: Obj) -> None:
         with self._handling():
-            self.scheduler.on_node_add(node_from_v1(obj))
+            self.scheduler.on_node_add(decoded(node_from_v1, obj))
 
     def _on_node_update(self, old: Obj, new: Obj) -> None:
         with self._handling():
@@ -565,8 +604,10 @@ class SchedulerServer:
         enable_compile_cache()  # before the loop's first compile
         steady_heap()  # before the first wave commits
         # the loop's account of the time between waves begins here: the
-        # informers' list+sync below is the first wave's `start` phase
-        self.scheduler.telemetry.loop_reset()
+        # informers' list+sync below is the first wave's `start` phase,
+        # and each stretch of it a stage below that (`loop.children`)
+        tel = self.scheduler.telemetry
+        tel.loop_reset()
         if self.scheduler.preemptor is not None \
                 and getattr(self.scheduler.preemptor, "pdb_source", None) \
                 is not None:
@@ -576,8 +617,8 @@ class SchedulerServer:
                 on_add=self._on_pdb,
                 on_update=lambda old, new: self._on_pdb(new),
                 on_delete=self._on_pdb_delete)
-            self.pdb_informer.start()
-            self.pdb_informer.wait_for_sync()
+            start_informer(self.pdb_informer, tel, "start/pdb-sync",
+                           "scheduler")
         self.pod_informer = SharedInformer(self.client.pods)
         self.pod_informer.add_handlers(on_add=self._on_pod_add,
                                        on_update=self._on_pod_update,
@@ -586,10 +627,10 @@ class SchedulerServer:
         self.node_informer.add_handlers(on_add=self._on_node_add,
                                         on_update=self._on_node_update,
                                         on_delete=self._on_node_delete)
-        self.node_informer.start()
-        self.node_informer.wait_for_sync()
-        self.pod_informer.start()
-        self.pod_informer.wait_for_sync()
+        start_informer(self.node_informer, tel, "start/nodes-sync",
+                       "scheduler")
+        start_informer(self.pod_informer, tel, "start/pods-sync",
+                       "scheduler")
         self._watch_plane()  # the initial lists are no wave's relists
         if self.elector is not None:
             self.elector.start()
@@ -615,6 +656,9 @@ class SchedulerServer:
             self.telemetry_gateway = TelemetryGateway(
                 self.scheduler.telemetry, port=self.telemetry_port,
                 scheduler=self.scheduler).start()
+        # the last stage, closed before the loop's thread exists: from its
+        # first act (the `start` lap) on, the account is that thread's
+        tel.loop_stage("start/wiring")
         t = threading.Thread(target=self._loop, daemon=True,
                              name="scheduler-loop")
         t.start()

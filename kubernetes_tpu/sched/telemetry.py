@@ -36,9 +36,14 @@ apiserver.bind/store.txn` — as `[count, total_s, max_s]` aggregates from
 the wave's Trace, which is `component.trace.current()` while the wave
 runs), `loop` (what the server loop did between the previous wave and
 this one: `post-wave`, `lock-wait`, `batch-wait`, `idle-wait`, plus the
-informer handlers' calls, waits for and holds of the server's lock) and
-`waits` (how long the popped pods had queued; how long Binding
-confirmations took to come back through the informer).
+informer handlers' calls, waits for and holds of the server's lock; under
+`loop.children` what ran inside a lap, in `children`' own form: the stages
+of a server's `start` (ISSUE 37), each informer's list+replace round below
+the stage that waited for it) and `waits` (how long the popped pods had
+queued; how long Binding confirmations took to come back through the
+informer). Every record also says what the interpreter's collector did
+since the record before it: `gc_full_collections`, `gc_pause_s`,
+`gc_max_pause_s`, `gc_max_pause_at` (`GcAccount`, one hook a process).
 
 Kill switch: ``KTPU_TELEMETRY=0`` turns every tier into a no-op (the
 `latency` bench stage uses it to bound telemetry overhead at <2% of the
@@ -47,6 +52,7 @@ untelemetered flagship pods/s).
 
 from __future__ import annotations
 
+import gc
 import gzip
 import json
 import os
@@ -55,6 +61,7 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ..component.metrics import DEFAULT_REGISTRY, Counter
 from ..component.trace import Trace
 from .metrics import FLIGHT_DUMPS, POD_E2E_LATENCY, SCHEDULING_DURATION
 
@@ -72,8 +79,8 @@ WAVE_PHASES = ("pump", "pop", "snapshot", "prewarm", "dispatch", "readback",
 #: per-record payload caps, applied at SERIALIZATION time (snapshot/dump —
 #: the in-memory ring keeps full records): a large fleet's per-tick tenant
 #: map and a storm's event burst were most of FLIGHT_rNN.json's ~4.6k
-#: lines per bench run. Overridable via KTPU_FLIGHT_FLEET_CAP /
-#: KTPU_FLIGHT_EVENT_CAP (bounds-checked; garbage → default).
+#: lines per bench run. The tenant cap is overridable via
+#: KTPU_FLIGHT_FLEET_CAP (bounds-checked; garbage → default).
 FLIGHT_FLEET_TENANT_CAP = 8
 FLIGHT_EVENT_CAP = 32
 
@@ -109,7 +116,7 @@ def _cap_record(rec: Dict[str, Any]) -> Dict[str, Any]:
         capped["..."] = agg
         out["fleet"] = capped
     ev = out.get("supervisor_events")
-    ecap = env_int("KTPU_FLIGHT_EVENT_CAP", FLIGHT_EVENT_CAP, 1, 4096)
+    ecap = FLIGHT_EVENT_CAP
     if isinstance(ev, list) and len(ev) > ecap:
         head = ev[:max(ecap // 2, 1)]
         tail = ev[len(ev) - max(ecap - len(head) - 1, 0):]
@@ -300,6 +307,155 @@ def flight_ring_capacity(default: int = FLIGHT_RING_DEFAULT) -> int:
     return min(max(v, FLIGHT_RING_MIN), FLIGHT_RING_MAX)
 
 
+class _Pauses:
+    """Collections counted one at a time by `GcAccount`'s hook: how many,
+    their seconds, and the pauses a later reader may still ask the longest
+    of."""
+
+    #: pauses kept for `since`; an instant older than the oldest kept is
+    #: answered from what is kept
+    PEAKS = 64
+
+    def __init__(self) -> None:
+        self.count = 0
+        self.total = 0.0
+        # (began, seconds), seconds strictly falling: a pause is dropped
+        # when a later one is at least as long, so the longest pause since
+        # an instant is the first kept one that ended after it
+        self._peaks: deque = deque()
+
+    def add(self, began: float, dt: float) -> None:
+        self.count += 1
+        self.total += dt
+        peaks = self._peaks
+        while peaks and peaks[-1][1] <= dt:
+            peaks.pop()
+        peaks.append((began, dt))
+        if len(peaks) > self.PEAKS:
+            peaks.popleft()
+
+    def mark(self) -> Tuple[int, float]:
+        return self.count, self.total
+
+    def since(self, mark: Tuple[int, float],
+              t: float) -> Tuple[int, float, float, float]:
+        """(collections, their seconds, the longest, the instant it began)
+        of those that ENDED after `t`, where the account stood at `mark`:
+        a collection is counted where it stops, so one that straddles `t`
+        is this interval's, pause and peak alike."""
+        began, longest = next(
+            (p for p in list(self._peaks) if p[0] + p[1] >= t), (0.0, 0.0))
+        return self.count - mark[0], self.total - mark[1], longest, began
+
+
+#: (`all`'s mark, `full`'s mark, the instant): `GcAccount.mark()`
+_GcMark = Tuple[Tuple[int, float], Tuple[int, float], float]
+
+
+class GcAccount:
+    """What the interpreter's collector cost this process: the collections
+    and the seconds from a collection's `start` to its `stop` on
+    `time.perf_counter`, of every generation (`all`) and of the full ones
+    (`full`), and per generation for the two Prometheus series. ONE
+    `gc.callbacks` hook a process (`gc_account()`), two calls a collection.
+
+    The hook takes no lock and touches no metric: a collection begins
+    wherever an allocation does, under any lock its thread holds (a
+    metric's own, while it exposes). Collections do not nest and the hook
+    is the only writer, so the lists need none; the two series read them
+    when they are read (`_GcSeries`)."""
+
+    def __init__(self, clock: Callable[[], float] = time.perf_counter) -> None:
+        self.clock = clock
+        self.collections = [0, 0, 0]
+        self.pause_s = [0.0, 0.0, 0.0]
+        self.all, self.full = _Pauses(), _Pauses()
+        self._began = 0.0
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            self._began = self.clock()
+            return
+        dt = self.clock() - self._began
+        gen = info["generation"]
+        self.collections[gen] += 1
+        self.pause_s[gen] += dt
+        self.all.add(self._began, dt)
+        if gen == 2:
+            self.full.add(self._began, dt)
+
+    def mark(self) -> _GcMark:
+        """Where the account stands now: what `since` and `children` take
+        to say what happened after this instant."""
+        return self.all.mark(), self.full.mark(), self.clock()
+
+    def since(self, mark: _GcMark) -> Dict[str, float]:
+        """A record's `gc_*` fields for the interval that began at `mark`."""
+        _n, pause, longest, began = self.all.since(mark[0], mark[2])
+        return {"gc_full_collections": self.full.count - mark[1][0],
+                "gc_pause_s": round(pause, 6),
+                "gc_max_pause_s": round(longest, 6),
+                "gc_max_pause_at": round(began, 6) if longest else None}
+
+    def children(self, mark: _GcMark) -> Dict[str, List[float]]:
+        """The same interval in a Trace's `children()` form, to graft below
+        the stage it covers: `gc` (every generation) and `gc/full`, each
+        `[collections, pause_s, the longest]`; neither where none ran."""
+        out = {}
+        for path, pauses, stood in (("gc", self.all, mark[0]),
+                                    ("gc/full", self.full, mark[1])):
+            n, pause, longest, _began = pauses.since(stood, mark[2])
+            if n:
+                out[path] = [n, pause, longest]
+        return out
+
+
+class _GcSeries(Counter):
+    """A counter by `generation` that IS one of `GcAccount`'s lists (the
+    hook may take no lock, so it keeps no metric): read when it is read."""
+
+    def __init__(self, name: str, help_: str, per_generation: list) -> None:
+        super().__init__(name, help_, ("generation",))
+        self._per_generation = per_generation
+
+    def value(self, **labels) -> float:
+        return float(self._per_generation[int(labels["generation"])])
+
+    def total(self) -> float:
+        return float(sum(self._per_generation))
+
+    def expose(self) -> List[str]:
+        return self._header() + [
+            f"{self.name}{self._fmt_labels(self.label_names, (str(gen),))} "
+            f"{float(v)}" for gen, v in enumerate(self._per_generation)]
+
+
+_GC: Optional[GcAccount] = None
+_GC_MU = threading.Lock()
+
+
+def gc_account() -> GcAccount:
+    """The process's `GcAccount`, its hook installed by the first caller
+    (the first enabled `SchedulerTelemetry`: `KTPU_TELEMETRY=0` installs
+    none) with its two series, `process_gc_collections_total` and
+    `process_gc_pause_seconds_total`, by `generation`."""
+    global _GC
+    with _GC_MU:
+        if _GC is None:
+            acct = GcAccount()
+            DEFAULT_REGISTRY.register(_GcSeries(
+                "process_gc_collections_total",
+                "Collections of the interpreter's cyclic collector, by "
+                "generation", acct.collections))
+            DEFAULT_REGISTRY.register(_GcSeries(
+                "process_gc_pause_seconds_total",
+                "Seconds every thread stood still for the interpreter's "
+                "cyclic collector, by generation", acct.pause_s))
+            gc.callbacks.append(acct)
+            _GC = acct
+        return _GC
+
+
 class SchedulerTelemetry:
     """The scheduler-wide observability layer: one per Scheduler (and one
     per FleetServer). Thread-aware: supervisor events and the device-time
@@ -338,8 +494,15 @@ class SchedulerTelemetry:
         # server's lock over the same interval — both drained onto the
         # next such wave's record
         self._loop: Optional[Trace] = None
-        self._loop_lap_t = 0.0
+        self._loop_lap_t = self._loop_stage_t = 0.0
         self._handlers: List[float] = [0, 0.0, 0.0]
+        self._synced: Dict[str, bool] = {}
+        # the collector's account, and where this recorder's previous
+        # record (and the loop account's previous stage) ended on it; None
+        # with telemetry off: no hook
+        self._gc = gc_account() if enabled else None
+        self._gc_mark = self._stage_gc_mark = \
+            self._gc.mark() if enabled else None
         self.last_dump: Optional[Dict[str, Any]] = None
         self.dumps = 0
         # KTPU_PROFILE=<dir>: jax.profiler trace capture around dispatches
@@ -423,11 +586,14 @@ class SchedulerTelemetry:
             return
         with self._mu:
             self._new_loop(self.clock())
+            self._gc_mark = self._gc.mark()
 
     def _new_loop(self, t: float) -> None:
         self._loop = Trace("loop", clock=lambda: t)  # its start IS t
-        self._loop_lap_t = t
+        self._loop_lap_t = self._loop_stage_t = t
         self._handlers = [0, 0.0, 0.0]
+        self._synced = {}
+        self._stage_gc_mark = self._gc.mark()
 
     def loop_lap(self, name: str) -> None:
         """The server loop (one thread) closes the stretch since its last
@@ -439,7 +605,40 @@ class SchedulerTelemetry:
             return
         now = self.clock()
         loop.child(name, now - self._loop_lap_t)
-        self._loop_lap_t = now
+        self._loop_lap_t = self._loop_stage_t = now
+
+    def loop_stage(self, path: str,
+                   below: Optional[Dict[str, List[float]]] = None,
+                   synced: Optional[bool] = None) -> None:
+        """A stage INSIDE the lap that will close over it, on the thread
+        that runs it (a server's `start()`): the stretch since the previous
+        stage, or the lap before it, is filed under `path`
+        (`start/pods-sync` below the `start` lap), on `loop.children`.
+        `below` is the `children()` of a Trace that was current while the
+        stage's work ran, on whatever thread (an informer's
+        `last_sync["children"]`): grafted under `path`. `synced` is the
+        verdict of a stage that waited for an informer (`loop.synced`).
+        The collections that ended inside the stage are filed below it as
+        `gc` (every generation) and `gc/full`: pauses that the spans beside
+        them HOLD, not a further share of the stage."""
+        loop = self._loop
+        if loop is None:
+            return
+        now = self.clock()
+        loop.child(path, now - self._loop_stage_t)
+        self._loop_stage_t = now
+        if below:
+            loop.graft(path, below)
+        if synced is not None:
+            self._synced[path] = synced
+        loop.graft(path, self._gc.children(self._stage_gc_mark))
+        self._stage_gc_mark = self._gc.mark()
+
+    def loop_account(self) -> Dict[str, List[float]]:
+        """The open account as it stands, `{path: [count, total_s, max_s]}`:
+        laps at the top, stages below them."""
+        loop = self._loop
+        return loop.children() if loop is not None else {}
 
     def note_handler(self, wait_s: float, held_s: float) -> None:
         """One informer handler call on the server's lock (any thread)."""
@@ -490,13 +689,20 @@ class SchedulerTelemetry:
                 # what the server loop did since the last wave like this
                 # one; its account starts over where this wave ends
                 calls, wait_s, held_s = self._handlers
-                loop_rec = {
-                    "t_start": round(self._loop.start, 6),
-                    "phases": [[n, round(v[1], 6)] for n, v
-                               in self._loop.children().items()],
-                    "handlers": {"calls": calls,
-                                 "wait_s": round(wait_s, 6),
-                                 "held_s": round(held_s, 6)}}
+                account = self._loop.record()
+                if account or calls:   # a loop nobody lapped has none
+                    loop_rec = {
+                        "t_start": round(self._loop.start, 6),
+                        "phases": [[n, v[1]] for n, v in account.items()
+                                   if "/" not in n],
+                        "handlers": {"calls": calls,
+                                     "wait_s": round(wait_s, 6),
+                                     "held_s": round(held_s, 6)}}
+                    below = {p: v for p, v in account.items() if "/" in p}
+                    if below:
+                        loop_rec["children"] = below
+                    if self._synced:
+                        loop_rec["synced"] = self._synced
                 self._new_loop(t_end)
             events, self._pending_events = self._pending_events, []
             # this wave's own reading (or an untokened caller's); entries
@@ -504,6 +710,9 @@ class SchedulerTelemetry:
             # left behind and bounded-cleared by note_device_split
             split = self._device_split.pop(span, None) \
                 or self._device_split.pop(None, None)
+            # what the collector did since the previous record ended
+            gc_fields = self._gc.since(self._gc_mark)
+            self._gc_mark = self._gc.mark()
         rec: Dict[str, Any] = {
             "recorder": self.name,
             "t_start": round(span.trace.start, 6),
@@ -540,14 +749,14 @@ class SchedulerTelemetry:
             rec["supervisor_events"] = events
         if split is not None:
             rec["device_split"] = split
-        children = span.trace.children()
+        children = span.trace.record()
         if children:
-            rec["children"] = {p: [c, round(t, 6), round(m, 6)]
-                               for p, (c, t, m) in children.items()}
+            rec["children"] = children
         if loop_rec is not None:
             rec["loop"] = loop_rec
         if fleet is not None:
             rec["fleet"] = fleet
+        rec.update(gc_fields)
         if extra:
             rec.update(extra)
         self.recorder.record(rec)

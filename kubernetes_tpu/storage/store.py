@@ -109,13 +109,16 @@ WATCH_BOOKMARKS_SENT = _REG.counter(
 # one write through the store (the etcd3 store's
 # `etcd_request_duration_seconds` seat): read, transform, encode, CAS put,
 # retries included. The watch fan-out runs on the dispatch thread, outside.
+# `list` is the one read counted: the range scan and the decode of every
+# record it returned (an informer's initial list is a server's start).
 TXN_DURATION = _REG.histogram(
     "storage_txn_duration_seconds",
-    "One write transaction through the store, by operation",
+    "One write transaction through the store (or one list), by operation",
     labels=("op",),
     buckets=(0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
              0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5))
 _OP_CREATE, _OP_UPDATE, _OP_DELETE = ("create",), ("update",), ("delete",)
+_OP_LIST = ("list",)
 _EVENT_TYPES = {native.EVENT_CREATE: mwatch.ADDED,
                 native.EVENT_PUT: mwatch.MODIFIED,
                 native.EVENT_DELETE: mwatch.DELETED}
@@ -140,22 +143,23 @@ EXPORT_EVERY_S = 0.1
 
 
 def _txn_done(op: Tuple[str], t0: float, kv_s: float = 0.0,
-              paced_s: float = 0.0) -> None:
+              paced_s: float = 0.0, span: str = "store.txn") -> None:
     """Close one transaction: the histogram, and — when the caller's
-    thread runs a traced operation (a scheduling wave) — a `store.txn`
-    child of the span that caused it, with the seconds of it spent inside
-    the KV backend's calls as `store.txn/kv` and those it waited for the
-    watch pump as `store.txn/pace` (the rest is this module's Python:
-    decode, the caller's transform, encode)."""
+    thread runs a traced operation (a scheduling wave, an informer's
+    list+replace round) — a `store.txn` child of the span that caused it
+    (`store.list` for a list), with the seconds of it spent inside the KV
+    backend's calls as `<span>/kv` and those it waited for the watch pump
+    as `<span>/pace` (the rest is this module's Python: decode, the
+    caller's transform or predicate, encode)."""
     dt = time.perf_counter() - t0
     TXN_DURATION.observe_at(op, dt)
     tr = trace.current()
     if tr is not None:
-        tr.child("store.txn", dt)
+        tr.child(span, dt)
         if kv_s:
-            tr.child("store.txn/kv", kv_s)
+            tr.child(span + "/kv", kv_s)
         if paced_s:
-            tr.child("store.txn/pace", paced_s)
+            tr.child(span + "/pace", paced_s)
 
 
 def _parse_watch_buffer(value, default: int = 8192) -> int:
@@ -419,13 +423,19 @@ class Storage:
         return _decode(rec.value, rec.mod_rev)
 
     def list(self, prefix: str, predicate: Predicate = None) -> Tuple[List[Obj], str]:
-        recs, at_rev = self.kv.range(prefix)
-        items = []
-        for rec in recs:
-            obj = _decode(rec.value, rec.mod_rev)
-            if predicate is None or predicate(obj):
-                items.append(obj)
-        return items, str(at_rev)
+        t0 = time.perf_counter()
+        kv_s = 0.0
+        try:
+            recs, at_rev = self.kv.range(prefix)
+            kv_s = time.perf_counter() - t0
+            items = []
+            for rec in recs:
+                obj = _decode(rec.value, rec.mod_rev)
+                if predicate is None or predicate(obj):
+                    items.append(obj)
+            return items, str(at_rev)
+        finally:
+            _txn_done(_OP_LIST, t0, kv_s, span="store.list")
 
     def count(self, prefix: str) -> int:
         return self.kv.count(prefix)

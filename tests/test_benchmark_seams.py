@@ -350,3 +350,118 @@ def test_the_extender_roofline_counts_a_cycles_bytes_once_a_dispatch():
     assert extender_roofline.read({**obs, "waves": [{}]}, {}) is None
     assert extender_roofline.read({**obs, "rehearse": True}, {}) is None
     assert extender_roofline.read({**obs, "trace": None}, {}) is None
+
+
+# --------------------------------------------------------------------------- #
+# ISSUE 37: the start's stages (`loop.children`) and the collector's pauses
+# (`gc_*`: record fields that cover the interval since the record before)
+# through their metric files
+# --------------------------------------------------------------------------- #
+
+from benchmarks.harness.sources import (  # noqa: E402
+    interval_field,
+    loop_children,
+)
+
+_PODS = "start/pods-sync"
+_START = {  # a start's account, as the first record of a server carries it
+    "start/nodes-sync": [1, 0.5, 0.5], _PODS: [1, 2.0, 2.0],
+    _PODS + "/list": [1, 0.75, 0.75],
+    _PODS + "/list/apiserver.list": [1, 0.7, 0.7],
+    _PODS + "/list/apiserver.list/store.list": [1, 0.65, 0.65],
+    _PODS + "/list/apiserver.list/store.list/kv": [1, 0.125, 0.125],
+    _PODS + "/handlers": [1, 1.2, 1.2],
+    _PODS + "/handlers/decode": [30000, 0.6, 0.001],
+    "start/compile-ahead": [1, 1.5, 1.5]}
+
+
+def _started(t_loop: float, first_gc: dict) -> dict:
+    """A 40 s window that opened at t = 100: the first record carries the
+    start (its `loop` began at `t_loop`), the second a later lap alone."""
+    def rec(t, loop, gc_fields):
+        loop["handlers"] = {"calls": 0, "wait_s": 0.0, "held_s": 0.0}
+        return {"t_start": t, "duration_s": 1.0, "phases": [],
+                "stats": {"attempted": 10}, "loop": loop, **gc_fields}
+    return {"window_s": 40.0, "bound_in_window": 100, "series": {},
+            "memory": {}, "trace": None, "rehearse": True, "waves": [
+                rec(104.0, {"t_start": t_loop, "phases": [["start", 4.0]],
+                            "children": _START}, first_gc),
+                rec(120.0, {"t_start": 105.0, "phases": [["idle-wait", 15]]},
+                    {"gc_full_collections": 1, "gc_pause_s": 0.25})]}
+
+
+@pytest.mark.parametrize("path, seconds", [
+    ("start/nodes-sync", 0.5), (_PODS + "/handlers/decode", 0.6),
+    (_PODS + "/list/apiserver.list/store.list/kv", 0.125),
+    ("start/recover", None)])
+def test_the_start_reader_takes_a_path_of_the_first_records_loop(
+        path, seconds):
+    got = loop_children.read(_started(100.5, {}), {"path": path})
+    assert got == (None if seconds is None else [seconds])
+
+
+def test_the_start_reader_keeps_loops_window_rule_and_the_parents_silence():
+    spec = {"path": "start/nodes-sync"}
+    # a loop that began before the window opened (100) is not the window's
+    assert loop_children.read(_started(70.0, {}), spec) is None
+    # the parent: a `loop` without `children`, or no `loop` at all
+    obs = _started(100.5, {})
+    for w in obs["waves"]:
+        w["loop"].pop("children", None)
+    assert loop_children.read(obs, spec) is None
+    assert loop_children.read({**obs, "waves": [
+        {"t_start": 104.0, "stats": {"attempted": 1}}]}, spec) is None
+
+
+@pytest.mark.parametrize("first_loop, pauses", [
+    # the server started inside the window: its first record counts
+    ({"t_start": 100.5, "phases": [["start", 3.5]]}, [0.75, 0.25]),
+    # it started before the window can have opened (120 - 40): set-up's
+    ({"t_start": 70.0, "phases": [["start", 34.0]]}, [0.25]),
+    # no start: the stretch reaches back to set-up's last wave, 4.9 s before
+    # the window opened, over the harness's own collections; `loop.py`'s
+    # rule alone would keep it (REVIEW, PR 37)
+    ({"t_start": 95.1, "phases": [["batch-wait", 8.9]]}, [0.25]),
+    # a first record that does not say where its interval began
+    (None, [0.25])])
+def test_a_records_interval_is_the_windows_only_if_it_began_inside(
+        first_loop, pauses):
+    obs = _started(100.5, {"gc_full_collections": 2, "gc_pause_s": 0.75})
+    obs["waves"][0]["loop"] = first_loop
+    assert interval_field.read(obs, {"field": "gc_pause_s"}) == pauses
+    # a later record without a `loop` (an extender's later pods) began where
+    # the one before it ended: inside
+    del obs["waves"][1]["loop"]
+    assert interval_field.read(obs, {"field": "gc_pause_s"}) == pauses
+    # no record has the field: nothing, not an empty sum
+    assert interval_field.read(obs, {"field": "gc_minor"}) is None
+
+
+@pytest.mark.parametrize("cell_name, expect, absent", [
+    ("density-1k.backlog",
+     {"start_nodes_sync_s": 0.5, "start_pods_sync_s": 2.0,
+      "start_pods_list_s": 0.75, "start_pods_list_kv_s": 0.125,
+      "start_pods_handlers_s": 1.2, "start_pods_decode_s": 0.6,
+      "gc_pause_s.backlog": 1.0, "gc_full_collections": 3.0},
+     ["start_compile_ahead_s", "gc_pause_s.arrivals"]),
+    ("extender-5k.filter-prioritize",
+     {"start_compile_ahead_s": 1.5, "start_pods_decode_s": 0.6,
+      "gc_full_collections": 3.0}, ["gc_pause_s.arrivals"]),
+    ("flagship-5k.arrivals", {"gc_pause_s.arrivals": 1.0},
+     ["gc_pause_s.backlog", "gc_full_collections", "start_pods_sync_s"])])
+def test_the_new_metrics_through_their_files(cell_name, expect, absent):
+    obs = _started(100.5, {"gc_full_collections": 2, "gc_pause_s": 0.75})
+    out = cell.compute_metrics(BENCH, "per_layer", cell_name, obs)
+    assert {n: out[n]["value"] for n in expect} == pytest.approx(expect)
+    assert not set(absent) & set(out)
+    assert out[next(iter(expect))]["unit"] == "s"
+    # a parent's records (no `children` on the loop, no `gc_*`) leave every
+    # one of them out and keep the rest
+    old = _started(100.5, {})
+    for w in old["waves"]:
+        w["loop"].pop("children", None)
+        w.pop("gc_pause_s", None), w.pop("gc_full_collections", None)
+    kept = cell.compute_metrics(BENCH, "per_layer", cell_name, old)
+    new = {m["name"] for m in BENCH["per_layer"]
+           if m["name"].startswith(("start_", "gc_"))}
+    assert len(new) == 10 and set(kept) == set(out) - new

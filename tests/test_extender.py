@@ -531,6 +531,14 @@ def test_telemetry_off_records_nothing_and_answers_the_same(monkeypatch):
             "PodUID": v1["metadata"]["uid"], "Node": flt["NodeNames"][0]})
         assert res["Error"] == ""
         assert served.backend.telemetry.recorder.records() == []
+        # nor does the start pay for an account nobody keeps (ISSUE 37):
+        # no Trace under a handler or the compile-ahead, the round's three
+        # stages alone (a handful of clock reads)
+        assert served.start_log == []
+        for informer in (served.node_informer, served.pod_informer):
+            assert not informer.trace_below and list(
+                informer.last_sync["children"]) == ["list", "index",
+                                                    "handlers"]
 
 
 def test_no_verb_compiles_after_start_returns():
@@ -548,7 +556,7 @@ def test_no_verb_compiles_after_start_returns():
         assert [name for _d, name in served.warm_log] == [
             "filter", "diagnose", "prioritize"]
         assert [name for name, _s in served.start_log] == [
-            "nodes-sync", "pods-sync", "compile-ahead"]
+            "nodes-sync", "pods-sync", "compile-ahead", "socket"]
         names = [f"node-{i}" for i in range(64)]
         armed[0] = True
         try:
@@ -570,3 +578,26 @@ def test_no_verb_compiles_after_start_returns():
         finally:
             armed[0] = False
         assert events == []
+        # ISSUE 37: `start_log` and the first pod's `loop` are ONE account
+        first, *later = served.backend.telemetry.recorder.records()
+        loop = first["loop"]
+        stages = {p: v for p, v in loop["children"].items()
+                  if p.count("/") == 1}
+        assert [(p.split("/")[1], v[1]) for p, v in stages.items()] \
+            == served.start_log
+        assert [n for n, _s in loop["phases"]] == ["start"]
+        assert sum(v[1] for v in stages.values()) == pytest.approx(
+            loop["phases"][0][1], rel=0.02, abs=1e-3)
+        assert loop["synced"] == {"start/nodes-sync": True,
+                                  "start/pods-sync": True}
+        below = set(loop["children"])
+        assert below >= {
+            "start/pods-sync/list/apiserver.list/store.list/kv",
+            "start/pods-sync/index", "start/pods-sync/handlers/decode",
+            "start/nodes-sync/handlers/decode",
+            "start/compile-ahead/snapshot", "start/compile-ahead/filter",
+            "start/compile-ahead/diagnose", "start/compile-ahead/prioritize",
+            "start/compile-ahead/patch-ladder"}
+        assert loop["children"]["start/pods-sync/handlers/decode"][0] == 128
+        assert len(later) == 3 and not any("loop" in r for r in later)
+        assert all("gc_pause_s" in r for r in [first] + later)

@@ -9,6 +9,7 @@ exactly — no sleeps, no wall-time flakes.
 """
 
 import dataclasses
+import gc
 import json
 import logging
 import threading
@@ -195,6 +196,10 @@ _BINDING = ["bind-commit/assume", "bind-commit/bind-call",
 _FIRST_SNAPSHOT = ["snapshot/full", "snapshot/full/upload",
                    "snapshot/prepare"]
 _HEAD = ["recorder", "t_start", "duration_s", "phases", "engine", "rc"]
+# what the collector did since the previous record (ISSUE 37): after the
+# record's own fields, before what the caller adds
+_GC = ["gc_full_collections", "gc_pause_s", "gc_max_pause_s",
+       "gc_max_pause_at"]
 _WAVE_SHAPES = {
     # kind: (phases, children, keys)
     "bulk": (_BULK,
@@ -203,26 +208,27 @@ _WAVE_SHAPES = {
                          "requeue/snapshot/patch/upload",
                          "requeue/snapshot/prepare"] + _FIRST_SNAPSHOT,
              _HEAD + ["bucket", "affinity_agg", "stats", "device_split",
-                      "children", "snapshot_mode", "waits",
+                      "children"] + _GC + ["snapshot_mode", "waits",
                       "assumed_outstanding", "minor_faults", "seq"]),
     "micro": (_BULK,
               _BINDING + _FIRST_SNAPSHOT + ["snapshot/upload"],
               _HEAD + ["micro", "bucket", "affinity_agg", "stats",
-                       "device_split", "children", "snapshot_mode", "waits",
-                       "assumed_outstanding", "minor_faults", "seq"]),
+                       "device_split", "children"] + _GC + [
+                           "snapshot_mode", "waits", "assumed_outstanding",
+                           "minor_faults", "seq"]),
     "paused": (["pump", "paused"], None,
-               _HEAD + ["stats", "supervisor_events", "seq"]),
+               _HEAD + ["stats", "supervisor_events"] + _GC + ["seq"]),
     "abandoned": (["pump", "pop", "snapshot", "prewarm", "dispatch",
                    "readback", "requeue"],
                   _FIRST_SNAPSHOT,
                   _HEAD + ["bucket", "affinity_agg", "stats",
-                           "supervisor_events", "children", "waits",
-                           "assumed_outstanding", "seq"]),
+                           "supervisor_events", "children"] + _GC + [
+                               "waits", "assumed_outstanding", "seq"]),
     "raises": (_BULK[:8] + ["exception"],
                _BINDING + _FIRST_SNAPSHOT,
                _HEAD + ["bucket", "affinity_agg", "stats", "device_split",
-                        "children", "waits", "assumed_outstanding",
-                        "exception", "seq"]),
+                        "children"] + _GC + ["waits", "assumed_outstanding",
+                                             "exception", "seq"]),
 }
 
 
@@ -773,6 +779,9 @@ class TestChildSpans:
         assert spy.seen == [(None, None)]    # a callee pays one None check
         assert s.telemetry.recorder.records() == []
         assert s.telemetry._loop is None
+        s.telemetry.loop_stage("start/wiring")       # a no-op, like the lap
+        assert s.telemetry.loop_account() == {}
+        assert s.telemetry._gc is None               # no collector hook
 
     def test_no_server_loop_no_loop_field(self):
         clk = {"t": 0.0}
@@ -1011,6 +1020,184 @@ class TestServerLoopSpans:
             pytest.approx(rec["t_start"])
 
 
+    def test_start_stages_sum_to_the_start_lap(self, monkeypatch, caplog):
+        """`SchedulerServer.start()` over scripted informers: each stretch
+        of it is a stage below the `start` lap, an informer's own round
+        grafted under the stage that waited for it; a start that goes on
+        unsynced says so, on the record, in the log and in a counter."""
+        from kubernetes_tpu.sched import server as server_mod
+        from kubernetes_tpu.sched.metrics import START_UNSYNCED
+
+        clk = {"t": 50.0}
+        srv, s = _loop_server(clk)
+        # resource: (seconds its sync took, synced in time, its round)
+        script = {
+            "nodes": (0.25, True, {"list": [1, 0.1, 0.1],
+                                   "list/apiserver.list": [1, 0.08, 0.08],
+                                   "index": [1, 0.01, 0.01],
+                                   "handlers": [1, 0.125, 0.125],
+                                   "handlers/decode": [8, 0.0625, 0.03]}),
+            "pods": (1.25, False, None)}
+
+        class _Informer:
+            last_sync, relists = None, 1
+
+            def __init__(self, rc):
+                self.rc = rc
+
+            def add_handlers(self, **kw):
+                pass
+
+            def start(self):
+                return self
+
+            def stop(self):
+                pass
+
+            def wait_for_sync(self, timeout=10.0):
+                cost, synced, children = script[self.rc.resource]
+                clk["t"] += cost
+                if synced:
+                    gc.collect()   # a pause inside this stage
+                if synced:
+                    self.last_sync = {"synced": True, "children": children}
+                return synced
+
+        monkeypatch.setattr(server_mod, "SharedInformer", _Informer)
+        nothing = lambda: None
+        srv._stop = _ScriptedStop(clk, [nothing, nothing])
+        for i in range(40):
+            s.on_pod_add(_pod(i))
+        unsynced = START_UNSYNCED.value(component="scheduler",
+                                        resource="pods")
+        with caplog.at_level(logging.WARNING,
+                             logger="kubernetes_tpu.sched.server"):
+            srv.start()
+        srv._threads[0].join(30)
+        assert not srv._threads[0].is_alive()
+        w1 = next(r for r in s.telemetry.recorder.records()
+                  if r["stats"]["attempted"])
+        loop = w1["loop"]
+        phases = dict(map(tuple, loop["phases"]))
+        # the top level is what it was: laps, contiguous from t_start
+        assert set(phases) == {"start", "lock-wait", "batch-wait"}
+        assert sum(phases.values()) == pytest.approx(w1["t_start"] - 50.0)
+        stages = {p: v[1] for p, v in loop["children"].items()
+                  if p.count("/") == 1}
+        assert stages == pytest.approx({"start/nodes-sync": 0.25,
+                                        "start/pods-sync": 1.25,
+                                        "start/wiring": 0.0})
+        assert sum(stages.values()) == pytest.approx(phases["start"])
+        # the nodes' round, grafted under the stage that waited for it;
+        # the pods' had not ended: nothing of it, and the verdict kept
+        assert loop["children"]["start/nodes-sync/handlers/decode"] == [
+            8, 0.0625, 0.03]
+        assert loop["children"]["start/nodes-sync/list/apiserver.list"] \
+            == [1, 0.08, 0.08]
+        assert not [p for p in loop["children"]
+                    if p.startswith("start/pods-sync/") and "/gc" not in p]
+        # the collection that ran inside the nodes' stage, below it: a
+        # pause the spans beside it hold, in the children's own form
+        every, full = (loop["children"]["start/nodes-sync/gc" + sub]
+                       for sub in ("", "/full"))
+        assert every[0] >= full[0] >= 1 and every[1] >= full[1] >= full[2] > 0
+        assert "gc" not in loop
+        assert loop["synced"] == {"start/nodes-sync": True,
+                                  "start/pods-sync": False}
+        assert START_UNSYNCED.value(component="scheduler",
+                                    resource="pods") == unsynced + 1
+        assert any("pods informer had not synced" in r.getMessage()
+                   for r in caplog.records)
+
+    def test_gc_fields_cover_the_collections_between_two_records(self):
+        import time
+
+        clk = {"t": 0.0}
+        s = _scheduler(clk)
+        gc.collect()
+        gc.disable()   # only the collections this test forces
+        try:
+            s.on_pod_add(_pod(0))
+            s.schedule_pending()
+            t0 = time.perf_counter()
+            gc.collect()
+            gc.collect(1)
+            gc.collect()
+            t1 = time.perf_counter()
+            s.on_pod_add(_pod(1))
+            s.schedule_pending()
+            s.on_pod_add(_pod(2))
+            s.schedule_pending()
+        finally:
+            gc.enable()
+        _first, forced, quiet = s.telemetry.recorder.records()
+        assert forced["gc_full_collections"] == 2
+        assert 0 < forced["gc_max_pause_s"] <= forced["gc_pause_s"] < t1 - t0
+        assert t0 <= forced["gc_max_pause_at"] <= t1
+        assert {k: v for k, v in quiet.items() if k.startswith("gc_")} == {
+            "gc_full_collections": 0, "gc_pause_s": 0.0,
+            "gc_max_pause_s": 0.0, "gc_max_pause_at": None}
+
+    def test_gc_account_and_its_series(self):
+        from kubernetes_tpu.component.metrics import DEFAULT_REGISTRY
+        from kubernetes_tpu.sched.telemetry import (
+            GcAccount,
+            _GcSeries,
+            gc_account,
+        )
+
+        # the process's account: one hook, however many recorders
+        acct = gc_account()
+        SchedulerTelemetry(name="another")
+        assert gc.callbacks.count(acct) == 1 and gc_account() is acct
+        gc.disable()
+        try:
+            mark = acct.mark()
+            gc.collect(0)
+            gc.collect()
+        finally:
+            gc.enable()
+        assert acct.since(mark)["gc_full_collections"] == 1
+        text = DEFAULT_REGISTRY.expose_text()
+        for gen, n in enumerate(acct.collections):
+            assert (f'process_gc_collections_total{{generation="{gen}"}} '
+                    f'{float(n)}') in text
+        assert 'process_gc_pause_seconds_total{generation="2"}' in text
+        # the longest pause since an instant: the first kept one that
+        # ENDED after it
+        ticks = iter((0.5, 1.0, 1.9, 2.0, 2.7, 3.0, 3.8, 4.0, 5.0))
+        a = GcAccount(clock=lambda: next(ticks))
+        for gen in (2, 0, 1, 0):     # pauses of 0.5, 0.1, 0.3, 0.2 s
+            a("start", {"generation": gen})
+            a("stop", {"generation": gen})
+        assert a.collections == [2, 1, 1]
+        assert [round(dt, 6) for _t, dt in a.all._peaks] == [0.5, 0.3, 0.2]
+        assert a.since(((1, 0.5), (1, 0.5), 1.5)) == {
+            "gc_full_collections": 0, "gc_pause_s": 0.6,
+            "gc_max_pause_s": 0.3, "gc_max_pause_at": 2.7}
+        # a collection that straddles the mark (2.7 to 3.0) is counted
+        # where it stops: its pause AND its peak are this interval's
+        assert a.since(((2, 0.6), (1, 0.5), 2.9)) == {
+            "gc_full_collections": 0, "gc_pause_s": 0.5,
+            "gc_max_pause_s": 0.3, "gc_max_pause_at": 2.7}
+        assert a.since(((3, 0.9), (1, 0.5), 3.5))["gc_max_pause_at"] == 3.8
+        # the same in a Trace's form, for the stage it is grafted below
+        assert a.children(((0, 0.0), (0, 0.0), 0.0)) == {
+            "gc": [4, pytest.approx(1.1), 0.5], "gc/full": [1, 0.5, 0.5]}
+        assert a.children(((1, 0.5), (1, 0.5), 1.5)) == {
+            "gc": [3, pytest.approx(0.6), pytest.approx(0.3)]}
+        mark = a.mark()
+        assert mark == ((4, pytest.approx(1.1)), (1, 0.5), 5.0)
+        assert a.children(mark) == {}
+        assert a.since(mark)["gc_max_pause_at"] is None
+        # a series IS the account's list, whichever way it is read
+        series = _GcSeries("gc_test_total", "", a.collections)
+        assert series.value(generation="0") == 2.0 and series.total() == 4.0
+        assert series.expose()[-3:] == [
+            f'gc_test_total{{generation="{g}"}} {n}'
+            for g, n in enumerate((2.0, 1.0, 1.0))]
+
+
 def _event_server(clk, write_s=0.0):
     """`_loop_server` whose FailedScheduling Events can be watched: a lister
     that knows every pod, and Event creates that wait at `gate` and then
@@ -1168,6 +1355,7 @@ class TestRequestAndTxnMetrics:
                                         subresource="binding")
         n_txn_c = TXN_DURATION.count(op="create")
         n_txn_u = TXN_DURATION.count(op="update")
+        n_txn_l = TXN_DURATION.count(op="list")
         tr = Trace("op", clock=lambda: 0.0)
         token = ktrace.activate(tr)
         try:
@@ -1189,11 +1377,17 @@ class TestRequestAndTxnMetrics:
         assert TXN_DURATION.count(op="create") == n_txn_c + 3
         assert TXN_DURATION.count(op="update") == n_txn_u + 3
         ch = tr.children()
+        # a create's admission reads its namespace's LimitRanges (twice),
+        # ResourceQuotas and the PriorityClasses: four lists (ISSUE 37)
         assert sorted(ch) == [
             "bind/apiserver.bind", "bind/apiserver.bind/store.txn",
             "bind/apiserver.bind/store.txn/kv", "create/apiserver.create",
+            "create/apiserver.create/store.list",
+            "create/apiserver.create/store.list/kv",
             "create/apiserver.create/store.txn",
             "create/apiserver.create/store.txn/kv"]
+        assert ch["create/apiserver.create/store.list"][0] == 12
+        assert TXN_DURATION.count(op="list") == n_txn_l + 12
         for outer in ("bind/apiserver.bind", "create/apiserver.create"):
             assert ch[outer][0] == 3
             assert ch[outer + "/store.txn/kv"][1] \

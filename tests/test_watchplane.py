@@ -753,6 +753,111 @@ class TestRelistBackoffFix:
         assert not inf._thread.is_alive()
 
 
+class TestLastSync:
+    """ISSUE 37: every list+replace round leaves `SharedInformer.last_sync`
+    and one observation a stage of `informer_sync_duration_seconds`."""
+
+    @staticmethod
+    def _observed(stage):
+        from kubernetes_tpu.client.informers import INFORMER_SYNC_DURATION
+
+        return INFORMER_SYNC_DURATION.count(resource="stubs", stage=stage)
+
+    @pytest.mark.parametrize("below", [True, False])
+    def test_the_initial_list_files_the_store_below_the_round(self, below):
+        """Through `Client.local` the round's Trace is the request's: the
+        in-process apiserver and the store file themselves below `list`.
+        With `trace_below` off (a server whose telemetry is off) the three
+        stages alone are timed, and a handler finds no Trace."""
+        from kubernetes_tpu.client import SharedInformer
+        from kubernetes_tpu.component import trace
+
+        api, client = _mkapi()
+        decoded = []
+        try:
+            for i in range(5):
+                client.pods.create(v1pod(f"p{i}"))
+            inf = SharedInformer(client.pods, namespace="default")
+            inf.trace_below = below
+            inf.add_handlers(
+                on_add=lambda o: decoded.append(trace.current() is not None))
+            assert inf.last_sync is None
+            inf.start()
+            assert inf.wait_for_sync(10)
+            sync = inf.last_sync     # written before the waiter is let go
+            inf.stop()
+        finally:
+            api.close()
+        assert (sync["resource"], sync["items"], sync["synced"]) == (
+            "pods", 5, True) and decoded == [below] * 5
+        ch = sync["children"]
+        kv = "list/apiserver.list/store.list/kv"
+        assert list(ch) == ["list", "list/apiserver.list",
+                            "list/apiserver.list/store.list", kv, "index",
+                            "handlers"] if below else ["list", "index",
+                                                       "handlers"]
+        if below:
+            assert ch[kv][1] <= ch["list/apiserver.list/store.list"][1] \
+                <= ch["list/apiserver.list"][1] <= ch["list"][1]
+        stages = sum(ch[s][1] for s in ("list", "index", "handlers"))
+        assert stages <= sync["duration_s"] <= stages + 0.05
+
+    def test_a_relist_leaves_the_same(self):
+        from kubernetes_tpu.client import SharedInformer
+        from kubernetes_tpu.component import trace
+
+        seen = []
+        rc = _StubRC(list_fn=lambda: {     # list OK, the watch 410s: relist
+            "items": [{"metadata": {"name": "a", "namespace": "d"}}],
+            "metadata": {"resourceVersion": str(len(seen) + 1)}})
+        n0 = self._observed("handlers")
+        inf = SharedInformer(rc, relist_backoff=0.01)
+        # what a handler files on `trace.current()` is the round's; the
+        # second round delivers the known object as an update
+        inf.add_handlers(
+            on_add=lambda o: (seen.append("add"),
+                              trace.current().child("decode", 0.25)),
+            on_update=lambda old, new: (
+                seen.append("update"), trace.current().child("decode", 0.5)))
+        inf.start()
+        try:
+            assert inf.wait_for_sync(10)
+            first = inf.last_sync
+            assert wait_until(lambda: inf.relists >= 2 and inf.last_sync
+                              is not first and inf.last_sync["synced"], 10)
+            again = inf.last_sync
+        finally:
+            inf.stop()
+        assert seen[:2] == ["add", "update"]
+        assert first["children"]["handlers/decode"] == [1, 0.25, 0.25]
+        assert again["children"]["handlers/decode"] == [1, 0.5, 0.5]
+        assert again["t_start"] > first["t_start"]
+        assert set(again) == set(first) == {
+            "resource", "t_start", "duration_s", "items", "synced",
+            "children"}
+        assert self._observed("handlers") >= n0 + 2
+
+    def test_a_round_that_does_not_end_is_not_synced(self):
+        from kubernetes_tpu.client import SharedInformer
+
+        def boom():
+            raise RuntimeError("list down")
+
+        rc = _StubRC(list_fn=boom)
+        n0 = self._observed("list"), self._observed("handlers")
+        inf = SharedInformer(rc, relist_backoff=0.01)
+        inf.start()
+        try:
+            assert not inf.wait_for_sync(0.3)   # the verdict start() reads
+            assert wait_until(lambda: inf.last_sync is not None, 5)
+            sync = inf.last_sync
+        finally:
+            inf.stop()
+        assert sync["synced"] is False and sync["items"] == 0
+        assert sync["children"] == {}           # no stage ran to its end
+        assert (self._observed("list"), self._observed("handlers")) == n0
+
+
 # --------------------------------------------------------------------- #
 # WatchMux: routing, backpressure, resync, death
 # --------------------------------------------------------------------- #
