@@ -41,6 +41,18 @@ TPU idle. This module replaces it as the default path. Per wave:
      against exactly the state that rejected them) — so the loop always
      terminates and an infeasible head class never costs a dedicated wave.
 
+How a round sees its nodes in order (steps 3 and the map back to pods): an
+order is a `lax.sort`, and whatever must be seen in that order is an OPERAND
+of the sort that makes it — the class's score order (`_score_order`: two keys,
+score then the rotated tie-break), a constraint's nodes grouped by topology
+domain (`_within_quota`: domain first, then the score order's two keys; the
+rank inside a domain is a segmented scan, `_rank_in_run`), an answer put back
+in node order (`_to_nodes`: a sort keyed by the permutation), a class's kept
+nodes moved to the head of their row. No [SC, N] array is indexed element by
+element through a permutation the round has just computed: on the TPU such a
+gather or scatter costs ~10 ns an element, more than the sort that made its
+indices (PERF.md section 6, PR 38; tests/test_waves.py counts them).
+
 Soundness invariant (tested in tests/test_waves.py): replaying the final
 assignment wave-by-wave, each pod in queue order, every placement passes the
 full Filter mask at replay time — i.e. the output is a valid greedy execution
@@ -60,7 +72,8 @@ from ..state.arrays import Array, ClusterTables, PodArrays
 from .assign import (AssignResult, AssignState, pod_mask_row, score_row,
                      state_affinity_table)
 from .fit import _fit
-from .interpod import class_term_membership, domain_agg, in_domain_counts
+from .interpod import (class_term_membership, domain_agg, domain_of_term,
+                       in_domain_counts)
 from .lattice import CycleArrays
 
 # plain Python ints only: a module-level jnp scalar would be captured as a
@@ -83,8 +96,8 @@ class _WaveCarry(NamedTuple):
     state: AssignState
     cursor: Array     # [SC] pods consumed per class (placed or tier-failed)
     placed: Array     # [SC] pods actually placed per class
-    node_out: Array   # [P+1] chosen node per sorted-pod slot (last = sink)
-    wave_out: Array   # [P+1] wave index each pod was admitted in (-1 = never)
+    node_out: Array   # [P] chosen node per pod (-1 = none)
+    wave_out: Array   # [P] wave index each pod was admitted in (-1 = never)
     waves: Array      # scalar i32
 
 
@@ -149,38 +162,82 @@ def _class_mask_score(tables, cyc, state, table):
             scores.reshape(-1, scores.shape[-1])[:SC])
 
 
+def _score_order(neg_score: Array, rot_pos: Array, offs: Array, *riders):
+    """Every class's nodes best first → (order_n [SC, N], *riders in that
+    order). ONE two-key sort in node space: score descending (`neg_score` =
+    -score; a masked node's is +inf, so it sorts last), ties by `rot_pos`,
+    the node's position in the class's rotated row (offs: [SC] rotation).
+    What must be seen in the order rides the sort as an operand: an element
+    gather through a permutation costs more here than the sort that made
+    it."""
+    N = neg_score.shape[1]
+    _, pos, *out = lax.sort((neg_score, rot_pos) + riders, dimension=1,
+                            num_keys=2)
+    return ((pos + offs[:, None]) % N, *out)
+
+
+def _rank_in_run(key: Array) -> Array:
+    """key [N], sorted → every element's 0-based rank inside its run of
+    equal keys: its index less the index its run starts at, and that start
+    is a running max over the run boundaries' indices (position 0 is one).
+    A segmented scan: no table over the key's range, which for a
+    hostname-keyed constraint is N itself."""
+    idx = jnp.arange(key.shape[0], dtype=jnp.int32)
+    starts = jnp.concatenate([jnp.ones((1,), bool), key[1:] != key[:-1]])
+    return idx - lax.cummax(jnp.where(starts, idx, 0), axis=0)
+
+
+def _to_nodes(node: Array, bits: Array) -> Array:
+    """bits [..., N], each said of the node beside it in `node` (a
+    permutation of the node axis per row) → the same bits in node order. A
+    scatter through a permutation is a sort keyed by the permutation with
+    the values riding."""
+    return lax.sort((node, bits), dimension=node.ndim - 1, num_keys=1)[1]
+
+
+def _within_quota(neg_score: Array, rot_pos: Array, off: Array, dom: Array,
+                  D: int, quota_n: Array | int) -> Array:
+    """One class, one constraint slot → [N] bool in node order: the node is
+    among the first `quota_n` of its topology domain in the class's score
+    order. dom [N]: the node's domain (-1 = none: those share bucket D);
+    quota_n: the domain's cap said of each node [N], or one cap for all.
+
+    ONE sort groups the nodes by domain, best first inside each group (the
+    score order's own two keys break the domain's ties), the cap riding as an
+    operand: O(N log N), never an [N, D] one-hot (D can be N itself), and
+    rank-in-domain is the index in the grouped order less the group's
+    start."""
+    N = dom.shape[0]
+    riders = () if isinstance(quota_n, int) else (quota_n,)
+    dom_g, _, pos_g, *cap_g = lax.sort(
+        (jnp.where(dom >= 0, dom, D), neg_score, rot_pos) + riders,
+        num_keys=3)
+    ok_g = _rank_in_run(dom_g) < (cap_g[0] if cap_g else quota_n)
+    return _to_nodes((pos_g + off) % N, ok_g)
+
+
 @jax.named_scope("domain_quota_pass")
-def _domain_quota_pass(tables, cyc, state, mask, order_n, allowed_sorted):
-    """AND per-domain admission quotas into `allowed_sorted` [SC, N] (nodes in
-    per-class score order). Quotas keep same-wave same-class admissions from
-    violating hard spread / self-anti-affinity when replayed sequentially."""
+def _domain_quota_pass(tables, cyc, state, allowed, neg_score, rot_pos, offs):
+    """AND per-domain admission quotas into `allowed` [SC, N] (node order).
+    Quotas keep same-wave same-class admissions from violating hard spread /
+    self-anti-affinity when replayed sequentially. `neg_score`, `rot_pos`
+    [SC, N] are the two keys of the class's score order (assign_waves.body),
+    `offs` [SC] the rotation that turns a `rot_pos` back into its node."""
     classes = tables.classes
     nodes = tables.nodes
     terms = tables.terms
     D = cyc.ELD.shape[2] - 1
-    SC, N = mask.shape
+    SC, N = allowed.shape
     TS = classes.tsc_term.shape[1]
     AN = classes.anti_terms.shape[1]
 
-    def slot_quota(c, s_id, topo_key, active, quota_d):
-        """quota_d: [D+1] cap per domain; returns [N] allowed-in-sorted-order
-        for this class/slot. rank-in-domain is computed by a (domain, score
-        rank) lexsort — O(N log N), never materializing an [N, D] one-hot
-        (D can be N itself for hostname-keyed constraints)."""
-        k = jnp.maximum(topo_key, 0)
-        dom = jnp.where((topo_key >= 0) & nodes.valid, nodes.domain[:, k], -1)
-        dom_sorted = dom[order_n[c]]                  # [N] score-desc order
-        dsafe = jnp.where(dom_sorted >= 0, dom_sorted, D)
-        # stable-sort score-ordered positions by domain: within each domain
-        # group the score order is preserved, so rank-in-domain = index in
-        # the grouped array minus the group's start index
-        gidx = jnp.arange(N, dtype=jnp.int32)
-        grp = jnp.argsort(dsafe, stable=True)         # grouped order
-        dom_g = dsafe[grp]
-        start = jnp.full((D + 1,), N, jnp.int32).at[dom_g].min(gidx)
-        rank_g = gidx - start[dom_g]
-        rank_in_dom = jnp.zeros((N,), jnp.int32).at[grp].set(rank_g)
-        return ~active | (rank_in_dom < quota_d[dsafe])
+    def key_domain(topo_key):
+        """[N] every node's domain under the key, -1 where it has none."""
+        return domain_of_term(nodes, topo_key[None])[0][0]
+
+    def slot_quota(c, dom, active, quota_n):
+        return ~active | _within_quota(neg_score[c], rot_pos[c], offs[c],
+                                       dom, D, quota_n)
 
     # --- hard topology-spread slots (only self-matching classes move their
     # own counts; others are quota-free here and guarded by the graph).
@@ -197,17 +254,15 @@ def _domain_quota_pass(tables, cyc, state, mask, order_n, allowed_sorted):
         )
         eld = cyc.ELD[c, t, :D]
         active = active & eld.any()
-        k = terms.topo_key[s]
-        dom = jnp.where((k >= 0) & nodes.valid,
-                        nodes.domain[:, jnp.maximum(k, 0)], -1)
+        dom = key_domain(terms.topo_key[s])
         seg = domain_agg(state.CNT[s][None], dom[None], D,
                          eligible=cyc.static.node_match[c][None])[0]
         min_cnt = jnp.min(jnp.where(eld, seg[:D], _I32_MAX))
         quota = jnp.clip(
             classes.tsc_maxskew[c, t] + min_cnt - seg, 0, _I32_MAX
         )
-        quota = jnp.where(active, quota, _I32_MAX)
-        return slot_quota(c, s_id, k, active, quota)
+        # the domain's cap is said of each node ONCE, here in node order
+        return slot_quota(c, dom, active, quota[jnp.where(dom >= 0, dom, D)])
 
     def apply_spread(allowed):
         rows = jax.vmap(
@@ -218,8 +273,7 @@ def _domain_quota_pass(tables, cyc, state, mask, order_n, allowed_sorted):
 
     any_spread = ((classes.tsc_term >= 0) & classes.tsc_hard
                   & classes.valid[:, None]).any()
-    allowed_sorted = lax.cond(any_spread, apply_spread,
-                              lambda a: a, allowed_sorted)
+    allowed = lax.cond(any_spread, apply_spread, lambda a: a, allowed)
 
     # --- self-matching anti-affinity slots: one per domain per wave ---
     def anti_slot(c, t):
@@ -227,9 +281,7 @@ def _domain_quota_pass(tables, cyc, state, mask, order_n, allowed_sorted):
         s = jnp.maximum(s_id, 0)
         k = terms.topo_key[s]
         active = (s_id >= 0) & cyc.TM[s, c] & (k >= 0)
-        quota = jnp.where(active, jnp.ones((D + 1,), jnp.int32),
-                          _I32_MAX)
-        return slot_quota(c, s_id, k, active, quota)
+        return slot_quota(c, key_domain(k), active, 1)
 
     def apply_anti(allowed):
         rows = jax.vmap(
@@ -240,10 +292,7 @@ def _domain_quota_pass(tables, cyc, state, mask, order_n, allowed_sorted):
 
     any_anti = ((classes.anti_terms >= 0)
                 & classes.valid[:, None]).any()
-    allowed_sorted = lax.cond(any_anti, apply_anti,
-                              lambda a: a, allowed_sorted)
-
-    return allowed_sorted
+    return lax.cond(any_anti, apply_anti, lambda a: a, allowed)
 
 
 def _escape_cap(tables, cyc, state, r, table):
@@ -306,11 +355,12 @@ def assign_waves(
     class_offset = jnp.cumsum(class_total) - class_total  # [SC] exclusive
     sorted_pods_pad = jnp.concatenate(
         [sorted_pods, jnp.full((1,), P, jnp.int32)])
-    pos_in_class = jnp.arange(P, dtype=jnp.int32) - class_offset[
-        jnp.minimum(cls_safe[sorted_pods], SC - 1)]
-    pri_sorted = pods.priority[sorted_pods]
-    cls_sorted = jnp.minimum(cls_safe[sorted_pods], SC - 1)
-    sorted_valid = pods.valid[sorted_pods]
+    cls_of_pod = jnp.minimum(cls_safe, SC - 1)
+    # every pod's place in its class's queue order: the inverse of the sort,
+    # taken once a dispatch
+    pos_of_pod = jnp.zeros((P,), jnp.int32).at[sorted_pods].set(
+        jnp.arange(P, dtype=jnp.int32)) - class_offset[cls_of_pod]
+    node_ids = jnp.arange(N, dtype=jnp.int32)
 
     def body(carry: _WaveCarry) -> _WaveCarry:
         state, cursor, placed, node_out, wave_out, waves = carry
@@ -336,11 +386,11 @@ def assign_waves(
         # own head priority, at/after the cursor) — the unit that fails
         # together when the head pod is infeasible against frozen state
         run_pod = (
-            sorted_valid & (pri_sorted == nxt_pri[cls_sorted])
-            & (pos_in_class >= cursor[cls_sorted])
+            pods.valid & (pods.priority == nxt_pri[cls_of_pod])
+            & (pos_of_pod >= cursor[cls_of_pod])
         )
         run_cnt = (
-            jnp.zeros((SC,), jnp.int32).at[cls_sorted].add(
+            jnp.zeros((SC,), jnp.int32).at[cls_of_pod].add(
                 run_pod.astype(jnp.int32))
         )
         r = jnp.where(nxt_ok, jnp.minimum(remaining, run_cnt), 0)
@@ -391,18 +441,13 @@ def assign_waves(
         # batch at offset 0 → identical to the sequential scan's
         # argmax-lowest-index (PARITY #1, tests' singleton agreement).
         offs = (crank * 97) % N
-        rot = (jnp.arange(N, dtype=jnp.int32)[None, :]
-               + offs[:, None]) % N                          # [SC, N]
-        score_rot = jnp.take_along_axis(score, rot, axis=1)
-        order_rot = jnp.argsort(-score_rot, axis=1)
-        order_n = jnp.take_along_axis(rot, order_rot, axis=1)  # [SC, N]
-        feas_sorted = jnp.take_along_axis(adm_mask, order_n, axis=1)
-        allowed = _domain_quota_pass(
-            tables, cyc, state, adm_mask, order_n, feas_sorted)
+        neg_score = -score
+        rot_pos = (node_ids[None, :] - offs[:, None]) % N     # [SC, N]
+        allowed_n = _domain_quota_pass(
+            tables, cyc, state, adm_mask, neg_score, rot_pos, offs)
+        order_n, allowed = _score_order(neg_score, rot_pos, offs, allowed_n)
         grank = jnp.cumsum(allowed.astype(jnp.int32), axis=1) - 1
-        adm_sorted = allowed & (grank < r[:, None])
-        A = jnp.zeros((SC, N), bool).at[
-            jnp.arange(SC)[:, None], order_n].set(adm_sorted)
+        A = _to_nodes(order_n, allowed & (grank < r[:, None]))
 
         # per-node cross-class resolution in queue-rank order, as a scan
         # over CLASS BLOCKS: the cumulative passes need [block, N, …]
@@ -550,20 +595,19 @@ def assign_waves(
                 vol_any=state.vol_any | orva, vol_rw=state.vol_rw | orvr,
             )
 
-        # ---- map admissions back to pods (rank among kept, score order) ----
-        sck = jnp.where(A_final, score, -jnp.inf)
-        ordk = jnp.argsort(-sck, axis=1)
-        kept_sorted = jnp.take_along_axis(A_final, ordk, axis=1)
-        rank_sorted = jnp.cumsum(kept_sorted.astype(jnp.int32), axis=1) - 1
-        rank = jnp.zeros((SC, N), jnp.int32).at[
-            jnp.arange(SC)[:, None], ordk].set(rank_sorted)
-        tgt = jnp.where(A_final, class_offset[:, None] + cursor[:, None] + rank,
-                        P)
-        pod_id = jnp.where(A_final, sorted_pods_pad[jnp.minimum(tgt, P)], P)
-        node_out2 = node_out.at[pod_id.reshape(-1)].set(
-            jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32)[None, :],
-                             (SC, N)).reshape(-1))
-        wave_out2 = wave_out.at[pod_id.reshape(-1)].set(waves)
+        # ---- map admissions back to pods: a class's kept nodes, best first
+        # (score, then node index), go to its next m pods in queue order.
+        # The kept ride to the head of their row in one sort, and every POD
+        # looks its node up: P lookups, where a write per (class, node)
+        # would be SC·N updates of which sum(m) ≤ P carry anything ----
+        _, _, kept_nodes = lax.sort(
+            (~A_final, neg_score, jnp.broadcast_to(node_ids, (SC, N))),
+            dimension=1, num_keys=2, is_stable=True)
+        j = pos_of_pod - cursor[cls_of_pod]
+        won = pods.valid & (j >= 0) & (j < m[cls_of_pod])
+        node_out2 = jnp.where(
+            won, kept_nodes[cls_of_pod, jnp.clip(j, 0, N - 1)], node_out)
+        wave_out2 = jnp.where(won, waves, wave_out)
 
         # Failure consumption, two rules (both replay-sound):
         #  * global zero progress ⇒ state is frozen ⇒ every attempting
@@ -610,13 +654,13 @@ def assign_waves(
         state=init,
         cursor=jnp.zeros((SC,), jnp.int32),
         placed=jnp.zeros((SC,), jnp.int32),
-        node_out=jnp.full((P + 1,), -1, jnp.int32),
-        wave_out=jnp.full((P + 1,), -1, jnp.int32),
+        node_out=jnp.full((P,), -1, jnp.int32),
+        wave_out=jnp.full((P,), -1, jnp.int32),
         waves=jnp.int32(0),
     )
     final = lax.while_loop(cond, body, init_carry)
-    node = final.node_out[:P]
+    node = final.node_out
     result = AssignResult(node=node, feasible=node >= 0, state=final.state)
     if return_waves:
-        return result, final.wave_out[:P]
+        return result, final.wave_out
     return result

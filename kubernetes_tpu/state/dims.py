@@ -65,9 +65,11 @@ class Dims:
     PP: int = 4       # host ports per pod
     AT: int = 2       # required pod-affinity terms per pod
     # AN and TS floors are 1, not 2: each slot is a full vmapped
-    # quota family in the wave engine (ops/waves.py _domain_quota_pass —
-    # an [N] sort per class per slot per wave), so an unused second slot
-    # is pure device time; workloads with 2+ constraints grow the bucket
+    # quota family in the wave engine (ops/waves.py _within_quota — two
+    # [N] sorts per class per slot per wave: the nodes grouped by domain
+    # with the score order's keys and the cap riding, and the answer back
+    # to node order), so an unused second slot is pure device time;
+    # workloads with 2+ constraints grow the bucket
     AN: int = 1       # required pod-anti-affinity terms per pod
     PAT: int = 2      # preferred pod-affinity terms per pod
     PAN: int = 2      # preferred pod-anti-affinity terms per pod

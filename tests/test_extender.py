@@ -523,6 +523,10 @@ def test_telemetry_off_records_nothing_and_answers_the_same(monkeypatch):
     with served_cluster() as (client, served, groups):
         v1 = client.pods.create(groups.pod(_role_group(groups, "anti"),
                                            "quiet"))
+        # with no record to carry the filter's pod, `bind` looks the pod up
+        # in the informer's store: let its watch event land first
+        assert _wait(lambda: served.pod_informer.lister.get(
+            "default", "quiet") is not None)
         names = [f"node-{i}" for i in range(64)]
         _, flt = _post(served.url, "filter", {"Pod": v1, "NodeNames": names})
         assert 0 < len(flt["NodeNames"]) < 64

@@ -394,3 +394,271 @@ def test_escape_cap_counts_what_the_predicate_counts():
     assert len({int(n) % 3 for n in node}) == 1, node
     w = np.asarray(waves)[:4]
     assert (w == w.min()).sum() == 1, w
+
+
+# ---------------------------------------------------------------------------
+# PR 38: the admission's order and quotas ride sorts (ops/waves.py). Two
+# rungs: `assign_waves` end to end against arrays RECORDED from the parent
+# commit, and the new order / quota pieces against the parent's gather /
+# scatter forms, kept here verbatim as the plain reference.
+# ---------------------------------------------------------------------------
+
+_RECORDED = "waves_parent_e8e4f80.npz"   # from commit e8e4f80 (PR 37)
+
+
+def _recorded_case(name):
+    """Flagship-shaped inputs (hard zone spread on every group, host
+    anti-affinity on a third, required in-zone affinity to a partner on a
+    third, three priorities, four request tiers), built without randomness."""
+    from kubernetes_tpu.models.workloads import (ZONE, flagship_pods,
+                                                 make_nodes)
+
+    def renamed(pods, prefix, at):
+        return [dataclasses.replace(p, name=f"{prefix}-{p.name}",
+                                    creation_index=at + p.creation_index)
+                for p in pods]
+
+    if name == "flagship-100x1000":
+        return (make_nodes(100, zones=4, racks_per_zone=5), [],
+                flagship_pods(1000, groups=24))
+    if name == "bound-64x360":
+        # pods already bound (so counts, quotas and the escape are not
+        # zero) and four nodes that carry no zone label (dom = -1)
+        nodes = make_nodes(64, zones=4, racks_per_zone=4)
+        for i in (5, 22, 39, 56):
+            nodes[i] = dataclasses.replace(nodes[i], labels={
+                k: v for k, v in nodes[i].labels.items() if k != ZONE})
+        bound = [dataclasses.replace(p, node_name=f"node-{(7 * i) % 64}")
+                 for i, p in enumerate(flagship_pods(240, groups=12))]
+        return nodes, bound, renamed(flagship_pods(360, groups=12), "w", 1000)
+    if name == "tight-16x300":
+        # far more pods than room: contention losers retry, runs fail
+        return (make_nodes(16, zones=4, racks_per_zone=2, cpu="4",
+                           memory="8Gi", pods=20), [],
+                flagship_pods(300, groups=9))
+    raise KeyError(name)
+
+
+_RECORDED_CASES = ("flagship-100x1000", "bound-64x360", "tight-16x300")
+
+
+def _record_parent(path):
+    """How the file was made: this module run as a script with the PARENT's
+    package first on the path, `cd <git archive e8e4f80> && PYTHONPATH=.
+    python <this tree>/tests/test_waves.py record`."""
+    out = {}
+    for name in _RECORDED_CASES:
+        nodes, existing, pending = _recorded_case(name)
+        tables, ex, pe, uk, ev, d = _encode(nodes, existing, pending)
+        res, waves = _run("waves", tables, ex, pe, uk, ev, d.D)
+        out[name + "/node"] = np.asarray(res.node)
+        out[name + "/waves"] = np.asarray(waves)
+        print(name, int((out[name + "/node"] >= 0).sum()), "placed of",
+              len(pending), "in", int(out[name + "/waves"].max()) + 1, "waves")
+    np.savez_compressed(path, **out)
+
+
+@pytest.mark.parametrize("name", _RECORDED_CASES)
+def test_assign_waves_equals_the_parents_recorded_placements(name):
+    """`node` and the returned `waves`, element for element, against what
+    the parent commit's gather / scatter form placed on the same inputs."""
+    import os
+    ref = np.load(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               _RECORDED))
+    nodes, existing, pending = _recorded_case(name)
+    tables, ex, pe, uk, ev, d = _encode(nodes, existing, pending)
+    res, waves = _run("waves", tables, ex, pe, uk, ev, d.D)
+    np.testing.assert_array_equal(np.asarray(res.node), ref[name + "/node"])
+    np.testing.assert_array_equal(np.asarray(waves), ref[name + "/waves"])
+    assert (ref[name + "/node"] >= 0).sum() > 100
+
+
+# --- the parent's forms, verbatim (ops/waves.py at e8e4f80: `slot_quota`
+# :165-183 with its closure's names made arguments, the score order :393-405)
+def _parent_slot_quota(order_row, dom, D, active, quota_d):
+    N = dom.shape[0]
+    dom_sorted = dom[order_row]                   # [N] score-desc order
+    dsafe = jnp.where(dom_sorted >= 0, dom_sorted, D)
+    gidx = jnp.arange(N, dtype=jnp.int32)
+    grp = jnp.argsort(dsafe, stable=True)         # grouped order
+    dom_g = dsafe[grp]
+    start = jnp.full((D + 1,), N, jnp.int32).at[dom_g].min(gidx)
+    rank_g = gidx - start[dom_g]
+    rank_in_dom = jnp.zeros((N,), jnp.int32).at[grp].set(rank_g)
+    return ~active | (rank_in_dom < quota_d[dsafe])
+
+
+def _parent_admission(score, adm_mask, offs, r, slots, D):
+    """→ (order_n, allowed in score order, A). slots: (dom [SC, N],
+    active [SC], quota_d [SC, D + 1]) per constraint slot."""
+    SC, N = score.shape
+    rot = (jnp.arange(N, dtype=jnp.int32)[None, :]
+           + offs[:, None]) % N                          # [SC, N]
+    score_rot = jnp.take_along_axis(score, rot, axis=1)
+    order_rot = jnp.argsort(-score_rot, axis=1)
+    order_n = jnp.take_along_axis(rot, order_rot, axis=1)  # [SC, N]
+    feas_sorted = jnp.take_along_axis(adm_mask, order_n, axis=1)
+    allowed = feas_sorted
+    for dom, active, quota_d in slots:
+        allowed = allowed & jax.vmap(
+            lambda o, dm, a, q: _parent_slot_quota(o, dm, D, a, q))(
+                order_n, dom, active, quota_d)
+    grank = jnp.cumsum(allowed.astype(jnp.int32), axis=1) - 1
+    adm_sorted = allowed & (grank < r[:, None])
+    A = jnp.zeros((SC, N), bool).at[
+        jnp.arange(SC)[:, None], order_n].set(adm_sorted)
+    return order_n, allowed, A
+
+
+def _sorted_admission(score, adm_mask, offs, r, slots, D):
+    """The same three, composed from ops/waves.py's pieces the way
+    `assign_waves.body` and `_domain_quota_pass` compose them."""
+    from kubernetes_tpu.ops import waves as W
+
+    SC, N = score.shape
+    neg_score = -score
+    rot_pos = (jnp.arange(N, dtype=jnp.int32)[None, :] - offs[:, None]) % N
+    allowed_n = adm_mask
+    for dom, active, quota_d in slots:
+        cap = jnp.take_along_axis(quota_d, jnp.where(dom >= 0, dom, D), axis=1)
+        allowed_n = allowed_n & (~active[:, None] | jax.vmap(
+            lambda ns, rp, o, dm, q: W._within_quota(ns, rp, o, dm, D, q))(
+                neg_score, rot_pos, offs, dom, cap))
+    order_n, allowed = W._score_order(neg_score, rot_pos, offs, allowed_n)
+    grank = jnp.cumsum(allowed.astype(jnp.int32), axis=1) - 1
+    return order_n, allowed, W._to_nodes(order_n, allowed & (grank < r[:, None]))
+
+
+_I32_MAX = int(np.iinfo(np.int32).max)
+_ADMISSION_SHAPES = {
+    # name: (N, D, how the domains are drawn)
+    "zone-like-D16": (96, 16, "mod"),
+    "rack-like-D320": (640, 320, "mod"),
+    "hostname-D-is-N": (80, 80, "perm"),
+}
+_ADMISSION_TWISTS = ("plain", "invalid-nodes", "inactive-slot",
+                     "all-scores-equal", "inf-rows", "quota-0-1-max")
+
+
+def _admission_inputs(seed, shape, twist):
+    N, D, how = _ADMISSION_SHAPES[shape]
+    SC = 6
+    rng = np.random.default_rng([seed, N, len(twist)])
+    # few distinct scores: ties everywhere, so the rotation decides often
+    score = rng.integers(0, 4, (SC, N)).astype(np.float32)
+    if twist == "all-scores-equal":
+        score[:] = 7.0
+    mask = rng.random((SC, N)) < 0.8
+    if twist == "inf-rows":
+        mask[1] = False                   # a class feasible nowhere
+        mask[2, : N // 2] = False
+        score[3, ::3] = -np.inf           # -inf on unmasked nodes too
+    score = np.where(mask, score, -np.inf).astype(np.float32)
+    best = np.where(mask, score, -np.inf).max(axis=1, keepdims=True)
+    adm_mask = mask & (score >= best - 1.0)
+
+    def domains():
+        if how == "perm":
+            return np.stack([rng.permutation(N) for _ in range(SC)])
+        return (np.arange(N)[None, :] * 7 + rng.integers(0, D, (SC, 1))) % D
+
+    slots = []
+    for kind in ("spread", "anti"):
+        dom = domains().astype(np.int32)
+        if twist == "invalid-nodes":
+            dom[rng.random((SC, N)) < 0.2] = -1
+            dom[4] = -1                   # a key no node carries
+        active = np.ones((SC,), bool)
+        if twist == "inactive-slot":
+            active[rng.random(SC) < 0.5] = False
+            active[0] = False
+        if kind == "anti":
+            quota_d = np.ones((SC, D + 1), np.int32)
+        elif twist == "quota-0-1-max":
+            quota_d = rng.choice(np.array([0, 1, _I32_MAX], np.int32),
+                                 (SC, D + 1))
+        else:
+            quota_d = rng.integers(0, 4, (SC, D + 1)).astype(np.int32)
+        slots.append((jnp.asarray(dom), jnp.asarray(active),
+                      jnp.asarray(quota_d)))
+    offs = (rng.permutation(SC) * 97 % N).astype(np.int32)
+    r = rng.integers(0, N // 3, (SC,)).astype(np.int32)
+    return (jnp.asarray(score), jnp.asarray(adm_mask), jnp.asarray(offs),
+            jnp.asarray(r), slots, D)
+
+
+@pytest.mark.parametrize("twist", _ADMISSION_TWISTS)
+@pytest.mark.parametrize("shape", list(_ADMISSION_SHAPES))
+@pytest.mark.parametrize("seed", range(2))
+def test_sorted_admission_equals_the_parents_gather_form(seed, shape, twist):
+    """The score order, `allowed` in it and the admission matrix `A` from
+    the sort-borne pieces, bit for bit against the parent's element gathers
+    and scatters through the permutation."""
+    args = _admission_inputs(seed, shape, twist)
+    want = jax.jit(_parent_admission, static_argnums=5)(*args)
+    got = jax.jit(_sorted_admission, static_argnums=5)(*args)
+    for name, w, g in zip(("order_n", "allowed", "A"), want, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    if twist in ("plain", "all-scores-equal"):
+        assert np.asarray(want[2]).any()
+
+
+def _big_indexed_ops(jaxpr, floor, in_loop=False):
+    """(primitive, file:line) of every gather / scatter* equation inside a
+    `while` body whose index operand holds `floor` or more index vectors."""
+    import math
+
+    from jax._src import source_info_util
+
+    found = []
+    for eqn in jaxpr.eqns:
+        prim = eqn.primitive.name
+        if in_loop and (prim == "gather" or prim.startswith("scatter")):
+            idx = eqn.invars[1].aval.shape
+            if math.prod(idx[:-1]) >= floor:
+                fr = source_info_util.user_frame(eqn.source_info.traceback)
+                found.append((prim, f"{fr.file_name.rsplit('/', 1)[-1]}:"
+                                    f"{fr.start_line}" if fr else "?"))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _big_indexed_ops(sub, floor,
+                                              in_loop or prim == "while")
+    return found
+
+
+# what the change leaves: the spread family's cap said of each node, one
+# gather in node order from the [D + 1] table of domains
+_SC_BY_N_INDEXED_LEFT = 1
+
+
+def test_no_sc_by_n_array_is_fetched_through_a_permutation():
+    """Structural guard: in the compiled round (the `while` body of
+    `assign_waves`) count the gather / scatter equations that ops/waves.py
+    itself writes with SC x N or more index vectors. The parent (e8e4f80)
+    had 21 (`/PERF.md` section 6, PR 38: ~10 ns an element on the chip, 3.3 ms
+    each at [64, 5120]). What is left is listed here by name; a later PR
+    that fetches an [SC, N] array through a permutation again meets this
+    test and not a ledger line."""
+    nodes, existing, pending = _recorded_case("bound-64x360")
+    tables, ex, pe, uk, ev, d = _encode(nodes, existing, pending)
+    assert d.P < d.SC * d.N     # so that pod-axis lookups are not counted
+
+    def f(tables, ex, pe, uk, ev):
+        cyc = build_cycle(tables, ex, uk, ev, d.D)
+        return assign_waves(tables, cyc, pe, initial_state(tables, cyc),
+                            return_waves=True)
+
+    ops = _big_indexed_ops(jax.make_jaxpr(f)(tables, ex, pe, uk, ev).jaxpr,
+                           d.SC * d.N)
+    ours = sorted(o for o in ops if o[1].startswith("waves.py"))
+    assert len(ours) == _SC_BY_N_INDEXED_LEFT, ours
+
+
+if __name__ == "__main__":
+    import os
+    import sys
+    if sys.argv[1:] == ["record"]:
+        _record_parent(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                    _RECORDED))
