@@ -105,6 +105,8 @@ class NamespaceLifecycle(AdmissionPlugin):
             return
         if op != CREATE or not info.namespaced or obj is None:
             return
+        if info.group == "ktpu.io":
+            return   # a bind intent's namespace segment names a scheduler
         ns = meta.namespace(obj) or "default"
         try:
             ns_obj = api.store("", "namespaces").get("", ns)

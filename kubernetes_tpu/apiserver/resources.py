@@ -303,6 +303,14 @@ def build_scheme() -> Scheme:
                  namespaced=False, short_names=("csr",),
                  subresources=("status", "approval")))
 
+    # ---- ktpu.io (the scheduler's own coordination objects): a wave's
+    # bind intent (sched/ledger.py), served so that a scheduler in a process
+    # of its own keeps its ledger through the API. The namespace segment is
+    # the SCHEDULER'S NAME, no Namespace object, so the key is the one the
+    # ledger writes when it holds the store itself
+    # (`/registry/ktpu.io/bindintents/<scheduler>/<record>`) ----
+    s.register(R("ktpu.io", "v1", "BindIntent", "bindintents"))
+
     # ---- apiextensions (CRD registration; dynamic install handled by the
     # server's CRD hook) ----
     s.register(R("apiextensions.k8s.io", "v1", "CustomResourceDefinition",

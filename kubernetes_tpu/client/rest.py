@@ -8,6 +8,7 @@ with chunked watch streams. Components depend only on `Client`.
 
 from __future__ import annotations
 
+import http.client
 import json
 import random
 import threading
@@ -16,8 +17,9 @@ import urllib.error
 import urllib.parse
 import urllib.request
 from dataclasses import dataclass
-from typing import Any, Dict, Iterator, Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 
+from kubernetes_tpu.component import trace
 from kubernetes_tpu.machinery import errors, meta
 from kubernetes_tpu.machinery import watch as mwatch
 
@@ -103,11 +105,45 @@ class LocalTransport:
         return w
 
 
+#: what an `HTTPTransport` counts, by the name a wave's record carries it
+#: under (`counters_reader`): requests sent (each attempt), TCP connections
+#: dialled for them, re-dials after a kept-alive connection turned out
+#: closed, requests that ended in a transport error or a 5xx, body bytes
+#: out and in; and from the `http-watch` pump threads the events taken off
+#: the streams, the seconds spent decoding and handing them on, and the
+#: streams that ended any other way than the server or the consumer
+#: ending them
+WIRE_COUNTERS = ("http_requests", "http_connections_opened", "http_retries",
+                 "http_errors", "http_bytes_out", "http_bytes_in",
+                 "watch_events_in", "watch_decode_s", "watch_streams_broken")
+#: what a connection the server closed while it sat idle raises on its next
+#: use, before a byte of an answer: the request is sent again, once, on a
+#: new connection
+_STALE = (http.client.RemoteDisconnected, BrokenPipeError,
+          ConnectionResetError, ConnectionAbortedError)
+
+
 class HTTPTransport:
     """The wire path: REST + chunked watch streams. `binary=True` opts the
     client into the negotiated binary codec (machinery/codec.py — the
     `application/vnd.kubernetes.protobuf` seat every internal reference
-    client takes, protobuf.go); JSON stays the default and the fallback."""
+    client takes, protobuf.go); JSON stays the default and the fallback.
+
+    Connections are kept alive, one per calling thread: a scheduler's
+    13,600 Bindings are one connection of its loop's thread, not 13,600
+    sockets left in TIME_WAIT (a server that answers `Connection: close`
+    is dialled a request). A watch stream has a connection of its own
+    for its life. Every request and stream is counted (`WIRE_COUNTERS`,
+    `counters_reader`), and a request made while a `trace.Trace` is current
+    on its thread (a wave's Binding, an informer's first list) files itself
+    below the span that caused it as `http.request`, split into `codec`
+    (`encode`: the body to bytes; `decode`: the answer's bytes to an object)
+    and `wire` (from the first byte sent to the last byte read: the kernel,
+    the server's whole handling, the kernel again)."""
+
+    #: events a watch stream's consumer may leave untaken before its pump
+    #: waits for it
+    watch_buffer = 8192
 
     def __init__(self, base_url: str, timeout: float = 30.0,
                  token: str = "", binary: bool = False,
@@ -117,12 +153,50 @@ class HTTPTransport:
         self.token = token
         self.binary = binary
         self.retry = retry
+        split = urllib.parse.urlsplit(self.base_url)
+        self._dial = http.client.HTTPSConnection \
+            if split.scheme == "https" else http.client.HTTPConnection
+        self._netloc, self._prefix = split.netloc, split.path
+        self._local = threading.local()   # .conn: this thread's connection
+        self._mu = threading.Lock()
+        self._counts: Dict[str, float] = dict.fromkeys(WIRE_COUNTERS, 0)
+
+    # -- counters ----------------------------------------------------------- #
+
+    def _count(self, **deltas: float) -> None:
+        with self._mu:
+            for name, n in deltas.items():
+                self._counts[name] += n
+
+    def counters(self) -> Dict[str, float]:
+        """`WIRE_COUNTERS` since this transport was built."""
+        with self._mu:
+            return dict(self._counts)
+
+    def counters_reader(self) -> Callable[[], Dict[str, float]]:
+        """A reader with a baseline of its own: each call gives what was
+        counted since its previous call. A scheduler calls it at each
+        wave's end (`Client.store_counters`), so the wave's record says
+        what the wire carried while it ran."""
+        was = [self.counters()]
+
+        def read() -> Dict[str, float]:
+            now = self.counters()
+            out = {k: now[k] - was[0][k] for k in WIRE_COUNTERS}
+            out["watch_decode_s"] = round(out["watch_decode_s"], 6)
+            was[0] = now
+            return out
+
+        return read
+
+    # -- requests ----------------------------------------------------------- #
+
+    @staticmethod
+    def _with_query(path: str, query: Dict[str, str]) -> str:
+        return path + "?" + urllib.parse.urlencode(query) if query else path
 
     def _url(self, path: str, query: Dict[str, str]) -> str:
-        url = self.base_url + path
-        if query:
-            url += "?" + urllib.parse.urlencode(query)
-        return url
+        return self.base_url + self._with_query(path, query)
 
     def _decode_body(self, raw: bytes, content_type: str) -> Obj:
         from kubernetes_tpu.machinery import codec
@@ -145,44 +219,105 @@ class HTTPTransport:
                       body: Optional[Obj]) -> Obj:
         from kubernetes_tpu.machinery import codec
 
+        pc = time.perf_counter
+        t0 = pc()
         # the patch dialect travels as a Content-Type on the wire (the
         # gateway maps it back; apiserver patch.go patchTypes) — pop the
         # local-transport query key and translate
         query = dict(query)
         ptype = query.pop("__patchType", None)
-        req = urllib.request.Request(self._url(path, query), method=method)
+        headers: Dict[str, str] = {}
         data = None
         if self.token:
-            req.add_header("Authorization", f"Bearer {self.token}")
+            headers["Authorization"] = f"Bearer {self.token}"
         if self.binary:
-            req.add_header("Accept", codec.BINARY_MEDIA_TYPE)
+            headers["Accept"] = codec.BINARY_MEDIA_TYPE
         if body is not None:
             if self.binary and method != "PATCH":
                 data = codec.encode(body)
-                req.add_header("Content-Type", codec.BINARY_MEDIA_TYPE)
+                headers["Content-Type"] = codec.BINARY_MEDIA_TYPE
             else:
                 # PATCH always rides JSON: the dialect IS the Content-Type,
                 # and a binary body would make the server read the dialect
                 # as "merge" (patch bodies are partial docs/op lists — the
                 # typed binary codec has no frame for them anyway)
                 data = json.dumps(body).encode()
-                req.add_header("Content-Type", {
+                headers["Content-Type"] = {
                     "strategic": "application/strategic-merge-patch+json",
                     "json": "application/json-patch+json",
                     "merge": "application/merge-patch+json",
-                }.get(ptype, "application/json"))
+                }.get(ptype, "application/json")
+        t1 = pc()
+        code, ctype, raw = self._round_trip(
+            method, self._prefix + self._with_query(path, query), data,
+            headers)
+        t2 = pc()
         try:
-            with urllib.request.urlopen(req, data=data,
-                                        timeout=self.timeout) as r:
-                return self._decode_body(
-                    r.read(), r.headers.get("Content-Type", ""))
-        except urllib.error.HTTPError as e:
-            try:
-                status = self._decode_body(
-                    e.read(), e.headers.get("Content-Type", ""))
-            except Exception:  # noqa: BLE001
-                raise errors.StatusError(e.code, "Unknown", str(e))
-            raise errors.from_status(status)
+            obj = self._decode_body(raw, ctype)
+        except Exception as e:  # noqa: BLE001
+            if code < 400:
+                raise
+            raise errors.StatusError(code, "Unknown", str(e))
+        tr = trace.current()
+        if tr is not None:
+            t3 = pc()
+            tok = tr.begin("http.request")
+            inner = tr.begin("codec")
+            tr.child("encode", t1 - t0)
+            tr.child("decode", t3 - t2)
+            tr.end(inner, t1 - t0 + t3 - t2)
+            tr.child("wire", t2 - t1)
+            tr.end(tok, t3 - t0)
+        if code >= 400:
+            raise errors.from_status(obj)
+        return obj
+
+    def _round_trip(self, method: str, target: str, data: Optional[bytes],
+                    headers: Dict[str, str]) -> Tuple[int, str, bytes]:
+        """One request on this thread's connection, dialled if there is
+        none: (status code, content type, body). A kept-alive connection
+        that the server closed meanwhile is dialled again once."""
+        local = self._local
+        conn = getattr(local, "conn", None)
+        reused = conn is not None
+        opened = retried = 0
+        status, raw = None, b""
+        try:
+            while True:
+                if conn is None:
+                    conn = self._dial(self._netloc, timeout=self.timeout)
+                    opened += 1
+                try:
+                    try:
+                        conn.request(method, target, body=data,
+                                     headers=headers)
+                        r = conn.getresponse()
+                    except _STALE:
+                        if not reused:
+                            raise
+                        conn.close()
+                        conn, reused, retried = None, False, 1
+                        continue
+                    raw = r.read()
+                except BaseException:
+                    conn.close()
+                    local.conn = None
+                    raise
+                if r.will_close:
+                    conn.close()
+                    conn = None
+                local.conn = conn
+                status = r.status
+                return status, r.getheader("Content-Type") or "", raw
+        finally:   # an exception leaves `status` None: a transport error
+            self._count(
+                http_requests=1 + retried, http_retries=retried,
+                http_connections_opened=opened,
+                http_errors=1 if status is None or status >= 500 else 0,
+                http_bytes_out=(1 + retried) * len(data or b""),
+                http_bytes_in=len(raw))
+
+    # -- watch streams ------------------------------------------------------ #
 
     def stream_watch(self, path: str, query: Dict[str, str]) -> mwatch.Watch:
         q = dict(query)
@@ -197,17 +332,38 @@ class HTTPTransport:
         except (TypeError, ValueError):
             server_timeout = 3600.0
         sock_timeout = self.timeout + server_timeout
-        w = mwatch.Watch(capacity=8192)
+        w = mwatch.Watch(capacity=self.watch_buffer)
+        pc = time.perf_counter
+
+        def deliver(t0: float, kind: str, obj: Obj) -> bool:
+            """Hand one decoded event to the consumer; False once the
+            consumer has stopped the watch. A consumer that is behind
+            (its buffer full: an informer's handler waiting out a wave
+            under the server's lock) holds the PUMP back, and through the
+            socket the server, whose own buffer and deaf-watcher contract
+            (storage/store.py, 60 s) then decide as they do for a consumer
+            in its process: the pump never ends a stream for being late."""
+            ev = mwatch.Event(kind, obj)
+            sent = w.offer(ev)
+            self._count(watch_events_in=1, watch_decode_s=pc() - t0)
+            while not sent:
+                if w.stopped:
+                    return False
+                time.sleep(0.002)
+                sent = w.offer(ev)
+            return True
 
         def pump_json(r) -> None:
             for raw_line in r:
                 if w.stopped:
                     return
+                t0 = pc()
                 line = raw_line.strip()
                 if not line:
                     continue
                 ev = json.loads(line)
-                w.send(mwatch.Event(ev["type"], ev["object"]))
+                if not deliver(t0, ev["type"], ev["object"]):
+                    return
 
         def pump_binary(r) -> None:
             from kubernetes_tpu.machinery import codec
@@ -217,10 +373,13 @@ class HTTPTransport:
                 chunk = r.read1(65536)
                 if not chunk:
                     return
+                t0 = pc()
                 buf += chunk
                 events, buf = codec.decode_frames(buf)
                 for ev in events:
-                    w.send(mwatch.Event(ev["type"], ev["object"]))
+                    if not deliver(t0, ev["type"], ev["object"]):
+                        return
+                    t0 = pc()
 
         def pump() -> None:
             from kubernetes_tpu.machinery import codec
@@ -231,6 +390,7 @@ class HTTPTransport:
                     req.add_header("Authorization", f"Bearer {self.token}")
                 if self.binary:
                     req.add_header("Accept", codec.BINARY_MEDIA_TYPE)
+                self._count(http_requests=1, http_connections_opened=1)
                 with urllib.request.urlopen(req, timeout=sock_timeout) as r:
                     ctype = (r.headers.get("Content-Type") or "").split(";")[0]
                     if ctype == codec.BINARY_MEDIA_TYPE:
@@ -247,9 +407,23 @@ class HTTPTransport:
                 except Exception:  # noqa: BLE001
                     status = {"kind": "Status", "code": e.code,
                               "reason": "Unknown"}
-                w.send(mwatch.Event(mwatch.ERROR, status))
-            except Exception:  # noqa: BLE001 — stream teardown
-                pass
+                if e.code >= 500:
+                    self._count(http_errors=1)
+                w.terminate(mwatch.Event(mwatch.ERROR, status))
+            except Exception as e:  # noqa: BLE001 - the stream BROKE
+                # a connection refused or reset, a timeout, a frame that
+                # does not decode: not the server ending the stream. Unless
+                # the consumer stopped the watch itself (its own stop closes
+                # nothing, but a pump that dies after it is no fault), it is
+                # counted and the consumer gets a terminal ERROR, so that a
+                # reflector's relist-or-resume decision sees a broken
+                # stream as one (a 500: it resumes from its last
+                # resourceVersion, under its backoff ladder)
+                if not w.stopped:
+                    self._count(watch_streams_broken=1, http_errors=1)
+                    w.terminate(mwatch.Event(mwatch.ERROR, errors.StatusError(
+                        500, "InternalError",
+                        f"watch stream broke: {e!r}"[:300]).status()))
             finally:
                 w.stop()
 
@@ -441,9 +615,12 @@ class Client:
 
     def __init__(self, transport, store_counters=None):
         self.transport = transport
-        # where the store runs in the client's own process (`local`): a
-        # factory of readers of its watch-plane counters
-        # (`Storage.watch_plane_reader`); None over the wire
+        # a factory of readers of what lies between this client and the
+        # store, for a wave's record: where the store runs in the client's
+        # own process (`local`), its watch-plane counters
+        # (`Storage.watch_plane_reader`); over the wire, the wire's own
+        # (`HTTPTransport.counters_reader`: requests, connections, bytes,
+        # the watch pumps); None for a transport that counts nothing
         self.store_counters = store_counters
         self._cache: Dict[Tuple[str, str, str], ResourceClient] = {}
 
@@ -458,8 +635,9 @@ class Client:
         """`binary=True` negotiates the binary codec for every request and
         watch stream — the internal-client configuration (protobuf.go).
         `retry` opts into the 429/503 pushback budget (RetryPolicy)."""
-        return Client(HTTPTransport(base_url, token=token, binary=binary,
-                                    retry=retry))
+        transport = HTTPTransport(base_url, token=token, binary=binary,
+                                  retry=retry)
+        return Client(transport, store_counters=transport.counters_reader)
 
     def resource(self, group: str, version: str, resource: str,
                  namespaced: bool = True) -> ResourceClient:

@@ -27,10 +27,14 @@ reference's two-phase assume/bind split (scheduler.go:660-762) made durable:
                           replayed Binding write idempotent — exactly-once
                           holds even when the restart raced the watch stream.
 
-The ledger talks to the raw `Storage` tier (the analog of the scheduler
-writing its own coordination objects through etcd), NOT through the REST
-client: intents are scheduler-internal bookkeeping, not API objects, and the
-CAS create/delete pair is the whole protocol.
+`BindIntentLedger` talks to the raw `Storage` tier (a scheduler in the
+store's own process: the analog of writing its coordination objects through
+etcd); the CAS create/delete pair is the whole protocol. A scheduler in a
+process of its own has no `Storage`: `APIBindIntentLedger` keeps the same
+records, under the same keys, through its client, as the served resource
+`bindintents.ktpu.io` (apiserver/resources.py) whose namespace segment is
+the scheduler's name. One form's records are the other's: a scheduler that
+died under one wiring is reconciled by its successor under the other.
 """
 
 from __future__ import annotations
@@ -101,6 +105,17 @@ class BindIntentLedger:
     def _prefix(self) -> str:
         return f"{INTENT_PREFIX}{self.scheduler_name}/"
 
+    # the three record operations, on the store itself
+
+    def _put(self, name: str, obj: Dict) -> Dict:
+        return self.storage.create(INTENT_PREFIX + name, obj, "bindintents")
+
+    def _drop(self, intent: BindIntent) -> None:
+        self.storage.delete(intent.key, "bindintents", intent.name)
+
+    def _scan(self) -> List[Dict]:
+        return self.storage.list(self._prefix())[0]
+
     # ------------------------------------------------------------------ #
     # the write-ahead half (schedule_pending calls these around commits)
     # ------------------------------------------------------------------ #
@@ -120,7 +135,7 @@ class BindIntentLedger:
                      "holder": self.identity, "writtenAt": time.time(),
                      "bindings": dict(bindings)},
         }
-        out = self.storage.create(INTENT_PREFIX + name, obj, "bindintents")
+        out = self._put(name, obj)
         self.intents_written += 1
         from ..machinery import meta
 
@@ -133,7 +148,7 @@ class BindIntentLedger:
         (bound, rolled back, or requeued — all recoverable states). Not
         found is success: a reconciler may have retired it for us."""
         try:
-            self.storage.delete(intent.key, "bindintents", intent.name)
+            self._drop(intent)
         except errors.StatusError as e:
             if not errors.is_not_found(e):
                 raise
@@ -148,9 +163,8 @@ class BindIntentLedger:
     def unretired(self) -> List[BindIntent]:
         """All intents still on record for this scheduler name, oldest
         first — the replay set a restart/takeover must reconcile."""
-        items, _ = self.storage.list(self._prefix())
         out: List[BindIntent] = []
-        for obj in items:
+        for obj in self._scan():
             spec = obj.get("spec", {}) or {}
             out.append(BindIntent(
                 name=(f"{self.scheduler_name}/"
@@ -263,3 +277,32 @@ class BindIntentLedger:
             # the wave's skipPodSchedule check
         scheduler.queue.requeue_recovered(pod, attempts=1, now=now)
         report.released += 1
+
+
+class APIBindIntentLedger(BindIntentLedger):
+    """The ledger of a scheduler that reaches the store through the
+    apiserver alone (`Client.http`, or any client): the same records under
+    the same keys, each a `bindintents.ktpu.io` object in the "namespace"
+    of the scheduler's name. Create, delete and list are the client's; the
+    protocol, the recovery pass and the counters are `BindIntentLedger`'s."""
+
+    def __init__(self, client, scheduler_name: str = "default-scheduler",
+                 identity: str = "") -> None:
+        if "/" in scheduler_name:
+            raise ValueError(
+                f"scheduler name {scheduler_name!r}: a name with a `/` (the "
+                "fleet's `<tenant>/<scheduler>`) is two key segments; the "
+                "served resource has one")
+        super().__init__(None, scheduler_name=scheduler_name,
+                         identity=identity)
+        self.records = client.resource("ktpu.io", "v1", "bindintents")
+
+    def _put(self, name: str, obj: Dict) -> Dict:
+        return self.records.create(obj, namespace=self.scheduler_name)
+
+    def _drop(self, intent: BindIntent) -> None:
+        self.records.delete(intent.name.rsplit("/", 1)[-1],
+                            namespace=self.scheduler_name)
+
+    def _scan(self) -> List[Dict]:
+        return self.records.list(self.scheduler_name).get("items", [])

@@ -554,15 +554,19 @@ class TestColdRestartDrill:
     CAPS = {"capacity": {"cpu": "16", "memory": "64Gi", "pods": "110"},
             "allocatable": {"cpu": "16", "memory": "64Gi", "pods": "110"}}
 
-    def _mk_scheduler(self, client, storage):
+    def _mk_scheduler(self, client, storage, ledger="storage"):
         from kubernetes_tpu.api.v1 import node_from_v1, pod_from_v1
-        from kubernetes_tpu.sched.ledger import BindIntentLedger
+        from kubernetes_tpu.sched.ledger import (APIBindIntentLedger,
+                                                 BindIntentLedger)
         from kubernetes_tpu.sched.scheduler import Scheduler
         from kubernetes_tpu.sched.server import APIBinder
         from kubernetes_tpu.state.dims import Dims
 
         s = Scheduler(binder=APIBinder(client),
-                      ledger=BindIntentLedger(storage),
+                      # "api" (ISSUE 39): the same records, written through
+                      # the client as bindintents.ktpu.io
+                      ledger=BindIntentLedger(storage) if ledger == "storage"
+                      else APIBindIntentLedger(client),
                       base_dims=Dims(N=16, P=16, E=64), batch_size=8)
         for n in client.nodes.list()["items"]:
             s.on_node_add(node_from_v1(n))
@@ -582,7 +586,9 @@ class TestColdRestartDrill:
                 return None
         return lookup
 
-    def test_kill_apiserver_mid_commit_reboot_from_disk(self, tmp_path):
+    @pytest.mark.parametrize("ledger", ["storage", "api"])
+    def test_kill_apiserver_mid_commit_reboot_from_disk(self, tmp_path,
+                                                        ledger):
         from kubernetes_tpu.apiserver import APIServer
         from kubernetes_tpu.client import Client
         from kubernetes_tpu.client.informers import SharedInformer
@@ -609,7 +615,7 @@ class TestColdRestartDrill:
         assert informer.wait_for_sync(10)
         relists0 = informer.relists
 
-        s1 = self._mk_scheduler(client, api1.storage)
+        s1 = self._mk_scheduler(client, api1.storage, ledger)
         # the kill lands on the SECOND wal append after arming: the wave's
         # intent is durable, the first Binding just committed — the
         # apiserver dies mid-commit-loop with the response never returned
@@ -642,7 +648,7 @@ class TestColdRestartDrill:
 
         # the reborn apiserver still holds the bind intents: a successor
         # scheduler replays the ledger to 0 lost / 0 double-bound
-        s2 = self._mk_scheduler(client, api2.storage)
+        s2 = self._mk_scheduler(client, api2.storage, ledger)
         report = s2.recover(lookup=self._lookup(client))
         assert report.replayed_intents == 1
         s2.run_until_idle()

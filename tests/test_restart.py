@@ -92,9 +92,15 @@ class Cluster:
     not."""
 
     def __init__(self, n_nodes=N_NODES, n_pods=N_PODS, data_dir=None,
-                 durability="always"):
+                 durability="always", ledger="storage"):
         self.data_dir = data_dir
         self.durability = durability
+        # how a scheduler incarnation keeps its ledger (ISSUE 39):
+        # "storage" holds the store itself; "api" writes the same records
+        # as bindintents.ktpu.io through a client of an apiserver over the
+        # store; "api-http" does so across a real socket
+        self.ledger_form = ledger
+        self.gateway = None
         self.storage = self._open_storage()
         self.binder = DurableBinder()
         self.nodes = [mknode(f"n{i}") for i in range(n_nodes)]
@@ -114,7 +120,26 @@ class Cluster:
         return self.storage
 
     def close(self):
+        if self.gateway is not None:
+            self.gateway.stop()
         self.storage.close()
+
+    def make_ledger(self):
+        """A new incarnation's ledger over the shared store, in the form
+        this drill runs."""
+        if self.ledger_form == "storage":
+            return BindIntentLedger(self.storage)
+        from kubernetes_tpu.apiserver import APIServer, HTTPGateway
+        from kubernetes_tpu.client import Client
+        from kubernetes_tpu.sched.ledger import APIBindIntentLedger
+
+        api = APIServer(storage=self.storage)
+        if self.ledger_form == "api":
+            return APIBindIntentLedger(Client.local(api))
+        if self.gateway is not None:
+            self.gateway.stop()
+        self.gateway = HTTPGateway(api).start()
+        return APIBindIntentLedger(Client.http(self.gateway.url))
 
     def lookup(self, key):
         """Informer truth: the pod with its COMMITTED node (from the
@@ -136,8 +161,7 @@ class Cluster:
         persist."""
         kw.setdefault("base_dims", Dims(N=16, P=16, E=64))
         kw.setdefault("batch_size", 8)
-        s = Scheduler(binder=self.binder,
-                      ledger=BindIntentLedger(self.storage), **kw)
+        s = Scheduler(binder=self.binder, ledger=self.make_ledger(), **kw)
         for n in self.nodes:
             s.on_node_add(n)
         for key, pod in self.pods.items():
@@ -171,14 +195,21 @@ class Cluster:
 # --------------------------------------------------------------------- #
 
 
+# every drill below views the ledger through the STORAGE form
+# (`BindIntentLedger(cluster.storage).unretired()`), whatever form the
+# schedulers keep it in: one form's records are the other's
+LEDGER_FORMS = ["storage", "api"]
+
+
+@pytest.mark.parametrize("ledger", LEDGER_FORMS + ["api-http"])
 @pytest.mark.parametrize("site,binds_before_crash,intents_left", [
     ("pre_intent", 0, 0),   # decided, nothing durable yet
     ("post_intent", 0, 1),  # intent durable, no Binding committed
     ("post_bind", "all", 1),  # Bindings committed, intent unretired
 ])
 def test_kill_matrix_restart_reconciles_exactly_once(
-        site, binds_before_crash, intents_left):
-    cluster = Cluster()
+        site, binds_before_crash, intents_left, ledger):
+    cluster = Cluster(ledger=ledger)
     try:
         s1 = cluster.boot()
         faultline.install(f"proc.crash@{site}:1")
@@ -208,11 +239,12 @@ def test_kill_matrix_restart_reconciles_exactly_once(
         cluster.close()
 
 
-def test_crash_during_takeover_second_successor_finishes():
+@pytest.mark.parametrize("ledger", LEDGER_FORMS)
+def test_crash_during_takeover_second_successor_finishes(ledger):
     """The reconciler itself dies mid-replay (proc.crash@takeover): the
     intents it had not reached stay durable, and the NEXT successor's
     replay completes them — reconciliation is idempotent and restartable."""
-    cluster = Cluster()
+    cluster = Cluster(ledger=ledger)
     try:
         s1 = cluster.boot()
         faultline.install("proc.crash@post_intent:1")
@@ -325,12 +357,13 @@ def test_double_kill_apiserver_then_takeover_crash(tmp_path):
         cluster.close()
 
 
-def test_replay_releases_when_node_no_longer_fits():
+@pytest.mark.parametrize("ledger", LEDGER_FORMS)
+def test_replay_releases_when_node_no_longer_fits(ledger):
     """An intent whose chosen node was meanwhile filled (or deleted) must
     RELEASE the pod back to the active queue — never force the stale
     placement — and the next wave places it elsewhere (the third node the
     crashed leader never considered)."""
-    cluster = Cluster(n_nodes=3, n_pods=2)
+    cluster = Cluster(n_nodes=3, n_pods=2, ledger=ledger)
     try:
         s1 = cluster.boot()
         faultline.install("proc.crash@post_intent:1")
@@ -361,8 +394,9 @@ def test_replay_releases_when_node_no_longer_fits():
         cluster.close()
 
 
-def test_replay_drops_deleted_pods_and_skips_newer_tokens():
-    cluster = Cluster(n_nodes=2, n_pods=2)
+@pytest.mark.parametrize("ledger", LEDGER_FORMS)
+def test_replay_drops_deleted_pods_and_skips_newer_tokens(ledger):
+    cluster = Cluster(n_nodes=2, n_pods=2, ledger=ledger)
     try:
         s1 = cluster.boot()
         faultline.install("proc.crash@post_intent:1")
@@ -376,7 +410,7 @@ def test_replay_drops_deleted_pods_and_skips_newer_tokens():
         s2 = cluster.boot()
         # plant an intent from a NEWER leader (higher fencing token): a
         # stale reconciler must not touch it
-        newer = BindIntentLedger(cluster.storage)
+        newer = cluster.make_ledger()
         newer.write_intent(cycle=99, token=10**6,
                            bindings={"default/future": "n0"})
         report = s2.recover(lookup=cluster.lookup)
