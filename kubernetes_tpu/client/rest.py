@@ -64,11 +64,22 @@ class RetryPolicy:
         raise AssertionError("unreachable")  # loop always returns/raises
 
 
+#: Binding writes an `HTTPTransport` lets a wave keep in flight at once, each
+#: on a kept-alive connection of its own. Swept on the chip (PERF.md section
+#: 6, PR 40): the smallest width within 5 % of the best drain. Upstream's own
+#: bound is the client's QPS, not a count of threads.
+BIND_WINDOW = 32
+
+
 class LocalTransport:
     """Direct calls into an in-process APIServer (no serialization cost —
     the reference's integration suite does the same with its in-proc
     master). `retry` opts into the pushback budget — the in-proc
     max-inflight filter raises the same 429s the wire path serves."""
+
+    #: a request is a function call that holds the interpreter from end to
+    #: end: a second one in flight could only add a hand-over to it
+    writes_in_flight = 1
 
     def __init__(self, api, retry: Optional[RetryPolicy] = None):
         self.api = api
@@ -130,10 +141,11 @@ class HTTPTransport:
     client takes, protobuf.go); JSON stays the default and the fallback.
 
     Connections are kept alive, one per calling thread: a scheduler's
-    13,600 Bindings are one connection of its loop's thread, not 13,600
-    sockets left in TIME_WAIT (a server that answers `Connection: close`
-    is dialled a request). A watch stream has a connection of its own
-    for its life. Every request and stream is counted (`WIRE_COUNTERS`,
+    13,600 Bindings are the `writes_in_flight` connections of its binder's
+    threads (sched/server.py `BindWindow`), not 13,600 sockets left in
+    TIME_WAIT (a server that answers `Connection: close` is dialled a
+    request). A watch stream has a connection of its own for its life.
+    Every request and stream is counted (`WIRE_COUNTERS`,
     `counters_reader`), and a request made while a `trace.Trace` is current
     on its thread (a wave's Binding, an informer's first list) files itself
     below the span that caused it as `http.request`, split into `codec`
@@ -144,6 +156,10 @@ class HTTPTransport:
     #: events a watch stream's consumer may leave untaken before its pump
     #: waits for it
     watch_buffer = 8192
+    #: a request sleeps through its round trip with neither process
+    #: computing on it, so a writer of many (a wave's Bindings) may keep
+    #: this many in flight, a thread and its connection each
+    writes_in_flight = BIND_WINDOW
 
     def __init__(self, base_url: str, timeout: float = 30.0,
                  token: str = "", binary: bool = False,

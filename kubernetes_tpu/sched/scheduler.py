@@ -45,7 +45,14 @@ from .telemetry import _NULL_SPAN
 
 class Binder(Protocol):
     """The Binding write (scheduler.go:565 b.Client.CoreV1().Pods(...).Bind).
-    Returns True on success; False/raise → rollback via ForgetPod."""
+    Returns True on success; False/raise → rollback via ForgetPod.
+
+    A binder whose write sleeps through a round trip may also have
+    `window()`: the window a wave keeps several of its writes in flight
+    through (sched/server.py `BindWindow`: `width`, `submit`, `gather`,
+    `spans`), or None for one at a time; `bind` is then called from the
+    window's threads. Without the method a wave awaits each `bind` in its
+    turn, on its own thread."""
 
     def bind(self, pod: Pod, node_name: str) -> bool: ...
 
@@ -107,6 +114,12 @@ class CycleStats:
     # tenant-labelled DRF_CLAMPED counter, not from server internals
     drf_clamped: int = 0
     cycle_seconds: float = 0.0
+    # a wave whose Bindings went out through the binder's window
+    # (`_commit_windowed`): the seconds of its writes, each its own, and
+    # the wall seconds from the first hand-out to the last answer; their
+    # ratio is the mean number in flight. Onto the record in `_record`.
+    bind_request_s: float = 0.0
+    bind_window_s: float = 0.0
     assignments: Dict[str, str] = field(default_factory=dict)
     # pod keys that failed this wave (feeds FailedScheduling events)
     failed_keys: List[str] = field(default_factory=list)
@@ -984,13 +997,20 @@ class Scheduler:
                     cycle: int, stats: CycleStats,
                     span=_NULL_SPAN) -> List[Tuple[Pod, str, int]]:
         """Commit one wave's `(pod, node_name, attempts)` placements: intent
-        write → assume + Binding per pod → one batched span close → intent
-        retire. The ONE commit stage: a wave of this scheduler and a fleet
-        tenant's share of a tick (fleet/server.py) both come through here.
+        write → per pod assume, Binding, finish or roll back → one batched
+        span close → intent retire. The ONE commit stage: a wave of this
+        scheduler and a fleet tenant's share of a tick (fleet/server.py)
+        both come through here.
+
+        The Bindings go out in the wave's order, one awaited before the
+        next, unless the binder has a window for them (`Binder.window`: a
+        transport whose request sleeps through a round trip): then up to
+        its width are in flight at once (`_commit_windowed`), and every
+        one has been answered when this returns.
 
         The write-ahead intent makes the whole wave's placements durable
         in ONE CAS create before the first Binding write, and is retired
-        after the last. A crash at pre_intent leaves nothing (pods
+        after the last answer. A crash at pre_intent leaves nothing (pods
         re-deliver as pending), at post_intent leaves an intent recover()
         completes-or-releases, at post_bind leaves an intent recover()
         simply retires against informer truth (docs/RESILIENCE.md restart
@@ -1013,21 +1033,15 @@ class Scheduler:
         span.mark("intent-write")
         bound_keys: List[str] = []
         bind_times: List[float] = []
-        gov = self.governor
-        commit = self._commit
-        for ci, (pod, node_name, attempts) in enumerate(commits):
-            if gov is not None and not gov.commit_allowed():
-                # the breaker OPENED mid-wave (this wave's own commits
-                # tripped it): stop burning the commit path — the rest of
-                # the wave requeues promptly, no failure verdict. The
-                # intent stays valid (write-ahead covers the whole wave;
-                # unbound entries replay safely against informer truth)
-                # and is retired below as usual.
-                stats.requeued += self._abort(
-                    ((p, a) for p, _node, a in commits[ci:]), now)
-                break
-            commit(pod, node_name, attempts, now, cycle, stats,
-                   latency_keys=bound_keys, bind_times=bind_times)
+        open_window = getattr(self.binder, "window", None)
+        window = open_window() \
+            if open_window is not None and len(commits) > 1 else None
+        if window is None:
+            self._commit_in_turn(commits, now, cycle, stats, bound_keys,
+                                 bind_times)
+        else:
+            self._commit_windowed(window, commits, now, cycle, stats,
+                                  bound_keys, bind_times)
         # e2e watch→bind spans close in ONE batched call per wave (the
         # per-pod scalar path was most of the measured telemetry
         # overhead); the clock reading is the end of the commit loop —
@@ -1041,6 +1055,110 @@ class Scheduler:
         self._retire_intent(intent)
         span.mark("retire")
         return aborted
+
+    def _commit_in_turn(self, commits, now: float, cycle: int,
+                        stats: CycleStats, bound_keys: List[str],
+                        bind_times: List[float]) -> None:
+        """`commit_wave`'s loop, each Binding awaited before the next pod's
+        turn, all on this thread: a binder without a window."""
+        gov = self.governor
+        commit = self._commit
+        for ci, (pod, node_name, attempts) in enumerate(commits):
+            if gov is not None and not gov.commit_allowed():
+                # the breaker OPENED mid-wave (this wave's own commits
+                # tripped it): stop burning the commit path — the rest of
+                # the wave requeues promptly, no failure verdict. The
+                # intent stays valid (write-ahead covers the whole wave;
+                # unbound entries replay safely against informer truth)
+                # and is retired by the caller as usual.
+                stats.requeued += self._abort(
+                    ((p, a) for p, _node, a in commits[ci:]), now)
+                break
+            commit(pod, node_name, attempts, now, cycle, stats,
+                   latency_keys=bound_keys, bind_times=bind_times)
+
+    def _commit_windowed(self, window, commits, now: float, cycle: int,
+                         stats: CycleStats, bound_keys: List[str],
+                         bind_times: List[float]) -> None:
+        """`commit_wave`'s loop with up to `window.width` Binding writes in
+        flight (sched/server.py `BindWindow`). This thread still assumes
+        each pod, runs its Reserve, Permit, PreBind and Bind plugins, feeds
+        the governor and finishes or rolls back each pod, in the order the
+        answers come; only the binder's write is another thread's. It
+        hands out until the window is full, gathers an answer, settles
+        that pod, hands out the next; after the last hand-out it gathers
+        the rest, so nothing of the wave is outstanding when it returns.
+
+        The breaker is asked before each hand-out and fed at each gather
+        with that write's own seconds: once it opens nothing more goes out,
+        what is in flight (under `width`) is gathered and settled, the rest
+        requeues as in `_commit_in_turn`.
+
+        On the wave's Trace `bind-call` stays this thread's WALL seconds,
+        a call a Binding: a write's hand-out and the wait in which its
+        answer came. What the writes filed on their own threads is grafted
+        below it at the end (`http.request` and its `wire` / `codec`:
+        request-seconds, which pass their parent's wall seconds as soon as
+        two overlap). `stats.bind_request_s` over `stats.bind_window_s`
+        says how many were in flight on average."""
+        gov = self.governor
+        tr = trace.current()
+        traced = tr is not None
+        pc = time.perf_counter
+        width = window.width
+        held: Dict[int, Tuple] = {}   # handed out, unanswered, by position
+        request_s = 0.0
+        n, nxt = len(commits), 0
+
+        def settle(ok, seconds, call_s, answered, state, pod, node_name,
+                   attempts) -> None:
+            if traced:
+                tr.child("bind-call", call_s)
+            self._settle(ok, seconds, state, pod, node_name, attempts, now,
+                         cycle, stats, bound_keys, bind_times)
+            if traced:
+                tr.child("finish", pc() - answered)
+
+        t_first = pc()
+        while nxt < n or held:
+            while nxt < n and len(held) < width:
+                if gov is not None and not gov.commit_allowed():
+                    # as in `_commit_in_turn`; those in flight are gathered
+                    # below
+                    stats.requeued += self._abort(
+                        ((p, a) for p, _node, a in commits[nxt:]), now)
+                    n = nxt
+                    break
+                pod, node_name, attempts = commits[nxt]
+                ta0 = pc()
+                go, state = self._reserve(pod, node_name, attempts, now,
+                                          cycle, stats)
+                tb0 = pc()
+                if traced:
+                    tr.child("assume", tb0 - ta0)
+                if go:
+                    ok = self._bind_hooks(state, pod, node_name, None)
+                    if ok is None:
+                        window.submit(nxt, pod, node_name, traced)
+                        held[nxt] = (state, pod, node_name, attempts,
+                                     pc() - tb0)
+                    else:   # a plugin bound it, or refused: no write
+                        tb1 = pc()
+                        settle(ok, tb1 - tb0, tb1 - tb0, tb1, state, pod,
+                               node_name, attempts)
+                nxt += 1
+            if held:
+                tg0 = pc()
+                tag, ok, seconds = window.gather()
+                tb1 = pc()
+                *whose, handing = held.pop(tag)
+                request_s += seconds
+                settle(ok, seconds, handing + tb1 - tg0, tb1, *whose)
+        stats.bind_request_s += request_s
+        stats.bind_window_s += pc() - t_first
+        if traced:
+            for children in window.spans():
+                tr.graft("bind-call", children)
 
     def _account_gangs(self, wave: Wave,
                        failures: List[Tuple[Pod, int]]) -> None:
@@ -1154,6 +1272,9 @@ class Scheduler:
             self.micro_waves += 1
             MICRO_WAVES.inc(scheduler=self.scheduler_name)
         extra = {"snapshot_mode": wave.snap_mode, **wave.extra}
+        if stats.bind_window_s:
+            extra["bind_request_s"] = round(stats.bind_request_s, 6)
+            extra["bind_window_s"] = round(stats.bind_window_s, 6)
         if wave.span.enabled:
             extra["minor_faults"] = _minor_faults() - wave.minor_faults0
             if self.watch_plane is not None:
@@ -1452,53 +1573,17 @@ class Scheduler:
         latency_keys: Optional[List[str]] = None,
         bind_times: Optional[List[float]] = None,
     ) -> None:
-        fw = self.framework
-        state = None
+        """One pod through the whole sequence on this thread, its Binding
+        awaited: `_reserve` → `_run_bind` → `_settle`."""
         # the inside of a Binding, as children of the phase that called
         # (`bind-commit/assume`, `/bind-call`, `/finish`): one clock read
         # at each seam, aggregated per wave on the wave's Trace
         tr = trace.current()
         ta0 = time.perf_counter()
-        self.cache.assume_pod(pod, node_name)
-        self.queue.delete_nominated(pod.key)
-
-        def rollback(as_bind_error: bool) -> None:
-            # scheduler.go:717,732 — Unreserve + ForgetPod + requeue
-            if fw is not None and state is not None:
-                fw.run_unreserve_plugins(state, pod, node_name)
-            self.cache.forget_pod(pod.key)
-            if as_bind_error:
-                stats.bind_errors += 1
-            else:
-                stats.unschedulable += 1
-            stats.failed_keys.append(pod.key)
-            self.queue.add_unschedulable(pod, attempts, now, cycle=cycle)
-
-        if fw is not None:
-            from ..framework.interface import Code, CycleState
-
-            state = CycleState()
-            st = fw.run_reserve_plugins(state, pod, node_name)  # scheduler.go:669
-            if st is not None and not st.is_success:
-                rollback(as_bind_error=False)
-                return
-            # Pre-register the waiting metadata BEFORE the permit plugins run:
-            # run_permit_plugins publishes a WAITing pod in the framework's
-            # cross-thread waiting map, and a permit controller may allow +
-            # complete_waiting() in that window — the meta must already be
-            # there to consume. Keep the ORIGINAL (unstamped) pod for
-            # requeue-on-failure — the cached copy carries node_name and
-            # would pin retries to this node. dict.pop is the atomic
-            # exactly-one-consumer handoff.
-            self._waiting_meta[pod.key] = (attempts, state, node_name,
-                                           pod, binder_ext)
-            st = fw.run_permit_plugins(state, pod, node_name)   # scheduler.go:707
-            if st.code == Code.WAIT:
-                return  # parked (or already completed by a racing allow)
-            self._waiting_meta.pop(pod.key, None)
-            if not st.is_success:
-                rollback(as_bind_error=False)
-                return
+        go, state = self._reserve(pod, node_name, attempts, now, cycle,
+                                  stats, binder_ext)
+        if not go:
+            return
         tb0 = time.perf_counter()
         if tr is not None:
             tr.child("assume", tb0 - ta0)
@@ -1507,47 +1592,110 @@ class Scheduler:
         tb1 = time.perf_counter()
         if tr is not None:
             tr.end(tok, tb1 - tb0)
+        self._settle(ok, tb1 - tb0, state, pod, node_name, attempts, now,
+                     cycle, stats, latency_keys, bind_times)
+        if tr is not None:
+            tr.child("finish", time.perf_counter() - tb1)
+
+    def _reserve(self, pod: Pod, node_name: str, attempts: int, now: float,
+                 cycle: int, stats: CycleStats,
+                 binder_ext: Optional["object"] = None) -> Tuple[bool, Any]:
+        """assume → Reserve → Permit (scheduler.go:660-707). `(True, the
+        pod's CycleState or None)` when its Binding is next; `(False, _)`
+        when a plugin refused it (rolled back) or Permit parked it."""
+        fw = self.framework
+        self.cache.assume_pod(pod, node_name)
+        self.queue.delete_nominated(pod.key)
+        if fw is None:
+            return True, None
+        from ..framework.interface import Code, CycleState
+
+        state = CycleState()
+        st = fw.run_reserve_plugins(state, pod, node_name)  # scheduler.go:669
+        if st is not None and not st.is_success:
+            self._roll_back(state, pod, node_name, attempts, now, cycle,
+                            stats, as_bind_error=False)
+            return False, state
+        # Pre-register the waiting metadata BEFORE the permit plugins run:
+        # run_permit_plugins publishes a WAITing pod in the framework's
+        # cross-thread waiting map, and a permit controller may allow +
+        # complete_waiting() in that window — the meta must already be
+        # there to consume. Keep the ORIGINAL (unstamped) pod for
+        # requeue-on-failure — the cached copy carries node_name and
+        # would pin retries to this node. dict.pop is the atomic
+        # exactly-one-consumer handoff.
+        self._waiting_meta[pod.key] = (attempts, state, node_name,
+                                       pod, binder_ext)
+        st = fw.run_permit_plugins(state, pod, node_name)   # scheduler.go:707
+        if st.code == Code.WAIT:
+            return False, state  # parked (or completed by a racing allow)
+        self._waiting_meta.pop(pod.key, None)
+        if not st.is_success:
+            self._roll_back(state, pod, node_name, attempts, now, cycle,
+                            stats, as_bind_error=False)
+            return False, state
+        return True, state
+
+    def _roll_back(self, state, pod: Pod, node_name: str, attempts: int,
+                   now: float, cycle: int, stats: CycleStats,
+                   as_bind_error: bool) -> None:
+        # scheduler.go:717,732 — Unreserve + ForgetPod + requeue
+        if self.framework is not None and state is not None:
+            self.framework.run_unreserve_plugins(state, pod, node_name)
+        self.cache.forget_pod(pod.key)
+        if as_bind_error:
+            stats.bind_errors += 1
+        else:
+            stats.unschedulable += 1
+        stats.failed_keys.append(pod.key)
+        self.queue.add_unschedulable(pod, attempts, now, cycle=cycle)
+
+    def _settle(self, ok: bool, seconds: float, state, pod: Pod,
+                node_name: str, attempts: int, now: float, cycle: int,
+                stats: CycleStats, latency_keys: Optional[List[str]],
+                bind_times: Optional[List[float]]) -> None:
+        """A Binding's answer, on the thread that commits the wave: the
+        governor's note, then finish + PostBind, or the rollback."""
         if self.governor is not None:
             # commit-path breaker feed: outcome + wall latency of the
             # Binding write (wall time, not the injected clock — the SLO
             # is about real apiserver round-trips)
-            self.governor.note_commit(ok, tb1 - tb0)
-
-        if ok:
-            # scheduler_binding_duration_seconds: one sample per Binding
-            # written, fed per wave where the caller batches
-            if bind_times is not None:
-                bind_times.append(tb1 - tb0)
-            else:
-                BINDING_DURATION.observe(tb1 - tb0)
-            self.cache.finish_binding(pod.key, now)
-            # e2e watch→bind: close the pod's first-seen span (stamped at
-            # queue admission) in the scheduler's clock domain — at the
-            # clock's CURRENT reading, not the wave-entry `now`: the
-            # binding wave's own snapshot/dispatch/commit time is part of
-            # the span being claimed (under a per-tick deterministic
-            # clock the two readings coincide, so virtual latencies are
-            # unchanged). Wave callers pass `latency_keys` to close the
-            # whole wave's spans in one batched call instead (the per-pod
-            # scalar path was most of the measured telemetry overhead).
-            if latency_keys is not None:
-                latency_keys.append(pod.key)
-            else:
-                self.telemetry.record_bound(pod.key, self.clock())
-            stats.scheduled += 1
-            stats.assignments[pod.key] = node_name
-            if fw is not None and state is not None:
-                fw.run_post_bind_plugins(state, pod, node_name)
+            self.governor.note_commit(ok, seconds)
+        if not ok:
+            self._roll_back(state, pod, node_name, attempts, now, cycle,
+                            stats, as_bind_error=True)
+            return
+        # scheduler_binding_duration_seconds: one sample per Binding
+        # written, fed per wave where the caller batches
+        if bind_times is not None:
+            bind_times.append(seconds)
         else:
-            rollback(as_bind_error=True)
-        if tr is not None:
-            tr.child("finish", time.perf_counter() - tb1)
+            BINDING_DURATION.observe(seconds)
+        self.cache.finish_binding(pod.key, now)
+        # e2e watch→bind: close the pod's first-seen span (stamped at
+        # queue admission) in the scheduler's clock domain — at the
+        # clock's CURRENT reading, not the wave-entry `now`: the
+        # binding wave's own snapshot/dispatch/commit time is part of
+        # the span being claimed (under a per-tick deterministic
+        # clock the two readings coincide, so virtual latencies are
+        # unchanged). Wave callers pass `latency_keys` to close the
+        # whole wave's spans in one batched call instead (the per-pod
+        # scalar path was most of the measured telemetry overhead).
+        if latency_keys is not None:
+            latency_keys.append(pod.key)
+        else:
+            self.telemetry.record_bound(pod.key, self.clock())
+        stats.scheduled += 1
+        stats.assignments[pod.key] = node_name
+        if self.framework is not None and state is not None:
+            self.framework.run_post_bind_plugins(state, pod, node_name)
 
-    def _run_bind(self, state, pod: Pod, node_name: str,
-                  binder_ext: Optional["object"]) -> bool:
-        """The shared PreBind → Bind tail of the commit sequence
-        (scheduler.go:727-741). Everything — including raising plugins — is
-        contained here so both callers roll back identically on failure."""
+    def _bind_hooks(self, state, pod: Pod, node_name: str,
+                    binder_ext: Optional["object"]) -> Optional[bool]:
+        """PreBind and what may bind in the binder's place, a Bind plugin
+        or a binder extender (scheduler.go:727-741): the Binding's outcome
+        where one of them settled it, None where the binder's write is
+        still to make. A raising plugin is a refusal."""
         fw = self.framework
         try:
             if fw is not None and state is not None:
@@ -1562,6 +1710,19 @@ class Scheduler:
             if binder_ext is not None:
                 binder_ext.bind(pod, node_name)
                 return True
+        except Exception:
+            return False
+        return None
+
+    def _run_bind(self, state, pod: Pod, node_name: str,
+                  binder_ext: Optional["object"]) -> bool:
+        """The shared PreBind → Bind tail of the commit sequence
+        (scheduler.go:727-741). Everything — including raising plugins — is
+        contained here so both callers roll back identically on failure."""
+        ok = self._bind_hooks(state, pod, node_name, binder_ext)
+        if ok is not None:
+            return ok
+        try:
             return self.binder.bind(pod, node_name)
         except Exception:
             return False

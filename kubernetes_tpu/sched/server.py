@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import queue
 import threading
 import time
 from typing import Any, Dict, Optional
@@ -97,6 +98,92 @@ class _HandlerLock:
         self.tel.note_handler(self.t1 - self.t0, held)
 
 
+class BindWindow:
+    """A binder's writes on threads of their own, `width` of them: a wave
+    hands each Binding to one (`submit`), keeps at most `width` outstanding
+    and takes the answers as they come (`gather`); `Scheduler.commit_wave`
+    is the one caller, from the one thread that commits. A write is the
+    binder's own `bind`, whole: its fence stamp, its retry budget, its
+    one request through the client's transport, on the connection that
+    transport keeps for the calling thread, so a thread here is a
+    kept-alive connection. The threads start at the first `submit`, each
+    takes room in its own arena (utils/platform.py `steady_heap`: it
+    allocates through every wave from then on), and they live until
+    `close`.
+
+    A thread of this window has no `trace.current()` of the wave's, so a
+    write of a traced wave files what lies below it (`http.request`) on a
+    Trace of its thread's, and the wave takes them all (`spans`) once its
+    last answer is in, to graft below its own `bind-call`."""
+
+    def __init__(self, bind, width: int):
+        self.width = width
+        self._bind = bind
+        self._jobs: queue.SimpleQueue = queue.SimpleQueue()
+        self._answers: queue.SimpleQueue = queue.SimpleQueue()
+        self._traces: list = [None] * width
+        self._threads: list = []
+
+    def submit(self, tag, pod: Pod, node_name: str, traced: bool) -> None:
+        """Hand one write out; its answer comes back under `tag`."""
+        if not self._threads:
+            self._threads = [threading.Thread(
+                target=self._work, args=(i,), name=f"bind-window-{i}",
+                daemon=True) for i in range(self.width)]
+            for t in self._threads:
+                t.start()
+        self._jobs.put((tag, pod, node_name, traced))
+
+    def gather(self):
+        """Wait for the next answer: `(tag, ok, seconds)`, the seconds the
+        write's own, from its thread taking it to `bind` returning."""
+        tag, ok, seconds = self._answers.get()
+        if isinstance(ok, BaseException):
+            raise ok   # nothing an `except Exception` takes: the wave's
+        return tag, ok, seconds
+
+    def spans(self) -> list:
+        """The `children()` of each thread's Trace since the last call, for
+        the caller to graft; only with nothing outstanding."""
+        out = [tr.children() for tr in self._traces if tr is not None]
+        self._traces = [None] * self.width
+        return out
+
+    def close(self) -> None:
+        threads, self._threads = self._threads, []
+        for _ in threads:
+            self._jobs.put(None)
+        for t in threads:
+            t.join(timeout=2)
+
+    def _work(self, i: int) -> None:
+        from kubernetes_tpu.utils.platform import steady_heap
+
+        steady_heap()
+        pc = time.perf_counter
+        while True:
+            job = self._jobs.get()
+            if job is None:
+                return
+            tag, pod, node_name, traced = job
+            token = None
+            if traced:
+                if self._traces[i] is None:
+                    self._traces[i] = trace.Trace("bind-window", clock=pc)
+                token = trace.activate(self._traces[i])
+            t0 = pc()
+            try:
+                ok = bool(self._bind(pod, node_name))
+            except Exception:  # noqa: BLE001 - a raising binder is a refusal
+                ok = False
+            except BaseException as e:  # noqa: BLE001 - raised at the gather
+                ok = e
+            seconds = pc() - t0
+            if token is not None:
+                trace.deactivate(token)
+            self._answers.put((tag, ok, seconds))
+
+
 class APIBinder:
     """Binder over POST pods/{name}/binding (scheduler.go:565). When volume
     binding is wired, BindPodVolumes runs first (scheduler.go:660,517) and a
@@ -116,7 +203,13 @@ class APIBinder:
     retry can never double-apply. Everything else (fenced 409,
     already-assigned, NotFound) still fails fast — persistent pushback
     past the budget is the commit breaker's job (sched/overload.py),
-    not the binder's."""
+    not the binder's.
+
+    How many of a wave's Bindings may be in flight at once is the
+    transport's to say (client/rest.py `writes_in_flight`): `window()` is
+    None where it says one (`LocalTransport`: the wave calls `bind` in its
+    turn), else a `BindWindow` of that width over `bind`, which any number
+    of threads may call at once."""
 
     def __init__(self, client, volume_binder=None, pod_lookup=None,
                  fence_source=None,
@@ -136,13 +229,32 @@ class APIBinder:
         self.stale_rejects = 0  # fenced-off binds (the mechanism working)
         self.pushback_retries = 0  # 429/503 absorbed by the budget
         self.pushback_failures = 0  # budget/deadline exhausted
+        self._counts_mu = threading.Lock()   # the three counts above
+        self._window: Optional[BindWindow] = None
         self.retry = RetryPolicy(attempts=retry_budget, base_s=retry_base_s,
                                  cap_s=retry_cap_s,
                                  deadline_s=bind_deadline_s,
                                  on_retry=self._note_pushback_retry)
 
     def _note_pushback_retry(self) -> None:
-        self.pushback_retries += 1
+        with self._counts_mu:
+            self.pushback_retries += 1
+
+    def window(self) -> Optional[BindWindow]:
+        """The window a wave's Bindings go out through, or None where the
+        transport takes one write at a time."""
+        width = getattr(getattr(self.client, "transport", None),
+                        "writes_in_flight", 1)
+        if width <= 1:
+            return None
+        if self._window is None:
+            self._window = BindWindow(self.bind, width)
+        return self._window
+
+    def close(self) -> None:
+        """End the window's threads (the scheduler's stop)."""
+        if self._window is not None:
+            self._window.close()
 
     def bind(self, pod: Pod, node_name: str) -> bool:
         from kubernetes_tpu.api.types import (FENCED_BIND_MARKER,
@@ -165,11 +277,12 @@ class APIBinder:
                 uid=pod.uid, annotations=annotations))
             return True
         except errors.StatusError as e:
-            if annotations is not None and errors.is_conflict(e) \
-                    and FENCED_BIND_MARKER in str(e):
-                self.stale_rejects += 1
-            elif e.code in (429, 503):
-                self.pushback_failures += 1
+            with self._counts_mu:
+                if annotations is not None and errors.is_conflict(e) \
+                        and FENCED_BIND_MARKER in str(e):
+                    self.stale_rejects += 1
+                elif e.code in (429, 503):
+                    self.pushback_failures += 1
             return False
 
 
@@ -675,6 +788,7 @@ class SchedulerServer:
                 inf.stop()
         for t in self._threads:
             t.join(timeout=2)
+        self._close_binder()
         self.recorder.stop(timeout=10)
         if self.telemetry_gateway is not None:
             self.telemetry_gateway.stop()
@@ -698,7 +812,15 @@ class SchedulerServer:
                 inf.stop()
         for t in self._threads:
             t.join(timeout=2)
+        self._close_binder()
         self.recorder.abandon()
+
+    def _close_binder(self) -> None:
+        """The binder's threads live as long as the scheduler (APIBinder's
+        window of Binding writes); the loop that hands them work is gone."""
+        close = getattr(self.scheduler.binder, "close", None)
+        if close is not None:
+            close()
 
     def _on_stopped_leading(self) -> None:
         """Any leadership loss re-arms the reconciliation pass HERE, on the

@@ -412,6 +412,226 @@ def test_another_schedulers_intents_are_not_this_ones(gateway):
 
 
 # --------------------------------------------------------------------- #
+# a wave's Bindings, a window of them in flight (ISSUE 40)
+# --------------------------------------------------------------------- #
+
+
+class _Logged(HTTPTransport):
+    """A scheduler's transport as the benchmark's wiring subclasses it:
+    `request()` overridden, here to keep (method, path, the instant the
+    answer was in hand) of every request that passed through it."""
+
+    def __init__(self, url: str):
+        super().__init__(url)
+        self.log: list = []
+
+    def request(self, method, path, query, body):
+        try:
+            return super().request(method, path, query, body)
+        finally:
+            self.log.append((method, path, time.perf_counter()))
+
+    def bindings(self) -> list:
+        return [(path.split("/")[-2], at) for method, path, at in self.log
+                if method == "POST" and path.endswith("/binding")]
+
+
+def _populate(client, pods: int, nodes: int = 8) -> None:
+    for i in range(nodes):
+        client.nodes.create({
+            "metadata": {"name": f"n{i}"},
+            "status": {"allocatable": {"cpu": "64", "memory": "256Gi",
+                                       "pods": "110"}}})
+    for i in range(pods):
+        client.pods.create({
+            "metadata": {"name": f"p{i}", "namespace": "default"},
+            "spec": {"containers": [{
+                "name": "c", "image": "i",
+                "resources": {"requests": {"cpu": "100m"}}}]}})
+
+
+def _scheduler_over(client):
+    """A Scheduler that knows what `client` lists, its binder and its
+    bind-intent ledger through `client` too, as the `http` wiring builds
+    them; the pods queued in the order of their names' numbers."""
+    from kubernetes_tpu.api.v1 import node_from_v1, pod_from_v1
+    from kubernetes_tpu.sched.ledger import APIBindIntentLedger
+    from kubernetes_tpu.sched.scheduler import Scheduler
+    from kubernetes_tpu.sched.server import APIBinder
+
+    sched = Scheduler(binder=APIBinder(client), batch_size=512)
+    sched.prewarmer.enabled = False
+    sched.ledger = APIBindIntentLedger(client, identity="t")
+    for obj in client.nodes.list()["items"]:
+        sched.on_node_add(node_from_v1(obj))
+    for obj in sorted(client.pods.list("default")["items"],
+                      key=lambda o: int(o["metadata"]["name"][1:])):
+        sched.on_pod_add(pod_from_v1(obj))
+    return sched
+
+
+def _bound(client) -> dict:
+    return {p["metadata"]["name"]: p["spec"]["nodeName"]
+            for p in client.pods.list("default")["items"]
+            if p["spec"].get("nodeName")}
+
+
+@pytest.fixture(scope="module")
+def windowed_wave():
+    """One traced wave of 300 pods over a served apiserver, through a
+    transport whose `request()` a subclass overrides; what it left."""
+    from kubernetes_tpu.client.rest import BIND_WINDOW
+
+    api = APIServer()
+    gw = HTTPGateway(api).start()
+    try:
+        transport = _Logged(gw.url)
+        client = Client(transport, store_counters=transport.counters_reader)
+        _populate(Client.http(gw.url), pods=300)
+        sched = _scheduler_over(client)
+        assert sched.binder.window().width == BIND_WINDOW > 1
+        before = transport.counters()
+        threads = {t.name for t in threading.enumerate()}
+        stats = sched.schedule_pending()
+        yield {
+            "stats": stats, "log": list(transport.log),
+            "bindings": transport.bindings(),
+            "opened": transport.counters()["http_connections_opened"]
+            - before["http_connections_opened"],
+            "threads": {t.name for t in threading.enumerate()
+                        if not t.name.endswith("(process_request_thread)")}
+            - threads,   # (the served apiserver's own: a connection each)
+            "unretired": sched.ledger.unretired(),
+            "record": sched.telemetry.recorder.snapshot("test")[
+                "records"][-1],
+            "bound": _bound(Client.http(gw.url)), "width": BIND_WINDOW}
+        sched.binder.close()
+        assert not [t for t in threading.enumerate()
+                    if t.name.startswith("bind-window")]
+    finally:
+        gw.stop()
+        api.close()
+
+
+def test_a_wave_over_the_wire_binds_each_pod_exactly_once(windowed_wave):
+    w = windowed_wave
+    assert w["stats"].scheduled == 300 and w["stats"].bind_errors == 0
+    # one POST a pod, each through the subclass's `request()`, and what
+    # the apiserver lists is what the wave decided
+    names = [name for name, _at in w["bindings"]]
+    assert sorted(names) == sorted(f"p{i}" for i in range(300))
+    assert w["bound"] == {k.split("/")[1]: v
+                          for k, v in w["stats"].assignments.items()}
+
+
+def test_the_intent_is_retired_after_the_last_answer(windowed_wave):
+    w = windowed_wave
+    intents = [(m, at) for m, path, at in w["log"] if "bindintents" in path]
+    assert [m for m, _ in intents] == ["POST", "DELETE"]
+    answers = [at for _name, at in w["bindings"]]
+    # written before the first Binding went out, retired after the last
+    # was answered, and nothing of it left
+    assert intents[0][1] < min(answers) and max(answers) < intents[1][1]
+    assert w["unretired"] == []
+
+
+def test_the_window_is_its_threads_connections_and_no_more(windowed_wave):
+    w = windowed_wave
+    assert w["threads"] == {f"bind-window-{i}" for i in range(w["width"])}
+    # a connection a binder thread, and the wave's own thread's (the
+    # intent's write and retire)
+    assert w["opened"] <= w["width"] + 1
+
+
+def test_a_traced_wave_over_the_wire_keeps_the_requests_spans(
+        windowed_wave):
+    rec = windowed_wave["record"]
+    ch = rec["children"]
+    base = "bind-commit/bind-call"
+    for path in (base, base + "/http.request", base + "/http.request/wire",
+                 base + "/http.request/codec", "bind-commit/assume",
+                 "bind-commit/finish"):
+        assert ch[path][0] == 300, path
+    # `bind-call` is the committing thread's wall seconds, inside its
+    # phase; the requests' own seconds overlap and pass it
+    phase = dict(rec["phases"])["bind-commit"]
+    assert ch[base][1] <= phase
+    assert ch[base + "/http.request/wire"][1] \
+        <= ch[base + "/http.request"][1]
+    # the two numbers `bind_in_flight_mean` is the ratio of: the requests'
+    # seconds are the workers' own, a little over the spans below them
+    assert rec["bind_window_s"] <= phase
+    assert rec["bind_request_s"] >= ch[base + "/http.request"][1]
+    assert 1.0 < rec["bind_request_s"] / rec["bind_window_s"] \
+        <= windowed_wave["width"]
+
+
+def test_a_binding_refused_mid_wave_rolls_back_that_pod_alone(gateway):
+    other = Client.http(gateway.url)
+    _populate(other, pods=40)
+    transport = _Logged(gateway.url)
+    sched = _scheduler_over(Client(transport))
+    try:
+        # after the scheduler listed them: p3 is bound by someone else
+        # (409 already assigned), p5 is deleted (404)
+        other.pods.bind("p3", "n7")
+        other.pods.delete("p5")
+        stats = sched.schedule_pending()
+    finally:
+        sched.binder.close()
+    assert stats.scheduled == 38 and stats.bind_errors == 2
+    assert sorted(stats.failed_keys) == ["default/p3", "default/p5"]
+    assert len(transport.bindings()) == 40   # one write each, none again
+    for key in stats.failed_keys:   # forgotten, and back in the queue
+        assert sched.cache.get_pod(key) is None
+        assert sched.queue.get_pod(key) is not None
+    bound = _bound(other)
+    assert bound.pop("p3") == "n7"
+    assert bound == {k.split("/")[1]: v
+                     for k, v in stats.assignments.items()}
+    assert sched.ledger.unretired() == []
+
+
+def test_through_the_local_transport_no_thread_and_the_waves_order():
+    """`LocalTransport` says one write at a time: the binder has no window,
+    the commit starts no thread and the Bindings reach the apiserver in
+    the order the wave settled them."""
+    from kubernetes_tpu.client.rest import LocalTransport
+
+    class Ordered(LocalTransport):
+        order: list = []
+
+        def request(self, method, path, query, body):
+            if method == "POST" and path.endswith("/binding"):
+                self.order.append((path.split("/")[-2],
+                                   threading.current_thread().name))
+            return super().request(method, path, query, body)
+
+    api = APIServer()
+    try:
+        _populate(Client.local(api), pods=60)
+        sched = _scheduler_over(Client(Ordered(api)))
+        assert sched.binder.window() is None
+        threads = set(threading.enumerate())
+        stats = sched.schedule_pending()
+        # (the dispatch has a watchdog's worker; the commit has no one)
+        assert not [t.name for t in set(threading.enumerate()) - threads
+                    if t.name.startswith("bind-window")]
+        assert stats.scheduled == 60 and not stats.bind_request_s
+        assert [name for name, _ in Ordered.order] == [
+            k.split("/")[1] for k in stats.assignments]
+        assert {thread for _, thread in Ordered.order} == {
+            threading.current_thread().name}
+        by_revision = sorted(
+            Client.local(api).pods.list("default")["items"],
+            key=lambda p: int(p["metadata"]["resourceVersion"]))
+        assert [p["metadata"]["name"] for p in by_revision] == [
+            name for name, _ in Ordered.order]
+    finally:
+        api.close()
+
+
+# --------------------------------------------------------------------- #
 # the apiserver alone, as a process
 # --------------------------------------------------------------------- #
 
@@ -696,9 +916,12 @@ def test_the_http_wirings_run_is_held_to_the_wire_and_reads_zero(
         assert checks[name] == 0, name
     totals = info["wire_totals"]
     assert totals["http_errors"] == totals["http_retries"] == 0
-    # connections are kept alive: a handful for two thousand requests
+    # connections are kept alive: a handful for two thousand requests, and
+    # one a binder thread of each of the run's two schedulers (ISSUE 40)
+    from kubernetes_tpu.client.rest import BIND_WINDOW
+
     assert totals["http_requests"] > 1600
-    assert totals["http_connections_opened"] < 40
+    assert totals["http_connections_opened"] < 40 + 2 * BIND_WINDOW
 
 
 def test_the_http_cells_traced_line_carries_every_metric_listed_for_it(

@@ -302,6 +302,52 @@ class TestSchedulerIntegration:
         # 3 bind-error verdicts parked, 7 promptly retryable — all 10 live
         assert sum(d.values()) == 10              # nothing lost
 
+    def test_mid_wave_breaker_cut_with_a_window_of_writes_in_flight(self):
+        """A binder with a window (ISSUE 40): the breaker is asked before
+        each hand-out and fed at each gather, so when this wave's failures
+        open it at most `width` further writes were made, those are
+        settled, and the rest requeues with no verdict."""
+        import time
+
+        from kubernetes_tpu.sched.server import BindWindow
+
+        width = 4
+
+        class FailingWindowed(RecordingBinder):
+            def __init__(self):
+                super().__init__()
+                self.written = []
+                self.windowed = BindWindow(self.bind, width)
+
+            def bind(self, pod, node_name):
+                self.written.append(
+                    (pod.key, threading.current_thread().name))
+                time.sleep(0.002)
+                return False
+
+            def window(self):
+                return self.windowed
+
+        clk = FakeClock()
+        binder = FailingWindowed()
+        s = _sched(clk, batch=64, binder=binder, cfg=_cfg(fail_threshold=3))
+        for i in range(40):
+            s.on_pod_add(mkpod(f"p{i}", creation=i))
+        try:
+            st = s.schedule_pending(now=clk.advance(0.1))
+        finally:
+            binder.windowed.close()
+        assert s.governor.breaker.state == OPEN
+        assert 3 <= len(binder.written) <= 3 + width
+        assert {t for _, t in binder.written} <= {
+            f"bind-window-{i}" for i in range(width)}
+        assert len({k for k, _ in binder.written}) == len(binder.written)
+        # every write was answered and settled; the rest went back unjudged
+        assert st.bind_errors == len(binder.written)
+        assert st.requeued == 40 - len(binder.written)
+        assert sum(s.queue.depths().values()) == 40   # nothing lost
+        assert s.cache.pod_count == 0                 # nothing left assumed
+
     def test_kill_switch_bit_equal(self, monkeypatch):
         def run(overload):
             if overload:
@@ -509,6 +555,49 @@ class TestRetryBudgets:
         assert not b.bind(mkpod("a"), "n1")
         assert FencedPods.calls == 1
         assert b.stale_rejects == 1
+
+    def test_a_fenced_409_counts_once_a_binding_under_16_threads(self):
+        """`APIBinder.bind` is called from its window's threads (ISSUE 40):
+        its counts lose no update however the threads take turns."""
+        import sys
+
+        from kubernetes_tpu.api.types import FENCED_BIND_MARKER
+        from kubernetes_tpu.machinery import errors
+        from kubernetes_tpu.sched.server import APIBinder
+
+        class Pods:
+            def bind(self, name, *a, **kw):
+                if name.endswith("-busy"):
+                    raise errors.new_too_many_requests("busy",
+                                                       retry_seconds=0)
+                raise errors.new_conflict("pods", name,
+                                          f"{FENCED_BIND_MARKER}: stale")
+
+        class FakeClient:
+            pods = Pods()
+
+        b = APIBinder(FakeClient(), fence_source=lambda: 1, retry_budget=1,
+                      retry_base_s=0.0, bind_deadline_s=5.0)
+        each, was = 150, sys.getswitchinterval()
+
+        def bind_many(t):
+            for i in range(each):
+                assert not b.bind(mkpod(f"t{t}-{i}"), "n1")
+                assert not b.bind(mkpod(f"t{t}-{i}-busy"), "n1")
+
+        sys.setswitchinterval(1e-6)   # a turn a few bytecodes long
+        try:
+            threads = [threading.Thread(target=bind_many, args=(t,))
+                       for t in range(16)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+        finally:
+            sys.setswitchinterval(was)
+        assert b.stale_rejects == 16 * each
+        assert b.pushback_retries == 16 * each
+        assert b.pushback_failures == 16 * each
 
 
 class TestWatchTimeoutFix:
