@@ -358,6 +358,9 @@ class Pod:
     spread_selectors: Tuple[LabelSelector, ...] = ()
     priority: int = 0
     node_name: str = ""  # spec.nodeName — set once bound
+    # status.nominatedNodeName — a preemption pass published it
+    # (sched/preemption.py); the queue's nominated-pods map learns it
+    nominated_node_name: str = ""
     scheduler_name: str = DEFAULT_SCHEDULER_NAME
     creation_index: int = 0  # monotonic stand-in for creationTimestamp ordering
     # Gang/co-scheduling (BASELINE config 5). The reference has no in-tree

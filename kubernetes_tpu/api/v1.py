@@ -291,6 +291,8 @@ def pod_from_v1(obj: Dict[str, Any]) -> Pod:
         host_ports=tuple(host_ports),
         priority=int(spec.get("priority", 0) or 0),
         node_name=spec.get("nodeName", "") or "",
+        nominated_node_name=(obj.get("status") or {}).get(
+            "nominatedNodeName", "") or "",
         scheduler_name=spec.get("schedulerName", DEFAULT_SCHEDULER_NAME) or DEFAULT_SCHEDULER_NAME,
         pod_group=group,
         min_member=min_member,

@@ -21,7 +21,11 @@ TPU re-design — everything is one jitted dispatch:
   * the reprieve loop is a single lax.scan over existing pods in global
     priority-descending order — each victim only touches its own node's carry
     row, so per-node sequential semantics are preserved exactly;
-  * node choice is a masked lexicographic argmin on device.
+  * node choice is a masked lexicographic sort on device: its head is the
+    chosen node, and the whole order, with the scan's victims on EVERY
+    candidate node, goes back to the host (`order`, `node_victims`: an [N] and
+    an [E] a lane, never [N, E]), which serves every pending replica of the
+    lane's template from this one what-if (sched/preemption.py).
 
 PDB awareness (criterion 1): `pdb_blocked[e]` — computed host-side from the
 PodDisruptionBudget state (filterPodsWithPDBViolation, :1071-1100: pod matches
@@ -58,6 +62,15 @@ class PreemptResult(NamedTuple):
     victims: Array   # [E] bool — victims on the chosen node
     n_candidates: Array  # scalar i32 — nodes where preemption would work
     n_pdb_violations: Array  # scalar i32 — PDB-violating victims on the node
+    # what the pass's host hand-out reads (sched/preemption.py): every
+    # replica of the lane's template is served from ONE what-if
+    order: Array         # [N] i32 — the nodes in pickOneNodeForPreemption's
+                         # order, the n_candidates candidates first
+    node_victims: Array  # [E] bool — the reprieve scan's victims on EVERY
+                         # candidate node (each row names its own node)
+    bulk: Array          # scalar bool — the class meets other pods through a
+                         # node's resources alone: no required pod
+                         # (anti-)affinity term, no DoNotSchedule spread
 
 
 def _pairwise_port_conflict(
@@ -232,6 +245,10 @@ def preempt_for_pod(
     node = jnp.where(any_cand, best, -1)
     victims = victim & (node_e == node) & any_cand
     nv = (victims & pdb_blocked).sum().astype(jnp.int32)
+    bulk = ~((classes.aff_terms[cls] >= 0).any() | (ans >= 0).any()
+             | hard_ts.any())
     return PreemptResult(node=node.astype(jnp.int32), victims=victims,
                          n_candidates=cand.sum().astype(jnp.int32),
-                         n_pdb_violations=nv)
+                         n_pdb_violations=nv,
+                         order=choice_order.astype(jnp.int32),
+                         node_victims=vmask, bulk=bulk)

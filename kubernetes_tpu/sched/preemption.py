@@ -10,7 +10,17 @@ placement happens in a later wave once the victims' resources are released.
 
 PodEligibleToPreemptOthers (generic_scheduler.go:1085): a pod that already has
 a nominated node is assumed to be waiting for its victims to exit and does not
-preempt again."""
+preempt again.
+
+One pass serves every replica of a template: the what-if returns, per lane,
+the candidate nodes in pickOneNodeForPreemption's order and the reprieve
+scan's victims on every one of them, and the host hands the lane's k-th
+pending replica the k-th node of that order not yet handed out (a node goes
+out at most once a pass, across all lanes). The nomination is published
+(`status.nominatedNodeName`, through the evictor's client) before the victims
+are deleted, upstream's order (scheduler.go preempt: SetNominatedNodeName,
+then the DeletePod calls). Where this is upstream's own sequence and where it
+is not: docs/PARITY.md "Preemption"."""
 
 from __future__ import annotations
 
@@ -21,6 +31,7 @@ from typing import Callable, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..api.types import Pod
 from ..component import trace
@@ -69,6 +80,11 @@ class CacheEvictor:
         self.evicted.append(victim_key)
         return True
 
+    def nominate(self, scheduler, pod: Pod, node_name: str) -> bool:
+        """Publish the preemptor's nomination before its victims go. With no
+        API there is nowhere to publish it: the queue's map is the record."""
+        return True
+
 
 class APIEvictor(CacheEvictor):
     """Live-cluster evictor: DELETE the victim through the API (the
@@ -98,6 +114,51 @@ class APIEvictor(CacheEvictor):
         self.evicted.append(victim_key)
         return True
 
+    def nominate(self, scheduler, pod: Pod, node_name: str) -> bool:
+        """`status.nominatedNodeName` through the API (the reference's
+        podPreemptor.SetNominatedNodeName -> UpdateStatus): what `kubectl
+        describe`, the cluster autoscaler and a scheduler that takes over
+        read. A pod that is gone, or a write the API refuses, preempts
+        nothing (scheduler.go preempt returns before any delete)."""
+        from ..machinery import errors
+
+        try:
+            self.client.pods.patch_status(
+                pod.name, {"status": {"nominatedNodeName": node_name}},
+                pod.namespace)
+        except errors.StatusError:
+            return False
+        return True
+
+
+class _Lane:
+    """One lane's verdict as the hand-out reads it: the candidate nodes in
+    pickOneNodeForPreemption's order, a cursor into them, and the reprieve
+    scan's victims (rows of the existing-pod axis) grouped by node."""
+
+    __slots__ = ("order", "n_cand", "bulk", "at", "served", "_rows", "_on")
+
+    def __init__(self, order, n_cand: int, victims, bulk: bool, node_of_e):
+        self.order, self.n_cand, self.bulk = order, n_cand, bulk
+        self.at = self.served = 0
+        rows = np.flatnonzero(victims[: node_of_e.shape[0]])
+        by_node = np.argsort(node_of_e[rows], kind="stable")
+        self._rows = rows[by_node]
+        self._on = node_of_e[self._rows]
+
+    def next_node(self, taken: Set[int]) -> int:
+        """The best candidate not handed out yet this pass, -1 when none."""
+        while self.at < self.n_cand:
+            n = int(self.order[self.at])
+            self.at += 1
+            if n not in taken:
+                return n
+        return -1
+
+    def victims_on(self, n: int):
+        lo, hi = np.searchsorted(self._on, (n, n + 1))
+        return self._rows[lo:hi]
+
 
 class Preemptor:
     def __init__(self, evictor: Optional[CacheEvictor] = None,
@@ -112,6 +173,9 @@ class Preemptor:
         self.attempts = 0
         self.successes = 0
         self.last_pdb_violations = 0
+        # what the last pass did, as the wave's record carries it
+        # (docs/OBSERVABILITY.md `preempt_*`); empty when none ran
+        self.last_pass: dict = {}
         # zero-victim prompt retries already granted, per pod key: the
         # FIRST "candidate with zero victims" is almost always burst/wave
         # staleness (state changed under the what-if) and retries promptly;
@@ -121,8 +185,6 @@ class Preemptor:
         self._zero_victim_retries: dict = {}
 
     def _pdb_blocked(self, scheduler, snap: Snapshot):
-        import numpy as np
-
         E = len(snap.existing_keys)
         blocked = np.zeros((max(E, 1),), bool)
         if self.pdb_source is None:
@@ -162,21 +224,26 @@ class Preemptor:
         """The whole wave's preemption pass as ONE fused device dispatch
         (chunked at PREEMPT_BURST lanes, one lane per DISTINCT preemptor
         template): evaluate every unschedulable priority pod's what-if
-        against the same snapshot, then commit host-side in batch order. Returns the keys that preempted (victims
-        evicted, pod nominated + requeued); the caller requeues the rest as
-        plain unschedulable.
+        against the same snapshot, then hand the nodes out host-side in
+        batch order. Returns the keys that preempted (victims evicted, pod
+        nominated + requeued) or were told to retry promptly; the caller
+        requeues the rest as plain unschedulable.
 
-        Commit semantics vs the old per-pod loop (which re-snapshotted
-        between pods): lanes are evaluated against the PRE-burst state, so
-        two lanes can name the same victim. The commit evicts each victim
-        once; a lane none of whose victims remain evictable is NOT counted
-        as preempting — its space was already freed by an earlier lane and
-        the ordinary retry (the eviction's move event) will place it."""
-        import numpy as np
-
+        The hand-out: a lane's k-th pending replica takes the k-th node of
+        the lane's order that no earlier pod of the pass took, with the
+        victims the reprieve scan left on that node. A lane hands out no
+        more nodes than it has replicas pending; replicas beyond its
+        candidates are unschedulable. A lane whose class meets its own
+        replicas through required (anti-)affinity or a hard spread
+        constraint (`bulk` false) takes ONE node a pass — its what-if did
+        not see the first replica where it was sent — and its other
+        replicas retry promptly. Then every nomination is published, then
+        every victim evicted; what the pass did is `last_pass` (the wave's
+        record carries it)."""
         from ..ops.lattice import default_engine_config
         from .cycle import UNSCHEDULABLE_TAINT_KEY
 
+        self.last_pass = {}
         # ---- host-side eligibility (PodEligibleToPreemptOthers) ---- #
         row_of = {k: i for i, (k, _) in enumerate(snap.pending_keys)}
         eligible: List[Tuple[Pod, int, int]] = []  # (pod, attempts, row)
@@ -227,8 +294,12 @@ class Preemptor:
         lane_of = [(int(pend_cls[row]), int(pend_nnr[row]), int(pod.priority))
                    for pod, _attempts, row in eligible]
         distinct = list(dict.fromkeys(lane_of))
-        # lane → (node index, victim pod keys, PDB violations)
-        verdict: dict = {}
+        # which node each row of the existing-pod axis is on: a lane's
+        # victim mask names rows, the hand-out needs them by node
+        node_of_e = np.asarray(jax.device_get(snap.existing.node_id))[
+            : len(snap.existing_keys)]
+        verdict: dict = {}   # lane → _Lane
+        dispatches = 0
         supervisor = getattr(scheduler, "supervisor", None)
         B = PREEMPT_BURST
         for lo in range(0, len(distinct), B):
@@ -239,9 +310,10 @@ class Preemptor:
             prio_b = jnp.asarray(np.array([p for _, _, p in pad], np.int32))
 
             def _readback(res: PreemptResult):
-                return (np.asarray(jax.device_get(res.node)),
-                        np.asarray(jax.device_get(res.victims)),
-                        np.asarray(jax.device_get(res.n_pdb_violations)))
+                # per lane an [N] order and one [E] mask; never [N, E]
+                return tuple(np.asarray(a) for a in jax.device_get(
+                    (res.order, res.n_candidates, res.node_victims,
+                     res.bulk)))
 
             def _primary():
                 # the lookup carries the snapshot's mesh signature: a
@@ -293,7 +365,7 @@ class Preemptor:
                 from .supervisor import DispatchAbandonedError
 
                 try:
-                    nodes_b, victims_b, npdb_b = supervisor.run(
+                    order_b, ncand_b, victims_b, bulk_b = supervisor.run(
                         "preempt",
                         (_dc_replace(snap.dims, has_node_name=False, P=1), B,
                          _mesh_key(snap.mesh)),
@@ -307,63 +379,99 @@ class Preemptor:
                     # successful readback.
                     break
             else:
-                nodes_b, victims_b, npdb_b = _primary()
+                order_b, ncand_b, victims_b, bulk_b = _primary()
+            dispatches += 1
             if tr is not None:
                 tr.child("what-if", time.perf_counter() - tw0)
             for i, lane in enumerate(chunk):
-                verdict[lane] = (
-                    int(nodes_b[i]),
-                    [snap.existing_keys[e] for e in np.flatnonzero(
-                        victims_b[i][: len(snap.existing_keys)])],
-                    int(npdb_b[i]))
+                verdict[lane] = _Lane(order_b[i], int(ncand_b[i]),
+                                      victims_b[i], bool(bulk_b[i]),
+                                      node_of_e)
 
-        # ---- host commit, in batch order ---- #
-        handled: Set[str] = set()
-        retry_soon: Set[str] = set()  # candidates whose space another lane
-                                      # freed this burst: retry promptly
-        for (pod, attempts, _row), lane in zip(eligible, lane_of):
-            if lane not in verdict:
+        # ---- the hand-out, in batch order: host arithmetic only ---- #
+        handed: list = []             # (pod, node index, victim rows)
+        taken: Set[int] = set()       # nodes handed out this pass
+        retry_soon: Set[str] = set()  # pods whose room this pass freed or
+                                      # may have freed: retry promptly
+        for (pod, attempts, _row), key in zip(eligible, lane_of):
+            lane = verdict.get(key)
+            if lane is None or not lane.n_cand:
                 continue
-            node_idx, victim_keys, n_pdb = verdict[lane]
-            if node_idx < 0:
-                continue
-            if not victim_keys:
-                # a candidate with zero victims: the pod should simply
-                # fit. Once per pod that is burst staleness (an earlier
-                # lane/wave freed the space after the what-if's
-                # snapshot) — retry promptly. A repeat means a real
-                # host/device filter discrepancy: evicting nothing and
-                # nominating would only mask it, so it takes the
-                # normal backoff + FailedScheduling path.
-                if self._zero_victim_retries.get(pod.key, 0) < 1:
-                    if len(self._zero_victim_retries) > 4096:
-                        # bound the ledger by dropping the OLDEST half
-                        # (dict preserves insertion order) — clearing
-                        # wholesale would forget the pod just recorded
-                        # and re-arm the hot loop this cap prevents
-                        for k in list(self._zero_victim_retries)[:2048]:
-                            del self._zero_victim_retries[k]
-                    self._zero_victim_retries[pod.key] = 1
-                    retry_soon.add(pod.key)
-                continue
-            evicted_any = False
-            for vk in victim_keys:
-                if self.evictor.evict(scheduler, vk):
-                    evicted_any = True
-                    PREEMPTION_VICTIMS.inc()
-            if not evicted_any:
-                # every victim was already evicted for an earlier lane:
-                # that lane's commit freed this space — the pod is
-                # expected to fit next wave; exponential backoff here
-                # would serialize the whole burst at seconds per round
+            if lane.served and not lane.bulk:
+                # one node a pass: the next wave's Filter sees the first
+                # replica on its node; exponential backoff here would
+                # serialize the burst at seconds per round
                 retry_soon.add(pod.key)
                 continue
-            self.last_pdb_violations = n_pdb
-            scheduler.queue.add_nominated(pod.key,
-                                          snap.node_order[node_idx])
-            handled.add(pod.key)
-            self._zero_victim_retries.pop(pod.key, None)
-            self.successes += 1
+            n = lane.next_node(taken)
+            if n < 0:
+                continue   # more replicas than candidate nodes
+            taken.add(n)
+            lane.served += 1
+            rows = lane.victims_on(n)
+            if rows.size:
+                handed.append((pod, n, rows))
+                continue
+            # a candidate with zero victims: the pod should simply fit.
+            # Once per pod that is burst staleness (an earlier wave freed
+            # the space after the what-if's snapshot) — retry promptly. A
+            # repeat means a real host/device filter discrepancy: evicting
+            # nothing and nominating would only mask it, so it takes the
+            # normal backoff + FailedScheduling path.
+            if self._zero_victim_retries.get(pod.key, 0) < 1:
+                if len(self._zero_victim_retries) > 4096:
+                    # bound the ledger by dropping the OLDEST half (dict
+                    # preserves insertion order) — clearing wholesale would
+                    # forget the pod just recorded and re-arm the hot loop
+                    # this cap prevents
+                    for k in list(self._zero_victim_retries)[:2048]:
+                        del self._zero_victim_retries[k]
+                self._zero_victim_retries[pod.key] = 1
+                retry_soon.add(pod.key)
+
+        # ---- nominate, then evict (upstream's order), each under a span
+        # the apiserver's and the store's own spans nest below ---- #
+        handled: Set[str] = set()
+        victims = 0
+        tn0 = tn1 = tn2 = time.perf_counter()
+        if handed:
+            tr = trace.current()
+            tok = tr.begin("nominate") if tr is not None else None
+            named = [h for h in handed if self.evictor.nominate(
+                scheduler, h[0], snap.node_order[h[1]])]
+            tn1 = time.perf_counter()
+            if tr is not None:
+                tr.end(tok, tn1 - tn0)
+                tok = tr.begin("evict")
+            for pod, n, rows in named:
+                evicted = sum(1 for e in rows if self.evictor.evict(
+                    scheduler, snap.existing_keys[e]))
+                if not evicted:
+                    # its victims left by another hand since the snapshot:
+                    # the room is there, the pod is expected to fit next
+                    # wave
+                    retry_soon.add(pod.key)
+                    continue
+                victims += evicted
+                self.last_pdb_violations = int(blocked[rows].sum())
+                scheduler.queue.add_nominated(pod.key, snap.node_order[n])
+                handled.add(pod.key)
+                self._zero_victim_retries.pop(pod.key, None)
+                self.successes += 1
+            PREEMPTION_VICTIMS.inc(victims)
+            tn2 = time.perf_counter()
+            if tr is not None:
+                tr.end(tok, tn2 - tn1)
+        self.last_pass = {
+            "preempt_lanes": len(distinct),
+            "preempt_preemptors": len(eligible),
+            "preempt_dispatches": dispatches,
+            "preempt_nodes_handed_out": len(taken),
+            "preempt_nominated": len(handled),
+            "preempt_victims": victims,
+            "preempt_retry_soon": len(retry_soon),
+            "preempt_nominate_s": round(tn1 - tn0, 6),
+            "preempt_evict_s": round(tn2 - tn1, 6)}
 
         if not handled:
             # no lane evicted anything: a zero-victim candidate here is a

@@ -151,6 +151,7 @@ class PriorityQueue:
             self._push_active(e)
             if self._micro_eligible(pod):
                 self._micro[pod.key] = e
+            self._learn_nomination(pod)
 
     def add_unschedulable(
         self, pod: Pod, attempts: int, now: float, cycle: Optional[int] = None
@@ -202,6 +203,17 @@ class PriorityQueue:
             # micro-eligible (a retried pod keeps bulk-lane routing)
             if attempts == 0 and self._micro_eligible(pod):
                 self._micro[pod.key] = e
+            self._learn_nomination(pod)
+
+    def _learn_nomination(self, pod: Pod) -> None:
+        """A pending pod that arrives with a published nomination
+        (`status.nominatedNodeName`: listed at start() after a failover, or
+        the echo of this scheduler's own write) enters the nominated-pods
+        map, as the reference's nominatedPodMap.add does on Add / Update
+        (scheduling_queue.go addNominatedPodIfNeeded). What this process
+        decided itself wins over what it reads back. Caller holds `_mu`."""
+        if pod.nominated_node_name:
+            self._nominated.setdefault(pod.key, pod.nominated_node_name)
 
     def delete(self, key: str) -> None:
         with self._mu:

@@ -424,6 +424,13 @@ class Scheduler:
             # (eventhandlers.go moves pods on assigned-pod updates)
             self.queue.move_all_to_active(self.clock())
         elif self.responsible_for(new):
+            if self.cache.get_pod(new.key) is not None:
+                # skipPodUpdate (eventhandlers.go:347): the pod is assumed
+                # and this is the echo of a write that came before its
+                # Binding (a published nomination): its queue entry is
+                # spent, re-admitting it would only hand a later wave a pod
+                # that is already placed
+                return
             self.queue.update(new, now=self.clock())
 
     def on_pod_delete(self, pod: Pod) -> None:
@@ -1219,7 +1226,9 @@ class Scheduler:
         the extender pods' assumes), the queue (add_unschedulable), the
         device and the apiserver (evictions, the extender pods'
         Bindings). Its time is the `requeue` phase the driver closes, with
-        `requeue/snapshot` and `requeue/preempt` beneath it."""
+        `requeue/snapshot` and `requeue/preempt` (`what-if`, `nominate`,
+        `evict`) beneath it; the pass's counts (`preempt_*`) ride the
+        wave's record."""
         now, stats = wave.now, wave.stats
         handled_keys: set = set()
         if failures and self.preemptor is not None:
@@ -1246,6 +1255,7 @@ class Scheduler:
                     self, eligible, fresh, now)
                 if tr is not None:
                     tr.end(tok, time.perf_counter() - tp1)
+                wave.extra.update(self.preemptor.last_pass)
         for pod, attempts in failures:
             if pod.key in handled_keys:
                 continue

@@ -476,7 +476,7 @@ class TestFusedPreemptionBurst:
     def test_scheduler_burst_evicts_and_nominates(self):
         """End-to-end through Scheduler.schedule_pending: several failed
         priority pods preempt in ONE burst — victims evicted, preemptors
-        nominated on distinct nodes and requeued."""
+        nominated on distinct nodes and requeued, all in the one pass."""
         from kubernetes_tpu.sched.preemption import Preemptor
         from kubernetes_tpu.sched.scheduler import (
             RecordingBinder, Scheduler)
@@ -501,20 +501,20 @@ class TestFusedPreemptionBurst:
                              creation_index=10 + i))
         st = s.schedule_pending()
         assert st.scheduled == 0
-        # the burst evaluates both vips against the SAME snapshot: they
-        # pick the same best node, the overlap commit evicts its victim
-        # once, and exactly one vip is nominated there
-        assert len(s.preemptor.evictor.evicted) == 1
-        assert s.preemptor.successes == 1
-        # the freed space + follow-up bursts place both vips within a few
-        # waves (each wave: bind what fits, preempt what does not)
-        assigned = {}
-        for _wave in range(8):
-            clock.t += 10.0
-            assigned.update(s.schedule_pending().assignments)
-            if len(assigned) == 2:
-                break
+        # the burst evaluates both vips (one template, one lane) against
+        # the SAME snapshot; the hand-out gives the lane's first replica
+        # the best node and its second the next one (PR 41: before it the
+        # second was told to retry and took a pass of its own)
+        assert len(s.preemptor.evictor.evicted) == 2
+        assert s.preemptor.successes == 2
+        assert {s.queue.nominated_node(f"default/vip{i}")
+                for i in range(2)} == {"n0", "n1"}
+        assert s.preemptor.last_pass["preempt_nodes_handed_out"] == 2
+        # the very next wave binds both on the freed nodes
+        clock.t += 10.0
+        assigned = s.schedule_pending().assignments
         assert set(assigned) == {"default/vip0", "default/vip1"}
+        assert set(assigned.values()) == {"n0", "n1"}
         assert set(s.preemptor.evictor.evicted) == {"default/victim0",
                                                     "default/victim1"}
 
@@ -523,8 +523,9 @@ class TestFusedPreemptionBurst:
         """Preemptors that agree on (class, nodeName pin, priority) get the
         identical what-if against the pre-burst snapshot, so a burst of 40
         replicas of two templates is ONE 8-lane dispatch, not five — and
-        commits exactly as the lane-per-pod burst did (same-verdict pods
-        overlap on the victim; one is nominated, the rest retry)."""
+        the lane's replicas take its candidate nodes in order: two nodes,
+        two nominations, the other 18 replicas (and the template no node
+        can hold) unschedulable."""
         import kubernetes_tpu.sched.preemption as pm
         from kubernetes_tpu.sched.scheduler import (
             RecordingBinder, Scheduler)
@@ -556,8 +557,14 @@ class TestFusedPreemptionBurst:
         assert st.scheduled == 0
         assert len(calls) == 1
         assert s.preemptor.attempts == 40
-        assert s.preemptor.successes == 1
-        assert len(s.preemptor.evictor.evicted) == 1
+        assert s.preemptor.successes == 2
+        assert len(s.preemptor.evictor.evicted) == 2
+        assert s.preemptor.last_pass == {
+            **s.preemptor.last_pass, "preempt_lanes": 2,
+            "preempt_preemptors": 40, "preempt_dispatches": 1,
+            "preempt_nodes_handed_out": 2, "preempt_nominated": 2,
+            "preempt_victims": 2, "preempt_retry_soon": 0}
+        assert st.unschedulable == 38
 
 
 class TestSatellites:

@@ -200,6 +200,11 @@ _HEAD = ["recorder", "t_start", "duration_s", "phases", "engine", "rc"]
 # record's own fields, before what the caller adds
 _GC = ["gc_full_collections", "gc_pause_s", "gc_max_pause_s",
        "gc_max_pause_at"]
+# what a preemption pass did (ISSUE 41): on the record of a wave whose pass
+# had an eligible pod, candidates or none
+_PASS = ["preempt_lanes", "preempt_preemptors", "preempt_dispatches",
+         "preempt_nodes_handed_out", "preempt_nominated", "preempt_victims",
+         "preempt_retry_soon", "preempt_nominate_s", "preempt_evict_s"]
 _WAVE_SHAPES = {
     # kind: (phases, children, keys)
     "bulk": (_BULK,
@@ -209,7 +214,8 @@ _WAVE_SHAPES = {
                          "requeue/snapshot/prepare"] + _FIRST_SNAPSHOT,
              _HEAD + ["bucket", "affinity_agg", "stats", "device_split",
                       "children"] + _GC + ["snapshot_mode", "waits",
-                      "assumed_outstanding", "minor_faults", "seq"]),
+                      "assumed_outstanding"] + _PASS + ["minor_faults",
+                                                        "seq"]),
     "micro": (_BULK,
               _BINDING + _FIRST_SNAPSHOT + ["snapshot/upload"],
               _HEAD + ["micro", "bucket", "affinity_agg", "stats",
