@@ -75,7 +75,8 @@ def _fleet_cycle_impl(
 
     def body(t, pe, ky, ex, q):
         uk, ev = ky
-        cyc = build_cycle(t, ex, uk, ev, D, hard_weight, ecfg)
+        cyc = build_cycle(t, ex, uk, ev, D, hard_weight, ecfg,
+                          copies=quota.shape[0])
         admitted, share, dom = drf_admission_row(t, pe, q)
         clamped = pe._replace(valid=admitted)
         init = initial_state(t, cyc)

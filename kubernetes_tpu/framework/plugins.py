@@ -127,7 +127,7 @@ class InterPodAffinity(FilterPlugin, ScorePlugin):
         return jax.vmap(
             lambda c: soft_affinity_row(
                 c, tables.classes, tables.terms, cyc.CNT, tables.nodes, D,
-                TM=cyc.TM, WSYM=cyc.WSYM)
+                TM=cyc.TM, WSYM=cyc.WSYM, same=cyc.SAME)
         )(ctx.pending.cls)
 
 

@@ -161,8 +161,8 @@ def state_affinity_table(
              + classes.paff_terms.shape[1] + classes.panti_terms.shape[1])
     if affinity_agg(rows, slots, cyc.TM.shape[0]) == "row":
         return None
-    return term_domain_counts(tables.terms, state.CNT, tables.nodes,
-                              cyc.ELD.shape[2] - 1)
+    return term_domain_counts(tables.terms, state.CNT, state.HOLD, state.WSYM,
+                              tables.nodes, cyc.ELD.shape[2] - 1, cyc.SAME)
 
 
 def mask_context_row(
@@ -187,8 +187,8 @@ def mask_context_row(
     ecfg = cyc.ecfg
     D = cyc.ELD.shape[2] - 1
     aff_ok, anti_ok = affinity_rows(
-        cls, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D, table
-    )
+        cls, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D, table,
+        cyc.SAME)
     interpod_ok = (aff_ok & anti_ok) | ~_on(ecfg.f_interpod)
     spread_ok = spread_row(
         cls, classes, terms, cyc.TM, state.CNT, cyc.ELD,
@@ -304,7 +304,8 @@ def score_context_row(
     nodes, classes, terms = tables.nodes, tables.classes, tables.terms
     D = cyc.ELD.shape[2] - 1
     soft_ip = soft_affinity_row(cls, classes, terms, state.CNT, nodes, D,
-                                TM=cyc.TM, WSYM=state.WSYM, table=table)
+                                TM=cyc.TM, WSYM=state.WSYM, table=table,
+                                same=cyc.SAME)
     even_soft = even_spread_soft_row(
         cls, classes, terms, state.CNT, nodes, cyc.static.node_match[cls], D)
     ssel = selector_spread_row(
@@ -402,8 +403,8 @@ def mask_components(
         )
         port_ok = (ps < 0) | ~conflict
         aff_ok, anti_ok = affinity_rows(
-            c, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D, table
-        )
+            c, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D, table,
+            cyc.SAME)
         spread_ok = spread_row(
             c, classes, terms, cyc.TM, state.CNT, cyc.ELD,
             cyc.static.node_match[c], nodes, D,
@@ -485,7 +486,8 @@ def _explain_mask_row(tables: ClusterTables, cyc: CycleArrays,
     # interpod/spread decomposed: mask_context_row conjoins (aff ∧ anti)
     # under one flag — KEEP the flag composition in sync with it
     aff_ok, anti_ok = affinity_rows(
-        c, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D, table)
+        c, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D, table,
+        cyc.SAME)
     aff_ok = aff_ok | ~_on(ecfg.f_interpod)
     anti_ok = anti_ok | ~_on(ecfg.f_interpod)
     spread_ok = spread_row(

@@ -20,16 +20,32 @@ node axis as the source of truth makes both exact.
 
 The inter-pod aggregate of term s over the domains of term s's own key depends
 on s and the state alone, never on the class that asks. `in_domain_counts` is
-that aggregate (one scatter-add into [A, D+1], one gather back to [A, N]) in
-one of two parameterisations, chosen from static shapes at trace time
-(ops/assign.py state_affinity_table, by state/dims.py affinity_agg): a caller
-that evaluates many classes against ONE state (the waves round over SC
-classes) asks it once for every term — `term_domain_counts`, the [S, N] table
-— and every class SELECTS its slots' rows ("term"); a caller with fewer
+that aggregate in one of two parameterisations, chosen from static shapes at
+trace time (ops/assign.py state_affinity_table, by state/dims.py
+affinity_agg): a caller that evaluates many classes against ONE state (the
+waves round over SC classes) asks it once for every term —
+`term_domain_counts`, the [S, N] table, which sums HOLD and WSYM in the same
+pass — and every class SELECTS its slots' rows ("term"); a caller with fewer
 rows x slots than terms (a verb's P pods, a what-if lane's one preemptor, a
-scan step) asks it for its own slots ("row"). A scatter is serial in its
-updates, so rows aggregated is the whole cost. Integer counts either way: the
+scan step) asks it for its own slots ("row"). Integer counts either way: the
 two are bit-equal.
+
+HOW a per-node table is summed over each node's domain (`in_domain_sums`) is
+a second static choice, state/dims.py domain_sum, from N and K alone:
+
+  * "product": row s times SAME_k, the 0/1 matrix "nodes m and n carry key k
+    and share its value" (`same_domain`, built once a cycle by
+    ops/lattice.py build_cycle and carried on CycleArrays.SAME, outside the
+    rounds' loop). One bf16 product on the MXU for every table that shares
+    the keys; the integers split into 8-bit digits so every factor is exact
+    in bf16 and every f32 partial sum an integer below 2^24. No scatter, no
+    gather;
+  * "scatter": one scatter-add into [A, D+1], one gather back to [A, N] —
+    serial in its A x N updates (~19 ns each on the TPU, and ~10 ns an
+    element gathered back), kept for node axes whose K x N x N matrix has no
+    room beside the state.
+
+The two are bit-equal (tests/test_scores.py).
 
 The predicate semantics (satisfiesPodsAffinityAntiAffinity :1421-1520):
   * affinity:  ∀ term: node-has-key ∧ domain-count > 0, with the first-pod
@@ -139,25 +155,80 @@ class TermCounts(NamedTuple):
     cnt: Array  # [S, N] i32: pods matching term s in node n's domain of
     #             term s's key; 0 where n lacks the key
     tot: Array  # [S] i32: pods matching term s on nodes carrying the key
+    # the state's other two per-term tables, summed in the same pass
+    hold: Array  # [S, N] i32: holders of anti-term s in node n's domain
+    sym: Array   # [S, N] f32: symmetric weights (scores.py WSYM) likewise
 
 
-def _in_domain(rows: Array, topo_key: Array, nodes: NodeArrays,
-               D: int) -> TermCounts:
-    """rows [A, N] per-node counts of A terms with keys topo_key [A] →
-    their TermCounts: one scatter-add into [A, D+1], one gather back."""
+def same_domain(nodes: NodeArrays) -> Array:
+    """SAME [K, N, N] bf16 0/1: valid nodes m and n both carry key k and
+    share its value. A function of the node table alone — not of the state,
+    the round, the class or the table being summed."""
+    dom = jnp.where(nodes.valid[:, None], nodes.domain, -1).T    # [K, N]
+    same = (dom[:, :, None] == dom[:, None, :]) & (dom[:, :, None] >= 0)
+    return same.astype(jnp.bfloat16)
+
+
+def _same_domain_product(rows: Array, topo_key: Array, same: Array) -> Array:
+    """rows [A, N] integer-valued, |value| <= 2^24; topo_key [A]; same
+    [K, N, N] → out[a, n] = Σ_m rows[a, m] · same[topo_key[a], m, n], exact.
+    The values go in as three 8-bit digits (the top one signed), each exact
+    in bf16's 8 bits; a digit's sum over a domain is at most 256 · N, an
+    integer below 2^24 for any N `domain_sum` lets through, so the MXU's f32
+    accumulation is exact in any order; the digits meet again in i32. One
+    product contracts (key, node): a row is laid out under its own key."""
+    K = same.shape[0]
+    v = rows.astype(jnp.int32)
+    digits = jnp.stack([v & 255, (v >> 8) & 255, v >> 16]) \
+        .astype(jnp.bfloat16)                                     # [3, A, N]
+    own_key = topo_key[:, None] == jnp.arange(K)[None, :]         # [A, K]
+    lhs = jnp.where(own_key[None, :, :, None], digits[:, :, None, :], 0)
+    out = jax.lax.dot_general(
+        lhs, same, (((2, 3), (0, 1)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)     # [3, A, N]
+    return ((out[2] << 16) + (out[1] << 8) + out[0]).astype(rows.dtype)
+
+
+def in_domain_sums(rows: Array, topo_key: Array, nodes: NodeArrays, D: int,
+                   same: Array | None = None) -> Array:
+    """rows [A, N]: a per-node table of A terms with keys topo_key [A] →
+    [A, N]: the table summed over each node's domain of the term's key, 0
+    where the node lacks the key. With `same` (CycleArrays.SAME: the
+    program's Dims chose "product") a product on the MXU; without, one
+    scatter-add into [A, D+1] and one gather back. Bit-equal: the rows are
+    integers (i32 counts, integer-valued f32 weights) with sums below
+    2^24."""
+    if same is not None:
+        return _same_domain_product(rows, topo_key, same)
     dom, has_key = domain_of_term(nodes, topo_key)           # [A, N]
     seg = domain_agg(rows, dom, D)                           # [A, D+1]
-    cnt = jnp.take_along_axis(seg, jnp.where(has_key, dom, D), axis=1)
-    return TermCounts(cnt=jnp.where(has_key, cnt, 0),
-                      tot=jnp.sum(jnp.where(has_key, rows, 0), axis=1))
+    out = jnp.take_along_axis(seg, jnp.where(has_key, dom, D), axis=1)
+    return jnp.where(has_key, out, 0)
+
+
+def _on_keyed_nodes(rows: Array, topo_key: Array, nodes: NodeArrays) -> Array:
+    """rows [A, N] → [A]: each term's total over the nodes carrying its key."""
+    _, has_key = domain_of_term(nodes, topo_key)             # [A, N]
+    return jnp.sum(jnp.where(has_key, rows, 0), axis=1)
 
 
 def term_domain_counts(
-    terms: TermTable, CNT_node: Array, nodes: NodeArrays, D: int
+    terms: TermTable, CNT_node: Array, HOLD_node: Array, WSYM: Array,
+    nodes: NodeArrays, D: int, same: Array | None = None,
 ) -> TermCounts:
-    """The table: every term of the state aggregated once."""
+    """The table: every term of the state aggregated once, and the state's
+    HOLD and WSYM with it — three [S, N] tables over the same keys are ONE
+    [3 S, N] sum (the weights are integer-valued f32: scores.py
+    weighted_per_node)."""
+    S = CNT_node.shape[0]
     with jax.named_scope("term_domain_counts"):
-        return _in_domain(CNT_node, terms.topo_key, nodes, D)
+        stacked = jnp.concatenate(
+            [CNT_node, HOLD_node, WSYM.astype(jnp.int32)])
+        out = in_domain_sums(stacked, jnp.tile(terms.topo_key, 3), nodes, D,
+                             same)
+        return TermCounts(
+            cnt=out[:S], tot=_on_keyed_nodes(CNT_node, terms.topo_key, nodes),
+            hold=out[S:2 * S], sym=out[2 * S:].astype(WSYM.dtype))
 
 
 def in_domain_counts(
@@ -167,14 +238,17 @@ def in_domain_counts(
     nodes: NodeArrays,
     D: int,
     table: TermCounts | None = None,
-) -> TermCounts:
+    same: Array | None = None,         # CycleArrays.SAME
+) -> tuple[Array, Array]:
     """(cnt [A, N], tot [A]) for the terms in `term_slots`: their rows of
     `table` where the caller built one for this state, else aggregated here
     from the slots' own CNT rows."""
     s = jnp.maximum(term_slots, 0)
     if table is not None:
-        return TermCounts(cnt=table.cnt[s], tot=table.tot[s])
-    return _in_domain(CNT_node[s], terms.topo_key[s], nodes, D)
+        return table.cnt[s], table.tot[s]
+    rows, keys = CNT_node[s], terms.topo_key[s]
+    return (in_domain_sums(rows, keys, nodes, D, same),
+            _on_keyed_nodes(rows, keys, nodes))
 
 
 def affinity_rows(
@@ -186,14 +260,15 @@ def affinity_rows(
     HOLD_node: Array,        # [S, N]
     nodes: NodeArrays,
     D: int,
-    table: TermCounts | None = None,   # term_domain_counts of CNT_node
+    table: TermCounts | None = None,   # term_domain_counts of this state
+    same: Array | None = None,         # CycleArrays.SAME
 ) -> tuple[Array, Array]:
     """(affinity_ok [N], anti_ok [N]) for one pod against live counts."""
 
     # --- required affinity (satisfiesPodsAffinityAntiAffinity :1431-1444) ---
     ats = classes.aff_terms[cls]  # [AT]
     s = jnp.maximum(ats, 0)
-    cnt, tot = in_domain_counts(ats, terms, CNT_node, nodes, D, table)
+    cnt, tot = in_domain_counts(ats, terms, CNT_node, nodes, D, table, same)
     active = ats >= 0
     all_terms = (~active[:, None] | (cnt > 0)).all(0)  # [N]
     total = jnp.sum(jnp.where(active, tot, 0))
@@ -204,12 +279,14 @@ def affinity_rows(
 
     # --- incoming pod's anti-affinity (nodeMatchesAnyTopologyTerm :1447-1456) ---
     ans = classes.anti_terms[cls]  # [AN]
-    cnt_a, _ = in_domain_counts(ans, terms, CNT_node, nodes, D, table)
+    cnt_a, _ = in_domain_counts(ans, terms, CNT_node, nodes, D, table, same)
     blocked_own = ((ans >= 0)[:, None] & (cnt_a > 0)).any(0)  # [N]
 
     # --- existing pods' anti-affinity symmetry (:1319-1360) ---
-    # per TERM and not per class: computed once under a vmap over classes
-    hold = _in_domain(HOLD_node, terms.topo_key, nodes, D).cnt  # [S, N]
+    # per TERM and not per class: the table's, or computed here, once under
+    # a vmap over classes
+    hold = table.hold if table is not None else in_domain_sums(
+        HOLD_node, terms.topo_key, nodes, D, same)           # [S, N]
     blocked_sym = (TM[:, cls][:, None] & (hold > 0)).any(0)  # [N]
 
     return aff_ok, ~(blocked_own | blocked_sym)
@@ -224,7 +301,8 @@ def soft_affinity_row(
     D: int,
     TM: Array | None = None,
     WSYM: Array | None = None,
-    table: TermCounts | None = None,   # term_domain_counts of CNT_node
+    table: TermCounts | None = None,   # term_domain_counts of this state
+    same: Array | None = None,         # CycleArrays.SAME
 ) -> Array:
     """Preferred inter-pod (anti)affinity score [N] f32, 0..100 after min/max
     normalization (interpod_affinity.go:119-215). Both directions: the incoming
@@ -235,7 +313,8 @@ def soft_affinity_row(
     array is."""
 
     def contrib(term_slots: Array, weights: Array, sign: float) -> Array:
-        cnt, _ = in_domain_counts(term_slots, terms, CNT_node, nodes, D, table)
+        cnt, _ = in_domain_counts(term_slots, terms, CNT_node, nodes, D, table,
+                                  same)
         w = jnp.where(term_slots >= 0, weights, 0).astype(jnp.float32)
         return sign * (w[:, None] * cnt).sum(0)
 
@@ -245,7 +324,9 @@ def soft_affinity_row(
     if TM is not None and WSYM is not None:
         from .scores import sym_affinity_contrib
 
-        raw = raw + sym_affinity_contrib(cls, TM, WSYM, terms, nodes, D)
+        sym = table.sym if table is not None else in_domain_sums(
+            WSYM, terms.topo_key, nodes, D, same)
+        raw = raw + sym_affinity_contrib(cls, TM, sym)
     lo = jnp.min(jnp.where(nodes.valid, raw, jnp.inf))
     hi = jnp.max(jnp.where(nodes.valid, raw, -jnp.inf))
     return jnp.where(hi > lo, 100.0 * (raw - lo) / jnp.maximum(hi - lo, 1e-9), 0.0)

@@ -21,8 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..state.arrays import Array, ClusterTables, PodArrays
+from ..state.dims import domain_sum
 from .interpod import (class_node_hist, class_term_membership, per_node_counts,
-                       term_class_matrix)
+                       same_domain, term_class_matrix)
 from .labels import node_term_matrix
 from .scores import image_locality_static, symmetric_weight_cols, weighted_per_node
 from .taints import taint_matrices, taint_toleration_score
@@ -141,6 +142,10 @@ class CycleArrays(NamedTuple):
     WCOLS: Array     # [S, SC] f32 signed symmetric-preference weights per class
     WSYM: Array      # [S, N] f32 symmetric weight seed from existing pods
     ecfg: EngineConfig  # traced plugin composition (filters + score weights)
+    # [K, N, N] bf16 same-domain matrices (interpod.same_domain) where
+    # state/dims.py domain_sum chose "product" at these shapes, else None:
+    # built here, once a cycle, so no round of an engine's loop rebuilds them
+    SAME: Array | None = None
 
 
 def _safe_row_gather(M: Array, ids: Array, default: bool) -> Array:
@@ -214,12 +219,15 @@ def build_cycle(
     D: int,
     hard_weight=1,
     ecfg: EngineConfig | None = None,
+    copies: int = 1,
 ) -> CycleArrays:
     """Everything the scan needs, computed in one fused pass on device.
     The analog of RunPreFilterPlugins + GetPredicateMetadata
     (generic_scheduler.go:206, metadata.go:334) — but once per *cycle*, shared
     by every pod, instead of once per pod. `D` (domain-axis capacity) must be
-    static under jit — pass via static_argnums/partial."""
+    static under jit — pass via static_argnums/partial. `copies`: how many
+    node tables the caller's program stacks over this one (a fleet tick's
+    vmap over tenants), for the room `domain_sum` reckons with."""
     if ecfg is None:
         ecfg = default_engine_config()
     ecfg = EngineConfig(*[jnp.asarray(x, jnp.float32) for x in ecfg])
@@ -235,5 +243,9 @@ def build_cycle(
     ELD = eligible_domains(static.node_match, tables.classes, tables.nodes, D)
     WCOLS = symmetric_weight_cols(tables.classes, S, hard_weight)
     WSYM = weighted_per_node(WCOLS, M)
+    K = tables.nodes.domain.shape[1]
+    SAME = same_domain(tables.nodes) \
+        if domain_sum(N, K, copies) == "product" else None
     return CycleArrays(static=static, TM=TM, has_anti=has_anti, CNT=CNT,
-                       HOLD=HOLD, ELD=ELD, WCOLS=WCOLS, WSYM=WSYM, ecfg=ecfg)
+                       HOLD=HOLD, ELD=ELD, WCOLS=WCOLS, WSYM=WSYM, ecfg=ecfg,
+                       SAME=SAME)

@@ -164,7 +164,8 @@ def preempt_for_pod(
     # feasibility with all victims gone
     req_p = tables.reqs.vec[classes.rid[cls]]
     fit = _fit(req_p[None, :], nodes.alloc - used_wo) & nodes.valid
-    aff_ok, anti_ok = affinity_rows(cls, classes, terms, cyc.TM, CNT_wo, HOLD_wo, nodes, D)
+    aff_ok, anti_ok = affinity_rows(cls, classes, terms, cyc.TM, CNT_wo,
+                                    HOLD_wo, nodes, D, same=cyc.SAME)
     spread_ok = spread_row(cls, classes, terms, cyc.TM, CNT_wo, cyc.ELD,
                            cyc.static.node_match[cls], nodes, D)
     host_ok = (node_name_req < 0) | (nodes.name_id == node_name_req)

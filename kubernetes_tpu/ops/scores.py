@@ -84,25 +84,16 @@ def weighted_per_node(WCOLS: Array, M: Array) -> Array:
                    precision=jax.lax.Precision.HIGHEST)
 
 
-def sym_affinity_contrib(
-    cls: Array,
-    TM: Array,          # [S, SC]
-    WSYM: Array,        # [S, N] live signed weights
-    terms: TermTable,
-    nodes: NodeArrays,
-    D: int,
-) -> Array:
+def sym_affinity_contrib(cls: Array, TM: Array, sym: Array) -> Array:
     """[N] f32 raw symmetric contribution for one incoming pod: for every term
-    s the pod MATCHES (TM[s, cls]), credit every node sharing the topology
-    domain of a contributing existing pod (processTerm's fixed-term spreading
-    over same-topology nodes, interpod_affinity.go:87-117). Added to the raw
-    preferred-affinity counts BEFORE min-max normalization."""
-    S = TM.shape[0]
-    dom, has_key = domain_of_term(nodes, terms.topo_key)  # [S, N]
-    seg = domain_agg(WSYM, dom, D)                        # [S, D+1] (f32 sum)
-    per_term = jnp.take_along_axis(seg, jnp.where(dom >= 0, dom, D), axis=1)
-    credit = jnp.where(TM[:, cls][:, None] & has_key, per_term, 0.0)
-    return credit.sum(0)
+    s the pod MATCHES (TM [S, SC] at cls), credit every node sharing the
+    topology domain of a contributing existing pod (processTerm's fixed-term
+    spreading over same-topology nodes, interpod_affinity.go:87-117). `sym`
+    [S, N] is WSYM, the live signed weights, summed over each node's domain
+    of the term's key (interpod.in_domain_sums; 0 where the node lacks it).
+    Added to the raw preferred-affinity counts BEFORE min-max
+    normalization."""
+    return jnp.where(TM[:, cls][:, None], sym, 0.0).sum(0)
 
 
 def even_spread_soft_row(

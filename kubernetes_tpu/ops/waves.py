@@ -307,7 +307,7 @@ def _escape_cap(tables, cyc, state, r, table):
         ats = classes.aff_terms[c]
         active = ats >= 0
         tot = in_domain_counts(ats, tables.terms, state.CNT, tables.nodes,
-                               D, table).tot
+                               D, table, cyc.SAME)[1]
         return active.any() & (jnp.sum(jnp.where(active, tot, 0)) == 0)
 
     escape = jax.vmap(one)(jnp.arange(classes.valid.shape[0]))

@@ -734,6 +734,11 @@ class SchedulerTelemetry:
             agg = dims.affinity_agg(engine)
             if agg is not None:
                 rec["affinity_agg"] = agg
+            # and how that program sums a table over topology domains
+            # (state/dims.py domain_sum)
+            form = dims.domain_sum(engine)
+            if form is not None:
+                rec["domain_sum"] = form
         if stats is not None:
             rec["stats"] = {
                 "attempted": stats.attempted,

@@ -44,6 +44,35 @@ def affinity_agg(rows: int, slots: int, S: int) -> str:
     return "term" if rows * slots >= S else "row"
 
 
+#: HBM a cycle's K same-domain matrices may take: an eighth of the 16 GB of
+#: the smallest chip this runs on (TPU v5e)
+DOMAIN_SUM_MAX_BYTES = 2 << 30
+
+
+def domain_sum(N: int, K: int, copies: int = 1) -> str:
+    """How a program sums a per-node [rows, N] table over each node's
+    topology domain (ops/interpod.py in_domain_sums): "product" — rows times
+    the key's [N, N] 0/1 same-domain matrix in bf16 on the MXU, the K
+    matrices built once a cycle (CycleArrays.SAME) — or "scatter" — a
+    scatter-add into [rows, D+1] and a gather back. Static shapes only: the
+    choice is per compiled program.
+
+    The arithmetic (TPU v5e; PERF.md section 6, PR 42): the scatter form is
+    serial in its updates, rows x N x ~29 ns (19 a scatter-add, 10 a
+    gather); the product reads K x N x N x 2 bytes at 819 GB/s and
+    multiplies 3 digits x rows x K x N x N x 2 operations at 197 TFLOP/s: at
+    N 5,120, K 4 that is 0.26 ms + 0.002 ms a row against 0.15 ms a row, so
+    the product wins from 2 rows up (at N 1,024 from 1), and every caller
+    sums at least the 2 S rows of HOLD and WSYM (S >= 8). `rows` therefore
+    never decides; room does: the K matrices must fit beside the state.
+    copies x K x N x N x 2 bytes <= DOMAIN_SUM_MAX_BYTES (`copies`: the node
+    tables one program stacks, a fleet tick's tenants): N <= 16,384 at K 4
+    (2 GiB; the flagship's N 5,120: 210 MB). Above it the scatter form
+    stays."""
+    fits = copies * K * N * N * 2 <= DOMAIN_SUM_MAX_BYTES
+    return "product" if fits else "scatter"
+
+
 @dataclass(frozen=True)
 class Dims:
     """All array capacities. Fields are hashable/static for jit."""
@@ -111,6 +140,12 @@ class Dims:
             return None
         return affinity_agg(rows, self.AT + self.AN + self.PAT + self.PAN,
                             self.S)
+
+    def domain_sum(self, engine: str) -> Optional[str]:
+        """`domain_sum` of the program `engine` runs at these capacities, for
+        the flight recorder. None for a fleet tick, whose dispatches each
+        stack their own number of tenants."""
+        return None if engine == "fleet" else domain_sum(self.N, self.K)
 
     def union(self, other: Optional["Dims"]) -> "Dims":
         """Field-wise max of two capacity sets — the shared FLEET bucket K

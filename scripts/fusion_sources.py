@@ -6,12 +6,13 @@ fusion whose name or result type matches, the innermost frames of the
 program's own code that its instructions carry (`stack_frame_id` in the
 compiled module's text). At the flagship's capacities the fusion names are
 the ones the chip's trace shows (`breakdown.device_ops`: checked on PR 30's
-traced runs, and again on PR 35's), so a line's `fusion.823 s32[368640]` becomes
-`interpod.py:_in_domain:150`, the gather that ends the round's `[S, N]`
+traced runs, and again on PR 35's), so PR 41's line's `fusion.695 s32[368640]`
+became `interpod.py:_in_domain:150`, the gather that ended the round's `[S, N]`
 in-domain count table (a `jit(take_along_axis)` traced twice keeps its FIRST
-caller's frame: the `hold` gather beside it reads the same line), and
-`'s32\\[6553'`, the per-class aggregates PR 35 took out, finds nothing. Takes
-2-6 minutes for the flagship cycle.
+caller's frame: the `hold` gather beside it read the same line); since PR 42
+that table is a product (`interpod.py:_same_domain_product`) and
+`'s32\\[3686'` finds nothing, as `'s32\\[6553'`, the per-class aggregates
+PR 35 took out. Takes 2-6 minutes for the flagship cycle.
 
     JAX_PLATFORMS=cpu python3 scripts/fusion_sources.py 's32\\[3686' 'f32\\[3686'
     JAX_PLATFORMS=cpu python3 scripts/fusion_sources.py --preempt 8 'pred\\[8,65536\\]'

@@ -88,6 +88,9 @@ def test_sharded_cycle_matches_unsharded(request, which, engine):
     seeds' products shard along N)."""
     tables, pending, existing, uk, ev, d = request.getfixturevalue(which)
     D = d.D
+    # the in-domain sums are products against the same-domain matrices here
+    # (state/dims.py domain_sum): they contract the sharded node axis
+    assert d.domain_sum(engine) == "product"
 
     fn = jax.jit(lambda t, p, e, u, v: _cycle(t, p, e, u, v, D, engine))
 
@@ -114,18 +117,30 @@ def test_sharded_cycle_matches_unsharded(request, which, engine):
 
 
 def test_sharded_seeds_match_unsharded(populated):
-    """build_cycle's CNT, HOLD and WSYM, and the in-domain count table built
-    from CNT (interpod.term_domain_counts: cnt [S, N], tot [S]), under the
-    node-sharded mesh, bit for bit those of one device — and not all zero."""
+    """build_cycle's CNT, HOLD and WSYM, and the in-domain table built from
+    them (interpod.term_domain_counts: cnt, hold, sym [S, N], tot [S]) as a
+    product against the same-domain matrices (which contracts the SHARDED
+    node axis: XLA closes it with a reduction across the mesh), under the
+    node-sharded mesh, bit for bit those of one device, and the scatter
+    form's — and not all zero."""
     from kubernetes_tpu.ops.interpod import term_domain_counts
 
     tables, _pending, existing, uk, ev, d = populated
+    assert d.domain_sum("waves") == "product"
 
     @jax.jit
     def fn(t, e):
         cyc = build_cycle(t, e, uk, ev, d.D)
-        table = term_domain_counts(t.terms, cyc.CNT, t.nodes, d.D)
-        return cyc.CNT, cyc.HOLD, cyc.WSYM, table.cnt, table.tot
+        assert cyc.SAME is not None
+        table = term_domain_counts(t.terms, cyc.CNT, cyc.HOLD, cyc.WSYM,
+                                   t.nodes, d.D, cyc.SAME)
+        return cyc.CNT, cyc.HOLD, cyc.WSYM, *table
+
+    @jax.jit
+    def scatter(t, e):
+        cyc = build_cycle(t, e, uk, ev, d.D)
+        return tuple(term_domain_counts(t.terms, cyc.CNT, cyc.HOLD, cyc.WSYM,
+                                        t.nodes, d.D))
 
     ref = jax.tree.map(np.asarray, fn(tables, existing))
     mesh = make_mesh(8)
@@ -133,6 +148,10 @@ def test_sharded_seeds_match_unsharded(populated):
                                       replicate(existing, mesh)))
     assert all(a.any() for a in ref)
     for a, b in zip(got, ref):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(got[3:], jax.tree.map(np.asarray,
+                                          scatter(tables, existing))):
+        assert a.dtype == b.dtype
         np.testing.assert_array_equal(a, b)
 
 

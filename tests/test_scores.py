@@ -390,13 +390,16 @@ def test_term_table_matches_numpy_loop(case):
 
     tables, cyc, _pe, d = _table_state(case)
 
-    @jax.jit
-    def device(t, CNT):
-        dom, _ = domain_of_term(t.nodes, t.terms.topo_key)
-        return (domain_agg(CNT, dom, d.D),
-                term_domain_counts(t.terms, CNT, t.nodes, d.D))
+    assert d.domain_sum("waves") == "product" and cyc.SAME is not None
 
-    seg, table = jax.tree.map(np.asarray, device(tables, cyc.CNT))
+    @jax.jit
+    def device(t, cyc):
+        dom, _ = domain_of_term(t.nodes, t.terms.topo_key)
+        return (domain_agg(cyc.CNT, dom, d.D),
+                term_domain_counts(t.terms, cyc.CNT, cyc.HOLD, cyc.WSYM,
+                                   t.nodes, d.D, cyc.SAME))
+
+    seg, table = jax.tree.map(np.asarray, device(tables, cyc))
     CNT = np.asarray(cyc.CNT)
     key = np.asarray(tables.terms.topo_key)
     domain = np.asarray(tables.nodes.domain)
@@ -444,15 +447,17 @@ def test_affinity_rows_with_table_equal_per_row(case):
     def rows(table):
         def one(c):
             aff, anti = affinity_rows(c, classes, terms, cyc.TM, cyc.CNT,
-                                      cyc.HOLD, nodes, d.D, table)
+                                      cyc.HOLD, nodes, d.D, table, cyc.SAME)
             soft = soft_affinity_row(c, classes, terms, cyc.CNT, nodes, d.D,
-                                     TM=cyc.TM, WSYM=cyc.WSYM, table=table)
+                                     TM=cyc.TM, WSYM=cyc.WSYM, table=table,
+                                     same=cyc.SAME)
             return aff, anti, soft
         return jax.vmap(one)(jnp.arange(SC))
 
     per_row = jax.tree.map(np.asarray, jax.jit(lambda: rows(None))())
     from_table = jax.tree.map(np.asarray, jax.jit(lambda: rows(
-        term_domain_counts(terms, cyc.CNT, nodes, d.D)))())
+        term_domain_counts(terms, cyc.CNT, cyc.HOLD, cyc.WSYM, nodes, d.D,
+                           cyc.SAME)))())
     for name, a, b in zip(("affinity_ok", "anti_ok", "soft"),
                           per_row, from_table):
         assert a.dtype == b.dtype and np.array_equal(a, b), (case, name)
@@ -538,3 +543,160 @@ def test_affinity_agg_is_chosen_from_dims(dims, engine, want):
     """rows x (AT + AN + PAT + PAN) aggregates against S: the choice the
     flight recorder reports beside `bucket`."""
     assert Dims(**dims).affinity_agg(engine) == want
+
+
+# --------------------------------------------------------------------------- #
+# the in-domain sum's two forms (ops/interpod.py in_domain_sums; ISSUE 42):
+# rows times the key's same-domain matrix on the MXU ("product") against the
+# scatter-add into [A, D+1] and the gather back ("scatter"), bit for bit
+# --------------------------------------------------------------------------- #
+
+_SUM_CASES = ["key-absent-on-some-nodes", "keyless-rows", "invalid-nodes",
+              "hostname-key", "sum-past-2^16", "signed-weights",
+              "what-if-lanes"]
+
+
+def _sum_inputs(case, seed):
+    """(nodes, D, keys [A], rows [A, N] or [lanes, A, N]) for one hard case;
+    every case keeps the others' features at a lower dose."""
+    from types import SimpleNamespace
+
+    rng = np.random.default_rng(seed)
+    A, N, K = 12, 96, 4
+    absent = 0.5 if case == "key-absent-on-some-nodes" else 0.1
+    dom = np.stack([rng.integers(0, 4, N), rng.integers(0, 12, N),
+                    rng.permutation(N), rng.integers(0, 3, N)], 1)
+    dom = np.where(rng.random((N, K)) < absent, -1, dom)
+    dom[:, 2] = np.arange(N)           # hostname: a domain a node, D = N
+    valid = rng.random(N) > (0.4 if case == "invalid-nodes" else 0.05)
+    keys = rng.integers(-1 if case != "keyless-rows" else -3, K, A)
+    keys = np.maximum(keys, -1)
+    if case == "hostname-key":
+        keys[:] = 2
+    hi = 9000 if case == "sum-past-2^16" else 40
+    rows = rng.integers(0, hi, (A, N))
+    dtype = np.int32
+    if case == "signed-weights":       # WSYM: integer-valued f32, signed
+        rows, dtype = rng.integers(-100 * 40, 100 * 40, (A, N)), np.float32
+    if case == "what-if-lanes":        # a lane's own [S, N] survivors' counts
+        rows = rng.integers(0, hi, (8, A, N))
+    nodes = SimpleNamespace(domain=jnp.asarray(dom, jnp.int32),
+                            valid=jnp.asarray(valid))
+    return nodes, N, jnp.asarray(keys, jnp.int32), jnp.asarray(rows, dtype)
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("case", _SUM_CASES)
+def test_domain_product_equals_scatter(case, seed):
+    """The product form against the scatter form AND a plain loop over terms
+    and nodes, element for element, on the cases that could tell them apart:
+    a key absent on some nodes, rows with no key, invalid nodes, a hostname
+    key (D = N), a domain whose sum passes 2^16 (three bf16 digits), signed
+    f32 weights, and the what-if's lane-batched rows (a vmap over lanes)."""
+    from kubernetes_tpu.ops.interpod import in_domain_sums, same_domain
+
+    nodes, D, keys, rows = _sum_inputs(case, seed)
+    same = same_domain(nodes)
+    assert same.dtype == jnp.bfloat16 and same.shape == (4, D, D)
+
+    def both(r):
+        return (in_domain_sums(r, keys, nodes, D, same),
+                in_domain_sums(r, keys, nodes, D))
+
+    f = jax.vmap(both) if rows.ndim == 3 else both
+    product, scatter = jax.tree.map(np.asarray, jax.jit(f)(rows))
+    assert product.dtype == scatter.dtype == rows.dtype
+    np.testing.assert_array_equal(product, scatter)
+
+    dom = np.where(np.asarray(nodes.valid)[:, None],
+                   np.asarray(nodes.domain), -1)
+    r = np.asarray(rows).reshape((-1,) + rows.shape[-2:]).astype(np.int64)
+    want = np.zeros_like(r)
+    for a, k in enumerate(np.asarray(keys)):
+        if k < 0:
+            continue
+        for n in np.flatnonzero(dom[:, k] >= 0):
+            want[:, a, n] = r[:, a, dom[:, k] == dom[n, k]].sum(-1)
+    np.testing.assert_array_equal(product.reshape(want.shape), want)
+    assert want.any()
+    if case == "sum-past-2^16":
+        assert want.max() > 2 ** 16
+    if case == "signed-weights":
+        assert want.min() < 0 < want.max()
+    if case == "keyless-rows":
+        assert (np.asarray(keys) < 0).sum() >= 2
+
+
+@pytest.mark.parametrize("case", _TABLE_CASES)
+def test_program_with_product_equals_program_with_scatter(case):
+    """One state's Filter mask, its components and the Score matrix, with
+    the cycle's same-domain matrices (what build_cycle carries at these
+    shapes) against the same cycle without them (the scatter form), and the
+    state's table in both forms: bit for bit."""
+    from kubernetes_tpu.ops import assign
+
+    tables, cyc, pe, d = _table_state(case)
+    assert cyc.SAME is not None
+
+    @jax.jit
+    def run(cyc):
+        state = assign.initial_state(tables, cyc)
+        return (assign.feasible_matrix(tables, cyc, pe),
+                assign.score_matrix(tables, cyc, pe),
+                assign.mask_components(tables, cyc, pe),
+                assign.state_affinity_table(
+                    tables, cyc, state, tables.classes.valid.shape[0]))
+
+    product = jax.tree.map(np.asarray, run(cyc))
+    scatter = jax.tree.map(np.asarray, run(cyc._replace(SAME=None)))
+    for a, b in zip(jax.tree.leaves(product), jax.tree.leaves(scatter)):
+        assert a.dtype == b.dtype and np.array_equal(a, b), case
+    table = product[3]
+    if case != "no-term":
+        assert table.cnt.any() and product[0].any()
+    if case == "flagship-roles":
+        assert table.hold.any() and table.sym.any()
+
+
+@pytest.mark.parametrize("N,K,copies,want", [
+    # the benchmark's node axes: 210 MB and 8 MB of matrices
+    (5120, 4, 1, "product"), (1024, 4, 1, "product"), (8, 4, 1, "product"),
+    # the last bucket whose four matrices fit 2 GiB, and the next one up
+    (16384, 4, 1, "product"), (18432, 4, 1, "scatter"),
+    (53248, 4, 1, "scatter"),
+    # more keys, or a fleet tick's stacked tenants, take the room sooner
+    (16384, 8, 1, "scatter"), (5120, 4, 16, "scatter"),
+    (1024, 4, 16, "product"),
+])
+def test_domain_sum_is_chosen_from_shapes(N, K, copies, want):
+    """copies x K x N x N x 2 bytes against DOMAIN_SUM_MAX_BYTES: the rule
+    build_cycle applies and the flight recorder reports; rows do not enter
+    (state/dims.py domain_sum's docstring has the arithmetic)."""
+    from kubernetes_tpu.state.dims import domain_sum
+
+    assert domain_sum(N, K, copies) == want
+    if copies == 1:
+        d = Dims(N=N, K=K)
+        assert d.domain_sum("waves") == d.domain_sum("extender") \
+            == d.domain_sum("scan") == want
+        assert d.domain_sum("fleet") is None
+
+
+def test_build_cycle_carries_the_matrices_only_where_the_rule_says(
+        monkeypatch):
+    from kubernetes_tpu.ops.lattice import build_cycle
+    from kubernetes_tpu.state import dims as dims_mod
+
+    nodes, existing, pending = _table_cluster("flagship-roles")
+    tables, ex, _pe, d, uk, ev = _encode(nodes, existing, pending, E=512)
+
+    def shapes():
+        return jax.eval_shape(
+            lambda t, e: build_cycle(t, e, uk, ev, d.D), tables, ex)
+
+    cyc = shapes()
+    assert cyc.SAME.shape == (d.K, d.N, d.N)
+    assert cyc.SAME.dtype == jnp.bfloat16
+    monkeypatch.setattr(dims_mod, "DOMAIN_SUM_MAX_BYTES",
+                        d.K * d.N * d.N * 2 - 1)
+    assert shapes().SAME is None and d.domain_sum("waves") == "scatter"

@@ -212,14 +212,15 @@ _WAVE_SHAPES = {
                          "requeue/snapshot", "requeue/snapshot/patch",
                          "requeue/snapshot/patch/upload",
                          "requeue/snapshot/prepare"] + _FIRST_SNAPSHOT,
-             _HEAD + ["bucket", "affinity_agg", "stats", "device_split",
+             _HEAD + ["bucket", "affinity_agg", "domain_sum", "stats",
+                      "device_split",
                       "children"] + _GC + ["snapshot_mode", "waits",
                       "assumed_outstanding"] + _PASS + ["minor_faults",
                                                         "seq"]),
     "micro": (_BULK,
               _BINDING + _FIRST_SNAPSHOT + ["snapshot/upload"],
-              _HEAD + ["micro", "bucket", "affinity_agg", "stats",
-                       "device_split", "children"] + _GC + [
+              _HEAD + ["micro", "bucket", "affinity_agg", "domain_sum",
+                       "stats", "device_split", "children"] + _GC + [
                            "snapshot_mode", "waits", "assumed_outstanding",
                            "minor_faults", "seq"]),
     "paused": (["pump", "paused"], None,
@@ -227,12 +228,13 @@ _WAVE_SHAPES = {
     "abandoned": (["pump", "pop", "snapshot", "prewarm", "dispatch",
                    "readback", "requeue"],
                   _FIRST_SNAPSHOT,
-                  _HEAD + ["bucket", "affinity_agg", "stats",
+                  _HEAD + ["bucket", "affinity_agg", "domain_sum", "stats",
                            "supervisor_events", "children"] + _GC + [
                                "waits", "assumed_outstanding", "seq"]),
     "raises": (_BULK[:8] + ["exception"],
                _BINDING + _FIRST_SNAPSHOT,
-               _HEAD + ["bucket", "affinity_agg", "stats", "device_split",
+               _HEAD + ["bucket", "affinity_agg", "domain_sum", "stats",
+                        "device_split",
                         "children"] + _GC + ["waits", "assumed_outstanding",
                                              "exception", "seq"]),
 }
@@ -276,6 +278,31 @@ class TestRecordShape:
         assert list(rec) == keys
         if "waits" in rec:
             assert set(rec["waits"]) == {"queue", "confirm"}
+        if "bucket" in rec:
+            assert rec["domain_sum"] == "product"
+
+    @pytest.mark.parametrize("engine,dims,want", [
+        ("waves", dict(N=5120, S=72, SC=64), "product"),
+        ("extender", dict(N=5120, S=72, SC=64, P=8), "product"),
+        ("scan", dict(N=1024), "product"),
+        # four [N, N] bf16 matrices past 2 GiB: the scatter form stays
+        ("waves", dict(N=53248, S=72, SC=64), "scatter"),
+        # a fleet tick's dispatches stack their own numbers of tenants
+        ("fleet", dict(N=1024), None),
+    ])
+    def test_record_says_how_its_program_sums_over_domains(
+            self, engine, dims, want):
+        """`domain_sum` beside `affinity_agg`, from the same Dims
+        (state/dims.py domain_sum): which form of the in-domain sum the
+        record's compiled program runs."""
+        from kubernetes_tpu.state.dims import Dims
+
+        tel = SchedulerTelemetry(enabled=True)
+        span = tel.wave_span()
+        span.mark("pump")
+        rec = tel.finish_wave(span, engine=engine, dims=Dims(**dims))
+        assert rec.get("domain_sum") == want
+        assert rec["bucket"]["N"] == dims["N"]
 
 
 class TestFirstSeenAcrossRequeue:

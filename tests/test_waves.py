@@ -603,12 +603,18 @@ def test_sorted_admission_equals_the_parents_gather_form(seed, shape, twist):
         assert np.asarray(want[2]).any()
 
 
-def _big_indexed_ops(jaxpr, floor, in_loop=False):
-    """(primitive, file:line) of every gather / scatter* equation inside a
-    `while` body whose index operand holds `floor` or more index vectors."""
-    import math
-
+def _file_line(eqn):
     from jax._src import source_info_util
+
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
+    return f"{fr.file_name.rsplit('/', 1)[-1]}:{fr.start_line}" if fr else "?"
+
+
+def _big_indexed_ops(jaxpr, floor, in_loop=False, where=_file_line):
+    """(primitive, where(eqn)) of every gather / scatter* equation inside a
+    `while` body whose index operand holds `floor` or more index vectors;
+    `where` says file:line of the innermost frame of the program's own."""
+    import math
 
     found = []
     for eqn in jaxpr.eqns:
@@ -616,15 +622,13 @@ def _big_indexed_ops(jaxpr, floor, in_loop=False):
         if in_loop and (prim == "gather" or prim.startswith("scatter")):
             idx = eqn.invars[1].aval.shape
             if math.prod(idx[:-1]) >= floor:
-                fr = source_info_util.user_frame(eqn.source_info.traceback)
-                found.append((prim, f"{fr.file_name.rsplit('/', 1)[-1]}:"
-                                    f"{fr.start_line}" if fr else "?"))
+                found.append((prim, where(eqn)))
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (tuple, list)) else (v,)):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    found += _big_indexed_ops(sub, floor,
-                                              in_loop or prim == "while")
+                    found += _big_indexed_ops(
+                        sub, floor, in_loop or prim == "while", where)
     return found
 
 
@@ -654,6 +658,98 @@ def test_no_sc_by_n_array_is_fetched_through_a_permutation():
                            d.SC * d.N)
     ours = sorted(o for o in ops if o[1].startswith("waves.py"))
     assert len(ours) == _SC_BY_N_INDEXED_LEFT, ours
+
+
+def _round_jaxpr(case):
+    nodes, existing, pending = _recorded_case(case)
+    tables, ex, pe, uk, ev, d = _encode(nodes, existing, pending)
+
+    def f(tables, ex, pe, uk, ev):
+        cyc = build_cycle(tables, ex, uk, ev, d.D)
+        return assign_waves(tables, cyc, pe, initial_state(tables, cyc),
+                            return_waves=True)
+
+    return jax.make_jaxpr(f)(tables, ex, pe, uk, ev).jaxpr, d
+
+
+def _asked_by(eqn):
+    """The functions of ops/interpod.py and ops/scores.py on the equation's
+    traceback, innermost first, '' where neither file is on it: WHO asked
+    for the indexed operation."""
+    from jax._src import source_info_util
+
+    return ">".join(
+        fr.function_name
+        for fr in source_info_util.user_frames(eqn.source_info.traceback)
+        if fr.file_name.endswith(("ops/interpod.py", "ops/scores.py")))
+
+
+def test_no_s_by_n_table_is_summed_through_a_scatter_in_a_product_round(
+        monkeypatch):
+    """Structural guard (ISSUE 42): where the program's Dims choose the
+    product (state/dims.py domain_sum), the compiled round (the `while` body
+    of `assign_waves`) holds NO scatter-add and NO gather with S x N or more
+    index vectors that ops/interpod.py's in-domain sum or ops/scores.py's
+    symmetric weights asked for: the round's count table, `hold` and the
+    weights are one product against the cycle's same-domain matrices. The
+    parent had six (three scatter-adds into [S, D + 1], three gathers back:
+    41 ms of the flagship cycle's 82, `/PERF.md` section 6, PR 42); the same
+    count finds the scatter form's pair once the rule is made to fall back.
+    What the two files still ask for at that size is topology spread's own
+    aggregate over the nodes ELIGIBLE for a class (`domain_agg` for hard
+    spread's Filter row and quota and for the soft score: they read the
+    minimum over DOMAINS, which no per-node sum gives: ROADMAP A3)."""
+    from kubernetes_tpu.state import dims as dims_mod
+
+    def asked(jaxpr, floor):
+        ops = _big_indexed_ops(jaxpr, floor, where=_asked_by)
+        return sorted(o for o in ops if o[1])
+
+    jaxpr, d = _round_jaxpr("bound-64x360")
+    assert d.domain_sum("waves") == "product"
+    assert d.affinity_agg("waves") == "term"
+    floor = d.S * d.N
+    spread = asked(jaxpr, floor)
+    assert spread == [("scatter-add", "domain_agg")] * 2 + [
+        ("scatter-add", "domain_agg>even_spread_soft_row")], spread
+
+    monkeypatch.setattr(dims_mod, "DOMAIN_SUM_MAX_BYTES", 0)
+    assert d.domain_sum("waves") == "scatter"
+    jaxpr, _ = _round_jaxpr("bound-64x360")
+    left = [o for o in asked(jaxpr, floor) if o not in spread]
+    # the three tables ride ONE stacked sum: a gather and a scatter-add
+    assert left == [
+        ("gather", "in_domain_sums>term_domain_counts"),
+        ("scatter-add", "domain_agg>in_domain_sums>term_domain_counts"),
+    ], left
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_waves_with_product_equal_waves_with_scatter(seed, monkeypatch):
+    """The whole engine under either form of the in-domain sum: placements,
+    admission waves and the final counts identical (the sums are the same
+    integers)."""
+    from kubernetes_tpu.state import dims as dims_mod
+
+    nodes, existing, pending = _affinity_cluster(seed)
+    tables, ex, pe, uk, ev, d = _encode(nodes, existing, pending)
+    assert d.domain_sum("waves") == "product"
+    res_p, waves_p = _run("waves", tables, ex, pe, uk, ev, d.D)
+
+    monkeypatch.setattr(dims_mod, "DOMAIN_SUM_MAX_BYTES", 0)
+    jax.clear_caches()
+    try:
+        res_s, waves_s = _run("waves", tables, ex, pe, uk, ev, d.D)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    for a, b in ((res_p.node, res_s.node), (waves_p, waves_s),
+                 (res_p.state.CNT, res_s.state.CNT),
+                 (res_p.state.HOLD, res_s.state.HOLD),
+                 (res_p.state.WSYM, res_s.state.WSYM),
+                 (res_p.state.used, res_s.state.used)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert (np.asarray(res_p.node) >= 0).any()
 
 
 if __name__ == "__main__":
