@@ -123,7 +123,7 @@ class InterPodAffinity(FilterPlugin, ScorePlugin):
 
     def score_matrix(self, state: CycleState, ctx: TensorContext):
         tables, cyc = ctx.tables, ctx.cyc
-        D = cyc.ELD.shape[2] - 1
+        D = cyc.D
         return jax.vmap(
             lambda c: soft_affinity_row(
                 c, tables.classes, tables.terms, cyc.CNT, tables.nodes, D,
@@ -140,11 +140,11 @@ class PodTopologySpread(FilterPlugin, ScorePlugin):
 
     def score_matrix(self, state: CycleState, ctx: TensorContext):
         tables, cyc = ctx.tables, ctx.cyc
-        D = cyc.ELD.shape[2] - 1
+        D = cyc.D
         return jax.vmap(
             lambda c: even_spread_soft_row(
                 c, tables.classes, tables.terms, cyc.CNT, tables.nodes,
-                cyc.static.node_match[c], D)
+                cyc.static.node_match[c], D, cyc.SAME)
         )(ctx.pending.cls)
 
 
@@ -156,7 +156,7 @@ class SelectorSpread(ScorePlugin):
 
     def score_matrix(self, state: CycleState, ctx: TensorContext):
         tables, cyc = ctx.tables, ctx.cyc
-        D = cyc.ELD.shape[2] - 1
+        D = cyc.D
         return jax.vmap(
             lambda c: selector_spread_row(
                 c, tables.classes, cyc.CNT, tables.nodes, tables.zone_keys, D)

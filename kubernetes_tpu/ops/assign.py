@@ -35,7 +35,7 @@ from .interpod import (TermCounts, affinity_rows, soft_affinity_row,
 from .lattice import CycleArrays
 from .ports import port_conflict_row
 from .scores import even_spread_soft_row, selector_spread_row
-from .topospread import spread_row
+from .topospread import SpreadCounts, spread_counts, spread_row
 from .volumes import volume_components_row, volume_ok_row
 
 
@@ -162,7 +162,26 @@ def state_affinity_table(
     if affinity_agg(rows, slots, cyc.TM.shape[0]) == "row":
         return None
     return term_domain_counts(tables.terms, state.CNT, state.HOLD, state.WSYM,
-                              tables.nodes, cyc.ELD.shape[2] - 1, cyc.SAME)
+                              tables.nodes, cyc.D, cyc.SAME)
+
+
+def state_spread_counts(
+    tables: ClusterTables, cyc: CycleArrays, state: AssignState, rows: int
+) -> SpreadCounts | None:
+    """What the row functions below take as `spread`: topology spread's
+    counts of `state` for EVERY (class, slot), one in-domain sum of SC x TS
+    rows (topospread.spread_counts), where a program that evaluates `rows`
+    classes against the state would ask for at least as many row by row — the
+    rule pod affinity's table goes by — else None and each row sums its own
+    class's slots. The Filter row, the soft score and the waves round's
+    admission cap all read this one."""
+    classes = tables.classes
+    SC, TS = classes.tsc_term.shape
+    if affinity_agg(rows, TS, SC * TS) == "row":
+        return None
+    return spread_counts(jnp.arange(SC), classes, tables.terms, state.CNT,
+                         cyc.static.node_match, cyc.ELN, tables.nodes, cyc.D,
+                         cyc.SAME)
 
 
 def mask_context_row(
@@ -173,6 +192,7 @@ def mask_context_row(
     node_name_req: Array,
     valid: Array,
     table: TermCounts | None = None,
+    spread: SpreadCounts | None = None,
 ) -> Array:
     """The Filter components that are CONSTANT across a run of same-class
     replicas when the class is self-interaction-free (ops/runs.py): the
@@ -180,19 +200,20 @@ def mask_context_row(
     placed nodes, through terms such a class never reads), hard topology
     spread, spec.nodeName, and pod validity. The run-collapsed engine
     evaluates this once per RUN; pod_mask_row recomposes it per pod.
-    `table` is `state_affinity_table(state)` where the caller built one."""
+    `table` is `state_affinity_table(state)` and `spread`
+    `state_spread_counts(state)` where the caller built them."""
     from .lattice import _on
 
     nodes, classes, terms = tables.nodes, tables.classes, tables.terms
     ecfg = cyc.ecfg
-    D = cyc.ELD.shape[2] - 1
+    D = cyc.D
     aff_ok, anti_ok = affinity_rows(
         cls, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D, table,
         cyc.SAME)
     interpod_ok = (aff_ok & anti_ok) | ~_on(ecfg.f_interpod)
     spread_ok = spread_row(
-        cls, classes, terms, cyc.TM, state.CNT, cyc.ELD,
-        cyc.static.node_match[cls], nodes, D,
+        cls, classes, terms, cyc.TM, state.CNT, cyc.ELN,
+        cyc.static.node_match[cls], nodes, D, cyc.SAME, spread,
     ) | ~_on(ecfg.f_spread)
     host_ok = (node_name_req < 0) | (nodes.name_id == node_name_req) \
         | ~_on(ecfg.f_name)
@@ -267,6 +288,7 @@ def pod_mask_row(
     node_name_req: Array,
     valid: Array,
     table: TermCounts | None = None,
+    spread: SpreadCounts | None = None,
 ) -> Array:
     """Full Filter mask [N] for one pod against a given assume-state — the
     tensor analog of podFitsOnNode (generic_scheduler.go:628-706). Shared by
@@ -277,7 +299,7 @@ def pod_mask_row(
     conjunction, so the regrouping is exact."""
     return (
         mask_context_row(tables, cyc, state, cls, node_name_req, valid,
-                         table)
+                         table, spread)
         & mask_dynamic_row(tables, cyc, cls, state.used,
                            state.ppa, state.ppw, state.ppt,
                            state.vol_any, state.vol_rw)
@@ -300,14 +322,16 @@ def score_context_row(
     state: AssignState,
     cls: Array,
     table: TermCounts | None = None,
+    spread: SpreadCounts | None = None,
 ) -> ScoreContext:
     nodes, classes, terms = tables.nodes, tables.classes, tables.terms
-    D = cyc.ELD.shape[2] - 1
+    D = cyc.D
     soft_ip = soft_affinity_row(cls, classes, terms, state.CNT, nodes, D,
                                 TM=cyc.TM, WSYM=state.WSYM, table=table,
                                 same=cyc.SAME)
     even_soft = even_spread_soft_row(
-        cls, classes, terms, state.CNT, nodes, cyc.static.node_match[cls], D)
+        cls, classes, terms, state.CNT, nodes, cyc.static.node_match[cls], D,
+        cyc.SAME, spread)
     ssel = selector_spread_row(
         cls, classes, state.CNT, nodes, tables.zone_keys, D)
     return ScoreContext(soft_ip=soft_ip, even_soft=even_soft, ssel=ssel)
@@ -342,6 +366,7 @@ def score_row(
     state: AssignState,
     cls: Array,
     table: TermCounts | None = None,
+    spread: SpreadCounts | None = None,
 ) -> Array:
     """Full Score row [N] for one pod class against a live assume-state —
     prioritizeNodes' weighted sum (generic_scheduler.go:714-869) with the
@@ -349,7 +374,7 @@ def score_row(
     score-matrix surface."""
     return score_combine_row(
         tables, cyc, cls, state.used,
-        score_context_row(tables, cyc, state, cls, table))
+        score_context_row(tables, cyc, state, cls, table, spread))
 
 
 def feasible_matrix(
@@ -359,9 +384,12 @@ def feasible_matrix(
     (no assignment feedback) — findNodesThatFit (generic_scheduler.go:473) as
     one vmapped tensor, used for golden tests and the extender Filter verb."""
     state = initial_state(tables, cyc)
-    table = state_affinity_table(tables, cyc, state, pods.valid.shape[0])
+    P = pods.valid.shape[0]
+    table = state_affinity_table(tables, cyc, state, P)
+    spread = state_spread_counts(tables, cyc, state, P)
     return jax.vmap(
-        lambda c, nnr, v: pod_mask_row(tables, cyc, state, c, nnr, v, table)
+        lambda c, nnr, v: pod_mask_row(tables, cyc, state, c, nnr, v, table,
+                                       spread)
     )(pods.cls, pods.node_name_req, pods.valid)
 
 
@@ -387,8 +415,9 @@ def mask_components(
     """Decomposed feasibility against the initial state, vmapped over pods."""
     state = initial_state(tables, cyc)
     nodes, classes, terms = tables.nodes, tables.classes, tables.terms
-    D = cyc.ELD.shape[2] - 1
+    D = cyc.D
     table = state_affinity_table(tables, cyc, state, pods.valid.shape[0])
+    spread = state_spread_counts(tables, cyc, state, pods.valid.shape[0])
 
     def row(c, nnr, v):
         req_vec = tables.reqs.vec[classes.rid[c]]
@@ -406,8 +435,8 @@ def mask_components(
             c, classes, terms, cyc.TM, state.CNT, state.HOLD, nodes, D, table,
             cyc.SAME)
         spread_ok = spread_row(
-            c, classes, terms, cyc.TM, state.CNT, cyc.ELD,
-            cyc.static.node_match[c], nodes, D,
+            c, classes, terms, cyc.TM, state.CNT, cyc.ELN,
+            cyc.static.node_match[c], nodes, D, cyc.SAME, spread,
         )
         host_ok = (nnr < 0) | (nodes.name_id == nnr)
         vol_ok = volume_ok_row(tables, state.vol_any, state.vol_rw, c)
@@ -460,7 +489,8 @@ class ExplainResult(NamedTuple):
 
 def _explain_mask_row(tables: ClusterTables, cyc: CycleArrays,
                       state: AssignState, c: Array,
-                      table: TermCounts | None = None):
+                      table: TermCounts | None = None,
+                      spread: SpreadCounts | None = None):
     """The cheap half of attribution for ONE class against `state`: the 8
     class-granular predicate planes reduced to rejected-node counts
     (host/spec.nodeName is per-pod and folded by the caller) plus the
@@ -473,7 +503,7 @@ def _explain_mask_row(tables: ClusterTables, cyc: CycleArrays,
 
     nodes, classes, terms = tables.nodes, tables.classes, tables.terms
     ecfg = cyc.ecfg
-    D = cyc.ELD.shape[2] - 1
+    D = cyc.D
     nm = cyc.static.node_match[c]
     # static.mask = node_match ∧ taint_ok ∧ unsched_pass ∧ class-valid;
     # recover the taint/unschedulable plane by division (mask_components)
@@ -491,8 +521,8 @@ def _explain_mask_row(tables: ClusterTables, cyc: CycleArrays,
     aff_ok = aff_ok | ~_on(ecfg.f_interpod)
     anti_ok = anti_ok | ~_on(ecfg.f_interpod)
     spread_ok = spread_row(
-        c, classes, terms, cyc.TM, state.CNT, cyc.ELD,
-        cyc.static.node_match[c], nodes, D,
+        c, classes, terms, cyc.TM, state.CNT, cyc.ELN,
+        cyc.static.node_match[c], nodes, D, cyc.SAME, spread,
     ) | ~_on(ecfg.f_spread)
     planes = jnp.stack([nm, taints_ok, fit, ports_ok, aff_ok, anti_ok,
                         spread_ok, vol_ok])            # [8, N]
@@ -504,13 +534,14 @@ def _explain_mask_row(tables: ClusterTables, cyc: CycleArrays,
 
 def _explain_score_row(tables: ClusterTables, cyc: CycleArrays,
                        state: AssignState, c: Array,
-                       table: TermCounts | None = None):
+                       table: TermCounts | None = None,
+                       spread: SpreadCounts | None = None):
     """The EXPENSIVE half for one class: the composed score row and the
     context score components (soft inter-pod affinity's min/max
     normalization, even-spread, selector-spread — one extra full score
     pass per class, ~an engine wave-iteration's worth of work). Only
     evaluated under the failure-gated branch of explain_assignments."""
-    ctxs = score_context_row(tables, cyc, state, c, table)
+    ctxs = score_context_row(tables, cyc, state, c, table, spread)
     ctx = jnp.stack([ctxs.soft_ip, ctxs.even_soft, ctxs.ssel])  # [3, N]
     score = score_combine_row(tables, cyc, c, state.used, ctxs)
     return score, ctx
@@ -579,8 +610,10 @@ def explain_assignments(
     validn_scalar = jnp.sum(nv).astype(jnp.int32)
     i32 = jnp.int32
     any_failed = ((chosen < 0) & pods.valid).any()
-    table = state_affinity_table(tables, cyc, state,
-                                 P if granularity == "pod" else SC)
+    rows = P if granularity == "pod" else SC
+    # the state's two aggregates, each where its rule says a table pays
+    agg = (state_affinity_table(tables, cyc, state, rows),
+           state_spread_counts(tables, cyc, state, rows))
 
     def host_plane(nnr):
         return (nnr < 0) | (nodes.name_id == nnr) | ~_on(cyc.ecfg.f_name)
@@ -610,7 +643,7 @@ def explain_assignments(
 
     if granularity == "pod":
         def mrow(c, nnr):
-            r8, m8 = _explain_mask_row(tables, cyc, state, c, table)
+            r8, m8 = _explain_mask_row(tables, cyc, state, c, *agg)
             host_ok = host_plane(nnr)
             host_rej = jnp.sum(nv & ~host_ok).astype(i32)
             reasons = jnp.concatenate([r8[:7], host_rej[None], r8[7:]])
@@ -621,9 +654,9 @@ def explain_assignments(
 
         def pod_score(_):
             def row(c, nnr, ch):
-                _r8, m8 = _explain_mask_row(tables, cyc, state, c, table)
+                _r8, m8 = _explain_mask_row(tables, cyc, state, c, *agg)
                 full = m8 & host_plane(nnr)
-                sc_row, cx = _explain_score_row(tables, cyc, state, c, table)
+                sc_row, cx = _explain_score_row(tables, cyc, state, c, *agg)
                 topn, tops = _row_topk(
                     jnp.where(full, sc_row, -jnp.inf), K)
                 pn = jnp.where(ch >= 0, ch, topn[0])
@@ -638,7 +671,7 @@ def explain_assignments(
             any_failed, pod_score, cheap_score, None)
     else:
         r8, m8 = jax.vmap(
-            lambda c: _explain_mask_row(tables, cyc, state, c, table)
+            lambda c: _explain_mask_row(tables, cyc, state, c, *agg)
         )(jnp.arange(SC, dtype=jnp.int32))
         reasons9_c = jnp.concatenate(
             [r8[:, :7], jnp.zeros((SC, 1), i32), r8[:, 7:]], axis=1)
@@ -666,7 +699,7 @@ def explain_assignments(
 
         def class_score(_):
             sc_rows, cx = jax.vmap(
-                lambda c: _explain_score_row(tables, cyc, state, c, table)
+                lambda c: _explain_score_row(tables, cyc, state, c, *agg)
             )(jnp.arange(SC, dtype=jnp.int32))
             masked_c = jnp.where(m8, sc_rows, -jnp.inf)
             topn_c, tops_c = jax.vmap(
@@ -716,11 +749,12 @@ def score_matrix(
     weight-1 summed. Infeasible nodes score -inf."""
     state = initial_state(tables, cyc)
     table = state_affinity_table(tables, cyc, state, pods.valid.shape[0])
+    spread = state_spread_counts(tables, cyc, state, pods.valid.shape[0])
 
     def row(c, nnr, v):
-        mask = pod_mask_row(tables, cyc, state, c, nnr, v, table)
-        return jnp.where(mask, score_row(tables, cyc, state, c, table),
-                         -jnp.inf)
+        mask = pod_mask_row(tables, cyc, state, c, nnr, v, table, spread)
+        return jnp.where(
+            mask, score_row(tables, cyc, state, c, table, spread), -jnp.inf)
 
     return jax.vmap(row)(pods.cls, pods.node_name_req, pods.valid)
 

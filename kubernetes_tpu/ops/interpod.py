@@ -45,7 +45,13 @@ a second static choice, state/dims.py domain_sum, from N and K alone:
     element gathered back), kept for node axes whose K x N x N matrix has no
     room beside the state.
 
-The two are bit-equal (tests/test_scores.py).
+The two are bit-equal (tests/test_scores.py). Topology spread's counts go
+through the same function and the same choice: a constraint's CNT row zeroed
+on the nodes the class is not eligible for, summed over each node's domain
+(ops/topospread.py spread_counts, one sum of SC x TS rows a round), and the
+0/1 eligibility rows themselves once a cycle (eligible_in_domain). The
+minimum over a key's eligible DOMAINS that spread reads is the minimum over
+the nodes whose domain is eligible, so nothing is kept per domain.
 
 The predicate semantics (satisfiesPodsAffinityAntiAffinity :1421-1520):
   * affinity:  ∀ term: node-has-key ∧ domain-count > 0, with the first-pod
@@ -136,17 +142,13 @@ def domain_agg(
     cnt_rows: Array,   # [A, N] per-node counts for A terms
     dom: Array,        # [A, N] compact domain index (-1 absent)
     D: int,
-    eligible: Array | None = None,  # [N] or [A, N] node mask, optional
 ) -> Array:
     """Aggregate per-node counts over topology domains → [A, D+1] (slot D is
-    the discard bucket). Optionally restrict to eligible nodes (spread)."""
-    vals = cnt_rows
-    if eligible is not None:
-        vals = jnp.where(eligible, vals, 0)
+    the discard bucket)."""
     idx = jnp.where(dom >= 0, dom, D)
-    A = vals.shape[0]
-    seg = jnp.zeros((A, D + 1), vals.dtype)
-    return seg.at[jnp.arange(A)[:, None], idx].add(vals)
+    A = cnt_rows.shape[0]
+    seg = jnp.zeros((A, D + 1), cnt_rows.dtype)
+    return seg.at[jnp.arange(A)[:, None], idx].add(cnt_rows)
 
 
 class TermCounts(NamedTuple):

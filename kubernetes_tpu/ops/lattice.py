@@ -27,7 +27,7 @@ from .interpod import (class_node_hist, class_term_membership, per_node_counts,
 from .labels import node_term_matrix
 from .scores import image_locality_static, symmetric_weight_cols, weighted_per_node
 from .taints import taint_matrices, taint_toleration_score
-from .topospread import eligible_domains
+from .topospread import eligible_in_domain
 
 
 class EngineConfig(NamedTuple):
@@ -138,14 +138,23 @@ class CycleArrays(NamedTuple):
     has_anti: Array  # [SC, S] class anti-term membership
     CNT: Array       # [S, N] per-node term match counts (live carry seed)
     HOLD: Array      # [S, N] per-node anti-term holder counts (live carry seed)
-    ELD: Array       # [SC, TS, D+1] eligible domains per class × constraint
+    # [SC, TS, N] the node's domain of the constraint's key holds a node
+    # eligible for the class (topospread.eligible_in_domain)
+    ELN: Array
     WCOLS: Array     # [S, SC] f32 signed symmetric-preference weights per class
     WSYM: Array      # [S, N] f32 symmetric weight seed from existing pods
     ecfg: EngineConfig  # traced plugin composition (filters + score weights)
+    # [D, 0]: no bytes; its static length is the domain-axis capacity the
+    # cycle was built for (`D`), which no other array here has in its shape
+    domain_axis: Array
     # [K, N, N] bf16 same-domain matrices (interpod.same_domain) where
     # state/dims.py domain_sum chose "product" at these shapes, else None:
     # built here, once a cycle, so no round of an engine's loop rebuilds them
     SAME: Array | None = None
+
+    @property
+    def D(self) -> int:
+        return self.domain_axis.shape[0]
 
 
 def _safe_row_gather(M: Array, ids: Array, default: bool) -> Array:
@@ -240,12 +249,13 @@ def build_cycle(
     M = class_node_hist(existing, TM.shape[1], N)
     CNT = per_node_counts(TM, M)
     HOLD = per_node_counts(has_anti.T, M)
-    ELD = eligible_domains(static.node_match, tables.classes, tables.nodes, D)
     WCOLS = symmetric_weight_cols(tables.classes, S, hard_weight)
     WSYM = weighted_per_node(WCOLS, M)
     K = tables.nodes.domain.shape[1]
     SAME = same_domain(tables.nodes) \
         if domain_sum(N, K, copies) == "product" else None
+    ELN = eligible_in_domain(static.node_match, tables.classes, tables.nodes,
+                             D, SAME)
     return CycleArrays(static=static, TM=TM, has_anti=has_anti, CNT=CNT,
-                       HOLD=HOLD, ELD=ELD, WCOLS=WCOLS, WSYM=WSYM, ecfg=ecfg,
-                       SAME=SAME)
+                       HOLD=HOLD, ELN=ELN, WCOLS=WCOLS, WSYM=WSYM, ecfg=ecfg,
+                       SAME=SAME, domain_axis=jnp.zeros((D, 0), bool))

@@ -166,8 +166,8 @@ def preempt_for_pod(
     fit = _fit(req_p[None, :], nodes.alloc - used_wo) & nodes.valid
     aff_ok, anti_ok = affinity_rows(cls, classes, terms, cyc.TM, CNT_wo,
                                     HOLD_wo, nodes, D, same=cyc.SAME)
-    spread_ok = spread_row(cls, classes, terms, cyc.TM, CNT_wo, cyc.ELD,
-                           cyc.static.node_match[cls], nodes, D)
+    spread_ok = spread_row(cls, classes, terms, cyc.TM, CNT_wo, cyc.ELN,
+                           cyc.static.node_match[cls], nodes, D, cyc.SAME)
     host_ok = (node_name_req < 0) | (nodes.name_id == node_name_req)
     cand = (cyc.static.mask[cls] & fit & ~conflict_wo & aff_ok & anti_ok
             & spread_ok & host_ok)                              # [N]

@@ -29,7 +29,8 @@ from ..state.arrays import (
     PodClassTable,
     TermTable,
 )
-from .interpod import domain_agg, domain_of_term
+from .interpod import domain_of_term
+from .topospread import SpreadCounts, eligible_domain_counts
 
 MAX_NODE_SCORE = 100.0
 
@@ -104,11 +105,16 @@ def even_spread_soft_row(
     nodes: NodeArrays,
     node_match_row: Array, # [N] this class's selector/affinity eligibility
     D: int,
+    same: Array | None = None,    # CycleArrays.SAME
+    counts: SpreadCounts | None = None,   # the state's, over every class
 ) -> Array:
     """[N] f32 0..100: EvenPodsSpread score over ScheduleAnyway constraints
     (even_pods_spread.go:106-227). Raw score per node = Σ matching pods in
-    the node's topology domain; normalized inverted (total−raw)/(total−min),
-    ineligible nodes (missing key / failing node match) score 0.
+    the node's topology domain (the count hard spread's Filter reads:
+    topospread.eligible_domain_counts, or the class's rows of `counts`
+    where the caller built the state's); normalized inverted
+    (total−raw)/(total−min), ineligible nodes (missing key / failing node
+    match) score 0.
 
     Deviation (docs/PARITY.md): normalization runs over all valid eligible
     nodes, not just the cycle's feasible set — ordering is unaffected."""
@@ -116,11 +122,9 @@ def even_spread_soft_row(
     s = jnp.maximum(s_ids, 0)
     soft = (s_ids >= 0) & ~classes.tsc_hard[cls]  # [TS]
 
-    dom, has_key = domain_of_term(nodes, terms.topo_key[s])  # [TS, N]
-    # counts restricted to nodes eligible for this pod (buildPodTopologySpreadMap
-    # checks PodMatchesNodeSelectorAndAffinityTerms on the counted node)
-    seg = domain_agg(CNT[s], dom, D, eligible=node_match_row[None, :])
-    cnt = jnp.take_along_axis(seg, jnp.where(dom >= 0, dom, D), axis=1)
+    _, has_key = domain_of_term(nodes, terms.topo_key[s])  # [TS, N]
+    cnt = counts.cnt[cls] if counts is not None else eligible_domain_counts(
+        cls, classes, terms, CNT, node_match_row, nodes, D, same)
     raw = jnp.where(soft[:, None] & has_key, cnt, 0).sum(0)  # [N] i32
 
     elig = (

@@ -21,7 +21,10 @@ TPU idle. This module replaces it as the default path. Per wave:
        - hard topology-spread (predicates.go:1643): at most
          maxSkew + minMatch − count(d) new matching pods per domain d
          (the criticalPaths online-min, metadata.go:78-112, evaluated at
-         wave start — conservative, never violating);
+         wave start — conservative, never violating), said of each NODE of
+         d from the round's one `SpreadCounts` (ops/assign.py
+         state_spread_counts: the count and the minimum the Filter row and
+         the soft score read, built once a round outside the class axis);
        - self-matching anti-affinity (predicates.go:1447-1456): one pod per
          domain per wave;
        - required-affinity first-pod escape (predicates.go:1436-1440): a class
@@ -70,9 +73,9 @@ from jax import lax
 
 from ..state.arrays import Array, ClusterTables, PodArrays
 from .assign import (AssignResult, AssignState, pod_mask_row, score_row,
-                     state_affinity_table)
+                     state_affinity_table, state_spread_counts)
 from .fit import _fit
-from .interpod import (class_term_membership, domain_agg, domain_of_term,
+from .interpod import (class_term_membership, domain_of_term,
                        in_domain_counts)
 from .lattice import CycleArrays
 
@@ -126,12 +129,12 @@ def interaction_graph(tables: ClusterTables, cyc: CycleArrays) -> Array:
 # the device program's stages carry `jax.named_scope` names, so a profiler
 # trace groups its fusions by stage (no run-time cost: metadata only)
 @jax.named_scope("class_mask_score")
-def _class_mask_score(tables, cyc, state, table):
+def _class_mask_score(tables, cyc, state, table, spread):
     """[SC, N] Filter mask + Score for every class against `state` — the
     dense analog of findNodesThatFit + prioritizeNodes, once per class.
-    `table` is the round's `state_affinity_table`: built once, outside the
-    class axis (and outside the class blocks below), every class selecting
-    its terms' rows.
+    `table` is the round's `state_affinity_table` and `spread` its
+    `state_spread_counts`: built once, outside the class axis (and outside
+    the class blocks below), every class selecting its own rows.
 
     Long-context tiling (SURVEY §5 "blockwise tiles over the pod axis"):
     vmapping the full row over SC materializes per-class intermediates like
@@ -146,8 +149,8 @@ def _class_mask_score(tables, cyc, state, table):
 
     def row(c):
         mask = pod_mask_row(tables, cyc, state, c, jnp.int32(-1),
-                            classes.valid[c], table)
-        score = score_row(tables, cyc, state, c, table)
+                            classes.valid[c], table, spread)
+        score = score_row(tables, cyc, state, c, table, spread)
         return mask, jnp.where(mask, score, -jnp.inf)
 
     if SC <= _CLASS_BLOCK:
@@ -217,16 +220,18 @@ def _within_quota(neg_score: Array, rot_pos: Array, off: Array, dom: Array,
 
 
 @jax.named_scope("domain_quota_pass")
-def _domain_quota_pass(tables, cyc, state, allowed, neg_score, rot_pos, offs):
+def _domain_quota_pass(tables, cyc, spread, allowed, neg_score, rot_pos,
+                       offs):
     """AND per-domain admission quotas into `allowed` [SC, N] (node order).
     Quotas keep same-wave same-class admissions from violating hard spread /
-    self-anti-affinity when replayed sequentially. `neg_score`, `rot_pos`
-    [SC, N] are the two keys of the class's score order (assign_waves.body),
-    `offs` [SC] the rotation that turns a `rot_pos` back into its node."""
+    self-anti-affinity when replayed sequentially. `spread` is the round's
+    `state_spread_counts`; `neg_score`, `rot_pos` [SC, N] are the two keys
+    of the class's score order (assign_waves.body), `offs` [SC] the rotation
+    that turns a `rot_pos` back into its node."""
     classes = tables.classes
     nodes = tables.nodes
     terms = tables.terms
-    D = cyc.ELD.shape[2] - 1
+    D = cyc.D
     SC, N = allowed.shape
     TS = classes.tsc_term.shape[1]
     AN = classes.anti_terms.shape[1]
@@ -252,17 +257,14 @@ def _domain_quota_pass(tables, cyc, state, allowed, neg_score, rot_pos, offs):
         active = (
             (s_id >= 0) & classes.tsc_hard[c, t] & cyc.TM[s, c]
         )
-        eld = cyc.ELD[c, t, :D]
-        active = active & eld.any()
-        dom = key_domain(terms.topo_key[s])
-        seg = domain_agg(state.CNT[s][None], dom[None], D,
-                         eligible=cyc.static.node_match[c][None])[0]
-        min_cnt = jnp.min(jnp.where(eld, seg[:D], _I32_MAX))
-        quota = jnp.clip(
-            classes.tsc_maxskew[c, t] + min_cnt - seg, 0, _I32_MAX
-        )
-        # the domain's cap is said of each node ONCE, here in node order
-        return slot_quota(c, dom, active, quota[jnp.where(dom >= 0, dom, D)])
+        active = active & spread.any_eligible[c, t]
+        # the domain's cap, said of each of its nodes: the count is the
+        # domain's on every one of them. (A node without the key reads
+        # count 0 and shares bucket D; the slot's Filter row refuses it.)
+        cap = jnp.clip(
+            classes.tsc_maxskew[c, t] + spread.min_cnt[c, t]
+            - spread.cnt[c, t], 0, _I32_MAX)
+        return slot_quota(c, key_domain(terms.topo_key[s]), active, cap)
 
     def apply_spread(allowed):
         rows = jax.vmap(
@@ -301,7 +303,7 @@ def _escape_cap(tables, cyc, state, r, table):
     this wave, so the followers see its counts next wave. The totals are
     affinity_rows' own (`table`: the round's `state_affinity_table`)."""
     classes = tables.classes
-    D = cyc.ELD.shape[2] - 1
+    D = cyc.D
 
     def one(c):
         ats = classes.aff_terms[c]
@@ -396,7 +398,8 @@ def assign_waves(
         r = jnp.where(nxt_ok, jnp.minimum(remaining, run_cnt), 0)
 
         table = state_affinity_table(tables, cyc, state, SC)
-        mask, score = _class_mask_score(tables, cyc, state, table)
+        spread = state_spread_counts(tables, cyc, state, SC)
+        mask, score = _class_mask_score(tables, cyc, state, table, spread)
         mask = mask & nxt_ok[:, None]
         # score-window admission (EngineConfig.w_window): a class only
         # admits on nodes within the window of its per-class feasible max
@@ -444,7 +447,7 @@ def assign_waves(
         neg_score = -score
         rot_pos = (node_ids[None, :] - offs[:, None]) % N     # [SC, N]
         allowed_n = _domain_quota_pass(
-            tables, cyc, state, adm_mask, neg_score, rot_pos, offs)
+            tables, cyc, spread, adm_mask, neg_score, rot_pos, offs)
         order_n, allowed = _score_order(neg_score, rot_pos, offs, allowed_n)
         grank = jnp.cumsum(allowed.astype(jnp.int32), axis=1) - 1
         A = _to_nodes(order_n, allowed & (grank < r[:, None]))

@@ -6,6 +6,8 @@ httptest.NewServer analog), exercised through the HTTPExtender client."""
 import json
 import urllib.request
 
+import pytest
+
 from kubernetes_tpu.api.types import (
     Affinity,
     LabelSelector,
@@ -157,6 +159,86 @@ def test_backend_prioritize_prefers_empty_node():
     assert 0 <= scores["busy"] <= 10 and scores["empty"] <= 10
 
 
+def _spread_verbs(role):
+    """`filter` then `prioritize` for one pod under topology spread, on nine
+    nodes in three zones (one without the zone label) holding `web` pods
+    unevenly: (passed names, failed names, {host: score})."""
+    from kubernetes_tpu.api.types import (LabelSelector,
+                                          TopologySpreadConstraint,
+                                          UnsatisfiableAction)
+
+    zone, host = "topology.kubernetes.io/zone", "kubernetes.io/hostname"
+    be = ExtenderBackend()
+    nodes = []
+    for i in range(9):
+        labels = {host: f"n{i}", "pool": "ab"[i % 2]}
+        if i != 4:
+            labels[zone] = f"z{i % 3}"
+        nodes.append(mknode(f"n{i}", labels=labels))
+    be.sync_nodes(nodes)
+    bound = []
+    for i in range(9):
+        for j in range(1 + 2 * (i % 3 == 0) + (i == 1)):
+            p = mkpod(f"web-{i}-{j}", cpu="100m", labels={"app": "web"})
+            p.node_name = f"n{i}"
+            bound.append(p)
+    be.sync_scheduled_pods(bound)
+    sel = LabelSelector.of(match_labels={"app": "web"})
+    hard, soft = (UnsatisfiableAction.DO_NOT_SCHEDULE,
+                  UnsatisfiableAction.SCHEDULE_ANYWAY)
+    spread = {
+        "hard-zone-soft-hostname": (
+            TopologySpreadConstraint(1, zone, hard, sel),
+            TopologySpreadConstraint(1, host, soft, sel)),
+        "hard-hostname-soft-zone": (
+            TopologySpreadConstraint(1, host, hard, sel),
+            TopologySpreadConstraint(1, zone, soft, sel)),
+        "soft-zone-behind-a-selector": (
+            TopologySpreadConstraint(1, zone, soft, sel),),
+    }[role]
+    pod = mkpod("p", cpu="100m", labels={"app": "web"},
+                topology_spread=spread,
+                node_selector={"pool": "a"} if "selector" in role else {})
+    names = [n.name for n in nodes]
+    res = be.filter(ExtenderArgs(pod=pod_to_v1(pod), node_names=names))
+    prios = be.prioritize(ExtenderArgs(pod=pod_to_v1(pod),
+                                       node_names=list(res.node_names)))
+    return (list(res.node_names), dict(res.failed_nodes),
+            {p.host: p.score for p in prios})
+
+
+@pytest.mark.parametrize("role", ["hard-zone-soft-hostname",
+                                  "hard-hostname-soft-zone",
+                                  "soft-zone-behind-a-selector"])
+def test_verbs_with_product_equal_verbs_with_scatter(role, monkeypatch):
+    """A verb's mask (the names `filter` passes, and the reasons of those it
+    fails) and scores (`prioritize`) for a pod under hard and soft topology
+    spread, from programs whose Dims choose the product against the same
+    programs made to fall back to the scatter form (ISSUE 43: the spread
+    counts ride the cycle's same-domain matrices too)."""
+    import jax
+    from kubernetes_tpu.state import dims as dims_mod
+
+    product = _spread_verbs(role)
+    monkeypatch.setattr(dims_mod, "DOMAIN_SUM_MAX_BYTES", 0)
+    jax.clear_caches()
+    try:
+        scatter = _spread_verbs(role)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert product == scatter
+    passed, failed, scores = product
+    assert passed and len(set(scores.values())) > 1
+    if role.startswith("hard"):
+        # the zone-less node fails a zone constraint; a crowded domain fails
+        assert failed and len(passed) + len(failed) == 9
+        if role.startswith("hard-zone"):
+            assert "n4" in failed
+    else:
+        assert set(passed) == {"n0", "n2", "n4", "n6", "n8"}
+
+
 def test_backend_preemption_verifies_victims():
     be = ExtenderBackend()
     be.sync_nodes([mknode("n0", cpu=2)])
@@ -291,7 +373,6 @@ def test_scheduler_with_extender_in_cycle():
 import contextlib  # noqa: E402
 import time  # noqa: E402
 
-import pytest  # noqa: E402
 
 from benchmarks.harness import objects  # noqa: E402
 from kubernetes_tpu.extender import ServedExtender  # noqa: E402

@@ -403,3 +403,123 @@ def test_server_preemption_deletes_victim_through_api():
     finally:
         server.stop()
         api.close()
+
+
+# --------------------------------------------------------------------------- #
+# ISSUE 43: the what-if's hard-spread row reads the lane's own eligible-masked
+# in-domain counts through `ops/topospread.py spread_counts` (a product
+# against the cycle's same-domain matrices, or one scatter-add and gather)
+# --------------------------------------------------------------------------- #
+
+import pytest  # noqa: E402
+
+ZONE = "topology.kubernetes.io/zone"
+
+
+def _spread_whatif_cluster(case):
+    """9 nodes in three zones (one node without the zone label), each nearly
+    full of low-priority `web` pods, unevenly by zone, and four `web` pods
+    no preemptor may evict on two nodes of one zone; two preemptors of
+    priority 10 that must evict to land, under hard spread over `case`'s
+    key, the second behind a node selector."""
+    from kubernetes_tpu.api.types import (TopologySpreadConstraint,
+                                          UnsatisfiableAction)
+
+    nodes = []
+    for i in range(9):
+        labels = {HOSTNAME: f"n{i}", "pool": "ab"[i % 2]}
+        if i != 4:
+            labels[ZONE] = f"z{i % 3}"
+        nodes.append(Node(name=f"n{i}", labels=labels,
+                          allocatable=Resources.make(cpu=4, memory="8Gi",
+                                                     pods=110)))
+    existing = []
+    for i in range(9):
+        for j in range(2 + (i % 3 == 0) + (i == 1)):
+            existing.append(bound(f"low-{i}-{j}", f"n{i}", cpu="1",
+                                  priority=j % 2, labels={"app": "web"}))
+    # survivors of the what-if (priority over the preemptors'): the counts
+    # the spread row reads once every victim is gone
+    for i in (0, 0, 3, 3):
+        existing.append(bound(f"keep-{len(existing)}", f"n{i}", cpu="100m",
+                              priority=20, labels={"app": "web"}))
+    key = HOSTNAME if case == "hostname-spread" else ZONE
+    spread = (TopologySpreadConstraint(
+        max_skew=1, topology_key=key,
+        when_unsatisfiable=UnsatisfiableAction.DO_NOT_SCHEDULE,
+        selector=LabelSelector.of(match_labels={"app": "web"})),)
+    pending = [
+        Pod(name="hi-0", labels={"app": "web"}, priority=10,
+            requests=Resources.make(cpu="3", memory="1Gi"),
+            topology_spread=spread, creation_index=0),
+        Pod(name="hi-1", labels={"app": "web"}, priority=10,
+            requests=Resources.make(cpu="3", memory="1Gi"),
+            node_selector={"pool": "a"}, topology_spread=spread,
+            creation_index=1),
+    ]
+    return nodes, existing, pending
+
+
+@pytest.mark.parametrize("case", ["zone-spread", "hostname-spread"])
+def test_whatif_with_product_equals_scatter_and_the_parents_row(
+        case, monkeypatch):
+    """Every field of the what-if's result for two lanes (the chosen node,
+    the candidates' count, the whole order, the victims) with the cycle's
+    same-domain matrices, without them, and with the PARENT's spread row
+    (its own scatter-add + gather and ELD's scatter-max) put back in its
+    place: identical."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import spread_parent_forms as parent
+    from kubernetes_tpu.ops import preempt as preempt_mod
+    from kubernetes_tpu.ops.lattice import build_cycle
+    from kubernetes_tpu.sched.cycle import UNSCHEDULABLE_TAINT_KEY
+    from kubernetes_tpu.state.encode import Encoder
+
+    nodes, existing, pending = _spread_whatif_cluster(case)
+    enc = Encoder()
+    enc.vocabs.label_keys.intern(UNSCHEDULABLE_TAINT_KEY)
+    enc.vocabs.label_vals.intern("")
+    tables, ex, pe, d = enc.encode_cluster(nodes, existing, pending, None)
+    uk = jnp.int32(enc.vocabs.label_keys.get(UNSCHEDULABLE_TAINT_KEY))
+    ev = jnp.int32(enc.vocabs.label_vals.get(""))
+    assert d.domain_sum("waves") == "product"
+    lanes = (pe.cls[:2], pe.node_name_req[:2], pe.priority[:2])
+
+    def whatif(scatter):
+        def f(tables, ex):
+            cyc = build_cycle(tables, ex, uk, ev, d.D)
+            assert cyc.SAME is not None
+            if scatter:
+                cyc = cyc._replace(SAME=None)
+            return preempt_mod.preempt_batch(tables, cyc, ex, *lanes, d.D)
+
+        return jax.tree.map(np.asarray, jax.jit(f)(tables, ex))
+
+    product, scatter = whatif(False), whatif(True)
+
+    def parents_row(cls, classes, terms, TM, CNT, _ELN, nm_row, nodes, D,
+                    _same=None):
+        eld = parent.eligible_domains(
+            parents_row.node_match, classes, nodes, D)
+        return parent.spread_row(cls, classes, terms, TM, CNT, eld, nm_row,
+                                 nodes, D)
+
+    def with_parents_row(tables, ex):
+        cyc = build_cycle(tables, ex, uk, ev, d.D)
+        parents_row.node_match = cyc.static.node_match
+        return preempt_mod.preempt_batch(tables, cyc, ex, *lanes, d.D)
+
+    monkeypatch.setattr(preempt_mod, "spread_row", parents_row)
+    old = jax.tree.map(np.asarray, jax.jit(with_parents_row)(tables, ex))
+    for name, p, s, o in zip(product._fields, product, scatter, old):
+        np.testing.assert_array_equal(p, s, name)
+        np.testing.assert_array_equal(p, o, name)
+    n_valid = int(np.asarray(tables.nodes.valid).sum())
+    # both lanes find a node, and hard spread refuses some node to each
+    assert (product.node >= 0).all() and product.victims.any()
+    assert (product.n_candidates > 0).all()
+    assert (product.n_candidates < n_valid).all()
+    assert not product.bulk.any()

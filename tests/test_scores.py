@@ -700,3 +700,254 @@ def test_build_cycle_carries_the_matrices_only_where_the_rule_says(
     monkeypatch.setattr(dims_mod, "DOMAIN_SUM_MAX_BYTES",
                         d.K * d.N * d.N * 2 - 1)
     assert shapes().SAME is None and d.domain_sum("waves") == "scatter"
+
+
+# --------------------------------------------------------------------------- #
+# topology spread's eligible-masked in-domain count (ops/topospread.py
+# spread_counts, eligible_in_domain; ISSUE 43): one sum per (class, slot) and
+# state, under either form of the in-domain sum, shared by the Filter row,
+# the soft score and the waves round's cap. Held to a plain numpy loop over
+# nodes and domains and to the parent's forms (tests/spread_parent_forms.py).
+# --------------------------------------------------------------------------- #
+
+_SPREAD_CASES = ["key-absent-on-some-nodes", "hostname-key",
+                 "class-with-no-eligible-node",
+                 "eligible-nodes-all-lack-the-key", "invalid-nodes",
+                 "counts-past-2^16"]
+_I32_MAX = int(np.iinfo(np.int32).max)
+
+
+def _spread_cluster(case):
+    """12 nodes (11 for `invalid-nodes`: N pads to 16) in three zones and two
+    pools, 60 bound pods spread unevenly, and one pending pod per (app, pool
+    choice): hard spread over one key and soft spread over the other, so
+    every class fills both constraint slots."""
+    rng = random.Random(case)
+    n = 11 if case == "invalid-nodes" else 12
+    nodes = [Node(name=f"n{i}",
+                  labels={HOSTNAME: f"n{i}", ZONE: f"z{i % 3}",
+                          "pool": "ab"[i % 2]},
+                  allocatable=Resources.make(cpu="64", memory="256Gi",
+                                             pods=500))
+             for i in range(n)]
+    if case in ("key-absent-on-some-nodes", "eligible-nodes-all-lack-the-key",
+                "counts-past-2^16"):
+        for i in (0, 5, 7):     # matching pods sit on these all the same
+            nodes[i].labels.pop(ZONE)
+            nodes[i].labels["pool"] = "bare"
+    existing = [_plain_pod(f"e{i}", APPS[(i * i) % 4], 100 + i,
+                           node=nodes[(i * 7 + i // 5) % n].name)
+                for i in range(60)]
+    hard_key, soft_key = (HOSTNAME, ZONE) if case == "hostname-key" \
+        else (ZONE, HOSTNAME)
+    pools = {"class-with-no-eligible-node": "nowhere",
+             "eligible-nodes-all-lack-the-key": "bare"}.get(case, "a")
+    pending = []
+    for i, app in enumerate(APPS):
+        for j, sel in enumerate(({}, {"pool": pools})):
+            spread = (
+                TopologySpreadConstraint(
+                    max_skew=1 + (i + j) % 2, topology_key=hard_key,
+                    when_unsatisfiable=UnsatisfiableAction.DO_NOT_SCHEDULE,
+                    selector=LabelSelector.of(match_labels={"app": app})),
+                TopologySpreadConstraint(
+                    max_skew=1, topology_key=soft_key,
+                    when_unsatisfiable=UnsatisfiableAction.SCHEDULE_ANYWAY,
+                    selector=LabelSelector.of(
+                        match_labels={"app": APPS[(i + 1) % 4]})))
+            pending.append(Pod(
+                name=f"p{i}-{j}", labels={"app": app}, node_selector=sel,
+                requests=Resources.make(cpu="10m", memory="16Mi"),
+                topology_spread=spread[:1 + rng.randrange(2)] if j else spread,
+                creation_index=2 * i + j))
+    return nodes, existing, pending
+
+
+def _spread_state(case):
+    from kubernetes_tpu.ops.lattice import build_cycle
+
+    nodes, existing, pending = _spread_cluster(case)
+    tables, ex, pe, d, uk, ev = _encode(nodes, existing, pending, E=512)
+    tables, ex, pe = (jax.device_put(x) for x in (tables, ex, pe))
+    cyc = jax.jit(build_cycle, static_argnums=(4,))(tables, ex, uk, ev, d.D)
+    assert cyc.SAME is not None and cyc.D == d.D
+    CNT = cyc.CNT
+    if case == "counts-past-2^16":
+        CNT = CNT * 30011 + (jnp.arange(CNT.shape[1]) % 7)[None, :]
+    return tables, cyc, CNT, pe, d
+
+
+def _numpy_spread_counts(tables, node_match, CNT, classes_with_slots):
+    """(cnt [SC, TS, N], min_cnt [SC, TS], any_eligible [SC, TS]) by loops
+    over classes, slots, nodes and a dict of domains."""
+    tsc_term = np.asarray(tables.classes.tsc_term)
+    tsc_key = np.asarray(tables.classes.tsc_key)
+    topo_key = np.asarray(tables.terms.topo_key)
+    domain = np.asarray(tables.nodes.domain)
+    valid = np.asarray(tables.nodes.valid)
+    SC, TS = tsc_term.shape
+    N = valid.shape[0]
+    cnt = np.zeros((SC, TS, N), np.int64)
+    min_cnt = np.full((SC, TS), _I32_MAX, np.int64)
+    any_el = np.zeros((SC, TS), bool)
+    for c in range(SC):
+        for t in range(TS):
+            s = max(tsc_term[c, t], 0)
+            k = topo_key[s]
+            keyed = [m for m in range(N)
+                     if k >= 0 and valid[m] and domain[m, k] >= 0]
+            seg = {}
+            for m in keyed:
+                if node_match[c, m]:
+                    seg[domain[m, k]] = seg.get(domain[m, k], 0) + CNT[s, m]
+            for m in keyed:
+                cnt[c, t, m] = seg.get(domain[m, k], 0)
+            if tsc_term[c, t] < 0:
+                assert tsc_key[c, t] < 0
+                continue
+            assert tsc_key[c, t] == k
+            classes_with_slots.add(c)
+            eligible = {domain[m, k] for m in keyed if node_match[c, m]}
+            any_el[c, t] = bool(eligible)
+            if eligible:
+                min_cnt[c, t] = min(seg[d] for d in eligible)
+    return cnt, min_cnt, any_el
+
+
+@pytest.mark.parametrize("case", _SPREAD_CASES)
+def test_spread_counts_match_numpy_loop(case):
+    """`spread_counts` for every (class, slot) under the product, under the
+    scatter form, and each class summing its own rows, all against the numpy
+    loop, element for element."""
+    from kubernetes_tpu.ops.topospread import spread_counts
+
+    tables, cyc, CNT, _pe, d = _spread_state(case)
+    classes, terms, nodes = tables.classes, tables.terms, tables.nodes
+    nm = cyc.static.node_match
+    SC = classes.valid.shape[0]
+
+    def table(same):
+        return spread_counts(jnp.arange(SC), classes, terms, CNT, nm, cyc.ELN,
+                             nodes, d.D, same)
+
+    def own(same):
+        return jax.vmap(lambda c: spread_counts(
+            c, classes, terms, CNT, nm[c], cyc.ELN[c], nodes, d.D, same))(
+                jnp.arange(SC))
+
+    got = jax.tree.map(np.asarray, jax.jit(lambda: {
+        "product": table(cyc.SAME), "scatter": table(None),
+        "own-rows-product": own(cyc.SAME), "own-rows-scatter": own(None)})())
+    slotted = set()
+    want = _numpy_spread_counts(tables, np.asarray(nm), np.asarray(CNT),
+                                slotted)
+    for form, sc in got.items():
+        assert sc.cnt.dtype == np.int32 and sc.min_cnt.dtype == np.int32
+        for name, g, w in zip(sc._fields, sc, want):
+            np.testing.assert_array_equal(g, w, f"{form} {name}")
+    cnt, min_cnt, any_el = want
+    live = sorted(slotted)
+    assert len(live) >= 8 and cnt[live].any()
+    if case == "class-with-no-eligible-node":
+        assert (~any_el[live]).any() and any_el[live].any()
+        assert (min_cnt[live][~any_el[live]] == _I32_MAX).all()
+    elif case == "eligible-nodes-all-lack-the-key":
+        # eligible nodes exist, none carries the zone key: no eligible DOMAIN
+        none = [c for c in live if np.asarray(nm)[c].any() and not any_el[c, 0]]
+        assert none
+    else:
+        assert any_el[live, 0].all()
+    if case == "counts-past-2^16":
+        assert cnt.max() > 2 ** 16 and min_cnt[any_el].max() > 2 ** 16
+    if case == "hostname-key":
+        assert d.D >= len(np.flatnonzero(np.asarray(nodes.valid)))
+    if case == "invalid-nodes":
+        assert not np.asarray(nodes.valid).all()
+    if case == "key-absent-on-some-nodes":
+        keyless = np.asarray(nodes.valid) & (np.asarray(nodes.domain)[
+            :, np.asarray(terms.topo_key)[
+                np.asarray(classes.tsc_term)[live[0], 0]]] < 0)
+        assert keyless.sum() == 3 and not cnt[live[0], 0][keyless].any()
+
+
+@pytest.mark.parametrize("case", _SPREAD_CASES)
+def test_eligible_nodes_equal_the_parents_eligible_domains(case):
+    """ELN [SC, TS, N] (one in-domain sum of the 0/1 eligibility rows, either
+    form) against the parent's ELD [SC, TS, D + 1] scatter-max gathered to
+    nodes: node n's domain is eligible, and False where n lacks the key."""
+    import spread_parent_forms as parent
+    from kubernetes_tpu.ops.topospread import eligible_in_domain
+
+    tables, cyc, _CNT, _pe, d = _spread_state(case)
+    classes, nodes = tables.classes, tables.nodes
+    nm = cyc.static.node_match
+    eld = np.asarray(jax.jit(lambda: parent.eligible_domains(
+        nm, classes, nodes, d.D))())
+    scatter = np.asarray(jax.jit(lambda: eligible_in_domain(
+        nm, classes, nodes, d.D))())
+    key = np.asarray(classes.tsc_key)
+    dom = np.where(np.asarray(nodes.valid)[:, None],
+                   np.asarray(nodes.domain), -1)
+    SC, TS = key.shape
+    want = np.zeros((SC, TS, dom.shape[0]), bool)
+    for c in range(SC):
+        for t in range(TS):
+            if key[c, t] >= 0:
+                dn = dom[:, key[c, t]]
+                want[c, t] = (dn >= 0) & eld[c, t, np.maximum(dn, 0)]
+    np.testing.assert_array_equal(np.asarray(cyc.ELN), want)
+    np.testing.assert_array_equal(scatter, want)
+    np.testing.assert_array_equal(want.any(-1), eld[:, :, :d.D].any(-1))
+    assert want.any() and not want.all()
+
+
+@pytest.mark.parametrize("form", ["product-table", "product-own-rows",
+                                  "scatter-table", "scatter-own-rows"])
+@pytest.mark.parametrize("case", _SPREAD_CASES)
+def test_spread_rows_equal_the_parents_forms(case, form):
+    """`spread_row` (the Filter mask) and `even_spread_soft_row` (the score)
+    for EVERY class, selecting from the state's `SpreadCounts` or summing
+    the class's own rows, under either form of the sum: bit for bit the
+    parent's rows (its own scatter-add + gather each, ELD's scatter-max)."""
+    import spread_parent_forms as parent
+    from kubernetes_tpu.ops import assign
+    from kubernetes_tpu.ops.scores import even_spread_soft_row
+    from kubernetes_tpu.ops.topospread import spread_row
+
+    tables, cyc, CNT, _pe, d = _spread_state(case)
+    classes, terms, nodes = tables.classes, tables.terms, tables.nodes
+    nm = cyc.static.node_match
+    SC = classes.valid.shape[0]
+    same = cyc.SAME if form.startswith("product") else None
+    cs = jnp.arange(SC)
+
+    @jax.jit
+    def new():
+        state = assign.initial_state(tables, cyc)._replace(CNT=CNT)
+        counts = assign.state_spread_counts(
+            tables, cyc._replace(SAME=same), state, SC) \
+            if form.endswith("table") else None
+        return jax.vmap(lambda c: (
+            spread_row(c, classes, terms, cyc.TM, CNT, cyc.ELN, nm[c], nodes,
+                       d.D, same, counts),
+            even_spread_soft_row(c, classes, terms, CNT, nodes, nm[c], d.D,
+                                 same, counts)))(cs)
+
+    @jax.jit
+    def old():
+        eld = parent.eligible_domains(nm, classes, nodes, d.D)
+        return jax.vmap(lambda c: (
+            parent.spread_row(c, classes, terms, cyc.TM, CNT, eld, nm[c],
+                              nodes, d.D),
+            parent.even_spread_soft_row(c, classes, terms, CNT, nodes, nm[c],
+                                        d.D)))(cs)
+
+    (mask, soft), (mask0, soft0) = jax.tree.map(np.asarray, (new(), old()))
+    np.testing.assert_array_equal(mask, mask0)
+    assert soft.dtype == np.float32
+    np.testing.assert_array_equal(soft, soft0)
+    valid = np.asarray(classes.valid)
+    assert soft0[valid].any()
+    if case == "class-with-no-eligible-node":
+        assert mask0[valid].all(axis=1).any()     # passes everywhere
+    assert not mask0[valid].all()                  # and the Filter refuses

@@ -632,9 +632,10 @@ def _big_indexed_ops(jaxpr, floor, in_loop=False, where=_file_line):
     return found
 
 
-# what the change leaves: the spread family's cap said of each node, one
-# gather in node order from the [D + 1] table of domains
-_SC_BY_N_INDEXED_LEFT = 1
+# what is left: nothing. (PR 38 left one, the spread family's cap said of
+# each node by a gather from the [D + 1] table of domains; since PR 43 the
+# cap is computed per node from the round's per-node counts.)
+_SC_BY_N_INDEXED_LEFT = 0
 
 
 def test_no_sc_by_n_array_is_fetched_through_a_permutation():
@@ -673,32 +674,36 @@ def _round_jaxpr(case):
 
 
 def _asked_by(eqn):
-    """The functions of ops/interpod.py and ops/scores.py on the equation's
-    traceback, innermost first, '' where neither file is on it: WHO asked
-    for the indexed operation."""
+    """The functions of ops/interpod.py, ops/topospread.py and ops/scores.py
+    on the equation's traceback, innermost first, '' where none of the files
+    is on it: WHO asked for the indexed operation."""
     from jax._src import source_info_util
 
     return ">".join(
         fr.function_name
         for fr in source_info_util.user_frames(eqn.source_info.traceback)
-        if fr.file_name.endswith(("ops/interpod.py", "ops/scores.py")))
+        if fr.file_name.endswith(("ops/interpod.py", "ops/topospread.py",
+                                  "ops/scores.py")))
 
 
 def test_no_s_by_n_table_is_summed_through_a_scatter_in_a_product_round(
         monkeypatch):
-    """Structural guard (ISSUE 42): where the program's Dims choose the
-    product (state/dims.py domain_sum), the compiled round (the `while` body
-    of `assign_waves`) holds NO scatter-add and NO gather with S x N or more
-    index vectors that ops/interpod.py's in-domain sum or ops/scores.py's
-    symmetric weights asked for: the round's count table, `hold` and the
-    weights are one product against the cycle's same-domain matrices. The
-    parent had six (three scatter-adds into [S, D + 1], three gathers back:
-    41 ms of the flagship cycle's 82, `/PERF.md` section 6, PR 42); the same
-    count finds the scatter form's pair once the rule is made to fall back.
-    What the two files still ask for at that size is topology spread's own
-    aggregate over the nodes ELIGIBLE for a class (`domain_agg` for hard
-    spread's Filter row and quota and for the soft score: they read the
-    minimum over DOMAINS, which no per-node sum gives: ROADMAP A3)."""
+    """Structural guard (ISSUE 42, ISSUE 43): where the program's Dims choose
+    the product (state/dims.py domain_sum), the compiled round (the `while`
+    body of `assign_waves`) holds NO scatter-add and NO gather with SC x N
+    or more index vectors that ops/interpod.py's in-domain sum,
+    ops/topospread.py's spread counts or ops/scores.py asked for: the
+    round's count table, `hold` and the weights are one product against the
+    cycle's same-domain matrices, and topology spread's eligible-masked
+    counts of every (class, slot) another. PR 42's parent had six for the
+    tables (41 ms of the flagship cycle's 82, `/PERF.md` section 6, PR 42);
+    PR 43's had three more `domain_agg` scatter-adds for spread (the Filter
+    row's, the soft score's, the quota's) and a gather back each (27 ms of a
+    cycle's 42, section 6, PR 43): the minimum over a key's DOMAINS that
+    they read is the minimum over the nodes whose domain is eligible, which
+    the per-node sum does give. Once the rule is made to fall back the same
+    count finds exactly two pairs: the tables' stacked sum and spread's ONE
+    sum a round, shared by its three readers."""
     from kubernetes_tpu.state import dims as dims_mod
 
     def asked(jaxpr, floor):
@@ -708,30 +713,85 @@ def test_no_s_by_n_table_is_summed_through_a_scatter_in_a_product_round(
     jaxpr, d = _round_jaxpr("bound-64x360")
     assert d.domain_sum("waves") == "product"
     assert d.affinity_agg("waves") == "term"
-    floor = d.S * d.N
-    spread = asked(jaxpr, floor)
-    assert spread == [("scatter-add", "domain_agg")] * 2 + [
-        ("scatter-add", "domain_agg>even_spread_soft_row")], spread
+    floor = min(d.S, d.SC) * d.N
+    assert asked(jaxpr, floor) == []
 
     monkeypatch.setattr(dims_mod, "DOMAIN_SUM_MAX_BYTES", 0)
     assert d.domain_sum("waves") == "scatter"
     jaxpr, _ = _round_jaxpr("bound-64x360")
-    left = [o for o in asked(jaxpr, floor) if o not in spread]
-    # the three tables ride ONE stacked sum: a gather and a scatter-add
-    assert left == [
-        ("gather", "in_domain_sums>term_domain_counts"),
-        ("scatter-add", "domain_agg>in_domain_sums>term_domain_counts"),
-    ], left
+    left = asked(jaxpr, floor)
+    assert [w for p, w in left if p == "scatter-add"] == [
+        "domain_agg>in_domain_sums>eligible_domain_counts>spread_counts",
+        # the three tables ride ONE stacked sum
+        "domain_agg>in_domain_sums>term_domain_counts"], left
+    # one gather back each. (Its frames are those of whoever traced
+    # `take_along_axis` at that shape first, build_cycle's ELN for spread's:
+    # jax caches the inner jit's jaxpr.)
+    assert len(left) == 4 and all(
+        w.startswith("in_domain_sums>") for p, w in left if p == "gather"), left
+
+
+def _spread_cluster(seed):
+    """Random cluster whose pending pods carry HARD spread (zone or hostname,
+    maxSkew 1-2) and SOFT spread over the other key, some behind a node
+    selector (so eligibility bites), several replicas a spec; a fifth of
+    the nodes lack the zone label; bound pods matching the selectors."""
+    from kubernetes_tpu.api.types import (
+        LabelSelector, TopologySpreadConstraint, UnsatisfiableAction)
+
+    zone, host = "topology.kubernetes.io/zone", "kubernetes.io/hostname"
+    rng = random.Random(1000 + seed)
+    nodes = []
+    for i in range(14):
+        labels = {host: f"n{i}", "pool": rng.choice("ab")}
+        if rng.random() < 0.8:
+            labels[zone] = f"z{rng.randrange(3)}"
+        nodes.append(Node(name=f"n{i}", labels=labels,
+                          allocatable=Resources.make(cpu="8", memory="16Gi",
+                                                     pods=30)))
+    apps = ["web", "db", "cache"]
+
+    def pod(name, app, i, node="", spread=(), sel=None):
+        return Pod(name=name, labels={"app": app}, node_name=node,
+                   node_selector=sel or {}, topology_spread=spread,
+                   requests=Resources.make(cpu="250m", memory="256Mi"),
+                   creation_index=i)
+
+    existing = [pod(f"e{i}", rng.choice(apps), 500 + i,
+                    node=rng.choice(nodes).name) for i in range(24)]
+    pending = []
+    for g in range(8):
+        app = apps[g % 3]
+        hard_key, soft_key = (zone, host) if g % 3 else (host, zone)
+        spread = (
+            TopologySpreadConstraint(
+                max_skew=rng.randint(1, 2), topology_key=hard_key,
+                when_unsatisfiable=UnsatisfiableAction.DO_NOT_SCHEDULE,
+                selector=LabelSelector.of(match_labels={"app": app})),
+            TopologySpreadConstraint(
+                max_skew=1, topology_key=soft_key,
+                when_unsatisfiable=UnsatisfiableAction.SCHEDULE_ANYWAY,
+                selector=LabelSelector.of(
+                    match_labels={"app": rng.choice(apps)})))
+        sel = {"pool": "a"} if g % 4 == 1 else None
+        for j in range(5):
+            pending.append(pod(f"g{g}-{j}", app, 10 * g + j,
+                               spread=spread, sel=sel))
+    return nodes, existing, pending
 
 
 @pytest.mark.parametrize("seed", range(3))
-def test_waves_with_product_equal_waves_with_scatter(seed, monkeypatch):
+@pytest.mark.parametrize("cluster", ["affinity", "spread"])
+def test_waves_with_product_equal_waves_with_scatter(cluster, seed,
+                                                     monkeypatch):
     """The whole engine under either form of the in-domain sum: placements,
     admission waves and the final counts identical (the sums are the same
-    integers)."""
+    integers), on clusters of pod-affinity terms and on clusters of hard
+    and soft topology spread."""
     from kubernetes_tpu.state import dims as dims_mod
 
-    nodes, existing, pending = _affinity_cluster(seed)
+    nodes, existing, pending = {"affinity": _affinity_cluster,
+                                "spread": _spread_cluster}[cluster](seed)
     tables, ex, pe, uk, ev, d = _encode(nodes, existing, pending)
     assert d.domain_sum("waves") == "product"
     res_p, waves_p = _run("waves", tables, ex, pe, uk, ev, d.D)
@@ -750,6 +810,91 @@ def test_waves_with_product_equal_waves_with_scatter(seed, monkeypatch):
                  (res_p.state.used, res_s.state.used)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert (np.asarray(res_p.node) >= 0).any()
+    if cluster == "spread":
+        # the constraints bit: more than one wave, and not every pod landed
+        # where its class's first pod did
+        assert np.asarray(waves_p).max() >= 1
+        assert len(set(np.asarray(res_p.node)[:len(pending)].tolist())) > 4
+
+
+def _quota_inputs(case):
+    """(tables, cyc, state, neg_score, rot_pos, offs, adm_mask): a round's
+    inputs to `_domain_quota_pass`, taken after one committed wave so that
+    the counts are the placed pods' too."""
+    from kubernetes_tpu.ops import waves as W
+    from kubernetes_tpu.ops.assign import (state_affinity_table,
+                                           state_spread_counts)
+
+    if case.startswith("spread-"):
+        nodes, existing, pending = _spread_cluster(int(case[-1]))
+    else:
+        nodes, existing, pending = _recorded_case(case)
+    tables, ex, pe, uk, ev, d = _encode(nodes, existing, pending)
+    tables, ex, pe = (jax.device_put(x) for x in (tables, ex, pe))
+
+    @jax.jit
+    def prepare():
+        cyc = build_cycle(tables, ex, uk, ev, d.D)
+        first = assign_waves(tables, cyc, pe, initial_state(tables, cyc),
+                             max_waves=1)
+        state = first.state
+        SC, N = cyc.static.mask.shape
+        table = state_affinity_table(tables, cyc, state, SC)
+        spread = state_spread_counts(tables, cyc, state, SC)
+        mask, score = W._class_mask_score(tables, cyc, state, table, spread)
+        offs = (jnp.arange(SC, dtype=jnp.int32) * 97) % N
+        rot_pos = (jnp.arange(N, dtype=jnp.int32)[None, :]
+                   - offs[:, None]) % N
+        return cyc, state, spread, -score, rot_pos, offs, mask
+
+    return (tables, d) + prepare()
+
+
+@pytest.mark.parametrize("case", ["bound-64x360", "flagship-100x1000",
+                                  "spread-0", "spread-1", "spread-2"])
+def test_spread_quota_rows_equal_the_parents_gather_form(case):
+    """The round's hard-spread admission rows with the cap said of each node
+    from the per-node counts, against the parent's (its own scatter-add, the
+    minimum over ELD's domains, the cap gathered from a [D + 1] table): bit
+    for bit on every node the class's Filter mask lets through, and
+    therefore in `allowed`. A node WITHOUT the key reads another cap than
+    the parent's (count 0 against bucket D's sum); where the slot is active
+    the Filter row has already refused it, which the case with keyless
+    nodes shows."""
+    import spread_parent_forms as parent
+    from kubernetes_tpu.ops import waves as W
+
+    tables, d, cyc, state, spread, neg_score, rot_pos, offs, mask = \
+        _quota_inputs(case)
+    classes = tables.classes
+
+    @jax.jit
+    def both():
+        eld = parent.eligible_domains(cyc.static.node_match, classes,
+                                      tables.nodes, d.D)
+        rows = parent.spread_quota_rows(
+            tables, cyc.static.node_match, cyc.TM, eld, state.CNT, d.D,
+            neg_score, rot_pos, offs)
+        want = mask & rows.all(axis=1)
+        # the new pass with the anti-affinity family switched off: its rows
+        # did not change and are ANDed in after spread's
+        no_anti = tables._replace(classes=classes._replace(
+            anti_terms=jnp.full_like(classes.anti_terms, -1)))
+        got = W._domain_quota_pass(no_anti, cyc, spread, mask, neg_score,
+                                   rot_pos, offs)
+        return want, got, rows
+
+    want, got, rows = jax.tree.map(np.asarray, both())
+    np.testing.assert_array_equal(got, want)
+    hard = np.asarray((classes.tsc_term >= 0) & classes.tsc_hard
+                      & classes.valid[:, None])
+    assert hard.any() and np.asarray(mask).any()
+    assert (~rows[hard]).any() and want.any()     # the caps bite
+    if case != "flagship-100x1000":
+        py_nodes = (_spread_cluster(int(case[-1])) if case[0] == "s"
+                    else _recorded_case(case))[0]
+        assert any("topology.kubernetes.io/zone" not in n.labels
+                   for n in py_nodes)
 
 
 if __name__ == "__main__":
