@@ -101,9 +101,6 @@ DEFAULT_STAGES = [
                                # apiserver with correct dominant-reason
                                # counts, dedupe proven, kill-switch
                                # placement bit-equality
-    (5000, 50000, "classes"),  # run-collapsed admission vs the per-pod
-                               # scan on a 200-class deployment backlog:
-                               # bit-equal placements, ≥10× fewer scan steps
     (5000, 50000, "mesh"),   # LIVE scheduler on an 8-way virtual mesh:
                              # resident sharded state, donated patches,
                              # bit-equal placements vs single-device
@@ -115,10 +112,9 @@ DEFAULT_STAGES = [
     (2000, 2000, "fleet-flagship"),  # ISSUE 20: the largest fleet shape
                                      # this box sustains — 24 tenants × 2k
                                      # nodes × 2k pods on the 2-D
-                                     # (tenant × node-shard) mesh with
-                                     # MIXED per-tenant engines; one
-                                     # dispatch per engine group per tick,
-                                     # bit-equality vs per-tenant solo runs
+                                     # (tenant × node-shard) mesh; one
+                                     # dispatch per tick, bit-equality vs
+                                     # per-tenant solo runs
     (250, 1250, "watchplane"),  # ISSUE 13: 16 tenants on ONE mux'd watch
                                 # stream per resource through a real
                                 # apiserver — a 10k ev/s storm with a
@@ -171,10 +167,6 @@ CYCLE_BUDGETS = {
     ("explain", 1000): 30.0,     # worst steady wave with attribution on
                                  # (the 2% overhead claim lives in
                                  # METRIC_BUDGETS; this bounds box stalls)
-    ("classes", 5000): 60.0,     # the run-collapsed dispatch at 5k×50k
-                                 # (the stage also times the per-pod scan
-                                 # for the speedup check — budgeted via
-                                 # METRIC_BUDGETS, not this cycle bound)
     ("gang", 2000): 10.0,        # r5 CPU: 0.38 s (r4: 217 s — fixed)
     ("gang", 5000): 15.0,        # r5 CPU: 0.87 s
     ("control", 1000): 90.0,     # r5 CPU ingest: 15-33 s
@@ -244,13 +236,6 @@ METRIC_BUDGETS = {
                          "lost_pods": ("<=", 0),
                          "replayed_intents": (">=", 1),
                          "takeovers": (">=", 1)},
-    # ISSUE 5 acceptance: the run-collapsed engine reproduces the per-pod
-    # scan bit-exactly on the 200-class deployment backlog, collapses the
-    # serial chain ≥10× (collapse_ratio = valid pods / class runs), and
-    # its device dispatch is measurably faster than the per-pod scan's
-    ("classes", 5000): {"bit_equal": (">=", 1),
-                        "collapse_ratio": (">=", 10),
-                        "runs_vs_scan_speedup": (">=", 1.2)},
     # ISSUE 7 acceptance: the latency stage measures watch→bind e2e under
     # sustained churn. The p50/p99 bounds RECORD today's cycle-granular
     # baseline (the number ROADMAP item 2's micro-waves must beat — the
@@ -331,17 +316,15 @@ METRIC_BUDGETS = {
                       "drf_clamped": (">=", 1),
                       "tenants_lossless": (">=", 1)},
     # ISSUE 20 acceptance: the flagship fleet shape evaluates as ONE XLA
-    # dispatch PER ENGINE GROUP per tick (mixed per-tenant engines — three
-    # groups — so dispatches/groups must be exactly 1), the 2-D mesh run
-    # is bit-equal to per-tenant SOLO single-device runs (one tenant per
-    # engine re-run in isolation; bit_equal_tenants_checked says how many
-    # were actually compared), nothing is lost or double-bound across the
+    # dispatch per tick, the 2-D mesh run is bit-equal to per-tenant SOLO
+    # single-device runs (three tenants re-run in isolation;
+    # bit_equal_tenants_checked says how many were actually compared),
+    # nothing is lost or double-bound across the
     # whole fleet, and the throughput floor keeps the stage a regression
     # gate rather than a smoke test (pods_per_sec is fleet-wide bound
     # pods over wall-clock; floor set ~40% under the measured CPU number)
     ("fleet-flagship", 2000): {
-        "dispatches_per_engine_group": ("<=", 1.0),
-        "engine_groups": (">=", 3),
+        "fleet_dispatches_per_tick": ("<=", 1),
         "bit_equal": (">=", 1),
         "bit_equal_tenants_checked": (">=", 3),
         "node_shards": (">=", 2),
@@ -1527,14 +1510,13 @@ def _fleet_flagship_stage(n_nodes, n_pods):
     K tenants (default 24, KTPU_FLEET_FLAGSHIP_TENANTS) × n_nodes ×
     n_pods each, multiplexed through ONE FleetServer on the 2-D
     (tenant × node-shard) virtual mesh (KTPU_FLEET_NODE_SHARDS, default 2:
-    a 4×2 layout on 8 devices) with MIXED per-tenant engines — tenants
-    round-robin over waves/runs/scan, so every tick runs exactly one
-    vmap'd dispatch PER ENGINE GROUP. After the fleet run, one tenant per
-    engine is re-run SOLO (fresh single-device FleetServer, same nodes and
-    backlog) and its placements compared bit-for-bit; the honest scope of
-    that claim is recorded as bit_equal_tenants_checked. METRIC_BUDGETS
-    enforce dispatches/group == 1, three engine groups, bit-equality,
-    0 lost / 0 double-bound, and the pods/s floor. CPU-budgeted: the
+    a 4×2 layout on 8 devices), so every tick runs exactly one vmap'd
+    dispatch. After the fleet run, three tenants are re-run SOLO (fresh
+    single-device FleetServer, same nodes and backlog) and their
+    placements compared bit-for-bit; the honest scope of that claim is
+    recorded as bit_equal_tenants_checked. METRIC_BUDGETS enforce one
+    dispatch per tick, bit-equality, 0 lost / 0 double-bound, and the
+    pods/s floor. CPU-budgeted: the
     real-accelerator tick budget for this shape rides along as
     real_accel_cycle_budget_s rather than gating the virtual-mesh run."""
     import jax
@@ -1552,8 +1534,6 @@ def _fleet_flagship_stage(n_nodes, n_pods):
     n_devices = len(jax.devices())
     mesh = min(8, n_devices) if n_devices >= 2 else None
     names = [f"t{k:02d}" for k in range(tenants)]
-    engines = {n: FleetServer.ENGINES[k % len(FleetServer.ENGINES)]
-               for k, n in enumerate(names)}
     batch = min(4096, max(64, n_pods // 2))
     base = Dims(N=bucket(n_nodes), P=bucket(batch), E=bucket(n_pods + 256))
     nodes = make_nodes(n_nodes)
@@ -1594,7 +1574,7 @@ def _fleet_flagship_stage(n_nodes, n_pods):
         return srv, binders, ticks, time.perf_counter() - t0, t_ingest
 
     srv, binders, ticks, t_total, t_ingest = run(
-        names, mesh=mesh, node_shards=node_shards, engines=engines)
+        names, mesh=mesh, node_shards=node_shards)
 
     # ---- loss / duplication math (per tenant; queued ≠ lost) ---------- #
     per_tenant_bound = {n: len(b.bound) for n, b in binders.items()}
@@ -1610,28 +1590,24 @@ def _fleet_flagship_stage(n_nodes, n_pods):
         lost_by_tenant[name] = n_pods - len(set(keys)) - q
     lost = sum(lost_by_tenant.values())
 
-    # ---- bit-equality vs per-tenant SOLO runs: one tenant per engine -- #
-    # (fresh single-device FleetServer per tenant — the 2-D-sharded mixed-
-    # engine fleet must reproduce each solo run's placements exactly)
-    checked = names[:min(len(FleetServer.ENGINES), tenants)]
+    # ---- bit-equality vs per-tenant SOLO runs ------------------------- #
+    # (fresh single-device FleetServer per tenant — the 2-D-sharded fleet
+    # must reproduce each solo run's placements exactly)
+    checked = names[:min(3, tenants)]
     bit_equal_by_tenant = {}
     for name in checked:
-        _, solo_binders, _, _, _ = run(
-            [name], mesh=None, engines={name: engines[name]})
+        _, solo_binders, _, _, _ = run([name], mesh=None)
         bit_equal_by_tenant[name] = int(
             sorted(solo_binders[name].bound) == sorted(binders[name].bound))
 
     steady = [w for w, _ in ticks[1:]] or [ticks[0][0]]
     mesh_shape = list(fleet_mesh_shape(srv.mesh)) if srv.mesh else [1, 1]
-    groups = srv.max_engine_groups
     print(json.dumps({
         "nodes": n_nodes, "pods": n_pods, "kind": "fleet-flagship",
         "tenants": tenants, "n_devices": n_devices,
         "mesh_shape": mesh_shape,
         "node_shards": mesh_shape[1],
-        "engine_mix": {e: sum(1 for v in engines.values() if v == e)
-                       for e in FleetServer.ENGINES},
-        "stack_k": {e: s.K for e, s in sorted(srv.stacks.items())},
+        "stack_k": srv.stack.K,
         "scheduled": scheduled,
         "failed": max(tenants * n_pods - scheduled - still_queued, 0),
         "queued": still_queued,
@@ -1642,19 +1618,11 @@ def _fleet_flagship_stage(n_nodes, n_pods):
         "ticks": len(ticks),
         "ingest_seconds": round(t_ingest, 2),
         "fleet_dispatches_per_tick": srv.max_dispatches_per_tick,
-        "engine_groups": groups,
-        # exactly 1.0 when every tick ran one dispatch per engine group —
-        # a retry or a split group shows up as > 1 here
-        "dispatches_per_engine_group": round(
-            srv.max_dispatches_per_tick / max(groups, 1), 3),
         "drf_violations": srv.total_drf_violations,
         "cross_tenant_placements": srv.total_cross_tenant,
-        "full_restacks": {e: s.full_restacks
-                          for e, s in sorted(srv.stacks.items())},
-        "donated_patches": sum(s.donated_patches
-                               for s in srv.stacks.values()),
-        "donation_failures": sum(s.donation_failures
-                                 for s in srv.stacks.values()),
+        "full_restacks": srv.stack.full_restacks,
+        "donated_patches": srv.stack.donated_patches,
+        "donation_failures": srv.stack.donation_failures,
         "lost_pods": lost,
         "double_bound": double,
         "tenants_lossless": int(all(v == 0
@@ -1911,81 +1879,6 @@ def _watchplane_stage(n_nodes, n_pods):
     plane.stop()
     api.close()
     print(json.dumps(out))
-
-
-def _classes_stage(n_nodes, n_pods):
-    """ISSUE 5 acceptance stage: equivalence-class collapsed admission on a
-    deployment-style backlog (200 classes, replicas stamped in contiguous
-    creation bursts — the shape a controller scale-up produces). ONE
-    snapshot is dispatched through BOTH sequential engines — the per-pod
-    scan (ops/assign.py, P serialized steps) and the run-collapsed engine
-    (ops/runs.py, one step per class run) — placements must be bit-equal,
-    the scan-step collapse ≥10×, and the collapsed dispatch measurably
-    faster (METRIC_BUDGETS enforces all three)."""
-    import jax
-    import numpy as np
-
-    from kubernetes_tpu.models.workloads import (
-        deployment_backlog_pods, make_nodes)
-    from kubernetes_tpu.sched.cycle import _schedule_batch, snapshot_with_keys
-    from kubernetes_tpu.state.cache import SchedulerCache
-    from kubernetes_tpu.state.dims import Dims
-    from kubernetes_tpu.state.encode import Encoder
-
-    nodes = make_nodes(n_nodes)
-    pods = deployment_backlog_pods(n_pods, deployments=200)
-    base = Dims(N=n_nodes, P=n_pods, E=1)
-    cache = SchedulerCache()
-    enc = Encoder()
-    for n in nodes:
-        cache.add_node(n)
-    t0 = time.perf_counter()
-    enc.intern_pods(pods)
-    t_ingest = time.perf_counter() - t0
-    # KTPU_ASSIGN=runs while snapshotting so the cache emits the RunPlan
-    # (the host-counted scan-length bound) alongside the pending arrays
-    os.environ["KTPU_ASSIGN"] = "runs"
-    snap, keys = snapshot_with_keys(cache, enc, pods, base)
-    plan = snap.runs
-
-    def dispatch(engine):
-        os.environ["KTPU_ASSIGN"] = engine
-        t0 = time.perf_counter()
-        res = _schedule_batch(
-            snap.tables, snap.pending, keys, snap.dims.D, snap.existing,
-            has_node_name=snap.dims.has_node_name, gang=snap.gang,
-            runs=snap.runs)
-        node = np.asarray(jax.device_get(res.node))
-        return node, time.perf_counter() - t0
-
-    # warm (compile) both engines, then measure the steady dispatch
-    node_runs, _ = dispatch("runs")
-    node_scan, _ = dispatch("scan")
-    node_runs2, t_runs = dispatch("runs")
-    node_scan2, t_scan = dispatch("scan")
-    os.environ.pop("KTPU_ASSIGN", None)
-    bit_equal = bool((node_runs == node_scan).all()
-                     and (node_runs == node_runs2).all()
-                     and (node_scan == node_scan2).all())
-    n_sched = int((node_runs[:n_pods] >= 0).sum())
-    print(json.dumps({
-        "nodes": n_nodes, "pods": n_pods, "kind": "classes",
-        "scheduled": n_sched, "failed": n_pods - n_sched,
-        "class_runs": plan.n_runs,
-        "collapse_ratio": round(plan.collapse_ratio, 1),
-        "scan_steps_runs": plan.rc,
-        "scan_steps_scan": int(snap.dims.P),
-        "runs_dispatch_seconds": round(t_runs, 3),
-        "scan_dispatch_seconds": round(t_scan, 3),
-        "runs_vs_scan_speedup": round(t_scan / max(t_runs, 1e-9), 2),
-        # the collapsed engine runs the whole wave as ONE dispatch
-        "device_per_wave_seconds": round(t_runs, 3),
-        "bit_equal": int(bit_equal),
-        "ingest_seconds": round(t_ingest, 2),
-        "cycle_seconds": round(t_runs, 3),
-        "pods_per_sec": round(n_sched / max(t_runs, 1e-9), 1),
-        "backend": jax.default_backend(),
-    }))
 
 
 class _TimedSpan:
@@ -2905,9 +2798,6 @@ def _stage_main(n_nodes, n_pods, kind):
     if kind == "multichip":
         _multichip_stage(n_nodes, n_pods)
         return
-    if kind == "classes":
-        _classes_stage(n_nodes, n_pods)
-        return
     if kind == "latency":
         _latency_stage(n_nodes, n_pods)
         return
@@ -3070,7 +2960,7 @@ def _compact_line(full, out_name, wrote):
                 e["cross_tenant"] = r.get("cross_tenant_placements")
             if r.get("kind") == "fleet-flagship":
                 e["pods_per_sec"] = r.get("pods_per_sec")
-                e["disp_per_group"] = r.get("dispatches_per_engine_group")
+                e["disp_per_tick"] = r.get("fleet_dispatches_per_tick")
                 e["bit_equal"] = r.get("bit_equal")
             if r.get("kind") == "latency":
                 e["p50_ms"] = r.get("p50_ms")

@@ -29,9 +29,10 @@ chiprun_out/chip_smoke_{1chip,mesh4,programs}.json.
   python chip_smoke.py --mesh 4     the serving leg alone, node axis sharded
                                     over 4 chips
   python chip_smoke.py --programs   builder's census instead of the legs:
-                                    AOT-compile the other engines' programs
-                                    at the flagship shape (and time the gang
-                                    program both ways)
+                                    AOT-compile the programs the legs do
+                                    not reach (scan, explain tail, fleet,
+                                    gang) at the flagship shape, and run
+                                    the gang program
 """
 
 from __future__ import annotations
@@ -298,7 +299,7 @@ def oracle_leg(seed: int = 0, n_nodes: int = 64, n_pods: int = 256) -> dict:
 
     def run(engine, return_waves=False):
         return _schedule_batch_impl(tables, pe, keys, d.D, ex, engine, hw,
-                                    ecfg, (), (), None, return_waves, 0)
+                                    ecfg, (), (), None, return_waves)
 
     mask_dev = _feasible(tables, pe, keys, d.D, ex)
     scan_res = run("scan")
@@ -951,8 +952,8 @@ def extender_leg(n_nodes: int) -> dict:
 
 def programs_census(n_nodes: int, n_pods: int) -> dict:
     """AOT-compile, through the prewarmer's own path, the programs the legs
-    do not reach, at the flagship Dims: the `runs` and `scan` engines, the
-    explain tail, the fleet cycle at the bench `fleet` stage's shape, and the
+    do not reach, at the flagship Dims: the `scan` engine, the explain
+    tail, the fleet cycle at the bench `fleet` stage's shape, and the
     gang program at n_nodes x 2*n_pods — which is also RUN (the single
     device-loop program, ops/gang.py assign_gang). Per program: compiled or
     the compiler's refusal, compile seconds, memory_analysis() bytes."""
@@ -962,7 +963,6 @@ def programs_census(n_nodes: int, n_pods: int) -> dict:
 
     from kubernetes_tpu.models.workloads import (
         flagship_pods, gang_workload_pods, make_nodes)
-    from kubernetes_tpu.ops.runs import plan_runs
     from kubernetes_tpu.sched import cycle
     from kubernetes_tpu.sched.prewarm import (
         BucketPrewarmer, abstract_cycle_args)
@@ -1004,30 +1004,27 @@ def programs_census(n_nodes: int, n_pods: int) -> dict:
         programs.append(rec)
         return rec
 
-    def via_prewarmer(d, engine, gang=False, rc=0, fleet=None):
+    def via_prewarmer(d, engine, gang=False, fleet=None):
         """sched/prewarm.py _compile stores the executable or reports the
         failure to its supervisor; surface whichever happened."""
         pw = BucketPrewarmer()
         pw.supervisor = DispatchSupervisor(prewarmer=pw)
-        pw._compile(d, engine, (), gang, None, rc, fleet)
-        compiled = pw.lookup(d, engine, (), gang, rc=rc, fleet=fleet)
+        pw._compile(d, engine, (), gang, None, fleet)
+        compiled = pw.lookup(d, engine, (), gang, fleet=fleet)
         if compiled is None:
             raise RuntimeError(pw.supervisor.stats.last_failure)
         return compiled
 
-    _enc, _t, _ex, pe, d, _k = encode(
+    _enc, _t, _ex, _pe, d, _k = encode(
         flagship_pods(n_pods), serving_dims(n_nodes, n_pods, CHURN))
-    rc = plan_runs(pe.cls, pe.priority, pe.creation, pe.valid,
-                   pe.node_name_req).rc
     census("scan", d, lambda: via_prewarmer(d, "scan"))
-    census(f"runs rc={rc}", d, lambda: via_prewarmer(d, "runs", rc=rc))
 
     def explain_tail():
         (tables, pending, keys, existing, hw, ecfg,
          _gang) = abstract_cycle_args(d)
         return cycle._schedule_batch_impl.lower(
             tables, pending, keys, d.D, existing, "waves", hw, ecfg, (), (),
-            None, False, 0, True).compile()
+            None, False, True).compile()
 
     census("waves + explain tail", d, explain_tail)
 

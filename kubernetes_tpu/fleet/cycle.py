@@ -14,12 +14,12 @@ and per-tenant placements stay bit-equal to a solo run under the same clamp
 (vmap of these engines is element-wise exact; the bit-equality suite in
 tests/test_fleet.py holds the line).
 
-Engines: 'waves' (default), 'scan', and 'runs' — the run-collapsed engine's
-static scan bound `rc` is shared across the stack (the max of the tenants'
-RunPlans; masking merges/shrinks runs, never splits, so a shared upper
-bound is sound for every tenant). Gang-bearing tenant batches are NOT
-vmapped (group-atomic admission runs host rejection rounds); the server
-routes those tenants through their own single-cluster wave.
+Engines: the server's tick dispatches 'waves'; `engine` is the prewarm
+key's engine slot, and 'scan' traces the executable spec under the same vmap.
+Gang-bearing and nodeName-bearing tenant batches are
+NOT vmapped (group-atomic admission runs host rejection rounds; a pin would
+serialize every tenant); the server routes those tenants through their own
+single-cluster wave.
 """
 
 from __future__ import annotations
@@ -56,7 +56,7 @@ def fleet_signature(K: int) -> int:
     return int(K)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 5, 9, 10))
+@functools.partial(jax.jit, static_argnums=(3, 5, 9))
 def _fleet_cycle_impl(
     tables,          # stacked ClusterTables [K, …]
     pending,         # stacked PodArrays [K, P]
@@ -67,10 +67,8 @@ def _fleet_cycle_impl(
     quota,           # [K] f32 DRF quota fraction per tenant
     hard_weight=1.0,
     ecfg=None,
-    rc: int = 0,
     explain: bool = False,
 ):
-    from ..ops.runs import assign_runs
     from ..ops.waves import assign_waves
 
     def body(t, pe, ky, ex, q):
@@ -82,8 +80,6 @@ def _fleet_cycle_impl(
         init = initial_state(t, cyc)
         if engine == "scan":
             res = assign_batch(t, cyc, clamped, init)
-        elif engine == "runs":
-            res = assign_runs(t, cyc, clamped, init, rc)
         else:
             res = assign_waves(t, cyc, clamped, init)
         exp = None
@@ -106,12 +102,12 @@ def _fleet_cycle_impl(
 
 
 def dispatch_fleet(tables, pending, keys, D, existing, engine, quota,
-                   hard_weight: float = 1.0, ecfg=None, rc: int = 0,
+                   hard_weight: float = 1.0, ecfg=None,
                    dims=None, prewarmer=None, mesh=None,
                    explain: bool = False):
     """The fleet analog of sched/cycle.py `_schedule_batch`: normalize the
     traced config scalars, probe the prewarmer for an AOT executable under
-    the FLEET key (dims, engine, rc, fleet=K, mesh) — a single-cluster
+    the FLEET key (dims, engine, fleet=K, mesh) — a single-cluster
     Compiled can never answer, the key slot forbids it — and fall through
     to the ordinary jit. With `explain` (ISSUE 10, KTPU_EXPLAIN) the
     prewarmed executables are bypassed (they were compiled without the
@@ -125,11 +121,11 @@ def dispatch_fleet(tables, pending, keys, D, existing, engine, quota,
     hw = jnp.float32(hard_weight)
     if prewarmer is not None and dims is not None and not explain:
         compiled = prewarmer.lookup(dims, engine, (), False, mesh=mesh,
-                                    rc=rc, fleet=fleet_signature(K))
+                                    fleet=fleet_signature(K))
         if compiled is not None:
             ok, out = prewarmer.call(compiled, tables, pending, keys,
                                      existing, quota, hw, ecfg)
             if ok:
                 return FleetResult(*out)
     return _fleet_cycle_impl(tables, pending, keys, D, existing, engine,
-                             quota, hw, ecfg, rc, explain)
+                             quota, hw, ecfg, explain)

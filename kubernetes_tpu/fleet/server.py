@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import configured_engine
 from ..sched.scheduler import CycleStats, Scheduler
 from ..state.dims import Dims
 from ..utils import faultline
@@ -357,9 +356,8 @@ class FleetTickStats:
 
     per_tenant: Dict[str, CycleStats] = field(default_factory=dict)
     dispatches: int = 0               # XLA dispatches this tick (budget:
-                                      # one per ENGINE GROUP — 1 for a
-                                      # uniform-engine fleet)
-    engine_groups: int = 0            # distinct per-tenant engines this tick
+                                      # ONE stacked dispatch, plus one per
+                                      # solo-routed tenant)
     drf_violations: int = 0           # tenants whose admitted demand broke
                                       # their headroom (budget: 0)
     drf_clamped: int = 0              # pods deferred by the quota pre-mask
@@ -385,14 +383,9 @@ class FleetServer:
     """One resident scheduler serving K virtual tenant clusters per vmap'd
     tick. See the module docstring for the ownership model."""
 
-    #: the engines a per-tenant config may name (the lattice the
-    #: single-cluster KTPU_ASSIGN knob normalizes into)
-    ENGINES = ("waves", "runs", "scan")
-
     def __init__(self, batch_size: int = 1024,
                  base_dims: Optional[Dims] = None, mesh=None,
                  node_shards: Optional[int] = None,
-                 engines: Optional[Dict[str, str]] = None,
                  clock: Callable[[], float] = time.monotonic,
                  scheduler_name: str = "default-scheduler",
                  storage=None):
@@ -407,18 +400,6 @@ class FleetServer:
         self.clock = clock
         self.scheduler_name = scheduler_name
         self.storage = storage
-        # per-tenant engine config: tenants grouped by engine run as
-        # sub-dispatches of the same tick (one vmap'd dispatch per GROUP);
-        # unlisted tenants follow the fleet default (KTPU_ASSIGN). Unlike
-        # the env knob — which normalizes garbage to "waves" — an explicit
-        # config naming an unknown engine is a caller bug and raises.
-        engines = dict(engines or {})
-        bad = {n: e for n, e in engines.items() if e not in self.ENGINES}
-        if bad:
-            raise ValueError(
-                f"unknown engine(s) in per-tenant config: {bad!r} — "
-                f"valid engines: {self.ENGINES}")
-        self.engines: Dict[str, str] = engines
         if node_shards is None:
             node_shards = env_int("KTPU_FLEET_NODE_SHARDS", 1, 1, 64)
         self.node_shards = int(node_shards)
@@ -436,9 +417,8 @@ class FleetServer:
 
         self.telemetry = SchedulerTelemetry(name="fleet")
         self.supervisor.event_sink = self.telemetry.note_supervisor_event
-        # one resident FleetStack PER ENGINE GROUP, created lazily — a
-        # uniform-engine fleet (the common case) holds exactly one
-        self.stacks: Dict[str, FleetStack] = {}
+        # the resident stacked tables every tick's dispatch runs on
+        self.stack = FleetStack(mesh=self.mesh)
         self._fleet_dims: Dims = replace(base_dims or Dims(),
                                          has_node_name=False)
         self.tenants: Dict[str, FleetTenant] = {}
@@ -448,7 +428,6 @@ class FleetServer:
         self.total_cross_tenant = 0
         self.total_drf_clamped = 0
         self.max_dispatches_per_tick = 0
-        self.max_engine_groups = 1
         self._super_epoch = self._supervisor_epoch()
         # re-admission rewarm must target the FLEET mesh's executable key.
         # With a fleet-mode MeshState attached the supervisor reforms the
@@ -494,32 +473,6 @@ class FleetServer:
             return None, None
         return state.mesh, state
 
-    # ------------------------------------------------------------------ #
-    # per-engine-group residency
-    # ------------------------------------------------------------------ #
-
-    def _engine_for(self, name: str) -> str:
-        return self.engines.get(name) or configured_engine()
-
-    def _stack_for(self, engine: str) -> FleetStack:
-        st = self.stacks.get(engine)
-        if st is None:
-            st = self.stacks[engine] = FleetStack(mesh=self.mesh)
-        return st
-
-    @property
-    def stack(self) -> FleetStack:
-        """The default-engine group's stack — THE stack of a
-        uniform-engine fleet (back-compat accessor for tests/bench
-        reading restack/donation counters)."""
-        if len(self.stacks) == 1:
-            return next(iter(self.stacks.values()))
-        return self._stack_for(configured_engine())
-
-    def _invalidate_stacks(self) -> None:
-        for st in self.stacks.values():
-            st.invalidate()
-
     def _node_shard_width(self) -> int:
         if self.mesh is None:
             return 1
@@ -529,14 +482,13 @@ class FleetServer:
 
     def _sync_mesh(self) -> None:
         """Adopt the MeshState's current mesh (degrade dropped it; reform
-        rebuilt it — possibly narrower, always a FRESH object). Every
-        group stack re-homes and full-restacks onto the new placement."""
+        rebuilt it — possibly narrower, always a FRESH object). The stack
+        re-homes and full-restacks onto the new placement."""
         if self.mesh_state is None or self.mesh_state.mesh is self.mesh:
             return
         self.mesh = self.mesh_state.mesh
-        for st in self.stacks.values():
-            st.mesh = self.mesh
-            st.invalidate()
+        self.stack.mesh = self.mesh
+        self.stack.invalidate()
 
     # ------------------------------------------------------------------ #
     # tenant lifecycle
@@ -766,15 +718,14 @@ class FleetServer:
         # failure path must hand them back to their queues — losing them
         # is the one thing a scheduler may never do
         try:
-            out, snaps = self._dispatch_tick(tlist, batches, tick, now,
-                                             span)
+            out, exp, snaps = self._dispatch_tick(tlist, batches, tick, now,
+                                                  span)
         except DispatchAbandonedError:
             # the abandoned worker's zombie thread may still hold (or be
             # executing on) the resident stacked buffers — never donate or
             # scatter onto them again; the next healthy tick full-restacks.
-            # Earlier engine groups' (uncommitted) results are discarded
-            # with the requeue: every popped pod goes back to its queue.
-            self._invalidate_stacks()
+            # Every popped pod goes back to its queue.
+            self.stack.invalidate()
             self._requeue_batches(tlist, batches, tick, now)
             span.mark("requeue")
             tick.tick_seconds = time.perf_counter() - t0
@@ -784,15 +735,15 @@ class FleetServer:
             # any other post-pop failure (bucket non-convergence, a
             # donation assert in the stack refresh, an unexpected dispatch
             # error): requeue everything, drop the possibly half-patched
-            # stacks, and re-raise for visibility
-            self._invalidate_stacks()
+            # stack, and re-raise for visibility
+            self.stack.invalidate()
             self._requeue_batches(tlist, batches, tick, now)
             span.mark("requeue")
             tick.tick_seconds = time.perf_counter() - t0
             self._finish_tick(tick, span)
             raise
 
-        self._commit_tick(out, batches, snaps, tick, now)
+        self._commit_tick(tlist, out, exp, batches, snaps, tick, now)
         span.mark("bind-commit")
         tick.tick_seconds = time.perf_counter() - t0
         # per-tenant governor feedback: the shared tick's wall time is
@@ -830,12 +781,12 @@ class FleetServer:
 
     def _dispatch_tick(self, tlist, batches, tick, now, span):
         """Everything between the batch pop and the device results: the
-        snapshot convergence round, solo routing, per-engine-group resident
-        stack refresh and ONE vmap'd dispatch per engine group (exactly one
-        for a uniform-engine fleet). Raises propagate to tick()'s requeue
-        guard — this method never loses a popped pod."""
+        snapshot convergence round, solo routing, the resident stack's
+        refresh and ONE vmap'd dispatch. Returns `(out, exp, snaps)` for
+        _commit_tick. Raises propagate to tick()'s requeue guard — this
+        method never loses a popped pod."""
         # adopt a reformed/dropped mesh BEFORE snapshotting: the bucket's
-        # node-shard divisibility and the stacks' placement follow it
+        # node-shard divisibility and the stack's placement follow it
         self._sync_mesh()
         snaps, keys = self._snapshot_round(tlist, batches)
         span.mark("snapshot")
@@ -896,28 +847,17 @@ class FleetServer:
             snaps, keys = self._snapshot_round(tlist, batches)
             span.mark("solo")
 
-        # ---- per-tenant engine grouping + shared static run bounds ---- #
         # no waves→scan downgrade here: nodeName-bearing batches were solo-
-        # routed above, so every snapshot entering the shared programs has
+        # routed above, so every snapshot entering the shared program has
         # has_node_name=False (re-snapshotted with an empty batch) — one
-        # tenant's pin must never serialize the other K-1 tenants.
-        # Tenants group by their configured engine; each group is one
-        # sub-dispatch of this tick (one vmap'd program per group, so a
-        # runs tenant's static bound never recompiles the waves group).
-        groups: Dict[str, List] = {}
-        for t in tlist:
-            groups.setdefault(self._engine_for(t.name), []).append(t)
-        order = {e: i for i, e in enumerate(self.ENGINES)}
-        group_items = sorted(groups.items(),
-                             key=lambda kv: order.get(kv[0], len(order)))
-        tick.engine_groups = len(group_items)
-
+        # tenant's pin must never serialize the other K-1 tenants
+        engine = "waves"
         d = self._fleet_dims
         if self.supervisor.healthy:
             epoch = self._supervisor_epoch()
             if epoch != self._super_epoch:
                 # the primary hung/failed or the backend was re-admitted
-                # since the stacks' last refresh: a hung dispatch's
+                # since the stack's last refresh: a hung dispatch's
                 # abandoned worker may STILL hold the resident buffers
                 # (handle.result() returned the fallback's answer without
                 # raising), and a sub-second probe can re-admit before the
@@ -925,35 +865,14 @@ class FleetServer:
                 # from under the wedged execution. Full-restack fresh
                 # instead (the fleet analog of the cache's
                 # _dispatch_inflight copy gate).
-                self._invalidate_stacks()
+                self.stack.invalidate()
                 self._super_epoch = epoch
 
-        results: List[Tuple] = []
-        for engine, gts in group_items:
-            results.append(self._dispatch_group(
-                engine, gts, batches, snaps, keys, d, tick, span))
-        return results, snaps
-
-    def _dispatch_group(self, engine, gts, batches, snaps, keys, d, tick,
-                        span):
-        """One engine group's sub-dispatch: refresh ITS resident stack,
-        pad ITS quota vector, prewarm/sign under ITS fleet key, submit and
-        read back. Returns (gts, out, exp) for _commit_tick."""
-        from ..sched.cycle import _resolve_rc
-
-        rc = 0
-        if engine == "runs":
-            for t in gts:
-                sn = snaps[t.name]
-                rc = max(rc, _resolve_rc(sn.pending, sn.runs))
-                if sn.runs is not None:
-                    tick.per_tenant[t.name].class_runs = sn.runs.n_runs
-
         # ---- resident stack refresh (donated per-tenant row patches) --- #
-        stack = self._stack_for(engine)
+        stack = self.stack
         if self.supervisor.healthy:
-            Kp = stack.refresh([snaps[t.name] for t in gts],
-                               [keys[t.name] for t in gts], d)
+            Kp = stack.refresh([snaps[t.name] for t in tlist],
+                               [keys[t.name] for t in tlist], d)
         else:
             # degraded: the resident buffers live on the lost backend —
             # scattering onto them would dispatch onto dead hardware before
@@ -961,9 +880,9 @@ class FleetServer:
             # full restack on re-admission) and let the fallback re-encode
             # from host staging; submit() skips the primary while unhealthy.
             stack.invalidate()
-            Kp = stack.padded_k(len(gts))
+            Kp = stack.padded_k(len(tlist))
         span.mark("stack-refresh")
-        quota = jnp.asarray(self._pad_quota(gts, Kp), jnp.float32)
+        quota = jnp.asarray(self._pad_quota(tlist, Kp), jnp.float32)
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -975,20 +894,19 @@ class FleetServer:
         # ---- compile-ahead + supervisor bookkeeping under the FLEET key - #
         fsig = fleet_signature(Kp)
         self.prewarmer.observe(
-            d, n_nodes=max(t.sched.cache.node_count for t in gts),
-            n_existing=max(t.sched.cache.pod_count for t in gts),
-            engine=engine, mesh=self.mesh, rc=rc, fleet=fsig)
-        self.prewarmer.ensure_warm(d, engine, mesh=self.mesh, rc=rc,
-                                   fleet=fsig)
-        self.supervisor.note_cycle_signature(d, engine, (), False, rc=rc,
+            d, n_nodes=max(t.sched.cache.node_count for t in tlist),
+            n_existing=max(t.sched.cache.pod_count for t in tlist),
+            engine=engine, mesh=self.mesh, fleet=fsig)
+        self.prewarmer.ensure_warm(d, engine, mesh=self.mesh, fleet=fsig)
+        self.supervisor.note_cycle_signature(d, engine, (), False,
                                              fleet=fsig)
         span.mark("prewarm")
 
-        # ---- ONE vmap'd dispatch for this engine group ---- #
+        # ---- ONE vmap'd dispatch ---- #
         # decision provenance (ISSUE 10): one flag for the whole stack —
         # tenants share the process env, and the vmap'd program is one
         # executable. Attribution fans back out per tenant in _commit_tick.
-        explain_on = any(t.sched.explainer is not None for t in gts)
+        explain_on = any(t.sched.explainer is not None for t in tlist)
 
         def _primary():
             if stack.block is None:
@@ -998,18 +916,18 @@ class FleetServer:
                 # _readmit flips health asynchronously): full-restack from
                 # THIS tick's snapshots instead of dereferencing the
                 # dropped buffers
-                stack.refresh([snaps[t.name] for t in gts],
-                              [keys[t.name] for t in gts], d)
+                stack.refresh([snaps[t.name] for t in tlist],
+                              [keys[t.name] for t in tlist], d)
             out = dispatch_fleet(stack.tables, stack.pending, stack.keys,
                                  d.D, stack.existing, engine, quota,
-                                 rc=rc, dims=d, prewarmer=self.prewarmer,
+                                 dims=d, prewarmer=self.prewarmer,
                                  mesh=self.mesh, explain=explain_on)
             res, exp = out if explain_on else (out, None)
             return jax.device_get(res), \
                 (jax.device_get(exp) if exp is not None else None)
 
         def _fallback(dev, hung=False):
-            # degraded fleet tick: re-encode this group's tenants onto the
+            # degraded fleet tick: re-encode the tenants onto the
             # CPU fallback from host staging (the single-cluster ladder,
             # per tenant) and dispatch the stack there — no resident
             # buffers of the lost backend are touched
@@ -1017,7 +935,7 @@ class FleetServer:
             from .tables import stack_blocks
 
             blocks = []
-            for t in gts:
+            for t in tlist:
                 sn, ky = snapshot_with_keys(
                     t.sched.cache, t.sched.encoder,
                     [p for p, _ in batches[t.name]], self._fleet_dims,
@@ -1029,10 +947,10 @@ class FleetServer:
 
                 blocks.extend([empty_tenant_block(d)] * (Kp - len(blocks)))
             tb, pe, ex, ky = jax.device_put(stack_blocks(blocks), dev)
-            q = jax.device_put(jnp.asarray(self._pad_quota(gts, Kp),
+            q = jax.device_put(jnp.asarray(self._pad_quota(tlist, Kp),
                                            jnp.float32), dev)
             with jax.default_device(dev):
-                out = dispatch_fleet(tb, pe, ky, d.D, ex, engine, q, rc=rc,
+                out = dispatch_fleet(tb, pe, ky, d.D, ex, engine, q,
                                      explain=explain_on)
                 res, exp = out if explain_on else (out, None)
                 return jax.device_get(res), \
@@ -1043,24 +961,20 @@ class FleetServer:
         handle = self.supervisor.submit(
             "cycle",
             (replace(d, has_node_name=False), engine, fsig,
-             _mesh_key(self.mesh), rc),
+             _mesh_key(self.mesh)),
             _primary, _fallback)
         span.mark("dispatch")
         out, exp = handle.result()
         span.mark("readback")
         tick.dispatches += 1
-        return (gts, out, exp)
+        return out, exp, snaps
 
-    def _commit_tick(self, results, batches, snaps, tick, now) -> None:
+    def _commit_tick(self, tlist, out, exp, batches, snaps, tick,
+                     now) -> None:
         """The per-tenant commit loops (PR 4 machinery per tenant): intent
         write → assume → fenced bind → retire, through each tenant's own
-        Scheduler, plus the DRF violation check over each sub-dispatch's
-        own outputs."""
-        for gts, out, exp in results:
-            self._commit_group(gts, out, exp, batches, snaps, tick, now)
-
-    def _commit_group(self, tlist, out, exp, batches, snaps, tick,
-                      now) -> None:
+        Scheduler, plus the DRF violation check over the dispatch's
+        outputs."""
         node = np.asarray(out.node)
         admitted = np.asarray(out.admitted)
         share = np.asarray(out.share)
@@ -1150,8 +1064,6 @@ class FleetServer:
         self.total_drf_clamped += tick.drf_clamped
         self.max_dispatches_per_tick = max(self.max_dispatches_per_tick,
                                            tick.dispatches)
-        self.max_engine_groups = max(self.max_engine_groups,
-                                     tick.engine_groups)
         # per-tenant attribution happens INSIDE observe_fleet_tick now:
         # the chaos suite and bench assert tenant isolation (and the DRF
         # clamp) from the tenant-labelled metrics, routed through
@@ -1169,7 +1081,6 @@ class FleetServer:
                               "aborted": st.aborted}
                        for name, st in tick.per_tenant.items()},
                 extra={"dispatches": tick.dispatches,
-                       "engine_groups": tick.engine_groups,
                        "drf_violations": tick.drf_violations,
                        "cross_tenant_placements":
                            tick.cross_tenant_placements})
@@ -1188,7 +1099,6 @@ class FleetServer:
             tk = self.tick()
             stalled = stalled + 1 if tk.scheduled == 0 else 0
             total.dispatches += tk.dispatches
-            total.engine_groups = max(total.engine_groups, tk.engine_groups)
             total.drf_violations += tk.drf_violations
             total.drf_clamped += tk.drf_clamped
             total.cross_tenant_placements += tk.cross_tenant_placements

@@ -144,40 +144,3 @@ def flagship_pods(n: int, groups: int = 50) -> List[Pod]:
             creation_index=i,
         ))
     return pods
-
-
-def deployment_backlog_pods(n: int, deployments: int = 200,
-                            seed: int = 0) -> List[Pod]:
-    """Deployment-style backlog (ops/runs.py's motivating shape): each
-    'Deployment' stamps its replicas in one contiguous creation burst —
-    exactly what a controller scale-up produces — so the queue-ordered wave
-    factors into ~`deployments` class runs. Specs are plain requests +
-    labels (self-interaction-free classes: the run-collapsed engine's
-    closed-form waterfill fires on every run). A few priority tiers ride
-    along — each deployment carries ONE priority, so queue order (priority
-    desc, creation asc) keeps its replica block contiguous."""
-    rng = random.Random(seed)
-    per = max(n // deployments, 1)
-    pods: List[Pod] = []
-    i = 0
-    dep = 0
-    while i < n:
-        # per-deployment cpu makes each deployment a DISTINCT equivalence
-        # class even under label projection (unreferenced `app` labels fold
-        # out of class identity — state/encode.py), so the backlog really
-        # carries `deployments` classes, not len(_TIERS)
-        _, mem = _TIERS[rng.randrange(len(_TIERS))]
-        cpu = f"{100 + dep}m"
-        prio = dep % 3
-        size = min(per, n - i)
-        for _ in range(size):
-            pods.append(Pod(
-                name=f"dep-{dep}-{i}",
-                labels={"app": f"dep-{dep}"},
-                requests=Resources.make(cpu=cpu, memory=mem),
-                priority=prio,
-                creation_index=i,
-            ))
-            i += 1
-        dep += 1
-    return pods

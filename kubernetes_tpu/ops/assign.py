@@ -75,10 +75,8 @@ def assign_step(
     node_name_req: Array,
 ) -> Tuple[AssignState, Array, Array]:
     """ONE pod's Filter → Score → selectHost → assume against a live state —
-    the body of the sequential scan, factored out so the run-collapsed
-    engine's per-pod fallback (ops/runs.py) executes the IDENTICAL op
-    sequence (bit-equality between the engines is by shared code, not by
-    re-derivation). Returns (new state, node index or -1, feasible)."""
+    the body of the sequential scan. Returns (new state, node index or -1,
+    feasible)."""
     classes = tables.classes
     req_vec = tables.reqs.vec[classes.rid[c]]
     ps = classes.portset[c]
@@ -194,12 +192,11 @@ def mask_context_row(
     table: TermCounts | None = None,
     spread: SpreadCounts | None = None,
 ) -> Array:
-    """The Filter components that are CONSTANT across a run of same-class
-    replicas when the class is self-interaction-free (ops/runs.py): the
-    static lattice, inter-pod affinity/anti-affinity (counts only move at
-    placed nodes, through terms such a class never reads), hard topology
-    spread, spec.nodeName, and pod validity. The run-collapsed engine
-    evaluates this once per RUN; pod_mask_row recomposes it per pod.
+    """The Filter components that do not move as replicas of a
+    self-interaction-free class land: the static lattice, inter-pod
+    affinity/anti-affinity (counts only move at placed nodes, through terms
+    such a class never reads), hard topology spread, spec.nodeName, and pod
+    validity. pod_mask_row composes it with mask_dynamic_row per pod.
     `table` is `state_affinity_table(state)` and `spread`
     `state_spread_counts(state)` where the caller built them."""
     from .lattice import _on
@@ -271,10 +268,9 @@ def mask_dynamic_row(
 ) -> Array:
     """The Filter components that move as replicas of the SAME class land:
     resources, host ports, volumes — all strictly per-node functions of the
-    passed state planes. The run-collapsed engine re-evaluates exactly this
-    per admission epoch against synthesized per-node planes; the per-pod
-    scan calls it (via pod_mask_row) with the live carry. Composed from the
-    same per-plane helpers the explain attribution decomposes."""
+    passed state planes; the per-pod scan calls it (via pod_mask_row) with
+    the live carry. Composed from the same per-plane helpers the explain
+    attribution decomposes."""
     return (fit_plane(tables, cyc, cls, used)
             & ports_plane(tables, cyc, cls, ppa, ppw, ppt)
             & volumes_plane(tables, cyc, cls, vol_any, vol_rw))
@@ -345,11 +341,9 @@ def score_combine_row(
     ctx: ScoreContext,
 ) -> Array:
     """The exact weighted-sum expression tree of the Score row, parameterized
-    by the per-node `used` plane. BOTH engines go through this one function
-    — the run-collapsed engine with synthesized used-after-j-replicas planes,
-    the scan with the live carry — so the float op sequence (and therefore
-    every rounding) is identical by construction, which is what makes the
-    argmax chains bit-equal."""
+    by the per-node `used` plane. Both engines go through this one function
+    (score_row), so the float op sequence (and therefore every rounding)
+    is identical by construction."""
     nodes, classes = tables.nodes, tables.classes
     w = cyc.ecfg
     req_vec = tables.reqs.vec[classes.rid[cls]]
@@ -578,8 +572,7 @@ def explain_assignments(
       * "pod"   — the spec: one full row per pod (the scan engine's
                   granularity; cost scales with P·N).
       * "class" — the cheap half evaluates ONCE per interned class (the
-                  run-collapsed engine's fan-out; the waves engine shares
-                  it — both already think in [SC, N] planes), then per-pod
+                  waves engine already thinks in [SC, N] planes), then per-pod
                   work is pure GATHERS when no spec.nodeName pod is in the
                   batch (a lax.cond keeps the per-pod host fold for
                   batches that actually pin).

@@ -109,13 +109,9 @@ def assign_gang(
     the GangVerdict (host surfaces per-group events from it).
     Pods of rejected groups come back node=-1/infeasible.
 
-    engine_fn(tables, cyc, pods, init) -> AssignResult lets a sequential
-    engine drive the feasibility loop instead of the wave engine: the
-    literal scan (ops/assign.py, the executable spec) or the run-collapsed
-    scan (ops/runs.py — each rejection round re-masks validity, which only
-    merges or shrinks class runs, so the host-supplied run capacity bound
-    holds for every round and the rounds stay bit-equal to the per-pod
-    scan's). Default is the wave engine."""
+    engine_fn(tables, cyc, pods, init) -> AssignResult lets the literal
+    scan (ops/assign.py assign_batch, the executable spec) drive the
+    feasibility loop instead of the wave engine, the default."""
     GR = gang.needed.shape[0]
     P = pods.valid.shape[0]
 

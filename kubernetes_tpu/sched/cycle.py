@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from ..api.types import Node, Pod
-from ..ops import configured_engine
 from ..ops.assign import assign_batch, initial_state
 from ..ops.lattice import build_cycle, default_engine_config
 from ..state.arrays import ClusterTables, PodArrays
@@ -91,24 +90,19 @@ def _taint_scalars(encoder: Encoder, device, mesh):
     return uk, ev
 
 
-def plan_engine(has_node_name: bool, runs=None) -> Tuple[str, int]:
-    """`(engine, rc)` for one wave: the program it dispatches and the
-    prewarm / supervisor key it is looked up under — the single home of
-    both routing rules. A nodeName-bearing batch reroutes 'waves' to the
-    literal scan: spec.nodeName is a per-POD (not per-class) host
+def plan_engine(has_node_name: bool) -> str:
+    """The program one wave dispatches, and the engine its prewarm /
+    supervisor key names — the single home of the choice. 'waves'
+    (ops/waves.py, wave-parallel dense admission) serves; a
+    nodeName-bearing batch goes to 'scan' (ops/assign.py, the literal
+    sequential-assume lax.scan that is also the executable spec the tests
+    hold 'waves' to): spec.nodeName is a per-POD (not per-class) host
     constraint the class-granular wave path cannot express, and in the
     reference such pods bypass the scheduler entirely (kubelet consumes
-    them), so a batch containing one is rare. (The runs engine splits runs
-    on nodeName and falls back per-pod for pinned stretches, so it keeps
-    such batches.) The flag comes from Dims (computed host-side at encode
-    time) so the hot path never blocks on a device readback. `rc`, the
-    run-collapsed engine's static scan length, is the snapshot's RunPlan's
-    where the engine is 'runs' and the cache emitted one, else 0."""
-    engine = configured_engine()
-    if engine == "waves" and has_node_name:
-        engine = "scan"
-    rc = runs.rc if (engine == "runs" and runs is not None) else 0
-    return engine, rc
+    them), so a batch containing one is rare. The flag comes from Dims
+    (computed host-side at encode time) so the hot path never blocks on a
+    device readback."""
+    return "scan" if has_node_name else "waves"
 
 
 def _apply_extra_plugins(tables, cyc, extra_plugins, extra_weights):
@@ -142,7 +136,7 @@ def _apply_extra_plugins(tables, cyc, extra_plugins, extra_weights):
         score=cyc.static.score + bias))
 
 
-@functools.partial(jax.jit, static_argnums=(3, 5, 8, 11, 12, 13))
+@functools.partial(jax.jit, static_argnums=(3, 5, 8, 11, 12))
 def _schedule_batch_impl(
     tables: ClusterTables,
     pending: PodArrays,
@@ -156,11 +150,9 @@ def _schedule_batch_impl(
     extra_weights: tuple = (),
     gang=None,
     return_waves: bool = False,
-    rc: int = 0,
     explain: bool = False,
 ):
     from ..ops.gang import assign_gang
-    from ..ops.runs import assign_runs
     from ..ops.waves import assign_waves
 
     uk, ev = keys
@@ -169,10 +161,6 @@ def _schedule_batch_impl(
         cyc = build_cycle(tables, existing, uk, ev, D, hard_weight, ecfg)
         cyc = _apply_extra_plugins(tables, cyc, extra_plugins, extra_weights)
         init = initial_state(tables, cyc)
-    # `rc` is the run-collapsed engine's static run capacity (ops/runs.py
-    # plan_runs); it also bounds every gang rejection round's run count
-    # (masking merges/shrinks runs, never splits them)
-    runs_fn = (lambda t, cy, pe, ini: assign_runs(t, cy, pe, ini, rc))
     waves = None
     if gang is not None:
         # group-atomic admission (ops/gang.py); gang=None traces the plain
@@ -181,14 +169,12 @@ def _schedule_batch_impl(
             res, verdict, waves = assign_gang(
                 tables, cyc, pending, init, gang, return_waves=True)
         else:
-            engine_fn = {"scan": assign_batch, "runs": runs_fn}.get(engine)
             res, verdict = assign_gang(
-                tables, cyc, pending, init, gang, engine_fn=engine_fn)
+                tables, cyc, pending, init, gang,
+                engine_fn=assign_batch if engine == "scan" else None)
         res = res._replace(gang=verdict)
     elif engine == "scan":
         res = assign_batch(tables, cyc, pending, init)
-    elif engine == "runs":
-        res = runs_fn(tables, cyc, pending, init)
     elif return_waves:
         # bench/profiling: per-pod admission-wave indices ride along so the
         # driver can report wave counts without a second dispatch
@@ -200,9 +186,9 @@ def _schedule_batch_impl(
         # decision provenance (ISSUE 10): the attribution reduction runs
         # INSIDE this same dispatch, against the post-wave assume state.
         # The scan engine attributes per pod (the spec); the class-interned
-        # engines attribute once per equivalence class and fan out — the
-        # runs engine's collapse applied to observability. A static flag:
-        # explain=False traces the byte-for-byte pre-provenance program.
+        # wave engine attributes once per equivalence class and fans out.
+        # A static flag: explain=False traces the byte-for-byte
+        # pre-provenance program.
         from ..ops.assign import explain_assignments
 
         with jax.named_scope("explain"):
@@ -211,23 +197,6 @@ def _schedule_batch_impl(
                 granularity="pod" if engine == "scan" else "class")
         return res, exp
     return (res, waves) if return_waves else res
-
-
-def _resolve_rc(pending, runs):
-    """The run-collapsed engine's static scan length: the snapshot-supplied
-    RunPlan when the cache emitted one (no readback), else derived from the
-    pending arrays (tests/bench calling the dispatch layer directly — one
-    [P]-column readback, off the serving hot path)."""
-    from ..ops.runs import plan_runs
-
-    if runs is not None:
-        return runs.rc
-    import numpy as np
-
-    return plan_runs(
-        np.asarray(pending.cls), np.asarray(pending.priority),
-        np.asarray(pending.creation), np.asarray(pending.valid),
-        np.asarray(pending.node_name_req)).rc
 
 
 def _schedule_batch(tables, pending, keys, D, existing,
@@ -241,21 +210,17 @@ def _schedule_batch(tables, pending, keys, D, existing,
                     dims=None,
                     prewarmer=None,
                     mesh=None,
-                    runs=None,
                     explain: bool = False,
-                    engine: Optional[str] = None,
-                    rc: int = 0):
+                    engine: Optional[str] = None):
     # the two opt-in result tails are mutually exclusive by contract:
     # return_waves callers unpack (res, waves) and would silently read an
     # ExplainResult as the wave-index array
     assert not (explain and return_waves), \
         "explain and return_waves cannot be combined"
-    # a wave passes the plan it keyed its prewarm and supervisor budget
+    # a wave passes the engine it keyed its prewarm and supervisor budget
     # on; a direct caller (tests, bench) passes none and gets the same one
     if engine is None:
-        engine, rc = plan_engine(has_node_name, runs)
-    if engine == "runs" and runs is None:
-        rc = _resolve_rc(pending, None)
+        engine = plan_engine(has_node_name)
     # hardPodAffinitySymmetricWeight (apis/config/types.go:70) and the
     # EngineConfig plugin composition ride as traced f32 scalars so config
     # changes never recompile
@@ -277,10 +242,8 @@ def _schedule_batch(tables, pending, keys, D, existing,
         # single-device one at the same Dims are different executables, and
         # invoking one with the other's arrays would silently reshard onto
         # (possibly dead) devices — lookup isolation makes that impossible.
-        # The run capacity rc is part of the key for the same reason: a
-        # different run bucket is a different compiled program.
         compiled = prewarmer.lookup(dims, engine, extra_plugins,
-                                    gang is not None, mesh=mesh, rc=rc)
+                                    gang is not None, mesh=mesh)
         if compiled is not None:
             ok, out = prewarmer.call(compiled, tables, pending, keys,
                                      existing, hw, ecfg, extra_weights, gang)
@@ -289,7 +252,7 @@ def _schedule_batch(tables, pending, keys, D, existing,
     return _schedule_batch_impl(tables, pending, keys, D, existing, engine,
                                 hw, ecfg,
                                 extra_plugins, extra_weights, gang,
-                                return_waves, rc, explain)
+                                return_waves, explain)
 
 
 @functools.partial(jax.jit, static_argnums=(3,))

@@ -189,11 +189,10 @@ class BucketPrewarmer:
         self._inflight_preempt: Optional[threading.Thread] = None
         self._compile_fn = compile_fn or self._compile
         self.warm_log: list = []   # (dims, engine) actually compiled — tests
-        # (dims, engine, extras, gang, rc, fleet, mesh sig) → jax Compiled
-        # for the cycle program (rc = the run-collapsed engine's static run
-        # capacity, 0 for the other engines; fleet = the tenant-stack count
-        # K of a fleet/cycle.py program, None for single-cluster — the slot
-        # that makes it impossible for a K-tenant Compiled to be handed a
+        # (dims, engine, extras, gang, fleet, mesh sig) → jax Compiled
+        # for the cycle program (fleet = the tenant-stack count K of a
+        # fleet/cycle.py program, None for single-cluster — the slot that
+        # makes it impossible for a K-tenant Compiled to be handed a
         # single cluster's arrays or vice versa);
         # ("preempt", dims, burst) → Compiled for the preemption burst
         self.compiled: dict = {}
@@ -216,10 +215,17 @@ class BucketPrewarmer:
 
         return mesh_key(mesh)
 
+    @classmethod
+    def _cycle_key(cls, d: Dims, engine: str, extras: tuple, gang: bool,
+                   fleet, mesh):
+        # has_node_name is a per-batch routing fact the ENGINE slot already
+        # carries (sched/cycle.py plan_engine), not a capacity
+        return (replace(d, has_node_name=False), engine, extras, gang,
+                fleet, cls._mesh_sig(mesh))
+
     def observe(self, d: Dims, n_nodes: int, n_existing: int,
                 engine: str = "waves", extras: tuple = (),
-                gang: bool = False, mesh=None, rc: int = 0,
-                fleet=None) -> None:
+                gang: bool = False, mesh=None, fleet=None) -> None:
         """Call once per cycle with live occupancy (and whether batches are
         gang-bearing — gangs trace a different program; and which mesh the
         cycle dispatches on — a sharded program is a different executable).
@@ -239,12 +245,10 @@ class BucketPrewarmer:
         if len(crossing) > 1:
             targets.append(d.grown_for(
                 **{ax: getattr(d, ax) + 1 for ax in crossing}))
-        msig = self._mesh_sig(mesh)
         for target in targets:
             if target == d:
                 continue
-            key = (replace(target, has_node_name=False), engine, extras,
-                   gang, rc, fleet, msig)
+            key = self._cycle_key(target, engine, extras, gang, fleet, mesh)
             with self._mu:
                 if key in self._warmed:
                     continue
@@ -253,7 +257,7 @@ class BucketPrewarmer:
                 self._warmed.add(key)
                 t = threading.Thread(
                     target=self._compile_fn,
-                    args=(target, engine, extras, gang, mesh, rc, fleet),
+                    args=(target, engine, extras, gang, mesh, fleet),
                     name=f"ktpu-prewarm-{target.N}x{target.E}", daemon=True)
                 # start BEFORE publishing: wait() joins _inflight without
                 # the lock, and joining a not-yet-started thread raises
@@ -262,9 +266,8 @@ class BucketPrewarmer:
             return
 
     def _compile(self, d: Dims, engine: str, extras: tuple,
-                 gang: bool, mesh=None, rc: int = 0, fleet=None) -> None:
-        key = (replace(d, has_node_name=False), engine, extras, gang,
-               rc, fleet, self._mesh_sig(mesh))
+                 gang: bool, mesh=None, fleet=None) -> None:
+        key = self._cycle_key(d, engine, extras, gang, fleet, mesh)
         epoch = self._epoch
         try:
             from ..utils import faultline
@@ -285,7 +288,7 @@ class BucketPrewarmer:
                  hw, ecfg) = abstract_fleet_args(d, int(fleet), mesh=mesh)
                 compiled = _fleet_cycle_impl.lower(
                     tables, pending, keys, d.D, existing, engine, quota,
-                    hw, ecfg, rc,
+                    hw, ecfg,
                 ).compile()
             else:
                 (tables, pending, keys, existing, hw, ecfg,
@@ -293,7 +296,6 @@ class BucketPrewarmer:
                 compiled = _schedule_batch_impl.lower(
                     tables, pending, keys, d.D, existing, engine, hw, ecfg,
                     extras, tuple(1.0 for _ in extras), gang_args,
-                    False, rc,
                 ).compile()
             with self._mu:
                 if epoch != self._epoch:
@@ -315,7 +317,7 @@ class BucketPrewarmer:
                 self.supervisor.note_compile_failure(e)
 
     def lookup(self, d: Dims, engine: str, extras: tuple, gang: bool,
-               mesh=None, rc: int = 0, fleet=None):
+               mesh=None, fleet=None):
         """The stored Compiled for this cycle signature, or None. Called on
         the dispatch hot path — one dict probe. The mesh signature is part
         of the key, so a single-device caller can NEVER receive a
@@ -325,8 +327,7 @@ class BucketPrewarmer:
         program and a single-cluster program at identical dims are
         different executables (fleet/cycle.py)."""
         return self.compiled.get(
-            (replace(d, has_node_name=False), engine, extras, gang,
-             rc, fleet, self._mesh_sig(mesh)))
+            self._cycle_key(d, engine, extras, gang, fleet, mesh))
 
     def call(self, compiled, *args):
         """Run a stored executable: (True, result), or (False, None) when
@@ -354,8 +355,7 @@ class BucketPrewarmer:
             self._warmed.clear()
 
     def rewarm(self, d: Dims, engine: str = "waves", extras: tuple = (),
-               gang: bool = False, mesh=None, rc: int = 0,
-               fleet=None) -> bool:
+               gang: bool = False, mesh=None, fleet=None) -> bool:
         """Force a background compile of the CURRENT dims regardless of
         occupancy thresholds — the backend re-admission path: the recovered
         device's first wave should deserialize a warm executable, not pay a
@@ -369,16 +369,14 @@ class BucketPrewarmer:
             return False
         if max(d.N, d.E) < self.min_axis:
             return False  # small shapes recompile in seconds on demand
-        key = (replace(d, has_node_name=False), engine, extras, gang,
-               rc, fleet, self._mesh_sig(mesh))
+        key = self._cycle_key(d, engine, extras, gang, fleet, mesh)
         with self._mu:
             self._warmed.add(key)
             prev = self._inflight
             if prev is not None and prev.is_alive():
                 def chained():
                     prev.join()
-                    self._compile_fn(d, engine, extras, gang, mesh, rc,
-                                     fleet)
+                    self._compile_fn(d, engine, extras, gang, mesh, fleet)
 
                 t = threading.Thread(
                     target=chained,
@@ -386,7 +384,7 @@ class BucketPrewarmer:
             else:
                 t = threading.Thread(
                     target=self._compile_fn,
-                    args=(d, engine, extras, gang, mesh, rc, fleet),
+                    args=(d, engine, extras, gang, mesh, fleet),
                     name=f"ktpu-rewarm-{d.N}x{d.E}", daemon=True)
             # start BEFORE publishing (wait() joins without the lock; a
             # not-yet-started thread would raise there). rewarm runs on the
@@ -396,8 +394,7 @@ class BucketPrewarmer:
         return True
 
     def ensure_warm(self, d: Dims, engine: str = "waves", extras: tuple = (),
-                    gang: bool = False, mesh=None, rc: int = 0,
-                    fleet=None) -> bool:
+                    gang: bool = False, mesh=None, fleet=None) -> bool:
         """The warm-standby beat (Scheduler.warm_standby): compile this
         exact signature in the background IF it is neither compiled nor
         already compiling — idempotent, unlike rewarm (which always
@@ -405,14 +402,13 @@ class BucketPrewarmer:
         known-poisoned). Returns True when a compile was scheduled."""
         if not self.enabled or max(d.N, d.E) < self.min_axis:
             return False
-        key = (replace(d, has_node_name=False), engine, extras, gang,
-               rc, fleet, self._mesh_sig(mesh))
+        key = self._cycle_key(d, engine, extras, gang, fleet, mesh)
         with self._mu:
             # _warmed covers both finished compiles (the key stays) and
             # in-flight ones (added before the thread starts)
             if key in self._warmed:
                 return False
-        return self.rewarm(d, engine, extras, gang, mesh, rc, fleet)
+        return self.rewarm(d, engine, extras, gang, mesh, fleet)
 
     def ensure_patch_ladder(self, cache, snap, mesh=None) -> bool:
         """Background compile-ahead for the resident patch-scatter ladder

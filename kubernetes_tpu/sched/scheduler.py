@@ -84,11 +84,6 @@ class CycleStats:
     # pods whose wave dispatch was abandoned (primary AND fallback failed):
     # requeued promptly with attempts preserved — not failures of the pods
     aborted: int = 0
-    # run-collapsed engine telemetry (ops/runs.py, KTPU_ASSIGN=runs): how
-    # many class runs the queue-ordered wave factored into, and the
-    # scan-step reduction P_valid/runs the collapse bought this wave
-    class_runs: int = 0
-    collapse_ratio: float = 0.0
     # fleet-tick telemetry (fleet/server.py, per TENANT per tick): pods
     # sent back to the queue without a failure verdict this tick (DRF
     # quota clamp, storm requeue, abort — they retry promptly, unlike
@@ -155,7 +150,6 @@ class Wave:
     keys: Any = None
     snap_mode: str = ""             # "full" | "patch" | "cached"
     engine: str = ""
-    rc: int = 0
     node_order: Sequence[str] = ()  # of the snapshot ACTUALLY dispatched
     node_idx: Any = None            # node row per batch pod, -1 = none
     attribution: Any = None         # the dispatch's ExplainResult, on host
@@ -549,7 +543,7 @@ class Scheduler:
             span.mark("exception")
             self.telemetry.finish_wave(
                 span, stats=wave.stats, engine=wave.engine, dims=wave.dims,
-                rc=wave.rc, extra={**wave.extra, "exception": True})
+                extra={**wave.extra, "exception": True})
             if self.telemetry.enabled:
                 self.telemetry.dump("exception")
             raise
@@ -610,7 +604,7 @@ class Scheduler:
             # dead tick is reconstructable from the artifact
             self.telemetry.finish_wave(
                 span, stats=stats, engine=wave.engine, dims=wave.dims,
-                rc=wave.rc, micro=wave.micro, extra=wave.extra)
+                micro=wave.micro, extra=wave.extra)
             return stats
         failures = self._commit_stage(wave)
         self._account_gangs(wave, failures)
@@ -762,29 +756,22 @@ class Scheduler:
         # between the two snapshots), and indexing the old order would
         # silently bind pods to the wrong nodes
         wave.node_order = snap.node_order
-        wave.engine, wave.rc = plan_engine(snap.dims.has_node_name,
-                                           snap.runs)
-        if wave.rc:  # nonzero exactly when a RunPlan drives the wave
-            stats.class_runs = snap.runs.n_runs
-            stats.collapse_ratio = round(snap.runs.collapse_ratio, 2)
+        wave.engine = plan_engine(snap.dims.has_node_name)
         gang = self._gang_of(snap) is not None
         self.prewarmer.observe(
             snap.dims, n_nodes=self.cache.node_count,
             n_existing=self.cache.pod_count,
             engine=wave.engine, extras=self._extras, gang=gang,
-            mesh=snap.mesh, rc=wave.rc)
+            mesh=snap.mesh)
         self.supervisor.note_cycle_signature(
-            snap.dims, wave.engine, self._extras, gang, rc=wave.rc)
-        if self.microwave and not wave.micro and snap.runs is None:
+            snap.dims, wave.engine, self._extras, gang)
+        if self.microwave and not wave.micro:
             # keep the micro signature warm from the bulk cadence: the
             # first delta after a quiet period must not pay a compile on
-            # the latency path. (The runs engine's rc varies per micro
-            # batch, so its micro programs compile on first use — small-P
-            # traces are cheap.)
-            micro_engine, _ = plan_engine(False)  # no nodeName, no RunPlan
+            # the latency path
             self.prewarmer.ensure_warm(
                 replace(snap.dims, P=self._micro_p, has_node_name=False),
-                micro_engine, self._extras, False, mesh=snap.mesh, rc=0)
+                plan_engine(False), self._extras, False, mesh=snap.mesh)
         if self.microwave:
             # the patch-scatter ladder is the OTHER compile micro-waves
             # cannot amortize: a fresh dirty-row bucket mid-churn stalls a
@@ -812,7 +799,7 @@ class Scheduler:
             handle = self.supervisor.submit(
                 "cycle",
                 (replace(snap.dims, has_node_name=False), wave.engine,
-                 self._extras, gang, mesh_key(snap.mesh), wave.rc),
+                 self._extras, gang, mesh_key(snap.mesh)),
                 partial(self._dispatch_primary, wave),
                 partial(self._dispatch_fallback, wave))
             self._prestage(wave)
@@ -834,7 +821,7 @@ class Scheduler:
         return snap.gang if self._device_gangs else None
 
     def _engine_call(self, tables, pending, keys, existing, gang, dims,
-                     runs, engine: str, rc: int, prewarmer=None, mesh=None):
+                     engine: str, prewarmer=None, mesh=None):
         """The wave's one call into the engine (primary and fallback):
         `(node, attribution or None, gang verdict or None)`, still on the
         device."""
@@ -846,7 +833,7 @@ class Scheduler:
             ecfg=self.engine_config,
             extra_plugins=self._extras, extra_weights=self._extra_w,
             gang=gang, dims=dims, prewarmer=prewarmer, mesh=mesh,
-            runs=runs, explain=explain, engine=engine, rc=rc)
+            explain=explain, engine=engine)
         res, exp = out if explain else (out, None)
         return res.node, exp, res.gang
 
@@ -873,8 +860,8 @@ class Scheduler:
         snap = wave.snap
         call = partial(
             self._engine_call, snap.tables, snap.pending, wave.keys,
-            snap.existing, self._gang_of(snap), snap.dims, snap.runs,
-            wave.engine, wave.rc, prewarmer=self.prewarmer, mesh=snap.mesh)
+            snap.existing, self._gang_of(snap), snap.dims, wave.engine,
+            prewarmer=self.prewarmer, mesh=snap.mesh)
         tel = self.telemetry
         if not tel.enabled:
             return self._read_back(*call())
@@ -907,7 +894,7 @@ class Scheduler:
         for what it then holds. No prewarmer — its executables belong to
         the primary."""
         snap, keys = wave.snap, wave.keys
-        engine, rc = wave.engine, wave.rc
+        engine = wave.engine
         arrays = None
         if not hung:
             try:
@@ -922,14 +909,14 @@ class Scheduler:
             snap, keys = self._snapshot_keys([p for p, _ in wave.batch])
             arrays = (snap.tables, snap.pending, keys, snap.existing,
                       self._gang_of(snap))
-            engine, rc = plan_engine(snap.dims.has_node_name, snap.runs)
+            engine = plan_engine(snap.dims.has_node_name)
             wave.node_order = snap.node_order
         with jax.default_device(dev):
             # degraded waves stay explainable: the chaos drill
             # reconstructs a degraded wave's failures from the flight
             # recorder, so the fallback attributes too
             return self._read_back(*self._engine_call(
-                *arrays, snap.dims, snap.runs, engine, rc))
+                *arrays, snap.dims, engine))
 
     def _prestage(self, wave: Wave) -> None:
         """Double-buffered host/device overlap: the dispatch runs on the
@@ -1293,7 +1280,7 @@ class Scheduler:
             extra["explain"] = wave.explain
         self.telemetry.finish_wave(
             wave.span, stats=stats, engine=wave.engine, dims=wave.dims,
-            rc=wave.rc, micro=wave.micro, extra=extra)
+            micro=wave.micro, extra=extra)
         return stats
 
     def _schedule_one_with_extenders(
@@ -1552,19 +1539,18 @@ class Scheduler:
         backlog = self.queue.peek_active(self.batch_size)
         self.encoder.intern_pods(backlog)
         snap, _keys = self._snapshot_keys(backlog)
-        wave_engine, rc = plan_engine(snap.dims.has_node_name, snap.runs)
+        wave_engine = plan_engine(snap.dims.has_node_name)
         extras = self._extras
         gang = self._gang_of(snap) is not None
         # compile the signature the first led wave WILL dispatch (idempotent
         # per signature), and keep the growth-boundary lookahead running so
         # a takeover into a growing cluster doesn't stall either
         self.prewarmer.ensure_warm(snap.dims, wave_engine, extras, gang,
-                                   mesh=snap.mesh, rc=rc)
+                                   mesh=snap.mesh)
         self.prewarmer.observe(
             snap.dims, n_nodes=self.cache.node_count,
             n_existing=self.cache.pod_count,
-            engine=wave_engine, extras=extras, gang=gang, mesh=snap.mesh,
-            rc=rc)
+            engine=wave_engine, extras=extras, gang=gang, mesh=snap.mesh)
 
     # ------------------------------------------------------------------ #
     # commit path: assume → Reserve → Permit → PreBind → Bind → PostBind
@@ -1814,10 +1800,6 @@ class Scheduler:
             total.shed += s.shed
             total.requeued += s.requeued
             total.commit_paused += s.commit_paused
-            if s.class_runs:
-                # run-collapse telemetry: keep the last non-empty wave's
-                total.class_runs = s.class_runs
-                total.collapse_ratio = s.collapse_ratio
             total.assignments.update(s.assignments)
             if self.queue.lengths()[0] == 0:
                 break

@@ -234,14 +234,14 @@ class DispatchSupervisor:
         return self.mesh_state.mesh
 
     def note_cycle_signature(self, dims, engine: str, extras: tuple,
-                             gang: bool, rc: int = 0, fleet=None) -> None:
+                             gang: bool, fleet=None) -> None:
         """Remember what the live cycle program looks like so re-admission
         can warm exactly it (the mesh itself is NOT part of the note: the
         rewarm targets whatever mesh exists post-reform, never the dead
         one's signature). `fleet` is the tenant-stack count when the live
         program is a fleet cycle (fleet/cycle.py) — the rewarm must target
         the stacked executable, not the single-cluster one."""
-        self._cycle_sig = (dims, engine, extras, gang, rc, fleet)
+        self._cycle_sig = (dims, engine, extras, gang, fleet)
 
     def _emit(self, kind: str, detail: str = "") -> None:
         sink = self.event_sink
@@ -398,13 +398,12 @@ class DispatchSupervisor:
             except Exception:  # noqa: BLE001 - rewarm is an optimization
                 mesh = None
         if self.prewarmer is not None and sig is not None:
-            dims, engine, extras, gang, rc, fleet = sig
+            dims, engine, extras, gang, fleet = sig
             try:
                 if self.prewarmer.rewarm(dims, engine=engine, extras=extras,
-                                         gang=gang, mesh=mesh, rc=rc,
-                                         fleet=fleet):
+                                         gang=gang, mesh=mesh, fleet=fleet):
                     self.stats.rewarms += 1
-                    self._emit("rewarm", f"{engine} rc={rc}")
+                    self._emit("rewarm", engine)
             except Exception:  # noqa: BLE001 - rewarm is an optimization
                 pass
 

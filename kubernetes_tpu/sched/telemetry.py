@@ -670,7 +670,7 @@ class SchedulerTelemetry:
             }
 
     def finish_wave(self, span, *, stats=None, engine: str = "",
-                    dims=None, rc: int = 0, micro: bool = False,
+                    dims=None, micro: bool = False,
                     fleet: Optional[Dict[str, Any]] = None,
                     extra: Optional[Dict[str, Any]] = None) -> Optional[Dict]:
         """Close one wave: derive phase durations, feed the per-phase
@@ -719,7 +719,6 @@ class SchedulerTelemetry:
             "duration_s": round(t_end - span.trace.start, 6),
             "phases": [(p, round(dt, 6)) for p, dt in phases],
             "engine": engine,
-            "rc": rc,
         }
         if micro:
             # micro-waves (ISSUE 18) are first-class flight-recorder

@@ -44,7 +44,6 @@ import numpy as np
 
 from ..api.types import Node, Pod
 from ..component import trace
-from ..ops import configured_engine
 from .arrays import ClusterTables, NodeArrays, PodArrays
 from .dims import Dims
 from .encode import Encoder
@@ -174,12 +173,6 @@ class Snapshot:
                          # small tables replicated — parallel/mesh.py);
                          # keyed by IDENTITY: a reformed mesh is a new
                          # object, which forces re-shard from host staging.
-    runs: object = None  # ops/runs.py RunPlan when KTPU_ASSIGN=runs: the
-                         # host-counted run-length encoding of the pending
-                         # wave (static scan-length bound + collapse
-                         # telemetry), emitted alongside pods.cls from the
-                         # SAME staging columns — pure host metadata, so
-                         # the patch path stays patch-compatible.
 
 
 class SchedulerCache:
@@ -767,11 +760,6 @@ class SchedulerCache:
         with self._mu:
             pe_host = encoder.build_pod_arrays(
                 list(pending), d, self._node_slot, capacity=d.P)
-            runs_plan = None
-            if self._runs_wanted():
-                runs_plan = self._run_plan_from_cols(
-                    pe_host.cls, pe_host.priority, pe_host.creation,
-                    pe_host.valid, pe_host.node_name_req)
             gang = self._gang_arrays(encoder, pending, d, mesh)
         return Snapshot(
             generation=base.generation,
@@ -785,7 +773,6 @@ class SchedulerCache:
             gang=gang,
             device=device,
             mesh=mesh,
-            runs=runs_plan,
         )
 
     def warm_patch_ladder(self, snap: Snapshot, mesh=None) -> int:
@@ -875,27 +862,6 @@ class SchedulerCache:
             "images": len(encoder.vocabs.images),
             "volsets": len(encoder.volset_reg),
         }
-
-    @staticmethod
-    def _runs_wanted() -> bool:
-        return configured_engine() == "runs"
-
-    @staticmethod
-    def _run_plan_from_cols(cls, priority, creation, valid, nnr):
-        """RunPlan over staging columns (numpy, no device readback) — the
-        run-collapsed engine's static scan-length bound, computed on the
-        snapshot path so the dispatch never blocks on a readback."""
-        from ..ops.runs import plan_runs
-
-        return plan_runs(cls, priority, creation, valid, nnr)
-
-    def _run_plan_from_stage(self):
-        stage = self._pending_stage
-        if stage is None:
-            return None
-        rows = stage.rows
-        return self._run_plan_from_cols(rows[:, 2], rows[:, 3], rows[:, 4],
-                                        stage.valid, rows[:, 5])
 
     def _gang_arrays(self, encoder: Encoder, pending, d: Dims,
                      mesh: object = None):
@@ -1018,9 +984,6 @@ class SchedulerCache:
         )
         pe = encoder.build_pod_arrays(list(pending), d, self._node_slot,
                                       capacity=d.P)
-        runs_plan = self._run_plan_from_cols(
-            pe.cls, pe.priority, pe.creation, pe.valid,
-            pe.node_name_req) if self._runs_wanted() else None
         if mesh is not None:
             # mesh-resident placement: node axis split across the mesh's
             # chips, small interned tables replicated (parallel/mesh.py);
@@ -1045,7 +1008,6 @@ class SchedulerCache:
             gang=self._gang_arrays(encoder, pending, d, mesh),
             device=device,
             mesh=mesh,
-            runs=runs_plan,
         )
         self._encoder = encoder
         self._reg_sizes = self._registry_sizes(encoder)
@@ -1244,15 +1206,6 @@ class SchedulerCache:
                 self.resident_donated_patches += 1
             else:
                 self.resident_copy_patches += 1
-        runs_plan = None
-        if self._runs_wanted():
-            # an identical pending batch keeps its plan; otherwise the
-            # pending stage (just brought current by _pending_block) has
-            # the columns — O(P log P) numpy, no device readback
-            if pending_keys == snap.pending_keys and snap.runs is not None:
-                runs_plan = snap.runs
-            else:
-                runs_plan = self._run_plan_from_stage()
         new_snap = Snapshot(
             generation=gen,
             node_order=list(self._node_names),
@@ -1265,7 +1218,6 @@ class SchedulerCache:
             gang=self._gang_arrays(encoder, pending, d, mesh),
             device=device,
             mesh=mesh,
-            runs=runs_plan,
         )
         self._dirty_nodes.clear()
         self._dirty_pods.clear()

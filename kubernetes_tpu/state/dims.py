@@ -131,11 +131,10 @@ class Dims:
     def affinity_agg(self, engine: str) -> Optional[str]:
         """`affinity_agg` of the program `engine` runs at these capacities,
         for the flight recorder: the waves round evaluates SC classes
-        against one state, an extender verb its P pods, a scan or runs step
-        one class. None where the record is not one program's (a fleet tick
-        runs each tenant group's own engine)."""
-        rows = {"waves": self.SC, "extender": self.P, "scan": 1,
-                "runs": 1}.get(engine)
+        against one state, an extender verb its P pods, a scan step one
+        class. None where the record is not one program's (a fleet tick's
+        solo tenants dispatch programs of their own)."""
+        rows = {"waves": self.SC, "extender": self.P, "scan": 1}.get(engine)
         if rows is None:
             return None
         return affinity_agg(rows, self.AT + self.AN + self.PAT + self.PAN,

@@ -25,10 +25,10 @@ The fleet-flagship stage (ISSUE 20) splits the same way: `pods_per_sec`
 and `cycle_seconds` are time-like (its CPU numbers come from the 8-way
 VIRTUAL mesh — a real-accelerator run records against the artifact's
 `real_accel_cycle_budget_s` instead, and cross-backend pairs are
-annotated, not gated), while `dispatches_per_engine_group`, `bit_equal`,
-`bit_equal_tenants_checked`, `engine_groups`, `node_shards`,
-`lost_pods`, and `double_bound` are invariants of the 2-D mesh + mixed
-per-tenant-engine contract that gate on every backend.
+annotated, not gated), while `fleet_dispatches_per_tick`, `bit_equal`,
+`bit_equal_tenants_checked`, `node_shards`, `lost_pods`, and
+`double_bound` are invariants of the 2-D mesh contract that gate on every
+backend.
 
 Usage:
     python scripts/bench_trend.py [--dir REPO] [--tolerance 0.25]

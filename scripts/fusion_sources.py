@@ -65,7 +65,7 @@ def compiled_text(dims: str, preempt: int) -> str:
             prewarm.abstract_cycle_args(d)[:6])
         lowered = _schedule_batch_impl.lower(
             tables, pending, keys, d.D, existing, "waves", hw, ecfg, (), (),
-            None, False, 0)
+            None, False)
     return lowered.compile().as_text()
 
 

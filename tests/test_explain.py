@@ -167,36 +167,30 @@ class TestDeviceAttribution:
             a, b = getattr(e_pod, name), getattr(e_cls, name)
             assert np.array_equal(np.asarray(a), np.asarray(b)), name
 
-    def test_engines_attribution_agrees(self, monkeypatch):
+    def test_engines_attribution_agrees(self):
         nodes = _nodes(6)
         pods = [_pod(i) for i in range(5)] + [_pod(9, cpu="64")]
         outs = {}
-        for engine in ("scan", "runs", "waves"):
-            monkeypatch.setenv("KTPU_ASSIGN", engine)
+        for engine in ("scan", "waves"):
             tables, ex, pe, d, keys = _encode(nodes, pods)
             res, exp = _schedule_batch(tables, pe, keys, d.D, ex,
-                                       has_node_name=d.has_node_name,
-                                       explain=True)
+                                       explain=True, engine=engine)
             outs[engine] = (np.asarray(res.node), jax.device_get(exp))
-        for engine in ("runs", "waves"):
-            assert np.array_equal(outs["scan"][0], outs[engine][0])
-            for name in outs["scan"][1]._fields:
-                a = np.asarray(getattr(outs["scan"][1], name))
-                b = np.asarray(getattr(outs[engine][1], name))
-                assert np.array_equal(a, b), (engine, name)
+        assert np.array_equal(outs["scan"][0], outs["waves"][0])
+        for name in outs["scan"][1]._fields:
+            a = np.asarray(getattr(outs["scan"][1], name))
+            b = np.asarray(getattr(outs["waves"][1], name))
+            assert np.array_equal(a, b), name
 
-    def test_explain_off_placement_bit_equality_all_engines(self,
-                                                            monkeypatch):
+    def test_explain_off_placement_bit_equality_all_engines(self):
         nodes = _nodes(6)
         pods = [_pod(i) for i in range(8)] + [_pod(20, cpu="64")]
-        for engine in ("scan", "runs", "waves"):
-            monkeypatch.setenv("KTPU_ASSIGN", engine)
+        for engine in ("scan", "waves"):
             tables, ex, pe, d, keys = _encode(nodes, pods)
             plain = _schedule_batch(tables, pe, keys, d.D, ex,
-                                    has_node_name=d.has_node_name)
+                                    engine=engine)
             res, _exp = _schedule_batch(tables, pe, keys, d.D, ex,
-                                        has_node_name=d.has_node_name,
-                                        explain=True)
+                                        explain=True, engine=engine)
             assert np.array_equal(np.asarray(plain.node),
                                   np.asarray(res.node)), engine
 

@@ -114,8 +114,9 @@ class TestStacking:
         assert existing.valid.shape == (3, d.E)
         assert uk.shape == (3,)
 
-    def test_pad_tenant_is_inert(self):
-        """An empty-cluster pad tenant admits nothing through any engine —
+    @pytest.mark.parametrize("engine", ["waves", "scan"])
+    def test_pad_tenant_is_inert(self, engine):
+        """An empty-cluster pad tenant admits nothing through either engine —
         the tenant-axis analog of pad_node_tables' zero-phantom proof."""
         import jax
         import jax.numpy as jnp
@@ -129,8 +130,8 @@ class TestStacking:
             stack_blocks(blocks))
         quota = jnp.ones((2,), jnp.float32)
         res = _fleet_cycle_impl(tables, pending, keys, d.D, existing,
-                                "waves", quota, jnp.float32(1.0),
-                                default_engine_config(), 0)
+                                engine, quota, jnp.float32(1.0),
+                                default_engine_config())
         assert not bool(res.feasible.any())
         assert int((res.node >= 0).sum()) == 0
 
@@ -618,11 +619,10 @@ class TestFleet2DMesh:
 
     SPEC = [("a", 5, 7, 1.0), ("b", 3, 5, 1.0), ("c", 6, 9, 1.0)]
 
-    def _run(self, mesh, node_shards=None, engines=None, spec=None):
+    def _run(self, mesh, node_shards=None):
         srv, binders = build_fleet(
-            spec or self.SPEC, mesh=mesh,
-            **({} if node_shards is None else {"node_shards": node_shards}),
-            **({} if engines is None else {"engines": engines}))
+            self.SPEC, mesh=mesh,
+            **({} if node_shards is None else {"node_shards": node_shards}))
         srv.run_until_idle(max_ticks=8)
         return srv, binders
 
@@ -728,39 +728,6 @@ class TestFleet2DMesh:
         restacks = stack.full_restacks
         stack.refresh(snaps, [(0, 0)], d)
         assert stack.full_restacks == restacks + 1      # patch path barred
-
-    def test_mixed_engines_one_dispatch_per_group(self):
-        """Per-tenant engines split the tick into engine groups: exactly
-        one dispatch per group per tick, placements bit-equal to each
-        tenant's SOLO run under its own engine."""
-        engines = {"a": "waves", "b": "runs", "c": "scan"}
-        srv, bm = self._run(mesh=None, engines=engines)
-        total = srv.run_until_idle(max_ticks=2)  # idle: no extra groups
-        assert set(srv.stacks) <= {"waves", "runs", "scan"}
-        assert srv.max_engine_groups == 3
-        assert srv.max_dispatches_per_tick == 3
-        del total
-        for name, n_nodes, n_pods, quota in self.SPEC:
-            _, solo = self._run(mesh=None,
-                                engines={name: engines[name]},
-                                spec=[(name, n_nodes, n_pods, quota)])
-            assert sorted(bm[name].bound) == sorted(solo[name].bound), name
-
-    def test_mixed_engines_on_2d_mesh_bit_equal(self):
-        import jax
-
-        if len(jax.devices()) < 8:
-            pytest.skip("needs 8 (virtual) devices")
-        engines = {"a": "waves", "b": "runs", "c": "scan"}
-        srv2, b2 = self._run(mesh=8, node_shards=2, engines=engines)
-        assert srv2.max_engine_groups == 3
-        srv0, b0 = self._run(mesh=None, engines=engines)
-        for name, _, _, _ in self.SPEC:
-            assert sorted(b2[name].bound) == sorted(b0[name].bound), name
-
-    def test_bad_engine_name_rejected(self):
-        with pytest.raises(ValueError, match="engine"):
-            FleetServer(engines={"a": "warp"})
 
     @pytest.mark.chaos
     def test_degrade_reform_under_2d_signature(self, monkeypatch):

@@ -181,6 +181,25 @@ def test_gang_soundness_randomized(seed):
         world.append(dataclasses.replace(pending[i], node_name=node.name))
 
 
+def test_gang_workload_jobs_wholly_placed_or_wholly_not():
+    """The gang workload (jobs of 8, 16, 32 and a 64-member monster that is
+    statically infeasible on 12 nodes, which forces a rejection round)
+    through the default engine: every job is wholly placed or wholly not;
+    the three that fit are placed, the monster holds nothing."""
+    from kubernetes_tpu.models.workloads import gang_workload_pods, make_nodes
+
+    nodes = make_nodes(12, zones=3, racks_per_zone=2, cpu="16",
+                       memory="64Gi")
+    pods = gang_workload_pods(120)
+    placed_of: dict = {}
+    for p, node in zip(pods, BatchScheduler().schedule(
+            nodes, [], pods).assignments):
+        placed_of.setdefault(p.pod_group, []).append(node is not None)
+    assert {g: (sum(v), len(v)) for g, v in placed_of.items()} == {
+        "job-0": (8, 8), "job-1": (16, 16), "job-2": (32, 32),
+        "job-3": (0, 64)}
+
+
 class TestStatefulScheduler:
     def _mk(self):
         from kubernetes_tpu.sched.scheduler import RecordingBinder, Scheduler

@@ -37,11 +37,11 @@ pytestmark = [
 ]
 
 
-def _encode(n_nodes, n_pods, n_bound=0):
+def _encode(n_nodes, n_pods, n_bound=0, groups=12):
     """`n_bound` further flagship pods sit on nodes already (several to a
     node, so the cycle's per-node seeds CNT / HOLD / WSYM are not zero)."""
     nodes = make_nodes(n_nodes, zones=min(8, n_nodes), racks_per_zone=4)
-    pods = flagship_pods(n_pods + n_bound, groups=min(12, n_pods))
+    pods = flagship_pods(n_pods + n_bound, groups=min(groups, n_pods))
     existing = pods[n_pods:]
     for i, p in enumerate(existing):
         p.node_name = nodes[(i * 7) % (n_nodes // 2)].name
@@ -73,19 +73,26 @@ def populated():
     return _encode(64, 96, n_bound=160)
 
 
+@pytest.fixture(scope="module")
+def eight_groups():
+    """One deployment group a mesh device: 12 replicas a class."""
+    return _encode(64, 96, groups=8)
+
+
 def test_mesh_requires_enough_devices():
     with pytest.raises(RuntimeError, match="devices visible"):
         make_mesh(len(jax.devices()) + 1)
 
 
 @pytest.mark.parametrize("engine", ["waves", "scan"])
-@pytest.mark.parametrize("which", ["cluster", "populated"])
+@pytest.mark.parametrize("which", ["cluster", "populated", "eight_groups"])
 def test_sharded_cycle_matches_unsharded(request, which, engine):
     """Both engines — `waves` (the production default) and `scan` (the
     executable spec) — must be bit-identical sharded vs unsharded, on an
-    empty cluster and on one whose existing pods seed the per-node counts
-    (the class × node histogram scatters into a node-sharded array and the
-    seeds' products shard along N)."""
+    empty cluster (of 12 deployment groups, and of 8) and on one whose
+    existing pods seed the per-node counts (the class × node histogram
+    scatters into a node-sharded array and the seeds' products shard
+    along N)."""
     tables, pending, existing, uk, ev, d = request.getfixturevalue(which)
     D = d.D
     # the in-domain sums are products against the same-domain matrices here

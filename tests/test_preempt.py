@@ -68,6 +68,31 @@ def test_preempts_lower_priority_and_schedules_after_eviction():
     assert s.queue.nominated_node("default/vip") is None
 
 
+def test_replica_burst_preempts_through_the_wave_engine():
+    """Two full nodes, three high-priority replicas that each need a node's
+    worth back: the binds and the victims of the drill on the engine that
+    serves (the wave placements feed the burst's what-if)."""
+    s, clock = mksched()
+    for i in range(2):
+        s.on_node_add(Node(
+            name=f"n{i}", labels={HOSTNAME: f"n{i}"},
+            allocatable=Resources.make(cpu="2", memory="4Gi", pods=10)))
+    for i in range(4):
+        s.on_pod_add(bound(f"f{i}", f"n{i % 2}", cpu="900m", mem="1800Mi",
+                           creation_index=i))
+    for i in range(3):
+        s.on_pod_add(Pod(
+            name=f"vip{i}", priority=1000, creation_index=10 + i,
+            requests=Resources.make(cpu="1500m", memory="3Gi")))
+    for _ in range(4):
+        s.schedule_pending()
+        clock.t += 10.0
+    assert sorted(s.binder.bound) == [("default/vip0", "n0"),
+                                      ("default/vip1", "n1")]
+    assert sorted(s.preemptor.evictor.evicted) == [
+        f"default/f{i}" for i in range(4)]
+
+
 def test_no_preemption_of_equal_or_higher_priority():
     s, clock = mksched()
     s.on_node_add(mknode("n0", cpu=1))

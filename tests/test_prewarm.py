@@ -77,14 +77,44 @@ class TestAbstractCompile:
                            (warm_args[0], warm_args[1], warm_args[3]))]
         assert warm_shapes == live_shapes
 
+    def test_no_run_capacity_in_any_key_and_no_engine_variable(
+            self, monkeypatch):
+        """A cycle program's prewarm key is (dims, engine, extras, gang,
+        fleet, mesh sig) and the supervisor's cycle signature (dims,
+        engine, extras, gang, fleet): neither has a run-capacity slot, no
+        entry point takes one, and `KTPU_ASSIGN` is read by nothing."""
+        import inspect
+        from dataclasses import replace
+
+        from kubernetes_tpu.sched.cycle import (
+            _schedule_batch, _schedule_batch_impl, plan_engine)
+        from kubernetes_tpu.sched.supervisor import DispatchSupervisor
+
+        monkeypatch.setenv("KTPU_ASSIGN", "scan")
+        assert plan_engine(False) == "waves"
+        d = Dims().grown_for(N=16, P=16, E=16)
+        pw = BucketPrewarmer(threshold=0.8, min_axis=8)
+        assert pw.ensure_warm(d, plan_engine(d.has_node_name))
+        pw.wait(120)
+        assert list(pw.compiled) == [
+            (replace(d, has_node_name=False), "waves", (), False, None,
+             None)]
+        assert pw.warm_log == [(d, "waves")]
+        sup = DispatchSupervisor(prewarmer=pw)
+        sup.note_cycle_signature(d, "waves", (), False)
+        assert sup._cycle_sig == (d, "waves", (), False, None)
+        for fn in (pw.lookup, pw.observe, pw.rewarm, pw.ensure_warm,
+                   pw._compile, sup.note_cycle_signature, plan_engine,
+                   _schedule_batch, _schedule_batch_impl):
+            assert not {"rc", "runs"} & set(inspect.signature(fn).parameters)
+
 
 class TestTriggerPolicy:
     def _spy(self):
         calls = []
         ev = threading.Event()
 
-        def fake_compile(d, engine, extras, gang, mesh=None, rc=0,
-                         fleet=None):
+        def fake_compile(d, engine, extras, gang, mesh=None, fleet=None):
             calls.append((d, engine, gang))
             ev.set()
         return calls, ev, fake_compile
@@ -182,7 +212,7 @@ class TestGrowthAcrossBucketBoundary:
         s = Scheduler(binder=binder, base_dims=Dims().grown_for(N=16, E=16))
         s.prewarmer = BucketPrewarmer(
             threshold=0.8, min_axis=8,
-            compile_fn=lambda d, e, x, g, m=None, rc=0, fleet=None:
+            compile_fn=lambda d, e, x, g, m=None, fleet=None:
             calls.append(d))
 
         for i in range(8):
@@ -230,7 +260,7 @@ class TestMeshSignatureIsolation:
         calls = []
         pw = BucketPrewarmer(
             threshold=0.8, min_axis=8,
-            compile_fn=lambda d, e, x, g, m=None, rc=0, fleet=None:
+            compile_fn=lambda d, e, x, g, m=None, fleet=None:
             calls.append((d, m)))
         d = Dims().grown_for(N=16, E=16)
         pw.observe(d, n_nodes=14, n_existing=1)              # single-device
@@ -251,16 +281,16 @@ class TestMeshSignatureIsolation:
         from kubernetes_tpu.parallel.mesh import mesh_key
 
         base = replace(d, has_node_name=False)
-        pw.compiled[(base, "waves", (), False, 0, None,
+        pw.compiled[(base, "waves", (), False, None,
                      mesh_key(mesh))] = "MESH-EXE"
-        pw.compiled[(base, "waves", (), False, 0, None, None)] = "SINGLE-EXE"
+        pw.compiled[(base, "waves", (), False, None, None)] = "SINGLE-EXE"
         assert pw.lookup(d, "waves", (), False, mesh=mesh) == "MESH-EXE"
         assert pw.lookup(d, "waves", (), False, mesh=None) == "SINGLE-EXE"
-        # the run-collapsed engine's static run capacity is part of the key:
-        # a different run bucket is a different compiled program
-        pw.compiled[(base, "runs", (), False, 16, None, None)] = "RUNS-RC16"
-        assert pw.lookup(d, "runs", (), False, rc=16) == "RUNS-RC16"
-        assert pw.lookup(d, "runs", (), False, rc=32) is None
+        # the engine is part of the key: the scan a nodeName batch is
+        # routed to is another compiled program
+        pw.compiled[(base, "scan", (), False, None, None)] = "SCAN-EXE"
+        assert pw.lookup(d, "scan", (), False) == "SCAN-EXE"
+        assert pw.lookup(d, "scan", (), True) is None
         # preempt programs carry the same isolation
         pw.compiled[pw._preempt_key(d, 8, mesh)] = "MESH-PREEMPT"
         assert pw.lookup_preempt(d, 8, mesh=None) is None
@@ -302,8 +332,8 @@ class TestMeshSignatureIsolation:
         pw = BucketPrewarmer(threshold=0.8, min_axis=8)
         d = Dims().grown_for(N=16, E=16)
         base = replace(d, has_node_name=False)
-        pw.compiled[(base, "waves", (), False, 0, 8, None)] = "FLEET-K8"
-        pw.compiled[(base, "waves", (), False, 0, None, None)] = "SINGLE"
+        pw.compiled[(base, "waves", (), False, 8, None)] = "FLEET-K8"
+        pw.compiled[(base, "waves", (), False, None, None)] = "SINGLE"
         assert pw.lookup(d, "waves", (), False, fleet=8) == "FLEET-K8"
         assert pw.lookup(d, "waves", (), False) == "SINGLE"
         assert pw.lookup(d, "waves", (), False, fleet=16) is None
@@ -312,7 +342,7 @@ class TestMeshSignatureIsolation:
         mesh = self._mesh()
         from kubernetes_tpu.parallel.mesh import mesh_key
 
-        pw.compiled[(base, "waves", (), False, 0, 8,
+        pw.compiled[(base, "waves", (), False, 8,
                      mesh_key(mesh))] = "FLEET-K8-MESH"
         assert pw.lookup(d, "waves", (), False, fleet=8,
                          mesh=mesh) == "FLEET-K8-MESH"
@@ -360,11 +390,9 @@ class TestMeshSignatureIsolation:
             lookups = []
             orig_lookup = s.prewarmer.lookup
 
-            def spy_lookup(d, engine, extras, gang, mesh=None,
-                           rc=0):
+            def spy_lookup(d, engine, extras, gang, mesh=None):
                 lookups.append(mesh_key(mesh))
-                return orig_lookup(d, engine, extras, gang, mesh=mesh,
-                                   rc=rc)
+                return orig_lookup(d, engine, extras, gang, mesh=mesh)
 
             s.prewarmer.lookup = spy_lookup
             for i in range(8):

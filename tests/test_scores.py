@@ -499,7 +499,7 @@ def test_few_rows_path_equals_many_rows_path():
     pe = jax.tree.map(lambda a: a[:8], pe)
     assert dataclasses.replace(d, P=8).affinity_agg("extender") == "row"
     assert d.affinity_agg("waves") == "term"
-    assert d.affinity_agg("scan") == d.affinity_agg("runs") == "row"
+    assert d.affinity_agg("scan") == "row"
     state = assign.initial_state(tables, cyc)
     assert assign.state_affinity_table(tables, cyc, state, 8) is None
     table = assign.state_affinity_table(

@@ -195,7 +195,7 @@ _BINDING = ["bind-commit/assume", "bind-commit/bind-call",
             "bind-commit/finish"]
 _FIRST_SNAPSHOT = ["snapshot/full", "snapshot/full/upload",
                    "snapshot/prepare"]
-_HEAD = ["recorder", "t_start", "duration_s", "phases", "engine", "rc"]
+_HEAD = ["recorder", "t_start", "duration_s", "phases", "engine"]
 # what the collector did since the previous record (ISSUE 37): after the
 # record's own fields, before what the caller adds
 _GC = ["gc_full_collections", "gc_pause_s", "gc_max_pause_s",

@@ -14,6 +14,7 @@ Two rungs, mirroring how the reference validates its scheduling algorithm
 """
 
 import dataclasses
+import functools
 import random
 
 import jax
@@ -29,6 +30,7 @@ from kubernetes_tpu.sched.cycle import UNSCHEDULABLE_TAINT_KEY
 from kubernetes_tpu.state.dims import Dims
 from kubernetes_tpu.state.encode import Encoder
 
+from scenarios import PLACED, SCENARIOS, nodename_pin_mid_burst
 from test_golden import oracle_fits, rand_node, rand_pod
 
 
@@ -40,9 +42,6 @@ def _encode(nodes, existing, pending):
     uk = jnp.int32(enc.vocabs.label_keys.get(UNSCHEDULABLE_TAINT_KEY))
     ev = jnp.int32(enc.vocabs.label_vals.get(""))
     return tables, ex, pe, uk, ev, d
-
-
-import functools
 
 
 @functools.partial(jax.jit, static_argnums=(0, 6))
@@ -175,11 +174,7 @@ def test_waves_handle_extreme_negative_priorities():
     assert int(np.asarray(waves).max()) < 6
 
 
-@pytest.mark.parametrize("seed", range(8))
-def test_wave_replay_is_valid_greedy_execution(seed):
-    """Randomized clusters (affinity, anti-affinity, spread, taints, ports):
-    replaying the wave output pod-by-pod in (wave, queue-order) must pass the
-    full oracle predicate chain at every step."""
+def _random_cluster(seed):
     rng = random.Random(1000 + seed)
     n_nodes = rng.randint(4, 8)
     nodes = [rand_node(rng, i) for i in range(n_nodes)]
@@ -188,6 +183,18 @@ def test_wave_replay_is_valid_greedy_execution(seed):
         for i in range(rng.randint(0, 6))
     ]
     pending = [rand_pod(rng, i) for i in range(rng.randint(8, 16))]
+    return nodes, existing, pending
+
+
+@pytest.mark.parametrize("seed", [*range(8), *SCENARIOS])
+def test_wave_replay_is_valid_greedy_execution(seed):
+    """Randomized clusters (affinity, anti-affinity, spread, taints, ports)
+    and the named replica-burst scenarios (tests/scenarios.py): replaying
+    the wave output pod-by-pod in (wave, queue-order) must pass the full
+    oracle predicate chain at every step; where every greedy execution of a
+    scenario places the same number of pods, so many are placed."""
+    nodes, existing, pending = SCENARIOS[seed]() if seed in SCENARIOS \
+        else _random_cluster(seed)
 
     tables, ex, pe, uk, ev, d = _encode(nodes, existing, pending)
     res, waves = _run("waves", tables, ex, pe, uk, ev, d.D)
@@ -209,6 +216,58 @@ def test_wave_replay_is_valid_greedy_execution(seed):
             f"pod={pending[i]}"
         )
         world.append(dataclasses.replace(pending[i], node_name=node.name))
+    if seed in PLACED:
+        assert len(placed) == PLACED[seed]
+    elif seed == "capacity-exhaustion":
+        assert 0 < len(placed) < len(pending)
+
+
+@pytest.mark.parametrize("has_node_name, engine", [
+    (False, "waves"),
+    (True, "scan"),     # spec.nodeName is per pod: the literal scan
+])
+def test_plan_engine_table(has_node_name, engine):
+    """`plan_engine` is the one place a wave's program is chosen, from the
+    one fact the snapshot's dims carry about the batch."""
+    from kubernetes_tpu.sched.cycle import plan_engine
+    from kubernetes_tpu.state.cache import SchedulerCache
+
+    assert plan_engine(has_node_name) == engine
+    cache = SchedulerCache()
+    cache.add_node(Node(name="n0", allocatable=Resources.make(
+        cpu="4", memory="8Gi", pods=10)))
+    pods = [Pod(name=f"p{i}", creation_index=i,
+                node_name="n0" if has_node_name and i == 1 else "",
+                requests=Resources.make(cpu="100m", memory="64Mi"))
+            for i in range(3)]
+    snap = cache.snapshot(Encoder(), pods, None,
+                          extra_intern=(UNSCHEDULABLE_TAINT_KEY,))
+    assert plan_engine(snap.dims.has_node_name) == engine
+
+
+def test_nodename_pins_mid_burst_land_through_the_scan():
+    """spec.nodeName pods in the middle of a replica burst: the batch is
+    the scan's (`plan_engine`), `_schedule_batch` dispatches it there, the
+    pinned pods land on their node and every other pod validly."""
+    from kubernetes_tpu.sched.cycle import _schedule_batch, plan_engine
+
+    nodes, existing, pending = nodename_pin_mid_burst()
+    tables, ex, pe, uk, ev, d = _encode(nodes, existing, pending)
+    assert d.has_node_name and plan_engine(d.has_node_name) == "scan"
+    res = _schedule_batch(jax.device_put(tables), jax.device_put(pe),
+                          (uk, ev), d.D, jax.device_put(ex),
+                          has_node_name=d.has_node_name)
+    node_idx = np.asarray(res.node)[: len(pending)]
+    scan_res, _ = _run("scan", tables, ex, pe, uk, ev, d.D)
+    np.testing.assert_array_equal(
+        node_idx, np.asarray(scan_res.node)[: len(pending)])
+    assert node_idx[3] == 2 and node_idx[4] == 2, "pinned pods land on n2"
+    assert (node_idx >= 0).all()
+    world = list(existing)
+    for i, pod in enumerate(pending):   # one priority: queue order
+        node = nodes[int(node_idx[i])]
+        assert oracle_fits(pod, node, nodes, world), (pod.name, node.name)
+        world.append(dataclasses.replace(pod, node_name=node.name))
 
 
 @pytest.mark.parametrize("seed", range(4))
@@ -780,18 +839,26 @@ def _spread_cluster(seed):
     return nodes, existing, pending
 
 
-@pytest.mark.parametrize("seed", range(3))
-@pytest.mark.parametrize("cluster", ["affinity", "spread"])
-def test_waves_with_product_equal_waves_with_scatter(cluster, seed,
-                                                     monkeypatch):
+_DOMAIN_SUM_CASES = {
+    **{f"{cluster}-{seed}": functools.partial(build, seed)
+       for cluster, build in (("affinity", _affinity_cluster),
+                              ("spread", _spread_cluster))
+       for seed in range(3)},
+    **SCENARIOS,
+}
+
+
+@pytest.mark.parametrize("case", list(_DOMAIN_SUM_CASES))
+def test_waves_with_product_equal_waves_with_scatter(case, monkeypatch):
     """The whole engine under either form of the in-domain sum: placements,
     admission waves and the final counts identical (the sums are the same
-    integers), on clusters of pod-affinity terms and on clusters of hard
-    and soft topology spread."""
+    integers), on clusters of pod-affinity terms, on clusters of hard and
+    soft topology spread, and on the named replica-burst scenarios
+    (tests/scenarios.py: the scatter form runs in no cell, so adversarial
+    inputs are what hold it)."""
     from kubernetes_tpu.state import dims as dims_mod
 
-    nodes, existing, pending = {"affinity": _affinity_cluster,
-                                "spread": _spread_cluster}[cluster](seed)
+    nodes, existing, pending = _DOMAIN_SUM_CASES[case]()
     tables, ex, pe, uk, ev, d = _encode(nodes, existing, pending)
     assert d.domain_sum("waves") == "product"
     res_p, waves_p = _run("waves", tables, ex, pe, uk, ev, d.D)
@@ -810,7 +877,7 @@ def test_waves_with_product_equal_waves_with_scatter(cluster, seed,
                  (res_p.state.used, res_s.state.used)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert (np.asarray(res_p.node) >= 0).any()
-    if cluster == "spread":
+    if case.startswith("spread-"):
         # the constraints bit: more than one wave, and not every pod landed
         # where its class's first pod did
         assert np.asarray(waves_p).max() >= 1
