@@ -307,6 +307,17 @@ class VolumeRef:
 
 
 @dataclass(frozen=True)
+class ClaimRef:
+    """A `persistentVolumeClaim` volume source: the claim's name in the pod's
+    namespace. Which volume it stands for is the listers' to say
+    (volume/binder.py resolves it to a VolumeRef before a wave encodes the
+    pod)."""
+
+    name: str
+    read_only: bool = False
+
+
+@dataclass(frozen=True)
 class HostPort:
     """A (protocol, hostIP, hostPort) triple; conflict semantics per
     nodeinfo/node_info.go HostPortInfo (wildcard 0.0.0.0 conflicts with all IPs)."""
@@ -350,6 +361,14 @@ class Pod:
     topology_spread: Tuple[TopologySpreadConstraint, ...] = ()
     host_ports: Tuple[HostPort, ...] = ()
     volumes: Tuple[VolumeRef, ...] = ()  # attachable volumes (NoDiskConflict)
+    # spec.volumes[].persistentVolumeClaim, by name. A wave resolves them
+    # against the listers into a COPY of the pod (`volumes` grown by what
+    # the claims attach, node affinity narrowed to where their PVs reach);
+    # the copy remembers the pod as the API gave it, so that a later wave
+    # resolves afresh whichever of the two the queue handed it
+    claims: Tuple[ClaimRef, ...] = ()
+    unresolved: Optional["Pod"] = field(default=None, compare=False,
+                                        repr=False)
     # container image names (ImageLocality; spec.containers[*].image)
     images: Tuple[str, ...] = ()
     # selectors of the Services/RCs/RSs/StatefulSets owning this pod —

@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from .types import (
     Affinity,
+    ClaimRef,
     HostPort,
     LabelSelector,
     Node,
@@ -39,6 +40,7 @@ from .types import (
     TolerationOp,
     TopologySpreadConstraint,
     UnsatisfiableAction,
+    VolumeRef,
     WeightedPodAffinityTerm,
     parse_cpu_milli,
     parse_mem_kib,
@@ -220,6 +222,105 @@ def affinity_from_spec(spec: Dict[str, Any]) -> Affinity:
 
 
 # --------------------------------------------------------------------------- #
+# volumes (predicates.go:156-221 isVolumeConflict; csi_volume_predicate.go,
+# the MaxPDVolumeCount family's filters)
+# --------------------------------------------------------------------------- #
+
+#: attachable-volume limits in a node's allocatable (volumeutil
+#: GetCSIAttachLimitKey / the in-tree *VolumeLimitKey constants)
+ATTACH_LIMIT_PREFIX = "attachable-volumes-"
+
+
+def _gce(src):
+    return VolumeRef(src.get("pdName", ""), "kubernetes.io/gce-pd",
+                     bool(src.get("readOnly", False)))
+
+
+def _ebs(src):   # an EBS volume conflicts even read-only (predicates.go:172)
+    return VolumeRef(src.get("volumeID", ""), "kubernetes.io/aws-ebs", False)
+
+
+def _rbd(src):
+    return VolumeRef(f"{src.get('pool', 'rbd')}/{src.get('image', '')}",
+                     "kubernetes.io/rbd", bool(src.get("readOnly", False)))
+
+
+def _iscsi(src):
+    return VolumeRef(src.get("iqn", ""), "kubernetes.io/iscsi",
+                     bool(src.get("readOnly", False)))
+
+
+def _azure(src):   # counted against its limit, never a disk conflict
+    return VolumeRef(src.get("diskName", ""), "kubernetes.io/azure-disk",
+                     True)
+
+
+#: a pod's own attachable sources: NoDiskConflict's four, and Azure's disk
+#: for its limit (an inline `csi` source is ephemeral: no attach limit)
+_DIRECT_SOURCES = (("gcePersistentDisk", _gce),
+                   ("awsElasticBlockStore", _ebs), ("rbd", _rbd),
+                   ("iscsi", _iscsi), ("azureDisk", _azure))
+
+
+def volumes_from_spec(spec: Dict[str, Any]) -> Tuple[tuple, tuple]:
+    """`(volumes, claims)` of a pod spec: the attachable sources it mounts
+    directly, as VolumeRefs, and its `persistentVolumeClaim` sources by
+    name."""
+    vols = spec.get("volumes")
+    if not vols:
+        return (), ()
+    direct, claims = [], []
+    for v in vols:
+        ref = v.get("persistentVolumeClaim")
+        if ref:
+            claims.append(ClaimRef(ref.get("claimName", ""),
+                                   bool(ref.get("readOnly", False))))
+            continue
+        for key, make in _DIRECT_SOURCES:
+            src = v.get(key)
+            if src:
+                direct.append(make(src))
+                break
+    return tuple(direct), tuple(claims)
+
+
+def volume_ref_from_pv(pv: Dict[str, Any]) -> Optional[VolumeRef]:
+    """The attachable volume behind a PersistentVolume, or None (NFS,
+    hostPath, local: nothing is attached). It counts against its driver's
+    limit on the node; NoDiskConflict reads a pod's direct sources only
+    (upstream's isVolumeConflict never follows a claim), so it is read-only
+    in VolumeRef's sense."""
+    spec = pv.get("spec") or {}
+    src = spec.get("csi")
+    if src:
+        return VolumeRef(src.get("volumeHandle", ""), src.get("driver", ""),
+                         True)
+    for key, make in _DIRECT_SOURCES:
+        src = spec.get(key)
+        if src:
+            ref = make(src)
+            return VolumeRef(ref.vol_id, ref.driver, True)
+    return None
+
+
+def _limit_driver(resource: str) -> str:
+    what = resource[len(ATTACH_LIMIT_PREFIX):]
+    return what[4:] if what.startswith("csi-") else "kubernetes.io/" + what
+
+
+def csinode_volume_limits(obj: Dict[str, Any]) -> Dict[str, int]:
+    """Per-driver attach limits a CSINode states
+    (`spec.drivers[].allocatable.count`; getMaxVolumeFunc reads it before
+    the node's allocatable)."""
+    out: Dict[str, int] = {}
+    for drv in (obj.get("spec") or {}).get("drivers") or []:
+        count = (drv.get("allocatable") or {}).get("count")
+        if count is not None and drv.get("name"):
+            out[drv["name"]] = int(count)
+    return out
+
+
+# --------------------------------------------------------------------------- #
 # Pod / Node
 # --------------------------------------------------------------------------- #
 
@@ -272,6 +373,8 @@ def pod_from_v1(obj: Dict[str, Any]) -> Pod:
         full = f"pod-group.scheduling.sigs.k8s.io/{key}"
         return labels.get(full, "") or anns.get(full, "")
 
+    volumes, claims = volumes_from_spec(spec)
+
     group = _gang("name")
     try:
         min_member = int(_gang("min-available") or 0)
@@ -289,6 +392,8 @@ def pod_from_v1(obj: Dict[str, Any]) -> Pod:
         tolerations=tolerations,
         topology_spread=spread,
         host_ports=tuple(host_ports),
+        volumes=volumes,
+        claims=claims,
         priority=int(spec.get("priority", 0) or 0),
         node_name=spec.get("nodeName", "") or "",
         nominated_node_name=(obj.get("status") or {}).get(
@@ -307,8 +412,13 @@ def node_from_v1(obj: Dict[str, Any]) -> Node:
     alloc = status.get("allocatable") or {}
 
     scalars: Dict[str, int] = {}
+    volume_limits: Dict[str, int] = {}
     for k, v in alloc.items():
         if k in ("cpu", "memory", "ephemeral-storage", "pods"):
+            continue
+        if k.startswith(ATTACH_LIMIT_PREFIX):
+            # no pod requests it: a limit on distinct volumes, not a scalar
+            volume_limits[_limit_driver(k)] = int(str(v))
             continue
         scalars[k] = parse_mem_kib(v) * 1024 if "hugepages" in k else int(parse_cpu_milli(v) / 1000)
 
@@ -340,6 +450,7 @@ def node_from_v1(obj: Dict[str, Any]) -> Node:
         taints=taints,
         unschedulable=bool(spec.get("unschedulable", False)),
         images_kib=images,
+        volume_limits=volume_limits,
     )
 
 

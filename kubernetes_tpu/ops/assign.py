@@ -49,6 +49,7 @@ class AssignState(NamedTuple):
     WSYM: Array  # [S, N] f32 — signed symmetric soft-affinity weights
     vol_any: Array  # [N, VW] u32 — attached volumes (NoDiskConflict/limits)
     vol_rw: Array   # [N, VW] u32 — attached read-write
+    vol_cnt: Array  # [N, DR] i32 — attached volumes of one pod alone
 
 
 class AssignResult(NamedTuple):
@@ -119,9 +120,11 @@ def assign_step(
     vr = jnp.where(live_vs, tables.volsets.rw_words[jnp.maximum(vs, 0)], 0)
     vol_any = state.vol_any.at[choice].set(state.vol_any[choice] | va)
     vol_rw = state.vol_rw.at[choice].set(state.vol_rw[choice] | vr)
+    vol_cnt = state.vol_cnt.at[choice].add(
+        jnp.where(feasible, tables.classes.vol_priv[c], 0))
 
     return AssignState(used, ppa, ppw, ppt, CNT, HOLD, WSYM,
-                       vol_any, vol_rw), node, feasible
+                       vol_any, vol_rw, vol_cnt), node, feasible
 
 
 def assign_batch(
@@ -247,13 +250,13 @@ def ports_plane(tables: ClusterTables, cyc: CycleArrays, cls: Array,
 
 
 def volumes_plane(tables: ClusterTables, cyc: CycleArrays, cls: Array,
-                  vol_any: Array, vol_rw: Array) -> Array:
+                  vol_any: Array, vol_rw: Array, vol_cnt: Array) -> Array:
     """NoDiskConflict + volume-limits plane [N] incl. the plugin flags
     (shared, see fit_plane)."""
     from .lattice import _on
 
     vconf_free, vlimit_ok = volume_components_row(
-        tables, vol_any, vol_rw, cls)
+        tables, vol_any, vol_rw, vol_cnt, cls)
     return (vconf_free | ~_on(cyc.ecfg.f_volrestrict)) \
         & (vlimit_ok | ~_on(cyc.ecfg.f_vollimits))
 
@@ -264,7 +267,7 @@ def mask_dynamic_row(
     cls: Array,
     used: Array,
     ppa: Array, ppw: Array, ppt: Array,
-    vol_any: Array, vol_rw: Array,
+    vol_any: Array, vol_rw: Array, vol_cnt: Array,
 ) -> Array:
     """The Filter components that move as replicas of the SAME class land:
     resources, host ports, volumes — all strictly per-node functions of the
@@ -273,7 +276,7 @@ def mask_dynamic_row(
     attribution decomposes."""
     return (fit_plane(tables, cyc, cls, used)
             & ports_plane(tables, cyc, cls, ppa, ppw, ppt)
-            & volumes_plane(tables, cyc, cls, vol_any, vol_rw))
+            & volumes_plane(tables, cyc, cls, vol_any, vol_rw, vol_cnt))
 
 
 def pod_mask_row(
@@ -298,7 +301,7 @@ def pod_mask_row(
                          table, spread)
         & mask_dynamic_row(tables, cyc, cls, state.used,
                            state.ppa, state.ppw, state.ppt,
-                           state.vol_any, state.vol_rw)
+                           state.vol_any, state.vol_rw, state.vol_cnt)
     )
 
 
@@ -433,7 +436,8 @@ def mask_components(
             cyc.static.node_match[c], nodes, D, cyc.SAME, spread,
         )
         host_ok = (nnr < 0) | (nodes.name_id == nnr)
-        vol_ok = volume_ok_row(tables, state.vol_any, state.vol_rw, c)
+        vol_ok = volume_ok_row(tables, state.vol_any, state.vol_rw,
+                               state.vol_cnt, c)
         nm = cyc.static.node_match[c]
         # static.mask = node_match ∧ taint_ok ∧ unsched_pass ∧ class valid;
         # recover the taint/unschedulable part by division
@@ -506,7 +510,8 @@ def _explain_mask_row(tables: ClusterTables, cyc: CycleArrays,
     # the engines' verdicts and these counts cannot drift apart
     fit = fit_plane(tables, cyc, c, state.used)
     ports_ok = ports_plane(tables, cyc, c, state.ppa, state.ppw, state.ppt)
-    vol_ok = volumes_plane(tables, cyc, c, state.vol_any, state.vol_rw)
+    vol_ok = volumes_plane(tables, cyc, c, state.vol_any, state.vol_rw,
+                           state.vol_cnt)
     # interpod/spread decomposed: mask_context_row conjoins (aff ∧ anti)
     # under one flag — KEEP the flag composition in sync with it
     aff_ok, anti_ok = affinity_rows(
@@ -757,5 +762,5 @@ def initial_state(tables: ClusterTables, cyc: CycleArrays) -> AssignState:
     return AssignState(
         used=n.used, ppa=n.port_pair_any, ppw=n.port_pair_wild, ppt=n.port_triple,
         CNT=cyc.CNT, HOLD=cyc.HOLD, WSYM=cyc.WSYM,
-        vol_any=n.vol_any, vol_rw=n.vol_rw,
+        vol_any=n.vol_any, vol_rw=n.vol_rw, vol_cnt=n.vol_cnt,
     )

@@ -470,9 +470,11 @@ def assign_waves(
         tripw = tables.portsets.trip_words[psafe]
         vs_ord = classes.volset[cord]
         vsafe = jnp.maximum(vs_ord, 0)
-        has_v = (vs_ord >= 0)
-        vanyw = tables.volsets.any_words[vsafe]               # [SC, VW]
-        vrww = tables.volsets.rw_words[vsafe]
+        vpriv = classes.vol_priv[cord]                        # [SC, DR]
+        has_v = (vs_ord >= 0) | (vpriv > 0).any(axis=1)
+        in_set = (vs_ord >= 0)[:, None]
+        vanyw = jnp.where(in_set, tables.volsets.any_words[vsafe], 0)  # [SC, VW]
+        vrww = jnp.where(in_set, tables.volsets.rw_words[vsafe], 0)
 
         B = min(_CONTENTION_BLOCK, SC)
         nb = -(-SC // B)
@@ -490,8 +492,8 @@ def assign_waves(
             jnp.bitwise_or, jnp.where(k, W[:, None, :], 0), axis=0)[-1]
 
         def block(carry, xs):
-            cum_used, c_pa, c_pw, c_pt, c_va, c_vr = carry
-            A_b, req_b, hp_b, pw_b, ww_b, tw_b, hv_b, va_b, vr_b = xs
+            cum_used, c_pa, c_pw, c_pt, c_va, c_vr, c_vc = carry
+            A_b, req_b, hp_b, pw_b, ww_b, tw_b, hv_b, va_b, vr_b, vp_b = xs
             add = jnp.where(A_b[:, :, None], req_b[:, None, :], 0)
             cum_exc = (jnp.cumsum(add, axis=0) - add) + cum_used[None]
             # earlier same-wave classes consume free space; the pod itself
@@ -519,8 +521,12 @@ def assign_waves(
             keep2 = keep & (~hp_b[:, None] | ~conflict)
 
             # volume conflict/limits against same-wave earlier classes on
-            # the same node: exclusive-prefix OR, then conflict + limits
+            # the same node: exclusive-prefix OR of the shared volumes'
+            # words and exclusive-prefix SUM of the counts of volumes that
+            # are one pod's alone, then conflict + limits
             kv = (keep2 & hv_b[:, None])[:, :, None]
+            addc = jnp.where(kv, vp_b[:, None, :], 0)         # [B, N, DR]
+            exc_vc = (jnp.cumsum(addc, axis=0) - addc) + c_vc[None]
             scan_orv = lambda W: lax.associative_scan(
                 jnp.bitwise_or, jnp.where(kv, W[:, None, :], 0), axis=0)
             exc_va = shift(scan_orv(va_b)) | c_va[None]
@@ -534,7 +540,8 @@ def assign_waves(
             after_v = tot_any | va_b[:, None, :]
             vcnt = jax.lax.population_count(
                 after_v[:, :, None, :] & tables.drv_masks[None, None, :, :]
-            ).sum(-1).astype(jnp.int32)                       # [B, N, DR]
+            ).sum(-1).astype(jnp.int32) + (
+                state.vol_cnt[None] + exc_vc + vp_b[:, None, :])  # [B, N, DR]
             vlim = nodes.vol_limit[None]                      # [1, N, DR]
             vlim_ok = ((vlim < 0) | (vcnt <= vlim)).all(-1)
             keep3 = keep2 & (~hv_b[:, None] | (~vconf & vlim_ok))
@@ -547,12 +554,14 @@ def assign_waves(
                 cum_used + add.sum(axis=0),
                 c_pa | inc_p[-1], c_pw | inc_w[-1], c_pt | inc_t[-1],
                 c_va | scan_orv(va_b)[-1], c_vr | scan_orv(vr_b)[-1],
+                c_vc + addc.sum(axis=0),
             )
             kp2 = (keep3 & hp_b[:, None])[:, :, None]
             kv2 = (keep3 & hv_b[:, None])[:, :, None]
             committed = (
                 or_red(kp2, pw_b), or_red(kp2, ww_b), or_red(kp2, tw_b),
                 or_red(kv2, va_b), or_red(kv2, vr_b),
+                jnp.where(kv2, vp_b[:, None, :], 0).sum(axis=0),
             )
             return carry2, (keep3, committed)
 
@@ -565,16 +574,19 @@ def assign_waves(
             jnp.zeros((N, Wp), tripw.dtype),
             jnp.zeros((N, VW), vanyw.dtype),
             jnp.zeros((N, VW), vrww.dtype),
+            jnp.zeros((N, vpriv.shape[1]), jnp.int32),
         )
         _, (keep_b, committed_b) = lax.scan(
             block, carry0,
             (blocks_of(A_ord), blocks_of(req_ord), blocks_of(has_p),
              blocks_of(pairw), blocks_of(wildw), blocks_of(tripw),
-             blocks_of(has_v), blocks_of(vanyw), blocks_of(vrww)))
+             blocks_of(has_v), blocks_of(vanyw), blocks_of(vrww),
+             blocks_of(vpriv)))
         keep = keep_b.reshape(nb * B, N)[:SC]
         or_blocks = lambda x: lax.associative_scan(
             jnp.bitwise_or, x, axis=0)[-1]
-        orp, orw, ort, orva, orvr = (or_blocks(cb) for cb in committed_b)
+        orp, orw, ort, orva, orvr = (or_blocks(cb) for cb in committed_b[:5])
+        addvc = committed_b[5].sum(axis=0)                    # [N, DR]
 
         A_final = jnp.zeros_like(A).at[cord].set(keep)
         m = A_final.sum(axis=1).astype(jnp.int32)             # [SC]
@@ -596,6 +608,7 @@ def assign_waves(
                 ppa=state.ppa | orp, ppw=state.ppw | orw, ppt=state.ppt | ort,
                 CNT=CNT2, HOLD=HOLD2, WSYM=WSYM2,
                 vol_any=state.vol_any | orva, vol_rw=state.vol_rw | orvr,
+                vol_cnt=state.vol_cnt + addvc,
             )
 
         # ---- map admissions back to pods: a class's kept nodes, best first

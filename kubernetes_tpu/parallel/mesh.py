@@ -129,6 +129,7 @@ def _pad_node_arrays(nodes: NodeArrays, pad: int, axis: int = 0) -> NodeArrays:
         vol_any=_auto(nodes.vol_any),
         vol_rw=_auto(nodes.vol_rw),
         vol_limit=_auto(nodes.vol_limit),
+        vol_cnt=_auto(nodes.vol_cnt),
         avoid=_concat(nodes.avoid, False),
     )
 

@@ -45,6 +45,13 @@ GANG_GROUPS = REG.counter(
     "Pod groups in gang-bearing waves, by result (admitted: at least "
     "min-available members placed; rejected: none placed)",
     labels=("result",))
+# volumes (volume/binder.py), counted on waves that popped a pod with one
+VOLUME_PODS = REG.counter(
+    "scheduler_volume_pods_total",
+    "Pods with a volume popped by a wave, by result (resolved: claims "
+    "followed to their volumes, the pod decided on; waiting: left for a "
+    "claim to bind or appear)",
+    labels=("result",))
 # cache-consistency sweep (sched/debugger.py ConsistencySweeper — the kube
 # cacheComparer made periodic): divergences found between the resident
 # encoded state and informer truth, and self-heal re-encodes taken
@@ -232,6 +239,10 @@ def observe_wave(stats, queue_lengths, cache_counts) -> None:
         GANG_GROUPS.inc(stats.gang_groups - stats.gang_groups_rejected,
                         result="admitted")
         GANG_GROUPS.inc(stats.gang_groups_rejected, result="rejected")
+    if stats.volume_pods:
+        VOLUME_PODS.inc(stats.volume_pods, result="resolved")
+    if stats.volume_waits:
+        VOLUME_PODS.inc(len(stats.volume_waits), result="waiting")
     if isinstance(queue_lengths, dict):
         observe_queue_depths(queue_lengths)
     else:

@@ -126,6 +126,11 @@ class CycleStats:
     gang_groups: int = 0
     gang_groups_rejected: int = 0
     gang_refusals: Dict[str, str] = field(default_factory=dict)
+    # volumes (volume/binder.py), on a wave that popped a pod with a volume
+    # only: the pods whose claims resolved, and for each pod left waiting on
+    # its claims why (its FailedScheduling Event's message, by pod key)
+    volume_pods: int = 0
+    volume_waits: Dict[str, str] = field(default_factory=dict)
 
 
 @dataclass
@@ -315,6 +320,14 @@ class Scheduler:
         # cost (`pump_busy_s`, `pump_turns`, `pump_events`) and the
         # `watch_evictions`. Read at a wave's END onto its record.
         self.watch_plane: Optional[Callable[[], Dict[str, Any]]] = None
+        # a pod's PersistentVolumeClaims are resolved against the server's
+        # listers at the head of a wave's `snapshot` phase
+        # (`_resolve_volumes`; volume/binder.py SchedulerVolumeBinder). None
+        # (no server, or `volume_binding=False`): claims are not followed.
+        self.volume_binder: Optional["object"] = None
+        # keys of the pods the last resolution left waiting on their claims
+        # (unschedulableQ; a PVC or PV event moves them back)
+        self.volume_waiting: set = set()
         # streaming micro-waves (ISSUE 18): when the live backlog is
         # nothing but a handful of FRESH watch deltas, admit them through
         # a small fixed-capacity wave grafted onto the resident snapshot
@@ -402,18 +415,30 @@ class Scheduler:
     def on_pod_add(self, pod: Pod) -> None:
         if pod.node_name:                       # assignedPod (:277)
             if self.cache.is_assumed(pod.key) or self.cache.get_pod(pod.key) is None:
-                self.cache.add_pod(pod)
+                self._confirm(pod)
             # a new pod landing may unblock anti-affinity waiters etc.
             self.queue.move_all_to_active(self.clock())
         elif self.responsible_for(pod):
             self.queue.add(pod, now=self.clock())
+
+    def _confirm(self, pod: Pod) -> None:
+        """`cache.add_pod` for a bound pod the informer delivers. Where it is
+        the echo of this scheduler's own Binding and the pod names claims,
+        what they attach is what the wave resolved and the node has counted
+        since the assume: the claim lister may not have heard yet of a claim
+        bound at placement, and the count must not dip meanwhile."""
+        if pod.claims:
+            assumed = self.cache.get_pod(pod.key)
+            if assumed is not None:
+                pod.volumes = assumed.volumes
+        self.cache.add_pod(pod)
 
     def on_pod_update(self, old: Pod, new: Pod) -> None:
         if new.node_name:
             if self.cache.get_pod(new.key) is not None and not self.cache.is_assumed(new.key):
                 self.cache.update_pod(new)
             else:
-                self.cache.add_pod(new)
+                self._confirm(new)
             # label changes on bound pods can unblock affinity waiters
             # (eventhandlers.go moves pods on assigned-pod updates)
             self.queue.move_all_to_active(self.clock())
@@ -435,6 +460,7 @@ class Scheduler:
             self.queue.move_all_to_active(self.clock())
         else:
             self.queue.delete(pod.key)
+            self.volume_waiting.discard(pod.key)
             # a pod parked in the Permit waiting map is assumed in the cache;
             # deletion must unwind that state, not leave it to expire into a
             # requeue of a pod that no longer exists
@@ -589,6 +615,12 @@ class Scheduler:
             self.telemetry.finish_wave(span, stats=stats, engine="extenders",
                                        extra=wave.extra)
             return stats
+        self._resolve_volumes(wave)
+        if not wave.batch and not wave.ext_batch:
+            # every pod popped waits on its claims: nothing to decide
+            wave.engine = "volume-wait"
+            span.mark("requeue")
+            return self._record(wave)
         if not self._decide(wave):
             # crash-consistent wave abort: the dispatch died on BOTH
             # backends before any readback, so nothing was assumed and
@@ -728,6 +760,51 @@ class Scheduler:
             return False
         return True
 
+    def _resolve_volumes(self, wave: Wave) -> None:
+        """Between admit and decide, at the head of the `snapshot` phase
+        (`snapshot/volumes`): each popped pod that names
+        PersistentVolumeClaims is exchanged for the copy the binder resolves
+        against the listers (volume/binder.py `resolved_pod`), and a pod
+        that must wait for a claim leaves the batch for unschedulableQ with
+        the binder's reason. A batch without a volume pays one pass of two
+        attribute reads a pod. Touches the queue (add_unschedulable) and
+        `volume_waiting`; counts ride the wave's record."""
+        binder = self.volume_binder
+        if binder is None or not any(
+                p.claims or p.volumes for p, _ in wave.batch):
+            return
+        from ..volume.binder import resolved_pod
+
+        tr = trace.current()
+        t0 = time.perf_counter()
+        stats, waiting = wave.stats, self.volume_waiting
+        kept: List[Tuple[Pod, int]] = []
+        distinct: set = set()
+        for pod, attempts in wave.batch:
+            pod = pod.unresolved or pod
+            if pod.claims:
+                decision = binder.resolve(pod)
+                if decision.wait:
+                    waiting.add(pod.key)
+                    stats.volume_waits[pod.key] = decision.reason
+                    stats.unschedulable += 1
+                    stats.failed_keys.append(pod.key)
+                    self.queue.add_unschedulable(pod, attempts, wave.now,
+                                                 cycle=wave.cycle)
+                    continue
+                waiting.discard(pod.key)
+                pod = resolved_pod(pod, decision)
+            if pod.volumes:
+                stats.volume_pods += 1
+                distinct.update((v.driver, v.vol_id) for v in pod.volumes)
+            kept.append((pod, attempts))
+        wave.batch = kept
+        wave.extra["volume_pods"] = stats.volume_pods
+        wave.extra["volumes_distinct"] = len(distinct)
+        wave.extra["volume_waiting"] = len(waiting)
+        if tr is not None:
+            tr.child("volumes", time.perf_counter() - t0)
+
     def _decide(self, wave: Wave) -> bool:
         """Stage 2, decide: snapshot, engine plan, prewarm bookkeeping,
         the supervised dispatch with its CPU fallback, the prestage
@@ -746,6 +823,12 @@ class Scheduler:
             self._micro_snapshot_keys(pending) if wave.micro
             else self._snapshot_keys(pending))
         snap = wave.snap
+        if wave.stats.volume_pods:
+            # the classes the engine tells the volume pods apart by: pods
+            # that differ only in the names of their own volumes share one
+            row = self.encoder.pod_row
+            wave.extra["volume_classes"] = len(
+                {row(p)[2] for p in pending if p.volumes})
         span.mark("snapshot")
         # how the snapshot this wave dispatches on was produced
         # ("full" | "patch" | "cached") rides the wave's record

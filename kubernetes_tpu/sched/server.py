@@ -10,7 +10,6 @@ leader election like the reference binary (:254-260).
 
 from __future__ import annotations
 
-import dataclasses
 import logging
 import queue
 import threading
@@ -19,11 +18,11 @@ from typing import Any, Dict, Optional
 
 from kubernetes_tpu.api.types import (
     Affinity,
-    NodeSelector,
-    NodeSelectorTerm,
+    Node,
     Pod,
 )
-from kubernetes_tpu.api.v1 import node_from_v1, pod_from_v1
+from kubernetes_tpu.api.v1 import (csinode_volume_limits, node_from_v1,
+                                   pod_from_v1)
 from kubernetes_tpu.client.events import EventBroadcaster
 from kubernetes_tpu.client.informers import SharedInformer
 from kubernetes_tpu.client.leaderelection import (
@@ -186,8 +185,10 @@ class BindWindow:
 
 class APIBinder:
     """Binder over POST pods/{name}/binding (scheduler.go:565). When volume
-    binding is wired, BindPodVolumes runs first (scheduler.go:660,517) and a
-    volume failure aborts the pod bind → assume rollback.
+    binding is wired (`SchedulerServer.start()` hands it the server's
+    `volume_binder`), BindPodVolumes runs first for a pod that names claims
+    (scheduler.go:660,517; the span `bind-call/volume-bind`) and a volume
+    failure aborts the pod bind → assume rollback.
 
     Fenced: with a `fence_source` attached (leader election), every Binding
     is stamped with the current lease generation so the apiserver can
@@ -211,7 +212,7 @@ class APIBinder:
     turn), else a `BindWindow` of that width over `bind`, which any number
     of threads may call at once."""
 
-    def __init__(self, client, volume_binder=None, pod_lookup=None,
+    def __init__(self, client, volume_binder=None,
                  fence_source=None,
                  fence_lease: str = "",
                  retry_budget: int = 3,
@@ -223,7 +224,6 @@ class APIBinder:
 
         self.client = client
         self.volume_binder = volume_binder
-        self.pod_lookup = pod_lookup  # (ns, name) -> dict pod or None
         self.fence_source = fence_source  # () -> int lease generation
         self.fence_lease = fence_lease or DEFAULT_FENCING_LEASE
         self.stale_rejects = 0  # fenced-off binds (the mechanism working)
@@ -261,9 +261,13 @@ class APIBinder:
                                               FENCING_LEASE_ANNOTATION,
                                               FENCING_TOKEN_ANNOTATION)
 
-        if self.volume_binder is not None and self.pod_lookup is not None:
-            obj = self.pod_lookup(pod.namespace, pod.name)
-            if obj is not None and not self.volume_binder.bind(obj, node_name):
+        if self.volume_binder is not None and pod.claims:
+            tr = trace.current()
+            t0 = time.perf_counter()
+            ok = self.volume_binder.bind(pod, node_name)
+            if tr is not None:
+                tr.child("volume-bind", time.perf_counter() - t0)
+            if not ok:
                 return False
         annotations = None
         if self.fence_source is not None:
@@ -433,25 +437,6 @@ def apply_pod_update_v1(scheduler: Scheduler, old: Obj, new: Obj,
     scheduler.on_pod_update(pod_from_v1(old), to_pod(new))
 
 
-def restrict_pod_nodes(pod: Pod, allowed: frozenset) -> Pod:
-    """AND a node-name restriction into the pod's required node affinity by
-    adding matchFields(metadata.name IN allowed) to every term (or one fresh
-    term) — evaluated on device like any other affinity."""
-    names = tuple(sorted(allowed))
-    aff = pod.affinity
-    if aff.node_required and aff.node_required.terms:
-        terms = tuple(
-            dataclasses.replace(t, field_name_in=tuple(
-                sorted(set(t.field_name_in) & allowed
-                       if t.field_name_in else allowed)) or ("",))
-            for t in aff.node_required.terms)
-    else:
-        terms = (NodeSelectorTerm(field_name_in=names),)
-    pod.affinity = dataclasses.replace(
-        aff, node_required=NodeSelector(terms=terms))
-    return pod
-
-
 class SchedulerServer:
     """The scheduler process: New + Run (scheduler.go:255,425-431)."""
 
@@ -575,9 +560,12 @@ class SchedulerServer:
         self.volume_binding = volume_binding
         self.volume_binder = None
         self.pvc_informer = self.pv_informer = self.sc_informer = None
+        self.csinode_informer = None
+        # claim key -> keys of the BOUND pods that name it: a claim that
+        # binds or changes after its pod was decoded is followed again
+        self._bound_by_claim: Dict[str, set] = {}
         self.pdb_informer = None
         self._pdb_cache: Dict[str, tuple] = {}  # key → (ns, selector, allowed)
-        self._waiting_on_volumes: set = set()  # pod keys parked on PVCs
         self._creation_seq = 0
         self._stop = threading.Event()
         self._threads = []
@@ -631,7 +619,30 @@ class SchedulerServer:
         # stable FIFO-within-priority ordering (creationTimestamp analog)
         self._creation_seq += 1
         pod.creation_index = self._creation_seq
+        if pod.claims and pod.node_name and self.volume_binder is not None:
+            # a bound pod's claims count on its node as they stand (and
+            # again when one of them changes: `_on_claim`); a pending
+            # pod's are resolved by the wave that pops it
+            for ref in pod.claims:
+                self._bound_by_claim.setdefault(
+                    f"{pod.namespace}/{ref.name}", set()).add(pod.key)
+            pod.volumes += self.volume_binder.volumes_of(pod)
         return pod
+
+    def _to_node(self, obj: Obj) -> Node:
+        node = node_from_v1(obj)
+        if self.csinode_informer is not None:
+            # getMaxVolumeFunc: a driver's CSINode count, else allocatable
+            csinode = self.csinode_informer.lister.get("", node.name)
+            if csinode is not None:
+                node.volume_limits.update(csinode_volume_limits(csinode))
+        return node
+
+    @property
+    def _waiting_on_volumes(self) -> set:
+        """Keys of the pods parked on their claims (the scheduler's
+        `volume_waiting`)."""
+        return self.scheduler.volume_waiting
 
     @staticmethod
     def _schedulable(obj: Obj) -> bool:
@@ -659,19 +670,67 @@ class SchedulerServer:
 
     def _on_pod_delete(self, obj: Obj) -> None:
         with self._handling():
-            self.scheduler.on_pod_delete(pod_from_v1(obj))
+            pod = pod_from_v1(obj)
+            for ref in pod.claims:
+                claim = f"{pod.namespace}/{ref.name}"
+                keys = self._bound_by_claim.get(claim)
+                if keys is not None:
+                    keys.discard(pod.key)
+                    if not keys:
+                        del self._bound_by_claim[claim]
+            self.scheduler.on_pod_delete(pod)
+
+    def _on_claim(self, obj: Obj) -> None:
+        """A claim came or changed: the bound pods that name it are decoded
+        again, so that their node counts the volume the claim stands for
+        NOW; then what every volume event does. All of it under `_mu`: a
+        pod handler decodes under it too, so either it read the claim
+        lister after this event reached it, or it has registered its pod
+        here by the time this looks."""
+        key = f"{meta.namespace(obj) or 'default'}/{meta.name(obj)}"
+        with self._handling():
+            sched = self.scheduler
+            for pod_key in tuple(self._bound_by_claim.get(key, ())):
+                ns, name = meta.split_key(pod_key)
+                cur = self.pod_informer.lister.get(ns, name)
+                if cur is not None and pod_schedulable_v1(cur) \
+                        and sched.cache.get_pod(pod_key) is not None \
+                        and not sched.cache.is_assumed(pod_key):
+                    sched.cache.update_pod(self._to_pod(cur))
+            sched.queue.move_all_to_active(sched.clock())
+
+    def _nodes_changed(self) -> None:
+        if self.volume_binder is not None:
+            self.volume_binder.nodes_changed()
 
     def _on_node_add(self, obj: Obj) -> None:
         with self._handling():
-            self.scheduler.on_node_add(decoded(node_from_v1, obj))
+            self._nodes_changed()
+            self.scheduler.on_node_add(decoded(self._to_node, obj))
 
     def _on_node_update(self, old: Obj, new: Obj) -> None:
         with self._handling():
-            self.scheduler.on_node_update(node_from_v1(new))
+            self._nodes_changed()
+            self.scheduler.on_node_update(self._to_node(new))
 
     def _on_node_delete(self, obj: Obj) -> None:
         with self._handling():
+            self._nodes_changed()
             self.scheduler.on_node_delete(meta.name(obj))
+
+    def _on_csinode(self, obj: Obj) -> None:
+        """A CSINode came, changed or went: its node's limits follow (the
+        node handler reads the CSINode lister, which has the event)."""
+        node = self.node_informer.lister.get("", meta.name(obj))
+        if node is not None:   # None at the initial list: no node yet
+            self._on_node_update(node, node)
+
+    def _on_volume_event(self, obj: Obj) -> None:
+        """A PersistentVolume(Claim) or StorageClass came or changed: pods
+        that wait on a claim, or found no node their volumes reach, may go
+        now (eventhandlers.go onPvAdd / onPvcAdd: MoveAllToActiveQueue)."""
+        with self._handling():
+            self.scheduler.queue.move_all_to_active(self.scheduler.clock())
 
     def _watch_plane(self) -> Dict[str, Any]:
         """What the watch plane did since the previous call, for a wave's
@@ -684,8 +743,9 @@ class SchedulerServer:
         another process leaves these out. `start()` resets it once the
         informers' initial lists are in."""
         now = sum(inf.relists for inf in (
-            self.pod_informer, self.node_informer, self.pdb_informer)
-            if inf is not None)
+            self.pod_informer, self.node_informer, self.pdb_informer,
+            self.csinode_informer, self.sc_informer, self.pv_informer,
+            self.pvc_informer) if inf is not None)
         out = {"informer_relists": now - self._relists_seen}
         self._relists_seen = now
         if self._store_counters is not None:
@@ -709,6 +769,44 @@ class SchedulerServer:
         m = obj.get("metadata", {})
         self._pdb_cache.pop(
             f"{m.get('namespace', 'default')}/{m.get('name', '')}", None)
+
+    def _start_volume_informers(self, tel) -> None:
+        """The PersistentVolumeClaim, PersistentVolume, StorageClass and
+        CSINode informers, listed and synced BEFORE the nodes and the pods
+        (a node's limits and a bound pod's volumes are read off their
+        listers as those arrive), and the volume binder over their listers:
+        the stage `start/volumes-sync`, each list a stage below it."""
+        from kubernetes_tpu.volume.binder import SchedulerVolumeBinder
+
+        t0 = tel.clock()
+        self.csinode_informer = SharedInformer(self.client.csinodes)
+        self.csinode_informer.add_handlers(
+            on_add=self._on_csinode,
+            on_update=lambda old, new: self._on_csinode(new),
+            on_delete=self._on_csinode)
+        self.sc_informer = SharedInformer(self.client.storageclasses)
+        self.pv_informer = SharedInformer(self.client.persistentvolumes)
+        self.pvc_informer = SharedInformer(self.client.persistentvolumeclaims)
+        for inf in (self.sc_informer, self.pv_informer):
+            inf.add_handlers(
+                on_add=self._on_volume_event,
+                on_update=lambda old, new: self._on_volume_event(new))
+        self.pvc_informer.add_handlers(
+            on_add=self._on_claim,
+            on_update=lambda old, new: self._on_claim(new))
+        for name, inf in (("csinodes", self.csinode_informer),
+                          ("storageclasses", self.sc_informer),
+                          ("persistentvolumes", self.pv_informer),
+                          ("persistentvolumeclaims", self.pvc_informer)):
+            start_informer(inf, tel, f"start/volumes-sync/{name}",
+                           "scheduler")
+        tel.loop_span("start/volumes-sync", tel.clock() - t0)
+        self.volume_binder = SchedulerVolumeBinder(
+            self.client, self.pvc_informer.lister, self.pv_informer.lister,
+            self.sc_informer.lister, self.node_informer.lister)
+        self.scheduler.volume_binder = self.volume_binder
+        if isinstance(self.scheduler.binder, APIBinder):
+            self.scheduler.binder.volume_binder = self.volume_binder
 
     def start(self) -> "SchedulerServer":
         from kubernetes_tpu.utils.platform import (enable_compile_cache,
@@ -740,6 +838,8 @@ class SchedulerServer:
         self.node_informer.add_handlers(on_add=self._on_node_add,
                                         on_update=self._on_node_update,
                                         on_delete=self._on_node_delete)
+        if self.volume_binding:
+            self._start_volume_informers(tel)
         start_informer(self.node_informer, tel, "start/nodes-sync",
                        "scheduler")
         start_informer(self.pod_informer, tel, "start/pods-sync",
@@ -783,7 +883,8 @@ class SchedulerServer:
         if self.elector is not None:
             self.elector.stop()
         for inf in (self.pod_informer, self.node_informer,
-                    self.pdb_informer):
+                    self.pdb_informer, self.csinode_informer,
+                    self.sc_informer, self.pv_informer, self.pvc_informer):
             if inf is not None:
                 inf.stop()
         for t in self._threads:
@@ -807,7 +908,8 @@ class SchedulerServer:
         if self.elector is not None:
             self.elector.crash()
         for inf in (self.pod_informer, self.node_informer,
-                    self.pdb_informer):
+                    self.pdb_informer, self.csinode_informer,
+                    self.sc_informer, self.pv_informer, self.pvc_informer):
             if inf is not None:
                 inf.stop()
         for t in self._threads:
@@ -952,8 +1054,9 @@ class SchedulerServer:
         for key in stats.failed_keys:
             if explainer is not None and explainer.why(key) is not None:
                 continue
-            msg = stats.gang_refusals.get(
-                key, "no nodes available to schedule pod")
+            msg = stats.gang_refusals.get(key) \
+                or stats.volume_waits.get(key) \
+                or "no nodes available to schedule pod"
             ns, name = meta.split_key(key)
             obj = self.pod_informer.lister.get(ns, name) \
                 if self.pod_informer else None

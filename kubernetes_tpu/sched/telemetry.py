@@ -634,6 +634,13 @@ class SchedulerTelemetry:
         loop.graft(path, self._gc.children(self._stage_gc_mark))
         self._stage_gc_mark = self._gc.mark()
 
+    def loop_span(self, path: str, seconds: float) -> None:
+        """A stage that HOLDS stages already filed below it
+        (`start/volumes-sync` over its four lists): its own seconds, without
+        moving the mark the next stage is reckoned from."""
+        if self._loop is not None:
+            self._loop.child(path, seconds)
+
     def loop_account(self) -> Dict[str, List[float]]:
         """The open account as it stands, `{path: [count, total_s, max_s]}`:
         laps at the top, stages below them."""

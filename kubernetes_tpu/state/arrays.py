@@ -57,6 +57,8 @@ class NodeArrays(NamedTuple):
     vol_any: Array        # [N, VW] u32 — volumes attached by pods on the node
     vol_rw: Array         # [N, VW] u32 — volumes attached read-write
     vol_limit: Array      # [N, DR] i32 — per-driver attach limits, -1 unlimited
+    vol_cnt: Array        # [N, DR] i32 — attached volumes no second pod
+                          # references (Encoder.vol_owner), per driver
     avoid: Array          # [N] bool — preferAvoidPods annotation present
                           # (NodePreferAvoidPods score, node_prefer_avoid_pods.go)
 
@@ -114,7 +116,10 @@ class VolSetTable(NamedTuple):
     predicates.go:156-221, csi_volume_predicate.go:89). Bitsets are over the
     volume vocab; per-driver occupancy is DERIVED from bitsets by popcount
     against `ClusterTables.drv_masks`, so the engines carry only two [N, VW]
-    words per node."""
+    words per node. Only SHARED volumes (two pods seen naming one) are in the
+    vocab; a volume of one pod alone is a count per driver
+    (`PodClassTable.vol_priv`, `NodeArrays.vol_cnt`), so a claim a pod neither
+    splits a class nor widens VW."""
 
     any_words: Array  # [SV, VW] u32 — all volumes in the set
     rw_words: Array   # [SV, VW] u32 — volumes mounted read-write
@@ -156,7 +161,8 @@ class PodClassTable(NamedTuple):
     tsc_key: Array      # [SC, TS] i32 topo-key index
     tsc_maxskew: Array  # [SC, TS] i32
     tsc_hard: Array     # [SC, TS] bool (DoNotSchedule)
-    volset: Array       # [SC] i32 → VolSetTable, -1 = no attachable volumes
+    volset: Array       # [SC] i32 → VolSetTable, -1 = no SHARED volumes
+    vol_priv: Array     # [SC, DR] i32 — volumes of the pod alone, per driver
     ssel_terms: Array   # [SC, SS] i32 → TermTable (SelectorSpread owners), -1 pad
     img_ids: Array      # [SC, CI] i32 → image vocab (ImageLocality), -1 pad
     lim_rid: Array      # [SC] i32 → ReqTable (container limits), -1 none

@@ -263,6 +263,14 @@ class SchedulerCache:
         if gk:
             self._group_bound[gk] = self._group_bound.get(gk, 0) + 1
 
+    def _pod_gone(self, pod: Pod) -> None:
+        """`_pod_unplaced` for a pod that leaves the cache for good (removed,
+        forgotten, expired), not for a newer object of itself: the volumes
+        it alone named lose their owner (Encoder.vol_owner)."""
+        self._pod_unplaced(pod)
+        if pod.volumes and self._encoder is not None:
+            self._encoder.release_volumes(pod)
+
     def _pod_unplaced(self, pod: Pod) -> None:
         if pod.node_name:
             self._by_node.get(pod.node_name, {}).pop(pod.key, None)
@@ -331,7 +339,7 @@ class SchedulerCache:
                 raise CacheError(f"pod {key} is bound, cannot forget")
             del self._pods[key]
             self._assumed_outstanding -= 1
-            self._pod_unplaced(st.pod)
+            self._pod_gone(st.pod)
             self._generation += 1
 
     def add_pod(self, pod: Pod) -> None:
@@ -381,7 +389,7 @@ class SchedulerCache:
             del self._pods[key]
             if st.assumed:
                 self._assumed_outstanding -= 1
-            self._pod_unplaced(st.pod)
+            self._pod_gone(st.pod)
             self._generation += 1
 
     def is_assumed(self, key: str) -> bool:
@@ -402,7 +410,7 @@ class SchedulerCache:
             for key, st in list(self._pods.items()):
                 if st.assumed:
                     del self._pods[key]
-                    self._pod_unplaced(st.pod)
+                    self._pod_gone(st.pod)
                     dropped.append(st.pod)
             if dropped:
                 self._assumed_outstanding -= len(dropped)
@@ -489,7 +497,7 @@ class SchedulerCache:
                 if st.assumed and st.binding_finished and st.deadline is not None \
                         and now >= st.deadline:
                     del self._pods[key]
-                    self._pod_unplaced(st.pod)
+                    self._pod_gone(st.pod)
                     expired.append(key)
             if expired:
                 self._assumed_outstanding -= len(expired)
@@ -647,6 +655,7 @@ class SchedulerCache:
                     self._staging_nodes.alloc[slot] = 0
                     self._staging_nodes.used[slot] = 0
                     self._staging_nodes.label_ints[slot] = 0
+                    self._staging_nodes.vol_cnt[slot] = 0
                 # pods still bound to the vanished node must stop pointing at
                 # the freed slot (a later node may reuse it); re-row them
                 for key, p in self._by_node.get(name, {}).items():
@@ -1138,6 +1147,11 @@ class SchedulerCache:
                 k: self._put(builders[k](d), device, mesh)
                 for k in builders if sizes[k] != self._reg_sizes[k]
             })
+            if sizes["volsets"] != self._reg_sizes["volsets"]:
+                # a set the registry had not seen may name a volume the
+                # vocab had not: its bit belongs to its driver's mask
+                tables = tables._replace(drv_masks=self._put(
+                    encoder.build_drv_masks(d), device, mesh))
             self._reg_sizes = sizes
 
         # --- existing-pod rows: removals first so a same-window remove+add
@@ -1313,7 +1327,7 @@ class FakeCache(SchedulerCache):
                        if s.assumed and s.binding_finished]
             for k in expired:
                 st = self._pods.pop(k)
-                self._pod_unplaced(st.pod)
+                self._pod_gone(st.pod)
             if expired:
                 self._assumed_outstanding -= len(expired)
                 self._generation += 1

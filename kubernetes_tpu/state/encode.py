@@ -139,6 +139,17 @@ class Encoder:
         self.image_sizes: List[int] = []  # KiB, parallel to vocabs.images
         self.volset_reg = Vocab()   # sorted ((vol_id, driver_id, ro), …)
         self.vol_driver: List[int] = []  # driver id per volume vocab id
+        # A volume enters the vocab (a bit of VW, a member of a volume set,
+        # part of its pods' class) only once TWO pods have been seen naming
+        # it: NoDiskConflict needs the identity of a volume two pods can
+        # share, the attach limit only a count per node and driver. Until
+        # then it is its one pod's alone: (driver, volume id) -> that pod's
+        # key here, a count in the class's `vol_priv` and the node's
+        # `vol_cnt`. A second pod naming it promotes it for good
+        # (`volume_key`); class ids and node rows made before are stale,
+        # which is `classes_stale`'s re-walk. A stale owner (a pending pod
+        # deleted unseen) can only promote a volume early, never late.
+        self.vol_owner: Dict[tuple, str] = {}
         # gang pod groups (BASELINE config 5; ops/gang.py): group key → id +
         # effective minMember per id. UNLIKE every other vocab these are
         # compactable (compact_groups): gang jobs churn per-job, and dead
@@ -289,6 +300,43 @@ class Encoder:
             for v in vols))
         return self.volset_reg.intern(key)
 
+    def volume_key(self, p: Pod) -> tuple:
+        """`(shared, private)` of a pod's distinct volumes: the VolumeRefs
+        that are in the vocab (another pod names them too), as a sorted
+        tuple, and ((driver id, count), …) of those that are this pod's
+        alone. Registers the pod as the owner of a volume seen first here,
+        and promotes a volume another pod owns (see `vol_owner`). Two pods
+        that differ only in the names of their own volumes get equal
+        keys."""
+        shared, priv, seen = [], {}, set()
+        known = self.vocabs.volumes.get
+        owner_of = self.vol_owner
+        me = p.key
+        for v in p.volumes:
+            ident = (v.driver, v.vol_id)
+            if ident in seen:
+                continue
+            seen.add(ident)
+            if known(ident) < 0:
+                owner = owner_of.setdefault(ident, me)
+                if owner == me:
+                    did = self.vocabs.vol_drivers.intern(v.driver)
+                    priv[did] = priv.get(did, 0) + 1
+                    continue
+                del owner_of[ident]
+                self.volume_id(v)
+                self.classes_stale = True
+            shared.append(v)
+        return (tuple(sorted(shared, key=lambda v: (v.driver, v.vol_id))),
+                tuple(sorted(priv.items())))
+
+    def release_volumes(self, p: Pod) -> None:
+        """The pod is gone: the volumes it alone named have no owner."""
+        for v in p.volumes:
+            ident = (v.driver, v.vol_id)
+            if self.vol_owner.get(ident) == p.key:
+                del self.vol_owner[ident]
+
     def projection_rewalk(self) -> None:
         """A new label key became selector-referenced: drop the row memos so
         the owner re-walks every pod under the widened projection."""
@@ -329,7 +377,7 @@ class Encoder:
                 p.tolerations, p.host_ports, p.topology_spread,
                 p.spread_selectors, p.images,
                 lim if (lim.milli_cpu or lim.memory_kib) else None,
-                p.volumes)
+                self.volume_key(p) if p.volumes else None)
 
     def class_id_memo(self, p: Pod, ns_id: int) -> int:
         """class_id through the value-based fingerprint memo: the full spec
@@ -388,9 +436,10 @@ class Encoder:
         imgs = tuple(self.image_id(nm) for nm in p.images)
         lim = (self.req_id(p.limits)
                if (p.limits.milli_cpu or p.limits.memory_kib) else -1)
-        vols = self.volset_id(p.volumes) if p.volumes else -1
+        shared, priv = self.volume_key(p) if p.volumes else ((), ())
+        vols = self.volset_id(shared) if shared else -1
         spec = (ns_id, rid, ls, nsel, aff_active, nterms, pterms, tol, ports,
-                aff, anti, paff, panti, tsc, ssel, imgs, lim, vols)
+                aff, anti, paff, panti, tsc, ssel, imgs, lim, vols, priv)
         before = len(self.class_reg)
         cid = self.class_reg.intern(spec)
         if cid == before:
@@ -519,7 +568,7 @@ class Encoder:
                   p.tolerations, p.host_ports, p.topology_spread,
                   p.spread_selectors, p.images,
                   lim if (lim.milli_cpu or lim.memory_kib) else None,
-                  p.volumes)
+                  self.volume_key(p) if p.volumes else None)
             cid = class_memo.get(fp)
             if cid is None:
                 cid = class_id(p)
@@ -758,13 +807,14 @@ class Encoder:
             panti_terms=z((SC, d.PAN), -1), panti_w=z((SC, d.PAN)),
             tsc_term=z((SC, d.TS), -1), tsc_key=z((SC, d.TS), -1),
             tsc_maxskew=z((SC, d.TS)), tsc_hard=z((SC, d.TS), False, bool),
-            volset=z((SC,), -1),
+            volset=z((SC,), -1), vol_priv=z((SC, d.DR)),
             ssel_terms=z((SC, d.SS), -1), img_ids=z((SC, d.CI), -1),
             lim_rid=z((SC,), -1),
         )
         for i, spec in enumerate(self._class_spec):
             (ns_id, rid, ls, nsel, aff_active, nterms, pterms, tol, ports,
-             aff, anti, paff, panti, tsc, ssel, imgs, lim, vols) = spec
+             aff, anti, paff, panti, tsc, ssel, imgs, lim, vols,
+             priv) = spec
             t["valid"][i] = True
             t["ns"][i], t["rid"][i], t["labelset"][i] = ns_id, rid, ls
             t["nsel_term"][i] = nsel
@@ -791,6 +841,8 @@ class Encoder:
                 t["img_ids"][i, ti] = x
             t["lim_rid"][i] = lim
             t["volset"][i] = vols
+            for did, n in priv:
+                t["vol_priv"][i, did] = n
         return PodClassTable(**t)
 
     def build_volset_table(self, d: Dims) -> "VolSetTable":
@@ -888,6 +940,7 @@ class Encoder:
         arrays.port_triple[i] = 0
         arrays.vol_any[i] = 0
         arrays.vol_rw[i] = 0
+        arrays.vol_cnt[i] = 0
         for p in pods_on_node:
             spec = self._class_spec[self.pod_row(p)[2]]
             cpu, mem, eph, scalars = self.req_reg.lookup(spec[1])
@@ -911,6 +964,8 @@ class Encoder:
                     _set_bit(arrays.vol_any[i], vid)
                     if not ro:
                         _set_bit(arrays.vol_rw[i], vid)
+            for did, n in spec[18]:
+                arrays.vol_cnt[i, did] += n
 
     @staticmethod
     def empty_node_arrays(d: Dims) -> NodeArrays:
@@ -937,6 +992,7 @@ class Encoder:
             vol_any=np.zeros((N, d.VW), U32),
             vol_rw=np.zeros((N, d.VW), U32),
             vol_limit=np.full((N, d.DR), -1, I32),
+            vol_cnt=np.zeros((N, d.DR), I32),
             avoid=np.zeros((N,), bool),
         )
 

@@ -464,6 +464,7 @@ def test_the_new_metrics_through_their_files(cell_name, expect, absent):
     kept = cell.compute_metrics(BENCH, "per_layer", cell_name, old)
     new = {m["name"] for m in BENCH["per_layer"]
            if m["name"].startswith(("start_", "gc_"))}
-    # PR 37's ten, and PR 39's two that read the same `loop.children`
+    # PR 37's ten, PR 39's two that read the same `loop.children`
     # (`start_pods_list_wire_s`, `start_pods_list_decode_s`: the http cell's)
-    assert len(new) == 12 and set(kept) == set(out) - new
+    # and PR 45's `start_volumes_sync_s` (the volume cell's)
+    assert len(new) == 13 and set(kept) == set(out) - new

@@ -1070,10 +1070,15 @@ class TestServerLoopSpans:
                                    "index": [1, 0.01, 0.01],
                                    "handlers": [1, 0.125, 0.125],
                                    "handlers/decode": [8, 0.0625, 0.03]}),
-            "pods": (1.25, False, None)}
+            "pods": (1.25, False, None),
+            # the four volume informers, listed before the nodes (PR 45)
+            "csinodes": (0.125, True, {"list": [1, 0.1, 0.1]}),
+            "storageclasses": (0.0, True, {}),
+            "persistentvolumes": (0.0625, True, {}),
+            "persistentvolumeclaims": (0.0625, True, {})}
 
         class _Informer:
-            last_sync, relists = None, 1
+            last_sync, relists, lister = None, 1, None
 
             def __init__(self, rc):
                 self.rc = rc
@@ -1117,10 +1122,21 @@ class TestServerLoopSpans:
         assert sum(phases.values()) == pytest.approx(w1["t_start"] - 50.0)
         stages = {p: v[1] for p, v in loop["children"].items()
                   if p.count("/") == 1}
-        assert stages == pytest.approx({"start/nodes-sync": 0.25,
+        assert stages == pytest.approx({"start/volumes-sync": 0.25,
+                                        "start/nodes-sync": 0.25,
                                         "start/pods-sync": 1.25,
                                         "start/wiring": 0.0})
         assert sum(stages.values()) == pytest.approx(phases["start"])
+        # the volume stage holds its four lists, each a stage below it
+        assert {p: v[1] for p, v in loop["children"].items()
+                if p.startswith("start/volumes-sync/")
+                and p.count("/") == 2} == pytest.approx({
+            "start/volumes-sync/csinodes": 0.125,
+            "start/volumes-sync/storageclasses": 0.0,
+            "start/volumes-sync/persistentvolumes": 0.0625,
+            "start/volumes-sync/persistentvolumeclaims": 0.0625})
+        assert loop["children"]["start/volumes-sync/csinodes/list"] == [
+            1, 0.1, 0.1]
         # the nodes' round, grafted under the stage that waited for it;
         # the pods' had not ended: nothing of it, and the verdict kept
         assert loop["children"]["start/nodes-sync/handlers/decode"] == [
@@ -1135,8 +1151,11 @@ class TestServerLoopSpans:
                        for sub in ("", "/full"))
         assert every[0] >= full[0] >= 1 and every[1] >= full[1] >= full[2] > 0
         assert "gc" not in loop
-        assert loop["synced"] == {"start/nodes-sync": True,
-                                  "start/pods-sync": False}
+        assert loop["synced"] == {
+            "start/nodes-sync": True, "start/pods-sync": False,
+            **{f"start/volumes-sync/{r}": True for r in (
+                "csinodes", "storageclasses", "persistentvolumes",
+                "persistentvolumeclaims")}}
         assert START_UNSYNCED.value(component="scheduler",
                                     resource="pods") == unsynced + 1
         assert any("pods informer had not synced" in r.getMessage()
