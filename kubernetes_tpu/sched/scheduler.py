@@ -667,11 +667,12 @@ class Scheduler:
 
         Touches the queue (pump, release_deferred, pop, park_deferred),
         the governor, the Permit waiters it expires, and the cache only
-        through `cleanup` and `drain_confirm_waits`. No snapshot, device,
+        through `cleanup` (with the size of its pass, for the record) and
+        `drain_confirm_waits`. No snapshot, device,
         assume or Binding. Closes `pump` and `pop`."""
         span, now = wave.span, wave.now
         self.queue.pump(now)
-        self.cache.cleanup(now)
+        expired = self.cache.cleanup(now)
         self.expire_waiting(now)
         span.mark("pump")
         # ---- overload governor gate (sched/overload.py): mode ladder,
@@ -726,6 +727,10 @@ class Scheduler:
                     [p.key for p, _ in batch], self.clock()),
                 "confirm": confirm}
             wave.extra["assumed_outstanding"] = outstanding
+            # the size of this wave's expiry pass: assumed pods it looked
+            # at, and those it dropped (their echo never came in the TTL)
+            wave.extra["assumed_examined"] = self.cache.last_cleanup_examined
+            wave.extra["assumed_expired"] = len(expired)
             if self.events_pending is not None:
                 wave.extra["events_pending"] = self.events_pending()
         span.mark("pop")

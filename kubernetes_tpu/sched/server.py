@@ -1029,8 +1029,7 @@ class SchedulerServer:
             # depths() carries the deferred lane too — the governor's own
             # control signals become scrapeable gauges
             queue_lengths = self.scheduler.queue.depths()
-            cache_counts = (len(self.scheduler.cache.nodes()),
-                            len(self.scheduler.cache.scheduled_pods()))
+            cache_counts = self.scheduler.cache.counts()[:2]
         sched_metrics.observe_wave(stats, queue_lengths, cache_counts)
         self.total_scheduled += stats.scheduled
         if stats.unschedulable:

@@ -190,11 +190,14 @@ class SchedulerCache:
         # cache's own (time.perf_counter, the flight recorder's), since
         # add_pod is handed no `now` and finish_binding's `now` may be a
         # per-tick virtual clock. [count, sum_s, max_s] since the last
-        # drain, and the assumed pods not yet confirmed — a counter kept
-        # wherever an assumed state enters or leaves, not a walk.
+        # drain, and the assumed pods not yet confirmed — an index kept
+        # wherever an assumed state enters or leaves (key -> state, in
+        # assume order: `key in _assumed` iff `_pods[key].assumed`), so
+        # that expiry and the counts range over IT (cache.go's assumedPods)
+        # and never over the population.
         self.lag_clock = time.perf_counter
         self._confirm_waits: List[float] = [0, 0.0, 0.0]
-        self._assumed_outstanding = 0
+        self._assumed: Dict[str, _PodState] = {}
         self._generation = 0
         self._snapshot: Optional[Snapshot] = None
         # ---- incremental snapshot state (cache.go:204-255 analog) ----
@@ -223,6 +226,8 @@ class SchedulerCache:
         # introspection for tests/bench: how the last snapshot was produced
         self.last_snapshot_mode: str = ""   # "cached" | "patch" | "full"
         self.last_patch_rows: int = 0
+        # assumed pods the last cleanup() looked at (the wave's record)
+        self.last_cleanup_examined: int = 0
         # ---- mesh-resident accounting (ISSUE 3 donation contract) ----
         # full shard_tables uploads (cold / capacity growth / mesh reform)
         self.resident_full_uploads: int = 0
@@ -312,8 +317,8 @@ class SchedulerCache:
             if key in self._pods:
                 raise CacheError(f"pod {key} is already in the cache")
             p = replace(pod, node_name=node_name)
-            self._pods[key] = _PodState(pod=p, assumed=True)
-            self._assumed_outstanding += 1
+            self._pods[key] = self._assumed[key] = \
+                _PodState(pod=p, assumed=True)
             self._pod_placed(p)
             self._generation += 1
 
@@ -338,7 +343,7 @@ class SchedulerCache:
             if not st.assumed:
                 raise CacheError(f"pod {key} is bound, cannot forget")
             del self._pods[key]
-            self._assumed_outstanding -= 1
+            del self._assumed[key]
             self._pod_gone(st.pod)
             self._generation += 1
 
@@ -353,7 +358,7 @@ class SchedulerCache:
                 # (cache.go:404-410 logs and corrects)
                 self._pod_unplaced(st.pod)
                 self._pods[key] = _PodState(pod=pod)
-                self._assumed_outstanding -= 1
+                del self._assumed[key]
                 if st.bound_at is not None:
                     lag = self.lag_clock() - st.bound_at
                     w = self._confirm_waits
@@ -388,7 +393,7 @@ class SchedulerCache:
                 raise CacheError(f"pod {key} is not in the cache")
             del self._pods[key]
             if st.assumed:
-                self._assumed_outstanding -= 1
+                del self._assumed[key]
             self._pod_gone(st.pod)
             self._generation += 1
 
@@ -405,17 +410,8 @@ class SchedulerCache:
         the apiserver rejected. Returns the forgotten Pod objects (their
         node_name still carries the assumed placement) so the caller can
         requeue them even when no other record of them survives."""
-        dropped: List[Pod] = []
         with self._mu:
-            for key, st in list(self._pods.items()):
-                if st.assumed:
-                    del self._pods[key]
-                    self._pod_gone(st.pod)
-                    dropped.append(st.pod)
-            if dropped:
-                self._assumed_outstanding -= len(dropped)
-                self._generation += 1
-        return dropped
+            return [st.pod for st in self._drop_assumed(lambda st: True)]
 
     def drain_confirm_waits(self) -> Tuple[List[float], int]:
         """`([count, sum_s, max_s], assumed_outstanding)`: the lag from
@@ -426,7 +422,7 @@ class SchedulerCache:
             (n, total, worst), self._confirm_waits = \
                 self._confirm_waits, [0, 0.0, 0.0]
             return ([n, round(total, 6), round(worst, 6)],
-                    self._assumed_outstanding)
+                    len(self._assumed))
 
     def pods_on_node(self, name: str) -> List[Pod]:
         """All pods (bound + assumed) occupying one node — the host-side
@@ -491,18 +487,27 @@ class SchedulerCache:
         """cleanupAssumedPods: drop assumed pods whose bind finished but whose
         confirming watch event never arrived within the TTL. Returns the
         expired keys (the reference logs a warning per pod, cache.go:657)."""
-        expired: List[str] = []
         with self._mu:
-            for key, st in list(self._pods.items()):
-                if st.assumed and st.binding_finished and st.deadline is not None \
-                        and now >= st.deadline:
-                    del self._pods[key]
-                    self._pod_gone(st.pod)
-                    expired.append(key)
-            if expired:
-                self._assumed_outstanding -= len(expired)
-                self._generation += 1
-        return expired
+            self.last_cleanup_examined = len(self._assumed)
+            return [st.pod.key for st in self._drop_assumed(
+                lambda st: st.binding_finished and st.deadline is not None
+                and now >= st.deadline)]
+
+    def _drop_assumed(self, gone) -> List[_PodState]:
+        """Take out of the cache the assumed pods of which `gone(state)`
+        holds, in assume order, and return their states (caller holds
+        `_mu`). The ONE place that finds assumed pods: it ranges over the
+        assumed index, which `_pods`' own order restricted to assumed pods
+        equals, since a pod enters both at `assume_pod`."""
+        dropped = [st for st in self._assumed.values() if gone(st)]
+        for st in dropped:
+            key = st.pod.key
+            del self._pods[key]
+            del self._assumed[key]
+            self._pod_gone(st.pod)
+        if dropped:
+            self._generation += 1
+        return dropped
 
     # ------------------------------------------------------------------ #
     # snapshot (cache.go:204-255)
@@ -526,8 +531,7 @@ class SchedulerCache:
         """(nodes, total pods, assumed pods) — the cache-size gauges
         (cache.go:692-696)."""
         with self._mu:
-            assumed = sum(1 for s in self._pods.values() if s.assumed)
-            return len(self._nodes), len(self._pods), assumed
+            return len(self._nodes), len(self._pods), len(self._assumed)
 
     def mark_dispatch_start(self) -> None:
         """A dispatch now holds the current snapshot's device arrays (the
@@ -660,8 +664,12 @@ class SchedulerCache:
                 # the freed slot (a later node may reuse it); re-row them
                 for key, p in self._by_node.get(name, {}).items():
                     self._dirty_pods.setdefault(key, p)
-            for name in self._nodes:
-                if name in self._dirty_nodes and name not in self._node_slot:
+            dirty_live: List[Node] = []  # in _nodes' order, for dims below
+            for name, node in self._nodes.items():
+                if name not in self._dirty_nodes:
+                    continue
+                dirty_live.append(node)
+                if name not in self._node_slot:
                     if self._free_node_slots:
                         slot = self._free_node_slots.pop()
                         self._node_names[slot] = name
@@ -681,9 +689,22 @@ class SchedulerCache:
                            if p is not None and k not in self._pod_slot)
             n_pod_slots = len(self._pod_keys) + max(new_pods - pod_frees, 0)
 
+            # the nodes whose topology domains can be new to the encoder:
+            # on the patch path's own terms (resident staging under this
+            # encoder, projection as it was, no topology key since the last
+            # snapshot) only the dirty ones, since every other node was
+            # registered under this key count and register_node_domains'
+            # memo would answer for it; every node otherwise
+            if (self._staging_nodes is not None
+                    and self._encoder is encoder
+                    and not projection_widened
+                    and len(encoder.vocabs.topo_keys) == self._n_topo_keys):
+                domain_nodes = dirty_live
+            else:
+                domain_nodes = list(self._nodes.values())
             d = encoder.dims(
                 len(self._node_names), n_pod_slots, len(pending),
-                list(self._nodes.values()),
+                domain_nodes,
                 # capacities are monotonic ACROSS cycles: seed from the live
                 # snapshot so a smaller pending batch doesn't shrink P and
                 # masquerade as a capacity change. The seed is the UNION of
@@ -1323,12 +1344,5 @@ class FakeCache(SchedulerCache):
 
     def expire_all_assumed(self) -> List[str]:
         with self._mu:
-            expired = [k for k, s in self._pods.items()
-                       if s.assumed and s.binding_finished]
-            for k in expired:
-                st = self._pods.pop(k)
-                self._pod_gone(st.pod)
-            if expired:
-                self._assumed_outstanding -= len(expired)
-                self._generation += 1
-        return expired
+            return [st.pod.key for st in self._drop_assumed(
+                lambda st: st.binding_finished)]

@@ -98,6 +98,220 @@ class TestSchedulerCache:
         assert s1 is not s2
 
 
+class _WalkingCache:
+    """The plain reference of the cache's assume bookkeeping: one dict of
+    pod states, and every question (which assumed pods expired, how many
+    are assumed) answered by a walk over ALL of it, as the cache did before
+    it kept the assumed pods in an index of their own. Keys only: what a
+    pod looks like is not the bookkeeping's business."""
+
+    def __init__(self, ttl, n_nodes):
+        self.ttl, self.n_nodes = ttl, n_nodes
+        self.pods = {}   # key -> {"assumed", "finished", "deadline"}
+
+    def assume_pod(self, key):
+        if key in self.pods:
+            raise CacheError(key)
+        self.pods[key] = {"assumed": True, "finished": False,
+                          "deadline": None}
+
+    def finish_binding(self, key, now):
+        st = self.pods.get(key)
+        if st is not None and st["assumed"]:
+            st["finished"], st["deadline"] = True, now + self.ttl
+
+    def forget_pod(self, key):
+        st = self.pods.get(key)
+        if st is None:
+            return
+        if not st["assumed"]:
+            raise CacheError(key)
+        del self.pods[key]
+
+    def add_pod(self, key):
+        st = self.pods.get(key)
+        if st is not None and not st["assumed"]:
+            raise CacheError(key)
+        self.pods[key] = {"assumed": False, "finished": False,
+                          "deadline": None}
+
+    def update_pod(self, key):
+        st = self.pods.get(key)
+        if st is None or st["assumed"]:
+            raise CacheError(key)
+
+    def remove_pod(self, key):
+        if key not in self.pods:
+            raise CacheError(key)
+        del self.pods[key]
+
+    def _drop(self, gone):
+        keys = [k for k, st in list(self.pods.items())
+                if st["assumed"] and gone(st)]
+        for k in keys:
+            del self.pods[k]
+        return keys
+
+    def forget_assumed(self):
+        return self._drop(lambda st: True)
+
+    def cleanup(self, now):
+        return self._drop(lambda st: st["finished"]
+                          and st["deadline"] is not None
+                          and now >= st["deadline"])
+
+    def is_assumed(self, key):
+        st = self.pods.get(key)
+        return bool(st and st["assumed"])
+
+    def assumed(self):
+        return sum(1 for st in self.pods.values() if st["assumed"])
+
+    def counts(self):
+        return self.n_nodes, len(self.pods), self.assumed()
+
+
+class _NoWalk(dict):
+    """A pod map that may be asked for a key and for its size, and not
+    walked."""
+
+    def _refuse(self, *a, **kw):
+        raise AssertionError("the cache walked its whole pod map")
+
+    items = values = keys = __iter__ = _refuse
+
+
+class TestAssumedIndex:
+    """The cache answers for its assumed pods from the index it keeps of
+    them (cache.go's assumedPods), never from a walk of every pod."""
+
+    OPS = {"assume_pod": 8, "finish_binding": 8, "forget_pod": 2,
+           "add_pod": 6, "update_pod": 2, "remove_pod": 2,
+           "forget_assumed": 0.3, "cleanup": 6}   # op: its weight in the draw
+
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3, 4, 5, 6, 7])
+    def test_random_lifecycles_agree_with_the_full_walk(self, seed):
+        import random
+
+        rng = random.Random(seed)
+        ttl, nodes = 30.0, ["n0", "n1", "n2"]
+        c = SchedulerCache(ttl=ttl)
+        for n in nodes:
+            c.add_node(Node(name=n))
+        ref = _WalkingCache(ttl, len(nodes))
+        keys = [f"default/p{i}" for i in range(24)]
+        now, expired_ever, forgotten_ever, assumed_most = 0.0, 0, 0, 0
+        for step in range(800):
+            op, = rng.choices(list(self.OPS), list(self.OPS.values()))
+            name = f"p{rng.randrange(len(keys))}"
+            key = f"default/{name}"
+            now += rng.choice((0.0, 0.5, 2.0, 8.0))
+            bound = pod(name)
+            bound.node_name = rng.choice(nodes)
+            call = {
+                "assume_pod": (lambda: c.assume_pod(pod(name),
+                                                    rng.choice(nodes)),
+                               lambda: ref.assume_pod(key)),
+                "finish_binding": (lambda: c.finish_binding(key, now),
+                                   lambda: ref.finish_binding(key, now)),
+                "forget_pod": (lambda: c.forget_pod(key),
+                               lambda: ref.forget_pod(key)),
+                "add_pod": (lambda: c.add_pod(bound),
+                            lambda: ref.add_pod(key)),
+                "update_pod": (lambda: c.update_pod(bound),
+                               lambda: ref.update_pod(key)),
+                "remove_pod": (lambda: c.remove_pod(key),
+                               lambda: ref.remove_pod(key)),
+                "forget_assumed": (
+                    lambda: [p.key for p in c.forget_assumed()],
+                    ref.forget_assumed),
+                "cleanup": (lambda: c.cleanup(now),
+                            lambda: ref.cleanup(now)),
+            }[op]
+            got = want = None
+            examined = ref.assumed()
+            assumed_most = max(assumed_most, examined)
+            try:
+                want = call[1]()
+            except CacheError:
+                with pytest.raises(CacheError):
+                    call[0]()
+            else:
+                got = call[0]()
+            where = f"seed {seed} step {step} {op} {key} now {now}"
+            if op in ("cleanup", "forget_assumed"):
+                assert got == want, where       # the keys, and their order
+            if op == "cleanup":
+                assert c.last_cleanup_examined == examined, where
+                expired_ever += len(want)
+            if op == "forget_assumed":
+                forgotten_ever += len(want)
+            assert c.counts() == ref.counts(), where
+            assert [c.is_assumed(k) for k in keys] \
+                == [ref.is_assumed(k) for k in keys], where
+            assert c.drain_confirm_waits()[1] == ref.assumed(), where
+            # the index is the assumed states of the pod map, in its order
+            assert list(c._assumed.items()) == [
+                (k, st) for k, st in c._pods.items() if st.assumed], where
+        # the sequence reached what it is here to compare
+        assert expired_ever >= 5 and forgotten_ever and assumed_most >= 5
+
+    def test_nothing_asked_every_wave_walks_the_pod_map(self):
+        from kubernetes_tpu.state.cache import FakeCache
+
+        c = FakeCache(ttl=30.0)
+        for i in range(6):
+            bound = pod(f"b{i}")
+            bound.node_name = "n1"
+            c.add_pod(bound)
+        for name in ("a0", "a1", "a2", "a3"):
+            c.assume_pod(pod(name), "n1")
+        c.finish_binding("default/a0", now=0.0)
+        c.finish_binding("default/a2", now=50.0)
+        c.finish_binding("default/a3", now=50.0)
+        c._pods = _NoWalk(c._pods)
+        with pytest.raises(AssertionError):
+            list(c._pods.items())               # the guard guards
+        assert c.counts() == (0, 10, 4)
+        assert c.cleanup(now=29.0) == []
+        assert c.cleanup(now=30.0) == ["default/a0"]
+        assert c.last_cleanup_examined == 4
+        assert c.counts() == (0, 9, 3)
+        assert c.drain_confirm_waits()[1] == 3
+        assert c.expire_all_assumed() == ["default/a2", "default/a3"]
+        assert [p.key for p in c.forget_assumed()] == ["default/a1"]
+        assert c.counts() == (0, 6, 0) and not c._assumed
+
+    def test_the_server_reads_its_gauges_from_the_counts(self):
+        from kubernetes_tpu.apiserver import APIServer
+        from kubernetes_tpu.client import Client
+        from kubernetes_tpu.models.workloads import make_nodes
+        from kubernetes_tpu.sched import metrics as sched_metrics
+        from kubernetes_tpu.sched.scheduler import RecordingBinder, Scheduler
+        from kubernetes_tpu.sched.server import SchedulerServer
+
+        s = Scheduler(binder=RecordingBinder(), batch_size=64)
+        for n in make_nodes(5):
+            s.on_node_add(n)
+        for i in range(7):
+            bound = pod(f"b{i}")
+            bound.node_name = "node-0"
+            s.cache.add_pod(bound)
+        for i in range(3):
+            s.on_pod_add(pod(f"p{i}", creation=i))
+
+        def listed(*a, **kw):
+            raise AssertionError("a wave copied the cache to count it")
+
+        s.cache.scheduled_pods = s.cache.nodes = listed
+        srv = SchedulerServer(Client.local(APIServer()), scheduler=s)
+        stats = srv.run_one_wave()
+        assert srv.last_wave_error is None and stats.scheduled == 3
+        assert sched_metrics.CACHE_SIZE.value(type="nodes") == 5
+        assert sched_metrics.CACHE_SIZE.value(type="pods") == 10
+        assert s.cache.counts() == (5, 10, 3)
+
+
 class TestPriorityQueue:
     def test_pop_order_priority_then_creation(self):
         q = PriorityQueue()

@@ -231,6 +231,82 @@ def test_new_topology_key_stays_on_patch_path(monkeypatch):
     assert got == oracle_names(cache, pending)
 
 
+def test_patch_path_registers_domains_of_dirty_nodes_only(monkeypatch):
+    """A wave's snapshot asks the encoder for the topology domains of the
+    nodes that can have changed, not of the fleet: on the patch path the
+    dirty nodes, and every node once a topology key is new (each node owns
+    a cell of the new [N, K] column, and its backfill must agree with a
+    from-scratch encode of the same state)."""
+    def racked(i, cpu="4"):
+        return Node(
+            name=f"n{i}",
+            labels={ZONE: f"z{i % 2}", HOSTNAME: f"n{i}",
+                    "example.com/rack": "rA" if i < 3 else "rB"},
+            allocatable=Resources.make(cpu=cpu, memory="16Gi", pods=110))
+
+    cache = SchedulerCache()
+    enc = Encoder()
+    for i in range(6):
+        cache.add_node(racked(i))
+    cache.add_pod(mkpod("g1a", app="g1", cpu="100m", node="n0", creation=0))
+    cache.add_pod(mkpod("g1b", app="g1", cpu="100m", node="n1", creation=1))
+    cache.add_pod(mkpod("biga", app="big", cpu="3", node="n3", creation=2))
+    cache.add_pod(mkpod("bigb", app="big", cpu="3", node="n4", creation=3))
+    cache.add_pod(mkpod("bigc", app="big", cpu="3", node="n5", creation=4))
+    schedule_names(cache, enc, [mkpod("w0", app="g0", spread=True,
+                                      creation=90)])
+    assert cache.last_snapshot_mode == "full"
+
+    asked = []
+    orig = Encoder.register_node_domains
+
+    def counting(self, n):
+        asked.append(n.name)
+        return orig(self, n)
+
+    monkeypatch.setattr(Encoder, "register_node_domains", counting)
+    # a pod lands on n2, n4 changes: two dirty nodes of six
+    cache.add_pod(mkpod("late", app="g0", node="n2", creation=5))
+    cache.update_node(racked(4, cpu="8"))
+    pending = [mkpod("p0", app="g0", spread=True, creation=100)]
+    got = schedule_names(cache, enc, pending)
+    assert cache.last_snapshot_mode == "patch"
+    assert set(asked) == {"n2", "n4"}, \
+        "a patch snapshot may ask only for its dirty nodes' domains"
+    assert got == oracle_names(cache, pending)
+    # nothing dirty: nothing asked
+    del asked[:]
+    schedule_names(cache, enc, [mkpod("p1", app="g0", spread=True,
+                                      creation=101)])
+    assert cache.last_snapshot_mode == "patch" and asked == []
+
+    # a topology key no pod named before: every node's domain under it
+    sel = LabelSelector.of(match_labels={"app": "g1"})
+    rack_spread = [Pod(
+        name="p-rack", labels={"app": "g1"},
+        requests=Resources.make(cpu="100m", memory="256Mi"),
+        topology_spread=(TopologySpreadConstraint(
+            max_skew=1, topology_key="example.com/rack",
+            when_unsatisfiable=UnsatisfiableAction.DO_NOT_SCHEDULE,
+            selector=sel),),
+        creation_index=102)]
+    got = schedule_names(cache, enc, rack_spread)
+    assert cache.last_snapshot_mode == "patch"
+    assert set(asked) == {f"n{i}" for i in range(6)}
+    # rA holds the two matching pods, rB none: only rB keeps the skew
+    assert got[0] in ("n3", "n4", "n5")
+    assert got == oracle_names(cache, rack_spread)
+    k = enc.vocabs.topo_keys.get("example.com/rack")
+    racks = cache._staging_nodes.domain[:6, k]
+    assert len(set(racks[:3])) == 1 and len(set(racks[3:])) == 1 \
+        and racks[0] != racks[3] and (racks >= 0).all()
+    # and the key count is the snapshot's again: back to the dirty nodes
+    del asked[:]
+    cache.update_node(racked(1, cpu="6"))
+    schedule_names(cache, enc, [mkpod("p2", app="g0", creation=103)])
+    assert cache.last_snapshot_mode == "patch" and set(asked) == {"n1"}
+
+
 def test_capacity_growth_falls_back_to_full():
     cache, enc = build_cache(n_nodes=12, n_bound=4)
     pending = [mkpod("p0", app="g0", creation=100)]

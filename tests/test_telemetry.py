@@ -205,6 +205,9 @@ _GC = ["gc_full_collections", "gc_pause_s", "gc_max_pause_s",
 _PASS = ["preempt_lanes", "preempt_preemptors", "preempt_dispatches",
          "preempt_nodes_handed_out", "preempt_nominated", "preempt_victims",
          "preempt_retry_soon", "preempt_nominate_s", "preempt_evict_s"]
+# the assumed set at the pop, and the size of the wave's expiry pass over
+# it (ISSUE 46): pods `cleanup` looked at, pods it dropped
+_ASSUMED = ["assumed_outstanding", "assumed_examined", "assumed_expired"]
 _WAVE_SHAPES = {
     # kind: (phases, children, keys)
     "bulk": (_BULK,
@@ -214,14 +217,13 @@ _WAVE_SHAPES = {
                          "requeue/snapshot/prepare"] + _FIRST_SNAPSHOT,
              _HEAD + ["bucket", "affinity_agg", "domain_sum", "stats",
                       "device_split",
-                      "children"] + _GC + ["snapshot_mode", "waits",
-                      "assumed_outstanding"] + _PASS + ["minor_faults",
-                                                        "seq"]),
+                      "children"] + _GC + ["snapshot_mode", "waits"]
+             + _ASSUMED + _PASS + ["minor_faults", "seq"]),
     "micro": (_BULK,
               _BINDING + _FIRST_SNAPSHOT + ["snapshot/upload"],
               _HEAD + ["micro", "bucket", "affinity_agg", "domain_sum",
                        "stats", "device_split", "children"] + _GC + [
-                           "snapshot_mode", "waits", "assumed_outstanding",
+                           "snapshot_mode", "waits"] + _ASSUMED + [
                            "minor_faults", "seq"]),
     "paused": (["pump", "paused"], None,
                _HEAD + ["stats", "supervisor_events"] + _GC + ["seq"]),
@@ -230,13 +232,13 @@ _WAVE_SHAPES = {
                   _FIRST_SNAPSHOT,
                   _HEAD + ["bucket", "affinity_agg", "domain_sum", "stats",
                            "supervisor_events", "children"] + _GC + [
-                               "waits", "assumed_outstanding", "seq"]),
+                               "waits"] + _ASSUMED + ["seq"]),
     "raises": (_BULK[:8] + ["exception"],
                _BINDING + _FIRST_SNAPSHOT,
                _HEAD + ["bucket", "affinity_agg", "domain_sum", "stats",
                         "device_split",
-                        "children"] + _GC + ["waits", "assumed_outstanding",
-                                             "exception", "seq"]),
+                        "children"] + _GC + ["waits"] + _ASSUMED + [
+                            "exception", "seq"]),
 }
 
 
@@ -926,6 +928,15 @@ class TestWaits:
         rec = s.telemetry.recorder.records()[-1]
         assert rec["waits"]["confirm"] == [1, 0.25, 0.25]
         assert rec["assumed_outstanding"] == 1          # p1 still unconfirmed
+        # the expiry pass looked at the one assumed pod and at no other
+        assert (first["assumed_examined"], first["assumed_expired"]) == (0, 0)
+        assert (rec["assumed_examined"], rec["assumed_expired"]) == (1, 0)
+        clk["t"] = 1e6                  # p1's and p2's echoes never came
+        s.on_pod_add(_pod(3))
+        s.schedule_pending()
+        late = s.telemetry.recorder.records()[-1]
+        assert (late["assumed_examined"], late["assumed_expired"]) == (2, 2)
+        assert late["assumed_outstanding"] == 0
 
 
 class _ScriptedStop:
