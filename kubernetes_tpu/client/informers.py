@@ -222,6 +222,9 @@ class SharedInformer:
         self._synced = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._watch: Optional[mwatch.Watch] = None
+        # when the watch event now being dispatched went into the stream's
+        # buffer (time.monotonic; None outside a dispatch): `delivery_lag`
+        self._dispatching_from: Optional[float] = None
         self.last_sync_rv = ""
         #: the last list+replace round (the initial list, a relist after a
         #: 410 or a deaf watch), None before the first has ended:
@@ -301,6 +304,22 @@ class SharedInformer:
 
     def wait_for_sync(self, timeout: float = 10.0) -> bool:
         return self._synced.wait(timeout)
+
+    def buffered(self) -> int:
+        """Watch events that have reached this informer's stream and wait
+        for its thread to deliver them (0 between streams)."""
+        depth = getattr(self._watch, "depth", None)
+        return depth() if depth is not None else 0
+
+    def delivery_lag(self) -> float:
+        """Seconds since the watch event now being dispatched reached this
+        informer's buffer, for a handler to ask on the informer's own
+        thread: one thread delivers the events in turn, so while a handler
+        waits (for a lock a scheduling wave holds) every later event waits
+        in the buffer behind it, unseen by its own handler. 0.0 outside a
+        watch dispatch (a list+replace round's synthesized adds)."""
+        since = self._dispatching_from
+        return 0.0 if since is None else max(time.monotonic() - since, 0.0)
 
     @property
     def has_synced(self) -> bool:
@@ -536,7 +555,11 @@ class SharedInformer:
                         # ingest-pressure signal is what this exercises; the
                         # at-least-once contract makes the redelivery safe.
                         return
-                    self._dispatch(ev)
+                    self._dispatching_from = getattr(w, "buffered_at", None)
+                    try:
+                        self._dispatch(ev)
+                    finally:
+                        self._dispatching_from = None
                     rv = meta.resource_version(ev.object)
                     if rv:
                         self.last_sync_rv = rv

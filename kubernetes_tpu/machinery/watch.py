@@ -16,12 +16,19 @@ A producer may buffer, in place of an `Event`, anything with an `event()`
 method that builds one (the store's `CachedEvent`): the consumer's side
 calls it as it takes the item, on the consumer's own thread, so what each
 stream receives is built once per reader and only when it reads.
+
+Every buffered item carries the instant (`time.monotonic()`) it went into
+the buffer; the consumer finds that of the event it took last in
+`buffered_at`: how long the event waited for its reader is the reader's to
+tell (a consumer that delivers in turn, as an informer does, holds every
+event behind the one whose handler waits).
 """
 
 from __future__ import annotations
 
 import queue
 import threading
+import time
 from dataclasses import dataclass
 from typing import Any, Dict, Iterator, Optional
 
@@ -51,6 +58,8 @@ class Watch:
         self._stopped = threading.Event()
         self._term_mu = threading.Lock()
         self._terminal: Optional[Event] = None
+        # when the event the consumer took last went into the buffer
+        self.buffered_at = 0.0
 
     def send(self, event: Event, timeout: Optional[float] = 5.0) -> bool:
         """Producer side. Returns False if the watcher is gone/slow: the
@@ -60,9 +69,9 @@ class Watch:
             return False
         try:
             if timeout is not None and timeout <= 0:
-                self._q.put_nowait(event)
+                self._q.put_nowait((event, time.monotonic()))
             else:
-                self._q.put(event, timeout=timeout)
+                self._q.put((event, time.monotonic()), timeout=timeout)
             return True
         except queue.Full:
             self.stop()
@@ -77,7 +86,7 @@ class Watch:
         if self._stopped.is_set():
             return False
         try:
-            self._q.put_nowait(event)
+            self._q.put_nowait((event, time.monotonic()))
             return True
         except queue.Full:
             return False
@@ -114,9 +123,9 @@ class Watch:
         dispatcher exports as `watch_buffer_depth`."""
         return self._q.qsize()
 
-    @staticmethod
-    def _taken(item: Any) -> Event:
-        return item if item.__class__ is Event else item.event()
+    def _taken(self, item: Any) -> Event:
+        event, self.buffered_at = item
+        return event if event.__class__ is Event else event.event()
 
     def __iter__(self) -> Iterator[Event]:
         while True:

@@ -79,6 +79,12 @@ class PriorityQueue:
         # heaps hold (key..., seq, entry); maps give O(1) membership
         self._active: List[Tuple[int, int, int, _Entry]] = []
         self._active_keys: Dict[str, _Entry] = {}
+        # since when the oldest entry now in activeQ has been there: a
+        # running minimum of `timestamp` over the entries pushed since
+        # activeQ was last empty (it starts over with the first push into
+        # an empty activeQ). An entry that leaves while others stay does
+        # not raise it, so it can only read early
+        self._active_since = 0.0
         self._backoff: List[Tuple[float, int, _Entry]] = []
         self._backoff_keys: Dict[str, _Entry] = {}
         self._unschedulable: Dict[str, _Entry] = {}
@@ -124,8 +130,12 @@ class PriorityQueue:
         return not pod.pod_group and not pod.node_name
 
     def _push_active(self, e: _Entry) -> None:
+        """`e.timestamp` is the instant it joins activeQ (a requeue's, a
+        flush's), or for a new pod the instant it reached the scheduler."""
         k = _active_key(e)
         heapq.heappush(self._active, (k[0], k[1], next(self._seq), e))
+        if not self._active_keys or e.timestamp < self._active_since:
+            self._active_since = e.timestamp
         self._active_keys[e.pod.key] = e
         self._cond.notify_all()
 
@@ -276,6 +286,17 @@ class PriorityQueue:
             oldest = (next(iter(self._micro.values())).timestamp
                       if self._micro else 0.0)
             return (len(self._micro), len(self._active_keys), oldest)
+
+    def active_stats(self) -> Tuple[int, float]:
+        """(activeQ depth, the instant since when its oldest entry has
+        waited there) — what the server loop's peek reads before a wave to
+        count the gathering wait from, O(1) like `micro_stats`: no walk of
+        the heap. The instant is the least `timestamp` pushed since activeQ
+        was last empty (0.0 while it is): a new pod's is when it reached
+        the scheduler, a requeued or flushed entry's when it came back."""
+        with self._mu:
+            depth = len(self._active_keys)
+            return (depth, self._active_since if depth else 0.0)
 
     def add_prompt_retry(self, pod: Pod, attempts: int,
                          now: float = 0.0) -> None:
@@ -453,6 +474,7 @@ class PriorityQueue:
                     )
                     self._backoff_keys[key] = e
                 else:
+                    e.timestamp = now
                     self._push_active(e)
             return n
 
@@ -469,11 +491,13 @@ class PriorityQueue:
                 if self._backoff_keys.get(e.pod.key) is not e:
                     continue
                 del self._backoff_keys[e.pod.key]
+                e.timestamp = now
                 self._push_active(e)
             # stale unschedulable → active (60s)
             for key, e in list(self._unschedulable.items()):
                 if now - e.timestamp >= UNSCHEDULABLE_FLUSH_INTERVAL:
                     del self._unschedulable[key]
+                    e.timestamp = now
                     self._push_active(e)
             # deferred safety flush: a wedged/removed governor must never
             # strand shed pods — deferred means deferred, not dropped
@@ -481,6 +505,7 @@ class PriorityQueue:
                 if now - e.timestamp >= DEFERRED_FLUSH_INTERVAL:
                     del self._deferred[key]
                     if key not in self._active_keys:
+                        e.timestamp = now
                         self._push_active(e)
 
     # ------------------------------------------------------------------ #

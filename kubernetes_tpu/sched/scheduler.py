@@ -412,14 +412,19 @@ class Scheduler:
         """responsibleForPod (eventhandlers.go:282)."""
         return pod.scheduler_name == self.scheduler_name
 
-    def on_pod_add(self, pod: Pod) -> None:
+    def on_pod_add(self, pod: Pod, arrived: Optional[float] = None) -> None:
+        """`arrived`: when the pod reached the caller, on this scheduler's
+        clock, where the caller read that before it took its lock (the
+        wait for a lock that a wave holds is part of the pod's wait); the
+        queue's stamp and the first-seen stamp. Now, if not given."""
         if pod.node_name:                       # assignedPod (:277)
             if self.cache.is_assumed(pod.key) or self.cache.get_pod(pod.key) is None:
                 self._confirm(pod)
             # a new pod landing may unblock anti-affinity waiters etc.
             self.queue.move_all_to_active(self.clock())
         elif self.responsible_for(pod):
-            self.queue.add(pod, now=self.clock())
+            self.queue.add(pod, now=self.clock() if arrived is None
+                           else arrived)
 
     def _confirm(self, pod: Pod) -> None:
         """`cache.add_pod` for a bound pod the informer delivers. Where it is
@@ -433,7 +438,8 @@ class Scheduler:
                 pod.volumes = assumed.volumes
         self.cache.add_pod(pod)
 
-    def on_pod_update(self, old: Pod, new: Pod) -> None:
+    def on_pod_update(self, old: Pod, new: Pod,
+                      arrived: Optional[float] = None) -> None:
         if new.node_name:
             if self.cache.get_pod(new.key) is not None and not self.cache.is_assumed(new.key):
                 self.cache.update_pod(new)
@@ -450,7 +456,8 @@ class Scheduler:
                 # spent, re-admitting it would only hand a later wave a pod
                 # that is already placed
                 return
-            self.queue.update(new, now=self.clock())
+            self.queue.update(new, now=self.clock() if arrived is None
+                              else arrived)
 
     def on_pod_delete(self, pod: Pod) -> None:
         if pod.node_name:

@@ -76,25 +76,41 @@ def decoded(convert, obj: Obj):
 
 
 class _HandlerLock:
-    """One informer handler's turn on `SchedulerServer._mu`, timed: the
-    seconds it waited for the lock (a wave holds it from pop to requeue)
-    and the seconds it held it, on the telemetry's clock."""
+    """One informer handler's turn on `SchedulerServer._mu`. While it waits
+    for the lock (a wave holds it from pop to requeue) its thread stands in
+    `waiting`, where the loop's peek sees it; as the last one through
+    leaves it sets `through`, for which the loop then waits. With a
+    telemetry (`tel`; None when it is off) the seconds it waited and the
+    seconds it held the lock are counted, on the telemetry's clock."""
 
-    __slots__ = ("mu", "tel", "t0", "t1")
+    __slots__ = ("mu", "waiting", "through", "tel", "t0", "t1")
 
-    def __init__(self, mu, tel) -> None:
+    def __init__(self, mu, waiting: set, through, tel) -> None:
         self.mu = mu
+        self.waiting = waiting
+        self.through = through
         self.tel = tel
 
     def __enter__(self) -> None:
-        self.t0 = self.tel.clock()
+        me = threading.get_ident()
+        tel = self.tel
+        self.waiting.add(me)
+        if tel is not None:
+            self.t0 = tel.clock()
         self.mu.acquire()
-        self.t1 = self.tel.clock()
+        self.waiting.discard(me)
+        if tel is not None:
+            self.t1 = tel.clock()
 
     def __exit__(self, *exc) -> None:
-        held = self.tel.clock() - self.t1
+        tel = self.tel
+        if tel is not None:
+            held = tel.clock() - self.t1
         self.mu.release()
-        self.tel.note_handler(self.t1 - self.t0, held)
+        if not self.waiting and not self.through.is_set():
+            self.through.set()
+        if tel is not None:
+            tel.note_handler(self.t1 - self.t0, held)
 
 
 class BindWindow:
@@ -417,13 +433,15 @@ def pod_schedulable_v1(obj: Obj) -> bool:
 
 
 def apply_pod_update_v1(scheduler: Scheduler, old: Obj, new: Obj,
-                        to_pod) -> None:
+                        to_pod, arrived: Optional[float] = None) -> None:
     """The informer pod-UPDATE transition (eventhandlers.go:335-441),
     against one Scheduler: a no-longer-schedulable pod either frees its
     node's resources (terminated on a node) or leaves the queue; a live
     one flows through on_pod_update. `to_pod` is the caller's v1→Pod
-    conversion (it owns creation_index stamping). Callers provide their
-    own locking. Shared by SchedulerServer and _TenantIngest."""
+    conversion (it owns creation_index stamping). `arrived` is when the
+    event reached the caller, on the scheduler's clock, if it read that
+    before taking its lock. Callers provide their own locking. Shared by
+    SchedulerServer and _TenantIngest."""
     if not pod_schedulable_v1(new):
         p = pod_from_v1(new)
         if p.node_name:
@@ -434,7 +452,7 @@ def apply_pod_update_v1(scheduler: Scheduler, old: Obj, new: Obj,
         else:
             scheduler.queue.delete(p.key)
         return
-    scheduler.on_pod_update(pod_from_v1(old), to_pod(new))
+    scheduler.on_pod_update(pod_from_v1(old), to_pod(new), arrived)
 
 
 class SchedulerServer:
@@ -570,6 +588,11 @@ class SchedulerServer:
         self._stop = threading.Event()
         self._threads = []
         self._mu = threading.Lock()  # serializes event handlers vs waves
+        # the handler threads that wait for `_mu` right now, and the signal
+        # that the last of them is through (_HandlerLock): `_mu` is not
+        # fair, so the loop lets them go first itself (`_gather`)
+        self._handlers_waiting: set = set()
+        self._handlers_through = threading.Event()
         self.pod_informer: Optional[SharedInformer] = None
         self.node_informer: Optional[SharedInformer] = None
         self.elector: Optional[LeaderElector] = None
@@ -651,22 +674,40 @@ class SchedulerServer:
     # -- event handlers (eventhandlers.go:335-441) --------------------------- #
 
     def _handling(self):
-        """`with` target of an informer handler: `_mu`, and with telemetry
-        on the handler's wait for it and hold of it are counted onto the
-        next wave's record (`loop.handlers`). The PDB handlers take no
-        lock and are not counted."""
+        """`with` target of an informer handler: its turn on `_mu`, seen
+        by the loop while it waits for it (`_gather`); with telemetry on
+        the wait and the hold are counted onto the next wave's record
+        (`loop.handlers`). The PDB handlers take no lock and are not
+        counted."""
         tel = self.scheduler.telemetry
-        return _HandlerLock(self._mu, tel) if tel.enabled else self._mu
+        return _HandlerLock(self._mu, self._handlers_waiting,
+                            self._handlers_through,
+                            tel if tel.enabled else None)
+
+    def _arrived(self) -> float:
+        """When the pod event now being handled reached the scheduler, on
+        the scheduler's clock: the handler's entry, read BEFORE the wait
+        for `_mu`, less what the event waited in the informer's buffer
+        (its one thread delivers in turn: while a handler waits out a
+        wave, the events behind it have arrived too). What the queue
+        stamps a new pod with, so its wait and the loop's gathering window
+        count from here and not from the end of the wave it waited out."""
+        now = self.scheduler.clock()
+        inf = self.pod_informer
+        return now - inf.delivery_lag() if inf is not None else now
 
     def _on_pod_add(self, obj: Obj) -> None:
         if not self._schedulable(obj):
             return
+        arrived = self._arrived()
         with self._handling():
-            self.scheduler.on_pod_add(decoded(self._to_pod, obj))
+            self.scheduler.on_pod_add(decoded(self._to_pod, obj), arrived)
 
     def _on_pod_update(self, old: Obj, new: Obj) -> None:
+        arrived = self._arrived()
         with self._handling():
-            apply_pod_update_v1(self.scheduler, old, new, self._to_pod)
+            apply_pod_update_v1(self.scheduler, old, new, self._to_pod,
+                                arrived)
 
     def _on_pod_delete(self, obj: Obj) -> None:
         with self._handling():
@@ -995,24 +1036,69 @@ class SchedulerServer:
                         # requeued by informer truth regardless)
                         self.last_recovery_error = e
                 lap("recover")
-            with self._mu:
-                lap("lock-wait")  # behind the informer handlers
-                pending = self.scheduler.queue.lengths()[0]
-            if pending and self.batch_window:
-                # coalesce STORMS into few large waves with the full
-                # window; a small pending set (a preemption retry burst, a
-                # gang trickling in over milliseconds) gets a SHORT wait —
-                # enough to gather co-created pods into one all-or-nothing
-                # wave, without the full window's latency tax on every
-                # tiny wave (the r5 preempt burst spent ~1 s just waiting)
-                w = self.batch_window if pending >= 32 \
-                    else min(0.05, self.batch_window)
-                self._stop.wait(w)  # let the batch fill
-                lap("batch-wait")
+            self._gather(lap)
             stats = self.run_one_wave()
             if stats is None or stats.attempted == 0:
                 self._stop.wait(self.cycle_interval)
                 lap("idle-wait")  # the active queue was empty
+
+    def _peek(self, lap) -> tuple:
+        """(activeQ depth, the instant its oldest entry has waited from,
+        whether pod events that have reached the scheduler are not on the
+        queue yet: a handler stands at `_mu`, or the pod informer's one
+        thread has events in its buffer behind it), read under `_mu`."""
+        with self._mu:
+            lap("lock-wait")  # behind the informer handlers
+            pending, oldest = self.scheduler.queue.active_stats()
+            inf = self.pod_informer
+            behind = bool(self._handlers_waiting) \
+                or (inf is not None and inf.buffered() > 0)
+            if behind:
+                self._handlers_through.clear()
+        return pending, oldest, behind
+
+    def _window(self, pending: int) -> float:
+        """How long pending pods are given to gather into one wave:
+        coalesce STORMS into few large waves with the full window; a small
+        pending set (a preemption retry burst, a gang trickling in over
+        milliseconds) gets a SHORT one — enough to gather co-created pods
+        into one all-or-nothing wave, without the full window's latency
+        tax on every tiny wave (the r5 preempt burst spent ~1 s just
+        waiting)."""
+        return self.batch_window if pending >= 32 \
+            else min(0.05, self.batch_window)
+
+    def _gather(self, lap) -> None:
+        """When the next wave starts. The window (`_window`) is counted
+        from the instant the OLDEST pod now in activeQ reached the
+        scheduler (a requeued one: came back), not from this peek: a pod
+        created during a wave has waited out the wave behind `_mu`, and so
+        have the pods created with it; the loop sleeps only what is left
+        of the window (`batch-wait`), and not at all where none is. Pod
+        events that reached the scheduler before the peek (`_peek`) belong
+        in the wave: `_mu` is not fair and the loop would win it back from
+        their handlers, so the loop lets those go first, for at most a
+        window from its first peek (`lock-wait`: the loop behind the
+        handlers). The age of the oldest pod and the sleep taken ride the
+        wave's record (`gather_age_s`, `gather_wait_s`)."""
+        sched = self.scheduler
+        pending, oldest, behind = self._peek(lap)
+        if behind and self.batch_window:
+            until = sched.clock() + self._window(pending)
+            while behind and not self._stop.is_set():
+                left = until - sched.clock()
+                if left <= 0.0:
+                    break
+                self._handlers_through.wait(left)
+                pending, oldest, behind = self._peek(lap)
+        age = wait = 0.0
+        if pending and self.batch_window:
+            age = max(sched.clock() - oldest, 0.0)
+            wait = max(self._window(pending) - age, 0.0)
+            if wait:
+                self._stop.wait(wait)  # let the batch fill
+                lap("batch-wait")
+        sched.telemetry.note_gather(age, wait)
 
     def run_one_wave(self):
         from kubernetes_tpu.sched import metrics as sched_metrics

@@ -497,6 +497,8 @@ class SchedulerTelemetry:
         self._loop_lap_t = self._loop_stage_t = 0.0
         self._handlers: List[float] = [0, 0.0, 0.0]
         self._synced: Dict[str, bool] = {}
+        # what the loop decided before the wave to come (note_gather)
+        self._gather: Optional[Tuple[float, float]] = None
         # the collector's account, and where this recorder's previous
         # record (and the loop account's previous stage) ended on it; None
         # with telemetry off: no hook
@@ -655,6 +657,15 @@ class SchedulerTelemetry:
             h[1] += wait_s
             h[2] += held_s
 
+    def note_gather(self, age_s: float, wait_s: float) -> None:
+        """The server loop's decision before a wave (its one thread): how
+        long the oldest pod in the active queue had been at the scheduler
+        when the loop looked, and what the loop then slept for pods to
+        gather (0.0: none). On the next record of a wave that attempted
+        pods, as `gather_age_s` and `gather_wait_s`."""
+        if self.enabled:
+            self._gather = (age_s, wait_s)
+
     def note_device_split(self, launch: float, execute: float,
                           readback: float, token: object = None) -> None:
         """Tier 3 readings from the dispatch worker: XLA launch vs device
@@ -689,10 +700,12 @@ class SchedulerTelemetry:
         for phase, dt in phases:
             SCHEDULING_DURATION.observe(dt, operation=phase)
         t_end = self.clock()
-        loop_rec = None
+        loop_rec = gather = None
         with self._mu:
-            if self._loop is not None and stats is not None \
-                    and stats.attempted:
+            attempted = stats is not None and stats.attempted
+            if attempted:
+                gather, self._gather = self._gather, None
+            if self._loop is not None and attempted:
                 # what the server loop did since the last wave like this
                 # one; its account starts over where this wave ends
                 calls, wait_s, held_s = self._handlers
@@ -765,6 +778,9 @@ class SchedulerTelemetry:
             rec["children"] = children
         if loop_rec is not None:
             rec["loop"] = loop_rec
+        if gather is not None:
+            rec["gather_age_s"] = round(gather[0], 6)
+            rec["gather_wait_s"] = round(gather[1], 6)
         if fleet is not None:
             rec["fleet"] = fleet
         rec.update(gc_fields)

@@ -404,6 +404,95 @@ class TestPriorityQueue:
         assert len(q.pop_batch(10)) == 1
 
 
+class _NoHeapWalk(list):
+    """An activeQ heap that may be pushed to and popped from (heapq works
+    on it through the list's C slots), and not walked from Python."""
+
+    def _refuse(self, *a, **kw):
+        raise AssertionError("the queue walked its heap")
+
+    __iter__ = __reversed__ = _refuse
+
+
+class TestOldestActiveInstant:
+    """`active_stats()`: since when the oldest entry now in activeQ has
+    waited there, for the server loop's gathering window (ISSUE 47); a
+    running minimum that starts over when activeQ empties, read in O(1)."""
+
+    def _q(self):
+        q = PriorityQueue()
+        q._active = _NoHeapWalk()
+        return q
+
+    def test_add_keeps_the_least_and_an_emptied_queue_starts_over(self):
+        q = self._q()
+        assert q.active_stats() == (0, 0.0)
+        q.add(pod("a"), now=10.0)
+        q.add(pod("b"), now=9.5)      # its handler entered earlier
+        q.add(pod("c"), now=11.0)
+        assert q.active_stats() == (3, 9.5)
+        assert len(q.pop_batch(10, now=12.0)) == 3
+        assert q.active_stats() == (0, 0.0)
+        q.add(pod("d"), now=12.5)
+        assert q.active_stats() == (1, 12.5)
+
+    def test_a_partial_pop_never_reads_late(self):
+        q = self._q()
+        q.add(pod("a", priority=5), now=1.0)
+        q.add(pod("b"), now=2.0)
+        assert [p.name for p, _ in q.pop_batch(1, now=3.0)] == ["a"]
+        depth, since = q.active_stats()
+        assert depth == 1 and since <= 2.0    # a bound from below
+
+    def test_delete_of_the_last_entry_starts_over(self):
+        q = self._q()
+        q.add(pod("a"), now=1.0)
+        q.add(pod("b"), now=2.0)
+        q.delete("default/a")
+        assert q.active_stats()[0] == 1 and q.active_stats()[1] <= 2.0
+        q.delete("default/b")
+        assert q.active_stats() == (0, 0.0)
+        q.add(pod("c"), now=7.0)
+        assert q.active_stats() == (1, 7.0)
+
+    def test_update_counts_from_the_update(self):
+        q = self._q()
+        q.add(pod("a"), now=1.0)
+        q.update(pod("a"), now=4.0)           # re-admitted alone: from 4.0
+        assert q.active_stats() == (1, 4.0)
+        q.add(pod("b"), now=3.0)
+        assert q.active_stats() == (2, 3.0)
+
+    def test_requeues_and_flushes_count_from_when_they_come_back(self):
+        q = self._q()
+        q.add(pod("a"), now=0.0)
+        q.add(pod("b"), now=0.0)
+        (a, n_a), (b, n_b) = q.pop_batch(2, now=0.0)
+        q.add_prompt_retry(a, n_a, now=5.0)   # a preemptor with a node
+        assert q.active_stats() == (1, 5.0)
+        q.pop_batch(1, now=5.0)
+        # b failed at 0.0; an event moves it to backoff, the pump's flush
+        # brings it back at 1.5: it counts from 1.5, not from its failure
+        q.add_unschedulable(b, n_b, now=0.0)
+        q.move_all_to_active(now=0.5)
+        assert q.lengths() == (0, 1, 0) and q.active_stats() == (0, 0.0)
+        q.pump(now=1.5)
+        assert q.active_stats() == (1, 1.5)
+        q.pop_batch(1, now=1.5)
+        # past its backoff when the event comes: straight back, from then
+        q.add_unschedulable(b, 1, now=2.0)
+        q.move_all_to_active(now=9.0)
+        assert q.active_stats() == (1, 9.0)
+
+    def test_the_micro_lane_and_recovery_keep_it_too(self):
+        q = self._q()
+        q.add(pod("a"), now=1.0)
+        assert len(q.pop_micro(4, now=2.0)) == 1
+        assert q.active_stats() == (0, 0.0)
+        assert q.requeue_recovered(pod("r"), now=6.0) == "active"
+        assert q.active_stats() == (1, 6.0)
+
+
 class TestStormBackoffBoundaries:
     """ISSUE 9 satellite: backoff boundaries under storm requeues — the
     clamp must hold (not crash) at attempt counts a storm accumulates,

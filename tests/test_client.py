@@ -128,6 +128,41 @@ class TestInformer:
         assert inf.lister.get("default", "pre") is None
         inf.stop()
 
+    def test_events_behind_a_waiting_handler_say_how_long_they_waited(
+            self, api):
+        """One thread delivers in turn (ISSUE 47): while a handler waits,
+        the later events sit in the stream's buffer (`buffered`), and each
+        then tells its handler how long ago it reached the informer
+        (`delivery_lag`), which is nothing outside a watch dispatch."""
+        client = Client.local(api)
+        client.pods.create(mkpod("pre"))
+        gate, lags = threading.Event(), {}
+
+        def on_add(o):
+            name = o["metadata"]["name"]
+            lags[name] = inf.delivery_lag()
+            if name == "first":
+                gate.wait(10)       # as behind a lock a wave holds
+
+        inf = SharedInformer(client.pods)
+        inf.add_handlers(on_add=on_add)
+        inf.start()
+        assert inf.wait_for_sync()
+        assert lags == {"pre": 0.0} and inf.buffered() == 0
+        client.pods.create(mkpod("first"))
+        client.pods.create(mkpod("second"))
+        deadline = time.monotonic() + 10
+        while inf.buffered() < 1 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert inf.buffered() == 1 and "second" not in lags
+        time.sleep(0.2)
+        gate.set()
+        while "second" not in lags and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert lags["second"] >= 0.2 > lags["first"]
+        assert inf.delivery_lag() == 0.0
+        inf.stop()
+
     def test_relist_after_stream_end(self, api):
         client = Client.local(api)
         inf = SharedInformer(client.pods, relist_backoff=0.1)

@@ -325,6 +325,60 @@ class TestCoschedulingPermitPlugin:
         assert not cos._reserved.get("default/jobQ")
 
 
+def test_gang_that_arrives_during_a_wave_binds_in_one_wave_after_it():
+    """ISSUE 47: four members reach the scheduler over 6 ms while a wave
+    holds the server's lock. The first stands at the lock from its entry,
+    the others wait in the informer's buffer behind it; all are stamped
+    with when they came. The loop then gives them what is left of the
+    0.05 s window from the FIRST member's arrival, no new 0.05 s from its
+    own peek, and ONE wave binds the gang whole."""
+    import threading
+    import types
+
+    from kubernetes_tpu.apiserver import APIServer
+    from kubernetes_tpu.client import Client
+    from kubernetes_tpu.sched.scheduler import RecordingBinder, Scheduler
+    from kubernetes_tpu.sched.server import SchedulerServer
+    from test_telemetry import _HeldByAWave
+
+    clk = {"t": 100.0}
+    binder = RecordingBinder()
+    s = Scheduler(binder=binder, clock=lambda: clk["t"])
+    s.telemetry.clock = lambda: clk["t"]
+    for n in mknodes(4):
+        s.on_node_add(n)
+    srv = SchedulerServer(Client.local(APIServer()), scheduler=s,
+                          cycle_interval=0.02, batch_window=0.15)
+    came = [100.005, 100.007, 100.009, 100.011]   # the wave ends at 100.045
+    now_handling = [0]
+    srv.pod_informer = types.SimpleNamespace(
+        buffered=lambda: 0, relists=0,
+        delivery_lag=lambda: clk["t"] - came[now_handling[0]])
+
+    srv._mu = _HeldByAWave(clk, free_at=100.045)
+    clk["t"] = came[0]
+    for i, p in enumerate(gang_pods("m", 4, "jobM", 4)):
+        now_handling[0] = i
+        srv._on_pod_add(pod_to_v1(p))
+        clk["t"] += 0.0002              # a handler's turn
+    srv._mu = threading.Lock()
+    assert s.queue.active_stats() == (4, pytest.approx(100.005))
+    naps = []
+
+    class _Stop:
+        def wait(self, timeout):
+            naps.append(timeout)
+            clk["t"] += timeout
+
+    srv._stop = _Stop()
+    srv._gather(s.telemetry.loop_lap)
+    stats = srv.run_one_wave()
+    assert stats is not None, srv.last_wave_error
+    assert stats.attempted == 4 and stats.scheduled == 4   # ONE wave, whole
+    assert naps == [pytest.approx(0.05 - (100.0458 - 100.005))]
+    assert {k for k, _ in binder.bound} == {f"default/m{i}" for i in range(4)}
+
+
 def test_group_ids_compact_on_full_snapshot():
     """Finished gang jobs must not grow GR forever: a full re-encode
     compacts dead group ids (the gang analog of domain-map compaction), so

@@ -184,6 +184,23 @@ class TestWatch:
         assert w.next(timeout=0.1) is None
         assert not w.send(watch.Event(watch.ADDED, {}))  # post-stop send refused
 
+    def test_an_event_taken_says_when_it_was_buffered(self, monkeypatch):
+        """`buffered_at` (ISSUE 47): the consumer finds when the event it
+        took last went into the buffer, whichever way it went in."""
+        import time as _time
+
+        now = [50.0]
+        monkeypatch.setattr(_time, "monotonic", lambda: now[0])
+        w = watch.Watch()
+        w.send(watch.Event(watch.ADDED, {"n": 1}))
+        now[0] = 50.25
+        assert w.offer(watch.Event(watch.ADDED, {"n": 2}))
+        now[0] = 51.0
+        assert w.next(timeout=1).object == {"n": 1}
+        assert w.buffered_at == 50.0
+        assert next(iter(w)).object == {"n": 2}
+        assert w.buffered_at == 50.25
+
     def test_slow_watcher_terminated(self):
         w = watch.Watch(capacity=2)
         assert w.send(watch.Event(watch.ADDED, {"n": 1}))

@@ -987,9 +987,10 @@ class TestServerLoopSpans:
             return lambda: [s.on_pod_add(_pod(i)) for i in range(lo, hi)]
 
         nothing = lambda: None
-        # batch-wait of wave 1, three empty polls, pods for wave 2 arrive
+        # wave 1 starts at once (its pods were listed 1.5 s before the
+        # peek: no batch-wait); three empty polls, pods for wave 2 arrive
         # on the fourth, its (short: < 32 pending) batch-wait, two polls
-        srv._stop = _ScriptedStop(clk, [nothing, nothing, nothing, nothing,
+        srv._stop = _ScriptedStop(clk, [nothing, nothing, nothing,
                                         add(40, 45), nothing, nothing,
                                         nothing])
         for i in range(40):
@@ -1003,7 +1004,8 @@ class TestServerLoopSpans:
         # wave 1: everything since the server started
         assert w1["loop"]["t_start"] == 50.0
         assert dict(map(tuple, w1["loop"]["phases"])) == pytest.approx(
-            {"start": 1.5, "lock-wait": 0.0, "batch-wait": 0.15})
+            {"start": 1.5, "lock-wait": 0.0})
+        assert (w1["gather_age_s"], w1["gather_wait_s"]) == (1.5, 0.0)
         assert sum(d for _, d in w1["loop"]["phases"]) == pytest.approx(
             w1["t_start"] - 50.0)
         # wave k+1: the gap since wave k ended, every second of it named
@@ -1015,6 +1017,7 @@ class TestServerLoopSpans:
         assert phases == pytest.approx(
             {"post-wave": 0.0, "lock-wait": 0.0, "idle-wait": 0.08,
              "batch-wait": 0.05})
+        assert (w2["gather_age_s"], w2["gather_wait_s"]) == (0.0, 0.05)
         # the wave's own span is untouched by the loop's account
         assert w1["duration_s"] == pytest.approx(40 * 0.002)
 
@@ -1103,6 +1106,9 @@ class TestServerLoopSpans:
             def stop(self):
                 pass
 
+            def buffered(self):
+                return 0
+
             def wait_for_sync(self, timeout=10.0):
                 cost, synced, children = script[self.rc.resource]
                 clk["t"] += cost
@@ -1128,8 +1134,9 @@ class TestServerLoopSpans:
                   if r["stats"]["attempted"])
         loop = w1["loop"]
         phases = dict(map(tuple, loop["phases"]))
-        # the top level is what it was: laps, contiguous from t_start
-        assert set(phases) == {"start", "lock-wait", "batch-wait"}
+        # the top level is what it was: laps, contiguous from t_start (no
+        # batch-wait: the pods were there before the start's 1.75 s)
+        assert set(phases) == {"start", "lock-wait"}
         assert sum(phases.values()) == pytest.approx(w1["t_start"] - 50.0)
         stages = {p: v[1] for p, v in loop["children"].items()
                   if p.count("/") == 1}
@@ -1293,6 +1300,312 @@ def _event_server(clk, write_s=0.0):
 def _nofit(i):
     return Pod(name=f"nofit{i}", creation_index=i,
                requests=Resources.make(cpu="4096", memory="8Mi"))
+
+
+class _HeldByAWave:
+    """Stands in for SchedulerServer._mu while a wave holds it until the
+    injected clock reads `free_at`: a handler that asks for it earlier
+    waits, which on this clock is a jump to `free_at`."""
+
+    def __init__(self, clk, free_at):
+        self.clk, self.free_at = clk, free_at
+        self._lock = threading.Lock()
+
+    def acquire(self, *a):
+        self.clk["t"] = max(self.clk["t"], self.free_at)
+        return self._lock.acquire(*a)
+
+    def release(self):
+        self._lock.release()
+
+    __enter__ = acquire
+
+    def __exit__(self, *exc):
+        self.release()
+
+
+class _LoopFirst:
+    """Stands in for SchedulerServer._mu as an unfair lock at its worst:
+    a handler thread gets it only once the loop's thread (the one that
+    made this) has had it and let it go."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._loop = threading.get_ident()
+        self._gate = threading.Event()
+
+    def acquire(self, *a):
+        if threading.get_ident() != self._loop:
+            self._gate.wait(30)
+        return self._lock.acquire(*a)
+
+    def release(self):
+        self._lock.release()
+        if threading.get_ident() == self._loop:
+            self._gate.set()
+
+    __enter__ = acquire
+
+    def __exit__(self, *exc):
+        self.release()
+
+
+def _no_wait(timeout=None):
+    raise AssertionError(f"the loop slept {timeout}")
+
+
+class TestGatheringWait:
+    """ISSUE 47: the window pods are given to gather into a wave counts
+    from when the oldest pod in the active queue reached the scheduler
+    (its handler's entry, before the wait for `_mu`), not from the loop's
+    peek (sched/server.py `_gather`)."""
+
+    def _beat(self, srv, s):
+        """One turn of the loop: decide when the wave starts, run it;
+        the record of the wave."""
+        srv._gather(s.telemetry.loop_lap)
+        assert srv.run_one_wave() is not None, srv.last_wave_error
+        return s.telemetry.recorder.records()[-1]
+
+    @pytest.mark.parametrize("waited,slept", [
+        (0.0, 0.05), (0.02, 0.03), (0.05, 0.0), (0.06, 0.0), (1.5, 0.0)])
+    def test_the_window_counts_from_the_handlers_entry(self, waited, slept):
+        """A pod whose handler entered `waited` s before the peek (it
+        waited out a wave behind `_mu`) is held for what is left of the
+        0.05 s window, and not at all once the window has passed: no
+        sleep, no `batch-wait` lap; the record says which."""
+        from kubernetes_tpu.api.v1 import pod_to_v1
+
+        clk = {"t": 0.0}
+        srv, s = _loop_server(clk)
+        s.telemetry.loop_reset()
+        srv._mu = _HeldByAWave(clk, free_at=waited)
+        srv._on_pod_add(pod_to_v1(_pod(0)))   # enters at 0.0, waits
+        assert clk["t"] == waited
+        srv._mu = threading.Lock()
+        naps = []
+        srv._stop = _ScriptedStop(clk, [lambda: naps.append(clk["t"])])
+        if not slept:
+            srv._stop.wait = _no_wait
+        rec = self._beat(srv, s)
+        assert rec["stats"]["scheduled"] == 1
+        assert rec["gather_age_s"] == pytest.approx(waited)
+        assert rec["gather_wait_s"] == pytest.approx(slept)
+        phases = dict(map(tuple, rec["loop"]["phases"]))
+        assert phases.get("batch-wait", 0.0) == pytest.approx(slept)
+        assert ("batch-wait" in phases) == bool(slept)
+        assert naps == ([pytest.approx(0.05)] if slept else [])
+
+    def test_the_first_pod_at_an_idle_loop_is_held_a_window(self):
+        """...and a pod created within the window of it rides its wave;
+        one created after the wave's pop waits for the next."""
+        clk = {"t": 100.0}
+        srv, s = _loop_server(clk)
+        s.telemetry.loop_reset()
+        s.on_pod_add(_pod(0))
+        # 0.03 s into the 0.05 s the loop sleeps, a second pod arrives
+        srv._stop = _ScriptedStop(clk, [
+            lambda: s.on_pod_add(_pod(1), arrived=clk["t"] - 0.02)])
+        rec = self._beat(srv, s)
+        assert rec["stats"]["scheduled"] == 2
+        assert (rec["gather_age_s"], rec["gather_wait_s"]) == (0.0, 0.05)
+        s.on_pod_add(_pod(2))
+        srv._stop = _ScriptedStop(clk, [lambda: None])
+        again = self._beat(srv, s)
+        assert again["stats"]["scheduled"] == 1
+        assert again["gather_wait_s"] == pytest.approx(0.05)
+
+    def test_storms_keep_the_full_window_from_their_oldest_pod(self):
+        """32 or more pending: `batch_window` (0.15 s), counted the same
+        way."""
+        clk = {"t": 100.0}
+        srv, s = _loop_server(clk)
+        s.telemetry.loop_reset()
+        for i in range(40):
+            s.on_pod_add(_pod(i), arrived=99.9 + i * 0.001)
+        srv._stop = _ScriptedStop(clk, [lambda: None])
+        rec = self._beat(srv, s)
+        assert rec["stats"]["scheduled"] == 40
+        assert rec["gather_age_s"] == pytest.approx(0.1)
+        assert rec["gather_wait_s"] == pytest.approx(0.05)
+
+    def test_a_requeued_pod_counts_from_its_requeue(self):
+        """`add_prompt_retry` (a preemptor whose victims were just
+        evicted): the wait before the wave of requeued pods stays what it
+        was, whatever the pod's first-seen stamp says."""
+        clk = {"t": 100.0}
+        srv, s = _loop_server(clk)
+        s.telemetry.loop_reset()
+        s.on_pod_add(_pod(0))
+        (pod, attempts), = s.queue.pop_batch(8, now=100.0)
+        clk["t"] = 100.5
+        s.queue.add_prompt_retry(pod, attempts, now=clk["t"])
+        clk["t"] = 100.51
+        srv._stop = _ScriptedStop(clk, [lambda: None])
+        rec = self._beat(srv, s)
+        assert rec["stats"]["scheduled"] == 1
+        assert rec["gather_age_s"] == pytest.approx(0.01)
+        assert rec["gather_wait_s"] == pytest.approx(0.04)
+        # the pod's own wait still counts from when it was first seen
+        assert rec["waits"]["queue"] == [1, pytest.approx(0.55),
+                                         pytest.approx(0.55)]
+
+    @pytest.mark.parametrize("telemetry", ["1", "0"])
+    def test_first_seen_is_the_handlers_entry(self, monkeypatch, telemetry):
+        """The queue entry's `timestamp` and the latency tracker's stamp
+        are the instant the handler was entered, not the instant it got
+        `_mu`; with telemetry off the handler is not timed and the entry
+        is stamped the same."""
+        from kubernetes_tpu.api.v1 import pod_to_v1
+
+        monkeypatch.setenv("KTPU_TELEMETRY", telemetry)
+        clk = {"t": 100.0}
+        srv, s = _loop_server(clk)
+        assert s.telemetry.enabled == (telemetry == "1")
+        srv._mu = _HeldByAWave(clk, free_at=100.045)
+        srv._on_pod_add(pod_to_v1(_pod(0)))
+        assert clk["t"] == 100.045
+        key = _pod(0).key
+        assert s.queue._active_keys[key].timestamp == 100.0
+        assert s.queue.active_stats() == (1, 100.0)
+        if s.telemetry.enabled:
+            assert s.telemetry.tracker.first_seen(key) == 100.0
+        else:
+            assert s.queue.tracker is None
+        # an update that admits the pod again: the same rule
+        clk["t"] = 101.0
+        srv._mu = _HeldByAWave(clk, free_at=101.03)
+        obj = pod_to_v1(_pod(0))
+        srv._on_pod_update(obj, obj)
+        assert s.queue._active_keys[key].timestamp == 101.0
+        assert not srv._handlers_waiting
+
+    def test_arrival_is_less_the_events_wait_in_the_informers_buffer(self):
+        """One informer thread delivers the pod events in turn: an event
+        behind the one whose handler waits out a wave reaches its own
+        handler only after it. What the informer says the event waited in
+        its buffer comes off the handler's entry."""
+        import types
+
+        from kubernetes_tpu.api.v1 import pod_to_v1
+
+        clk = {"t": 100.0}
+        srv, s = _loop_server(clk)
+        srv.pod_informer = types.SimpleNamespace(
+            delivery_lag=lambda: 0.04, buffered=lambda: 0, relists=0)
+        srv._on_pod_add(pod_to_v1(_pod(0)))
+        assert s.queue.active_stats() == (1, pytest.approx(99.96))
+        assert s.telemetry.tracker.first_seen(_pod(0).key) == \
+            pytest.approx(99.96)
+
+    def test_a_handler_at_the_lock_goes_before_the_pop(self):
+        """`_mu` is not fair: after a wave the loop can win it back from a
+        handler that has stood at it since before the wave ended. The loop
+        sees the handler and lets it through before it decides."""
+        from kubernetes_tpu.api.v1 import pod_to_v1
+
+        clk = {"t": 100.0}
+        srv, s = _loop_server(clk)
+        s.telemetry.loop_reset()
+        srv._mu = _LoopFirst()
+        handler = threading.Thread(
+            target=srv._on_pod_add, args=(pod_to_v1(_pod(0)),))
+        handler.start()
+        for _ in range(3000):
+            if srv._handlers_waiting:
+                break
+            threading.Event().wait(0.01)
+        assert srv._handlers_waiting and s.queue.active_stats()[0] == 0
+        srv._stop = _ScriptedStop(clk, [lambda: None])
+        rec = self._beat(srv, s)
+        handler.join(30)
+        assert rec["stats"]["scheduled"] == 1    # not an empty wave
+        assert not srv._handlers_waiting
+        assert "lock-wait" in dict(map(tuple, rec["loop"]["phases"]))
+
+    def test_events_in_the_informers_buffer_go_before_the_pop(self):
+        """What waits in the pod informer's buffer reached the scheduler
+        before the peek too: the loop waits for the informer's thread to
+        deliver it, at most a window."""
+        import types
+
+        clk = {"t": 100.0}
+        srv, s = _loop_server(clk)
+        s.telemetry.loop_reset()
+        left = [3]
+
+        class _Through:            # the informer delivers one per wait
+            def clear(self):
+                pass
+
+            def wait(self, timeout):
+                clk["t"] += 0.001
+                s.on_pod_add(_pod(left[0]), arrived=99.99)
+                left[0] -= 1
+
+        srv._handlers_through = _Through()
+        srv.pod_informer = types.SimpleNamespace(
+            delivery_lag=lambda: 0.0, buffered=lambda: left[0], relists=0)
+        srv._stop = _ScriptedStop(clk, [lambda: None])
+        rec = self._beat(srv, s)
+        assert rec["stats"]["scheduled"] == 3
+        assert rec["gather_age_s"] == pytest.approx(0.013)
+        assert rec["gather_wait_s"] == pytest.approx(0.037)
+        # a buffer that never empties holds the loop for a window, no more
+        left[0] = 10 ** 6
+        srv._handlers_through.wait = lambda timeout: clk.__setitem__(
+            "t", clk["t"] + 0.01)
+        srv._stop = _ScriptedStop(clk, [lambda: None])
+        t0 = clk["t"]
+        srv._gather(s.telemetry.loop_lap)
+        assert clk["t"] - t0 == pytest.approx(0.05)
+
+
+    def test_handlers_and_the_loop_under_a_short_switch_interval(self):
+        """More handler threads than cores against the loop's turns, the
+        interpreter switching every 10 us: every pod is bound once, no
+        handler is left standing in `waiting`, nothing hangs."""
+        import sys
+        import time
+
+        from kubernetes_tpu.apiserver import APIServer
+        from kubernetes_tpu.api.v1 import pod_to_v1
+        from kubernetes_tpu.client import Client
+        from kubernetes_tpu.sched.server import SchedulerServer
+
+        binder = RecordingBinder()
+        s = Scheduler(binder=binder, batch_size=64)
+        for n in make_nodes(8):
+            s.on_node_add(n)
+        srv = SchedulerServer(Client.local(APIServer()), scheduler=s,
+                              cycle_interval=0.001, batch_window=0.004)
+        threads, each = 16, 12
+        objs = [[pod_to_v1(_pod(t * each + i)) for i in range(each)]
+                for t in range(threads)]
+
+        def feed(mine):
+            for o in mine:
+                srv._on_pod_add(o)
+
+        workers = [threading.Thread(target=feed, args=(m,)) for m in objs]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for w in workers:
+                w.start()
+            deadline = time.monotonic() + 120
+            while len(binder.bound) < threads * each \
+                    and time.monotonic() < deadline:
+                srv._gather(s.telemetry.loop_lap)
+                assert srv.run_one_wave() is not None, srv.last_wave_error
+            for w in workers:
+                w.join(30)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(w.is_alive() for w in workers)
+        assert sorted(k for k, _ in binder.bound) == sorted(
+            _pod(i).key for i in range(threads * each))
+        assert not srv._handlers_waiting
 
 
 class TestServerEventsLeaveTheLoop:
