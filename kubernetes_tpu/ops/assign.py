@@ -59,6 +59,9 @@ class AssignResult(NamedTuple):
     # ops/gang.py GangVerdict on a gang-bearing batch; None (no pytree
     # leaf: the gang-free programs are unchanged) everywhere else
     gang: Any = None
+    # scalar i32, the rounds the waves engine's loop ran (ops/waves.py);
+    # None from the scan and from the gang loop, which counts its own
+    rounds: Any = None
 
 
 def queue_order(pods: PodArrays) -> Array:
@@ -74,6 +77,7 @@ def assign_step(
     c: Array,
     p_valid: Array,
     node_name_req: Array,
+    pin: Array = -1,
 ) -> Tuple[AssignState, Array, Array]:
     """ONE pod's Filter → Score → selectHost → assume against a live state —
     the body of the sequential scan. Returns (new state, node index or -1,
@@ -83,7 +87,8 @@ def assign_step(
     ps = classes.portset[c]
     psafe = jnp.maximum(ps, 0)
 
-    mask = pod_mask_row(tables, cyc, state, c, node_name_req, p_valid)
+    mask = pod_mask_row(tables, cyc, state, c, node_name_req, p_valid,
+                        pin=pin)
 
     # ---- Score row (weighted sum; component weights/enables come from
     #      the traced EngineConfig — generic_scheduler.go:823-832) ----
@@ -138,7 +143,7 @@ def assign_batch(
     def step(state: AssignState, idx):
         state, node, feasible = assign_step(
             tables, cyc, state, pods.cls[idx], pods.valid[idx],
-            pods.node_name_req[idx])
+            pods.node_name_req[idx], pods.pin[idx])
         return state, (node, feasible)
 
     final, (nodes_sorted, feas_sorted) = jax.lax.scan(step, init, order)
@@ -194,14 +199,17 @@ def mask_context_row(
     valid: Array,
     table: TermCounts | None = None,
     spread: SpreadCounts | None = None,
+    pin: Array = -1,
 ) -> Array:
     """The Filter components that do not move as replicas of a
     self-interaction-free class land: the static lattice, inter-pod
     affinity/anti-affinity (counts only move at placed nodes, through terms
-    such a class never reads), hard topology spread, spec.nodeName, and pod
-    validity. pod_mask_row composes it with mask_dynamic_row per pod.
-    `table` is `state_affinity_table(state)` and `spread`
-    `state_spread_counts(state)` where the caller built them."""
+    such a class never reads), hard topology spread, spec.nodeName, the
+    pod's pin, and pod validity. pod_mask_row composes it with
+    mask_dynamic_row per pod. `table` is `state_affinity_table(state)` and
+    `spread` `state_spread_counts(state)` where the caller built them; `pin`
+    is the pod's `PodArrays.pin` (-1: none, as a class-level caller says
+    it)."""
     from .lattice import _on
 
     nodes, classes, terms = tables.nodes, tables.classes, tables.terms
@@ -217,7 +225,20 @@ def mask_context_row(
     ) | ~_on(ecfg.f_spread)
     host_ok = (node_name_req < 0) | (nodes.name_id == node_name_req) \
         | ~_on(ecfg.f_name)
-    return cyc.static.mask[cls] & interpod_ok & spread_ok & host_ok & valid
+    return (cyc.static.mask[cls] & interpod_ok & spread_ok & host_ok
+            & pin_plane(tables, cyc, pin) & valid)
+
+
+def pin_plane(tables: ClusterTables, cyc: CycleArrays, pin: Array) -> Array:
+    """[N] the pod's pin as a Filter plane: the node affinity term
+    `metadata.name In [pin]` that every term of the pod carried
+    (state/encode.py pin_name), under the NodeAffinity plugin's flag. True
+    everywhere for a pod without one; false everywhere for a pin no node
+    bears."""
+    from .lattice import _on
+
+    return (pin < 0) | (tables.nodes.name_id == pin) \
+        | ~_on(cyc.ecfg.f_node_affinity)
 
 
 def fit_plane(tables: ClusterTables, cyc: CycleArrays, cls: Array,
@@ -288,6 +309,7 @@ def pod_mask_row(
     valid: Array,
     table: TermCounts | None = None,
     spread: SpreadCounts | None = None,
+    pin: Array = -1,
 ) -> Array:
     """Full Filter mask [N] for one pod against a given assume-state — the
     tensor analog of podFitsOnNode (generic_scheduler.go:628-706). Shared by
@@ -298,7 +320,7 @@ def pod_mask_row(
     conjunction, so the regrouping is exact."""
     return (
         mask_context_row(tables, cyc, state, cls, node_name_req, valid,
-                         table, spread)
+                         table, spread, pin)
         & mask_dynamic_row(tables, cyc, cls, state.used,
                            state.ppa, state.ppw, state.ppt,
                            state.vol_any, state.vol_rw, state.vol_cnt)
@@ -385,9 +407,9 @@ def feasible_matrix(
     table = state_affinity_table(tables, cyc, state, P)
     spread = state_spread_counts(tables, cyc, state, P)
     return jax.vmap(
-        lambda c, nnr, v: pod_mask_row(tables, cyc, state, c, nnr, v, table,
-                                       spread)
-    )(pods.cls, pods.node_name_req, pods.valid)
+        lambda c, nnr, v, pin: pod_mask_row(tables, cyc, state, c, nnr, v,
+                                            table, spread, pin)
+    )(pods.cls, pods.node_name_req, pods.valid, pods.pin)
 
 
 class MaskComponents(NamedTuple):
@@ -416,7 +438,7 @@ def mask_components(
     table = state_affinity_table(tables, cyc, state, pods.valid.shape[0])
     spread = state_spread_counts(tables, cyc, state, pods.valid.shape[0])
 
-    def row(c, nnr, v):
+    def row(c, nnr, v, pin):
         req_vec = tables.reqs.vec[classes.rid[c]]
         fit = fit_row(req_vec, state.used, nodes.alloc, nodes.valid)
         ps = classes.portset[c]
@@ -442,10 +464,13 @@ def mask_components(
         # static.mask = node_match ∧ taint_ok ∧ unsched_pass ∧ class valid;
         # recover the taint/unschedulable part by division
         taints_ok = cyc.static.mask[c] | ~nm
+        # the pin is a node affinity term: MatchNodeSelector's to refuse
+        nm = nm & ((pin < 0) | (nodes.name_id == pin))
         return (nm & v, taints_ok, fit, port_ok, aff_ok, anti_ok, spread_ok,
                 host_ok, vol_ok)
 
-    parts = jax.vmap(row)(pods.cls, pods.node_name_req, pods.valid)
+    parts = jax.vmap(row)(pods.cls, pods.node_name_req, pods.valid,
+                          pods.pin)
     return MaskComponents(*parts)
 
 
@@ -578,9 +603,9 @@ def explain_assignments(
                   granularity; cost scales with P·N).
       * "class" — the cheap half evaluates ONCE per interned class (the
                   waves engine already thinks in [SC, N] planes), then per-pod
-                  work is pure GATHERS when no spec.nodeName pod is in the
-                  batch (a lax.cond keeps the per-pod host fold for
-                  batches that actually pin).
+                  work is pure GATHERS when no pod of the batch names a node
+                  (spec.nodeName, or a pin: `PodArrays.pin`; a lax.cond
+                  keeps the per-pod fold for batches that do).
 
     Cost discipline (the <=2% bench budget): the REASON/feasibility
     reductions (the mask planes) always run — they are sum-reductions
@@ -613,8 +638,24 @@ def explain_assignments(
     agg = (state_affinity_table(tables, cyc, state, rows),
            state_spread_counts(tables, cyc, state, rows))
 
-    def host_plane(nnr):
-        return (nnr < 0) | (nodes.name_id == nnr) | ~_on(cyc.ecfg.f_name)
+    def host_plane(nnr, pin):
+        """spec.nodeName's plane and the pin's (MatchNodeSelector refuses by
+        the latter: `fold` counts it there)."""
+        return ((nnr < 0) | (nodes.name_id == nnr) | ~_on(cyc.ecfg.f_name),
+                pin_plane(tables, cyc, pin))
+
+    def fold(c, r8, m8, nnr, pin):
+        """One pod's reasons [9] and feasible-node count from its class's
+        (`r8`, `m8`) and its own two name constraints."""
+        host_ok, pin_ok = host_plane(nnr, pin)
+        host_rej = jnp.sum(nv & ~host_ok).astype(i32)
+        nm_rej = jnp.where(
+            pin >= 0,
+            jnp.sum(nv & ~(cyc.static.node_match[c] & pin_ok)).astype(i32),
+            r8[0])
+        reasons = jnp.concatenate(
+            [nm_rej[None], r8[1:7], host_rej[None], r8[7:]])
+        return reasons, jnp.sum(m8 & host_ok & pin_ok).astype(i32)
 
     def parts_stage(pn, ctx_at):
         """Score decomposition at the explained node: [P]-sized gathers +
@@ -640,20 +681,18 @@ def explain_assignments(
                 jnp.where(chosen >= 0, chosen, -1))
 
     if granularity == "pod":
-        def mrow(c, nnr):
+        def mrow(c, nnr, pin):
             r8, m8 = _explain_mask_row(tables, cyc, state, c, *agg)
-            host_ok = host_plane(nnr)
-            host_rej = jnp.sum(nv & ~host_ok).astype(i32)
-            reasons = jnp.concatenate([r8[:7], host_rej[None], r8[7:]])
-            feas = jnp.sum(m8 & host_ok).astype(i32)
-            return reasons, feas
+            return fold(c, r8, m8, nnr, pin)
 
-        reasons, feas = jax.vmap(mrow)(cls_safe, pods.node_name_req)
+        reasons, feas = jax.vmap(mrow)(cls_safe, pods.node_name_req,
+                                       pods.pin)
 
         def pod_score(_):
-            def row(c, nnr, ch):
+            def row(c, nnr, pin, ch):
                 _r8, m8 = _explain_mask_row(tables, cyc, state, c, *agg)
-                full = m8 & host_plane(nnr)
+                host_ok, pin_ok = host_plane(nnr, pin)
+                full = m8 & host_ok & pin_ok
                 sc_row, cx = _explain_score_row(tables, cyc, state, c, *agg)
                 topn, tops = _row_topk(
                     jnp.where(full, sc_row, -jnp.inf), K)
@@ -662,7 +701,7 @@ def explain_assignments(
                 return topn, tops, pn, ctx_at
 
             topn, tops, pn, ctx_at = jax.vmap(row)(
-                cls_safe, pods.node_name_req, chosen)
+                cls_safe, pods.node_name_req, pods.pin, chosen)
             return topn, tops, parts_stage(pn, ctx_at), pn
 
         topn, tops, parts, pn = jax.lax.cond(
@@ -674,23 +713,18 @@ def explain_assignments(
         reasons9_c = jnp.concatenate(
             [r8[:, :7], jnp.zeros((SC, 1), i32), r8[:, 7:]], axis=1)
         feas_c = m8.sum(axis=1).astype(i32)
-        any_pinned = ((pods.node_name_req >= 0) & pods.valid).any()
+        any_pinned = (((pods.node_name_req >= 0) | (pods.pin >= 0))
+                      & pods.valid).any()
 
         def gather_mask(_):
-            # no pinned pods: the host plane is all-true for every pod, so
-            # the class-level reductions ARE the per-pod answers
+            # no pod names a node: both planes are all-true for every pod,
+            # so the class-level reductions ARE the per-pod answers
             return reasons9_c[cls_safe], feas_c[cls_safe]
 
         def host_mask(_):
-            def fin(c, nnr):
-                host_ok = host_plane(nnr)
-                host_rej = jnp.sum(nv & ~host_ok).astype(i32)
-                reasons = jnp.concatenate(
-                    [r8[c, :7], host_rej[None], r8[c, 7:]])
-                feas = jnp.sum(m8[c] & host_ok).astype(i32)
-                return reasons, feas
-
-            return jax.vmap(fin)(cls_safe, pods.node_name_req)
+            return jax.vmap(lambda c, nnr, pin: fold(c, r8[c], m8[c], nnr,
+                                                     pin))(
+                cls_safe, pods.node_name_req, pods.pin)
 
         reasons, feas = jax.lax.cond(any_pinned, host_mask, gather_mask,
                                      None)
@@ -707,12 +741,12 @@ def explain_assignments(
                 return topn_c[cls_safe], tops_c[cls_safe]
 
             def h(_):
-                def fin(c, nnr):
-                    full = m8[c] & host_plane(nnr)
-                    return _row_topk(
-                        jnp.where(full, sc_rows[c], -jnp.inf), K)
+                def fin(c, nnr, pin):
+                    host_ok, pin_ok = host_plane(nnr, pin)
+                    return _row_topk(jnp.where(m8[c] & host_ok & pin_ok,
+                                               sc_rows[c], -jnp.inf), K)
 
-                return jax.vmap(fin)(cls_safe, pods.node_name_req)
+                return jax.vmap(fin)(cls_safe, pods.node_name_req, pods.pin)
 
             topn, tops = jax.lax.cond(any_pinned, h, g, None)
             pn = jnp.where(chosen >= 0, chosen, topn[:, 0])
@@ -749,12 +783,12 @@ def score_matrix(
     table = state_affinity_table(tables, cyc, state, pods.valid.shape[0])
     spread = state_spread_counts(tables, cyc, state, pods.valid.shape[0])
 
-    def row(c, nnr, v):
-        mask = pod_mask_row(tables, cyc, state, c, nnr, v, table, spread)
+    def row(c, nnr, v, pin):
+        mask = pod_mask_row(tables, cyc, state, c, nnr, v, table, spread, pin)
         return jnp.where(
             mask, score_row(tables, cyc, state, c, table, spread), -jnp.inf)
 
-    return jax.vmap(row)(pods.cls, pods.node_name_req, pods.valid)
+    return jax.vmap(row)(pods.cls, pods.node_name_req, pods.valid, pods.pin)
 
 
 def initial_state(tables: ClusterTables, cyc: CycleArrays) -> AssignState:

@@ -97,7 +97,8 @@ def preempt_batch(
     cyc: CycleArrays,
     existing: PodArrays,
     cls: Array,            # [B] i32: preemptor class ids
-    node_name_req: Array,  # [B] i32: spec.nodeName ids or -1
+    node_name_req: Array,  # [B] i32: spec.nodeName ids, else the pods'
+                           # pins (PodArrays.pin), or -1
     priority: Array,       # [B] i32: preemptor priorities
     D: int,
     pdb_blocked: Array | None = None,   # [E] bool — shared across the burst
@@ -124,7 +125,7 @@ def preempt_for_pod(
     cyc: CycleArrays,
     existing: PodArrays,
     cls: Array,            # scalar: preemptor's class id
-    node_name_req: Array,  # scalar: spec.nodeName id or -1
+    node_name_req: Array,  # scalar: spec.nodeName id, else the pin, or -1
     priority: Array,       # scalar: preemptor's priority
     D: int,
     pdb_blocked: Array | None = None,   # [E] bool — eviction violates a PDB

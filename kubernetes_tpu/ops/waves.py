@@ -44,6 +44,22 @@ TPU idle. This module replaces it as the default path. Per wave:
      against exactly the state that rejected them) — so the loop always
      terminates and an infeasible head class never costs a dedicated wave.
 
+Pins (`PodArrays.pin`: the one node a pod's required node affinity names on
+every term — a DaemonSet's pods, state/encode.py pin_name). A class's pods all
+carry one or none does, and those that do are NOT interchangeable: pod k goes
+to node k or nowhere. Such a class's feasible nodes in a round are its mask
+AND "a pod of its head priority run still waits for this node" (one
+scatter-min of the waiting pods' queue positions into [SC, N]); it claims one
+pod a node through steps 3-5 like any class (no score window: every pod has
+one candidate), and the map back hands each kept node to THE pod that waits
+for it, first in queue order where two of the class name one node. All of a
+DaemonSet's pods whose nodes pass are admitted in ONE round. Which pinned
+pods are consumed is said pod by pod (`_WaveCarry.done`); a pod whose node
+refuses it fails by the rules of step 6 read of ITS node: at once where the
+class is monotone, with its run on a zero-progress or failing-prefix round
+otherwise. All of it sits under `lax.cond`s on "the batch has a pin": pins
+are DATA of the one program, and a batch without them pays a few selects.
+
 How a round sees its nodes in order (steps 3 and the map back to pods): an
 order is a `lax.sort`, and whatever must be seen in that order is an OPERAND
 of the sort that makes it — the class's score order (`_score_order`: two keys,
@@ -102,6 +118,9 @@ class _WaveCarry(NamedTuple):
     node_out: Array   # [P] chosen node per pod (-1 = none)
     wave_out: Array   # [P] wave index each pod was admitted in (-1 = never)
     waves: Array      # scalar i32
+    done: Array       # [P] a PINNED pod is consumed (placed or failed): such
+                      # pods leave their class's queue out of order, so
+                      # `cursor` only counts them
 
 
 def interaction_graph(tables: ClusterTables, cyc: CycleArrays) -> Array:
@@ -316,6 +335,17 @@ def _escape_cap(tables, cyc, state, r, table):
     return jnp.where(escape, jnp.minimum(r, 1), r)
 
 
+def _pin_nodes(nodes, pin: Array) -> Array:
+    """pin [P] node-name ids → [P] the slot of the valid node that bears the
+    name, -1 where the pod has no pin or no node bears it. One fused
+    compare-and-reduce over [P, N], once a dispatch."""
+    N = nodes.valid.shape[0]
+    hit = ((nodes.name_id[None, :] == pin[:, None]) & nodes.valid[None, :]
+           & (pin[:, None] >= 0))
+    return jnp.max(jnp.where(hit, jnp.arange(N, dtype=jnp.int32)[None, :],
+                             -1), axis=1)
+
+
 def assign_waves(
     tables: ClusterTables,
     cyc: CycleArrays,
@@ -364,10 +394,30 @@ def assign_waves(
         jnp.arange(P, dtype=jnp.int32)) - class_offset[cls_of_pod]
     node_ids = jnp.arange(N, dtype=jnp.int32)
 
+    # ---- pins: see the module docstring. `any_pin` guards every pinned
+    # step below, so a batch without pins runs none of them ----
+    has_pin = pods.valid & (pods.pin >= 0)                     # [P]
+    any_pin = has_pin.any()
+    cls_pinned = jnp.zeros((SC,), bool).at[cls_of_pod].max(has_pin)
+    pin_node = lax.cond(any_pin, lambda: _pin_nodes(nodes, pods.pin),
+                        lambda: jnp.full((P,), -1, jnp.int32))
+    on_pin = has_pin & (pin_node >= 0)
+    pin_safe = jnp.maximum(pin_node, 0)
+    no_pods = jnp.zeros((P,), bool)
+
     def body(carry: _WaveCarry) -> _WaveCarry:
-        state, cursor, placed, node_out, wave_out, waves = carry
+        state, cursor, placed, node_out, wave_out, waves, done = carry
         remaining = class_total - cursor
         active = classes.valid & (remaining > 0)
+
+        # where a class's queue stands: the cursor, or for a pinned class
+        # the first of its pods still waiting
+        def pinned_heads():
+            first = jnp.full((SC,), P, jnp.int32).at[cls_of_pod].min(
+                jnp.where(has_pin & ~done, pos_of_pod, P))
+            return jnp.where(cls_pinned, first, cursor)
+
+        head = lax.cond(any_pin, pinned_heads, lambda: cursor)
 
         # next pending pod per class. Admission is CROSS-TIER: a class needs
         # no global priority-tier gate because everything priority order can
@@ -376,7 +426,7 @@ def assign_waves(
         # through the rank-ordered cumulative passes. A lower-priority pod
         # admitted alongside a higher-priority one replays after it
         # (wave, priority, creation) and sees identical committed state.
-        nxt = sorted_pods_pad[jnp.minimum(class_offset + cursor, P)]
+        nxt = sorted_pods_pad[jnp.minimum(class_offset + head, P)]
         nxt_ok = active & (nxt < P)
         nxt_safe = jnp.minimum(nxt, P - 1)
         # i32 min is the neutral element, not a magic sentinel: run counts
@@ -389,7 +439,7 @@ def assign_waves(
         # together when the head pod is infeasible against frozen state
         run_pod = (
             pods.valid & (pods.priority == nxt_pri[cls_of_pod])
-            & (pos_of_pod >= cursor[cls_of_pod])
+            & jnp.where(has_pin, ~done, pos_of_pod >= cursor[cls_of_pod])
         )
         run_cnt = (
             jnp.zeros((SC,), jnp.int32).at[cls_of_pod].add(
@@ -400,7 +450,18 @@ def assign_waves(
         table = state_affinity_table(tables, cyc, state, SC)
         spread = state_spread_counts(tables, cyc, state, SC)
         mask, score = _class_mask_score(tables, cyc, state, table, spread)
-        mask = mask & nxt_ok[:, None]
+        cls_mask = mask & nxt_ok[:, None]
+
+        # a pinned class's nodes: those a pod of its run still waits for,
+        # and for each the first such pod's queue position (P: none)
+        def pinned_waiting():
+            at = jnp.where(run_pod & on_pin, pin_node, N)
+            return jnp.full((SC, N), P, jnp.int32).at[cls_of_pod, at].min(
+                pos_of_pod, mode="drop")
+
+        waits = lax.cond(any_pin, pinned_waiting,
+                         lambda: jnp.full((SC, N), P, jnp.int32))
+        mask = cls_mask & (~cls_pinned[:, None] | (waits < P))
         # score-window admission (EngineConfig.w_window): a class only
         # admits on nodes within the window of its per-class feasible max
         # this wave, so decisive score gaps (preferAvoidPods, strong
@@ -411,7 +472,8 @@ def assign_waves(
         # waves once the leading tier fills and the class max drops.
         best = jnp.max(jnp.where(mask, score, -jnp.inf), axis=1,
                        keepdims=True)
-        adm_mask = mask & (score >= best - cyc.ecfg.w_window)
+        adm_mask = mask & ((score >= best - cyc.ecfg.w_window)
+                           | cls_pinned[:, None])
         r = _escape_cap(tables, cyc, state, r, table)
 
         # independent set over the interaction graph, queue-rank order:
@@ -620,10 +682,9 @@ def assign_waves(
             (~A_final, neg_score, jnp.broadcast_to(node_ids, (SC, N))),
             dimension=1, num_keys=2, is_stable=True)
         j = pos_of_pod - cursor[cls_of_pod]
-        won = pods.valid & (j >= 0) & (j < m[cls_of_pod])
+        won = pods.valid & ~has_pin & (j >= 0) & (j < m[cls_of_pod])
         node_out2 = jnp.where(
             won, kept_nodes[cls_of_pod, jnp.clip(j, 0, N - 1)], node_out)
-        wave_out2 = jnp.where(won, waves, wave_out)
 
         # Failure consumption, two rules (both replay-sound):
         #  * global zero progress ⇒ state is frozen ⇒ every attempting
@@ -654,9 +715,31 @@ def assign_waves(
         consume = jnp.where(infeasible & mono, remaining,
                             jnp.where((fail & attempted) | early_fail,
                                       run_left, m))
+
+        # pinned pods, one by one: a kept node goes to the pod that waits
+        # for it; a pod fails by the two rules above read of ITS node (a
+        # monotone class's pod as soon as its node refuses it, whatever its
+        # priority: the state only tightens), or with its run
+        def pinned_outcome():
+            c = cls_of_pod
+            mine = run_pod & on_pin & (waits[c, pin_safe] == pos_of_pod)
+            won_p = mine & A_final[c, pin_safe]
+            refused = ~(on_pin & cls_mask[c, pin_safe])
+            lost_p = (has_pin & ~done & ~won_p & attempted[c]
+                      & ((mono[c] & refused)
+                         | (run_pod & (fail | early_fail[c]))))
+            gone = jnp.zeros((SC,), jnp.int32).at[c].add(
+                (won_p | lost_p).astype(jnp.int32))
+            return won_p, lost_p, jnp.where(cls_pinned, gone, consume)
+
+        won_p, lost_p, consume = lax.cond(
+            any_pin, pinned_outcome, lambda: (no_pods, no_pods, consume))
+        node_out2 = jnp.where(won_p, pin_node, node_out2)
+        wave_out2 = jnp.where(won | won_p, waves, wave_out)
         return _WaveCarry(
             state=state2, cursor=cursor + consume, placed=placed + m,
             node_out=node_out2, wave_out=wave_out2, waves=waves + 1,
+            done=done | won_p | lost_p,
         )
 
     cap = jnp.int32(max_waves if max_waves is not None else 2 * P + 2)
@@ -673,10 +756,12 @@ def assign_waves(
         node_out=jnp.full((P,), -1, jnp.int32),
         wave_out=jnp.full((P,), -1, jnp.int32),
         waves=jnp.int32(0),
+        done=no_pods,
     )
     final = lax.while_loop(cond, body, init_carry)
     node = final.node_out
-    result = AssignResult(node=node, feasible=node >= 0, state=final.state)
+    result = AssignResult(node=node, feasible=node >= 0, state=final.state,
+                          rounds=final.waves)
     if return_waves:
         return result, final.wave_out
     return result

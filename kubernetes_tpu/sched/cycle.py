@@ -126,6 +126,7 @@ def _apply_extra_plugins(tables, cyc, extra_plugins, extra_weights):
         creation=jnp.zeros((SC,), jnp.int32),
         node_id=jnp.full((SC,), -1, jnp.int32),
         node_name_req=jnp.full((SC,), -1, jnp.int32),
+        pin=jnp.full((SC,), -1, jnp.int32),
     )
     ctx = TensorContext(tables=tables, cyc=cyc, pending=ident)
     bias = jnp.zeros_like(cyc.static.score)

@@ -52,6 +52,18 @@ VOLUME_PODS = REG.counter(
     "followed to their volumes, the pod decided on; waiting: left for a "
     "claim to bind or appear)",
     labels=("result",))
+# pins (`PodArrays.pin`: pods the DaemonSet controller wrote), counted on
+# waves whose batch holds one
+PINNED_PODS = REG.counter(
+    "scheduler_pinned_pods_total",
+    "Pods in a wave's batch whose required node affinity names one node by "
+    "metadata.name on every term, by result (fit: decided onto that node; "
+    "unfit: the node refused them)",
+    labels=("result",))
+PIN_CLASSES = REG.gauge(
+    "scheduler_pin_classes",
+    "Scheduling classes that held the pinned pods of the last wave with "
+    "any (a DaemonSet is one)")
 # cache-consistency sweep (sched/debugger.py ConsistencySweeper — the kube
 # cacheComparer made periodic): divergences found between the resident
 # encoded state and informer truth, and self-heal re-encodes taken
@@ -243,6 +255,10 @@ def observe_wave(stats, queue_lengths, cache_counts) -> None:
         VOLUME_PODS.inc(stats.volume_pods, result="resolved")
     if stats.volume_waits:
         VOLUME_PODS.inc(len(stats.volume_waits), result="waiting")
+    if stats.pinned:
+        PINNED_PODS.inc(stats.pinned - stats.pinned_unfit, result="fit")
+        PINNED_PODS.inc(stats.pinned_unfit, result="unfit")
+        PIN_CLASSES.set(stats.pin_classes)
     if isinstance(queue_lengths, dict):
         observe_queue_depths(queue_lengths)
     else:

@@ -283,10 +283,17 @@ class Preemptor:
         prewarmer = getattr(scheduler, "prewarmer", None)
 
         pend_cls = np.asarray(jax.device_get(snap.pending.cls))
+        # the one node a preemptor may land on: spec.nodeName, else its pin
+        # (`PodArrays.pin`: a DaemonSet pod's). Either way the what-if's
+        # candidates are that node alone, and pinned replicas of one class
+        # are a lane each: every one is handed ITS node, never the k-th of
+        # a shared order
         pend_nnr = np.asarray(jax.device_get(snap.pending.node_name_req))
+        pend_pin = np.asarray(jax.device_get(snap.pending.pin))
+        pend_nnr = np.where(pend_nnr >= 0, pend_nnr, pend_pin)
 
         # Lanes are evaluated against the PRE-burst snapshot, so preemptors
-        # that agree on (class, nodeName pin, priority) get the identical
+        # that agree on (class, named node, priority) get the identical
         # what-if: evaluate each distinct one once and share the answer. A
         # backlog's unschedulable tail is thousands of replicas of a few
         # templates — on the chip one 8-lane dispatch at the 5k×50k shape

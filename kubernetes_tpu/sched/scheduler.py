@@ -27,6 +27,7 @@ from typing import Any, Callable, Dict, List, Optional, Protocol, Sequence, Tupl
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..api.types import DEFAULT_SCHEDULER_NAME, Node, Pod
 from ..component import trace
@@ -131,6 +132,12 @@ class CycleStats:
     # its claims why (its FailedScheduling Event's message, by pod key)
     volume_pods: int = 0
     volume_waits: Dict[str, str] = field(default_factory=dict)
+    # pins (`PodArrays.pin`: a DaemonSet's pods), on a wave whose batch
+    # holds one only: the pods with a pin, and those of them whose node
+    # refused them
+    pinned: int = 0
+    pin_classes: int = 0
+    pinned_unfit: int = 0
 
 
 @dataclass
@@ -159,6 +166,7 @@ class Wave:
     node_idx: Any = None            # node row per batch pod, -1 = none
     attribution: Any = None         # the dispatch's ExplainResult, on host
     gang_verdict: Any = None        # ops/gang.py GangVerdict, on host
+    rounds: Any = None              # the waves engine's rounds, on host
     # ---- set by commit ---- #
     explain: Optional[Dict[str, Any]] = None   # the explainer's rendering
 
@@ -841,6 +849,7 @@ class Scheduler:
             row = self.encoder.pod_row
             wave.extra["volume_classes"] = len(
                 {row(p)[2] for p in pending if p.volumes})
+        which_pinned = self._note_pins(wave, len(pending))
         span.mark("snapshot")
         # how the snapshot this wave dispatches on was produced
         # ("full" | "patch" | "cached") rides the wave's record
@@ -900,11 +909,17 @@ class Scheduler:
             self._prestage(wave)
             span.mark("dispatch")
             try:
-                wave.node_idx, wave.attribution, wave.gang_verdict = \
-                    handle.result()
+                (wave.node_idx, wave.attribution, wave.gang_verdict,
+                 wave.rounds) = handle.result()
             except DispatchAbandonedError:
                 span.mark("readback")
                 return False
+            if which_pinned is not None:
+                stats.pinned_unfit = wave.extra["pinned_unfit"] = int(
+                    np.count_nonzero(np.asarray(
+                        wave.node_idx)[:len(pending)][which_pinned] < 0))
+                if wave.rounds is not None:
+                    wave.extra["pin_rounds"] = int(wave.rounds)
             span.mark("readback")
             return True
         finally:
@@ -912,14 +927,32 @@ class Scheduler:
             # next on-path mesh patch may donate the resident buffers
             self.cache.mark_dispatch_done()
 
+    def _note_pins(self, wave: Wave, k: int):
+        """At the tail of the `snapshot` phase (`snapshot/pins`): how many of
+        the batch's `k` pods carry a pin, how many classes hold them, and
+        the live class count, onto the wave's record, from the (class, pin)
+        columns the snapshot built on the host (state/cache.py
+        `pending_pins`: per stage, never per pod). A batch without a pin
+        adds no field. Returns which pods are pinned ([k] bool) or None."""
+        tr = trace.current()
+        t0 = time.perf_counter()
+        n, classes, which = self.cache.pending_pins(k)
+        if n:
+            wave.stats.pinned, wave.stats.pin_classes = n, classes
+            wave.extra.update(pinned=n, pin_classes=classes,
+                              classes=len(self.encoder.class_reg))
+        if tr is not None:
+            tr.child("pins", time.perf_counter() - t0)
+        return which
+
     def _gang_of(self, snap):
         return snap.gang if self._device_gangs else None
 
     def _engine_call(self, tables, pending, keys, existing, gang, dims,
                      engine: str, prewarmer=None, mesh=None):
         """The wave's one call into the engine (primary and fallback):
-        `(node, attribution or None, gang verdict or None)`, still on the
-        device."""
+        `(node, attribution or None, gang verdict or None, the waves
+        engine's rounds or None)`, still on the device."""
         explain = self.explainer is not None
         out = _schedule_batch(
             tables, pending, keys, dims.D, existing,
@@ -930,7 +963,7 @@ class Scheduler:
             gang=gang, dims=dims, prewarmer=prewarmer, mesh=mesh,
             explain=explain, engine=engine)
         res, exp = out if explain else (out, None)
-        return res.node, exp, res.gang
+        return res.node, exp, res.gang, res.rounds
 
     @staticmethod
     def _get_attribution(exp_dev):
@@ -943,11 +976,12 @@ class Scheduler:
         except Exception:  # noqa: BLE001 - observability, not placement
             return None
 
-    def _read_back(self, node, exp, verdict):
+    def _read_back(self, node, exp, verdict, rounds):
         """A dispatch's results on the host: `(node_idx, attribution,
-        gang verdict)`; the verdict rides the placements' own transfer."""
-        node, verdict = jax.device_get((node, verdict))
-        return node, self._get_attribution(exp), verdict
+        gang verdict, rounds)`; the verdict and the round count ride the
+        placements' own transfer."""
+        node, verdict, rounds = jax.device_get((node, verdict, rounds))
+        return node, self._get_attribution(exp), verdict, rounds
 
     def _dispatch_primary(self, wave: Wave):
         """The wave's dispatch on the serving backend, run by the
@@ -968,11 +1002,11 @@ class Scheduler:
         # TraceAnnotation inside a lazily-started profiler trace.
         with tel.device_annotation("ktpu-wave-dispatch"):
             tp0 = time.perf_counter()
-            node, exp, verdict = call()
+            node, exp, verdict, rounds = call()
             tp1 = time.perf_counter()
             jax.block_until_ready(node)
             tp2 = time.perf_counter()
-            out = self._read_back(node, exp, verdict)
+            out = self._read_back(node, exp, verdict, rounds)
         tel.note_device_split(tp1 - tp0, tp2 - tp1,
                               time.perf_counter() - tp2, token=wave.span)
         return out

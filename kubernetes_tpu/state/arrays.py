@@ -179,6 +179,9 @@ class PodArrays(NamedTuple):
     creation: Array      # [P] i32 creation ordering index
     node_id: Array       # [P] i32 bound/assumed node index, -1 unbound
     node_name_req: Array # [P] i32 spec.nodeName as name id, -1 none
+    pin: Array           # [P] i32 the one node the pod's required node
+                         # affinity names by metadata.name on every term
+                         # (state/encode.py pin_name), as name id; -1 none
 
 
 class ImageTable(NamedTuple):

@@ -46,7 +46,7 @@ from ..api.types import Node, Pod
 from ..component import trace
 from .arrays import ClusterTables, NodeArrays, PodArrays
 from .dims import Dims
-from .encode import Encoder
+from .encode import EMPTY_POD_ROW, Encoder
 
 
 DEFAULT_ASSUME_TTL = 30.0  # durationToExpireAssumedPod, scheduler.go:268 (30s)
@@ -214,7 +214,7 @@ class SchedulerCache:
         self._free_pod_slots: List[int] = []
         # host numpy staging mirrors of the device arrays
         self._staging_nodes: Optional[NodeArrays] = None
-        self._staging_pod_rows: Optional[np.ndarray] = None   # [E, 6] i32
+        self._staging_pod_rows: Optional[np.ndarray] = None   # [E, POD_ROW_COLS] i32
         self._staging_pod_valid: Optional[np.ndarray] = None  # [E] bool
         self._staging_pod_node: Optional[np.ndarray] = None   # [E] i32
         self._encoder: Optional[Encoder] = None
@@ -223,6 +223,9 @@ class SchedulerCache:
         # pending-batch staging (see _pending_block)
         self._pending_stage = None
         self._pending_stage_keys: Optional[Tuple] = None
+        # the (class, pin) columns of the pending batch last put on the
+        # device, on the host: what `pending_pins` counts
+        self._pending_pin_cols: Optional[Tuple[np.ndarray, np.ndarray]] = None
         # introspection for tests/bench: how the last snapshot was produced
         self.last_snapshot_mode: str = ""   # "cached" | "patch" | "full"
         self.last_patch_rows: int = 0
@@ -790,6 +793,7 @@ class SchedulerCache:
         with self._mu:
             pe_host = encoder.build_pod_arrays(
                 list(pending), d, self._node_slot, capacity=d.P)
+            self._pending_pin_cols = (pe_host.cls, pe_host.pin)
             gang = self._gang_arrays(encoder, pending, d, mesh)
         return Snapshot(
             generation=base.generation,
@@ -879,6 +883,21 @@ class SchedulerCache:
                 kb *= 2
         return compiled
 
+    def pending_pins(self, k: int) -> Tuple[int, int, Optional[np.ndarray]]:
+        """Of the first `k` pods of the pending batch last put on the device:
+        how many carry a pin (`PodArrays.pin`), how many classes hold them,
+        and which they are ([k] bool; None where none is). Two vectorized
+        passes over host columns the snapshot built anyway."""
+        with self._mu:
+            cols = self._pending_pin_cols
+        if cols is None:
+            return 0, 0, None
+        which = cols[1][:k] >= 0
+        n = int(np.count_nonzero(which))
+        if not n:
+            return 0, 0, None
+        return n, int(np.unique(cols[0][:k][which]).size), which
+
     @staticmethod
     def _registry_sizes(encoder: Encoder) -> Dict[str, int]:
         return {
@@ -918,7 +937,7 @@ class SchedulerCache:
             name_id=rows[: d.E, 0], ns=rows[: d.E, 1], cls=rows[: d.E, 2],
             priority=rows[: d.E, 3], creation=rows[: d.E, 4],
             node_id=self._staging_pod_node[: d.E],
-            node_name_req=rows[: d.E, 5],
+            node_name_req=rows[: d.E, 5], pin=rows[: d.E, 6],
         )
 
     @staticmethod
@@ -986,10 +1005,8 @@ class SchedulerCache:
                 self._staging_nodes, i, n,
                 list(self._by_node.get(n.name, {}).values()), d)
 
-        self._staging_pod_rows = np.zeros((d.E, 6), I32)
-        self._staging_pod_rows[:, 0] = -1
-        self._staging_pod_rows[:, 1] = -1
-        self._staging_pod_rows[:, 5] = -1
+        self._staging_pod_rows = np.tile(
+            np.array(EMPTY_POD_ROW, I32), (d.E, 1))
         self._staging_pod_valid = np.zeros((d.E,), bool)
         self._staging_pod_node = np.full((d.E,), -1, I32)
         for i, k in enumerate(self._pod_keys):
@@ -1014,6 +1031,7 @@ class SchedulerCache:
         )
         pe = encoder.build_pod_arrays(list(pending), d, self._node_slot,
                                       capacity=d.P)
+        self._pending_pin_cols = (pe.cls, pe.pin)
         if mesh is not None:
             # mesh-resident placement: node axis split across the mesh's
             # chips, small interned tables replicated (parallel/mesh.py);
@@ -1187,7 +1205,7 @@ class SchedulerCache:
             self._pod_keys[slot] = ""
             self._free_pod_slots.append(slot)
             self._staging_pod_valid[slot] = False
-            self._staging_pod_rows[slot] = (-1, -1, 0, 0, 0, -1)
+            self._staging_pod_rows[slot] = EMPTY_POD_ROW
             self._staging_pod_node[slot] = -1
             pod_idx.append(slot)
         for key in sorted(self._dirty_pods):
@@ -1293,6 +1311,7 @@ class SchedulerCache:
                         p.node_name, -1) if p.node_name else -1
                     stage.valid[i] = True
                 self._pending_stage_keys = pending_keys
+                self._pending_pin_cols = (stage.rows[:, 2], stage.rows[:, 6])
                 kb = _patch_bucket(len(changed))
                 idx = _pad_patch(changed, kb)
                 rows = PodArrays(
@@ -1304,6 +1323,7 @@ class SchedulerCache:
                     creation=np.ascontiguousarray(stage.rows[idx, 4]),
                     node_id=stage.node_id[idx],
                     node_name_req=np.ascontiguousarray(stage.rows[idx, 5]),
+                    pin=np.ascontiguousarray(stage.rows[idx, 6]),
                 )
                 self._last_pending_patched = True
                 return _patch_resident(
@@ -1314,11 +1334,12 @@ class SchedulerCache:
             list(pending), d, self._node_slot, capacity=d.P)
         self._pending_stage = _PendingStage.from_pod_arrays(pe_host)
         self._pending_stage_keys = pending_keys
+        self._pending_pin_cols = (pe_host.cls, pe_host.pin)
         return self._put(pe_host, device, mesh)
 
 
 class _PendingStage:
-    """Persistent host staging for the pending batch ([P, 6] rows +
+    """Persistent host staging for the pending batch ([P, POD_ROW_COLS] rows +
     node_id + valid), patched in place across cycles."""
 
     __slots__ = ("rows", "node_id", "valid")
@@ -1331,7 +1352,7 @@ class _PendingStage:
     @classmethod
     def from_pod_arrays(cls, pe: PodArrays) -> "_PendingStage":
         rows = np.stack([pe.name_id, pe.ns, pe.cls, pe.priority,
-                         pe.creation, pe.node_name_req], axis=1)
+                         pe.creation, pe.node_name_req, pe.pin], axis=1)
         return cls(rows=np.ascontiguousarray(rows),
                    node_id=np.array(pe.node_id, copy=True),
                    valid=np.array(pe.valid, copy=True))

@@ -12,6 +12,7 @@ device arrays can be patched incrementally (state/cache.py) instead of re-encode
 
 from __future__ import annotations
 
+from dataclasses import replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -19,6 +20,7 @@ import numpy as np
 from ..api.types import (
     NUM_FIXED_RES,
     RES_PODS,
+    Affinity,
     HostPort,
     LabelSelector,
     Node,
@@ -86,6 +88,44 @@ def nsel_as_term(node_selector: Dict[str, str]) -> NodeSelectorTerm:
     )
 
 
+#: columns of a pod's interned row (`Encoder.pod_row`): name, namespace,
+#: class, priority, creation, spec.nodeName, pin
+POD_ROW_COLS = 7
+#: the row of a slot no pod occupies: absent ids are -1
+EMPTY_POD_ROW = (-1, -1, 0, 0, 0, -1, -1)
+def pin_name(aff: Affinity) -> str:
+    """The pod's pin, "" for none: the one node its required node affinity
+    names by `matchFields metadata.name` on EVERY term (what the DaemonSet
+    controller writes: daemonset_util.go
+    ReplaceDaemonSetPodNodeNameNodeAffinity). Several names in a term, names
+    that differ between terms, or a term without one are no pin: those stay
+    on the term table's `fields` path."""
+    nr = aff.node_required
+    if nr is None or not nr.terms:
+        return ""
+    name = None
+    for t in nr.terms:
+        f = t.field_name_in
+        if len(f) != 1 or (name is not None and f[0] != name):
+            return ""
+        name = f[0]
+    return name
+
+
+def without_pin(aff: Affinity) -> Affinity:
+    """The affinity of a pinned pod as its CLASS sees it: the terms WITHOUT
+    their fields, so that a DaemonSet's pods are one class and the pin is the
+    pod's own datum (`PodArrays.pin`). A term that is then empty was
+    `name In [n]` alone and matches every node once the pin holds, so the OR
+    of the terms is true and the class has no required node affinity at all
+    (an empty term as WRITTEN matches nothing, and is no pin)."""
+    terms = aff.node_required.terms
+    rest = NodeSelector(tuple(NodeSelectorTerm(t.requirements)
+                              for t in terms)) \
+        if all(t.requirements for t in terms) else None
+    return replace(aff, node_required=rest)
+
+
 class Encoder:
     """Stateful interner: object graphs → integer ids → numpy tables."""
 
@@ -124,6 +164,7 @@ class Encoder:
         # with the row memos when the label projection widens
         # (projection_rewalk): fingerprints embed the projected label set.
         self._class_memo: Dict[tuple, int] = {}
+        self._pin_memo: Dict[tuple, Affinity] = {}   # see pin_split
         # incremental-encode state (the cache.go:204-255 analog's host half):
         # per-object memos so steady-state cycles do O(changed) interning work.
         self._pod_rows: Dict[int, tuple] = {}   # id(pod) → (pod, row tuple)
@@ -351,6 +392,25 @@ class Encoder:
         get = self.vocabs.label_keys.get
         return {k: v for k, v in labels.items() if get(k) in ref}
 
+    def pin_split(self, aff: Affinity) -> Tuple[str, Affinity]:
+        """(`pin_name`, the affinity as the class sees it: `without_pin`, or
+        the affinity itself where there is no pin). The stripped affinity is
+        memoized by what is left of it: a DaemonSet's pods share that (one
+        template), so its n pods build ONE, and their fingerprints compare
+        it by identity first."""
+        pin = pin_name(aff)
+        if not pin:
+            return "", aff
+        key = (tuple(t.requirements for t in aff.node_required.terms),
+               aff.node_preferred, aff.pod_required, aff.pod_preferred,
+               aff.anti_required, aff.anti_preferred)
+        memo = self._pin_memo
+        got = memo.get(key)
+        if got is None:
+            _evict_half(memo, 1 << 12)
+            got = memo[key] = without_pin(aff)
+        return pin, got
+
     def class_fingerprint(self, p: Pod, ns_id: int) -> tuple:
         """Value-based spec fingerprint: equal fingerprints ⇒ class_id would
         intern the same spec tuple. Built from raw field VALUES (everything
@@ -364,7 +424,7 @@ class Encoder:
         lk = tuple(sorted(
             (k, v) for k, v in labels.items() if k in ref)) \
             if (ref and labels) else ()
-        aff = p.affinity
+        pin, aff = self.pin_split(p.affinity)
         if (aff.node_required is None and not aff.node_preferred
                 and not aff.pod_required and not aff.anti_required
                 and not aff.pod_preferred and not aff.anti_preferred):
@@ -377,7 +437,7 @@ class Encoder:
                 p.tolerations, p.host_ports, p.topology_spread,
                 p.spread_selectors, p.images,
                 lim if (lim.milli_cpu or lim.memory_kib) else None,
-                self.volume_key(p) if p.volumes else None)
+                self.volume_key(p) if p.volumes else None, bool(pin))
 
     def class_id_memo(self, p: Pod, ns_id: int) -> int:
         """class_id through the value-based fingerprint memo: the full spec
@@ -395,9 +455,12 @@ class Encoder:
         rid = self.req_id(p.requests)
         ls = self.labelset_id(self._projected_labels(p.labels))
         nsel = self.nterm_id(nsel_as_term(p.node_selector)) if p.node_selector else -1
-        aff_active = p.affinity.node_required is not None
+        # the pin is the pod's own (pin_name): its terms enter the class
+        # without their fields, and the class says only THAT its pods have one
+        pin, aff_cls = self.pin_split(p.affinity)
+        aff_active = aff_cls.node_required is not None
         nterms = tuple(
-            self.nterm_id(t) for t in (p.affinity.node_required.terms if aff_active else ())
+            self.nterm_id(t) for t in (aff_cls.node_required.terms if aff_active else ())
             if (t.requirements or t.field_name_in)
         )
         pterms = tuple(
@@ -439,7 +502,8 @@ class Encoder:
         shared, priv = self.volume_key(p) if p.volumes else ((), ())
         vols = self.volset_id(shared) if shared else -1
         spec = (ns_id, rid, ls, nsel, aff_active, nterms, pterms, tol, ports,
-                aff, anti, paff, panti, tsc, ssel, imgs, lim, vols, priv)
+                aff, anti, paff, panti, tsc, ssel, imgs, lim, vols, priv,
+                bool(pin))
         before = len(self.class_reg)
         cid = self.class_reg.intern(spec)
         if cid == before:
@@ -467,8 +531,9 @@ class Encoder:
         self._node_seen[id(n)] = n
 
     def pod_row(self, p: Pod) -> tuple:
-        """Interned identity row for one pod:
-        (name_id, ns_id, class_id, priority, creation, node_name_vocab_id).
+        """Interned identity row for one pod (POD_ROW_COLS):
+        (name_id, ns_id, class_id, priority, creation, node_name_vocab_id,
+        pin_vocab_id).
         Memoized by object identity (the keepalive reference makes id() safe),
         so a pod is walked ONCE when it first appears — the analog of the
         reference encoding a pod into NodeInfo once per informer event, not
@@ -486,6 +551,7 @@ class Encoder:
             # nothing accounting for every group past the capacity)
             self.group_id(p)
         ns_id = self.vocabs.namespaces.intern(p.namespace)
+        pin = pin_name(p.affinity)
         row = (
             self.vocabs.pod_names.intern(p.name),
             ns_id,
@@ -493,6 +559,7 @@ class Encoder:
             p.priority,
             p.creation_index,
             self.vocabs.node_names.intern(p.node_name) if p.node_name else -1,
+            self.vocabs.node_names.intern(pin) if pin else -1,
         )
         _evict_half(self._pod_rows, 1 << 19)
         self._pod_rows[id(p)] = (p, row)
@@ -517,6 +584,7 @@ class Encoder:
         nn_intern = self.vocabs.node_names.intern
         class_memo = self._class_memo
         class_id = self.class_id
+        pin_split = self.pin_split
         ref = self.referenced_label_strs
         group_memo: Dict[object, Tuple[int, bool]] = {}
         group_min = self.group_min
@@ -554,7 +622,9 @@ class Encoder:
             lk = tuple(sorted(
                 (k, v) for k, v in labels.items() if k in ref)) \
                 if (ref and labels) else ()
-            aff = p.affinity
+            aff, pin = p.affinity, ""
+            if aff.node_required is not None:
+                pin, aff = pin_split(aff)
             if (aff.node_required is None and not aff.node_preferred
                     and not aff.pod_required and not aff.anti_required
                     and not aff.pod_preferred and not aff.anti_preferred):
@@ -568,7 +638,7 @@ class Encoder:
                   p.tolerations, p.host_ports, p.topology_spread,
                   p.spread_selectors, p.images,
                   lim if (lim.milli_cpu or lim.memory_kib) else None,
-                  self.volume_key(p) if p.volumes else None)
+                  self.volume_key(p) if p.volumes else None, bool(pin))
             cid = class_memo.get(fp)
             if cid is None:
                 cid = class_id(p)
@@ -580,7 +650,8 @@ class Encoder:
                 names_rev.append(name)
             nn = p.node_name
             row = (nid, nsid, cid, p.priority, p.creation_index,
-                   nn_intern(nn) if nn else -1)
+                   nn_intern(nn) if nn else -1,
+                   nn_intern(pin) if pin else -1)
             pod_rows[id(p)] = (p, row)
         _evict_half(pod_rows, 1 << 19)
         _evict_half(class_memo, 1 << 16)
@@ -814,7 +885,7 @@ class Encoder:
         for i, spec in enumerate(self._class_spec):
             (ns_id, rid, ls, nsel, aff_active, nterms, pterms, tol, ports,
              aff, anti, paff, panti, tsc, ssel, imgs, lim, vols,
-             priv) = spec
+             priv, _pinned) = spec
             t["valid"][i] = True
             t["ns"][i], t["rid"][i], t["labelset"][i] = ns_id, rid, ls
             t["nsel_term"][i] = nsel
@@ -1020,8 +1091,8 @@ class Encoder:
         k = len(pods)
         valid = np.zeros((P,), bool)
         node_id = np.full((P,), -1, I32)
-        rows = np.zeros((P, 6), I32)
-        rows[:, 0] = rows[:, 1] = rows[:, 5] = -1  # absent ids, like before
+        C = POD_ROW_COLS
+        rows = np.tile(np.array(EMPTY_POD_ROW, I32), (P, 1))
         if k:
             # one vectorized assembly from memoized rows — 50k pods cost one
             # flat fromiter, not 50k spec walks (pod_row pays the walk
@@ -1032,7 +1103,7 @@ class Encoder:
             # cycle at 50k pending.
             rows[:k] = np.fromiter(
                 (v for p in pods for v in self.pod_row(p)),
-                dtype=I32, count=6 * k).reshape(k, 6)
+                dtype=I32, count=C * k).reshape(k, C)
             valid[:k] = True
             node_id[:k] = np.fromiter(
                 (node_index.get(p.node_name, -1) if p.node_name else -1
@@ -1040,7 +1111,7 @@ class Encoder:
         return PodArrays(
             valid=valid, name_id=rows[:, 0], ns=rows[:, 1], cls=rows[:, 2],
             priority=rows[:, 3], creation=rows[:, 4],
-            node_id=node_id, node_name_req=rows[:, 5],
+            node_id=node_id, node_name_req=rows[:, 5], pin=rows[:, 6],
         )
 
     def build_gang_arrays(self, pending: Sequence[Pod], d: Dims,

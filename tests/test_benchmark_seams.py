@@ -53,6 +53,31 @@ def test_the_gang_cell_names_its_modules_and_they_are_there():
     assert (d.N, d.P, d.E, d.GR, d.SC) == (5120, 106496, 131072, 4096, 64)
 
 
+def test_the_daemon_cell_names_its_modules_and_its_capacities():
+    """ISSUE 49: `daemonset-5k.backlog` behind the seams: its four modules
+    are there by name, the device program is provisioned for the 20,000
+    waiting and the ~19,850 bound at the end, and NOTHING for a number of
+    pinned pods: SC, SN, STL are `DEFAULT_DIMS`' and the Dims defaults."""
+    from benchmarks.harness.wirings import local
+    from kubernetes_tpu.state.dims import Dims
+
+    name = "daemonset-5k.backlog"
+    c, cfg, tr = cell.find_cell(BENCH, name)
+    assert (c["traffic"], tr["kind"], c["chips"]) == (
+        "daemonset-restart-backlog", "daemon_backlog", 1)
+    plugs = cell.plug_ins(BENCH, "per_layer", name, cfg, tr)
+    assert plugs["shapes"].__name__.endswith("shapes.daemon_pods")
+    assert plugs["kind"].__name__.endswith("kinds.daemon_backlog")
+    assert plugs["wiring"].__name__.endswith("wirings.local_daemons")
+    assert [n for n, _m in plugs["checks"]] == ["placement", "daemons"]
+    d = local.serving_dims(cfg)
+    assert (d.N, d.P, d.E) == (5120, 20480, 32768) and "dims" not in cfg
+    assert (d.SC, d.SN, d.STL, d.F) == (
+        local.DEFAULT_DIMS["SC"], Dims().SN, Dims().STL, Dims().F)
+    assert name in next(m for m in BENCH["end_to_end"]
+                        if m["name"] == "drain_pods_per_s")["workloads"]
+
+
 def test_gang_jobs_are_the_same_table_whatever_the_seed():
     tables, names = set(), []
     for seed in (3, 2 ** 31 + 9):

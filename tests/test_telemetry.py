@@ -194,7 +194,7 @@ _BULK = ["pump", "pop", "snapshot", "prewarm", "dispatch", "readback",
 _BINDING = ["bind-commit/assume", "bind-commit/bind-call",
             "bind-commit/finish"]
 _FIRST_SNAPSHOT = ["snapshot/full", "snapshot/full/upload",
-                   "snapshot/prepare"]
+                   "snapshot/pins", "snapshot/prepare"]
 _HEAD = ["recorder", "t_start", "duration_s", "phases", "engine"]
 # what the collector did since the previous record (ISSUE 37): after the
 # record's own fields, before what the caller adds
@@ -225,6 +225,17 @@ _WAVE_SHAPES = {
                        "stats", "device_split", "children"] + _GC + [
                            "snapshot_mode", "waits"] + _ASSUMED + [
                            "minor_faults", "seq"]),
+    # a batch with pinned pods (ISSUE 49): how many, the classes that hold
+    # them and the live class count from the snapshot's `pins` stage; after
+    # the readback how many their node refused and the engine's rounds
+    "pinned": (_BULK,
+               _BINDING + _FIRST_SNAPSHOT,
+               _HEAD + ["bucket", "affinity_agg", "domain_sum", "stats",
+                        "device_split",
+                        "children"] + _GC + ["snapshot_mode", "waits"]
+               + _ASSUMED + ["pinned", "pin_classes", "classes",
+                             "pinned_unfit", "pin_rounds", "minor_faults",
+                             "seq"]),
     "paused": (["pump", "paused"], None,
                _HEAD + ["stats", "supervisor_events"] + _GC + ["seq"]),
     "abandoned": (["pump", "pop", "snapshot", "prewarm", "dispatch",
@@ -261,6 +272,15 @@ class TestRecordShape:
             s.on_pod_add(Pod(name="huge", creation_index=9, priority=5,
                              requests=Resources.make(cpu="4000",
                                                      memory="8Mi")))
+        elif kind == "pinned":
+            from kubernetes_tpu.api.types import (Affinity, NodeSelector,
+                                                  NodeSelectorTerm)
+            for i, node in enumerate(("node-1", "node-2", "gone")):
+                s.on_pod_add(Pod(
+                    name=f"daemon-{i}", creation_index=20 + i,
+                    requests=Resources.make(cpu="100m", memory="8Mi"),
+                    affinity=Affinity(node_required=NodeSelector(
+                        (NodeSelectorTerm((), (node,)),)))))
         elif kind == "paused":
             for _ in range(5):
                 s.governor.note_commit(False, 0.01)
@@ -278,6 +298,10 @@ class TestRecordShape:
         assert (sorted(rec["children"]) if "children" in rec else None) \
             == children
         assert list(rec) == keys
+        if kind == "pinned":
+            assert [rec[k] for k in ("pinned", "pin_classes", "pinned_unfit",
+                                     "pin_rounds")] == [3, 1, 1, 1]
+            assert rec["classes"] == len(s.encoder.class_reg)
         if "waits" in rec:
             assert set(rec["waits"]) == {"queue", "confirm"}
         if "bucket" in rec:
