@@ -31,6 +31,7 @@ from ..machinery import meta
 from ..sched.server import (
     APIBinder,
     decoded,
+    initial_lists,
     pod_schedulable_v1,
     start_informer,
 )
@@ -133,19 +134,23 @@ class ServedExtender:
             on_update=lambda old, new: self._on_pod(new),
             on_delete=self._on_pod_delete)
         tel = self.backend.telemetry
-        start_informer(self.node_informer, tel, "start/nodes-sync",
-                       "extender")
-        start_informer(self.pod_informer, tel, "start/pods-sync", "extender")
-        self._watch_plane()  # the initial lists are no pod's relists
-        # the compile-ahead files each program it runs on `trace.current()`
-        # (None with telemetry off)
-        ahead = trace.Trace("compile-ahead", clock=tel.clock)
-        token = trace.activate(ahead if tel.enabled else None)
-        try:
-            self.warm_log = self.backend.compile_ahead()
-        finally:
-            trace.deactivate(token)
-        tel.loop_stage("start/compile-ahead", below=ahead.record())
+        # the mirror's first full snapshot (the compile-ahead's `prepare`)
+        # lives as long as the mirror: it is built with the lists
+        with initial_lists(tel, "extender"):
+            start_informer(self.node_informer, tel, "start/nodes-sync",
+                           "extender")
+            start_informer(self.pod_informer, tel, "start/pods-sync",
+                           "extender")
+            self._watch_plane()  # the initial lists are no pod's relists
+            # the compile-ahead files each program it runs on
+            # `trace.current()` (None with telemetry off)
+            ahead = trace.Trace("compile-ahead", clock=tel.clock)
+            token = trace.activate(ahead if tel.enabled else None)
+            try:
+                self.warm_log = self.backend.compile_ahead()
+            finally:
+                trace.deactivate(token)
+            tel.loop_stage("start/compile-ahead", below=ahead.record())
         self.http.start()
         tel.loop_stage("start/socket")
         tel.loop_lap("start")

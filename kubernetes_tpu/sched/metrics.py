@@ -177,6 +177,13 @@ START_UNSYNCED = REG.counter(
     "made over a partial view, by component (scheduler, extender) and "
     "resource",
     labels=("component", "resource"))
+START_FROZEN = REG.gauge(
+    "scheduler_start_frozen_objects",
+    "Objects the last start moved out of the cyclic collector's walk when "
+    "its initial lists had synced (utils/platform.py listing_heap), by "
+    "component (scheduler, extender); 0 from a start that ended inside "
+    "another's",
+    labels=("component",))
 FAILED_EVENTS = REG.counter(
     "scheduler_failed_scheduling_events_total",
     "FailedScheduling event dispositions. The decision-provenance "

@@ -10,6 +10,7 @@ leader election like the reference binary (:254-260).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import queue
 import threading
@@ -31,7 +32,11 @@ from kubernetes_tpu.client.leaderelection import (
 )
 from kubernetes_tpu.component import trace
 from kubernetes_tpu.machinery import errors, meta
-from kubernetes_tpu.sched.metrics import FAILED_EVENTS, START_UNSYNCED
+from kubernetes_tpu.sched.metrics import (
+    FAILED_EVENTS,
+    START_FROZEN,
+    START_UNSYNCED,
+)
 from kubernetes_tpu.sched.scheduler import Scheduler
 
 logger = logging.getLogger("kubernetes_tpu.sched.server")
@@ -59,6 +64,25 @@ def start_informer(informer: SharedInformer, telemetry, stage: str,
                        component, resource)
         START_UNSYNCED.inc(component=component, resource=resource)
     return synced
+
+
+@contextlib.contextmanager
+def initial_lists(telemetry, component: str):
+    """The stretch of a server's start that lists the cluster, every
+    `start_informer` of it and what is built from their lists before the
+    first request: the cyclic collector stands aside for its length and
+    what it made leaves the collector's walk (utils/platform.py
+    `listing_heap`). What that took is kept on the start's account, as
+    `loop.start_frozen_objects` and `loop.start_collector_off_s` of the
+    first record, and on `scheduler_start_frozen_objects`."""
+    from kubernetes_tpu.utils.platform import listing_heap
+
+    with listing_heap() as took:
+        yield
+    START_FROZEN.set(took["frozen_objects"], component=component)
+    telemetry.loop_note(
+        start_frozen_objects=took["frozen_objects"],
+        start_collector_off_s=round(took["collector_off_s"], 6))
 
 
 def decoded(convert, obj: Obj):
@@ -849,17 +873,9 @@ class SchedulerServer:
         if isinstance(self.scheduler.binder, APIBinder):
             self.scheduler.binder.volume_binder = self.volume_binder
 
-    def start(self) -> "SchedulerServer":
-        from kubernetes_tpu.utils.platform import (enable_compile_cache,
-                                                   steady_heap)
-
-        enable_compile_cache()  # before the loop's first compile
-        steady_heap()  # before the first wave commits
-        # the loop's account of the time between waves begins here: the
-        # informers' list+sync below is the first wave's `start` phase,
-        # and each stretch of it a stage below that (`loop.children`)
-        tel = self.scheduler.telemetry
-        tel.loop_reset()
+    def _start_informers(self, tel) -> None:
+        """Every informer of the start, each listed and synced in turn: the
+        PDBs, the volumes' four, the nodes, the pods."""
         if self.scheduler.preemptor is not None \
                 and getattr(self.scheduler.preemptor, "pdb_source", None) \
                 is not None:
@@ -885,6 +901,20 @@ class SchedulerServer:
                        "scheduler")
         start_informer(self.pod_informer, tel, "start/pods-sync",
                        "scheduler")
+
+    def start(self) -> "SchedulerServer":
+        from kubernetes_tpu.utils.platform import (enable_compile_cache,
+                                                   steady_heap)
+
+        enable_compile_cache()  # before the loop's first compile
+        steady_heap()  # before the first wave commits
+        # the loop's account of the time between waves begins here: the
+        # informers' list+sync below is the first wave's `start` phase,
+        # and each stretch of it a stage below that (`loop.children`)
+        tel = self.scheduler.telemetry
+        tel.loop_reset()
+        with initial_lists(tel, "scheduler"):
+            self._start_informers(tel)
         self._watch_plane()  # the initial lists are no wave's relists
         if self.elector is not None:
             self.elector.start()

@@ -497,6 +497,7 @@ class SchedulerTelemetry:
         self._loop_lap_t = self._loop_stage_t = 0.0
         self._handlers: List[float] = [0, 0.0, 0.0]
         self._synced: Dict[str, bool] = {}
+        self._noted: Dict[str, Any] = {}
         # what the loop decided before the wave to come (note_gather)
         self._gather: Optional[Tuple[float, float]] = None
         # the collector's account, and where this recorder's previous
@@ -595,6 +596,7 @@ class SchedulerTelemetry:
         self._loop_lap_t = self._loop_stage_t = t
         self._handlers = [0, 0.0, 0.0]
         self._synced = {}
+        self._noted = {}
         self._stage_gc_mark = self._gc.mark()
 
     def loop_lap(self, name: str) -> None:
@@ -642,6 +644,13 @@ class SchedulerTelemetry:
         moving the mark the next stage is reckoned from."""
         if self._loop is not None:
             self._loop.child(path, seconds)
+
+    def loop_note(self, **fields: Any) -> None:
+        """Plain fields of the open account, beside its phases on the
+        record that closes it (`loop.start_frozen_objects`: what a server's
+        start says of itself that is no stretch of time)."""
+        if self._loop is not None:
+            self._noted.update(fields)
 
     def loop_account(self) -> Dict[str, List[float]]:
         """The open account as it stands, `{path: [count, total_s, max_s]}`:
@@ -723,6 +732,7 @@ class SchedulerTelemetry:
                         loop_rec["children"] = below
                     if self._synced:
                         loop_rec["synced"] = self._synced
+                    loop_rec.update(self._noted)
                 self._new_loop(t_end)
             events, self._pending_events = self._pending_events, []
             # this wave's own reading (or an untokened caller's); entries

@@ -6,6 +6,7 @@ multi-chip sharding tests use the 8 virtual devices. Set KTPU_TEST_TPU=1 to run
 the suite against the real chip instead.
 """
 
+import gc
 import os
 
 import pytest
@@ -48,3 +49,15 @@ def _release_compiled_programs():
         import jax
 
         jax.clear_caches()
+
+
+@pytest.fixture(autouse=True)
+def _thaw_what_a_start_froze():
+    """A server's start moves everything its process holds out of the cyclic
+    collector's walk (utils/platform.py `listing_heap`), and a server is
+    garbage only by a cycle (its handlers point back at it): frozen, a
+    test's stopped server and the cluster it listed would stay for the
+    worker's life, hundreds of them a worker. Hand them back after each
+    test; a process that serves starts once and keeps what it froze."""
+    yield
+    gc.unfreeze()
