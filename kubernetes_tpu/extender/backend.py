@@ -49,7 +49,7 @@ from ..api.v1 import node_from_v1, pod_from_v1
 from ..component import trace
 from ..sched.cycle import (_diagnose, _feasible, _scores,
                            snapshot_with_keys)
-from ..sched.telemetry import SchedulerTelemetry
+from ..sched.telemetry import SchedulerTelemetry, xla_scope
 from ..state.cache import SchedulerCache
 from ..state.dims import Dims
 from ..state.encode import Encoder
@@ -267,8 +267,15 @@ class ExtenderBackend:
         while the verb runs."""
         rec = self._enter(uid, key, verb)
         token = trace.activate(rec.span.trace) if rec else None
+        tel = self.telemetry
         try:
-            yield rec
+            # what the verb compiles (a Dims bucket the compile-ahead did
+            # not see) is the XLA account's under the verb, on the pod's
+            # record: the stock scheduler's `httpTimeout` is waiting
+            with xla_scope(f"extender/{verb}", on_path=True,
+                           seq=tel.recorder.next_seq(),
+                           sink=tel.note_supervisor_event):
+                yield rec
         finally:
             if token is not None:
                 trace.deactivate(token)
@@ -332,8 +339,11 @@ class ExtenderBackend:
         to the host; on a record, its launch / execute / readback split is
         added to the pod's."""
         t0 = time.perf_counter()
-        out = program(snap.tables, snap.pending, keys, snap.dims.D,
-                      snap.existing)
+        # a compile here is the enclosing scope's (the verb's, or the
+        # compile-ahead's), at these capacities
+        with xla_scope(None, (snap.dims,), ("dims",)):
+            out = program(snap.tables, snap.pending, keys, snap.dims.D,
+                          snap.existing)
         if rec is None:
             return jax.device_get(out)
         t1 = time.perf_counter()
@@ -368,7 +378,7 @@ class ExtenderBackend:
             finally:
                 tr.end(tok, time.perf_counter() - t0)
 
-        with self._mu:
+        with self._mu, xla_scope("compile-ahead", on_path=False):
             snap, keys = timed("snapshot", self._snapshot_for, Pod(
                 name="compile-ahead", namespace="kube-system"))
             warmed = []

@@ -417,6 +417,7 @@ class FleetServer:
 
         self.telemetry = SchedulerTelemetry(name="fleet")
         self.supervisor.event_sink = self.telemetry.note_supervisor_event
+        self.supervisor.wave_seq = self.telemetry.recorder.next_seq
         # the resident stacked tables every tick's dispatch runs on
         self.stack = FleetStack(mesh=self.mesh)
         self._fleet_dims: Dims = replace(base_dims or Dims(),
@@ -713,13 +714,19 @@ class FleetServer:
         span.mark("pump")
 
         from ..sched.supervisor import DispatchAbandonedError
+        from ..sched.telemetry import xla_scope
 
         # batches are popped: from here to the dispatch result, EVERY
         # failure path must hand them back to their queues — losing them
         # is the one thing a scheduler may never do
         try:
-            out, exp, snaps = self._dispatch_tick(tlist, batches, tick, now,
-                                                  span)
+            # what the tick compiles on this thread (the snapshot round, the
+            # stack's refresh) is the XLA account's under `tick`
+            with xla_scope("tick", on_path=True,
+                           seq=self.telemetry.recorder.next_seq(),
+                           sink=self.telemetry.note_supervisor_event):
+                out, exp, snaps = self._dispatch_tick(tlist, batches, tick,
+                                                      now, span)
         except DispatchAbandonedError:
             # the abandoned worker's zombie thread may still hold (or be
             # executing on) the resident stacked buffers — never donate or

@@ -24,6 +24,7 @@ from ..ops.lattice import build_cycle, default_engine_config
 from ..state.arrays import ClusterTables, PodArrays
 from ..state.dims import Dims
 from ..state.encode import Encoder
+from .telemetry import xla_scope
 
 UNSCHEDULABLE_TAINT_KEY = "node.kubernetes.io/unschedulable"  # predicates.go:1522-1541
 
@@ -36,11 +37,15 @@ def snapshot_with_keys(cache, encoder: Encoder, pending, base_dims,
     routes the arrays to an explicit placement (the supervisor's degraded
     mode: everything onto the CPU fallback, nothing on the lost backend);
     `mesh` routes them to mesh-resident sharded placement instead (the live
-    multichip serving path — state/cache.py keeps the tables resident)."""
-    snap = cache.snapshot(encoder, pending, base_dims,
-                          extra_intern=(UNSCHEDULABLE_TAINT_KEY,),
-                          device=device, mesh=mesh)
-    return snap, _taint_scalars(encoder, device, mesh)
+    multichip serving path — state/cache.py keeps the tables resident).
+    What it compiles (a fresh patch rung, an eager scalar) is the XLA
+    account's under `snapshot`, whoever waits being the enclosing scope's
+    (sched/telemetry.py)."""
+    with xla_scope("snapshot"):
+        snap = cache.snapshot(encoder, pending, base_dims,
+                              extra_intern=(UNSCHEDULABLE_TAINT_KEY,),
+                              device=device, mesh=mesh)
+        return snap, _taint_scalars(encoder, device, mesh)
 
 
 def micro_snapshot_with_keys(cache, encoder: Encoder, pending, base_dims,
@@ -56,12 +61,13 @@ def micro_snapshot_with_keys(cache, encoder: Encoder, pending, base_dims,
     only the pending identity signature, so each direction's first
     snapshot after a flip rebuilds one pending block and nothing else."""
     encoder.intern_pods(pending)
-    base = cache.snapshot(encoder, [], base_dims,
-                          extra_intern=(UNSCHEDULABLE_TAINT_KEY,),
-                          device=device, mesh=mesh)
-    snap = cache.micro_graft(encoder, pending, base, micro_p,
-                             device=device, mesh=mesh)
-    return snap, _taint_scalars(encoder, device, mesh)
+    with xla_scope("snapshot"):
+        base = cache.snapshot(encoder, [], base_dims,
+                              extra_intern=(UNSCHEDULABLE_TAINT_KEY,),
+                              device=device, mesh=mesh)
+        snap = cache.micro_graft(encoder, pending, base, micro_p,
+                                 device=device, mesh=mesh)
+        return snap, _taint_scalars(encoder, device, mesh)
 
 
 def _taint_scalars(encoder: Encoder, device, mesh):

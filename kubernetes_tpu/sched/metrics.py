@@ -184,6 +184,21 @@ START_FROZEN = REG.gauge(
     "component (scheduler, extender); 0 from a start that ended inside "
     "another's",
     labels=("component",))
+XLA_PROGRAMS = REG.counter(
+    "scheduler_xla_programs_total",
+    "Programs this process compiled or loaded from the persistent cache "
+    "(one a backend-compile event of jax; sched/telemetry.py XlaAccount), "
+    "by the stage whose scope the compiling thread was in (cycle, preempt, "
+    "scores, prewarm, patch-ladder, compile-ahead, ...; none: a site "
+    "nobody wrapped) and the cache's verdict (hit, miss, unstored: "
+    "compiled in under the minimum compile time and never stored, off)",
+    labels=("stage", "cache"))
+XLA_SECONDS = REG.counter(
+    "scheduler_xla_compile_seconds_total",
+    "Seconds this process spent making programs, by stage and part: "
+    "trace (Python to jaxpr), lower (jaxpr to MLIR), backend (XLA's "
+    "compile, or the load of a cached executable)",
+    labels=("stage", "part"))
 FAILED_EVENTS = REG.counter(
     "scheduler_failed_scheduling_events_total",
     "FailedScheduling event dispositions. The decision-provenance "

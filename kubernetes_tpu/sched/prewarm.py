@@ -29,6 +29,12 @@ from dataclasses import replace
 from typing import Callable, Optional
 
 from ..state.dims import Dims
+from .telemetry import xla_scope
+
+#: the parts of `_cycle_key` / `_preempt_key`: what the XLA account names a
+#: background compile's signature by (sched/telemetry.py `XlaAccount.scope`)
+_CYCLE_KEY_NAMES = ("dims", "engine", "extras", "gang", "fleet", "mesh")
+_PREEMPT_KEY_NAMES = ("program", "dims", "burst", "mesh")
 
 # axes that grow monotonically in a live cluster and cross buckets: nodes,
 # bound pods. (P — the pending batch — is bounded by batch_size and churns
@@ -272,31 +278,13 @@ class BucketPrewarmer:
         try:
             from ..utils import faultline
             from ..utils.faultline import InjectedDeviceError
-            from .cycle import _schedule_batch_impl
 
             if faultline.should("device.error", "prewarm"):
                 raise InjectedDeviceError(
                     "injected XlaRuntimeError at prewarm")
-            if fleet is not None:
-                # a tenant-stack program (fleet/cycle.py): K virtual
-                # clusters per dispatch — a structurally different
-                # executable from the single-cluster one at the same dims
-                from ..fleet.cycle import _fleet_cycle_impl
-                from ..fleet.tables import abstract_fleet_args
-
-                (tables, pending, keys, existing, quota,
-                 hw, ecfg) = abstract_fleet_args(d, int(fleet), mesh=mesh)
-                compiled = _fleet_cycle_impl.lower(
-                    tables, pending, keys, d.D, existing, engine, quota,
-                    hw, ecfg,
-                ).compile()
-            else:
-                (tables, pending, keys, existing, hw, ecfg,
-                 gang_args) = abstract_cycle_args(d, gang=gang, mesh=mesh)
-                compiled = _schedule_batch_impl.lower(
-                    tables, pending, keys, d.D, existing, engine, hw, ecfg,
-                    extras, tuple(1.0 for _ in extras), gang_args,
-                ).compile()
+            with xla_scope("prewarm", key, _CYCLE_KEY_NAMES, on_path=False):
+                compiled = self._lower_cycle(d, engine, extras, gang, mesh,
+                                             fleet).compile()
             with self._mu:
                 if epoch != self._epoch:
                     # invalidate() ran mid-compile (backend loss): this
@@ -315,6 +303,31 @@ class BucketPrewarmer:
                 self._warmed.discard(key)
             if self.supervisor is not None:
                 self.supervisor.note_compile_failure(e)
+
+    @staticmethod
+    def _lower_cycle(d: Dims, engine: str, extras: tuple, gang: bool,
+                     mesh, fleet):
+        """The cycle program at this signature, lowered from abstract
+        shapes alone."""
+        from .cycle import _schedule_batch_impl
+
+        if fleet is not None:
+            # a tenant-stack program (fleet/cycle.py): K virtual clusters
+            # per dispatch — a structurally different executable from the
+            # single-cluster one at the same dims
+            from ..fleet.cycle import _fleet_cycle_impl
+            from ..fleet.tables import abstract_fleet_args
+
+            (tables, pending, keys, existing, quota,
+             hw, ecfg) = abstract_fleet_args(d, int(fleet), mesh=mesh)
+            return _fleet_cycle_impl.lower(
+                tables, pending, keys, d.D, existing, engine, quota,
+                hw, ecfg)
+        (tables, pending, keys, existing, hw, ecfg,
+         gang_args) = abstract_cycle_args(d, gang=gang, mesh=mesh)
+        return _schedule_batch_impl.lower(
+            tables, pending, keys, d.D, existing, engine, hw, ecfg,
+            extras, tuple(1.0 for _ in extras), gang_args)
 
     def lookup(self, d: Dims, engine: str, extras: tuple, gang: bool,
                mesh=None, fleet=None):
@@ -497,11 +510,12 @@ class BucketPrewarmer:
             if faultline.should("device.error", "prewarm"):
                 raise InjectedDeviceError(
                     "injected XlaRuntimeError at prewarm")
-            (tables, existing, cls, nnr, prio, keys, pdb,
-             hw, ecfg) = abstract_preempt_args(d, burst, mesh=mesh)
-            compiled = _preempt.lower(
-                tables, existing, cls, nnr, prio, d.D, keys, pdb, hw, ecfg,
-            ).compile()
+            with xla_scope("prewarm", key, _PREEMPT_KEY_NAMES, on_path=False):
+                (tables, existing, cls, nnr, prio, keys, pdb,
+                 hw, ecfg) = abstract_preempt_args(d, burst, mesh=mesh)
+                compiled = _preempt.lower(
+                    tables, existing, cls, nnr, prio, d.D, keys, pdb, hw,
+                    ecfg).compile()
             with self._mu:
                 if epoch != self._epoch:
                     self._warmed.discard(key)  # invalidated mid-compile

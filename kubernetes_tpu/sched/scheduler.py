@@ -41,7 +41,7 @@ from .cycle import (UNSCHEDULABLE_TAINT_KEY, _schedule_batch,
 from .metrics import BINDING_DURATION, MICRO_WAVES
 from .queue import PriorityQueue
 from .supervisor import DispatchAbandonedError
-from .telemetry import _NULL_SPAN
+from .telemetry import _NULL_SPAN, xla_scope
 
 
 class Binder(Protocol):
@@ -295,6 +295,7 @@ class Scheduler:
         if self.telemetry.enabled:
             self.queue.tracker = self.telemetry.tracker
         self.supervisor.event_sink = self.telemetry.note_supervisor_event
+        self.supervisor.wave_seq = self.telemetry.recorder.next_seq
         # overload governor (sched/overload.py, ISSUE 9): brownout modes,
         # priority-aware shedding into the queue's deferred lane, adaptive
         # wave sizing, and the commit-path circuit breaker. None when
@@ -572,7 +573,13 @@ class Scheduler:
         wave = Wave(now, t0, span,
                     minor_faults0=_minor_faults() if span.enabled else 0)
         try:
-            return self._run_wave(wave, micro_only)
+            # what the wave compiles on THIS thread (a snapshot's fresh
+            # patch rung, an eager scalar) is the XLA account's under
+            # `wave`; the dispatch worker enters the supervisor's scope
+            with xla_scope("wave", on_path=True,
+                           seq=self.telemetry.recorder.next_seq(),
+                           sink=self.telemetry.note_supervisor_event):
+                return self._run_wave(wave, micro_only)
         except Exception:
             # a wave that DIES mid-flight is exactly the tick the flight
             # recorder exists to explain: record what ran before the raise

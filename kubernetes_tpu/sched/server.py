@@ -341,6 +341,10 @@ class TelemetryGateway:
                              (read-only: the same document shape an
                              auto-dump writes, with none of the dump
                              side effects)
+      /debug/compiles        what XLA cost this process: the account's
+                             four totals and its kept entries, oldest
+                             first (sched/telemetry.py XlaAccount; ISSUE
+                             51): which program, for whom, and why
       /debug/why/<ns>/<pod>  the pod's latest decision attribution
                              (ISSUE 10: reason counts, top-k candidates
                              with score decomposition, queue lane +
@@ -401,6 +405,14 @@ class TelemetryGateway:
                     # count as a dump, or write KTPU_FLIGHT_DIR files
                     body = _json.dumps(
                         tel.snapshot_doc("debug-endpoint"), indent=1).encode()
+                    ctype = "application/json"
+                elif path == "/debug/compiles":
+                    from kubernetes_tpu.sched.telemetry import xla_account
+
+                    acct = xla_account()
+                    body = _json.dumps(
+                        {"totals": acct.totals(),
+                         "entries": acct.entries()}, indent=1).encode()
                     ctype = "application/json"
                 elif path.startswith("/debug/why/") and sched is not None:
                     parts = [p for p in path.split("/") if p][2:]

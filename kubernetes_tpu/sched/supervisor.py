@@ -57,10 +57,22 @@ from jax.errors import JaxRuntimeError
 
 from ..utils import faultline
 from ..utils.faultline import InjectedDeviceError
+from .telemetry import xla_scope
 
 #: exception classes that indicate the BACKEND failed (vs a bug in the
 #: dispatched function, which must propagate to the caller unchanged)
 DEVICE_ERRORS: Tuple[type, ...] = (JaxRuntimeError, InjectedDeviceError)
+
+
+#: the parts of the `shape_key` each kind of supervised call is given, by
+#: (kind, parts): what the XLA account names a signature's fields by
+#: (sched/telemetry.py `XlaAccount.scope`; positions where none fits)
+_SIG_NAMES: Dict[Tuple[str, int], Tuple[str, ...]] = {
+    ("cycle", 5): ("dims", "engine", "extras", "gang", "mesh"),
+    ("cycle", 4): ("dims", "engine", "fleet", "mesh"),
+    ("scores", 3): ("dims", "extras", "mesh"),
+    ("preempt", 3): ("dims", "burst", "mesh"),
+}
 
 
 class DispatchAbandonedError(RuntimeError):
@@ -164,6 +176,9 @@ class DispatchSupervisor:
         # dump artifact. Called from the serving loop AND worker threads;
         # a raising sink must never take the ladder down.
         self.event_sink: Optional[Callable[[str, str], None]] = None
+        # the `seq` the record of the wave in flight will get (the
+        # telemetry's `recorder.next_seq`), for the XLA account's entries
+        self.wave_seq: Optional[Callable[[], int]] = None
         self.stats = SupervisorStats()
         self._mu = threading.Lock()
         self._healthy = True
@@ -442,6 +457,11 @@ class DispatchSupervisor:
         if not self._healthy:
             h._primary_skipped = True
             return h
+        # whatever `fn` compiles is the XLA account's under this call's
+        # kind and key, entered ON the worker (jax reports a compile on the
+        # thread that runs it), with the supervisor's own verdict: no
+        # budget for the key yet is a cold call
+        scope = self._xla_scope(h, cold=(kind, shape_key) not in self._budgets)
         if self._primary_device is None:
             try:
                 import jax
@@ -464,13 +484,23 @@ class DispatchSupervisor:
                 if faultline.should("device.oom", kind):
                     raise InjectedDeviceError(
                         f"RESOURCE_EXHAUSTED: injected device OOM at {kind}")
-                h._set_result(fn())
+                with scope:
+                    h._set_result(fn())
             except BaseException as e:  # noqa: BLE001 - ferried to caller
                 h._set_error(e)
 
         threading.Thread(target=work, name=f"ktpu-dispatch-{kind}",
                          daemon=True).start()
         return h
+
+    def _xla_scope(self, h: _Handle, cold: Optional[bool] = None,
+                   stage: Optional[str] = None):
+        key = h.shape_key
+        names = _SIG_NAMES.get((h.kind, len(key)), ()) \
+            if isinstance(key, tuple) else ()
+        return xla_scope(stage or h.kind, key, names, on_path=True, cold=cold,
+                         seq=self.wave_seq() if self.wave_seq else None,
+                         sink=self._emit)
 
     def run(self, kind: str, shape_key, fn: Callable[[], Any],
             fallback: Optional[Callable[[Any], Any]] = None) -> Any:
@@ -536,7 +566,8 @@ class DispatchSupervisor:
             # hung=True tells the fallback the primary's buffers are
             # untouchable (a transfer from a wedged runtime blocks forever
             # with no watchdog): rebuild from host state instead
-            out = h.fallback(dev, hung)
+            with self._xla_scope(h, stage=f"{h.kind}/fallback"):
+                out = h.fallback(dev, hung)
         except Exception as e:  # noqa: BLE001 - the ladder ends here
             self.stats.abandoned += 1
             self._emit("abandoned",

@@ -43,7 +43,13 @@ the stage that waited for it) and `waits` (how long the popped pods had
 queued; how long Binding confirmations took to come back through the
 informer). Every record also says what the interpreter's collector did
 since the record before it: `gc_full_collections`, `gc_pause_s`,
-`gc_max_pause_s`, `gc_max_pause_at` (`GcAccount`, one hook a process).
+`gc_max_pause_s`, `gc_max_pause_at` (`GcAccount`, one hook a process), and
+what XLA cost the process: `xla_total` (programs compiled or loaded, cache
+misses, backend and trace+lower seconds since the hook went in) and, where
+any ended since the record before, `xla_compiled`: each trace, lowering,
+cache verdict and compile folded into one entry with who asked, at what
+signature and what set it apart (`XlaAccount`, one listener pair a process;
+ISSUE 51).
 
 Kill switch: ``KTPU_TELEMETRY=0`` turns every tier into a no-op (the
 `latency` bench stage uses it to bound telemetry overhead at <2% of the
@@ -52,10 +58,13 @@ untelemetered flagship pods/s).
 
 from __future__ import annotations
 
+import dataclasses
 import gc
 import gzip
 import json
+import logging
 import os
+import re
 import threading
 import time
 from collections import deque
@@ -63,7 +72,10 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..component.metrics import DEFAULT_REGISTRY, Counter
 from ..component.trace import Trace
-from .metrics import FLIGHT_DUMPS, POD_E2E_LATENCY, SCHEDULING_DURATION
+from .metrics import (FLIGHT_DUMPS, POD_E2E_LATENCY, SCHEDULING_DURATION,
+                      XLA_PROGRAMS, XLA_SECONDS)
+
+logger = logging.getLogger("kubernetes_tpu.sched.telemetry")
 
 #: supervisor/tick event kinds that auto-dump the ring when they appear on
 #: a wave record (the "explainable without logs" triggers of ISSUE 7),
@@ -237,6 +249,10 @@ class FlightRecorder:
     def records(self) -> List[Dict[str, Any]]:
         with self._mu:
             return list(self._ring)
+
+    def next_seq(self) -> int:
+        """The `seq` the next record will get: the wave in flight's."""
+        return self._seq + 1
 
     def snapshot(self, trigger: str) -> Dict[str, Any]:
         with self._mu:
@@ -456,6 +472,350 @@ def gc_account() -> GcAccount:
         return _GC
 
 
+# --------------------------------------------------------------------- #
+# the XLA account (ISSUE 51): which program, for whom, and why
+# --------------------------------------------------------------------- #
+
+#: jax.monitoring's names (jax 0.9.0), in the order one program fires them
+#: on the thread that compiles it
+_XLA_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_XLA_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_XLA_BACKEND = "/jax/core/compile/backend_compile_duration"
+_XLA_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+_XLA_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_XLA_VERDICT = {"/jax/compilation_cache/cache_hits": "hit",
+                "/jax/compilation_cache/cache_misses": "miss"}
+#: an on-path entry at least this long is a WARNING on the log
+XLA_WARN_S = 1.0
+_JIT_OF = re.compile(r"^\w+\((.*)\)$")   # "jit(f)" -> "f"
+
+
+class _XlaScope:
+    """Who asks, on this thread, from `__enter__` to `__exit__`: what an
+    entry that ends inside is filed under. One thread-local store on the
+    way in, one restore on the way out; nothing else runs unless jax
+    compiles."""
+
+    __slots__ = ("account", "stage", "sig", "names", "on_path", "cold",
+                 "seq", "sink", "_outer")
+
+    def __init__(self, account, stage, sig, names, on_path, cold, seq, sink):
+        self.account = account
+        self.stage, self.sig, self.names = stage, sig, names
+        self.on_path, self.cold, self.seq, self.sink = \
+            on_path, cold, seq, sink
+
+    def __enter__(self):
+        local = self.account._local
+        outer = self._outer = getattr(local, "scope", None)
+        if outer is not None:
+            # what this scope leaves open is the enclosing one's: a
+            # narrower scope names the key, its caller who waits
+            for field in ("stage", "on_path", "cold", "seq", "sink"):
+                if getattr(self, field) is None:
+                    setattr(self, field, getattr(outer, field))
+        local.scope = self
+        return self
+
+    def __exit__(self, *exc):
+        self.account._local.scope = self._outer
+        return False
+
+
+def _plain(v):
+    """A signature's value as JSON keeps it."""
+    if v is None or isinstance(v, (bool, int, float, str)):
+        return v
+    if isinstance(v, (tuple, list)):
+        return [_plain(x) for x in v]
+    return str(v)[:120]
+
+
+def _sig_fields(sig, names) -> Optional[Dict[str, Any]]:
+    """A scope's key by field: a dataclass among its parts (the `Dims`) by
+    its own field names, the others under `names` (positions where the
+    names do not fit the key)."""
+    if sig is None:
+        return None
+    if isinstance(sig, dict):
+        items = list(sig.items())
+    else:
+        parts = sig if isinstance(sig, tuple) else (sig,)
+        if len(names) != len(parts):
+            names = tuple(f"k{i}" for i in range(len(parts)))
+        items = list(zip(names, parts))
+    out: Dict[str, Any] = {}
+    for name, v in items:
+        if dataclasses.is_dataclass(v) and not isinstance(v, type):
+            for f in dataclasses.fields(v):
+                out[f.name] = _plain(getattr(v, f.name))
+        else:
+            out[name] = _plain(v)
+    return out
+
+
+class XlaAccount:
+    """What XLA cost this process, a program at a time: every trace,
+    lowering, persistent-cache verdict and backend compile (or load) that
+    jax reports, folded per thread into ONE entry a program, with the scope
+    it ran under. ONE `jax.monitoring` listener pair a process
+    (`xla_account()`), called by jax on the compiling thread and only when
+    it traces or compiles: nothing here runs on a wave that compiles
+    nothing.
+
+    An entry: `fun`, `stage` (None: a site nobody wrapped), `sig`,
+    `differs` (`{field: [nearest, this]}` against the nearest earlier
+    signature of the same `fun` and `stage`; None for the first), `cache`
+    (`hit` / `miss` / `unstored`: asked, not found, and compiled in under
+    `jax_persistent_cache_min_compile_time_secs`, so never stored / `off`:
+    jax asked no cache),
+    `trace_s`, `lower_s`, `backend_s`, `saved_s`, `cold`, `on_path`,
+    `thread`, `t_start` / `t_end` on `clock`, `seq`. The newest `KEEP`
+    entries are kept, and four totals since the hook went in."""
+
+    KEEP = 256
+    #: earlier signatures remembered a (fun, stage), newest last
+    NEAREST = 32
+
+    def __init__(self, clock: Callable[[], float] = time.perf_counter) -> None:
+        self.clock = clock
+        self._mu = threading.Lock()
+        self._local = threading.local()   # .scope, .making
+        self._entries: deque = deque(maxlen=self.KEEP)
+        self._seen: Dict[Tuple[str, Optional[str]], deque] = {}
+        # entries ever finished, one a backend event: `mark()`, and the
+        # total `programs`
+        self.count = self.cache_misses = 0
+        self.backend_s = self.trace_lower_s = 0.0
+
+    # -- who asks -- #
+
+    def scope(self, stage: Optional[str], sig=None, names: tuple = (), *,
+              on_path: Optional[bool] = None, cold: Optional[bool] = None,
+              seq: Optional[int] = None,
+              sink: Optional[Callable[[str, str], None]] = None):
+        """A context for the calling thread: entries that end inside it are
+        filed under `stage` at `sig` (a key as the caller already builds
+        it; `names` for its parts). `on_path`: a started server's dispatch
+        waits for this thread; `cold`: the supervisor had no budget for the
+        key; `seq`: the record the wave in flight will get; `sink`: where
+        an on-path entry is narrated (`note_supervisor_event`). All but
+        `sig` default to the enclosing scope's."""
+        return _XlaScope(self, stage, sig, names, on_path, cold, seq, sink)
+
+    # -- jax's side: the two listeners -- #
+
+    def on_duration(self, event: str, seconds: float, **kw) -> None:
+        fold = self._FOLDS.get(event)
+        if fold is None:
+            return
+        try:
+            fold(self, kw.get("fun_name", "?"), seconds)
+        except Exception:  # noqa: BLE001 - the account never breaks a compile
+            logger.debug("xla account: %s", event, exc_info=True)
+
+    def on_event(self, event: str, **kw) -> None:  # noqa: ARG002
+        # asked, and so far neither found nor stored: jax reports a miss
+        # where it WRITES the entry, so a compile under the minimum compile
+        # time is asked for and never heard of again
+        if event == _XLA_ASKED:
+            self._making().setdefault("cache", "unstored")
+        elif event in _XLA_VERDICT:
+            self._making()["cache"] = _XLA_VERDICT[event]
+
+    def _making(self) -> Dict[str, Any]:
+        """What this thread has of the program it is making: `traces` (by
+        name), the lowering (`fun`, `lower_s`), `cache`, `saved_s`."""
+        making = getattr(self._local, "making", None)
+        if making is None:
+            making = self._local.making = {"traces": {}}
+        return making
+
+    def _on_saved(self, _fun: str, seconds: float) -> None:
+        self._making()["saved_s"] = seconds
+
+    def _on_trace(self, fun: str, seconds: float) -> None:
+        # kept by name until the lowering that names it: a program's trace
+        # ends after those of every jit traced inside it, and the lowering
+        # itself traces helpers before it reports
+        self._making()["traces"][fun] = (seconds, self.clock() - seconds)
+
+    def _on_lower(self, module: str, seconds: float) -> None:
+        self._making().update(fun=_JIT_OF.sub(r"\1", module),
+                              lower_s=seconds)
+
+    def _on_backend(self, module: str, seconds: float) -> None:
+        making, self._local.making = self._making(), None
+        fun, lower_s = _JIT_OF.sub(r"\1", module), making.get("lower_s", 0.0)
+        if making.get("fun") != fun:
+            lower_s = 0.0   # `.compile()` of what another thread lowered
+        # no trace by that name: a jaxpr traced earlier, lowered anew
+        trace_s, began = making["traces"].get(
+            fun, (0.0, self.clock() - seconds - lower_s))
+        self._finish(fun, began, trace_s, lower_s, seconds,
+                     making.get("cache", "off"), making.get("saved_s"))
+
+    def _finish(self, fun: str, began: float, trace_s: float, lower_s: float,
+                backend_s: float, cache: str,
+                saved_s: Optional[float]) -> None:
+        scope = getattr(self._local, "scope", None)
+        stage = scope.stage if scope is not None else None
+        sig = _sig_fields(scope.sig, scope.names) if scope is not None \
+            else None
+        entry = {
+            "fun": fun, "stage": stage, "sig": sig,
+            "differs": None, "cache": cache,
+            "trace_s": round(trace_s, 6),
+            "lower_s": round(lower_s, 6),
+            "backend_s": round(backend_s, 6),
+            "saved_s": None if saved_s is None else round(saved_s, 6),
+            "cold": scope.cold if scope is not None else None,
+            "on_path": bool(scope is not None and scope.on_path),
+            "thread": threading.current_thread().name,
+            "t_start": round(began, 6),
+            "t_end": round(self.clock(), 6),
+            "seq": scope.seq if scope is not None else None,
+        }
+        with self._mu:
+            seen = self._seen.setdefault((fun, stage),
+                                         deque(maxlen=self.NEAREST))
+            if sig is not None:
+                entry["differs"] = _differs(seen, sig)
+                seen.append(sig)
+            self._entries.append(entry)
+            self.count += 1
+            self.backend_s += backend_s
+            if cache == "miss":
+                self.cache_misses += 1
+            self.trace_lower_s += trace_s + lower_s
+        label = stage or "none"
+        XLA_PROGRAMS.inc(stage=label, cache=cache)
+        XLA_SECONDS.inc(backend_s, stage=label, part="backend")
+        XLA_SECONDS.inc(trace_s, stage=label, part="trace")
+        XLA_SECONDS.inc(lower_s, stage=label, part="lower")
+        on_path = entry["on_path"]
+        loud = on_path and backend_s + trace_s + lower_s > XLA_WARN_S
+        if not (on_path or loud or logger.isEnabledFor(logging.INFO)):
+            return
+        line = describe_compile(entry)
+        logger.log(logging.WARNING if loud else logging.INFO, "%s", line)
+        if on_path and scope.sink is not None:
+            try:
+                scope.sink("compile", line)
+            except Exception:  # noqa: BLE001 - narration never breaks jax
+                pass
+
+    _FOLDS = {_XLA_TRACE: _on_trace, _XLA_LOWER: _on_lower,
+              _XLA_BACKEND: _on_backend, _XLA_SAVED: _on_saved}
+
+    # -- the readers' side -- #
+
+    def mark(self) -> int:
+        """Where the account stands: what `since` takes."""
+        return self.count
+
+    def since(self, mark: int) -> Tuple[Dict[str, Any], int]:
+        """A record's `xla_*` fields for the interval that began at `mark`,
+        and the mark the next interval begins at: `xla_total` (the totals
+        as they stand: process-wide, so an interval is the difference of
+        two records) and, where any entry ended since `mark` and is still
+        kept, `xla_compiled` (oldest first)."""
+        with self._mu:
+            fields: Dict[str, Any] = {"xla_total": self._totals()}
+            fresh = min(self.count - mark, len(self._entries))
+            if fresh > 0:
+                fields["xla_compiled"] = \
+                    list(self._entries)[len(self._entries) - fresh:]
+            return fields, self.count
+
+    def entries(self) -> List[Dict[str, Any]]:
+        """The kept entries, oldest first."""
+        with self._mu:
+            return list(self._entries)
+
+    def totals(self) -> Dict[str, float]:
+        """The four running totals since the hook went in: `programs`
+        (backend events: compiles and loads), `cache_misses` (programs
+        compiled and stored: jax counts a miss where it writes),
+        `backend_s`, `trace_lower_s`."""
+        with self._mu:
+            return self._totals()
+
+    def _totals(self) -> Dict[str, float]:
+        return {"programs": self.count,
+                "cache_misses": self.cache_misses,
+                "backend_s": round(self.backend_s, 6),
+                "trace_lower_s": round(self.trace_lower_s, 6)}
+
+
+def _differs(seen, sig: Dict[str, Any]) -> Optional[Dict[str, List[Any]]]:
+    """`{field: [nearest, this]}` against the earlier signature that
+    differs from `sig` in the fewest fields (the newest of equals); None
+    where there is none."""
+    best = None
+    for old in reversed(seen):
+        diff = {k: [old.get(k), sig.get(k)]
+                for k in {**old, **sig} if old.get(k) != sig.get(k)}
+        if best is None or len(diff) < len(best):
+            best = diff
+    return best
+
+
+def describe_compile(entry: Dict[str, Any]) -> str:
+    """One line an entry, for the log and the supervisor event: who waited
+    how long for what, the cache's verdict, and what set the signature
+    apart from the nearest one this process had."""
+    took = entry["backend_s"] + entry["trace_s"] + entry["lower_s"]
+    who = f"wave {entry['seq']}" if entry["seq"] is not None \
+        else f"thread {entry['thread']}"
+    verb = "waited" if entry["on_path"] else "spent"
+    differs = entry["differs"]
+    if differs is None:
+        why = "first signature of this program here"
+    elif not differs:
+        why = "the signature of an earlier program"
+    else:
+        why = "differs from the nearest earlier program in " + ", ".join(
+            f"{k} {a} -> {b}" for k, (a, b) in sorted(differs.items()))
+    cold = {True: ", cold", False: ", warm key"}.get(entry["cold"], "")
+    return (f"{who} {verb} {took:.3f} s for `{entry['fun']}` "
+            f"(stage {entry['stage']}{cold}): cache {entry['cache']}, "
+            f"trace {entry['trace_s']:.3f} lower {entry['lower_s']:.3f} "
+            f"backend {entry['backend_s']:.3f}; {why}")
+
+
+_XLA: Optional[XlaAccount] = None
+_XLA_MU = threading.Lock()
+
+
+def xla_account() -> XlaAccount:
+    """The process's `XlaAccount`. Its listener pair goes in with the
+    first call that finds telemetry on (`utils/platform.py
+    enable_compile_cache`, which every entry point calls before its first
+    compile; the first enabled `SchedulerTelemetry` otherwise):
+    `KTPU_TELEMETRY=0` installs none, and the account then stays empty and
+    its scopes are thread-local stores nobody reads."""
+    global _XLA
+    if _XLA is not None:   # every scope comes through here
+        return _XLA
+    with _XLA_MU:
+        if _XLA is None:
+            _XLA = XlaAccount()
+            if os.environ.get("KTPU_TELEMETRY", "1") not in ("0", "off"):
+                import jax.monitoring
+
+                jax.monitoring.register_event_duration_secs_listener(
+                    _XLA.on_duration)
+                jax.monitoring.register_event_listener(_XLA.on_event)
+        return _XLA
+
+
+def xla_scope(stage: str, sig=None, names: tuple = (), **who):
+    """`xla_account().scope(...)`: the context a compile site enters round
+    the call that may compile, with the key it already builds."""
+    return xla_account().scope(stage, sig, names, **who)
+
+
 class SchedulerTelemetry:
     """The scheduler-wide observability layer: one per Scheduler (and one
     per FleetServer). Thread-aware: supervisor events and the device-time
@@ -506,6 +866,11 @@ class SchedulerTelemetry:
         self._gc = gc_account() if enabled else None
         self._gc_mark = self._stage_gc_mark = \
             self._gc.mark() if enabled else None
+        # the XLA account, and where this recorder's previous record ended
+        # on it (a new recorder's first record carries what the process
+        # compiled since it was made; `xla_total` what it paid in all)
+        self._xla = xla_account() if enabled else None
+        self._xla_mark = self._xla.mark() if enabled else 0
         self.last_dump: Optional[Dict[str, Any]] = None
         self.dumps = 0
         # KTPU_PROFILE=<dir>: jax.profiler trace capture around dispatches
@@ -743,6 +1108,8 @@ class SchedulerTelemetry:
             # what the collector did since the previous record ended
             gc_fields = self._gc.since(self._gc_mark)
             self._gc_mark = self._gc.mark()
+            # and what XLA cost the process, with the programs it made
+            xla_fields, self._xla_mark = self._xla.since(self._xla_mark)
         rec: Dict[str, Any] = {
             "recorder": self.name,
             "t_start": round(span.trace.start, 6),
@@ -794,6 +1161,7 @@ class SchedulerTelemetry:
         if fleet is not None:
             rec["fleet"] = fleet
         rec.update(gc_fields)
+        rec.update(xla_fields)
         if extra:
             rec.update(extra)
         self.recorder.record(rec)

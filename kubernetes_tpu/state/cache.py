@@ -833,8 +833,17 @@ class SchedulerCache:
         signatures compiled by THIS call; repeat calls are cheap
         (memoized on plane shapes). Safe to run from a background thread —
         jit dispatch is thread-safe and the warm never mutates the cache."""
-        import jax
+        from ..sched.telemetry import xla_scope
 
+        # every rung is the XLA account's under the planes' capacities,
+        # whoever warms: the prewarmer's thread, an extender's start, a
+        # caller of its own
+        with xla_scope("patch-ladder",
+                       {"N": snap.dims.N, "E": snap.dims.E, "P": snap.dims.P,
+                        "mesh": mesh is not None}):
+            return self._warm_patch_ladder(snap, mesh)
+
+    def _warm_patch_ladder(self, snap: Snapshot, mesh) -> int:
         compiled = 0
         for tree in (snap.tables.nodes, snap.existing, snap.pending):
             leaves = jax.tree.leaves(tree)

@@ -34,8 +34,14 @@ def enable_compile_cache() -> str:
     here; otherwise the fixed `<checkout>/.cache/xla` — the path is part of
     the cache key, so it never moves. Call before the first compile of the
     process (every entry point that builds a scheduler does); idempotent.
-    Returns the directory in effect."""
+    Returns the directory in effect.
+
+    Also where the process's XLA account goes in (sched/telemetry.py
+    `xla_account`: one `jax.monitoring` listener pair a process, none with
+    `KTPU_TELEMETRY=0`), so that the first compile is already on it."""
     import jax
+
+    from ..sched.telemetry import xla_account
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         d = os.path.join(_CHECKOUT, ".cache", "xla")
@@ -43,6 +49,7 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", d)
     # cache every compile that takes noticeable time, not just >1s ones
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    xla_account()
     return jax.config.jax_compilation_cache_dir
 
 
@@ -160,12 +167,17 @@ def listing_heap() -> Iterator[Dict[str, float]]:
     in turns the collector off, the LAST out freezes and restores. A relist
     in a running server is not a start: the collector stays on there.
 
-    Yields a dict that is filled at the exit: `frozen_objects`, by how many
-    `gc.get_freeze_count()` rose between this context's entry and its exit
-    (0 from one that was not the last out), and `collector_off_s`, how long
-    this context stood open. Reading that count is itself a walk of the
-    permanent generation's list, once at either end (~12 ns an object on
-    the builder's CPU, PR 50: 0.06 s over 5 million frozen objects)."""
+    Yields a dict that is filled at the exit: `frozen_objects`, how many
+    container objects the young generation held when this context froze it
+    (what was made since the collector last ran and is still alive: with
+    the collector off, the start's own population; 0 from a context that
+    was not the last out), and `collector_off_s`, how long this context
+    stood open. The count is the allocator's own (`gc.get_count()[0]`:
+    allocations less deallocations since the last collection), one number
+    read once. Until PR 51 it was `gc.get_freeze_count()` at either end,
+    each a walk of the whole permanent generation inside the start's timed
+    stages (~12 ns an object: 0.06 s over 5 million; ledger, PR 50: the
+    extender cell's `start_nodes_sync_s` 0.125 -> 0.237 s)."""
     global _listing_depth, _collector_was_on
     took: Dict[str, float] = {}
     with _listing_mu:
@@ -173,15 +185,16 @@ def listing_heap() -> Iterator[Dict[str, float]]:
         if _listing_depth == 1:
             _collector_was_on = gc.isenabled()
             gc.disable()
-    t0, frozen0 = time.perf_counter(), gc.get_freeze_count()
+    t0, froze = time.perf_counter(), 0
     try:
         yield took
     finally:
         with _listing_mu:
             _listing_depth -= 1
             if _listing_depth == 0:
+                froze = gc.get_count()[0]
                 gc.freeze()
                 if _collector_was_on:
                     gc.enable()
-        took["frozen_objects"] = gc.get_freeze_count() - frozen0
+        took["frozen_objects"] = froze
         took["collector_off_s"] = time.perf_counter() - t0

@@ -49,6 +49,9 @@ class _Gc:
     def get_freeze_count(self):
         return self.frozen
 
+    def get_count(self):
+        return (self.made, 0, 0)
+
 
 @pytest.fixture
 def fake(monkeypatch):

@@ -199,7 +199,11 @@ _HEAD = ["recorder", "t_start", "duration_s", "phases", "engine"]
 # what the collector did since the previous record (ISSUE 37): after the
 # record's own fields, before what the caller adds
 _GC = ["gc_full_collections", "gc_pause_s", "gc_max_pause_s",
-       "gc_max_pause_at"]
+       "gc_max_pause_at",
+       # and what XLA cost the process (ISSUE 51); `xla_compiled` follows
+       # on the record of a wave that compiled, which depends on what this
+       # process ran before: the shape below is held without it
+       "xla_total"]
 # what a preemption pass did (ISSUE 41): on the record of a wave whose pass
 # had an eligible pod, candidates or none
 _PASS = ["preempt_lanes", "preempt_preemptors", "preempt_dispatches",
@@ -253,6 +257,16 @@ _WAVE_SHAPES = {
 }
 
 
+def _without_compiles(rec):
+    """The record's keys less what an on-path compile adds to the record of
+    the wave that waited for it: `xla_compiled`, and `supervisor_events`
+    where every event is a `compile`."""
+    events = rec.get("supervisor_events", [("", "")])
+    return [k for k in rec if k != "xla_compiled" and not (
+        k == "supervisor_events"
+        and all(kind == "compile" for kind, _detail in events))]
+
+
 class TestRecordShape:
     @pytest.mark.parametrize("kind", sorted(_WAVE_SHAPES))
     def test_each_kind_of_wave_keeps_its_record(self, kind):
@@ -297,7 +311,7 @@ class TestRecordShape:
         assert [p for p, _ in rec["phases"]] == phases
         assert (sorted(rec["children"]) if "children" in rec else None) \
             == children
-        assert list(rec) == keys
+        assert _without_compiles(rec) == keys
         if kind == "pinned":
             assert [rec[k] for k in ("pinned", "pin_classes", "pinned_unfit",
                                      "pin_rounds")] == [3, 1, 1, 1]
@@ -535,8 +549,10 @@ class TestFleetStormDump:
         dump = srv.telemetry.last_dump
         assert dump is not None and dump["trigger"] == "storm"
         rec = dump["records"][-1]
-        assert rec["supervisor_events"] == [["storm", "t00"]] or \
-            rec["supervisor_events"] == [("storm", "t00")]
+        # (a solo tenant's first dispatch may compile: ISSUE 51 narrates
+        # that beside the storm, on the same record)
+        assert [tuple(e) for e in rec["supervisor_events"]
+                if e[0] != "compile"] == [("storm", "t00")]
         # per-tenant attribution on the record itself: ONLY t00 degraded
         assert rec["fleet"]["t00"]["degraded"] == 1
         assert rec["fleet"]["t01"]["degraded"] == 0
