@@ -14,6 +14,7 @@ from .wire import (
     ExtenderPreemptionArgs,
     ExtenderPreemptionResult,
     HostPriority,
+    HostPriorityList,
     MAX_EXTENDER_PRIORITY,
     MetaVictims,
     Victims,
@@ -23,6 +24,7 @@ __all__ = [
     "ExtenderBackend", "ExtenderConfig", "ExtenderError", "HTTPExtender",
     "ExtenderServer", "ServedExtender", "ExtenderArgs", "ExtenderBindingArgs",
     "ExtenderBindingResult", "ExtenderFilterResult", "ExtenderPreemptionArgs",
-    "ExtenderPreemptionResult", "HostPriority", "MAX_EXTENDER_PRIORITY",
+    "ExtenderPreemptionResult", "HostPriority", "HostPriorityList",
+    "MAX_EXTENDER_PRIORITY",
     "MetaVictims", "Victims",
 ]

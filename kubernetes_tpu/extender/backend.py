@@ -29,6 +29,14 @@ the pod in the cache before it writes and the informer's echo confirms it
 (state/cache.py assume / finish / expire), so the next pod's `filter` sees
 the placement whether or not the echo has arrived.
 
+One evaluation a pod: `filter` takes ONE snapshot and runs ONE program
+(sched/cycle.py `_evaluate`: mask, failure components, scores) and keeps the
+result on the host for the pod's UID; its own answer and the same pod's
+`prioritize` answer are both cut from those arrays. The kept evaluation is
+good for as long as the mirror's epoch (this backend's count of changes to
+what the lattice reads) has not moved; whatever else arrives evaluates
+afresh the same way.
+
 One flight-recorder record per POD (docs/OBSERVABILITY.md): the `filter` ->
 `prioritize` -> `bind` of one UID, in the wave record's shape, closed after
 the `bind`'s answer (`answered`) or by the next pod's first verb.
@@ -38,17 +46,20 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 import time
+from itertools import compress
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import numpy as np
 
 from ..api.types import Node, Pod
 from ..api.v1 import node_from_v1, pod_from_v1
 from ..component import trace
-from ..sched.cycle import (_diagnose, _feasible, _scores,
-                           snapshot_with_keys)
+from ..component.metrics import DEFAULT_REGISTRY as REG
+from ..sched.cycle import _evaluate, snapshot_with_keys
 from ..sched.telemetry import SchedulerTelemetry, xla_scope
 from ..state.cache import SchedulerCache
 from ..state.dims import Dims
@@ -60,7 +71,7 @@ from .wire import (
     ExtenderFilterResult,
     ExtenderPreemptionArgs,
     ExtenderPreemptionResult,
-    HostPriority,
+    HostPriorityList,
     MAX_EXTENDER_PRIORITY,
     MetaVictims,
 )
@@ -76,7 +87,41 @@ _REASONS = (
     "node(s) didn't match pod anti-affinity rules",
     "node(s) didn't match pod topology spread constraints",
     "node(s) didn't match the requested hostname",
+    "node(s) had volume conflicts or exceeded volume limits",
 )
+
+EXTENDER_EVALUATIONS = REG.counter(
+    "extender_evaluations_total",
+    "Verb answers by where their arrays came from: `computed` by one "
+    "snapshot and one program, `reused` from the evaluation kept for the "
+    "pod's UID since its filter",
+    labels=("result",))
+
+_UNKNOWN = -1   # the reason code of a candidate the mirror does not hold
+
+
+@functools.lru_cache(maxsize=None)   # at most 2 ** len(_REASONS) + 1 texts
+def _reason_text(code: int) -> str:
+    """A failed node's FailedNodes text from its reason code (bit j set
+    where MaskComponents field j refuses)."""
+    if code == _UNKNOWN:
+        return "node not found in extender cache"
+    return "; ".join(r for j, r in enumerate(_REASONS) if code >> j & 1) \
+        or "node is not feasible"
+
+
+class _Evaluation:
+    """One pod evaluated against the mirror, on the host: what `filter`'s
+    and `prioritize`'s answers are cut from. `mask`, `codes` and `scores`
+    are [N] rows indexed by the slot of a name in `order`."""
+
+    __slots__ = ("uid", "epoch", "order", "mask", "codes", "scores")
+
+    def __init__(self, uid: str, epoch: int, order: List[str],
+                 mask: np.ndarray, codes: np.ndarray,
+                 scores: np.ndarray) -> None:
+        self.uid, self.epoch, self.order = uid, epoch, order
+        self.mask, self.codes, self.scores = mask, codes, scores
 
 
 class _PodStats:
@@ -103,14 +148,16 @@ class _PodRecord:
     beside it."""
 
     __slots__ = ("uid", "key", "pod", "span", "verbs", "dispatches",
-                 "snapshots", "split", "assumed_outstanding", "confirm",
-                 "feasible", "bound", "bind_error", "dims", "mode")
+                 "snapshots", "evaluations", "eval_reused", "split",
+                 "assumed_outstanding", "confirm", "feasible", "bound",
+                 "bind_error", "dims", "mode")
 
     def __init__(self, uid: str, key: str, span) -> None:
         self.uid, self.key, self.span = uid, key, span
         self.pod: Optional[Pod] = None
         self.verbs: List[str] = []
         self.dispatches = self.snapshots = 0
+        self.evaluations = self.eval_reused = 0
         self.split = [0.0, 0.0, 0.0]   # launch, execute, readback seconds
         self.assumed_outstanding: Optional[int] = None
         self.confirm: Optional[List[float]] = None
@@ -146,6 +193,16 @@ class ExtenderBackend:
         # counters), as Scheduler.watch_plane is
         self.watch_plane: Optional[Callable[[], dict]] = None
         self._mu = threading.Lock()
+        # the mirror's epoch: every change to what the lattice reads is made
+        # and counted under `_mirror_mu` (the informers' threads do not hold
+        # `_mu`), so an evaluation whose epoch still stands saw all of them
+        self._mirror_mu = threading.Lock()
+        self._epoch = 0
+        self._kept: Optional[_Evaluation] = None
+        # {name: slot} of the last node order a candidate list was looked
+        # up in, rebuilt when the order changes
+        self._slot_order: List[str] = []
+        self._slot_of: Dict[str, int] = {}
         self._rec: Optional[_PodRecord] = None
         self._arrival = threading.local()
         self.bound: List[Tuple[str, str]] = []  # (pod key, node) — audit trail
@@ -163,36 +220,53 @@ class ExtenderBackend:
         cache = self.cache
         if not pod.node_name:
             return
-        known = cache.get_pod(pod.key) is not None
-        if not live:
-            if known:
+        with self._mirror_mu:
+            held = cache.get_pod(pod.key)
+            if not live:
+                if held is None:
+                    return
                 cache.remove_pod(pod.key)
-        elif known and not cache.is_assumed(pod.key):
-            cache.update_pod(pod)
-        else:
-            cache.add_pod(pod)
+            elif held is not None and not cache.is_assumed(pod.key):
+                cache.update_pod(pod)
+            else:
+                cache.add_pod(pod)
+                if held is not None and held.node_name == pod.node_name:
+                    # the echo of this backend's own Binding: the pod has
+                    # been counted on that node since `bind` assumed it,
+                    # and a Binding changes `nodeName` and a condition, so
+                    # no row the lattice reads has changed. It lands during
+                    # or just after the NEXT pod's `filter`: counting it
+                    # would cost that pod its kept evaluation
+                    return
+            self._epoch += 1
 
     def forget_pod(self, key: str) -> None:
         """A pod informer's delete."""
-        if self.cache.get_pod(key) is not None:
-            self.cache.remove_pod(key)
+        with self._mirror_mu:
+            if self.cache.get_pod(key) is not None:
+                self.cache.remove_pod(key)
+                self._epoch += 1
 
     def observe_node(self, node: Node) -> None:
-        if self.cache.get_node(node.name) is None:
-            self.cache.add_node(node)
-        else:
-            self.cache.update_node(node)
+        with self._mirror_mu:
+            if self.cache.get_node(node.name) is None:
+                self.cache.add_node(node)
+            else:
+                self.cache.update_node(node)
+            self._epoch += 1
 
     def forget_node(self, name: str) -> None:
-        if self.cache.get_node(name) is not None:
-            self.cache.remove_node(name)
+        with self._mirror_mu:
+            if self.cache.get_node(name) is not None:
+                self.cache.remove_node(name)
+                self._epoch += 1
 
     def sync_nodes(self, nodes: Sequence[Node]) -> None:
         """Full reconcile: `nodes` is the complete node set (informer relist)."""
         known = {n.name for n in self.cache.nodes()}
         self.upsert_nodes(nodes)
         for gone in known - {n.name for n in nodes}:
-            self.cache.remove_node(gone)
+            self.forget_node(gone)
 
     def upsert_nodes(self, nodes: Sequence[Node]) -> None:
         """Partial refresh: update/insert only — used for the node objects
@@ -203,18 +277,20 @@ class ExtenderBackend:
             self.observe_node(n)
 
     def sync_scheduled_pods(self, pods: Sequence[Pod]) -> None:
-        known = {p.key for p in self.cache.scheduled_pods()}
-        incoming = set()
-        for p in pods:
-            if not p.node_name:
-                continue
-            incoming.add(p.key)
-            if p.key in known:
-                self.cache.update_pod(p)
-            else:
-                self.cache.add_pod(p)
-        for gone in known - incoming:
-            self.cache.remove_pod(gone)
+        with self._mirror_mu:
+            known = {p.key for p in self.cache.scheduled_pods()}
+            incoming = set()
+            for p in pods:
+                if not p.node_name:
+                    continue
+                incoming.add(p.key)
+                if p.key in known:
+                    self.cache.update_pod(p)
+                else:
+                    self.cache.add_pod(p)
+            for gone in known - incoming:
+                self.cache.remove_pod(gone)
+            self._epoch += 1
 
     # ------------------------------------------------------------------ #
     # IsInterested (extender.go:454-470)
@@ -284,7 +360,8 @@ class ExtenderBackend:
         self._rec = None
         extra = {"pod": rec.key, "verbs": rec.verbs,
                  "dispatches": rec.dispatches, "snapshots": rec.snapshots,
-                 "snapshot_mode": rec.mode}
+                 "evaluations": rec.evaluations,
+                 "eval_reused": rec.eval_reused, "snapshot_mode": rec.mode}
         if rec.feasible is not None:
             extra["feasible"] = rec.feasible
         if rec.assumed_outstanding is not None:
@@ -334,16 +411,16 @@ class ExtenderBackend:
         return snap, keys
 
     @staticmethod
-    def _dispatch(program, snap, keys, rec: Optional[_PodRecord] = None):
-        """One device call of a verb's program over the snapshot, read back
-        to the host; on a record, its launch / execute / readback split is
+    def _dispatch(snap, keys, rec: Optional[_PodRecord] = None):
+        """One device call of `_evaluate` over the snapshot, read back to
+        the host; on a record, its launch / execute / readback split is
         added to the pod's."""
         t0 = time.perf_counter()
         # a compile here is the enclosing scope's (the verb's, or the
         # compile-ahead's), at these capacities
         with xla_scope(None, (snap.dims,), ("dims",)):
-            out = program(snap.tables, snap.pending, keys, snap.dims.D,
-                          snap.existing)
+            out = _evaluate(snap.tables, snap.pending, keys, snap.dims.D,
+                            snap.existing)
         if rec is None:
             return jax.device_get(out)
         t1 = time.perf_counter()
@@ -358,9 +435,53 @@ class ExtenderBackend:
             rec.split[i] += dt
         return host
 
+    def _evaluate_pod(self, pod: Pod, uid: str,
+                      rec: Optional[_PodRecord]) -> _Evaluation:
+        """Evaluate `pod` against the mirror as it stands: one snapshot,
+        one program, and the result kept for `uid` in place of the last.
+        The epoch is read BEFORE the snapshot: a change that lands during
+        it moves the epoch past the kept one, whether or not the snapshot
+        caught it."""
+        with self._mirror_mu:
+            epoch = self._epoch
+        snap, keys = self._snapshot_for(pod, rec=rec)
+        mask, comp, scores = self._dispatch(snap, keys, rec)
+        codes = np.zeros(mask.shape[1], np.int32)
+        for j, part in enumerate(comp):
+            codes |= (~part[0]).astype(np.int32) << j
+        if rec is not None:
+            rec.evaluations += 1
+        EXTENDER_EVALUATIONS.inc(result="computed")
+        kept = self._kept = _Evaluation(uid, epoch, snap.node_order, mask[0],
+                                        codes, scores[0])
+        return kept
+
+    @staticmethod
+    def _candidates(args: ExtenderArgs) -> Optional[List[str]]:
+        """The names a verb is asked about, in either form of the
+        arguments; None where neither is present."""
+        if args.node_names is not None:
+            return args.node_names
+        if args.nodes is not None:
+            return [n["metadata"]["name"] for n in args.nodes]
+        return None
+
+    def _slots(self, names: Sequence[str], order: List[str]) -> np.ndarray:
+        """The slot in `order` of each candidate name, -1 for a name the
+        mirror does not hold. Every node in the mirror's own order is what a
+        `nodeCacheCapable` scheduler sends to `filter`."""
+        if names == order:
+            return np.arange(len(order))
+        if order != self._slot_order:
+            self._slot_order = order
+            self._slot_of = {name: i for i, name in enumerate(order)}
+        slot = self._slot_of.get
+        return np.fromiter((slot(name, _UNKNOWN) for name in names), np.intp,
+                           len(names))
+
     def compile_ahead(self) -> list:
-        """Run the three verbs' programs once over the mirror as it stands,
-        and the cache's patch-scatter ladder, so that no later verb at these
+        """Run the verbs' program once over the mirror as it stands, and
+        the cache's patch-scatter ladder, so that no later verb at these
         capacities compiles (upstream's `httpTimeout` defaults to 5 s; the
         flagship's programs compile for a minute). A real call at the live
         shapes, as `SchedulerCache.warm_patch_ladder` is: what seeds the
@@ -381,14 +502,9 @@ class ExtenderBackend:
         with self._mu, xla_scope("compile-ahead", on_path=False):
             snap, keys = timed("snapshot", self._snapshot_for, Pod(
                 name="compile-ahead", namespace="kube-system"))
-            warmed = []
-            for name, program in (("filter", _feasible),
-                                  ("diagnose", _diagnose),
-                                  ("prioritize", _scores)):
-                timed(name, self._dispatch, program, snap, keys)
-                warmed.append((snap.dims, name))
+            timed("evaluate", self._dispatch, snap, keys)
             timed("patch-ladder", self.cache.warm_patch_ladder, snap)
-            return warmed
+            return [(snap.dims, "evaluate")]
 
     # ------------------------------------------------------------------ #
     # verb: Filter
@@ -400,10 +516,11 @@ class ExtenderBackend:
                 pod = pod_from_v1(args.pod)
             except Exception as e:  # noqa: BLE001 — wire boundary
                 return ExtenderFilterResult(error=f"bad pod: {e}")
-            with self._serving(pod.uid or pod.key, pod.key, "filter") as rec:
-                return self._filter(args, pod, rec)
+            uid = pod.uid or pod.key
+            with self._serving(uid, pod.key, "filter") as rec:
+                return self._filter(args, pod, uid, rec)
 
-    def _filter(self, args: ExtenderArgs, pod: Pod,
+    def _filter(self, args: ExtenderArgs, pod: Pod, uid: str,
                 rec: Optional[_PodRecord]) -> ExtenderFilterResult:
         cache_capable = args.node_names is not None
         if not cache_capable and args.nodes is not None:
@@ -417,44 +534,29 @@ class ExtenderBackend:
         # whose echo is still out: one whose echo never comes expires here
         confirm, outstanding = self.cache.drain_confirm_waits()
         if outstanding:
-            self.cache.cleanup(self.telemetry.clock())
+            with self._mirror_mu:
+                if self.cache.cleanup(self.telemetry.clock()):
+                    self._epoch += 1
         if rec is not None:
             rec.confirm, rec.assumed_outstanding = confirm, outstanding
 
-        snap, keys = self._snapshot_for(pod, rec=rec)
-        mask = self._dispatch(_feasible, snap, keys, rec)[0]
-
-        if cache_capable:
-            candidates = args.node_names or []
-        elif args.nodes is not None:
-            candidates = [n["metadata"]["name"] for n in args.nodes]
-        else:
-            # neither form present: evaluate every mirrored node
-            candidates = list(snap.node_order)
-        index = {name: i for i, name in enumerate(snap.node_order)}
-
-        passing: List[str] = []
-        failed: Dict[str, str] = {}
-        for name in candidates:
-            i = index.get(name)
-            if i is not None and bool(mask[i]):
-                passing.append(name)
-            else:
-                failed[name] = ""
+        # always afresh: a retry of the stock scheduler sees the world as
+        # it is now
+        ev = self._evaluate_pod(pod, uid, rec)
+        names = self._candidates(args)
+        if names is None:   # neither form present: every mirrored node
+            names = ev.order
+        slots = self._slots(names, ev.order)
+        known = slots >= 0
+        ok = known & ev.mask[slots]
+        passing = list(compress(names, ok.tolist()))
+        refused = ~ok
+        failed = dict(zip(
+            compress(names, refused.tolist()),
+            map(_reason_text,
+                np.where(known, ev.codes[slots], _UNKNOWN)[refused].tolist())))
         if rec is not None:
             rec.feasible = len(passing)
-
-        if failed:
-            comp = self._dispatch(_diagnose, snap, keys, rec)
-            for name in failed:
-                i = index.get(name)
-                if i is None:
-                    failed[name] = "node not found in extender cache"
-                    continue
-                reasons = [
-                    _REASONS[j] for j, part in enumerate(comp) if not bool(part[0][i])
-                ]
-                failed[name] = "; ".join(reasons) or "node is not feasible"
 
         if cache_capable:
             return ExtenderFilterResult(node_names=passing, failed_nodes=failed)
@@ -468,44 +570,45 @@ class ExtenderBackend:
     # verb: Prioritize
     # ------------------------------------------------------------------ #
 
-    def prioritize(self, args: ExtenderArgs) -> List[HostPriority]:
+    def prioritize(self, args: ExtenderArgs) -> HostPriorityList:
         with self._mu:
             pod = pod_from_v1(args.pod)
-            with self._serving(pod.uid or pod.key, pod.key,
-                               "prioritize") as rec:
-                return self._prioritize(args, pod, rec)
+            uid = pod.uid or pod.key
+            with self._serving(uid, pod.key, "prioritize") as rec:
+                return self._prioritize(args, pod, uid, rec)
 
-    def _prioritize(self, args: ExtenderArgs, pod: Pod,
-                    rec: Optional[_PodRecord]) -> List[HostPriority]:
+    def _prioritize(self, args: ExtenderArgs, pod: Pod, uid: str,
+                    rec: Optional[_PodRecord]) -> HostPriorityList:
         if rec is not None:
             rec.pod = pod
             rec.span.mark("decode")
-        snap, keys = self._snapshot_for(pod, rec=rec)
-        raw = self._dispatch(_scores, snap, keys, rec)[0]
+        ev = self._kept
+        with self._mirror_mu:
+            epoch = self._epoch
+        if ev is not None and ev.uid == uid and ev.epoch == epoch:
+            # this pod's `filter` evaluated it and the mirror has not
+            # changed since: a new snapshot would encode the same rows
+            if rec is not None:
+                rec.eval_reused += 1
+            EXTENDER_EVALUATIONS.inc(result="reused")
+        else:
+            ev = self._evaluate_pod(pod, uid, rec)
 
-        candidates = (args.node_names if args.node_names is not None
-                      else [n["metadata"]["name"] for n in (args.nodes or [])])
-        index = {name: i for i, name in enumerate(snap.node_order)}
-        vals: List[Tuple[str, float]] = []
-        for name in candidates or []:
-            i = index.get(name)
-            s = float(raw[i]) if i is not None else float("-inf")
-            vals.append((name, s))
-
-        finite = [s for _, s in vals if s != float("-inf")]
-        hi = max(finite) if finite else 0.0
-        lo = min(finite) if finite else 0.0
+        names = self._candidates(args) or []
+        slots = self._slots(names, ev.order)
+        # float64 from here on, as Python's floats are: the integers are
+        # those of round((s - lo) / span * MAX) over float(raw[i]), both
+        # rounding half to even. -inf (infeasible, unknown) scores 0
+        raw = np.where(slots >= 0, ev.scores[slots],
+                       -np.inf).astype(np.float64)
+        finite = raw != -np.inf
+        lo, hi = (raw[finite].min(), raw[finite].max()) if finite.any() \
+            else (0.0, 0.0)
         span = (hi - lo) or 1.0
-        out: List[HostPriority] = []
-        for name, s in vals:
-            if s == float("-inf"):
-                out.append(HostPriority(host=name, score=0))
-            else:
-                out.append(HostPriority(
-                    host=name,
-                    score=round((s - lo) / span * MAX_EXTENDER_PRIORITY),
-                ))
-        return out
+        scaled = (np.where(finite, raw, lo) - lo) / span \
+            * MAX_EXTENDER_PRIORITY
+        scores = np.where(finite, np.rint(scaled), 0).astype(np.int64)
+        return HostPriorityList(names, scores.tolist())
 
     # ------------------------------------------------------------------ #
     # verb: ProcessPreemption (extender.go:166-230)
@@ -542,9 +645,7 @@ class ExtenderBackend:
                 for p in keep:
                     probe.add_pod(p)
                 snap, keys = self._snapshot_for(pod, cache=probe)
-                mask = jax.device_get(_feasible(
-                    snap.tables, snap.pending, keys, snap.dims.D, snap.existing
-                ))[0]
+                mask = self._dispatch(snap, keys)[0][0]
                 try:
                     i = snap.node_order.index(node_name)
                 except ValueError:
@@ -562,6 +663,8 @@ class ExtenderBackend:
     def bind(self, args: ExtenderBindingArgs) -> ExtenderBindingResult:
         with self._mu:
             key = f"{args.pod_namespace}/{args.pod_name}"
+            # the placement changes what the next evaluation must see
+            self._kept = None
             with self._serving(args.pod_uid or key, key, "bind") as rec:
                 if rec is not None:
                     rec.span.mark("decode")
@@ -602,7 +705,9 @@ class ExtenderBackend:
         t0 = time.perf_counter()
         assumed = self.cache.get_pod(key) is None
         if assumed:
-            self.cache.assume_pod(pod, args.node)
+            with self._mirror_mu:
+                self.cache.assume_pod(pod, args.node)
+                self._epoch += 1
         t1 = time.perf_counter()
         tok = None
         if tr is not None:
@@ -618,8 +723,11 @@ class ExtenderBackend:
         if ok:
             self.cache.finish_binding(key, self.telemetry.clock())
             self.bound.append((key, args.node))
-        elif assumed and self.cache.is_assumed(key):
-            self.cache.forget_pod(key)
+        elif assumed:
+            with self._mirror_mu:
+                if self.cache.is_assumed(key):
+                    self.cache.forget_pod(key)
+                    self._epoch += 1
         if tr is not None:
             tr.child("finish", time.perf_counter() - t2)
         if not ok:

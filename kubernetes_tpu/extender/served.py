@@ -8,7 +8,7 @@ mirror its feed and the backend its Binding write, as `SchedulerServer`
     a time (initial list included); their relists ride the pod's record;
   * `bind` goes through `APIBinder(client)` (POST pods/{name}/binding with
     the retry budget) and assumes the pod first (backend.py `_bind`);
-  * the three verbs' programs and the patch-scatter ladder are run once at
+  * the verbs' one program and the patch-scatter ladder are run once at
     `start()`, after the initial lists are in and before the socket opens,
     so that the first request answers inside upstream's `httpTimeout` (5 s
     by default; a non-ignorable extender that misses it fails the pod);

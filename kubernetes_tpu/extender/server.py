@@ -78,7 +78,9 @@ class ExtenderServer:
                 pass
 
             def _reply(self, code: int, obj, encoded=None) -> None:
-                body = json.dumps(obj).encode()
+                # a verb that encodes its own reply hands over the bytes
+                body = obj if isinstance(obj, bytes) \
+                    else json.dumps(obj).encode()
                 if encoded is not None:
                     encoded()   # before a byte leaves: see do_POST
                 self.send_response(code)
@@ -115,8 +117,8 @@ class ExtenderServer:
                         code, obj = 200, backend.filter(
                             ExtenderArgs.from_json(payload)).to_json()
                     elif verb == "prioritize":
-                        code, obj = 200, [p.to_json() for p in backend.prioritize(
-                            ExtenderArgs.from_json(payload))]
+                        code, obj = 200, backend.prioritize(
+                            ExtenderArgs.from_json(payload)).encode()
                     elif verb == "preemption":
                         code, obj = 200, backend.process_preemption(
                             ExtenderPreemptionArgs.from_json(payload)).to_json()

@@ -1,7 +1,7 @@
 """Scheduler-extender wire types — byte-compatible with the reference's JSON.
 
 Mirror of pkg/scheduler/apis/extender/v1/types.go: ExtenderArgs (:71),
-ExtenderFilterResult (:86), HostPriority/HostPriorityList (:118),
+ExtenderFilterResult (:86), HostPriority/HostPriorityList (:118,:124),
 Victims/MetaVictims (:50,:63), ExtenderPreemptionArgs/Result (:37,:33),
 ExtenderBindingArgs/Result (:100,:112), MaxExtenderPriority=10 (:29).
 
@@ -15,6 +15,7 @@ and decode our responses unchanged.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from json.encoder import encode_basestring_ascii
 from typing import Any, Dict, List, Optional
 
 MIN_EXTENDER_PRIORITY = 0
@@ -88,6 +89,37 @@ class HostPriority:
     @staticmethod
     def from_json(obj: Dict[str, Any]) -> "HostPriority":
         return HostPriority(host=obj.get("Host", ""), score=int(obj.get("Score", 0)))
+
+
+class HostPriorityList:
+    """types.go:124 HostPriorityList as two parallel lists, `hosts[i]` scored
+    `scores[i]`: a reply over 5,000 candidates is encoded from them once.
+    Iterating gives `HostPriority`."""
+
+    __slots__ = ("hosts", "scores")
+
+    def __init__(self, hosts: List[str], scores: List[int]) -> None:
+        self.hosts, self.scores = hosts, scores
+
+    def __len__(self) -> int:
+        return len(self.hosts)
+
+    def __iter__(self):
+        return map(HostPriority, self.hosts, self.scores)
+
+    def to_json(self) -> List[Dict[str, Any]]:
+        return [{"Host": h, "Score": s}
+                for h, s in zip(self.hosts, self.scores)]
+
+    def encode(self) -> bytes:
+        """The reply's body: byte for byte `json.dumps(self.to_json())`,
+        made without a dict a candidate (the names escaped by json's own
+        routine, a score's text looked up)."""
+        tail = {s: ', "Score": %d}' % s for s in set(self.scores)}
+        items = map(str.__add__, map(encode_basestring_ascii, self.hosts),
+                    map(tail.__getitem__, self.scores))
+        return ('[{"Host": ' + ', {"Host": '.join(items) + "]"
+                if self.hosts else "[]").encode()
 
 
 @dataclass

@@ -326,6 +326,34 @@ def _diagnose(
     return mask_components(tables, cyc, pending)
 
 
+@functools.partial(jax.jit, static_argnums=(3, 7))
+def _evaluate(
+    tables: ClusterTables,
+    pending: PodArrays,
+    keys: Tuple[jnp.ndarray, jnp.ndarray],
+    D: int,
+    existing: PodArrays,
+    hard_weight=1.0,
+    ecfg=None,
+    extra_plugins: tuple = (),
+    extra_weights: tuple = (),
+):
+    """`(mask [P, N], MaskComponents, scores [P, N])` from ONE lattice: what
+    `_feasible`, `_diagnose` and `_scores` give, each of which rebuilds the
+    cycle for itself, as one dispatch (the extender's verbs: a pod is
+    evaluated once and both its answers are cut from these arrays). The
+    three stay as the spec this is held to (tests/test_extender.py)."""
+    from ..ops.assign import feasible_matrix, mask_components, score_matrix
+
+    uk, ev = keys
+    cyc = build_cycle(tables, existing, uk, ev, D, hard_weight,
+                      ecfg or default_engine_config())
+    scored = _apply_extra_plugins(tables, cyc, extra_plugins, extra_weights)
+    return (feasible_matrix(tables, cyc, pending),
+            mask_components(tables, cyc, pending),
+            score_matrix(tables, scored, pending))
+
+
 @dataclass
 class CycleResult:
     """Placements for one cycle. `assignments[i]` is the node name for

@@ -272,6 +272,32 @@ def test_the_extender_cell_names_its_modules_and_they_are_there():
             "device_idle_pct.backlog"} <= named
 
 
+@pytest.mark.parametrize("field, want", [("eval_reused", 1.0), (None, None)])
+def test_the_reuse_metric_reads_the_records_field_or_nothing(field, want):
+    """`extender_eval_reused_per_pod` through its file: the records'
+    `eval_reused` over the pods bound; a program that records no such field
+    (the parent of ISSUE 52) leaves the metric out and nothing raises."""
+    rec = {"t_start": 1.0, "duration_s": 0.05, "phases": [],
+           "stats": {"attempted": 1}, "dispatches": 1}
+    if field:
+        rec[field] = 1
+    obs = {"window_s": 40.0, "bound_in_window": 3, "series": {},
+           "memory": {}, "trace": None, "rehearse": True,
+           "waves": [dict(rec) for _ in range(3)]}
+    out = cell.compute_metrics(BENCH, "per_layer", EXT_CELL, obs)
+    assert out["extender_dispatches_per_pod"]["value"] == 1.0
+    if want is None:
+        assert "extender_eval_reused_per_pod" not in out
+    else:
+        assert out["extender_eval_reused_per_pod"] == {
+            "value": want, "unit": "answers/pod"}
+    entry = BENCH["per_layer"][-1]
+    assert entry["name"] == "extender_eval_reused_per_pod"
+    assert entry["workloads"] == [EXT_CELL]
+    assert (entry["layer"], entry["moves"], entry["better"]) == (
+        "extender server", "drain_pods_per_s", "higher")
+
+
 @pytest.mark.parametrize("answer, malformed, chosen", [
     ([("a", 3), ("b", 10), ("c", 10)], "", {"b", "c"}),
     ([("a", 3), ("b", 10)], "candidates unscored", {"b"}),

@@ -21,6 +21,7 @@ from kubernetes_tpu.api.types import (
     TaintEffect,
     Toleration,
     TolerationOp,
+    VolumeRef,
 )
 from kubernetes_tpu.api.v1 import node_from_v1, node_to_v1, pod_from_v1, pod_to_v1
 from kubernetes_tpu.extender import (
@@ -263,6 +264,411 @@ def test_backend_preemption_verifies_victims():
     )
     res2 = be.process_preemption(args2)
     assert "n0" not in res2.node_name_to_meta_victims
+
+
+# --------------------------------------------------------------------------- #
+# one evaluation a pod (ISSUE 52): `filter` snapshots and dispatches once and
+# both verbs' answers are cut from its arrays. The three programs and the
+# name-at-a-time loops the verbs used to run live on here as the reference
+# --------------------------------------------------------------------------- #
+
+
+def _reference_verbs(be, pod, filter_names, prioritize_names):
+    """`filter`'s (NodeNames, FailedNodes) and `prioritize`'s [(Host, Score)]
+    for `pod` over the mirror as it stands, by `_feasible`, `_diagnose` and
+    `_scores` on one snapshot and a Python loop over the candidates."""
+    import jax
+    from kubernetes_tpu.extender.backend import _REASONS
+    from kubernetes_tpu.extender.wire import MAX_EXTENDER_PRIORITY
+    from kubernetes_tpu.sched.cycle import (_diagnose, _feasible, _scores,
+                                            snapshot_with_keys)
+
+    snap, keys = snapshot_with_keys(be.cache, be.encoder, [pod], be.base_dims)
+    call = (snap.tables, snap.pending, keys, snap.dims.D, snap.existing)
+    mask = jax.device_get(_feasible(*call))[0]
+    comp = jax.device_get(_diagnose(*call))
+    raw = jax.device_get(_scores(*call))[0]
+    index = {name: i for i, name in enumerate(snap.node_order)}
+
+    passing, failed = [], {}
+    for name in filter_names:
+        i = index.get(name)
+        if i is not None and bool(mask[i]):
+            passing.append(name)
+        elif i is None:
+            failed[name] = "node not found in extender cache"
+        else:
+            reasons = [_REASONS[j] for j, part in enumerate(comp)
+                       if not bool(part[0][i])]
+            failed[name] = "; ".join(reasons) or "node is not feasible"
+
+    vals = []
+    for name in prioritize_names:
+        i = index.get(name)
+        vals.append((name, float(raw[i]) if i is not None
+                     else float("-inf")))
+    finite = [s for _, s in vals if s != float("-inf")]
+    hi = max(finite) if finite else 0.0
+    lo = min(finite) if finite else 0.0
+    span = (hi - lo) or 1.0
+    prios = [(name, 0 if s == float("-inf")
+              else round((s - lo) / span * MAX_EXTENDER_PRIORITY))
+             for name, s in vals]
+    return passing, failed, prios
+
+
+ZONE, HOST = "topology.kubernetes.io/zone", "kubernetes.io/hostname"
+
+
+def _small_mirror_nodes():
+    """Nine nodes in three zones (n4 has no zone label), pools a/b; n7 is
+    small and n8 tainted."""
+    nodes = []
+    for i in range(9):
+        labels = {HOST: f"n{i}", "pool": "ab"[i % 2]}
+        if i != 4:
+            labels[ZONE] = f"z{i % 3}"
+        nodes.append(mknode(
+            f"n{i}", cpu=1 if i == 7 else 4, labels=labels,
+            taints=(Taint(key="dedicated", value="x",
+                          effect=TaintEffect.NO_SCHEDULE),) if i == 8
+            else ()))
+    return nodes
+
+
+def _small_mirror(**kw):
+    """The nodes above holding `web` pods unevenly over the zones and a `db`
+    pod on n1, n2 and n5."""
+    be = ExtenderBackend(**kw)
+    be.sync_nodes(_small_mirror_nodes())
+    bound = []
+    for i in range(9):
+        for j in range(1 + 2 * (i % 3 == 0) + (i == 1)):
+            bound.append(mkpod(f"web-{i}-{j}", cpu="100m",
+                               labels={"app": "web"}, node_name=f"n{i}"))
+    for i in (1, 2, 5):
+        bound.append(mkpod(f"db-{i}", cpu="200m", labels={"app": "db"},
+                           node_name=f"n{i}"))
+    bound.append(mkpod("writer", cpu="100m", node_name="n6", volumes=(
+        VolumeRef(vol_id="shared", driver="kubernetes.io/gce-pd"),)))
+    be.sync_scheduled_pods(bound)
+    return be
+
+
+def _asking_pod(case):
+    from kubernetes_tpu.api.types import (TopologySpreadConstraint,
+                                          UnsatisfiableAction)
+
+    if case == "anti":
+        return mkpod("asks", cpu="100m", uid="u-asks", labels={"app": "db"},
+                     affinity=Affinity(anti_required=(PodAffinityTerm(
+                         selector=LabelSelector.of({"app": "db"}),
+                         topology_key=HOST),)))
+    if case == "spread":
+        return mkpod("asks", cpu="100m", uid="u-asks", labels={"app": "web"},
+                     topology_spread=(TopologySpreadConstraint(
+                         1, ZONE, UnsatisfiableAction.DO_NOT_SCHEDULE,
+                         LabelSelector.of(match_labels={"app": "web"})),))
+    if case == "several-reasons":
+        return mkpod("asks", cpu="3", uid="u-asks",
+                     node_selector={"pool": "a"})
+    if case == "volume-conflict":    # as the wire carries it (`_asking_v1`)
+        return mkpod("asks", cpu="100m", uid="u-asks")
+    return mkpod("asks", cpu="100m", uid="u-asks")
+
+
+def _asking_v1(case):
+    v1 = pod_to_v1(_asking_pod(case))
+    if case == "volume-conflict":
+        v1["spec"]["volumes"] = [
+            {"name": "data", "gcePersistentDisk": {"pdName": "shared"}}]
+    return v1
+
+
+NAMES = [f"n{i}" for i in range(9)]
+
+
+@pytest.mark.parametrize("case", ["plain", "anti", "spread",
+                                  "several-reasons", "volume-conflict",
+                                  "unknown-name",
+                                  "subset-out-of-order", "nodes-form"])
+def test_answers_equal_the_three_programs_and_the_loops(case):
+    be = _small_mirror()
+    v1 = _asking_v1(case)
+    pod = pod_from_v1(v1)
+    names = {"unknown-name": NAMES[:4] + ["ghost"] + NAMES[4:],
+             "subset-out-of-order": ["n6", "n2", "n8", "n0", "n2"],
+             }.get(case, NAMES)
+    if case == "nodes-form":
+        # nodeCacheCapable=false: the caller's node objects, n3 as the
+        # caller sees it (cordoned: not what the mirror held)
+        objs = [node_to_v1(n) for n in _small_mirror_nodes()]
+        objs[3]["spec"]["unschedulable"] = True
+        flt = be.filter(ExtenderArgs(pod=v1, nodes=objs))
+        passed = [n["metadata"]["name"] for n in flt.nodes]
+        prio = be.prioritize(ExtenderArgs(
+            pod=v1,
+            nodes=[o for o in objs if o["metadata"]["name"] in passed]))
+        assert flt.node_names is None and "n3" in flt.failed_nodes
+    else:
+        flt = be.filter(ExtenderArgs(pod=v1, node_names=names))
+        passed = flt.node_names
+        prio = be.prioritize(ExtenderArgs(pod=v1,
+                                          node_names=list(passed)))
+    want_pass, want_failed, want_prio = _reference_verbs(
+        be, pod, names, list(passed))
+    assert passed == want_pass and passed
+    assert flt.failed_nodes == want_failed
+    assert list(flt.failed_nodes) == list(want_failed)   # and in that order
+    assert [(h.host, h.score) for h in prio] == want_prio
+    assert prio.to_json() == [{"Host": h, "Score": s} for h, s in want_prio]
+    assert prio.encode() == json.dumps(prio.to_json()).encode()
+    assert all(type(s) is int for _h, s in want_prio)
+    if case in ("anti", "spread", "several-reasons", "volume-conflict"):
+        assert want_failed
+    if case == "volume-conflict":
+        # the ninth component had no text before ISSUE 52: the verb raised
+        assert want_failed["n6"] == (
+            "node(s) had volume conflicts or exceeded volume limits")
+    if case == "several-reasons":
+        assert want_failed["n7"] == (
+            "node(s) didn't match node selector; Insufficient resources")
+    if case == "unknown-name":
+        assert want_failed["ghost"] == "node not found in extender cache"
+    # over every name, the unknown one too, `prioritize` gives -inf a 0
+    _p, _f, want_all = _reference_verbs(be, pod, [], names)
+    again = be.prioritize(ExtenderArgs(pod=v1, node_names=names))
+    assert [(h.host, h.score) for h in again] == want_all
+    assert len({s for _h, s in want_all}) > 1
+
+
+def test_scores_round_half_to_even_as_the_loop_did():
+    """Raw scores that land on x.5 after scaling: `np.rint` in float64
+    against `round` over Python floats."""
+    import numpy as np
+    from kubernetes_tpu.extender.backend import _Evaluation
+
+    be = _small_mirror()
+    raw = np.full(16, -np.inf, np.float32)
+    raw[:9] = [0.0, 0.5, 1.5, 2.5, 3.5, 10.0, 6.5, 0.1, 9.5]
+    be._kept = _Evaluation("u-asks", be._epoch, NAMES, np.ones(16, bool),
+                           np.zeros(16, np.int32), raw)
+    prio = be.prioritize(ExtenderArgs(pod=pod_to_v1(_asking_pod("plain")),
+                                      node_names=NAMES + ["ghost"]))
+    assert prio.scores == [round(float(s)) for s in raw[:9]] + [0]
+    assert prio.scores[:5] == [0, 0, 2, 2, 4]
+
+
+def test_the_epoch_counts_every_change_from_many_threads():
+    """The informers' threads feed the mirror without the verbs' lock: with
+    more threads than cores and a tiny switch interval no change is made
+    without its count, while a verb thread keeps evaluating."""
+    import sys
+    import threading
+
+    be = _small_mirror()
+    epoch, threads, each = be._epoch, 16, 50
+    pod = pod_to_v1(_asking_pod("plain"))
+    stop = threading.Event()
+
+    def feed(t):
+        for i in range(each):
+            if i % 2:
+                be.observe_node(mknode(f"extra-{t}", cpu=1 + i % 3))
+            else:
+                be.observe_pod(mkpod(f"foreign-{t}-{i}", cpu="10m",
+                                     node_name=f"n{t % 9}"))
+
+    def ask():
+        while not stop.is_set():
+            be.filter(ExtenderArgs(pod=pod, node_names=NAMES))
+            be.prioritize(ExtenderArgs(pod=pod, node_names=NAMES))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        asker = threading.Thread(target=ask)
+        feeders = [threading.Thread(target=feed, args=(t,))
+                   for t in range(threads)]
+        for th in [asker] + feeders:
+            th.start()
+        for th in feeders:
+            th.join(timeout=60)
+        stop.set()
+        asker.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not asker.is_alive() and not any(f.is_alive() for f in feeders)
+    assert be._epoch - epoch == threads * each
+    # and the mirror the verbs see at rest is the fed one
+    _p, _f, want = _reference_verbs(be, pod_from_v1(pod), [], NAMES)
+    prio = be.prioritize(ExtenderArgs(pod=pod, node_names=NAMES))
+    assert [(h.host, h.score) for h in prio] == want
+
+
+def test_a_priority_list_encodes_itself_as_json_dumps_would():
+    from kubernetes_tpu.extender import HostPriorityList
+
+    for hosts, scores in ([], []), (["n0"], [10]), (
+            ['quo"te', "back\\slash", "nö-ascii", "tab\there", "n1"],
+            [0, 3, 10, 7, 250]):
+        prio = HostPriorityList(hosts, scores)
+        assert prio.encode() == json.dumps(prio.to_json()).encode()
+        assert [(h.host, h.score) for h in prio] == list(zip(hosts, scores))
+        assert len(prio) == len(hosts)
+
+
+def _counting(be, monkeypatch):
+    """[snapshots taken] of the backend's mirror, whatever its telemetry."""
+    taken, real = [], be.cache.snapshot
+
+    def snapshot(*a, **kw):
+        taken.append(1)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(be.cache, "snapshot", snapshot)
+    return taken
+
+
+def _evaluations():
+    from kubernetes_tpu.extender.backend import EXTENDER_EVALUATIONS as ev
+
+    return ev.value(result="computed"), ev.value(result="reused")
+
+
+@pytest.mark.parametrize("telemetry", ["on", "off"])
+def test_prioritize_after_filter_runs_no_program_and_no_snapshot(
+        telemetry, monkeypatch):
+    if telemetry == "off":
+        monkeypatch.setenv("KTPU_TELEMETRY", "0")
+    be = _small_mirror()
+    taken, before = _counting(be, monkeypatch), _evaluations()
+    pod = pod_to_v1(_asking_pod("anti"))
+    flt = be.filter(ExtenderArgs(pod=pod, node_names=NAMES))
+    assert len(taken) == 1
+    be.prioritize(ExtenderArgs(pod=pod, node_names=flt.node_names))
+    assert len(taken) == 1
+    computed, reused = _evaluations()
+    assert (computed - before[0], reused - before[1]) == (1, 1)
+    be.flush_record()
+    records = be.telemetry.recorder.records()
+    if telemetry == "off":
+        assert records == []
+        return
+    (rec,) = records
+    assert rec["verbs"] == ["filter", "prioritize"]
+    assert (rec["dispatches"], rec["snapshots"]) == (1, 1)
+    assert (rec["evaluations"], rec["eval_reused"]) == (1, 1)
+
+
+def _between(case, be):
+    """What happens between a pod's `filter` and its `prioritize`."""
+    if case == "a-pod-bound-by-someone-else":
+        be.observe_pod(mkpod("foreign", cpu="2", node_name="n0"))
+    elif case == "a-node-update":
+        be.observe_node(mknode("n2", cpu=2, labels={HOST: "n2", ZONE: "z2",
+                                                    "pool": "a"}))
+    elif case == "a-node-delete":
+        be.forget_node("n6")
+    elif case == "a-pod-delete":
+        be.forget_pod("default/web-0-0")
+    elif case == "bind-before-prioritize":
+        res = be.bind(ExtenderBindingArgs(
+            pod_name="asks", pod_namespace="default", pod_uid="u-asks",
+            node="n0"))
+        assert res.error == ""
+
+
+@pytest.mark.parametrize("case", [
+    "a-pod-bound-by-someone-else", "a-node-update", "a-node-delete",
+    "a-pod-delete", "bind-before-prioritize"])
+def test_a_mirror_that_moved_between_the_verbs_is_evaluated_afresh(
+        case, monkeypatch):
+    be = _small_mirror(binder=lambda pod, node: True)
+    pod = _asking_pod("plain")
+    be.filter(ExtenderArgs(pod=pod_to_v1(pod), node_names=NAMES))
+    _between(case, be)
+    taken, before = _counting(be, monkeypatch), _evaluations()
+    prio = be.prioritize(ExtenderArgs(pod=pod_to_v1(pod), node_names=NAMES))
+    computed, reused = _evaluations()
+    assert (len(taken), computed - before[0], reused - before[1]) == (1, 1, 0)
+    _p, _f, want = _reference_verbs(be, pod, [], NAMES)
+    assert [(h.host, h.score) for h in prio] == want
+    # and what it was evaluated afresh for is kept in its turn
+    n = len(taken)      # the reference took a snapshot of its own
+    be.prioritize(ExtenderArgs(pod=pod_to_v1(pod), node_names=NAMES[:3]))
+    assert len(taken) == n and _evaluations()[1] - reused == 1
+
+
+@pytest.mark.parametrize("case", ["another-uid", "no-filter-before"])
+def test_a_prioritize_that_is_not_the_kept_pods_is_evaluated_afresh(
+        case, monkeypatch):
+    be = _small_mirror()
+    pod = _asking_pod("anti")
+    if case == "another-uid":
+        other = mkpod("other", cpu="100m", uid="u-other")
+        be.filter(ExtenderArgs(pod=pod_to_v1(other), node_names=NAMES))
+    taken, before = _counting(be, monkeypatch), _evaluations()
+    prio = be.prioritize(ExtenderArgs(pod=pod_to_v1(pod), node_names=NAMES))
+    computed, reused = _evaluations()
+    assert (len(taken), computed - before[0], reused - before[1]) == (1, 1, 0)
+    _p, _f, want = _reference_verbs(be, pod, [], NAMES)
+    assert [(h.host, h.score) for h in prio] == want
+    assert {s for (h, s) in want if h in ("n1", "n2", "n5")} == {0}
+
+
+@pytest.mark.parametrize("echo_onto, reused_want", [("the-assumed-node", 1),
+                                                    ("another-node", 0)])
+def test_the_echo_of_the_backends_own_binding(echo_onto, reused_want,
+                                              monkeypatch):
+    """`bind` assumes the first pod on n3; its informer echo lands between
+    the second pod's two verbs. Confirmed onto n3 it changes no row and the
+    kept evaluation stands; onto another node the mirror has moved."""
+    be = _small_mirror(binder=lambda pod, node: True)
+    first = mkpod("first", cpu="1", uid="u-first", labels={"app": "db"})
+    be.filter(ExtenderArgs(pod=pod_to_v1(first), node_names=NAMES))
+    assert be.bind(ExtenderBindingArgs(
+        pod_name="first", pod_namespace="default", pod_uid="u-first",
+        node="n3")).error == ""
+    assert be.cache.is_assumed("default/first")
+
+    pod = _asking_pod("anti")
+    flt = be.filter(ExtenderArgs(pod=pod_to_v1(pod), node_names=NAMES))
+    assert "n3" in flt.failed_nodes      # the assumed pod is counted there
+    generation = be.cache.generation
+    first.node_name = "n3" if echo_onto == "the-assumed-node" else "n4"
+    be.observe_pod(first)
+    assert not be.cache.is_assumed("default/first")
+    assert be.cache.generation > generation     # the cache's own count moves
+    taken, before = _counting(be, monkeypatch), _evaluations()
+    prio = be.prioritize(ExtenderArgs(pod=pod_to_v1(pod), node_names=NAMES))
+    computed, reused = _evaluations()
+    assert (len(taken), reused - before[1]) == (1 - reused_want, reused_want)
+    assert computed - before[0] == 1 - reused_want
+    # either way the scores are those of a snapshot taken now
+    _p, _f, want = _reference_verbs(be, pod, [], NAMES)
+    assert [(h.host, h.score) for h in prio] == want
+    moved_to = dict(want)[first.node_name]
+    assert moved_to == 0
+
+
+def test_a_filter_always_evaluates_and_an_expired_assume_moves_the_epoch():
+    be = _small_mirror(binder=lambda pod, node: True)
+    pod = pod_to_v1(_asking_pod("plain"))
+    before = _evaluations()
+    for _ in range(2):      # a retry of the stock scheduler: the same UID
+        be.filter(ExtenderArgs(pod=pod, node_names=NAMES))
+    assert _evaluations()[0] - before[0] == 2
+    be.bind(ExtenderBindingArgs(pod_name="asks", pod_namespace="default",
+                                pod_uid="u-asks", node="n0"))
+    # its echo never comes: the next filter's cleanup expires it
+    epoch = be._epoch
+    be.cache._ttl = 0.0
+    be.cache.finish_binding("default/asks", be.telemetry.clock() - 1.0)
+    flt = be.filter(ExtenderArgs(pod=pod_to_v1(mkpod("next", uid="u-next")),
+                                 node_names=NAMES))
+    assert be.cache.get_pod("default/asks") is None and be._epoch > epoch
+    assert "n0" in flt.node_names
 
 
 # --------------------------------------------------------------------------- #
@@ -571,16 +977,18 @@ def test_one_record_a_pod_with_its_phases_counters_and_children():
         assert anti["stats"]["scheduled"] == 1
         phases = [name for name, _dt in anti["phases"]]
         assert phases == [
-            "decode", "snapshot", "dispatch", "readback",   # filter
-            "dispatch", "readback", "answer",               # its reasons
-            "caller", "decode", "snapshot", "dispatch", "readback", "answer",
+            "decode", "snapshot", "dispatch", "readback", "answer",  # filter
+            "caller", "decode", "answer",           # prioritize: kept arrays
             "caller", "decode", "bind-commit", "answer"]
         assert anti["duration_s"] == pytest.approx(
             sum(dt for _name, dt in anti["phases"]), abs=2e-3)
-        # an anti-affinity pod is refused somewhere: three dispatches; a
-        # pod every node takes needs no reasons: two
-        assert (anti["dispatches"], anti["snapshots"]) == (3, 2)
-        assert (spread["dispatches"], spread["snapshots"]) == (2, 2)
+        # one evaluation a pod, refused somewhere or not: `filter`'s one
+        # snapshot and one program, and `prioritize` cut from its arrays
+        # (the previous pod's echo between the two verbs does not move the
+        # mirror's epoch)
+        for rec in (anti, spread):
+            assert (rec["dispatches"], rec["snapshots"]) == (1, 1)
+            assert (rec["evaluations"], rec["eval_reused"]) == (1, 1)
         assert spread["feasible"] == 64 > anti["feasible"]
         assert set(anti["device_split"]) == {"launch_s", "execute_s",
                                              "readback_s"}
@@ -638,8 +1046,7 @@ def test_no_verb_compiles_after_start_returns():
     jax.monitoring.register_event_duration_secs_listener(listen)
 
     with served_cluster() as (client, served, groups):
-        assert [name for _d, name in served.warm_log] == [
-            "filter", "diagnose", "prioritize"]
+        assert [name for _d, name in served.warm_log] == ["evaluate"]
         assert [name for name, _s in served.start_log] == [
             "nodes-sync", "pods-sync", "compile-ahead", "socket"]
         names = [f"node-{i}" for i in range(64)]
@@ -680,8 +1087,7 @@ def test_no_verb_compiles_after_start_returns():
             "start/pods-sync/list/apiserver.list/store.list/kv",
             "start/pods-sync/index", "start/pods-sync/handlers/decode",
             "start/nodes-sync/handlers/decode",
-            "start/compile-ahead/snapshot", "start/compile-ahead/filter",
-            "start/compile-ahead/diagnose", "start/compile-ahead/prioritize",
+            "start/compile-ahead/snapshot", "start/compile-ahead/evaluate",
             "start/compile-ahead/patch-ladder"}
         assert loop["children"]["start/pods-sync/handlers/decode"][0] == 128
         assert len(later) == 3 and not any("loop" in r for r in later)

@@ -471,7 +471,8 @@ def test_the_four_setup_metrics_through_their_files():
                               if k != "xla_total"}]})
         assert not set(want) & set(old)
     mine = [m for m in bench["per_layer"] if m["name"] in want]
-    assert len(mine) == 4 and bench["per_layer"][-4:] == mine
+    at = bench["per_layer"].index(mine[0])   # appended as one block
+    assert len(mine) == 4 and bench["per_layer"][at:at + 4] == mine
     assert all(m["moves"] == "setup_s" and m["better"] == "lower"
                and m["layer"] == "dispatch / engines" for m in mine)
 
