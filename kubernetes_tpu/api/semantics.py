@@ -376,6 +376,58 @@ def balanced_allocation_score(req: Resources, used: Resources, alloc: Resources)
     return int(100 - abs(cpu - mem) * 100)
 
 
+def _resource_amount(res: Resources, name: str) -> int:
+    fixed = {"cpu": res.milli_cpu, "memory": res.memory_kib,
+             "ephemeral-storage": res.ephemeral_kib}
+    return fixed[name] if name in fixed else dict(res.scalars).get(name, 0)
+
+
+def broken_linear(shape: Sequence[Tuple[int, int]], p: int) -> int:
+    """buildBrokenLinearFunction (requested_to_capacity_ratio.go) through
+    `shape`'s (utilization, score) points, utilization ascending; Go's
+    integer division truncates toward zero."""
+    for i, (x, y) in enumerate(shape):
+        if p <= x:
+            if i == 0:
+                return y
+            x0, y0 = shape[i - 1]
+            num = (y - y0) * (p - x0)
+            q = abs(num) // (x - x0)
+            return y0 + (q if num >= 0 else -q)
+    return shape[-1][1]
+
+
+def requested_to_capacity_ratio_score(
+    req: Resources, used: Resources, alloc: Resources,
+    shape: Sequence[Tuple[int, int]] = ((0, 0), (100, 100)),
+    resources: Sequence[Tuple[str, int]] = (("cpu", 1), ("memory", 1)),
+) -> int:
+    """requested_to_capacity_ratio.go (v1.17), 0..100: per resource of the
+    weight map `100 - (cap - total) * 100 / cap` in integers (100 where the
+    node has none of it or total > cap; a pod that asks no cpu / no memory
+    counts the non-zero defaults 100m / 200Mi), through the shape (scores
+    already on the 0..100 scale), then the weighted mean over the resources
+    whose score is positive, math.Round-ed. `used` is what the node's pods
+    ask (this repo counts a bound pod's own requests, not their non-zero
+    defaults: docs/PARITY.md 6)."""
+    num = den = 0
+    for name, weight in resources:
+        r = _resource_amount(req, name)
+        if r == 0 and name == "cpu":
+            r = 100
+        if r == 0 and name == "memory":
+            r = 200 * 1024
+        total = _resource_amount(used, name) + r
+        cap = _resource_amount(alloc, name)
+        util = 100 if cap == 0 or total > cap \
+            else 100 - (cap - total) * 100 // cap
+        s = broken_linear(shape, util)
+        if s > 0:
+            num += s * weight
+            den += weight
+    return 0 if den == 0 else (2 * num + den) // (2 * den)
+
+
 def taint_toleration_score(pod: Pod, node: Node) -> int:
     """taint_toleration.go: count of intolerable PreferNoSchedule taints,
     reduced to 0..100 (fewer = better) by reduce (max-normalized elsewhere);

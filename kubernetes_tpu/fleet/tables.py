@@ -94,7 +94,7 @@ def abstract_fleet_args(d: Dims, K: int, mesh=None):
     fleet sharding (leading axis split) and the scalars replicate — the
     AOT compile produces the same GSPMD placement the live fleet path
     dispatches."""
-    from ..ops.lattice import default_engine_config
+    from ..ops.lattice import abstract_engine_config
     from ..sched.prewarm import abstract_cycle_args
 
     (tables, pending, keys, existing, _hw, _ecfg,
@@ -128,7 +128,7 @@ def abstract_fleet_args(d: Dims, K: int, mesh=None):
     return (stack_tables, stack(pending),
             (vec(jnp.int32), vec(jnp.int32)), stack(existing),
             vec(jnp.float32), scalar_f32,
-            jax.tree.map(lambda _: scalar_f32, default_engine_config()))
+            abstract_engine_config(rep))
 
 
 class FleetStack:

@@ -15,6 +15,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..api.types import RES_CPU, RES_EPHEMERAL, RES_MEM
 from ..ops.assign import mask_components
 from ..ops.fit import resource_scores_row
 from ..ops.interpod import soft_affinity_row
@@ -206,48 +207,106 @@ class NodeLabel(ScorePlugin):
         return jnp.broadcast_to(score[None, :], (P, N))
 
 
-class RequestedToCapacityRatio(ScorePlugin):
-    """requestedtocapacityratio/ — broken-linear utilization shape
-    (priorities/requested_to_capacity_ratio.go:30-146). Config: shape points
-    [(utilization%, score)], default [(0,100),(100,0)] = least-utilized."""
+#: a resource of RequestedToCapacityRatio's weight map -> its slot of the R
+#: axis, for the names with a fixed slot (api/types.py RES_*); an extended
+#: resource's slot follows its id in the vocab (state/vocab.py resources)
+_FIXED_RESOURCE_SLOT = {"cpu": RES_CPU, "memory": RES_MEM,
+                        "ephemeral-storage": RES_EPHEMERAL}
 
-    def __init__(self, shape=((0, 100), (100, 0))):
-        # accept both the reference arg format [{"utilization": u, "score" : s}]
-        # and plain (u, s) pairs
-        pts = []
-        for p in shape:
-            if isinstance(p, dict):
-                pts.append((float(p["utilization"]), float(p["score"])))
-            else:
-                pts.append((float(p[0]), float(p[1])))
-        self.shape = tuple(pts)
+
+def rtc_arguments(args: Optional[dict]):
+    """RequestedToCapacityRatioArguments (scheduler/api/types.go; the
+    plugin's args of later versions carry the same two fields) ->
+    `(points, resources)`: the shape as (utilization, score) pairs with the
+    score scaled from the argument's 0..10 to the 0..100 of a node score
+    (factory/plugins.go buildScoringFunctionShapeFromRequestedToCapacity
+    RatioArguments), the weight map as (name, weight) pairs, cpu 1 and
+    memory 1 where the argument names none. Raises ValueError on what
+    upstream's validation refuses, and on what the fused row cannot carry."""
+    from ..ops.lattice import RTC_POINTS
+
+    args = args or {}
+    raw = args.get("shape") \
+        or [{"utilization": 0, "score": 10}, {"utilization": 100, "score": 0}]
+    points = []
+    for p in raw:
+        u, sc = (p["utilization"], p["score"]) if isinstance(p, dict) else p
+        if int(u) != u or int(sc) != sc:
+            raise ValueError(f"shape point {p!r}: integers wanted")
+        points.append((int(u), int(sc)))
+    if len(points) > RTC_POINTS:
+        raise ValueError(f"shape has {len(points)} points, at most "
+                         f"{RTC_POINTS} are carried")
+    for i, (u, sc) in enumerate(points):
+        if not 0 <= u <= 100 or not 0 <= sc <= 10:
+            raise ValueError(f"shape point {(u, sc)}: utilization 0..100 "
+                             "and score 0..10 wanted")
+        if i and u <= points[i - 1][0]:
+            raise ValueError("shape utilization values must be sorted "
+                             "and strictly increasing")
+    resources = []
+    for r in args.get("resources") or [{"name": "cpu", "weight": 1},
+                                       {"name": "memory", "weight": 1}]:
+        name, weight = (r["name"], r.get("weight", 1)) \
+            if isinstance(r, dict) else r
+        if int(weight) != weight or weight < 1:
+            raise ValueError(f"resource {name!r}: weight {weight!r} < 1")
+        if name not in _FIXED_RESOURCE_SLOT and "/" not in name:
+            raise ValueError(f"resource {name!r} is neither cpu, memory, "
+                             "ephemeral-storage nor an extended resource")
+        resources.append((name, int(weight)))
+    return tuple((u, sc * 10) for u, sc in points), tuple(resources)
+
+
+def rtc_arrays(points, resources, resource_slot):
+    """The three `EngineConfig.rtc_*` arrays of `rtc_arguments`' result.
+    `resource_slot(name)` gives an extended resource's slot of the R axis
+    (interning it: `NUM_FIXED_RES + vocab.resources.intern(name)`)."""
+    import numpy as np
+
+    from ..ops.lattice import RTC_POINTS, RTC_SLOTS
+
+    pts = list(points) + [points[-1]] * (RTC_POINTS - len(points))
+    w = np.zeros((RTC_SLOTS,), np.float32)
+    for name, weight in resources:
+        slot = _FIXED_RESOURCE_SLOT.get(name)
+        if slot is None:
+            slot = resource_slot(name)
+        if slot >= RTC_SLOTS:
+            raise ValueError(f"resource {name!r} sits in slot {slot} of the "
+                             f"resource axis; {RTC_SLOTS} are carried")
+        w[slot] += weight
+    return (np.asarray([p[0] for p in pts], np.float32),
+            np.asarray([p[1] for p in pts], np.float32), w)
+
+
+class RequestedToCapacityRatio(ScorePlugin):
+    """requestedtocapacityratio/ — broken-linear utilization shape over the
+    resources of a weight map (priorities/requested_to_capacity_ratio.go).
+    Config: the argument's `shape` ([{"utilization": u, "score": 0..10}])
+    and `resources` ([{"name", "weight"}], default cpu 1, memory 1). The
+    score itself lives in the engines' fused row (ops/fit.py rtc_score_row,
+    weight `EngineConfig.w_rtc`), which this plugin evaluates against the
+    cycle-start state: the spec the tests hold the fused row to."""
+
+    def __init__(self, shape=None, resources=None, resource_slot=None):
+        self.points, self.resources = rtc_arguments(
+            {"shape": shape, "resources": resources})
+        # the slot of an extended resource of the map; the config wiring
+        # (SchedulerServer) hands the encoder's vocab in
+        self.resource_slot = resource_slot
 
     def score_matrix(self, state: CycleState, ctx: TensorContext):
-        tables = ctx.tables
+        from ..ops.fit import rtc_score_row
 
-        xs = jnp.array([p[0] for p in self.shape], jnp.float32)
-        ys = jnp.array([p[1] for p in self.shape], jnp.float32)
+        tables = ctx.tables
+        xs, ys, w = (jnp.asarray(a) for a in rtc_arrays(
+            self.points, self.resources, self.resource_slot))
 
         def row(c):
             req_vec = tables.reqs.vec[tables.classes.rid[c]]
-            total = tables.nodes.used + req_vec[None, :]
-            cap = tables.nodes.alloc
-
-            def util(t, cp):
-                return jnp.where(
-                    cp > 0,
-                    100.0 * t.astype(jnp.float32)
-                    / jnp.maximum(cp.astype(jnp.float32), 1.0),
-                    0.0)
-
-            def eval_shape(u):
-                # buildBrokenLinearFunction: clamp below/above, interpolate
-                u = jnp.clip(u, xs[0], xs[-1])
-                return jnp.interp(u, xs, ys)
-
-            s_cpu = eval_shape(util(total[:, 0], cap[:, 0]))
-            s_mem = eval_shape(util(total[:, 1], cap[:, 1]))
-            return (s_cpu + s_mem) / 2.0
+            return rtc_score_row(req_vec, tables.nodes.used,
+                                 tables.nodes.alloc, xs, ys, w)
 
         return jax.vmap(row)(ctx.pending.cls)
 
@@ -348,6 +407,7 @@ FUSED_SCORE_PLUGINS = frozenset({
     "NodeResourcesLeastAllocated", "NodeResourcesBalancedAllocation",
     "NodeResourcesMostAllocated", "NodeAffinityScore", "TaintToleration",
     "InterPodAffinity", "PodTopologySpread", "SelectorSpread", "ImageLocality",
+    "RequestedToCapacityRatio",
     # registry alias for SelectorSpread (default_registry.go keeps both
     # names); it must not leak into the class-pure extras path — its score
     # depends on in-cycle placements
@@ -435,8 +495,8 @@ class Coscheduling(Plugin):
 
 def extra_score_plugins(framework) -> tuple:
     """(plugin, weight) pairs for configured score plugins OUTSIDE the fused
-    set — NodeLabel, RequestedToCapacityRatio, ResourceLimits,
-    NodePreferAvoidPods, or any custom registration. These are class-pure
+    set — NodeLabel, ResourceLimits, NodePreferAvoidPods, or any custom
+    registration. These are class-pure
     (their scores depend only on (class, node), not on in-cycle placement),
     so the fused dispatch evaluates them once per cycle as a [SC, N] bias
     added to the static score lattice."""
@@ -481,7 +541,8 @@ def default_registry() -> Registry:
         "ImageLocality": lambda cfg: ImageLocality(),
         "NodeLabel": lambda cfg: _make_node_label(cfg or {}),
         "RequestedToCapacityRatio": lambda cfg: RequestedToCapacityRatio(
-            shape=(cfg or {}).get("shape", ((0, 100), (100, 0)))),
+            shape=(cfg or {}).get("shape"),
+            resources=(cfg or {}).get("resources")),
         "NodeResourcesResourceLimits": lambda cfg: ResourceLimits(),
         "Coscheduling": lambda cfg: Coscheduling(
             timeout=float((cfg or {}).get("permitWaitingTimeSeconds", 30.0))),

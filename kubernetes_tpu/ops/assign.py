@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from ..state.arrays import Array, ClusterTables, PodArrays
 from ..state.dims import affinity_agg
-from .fit import fit_row, resource_scores_row
+from .fit import fit_row, resource_scores_row, rtc_score_row
 from .interpod import (TermCounts, affinity_rows, soft_affinity_row,
                        term_domain_counts)
 from .lattice import CycleArrays
@@ -62,6 +62,10 @@ class AssignResult(NamedTuple):
     # scalar i32, the rounds the waves engine's loop ran (ops/waves.py);
     # None from the scan and from the gang loop, which counts its own
     rounds: Any = None
+    # [3] i32 from the waves engine (ops/waves.py, "Fill"): the classes of
+    # the batch that fill, the pods they placed, the rounds in which one
+    # placed any; None from the scan and from the gang loop
+    fill: Any = None
 
 
 def queue_order(pods: PodArrays) -> Array:
@@ -373,10 +377,18 @@ def score_combine_row(
     w = cyc.ecfg
     req_vec = tables.reqs.vec[classes.rid[cls]]
     least, balanced, most = resource_scores_row(req_vec, used, nodes.alloc)
+    # (a configuration without the priority, the default provider's among
+    # them, does not pay for its arithmetic: the weight is traced, so the
+    # program is one and the branch is the device's to take)
+    rtc = jax.lax.cond(
+        w.w_rtc != 0,
+        lambda: rtc_score_row(req_vec, used, nodes.alloc, w.rtc_x, w.rtc_y,
+                              w.rtc_w),
+        lambda: jnp.zeros(used.shape[:1], jnp.float32))
     return (cyc.static.score[cls] + least * w.w_least
             + balanced * w.w_balanced + most * w.w_most
             + ctx.soft_ip * w.w_interpod + ctx.even_soft * w.w_even
-            + ctx.ssel * w.w_ssel)
+            + ctx.ssel * w.w_ssel + rtc * w.w_rtc)
 
 
 def score_row(
@@ -488,7 +500,7 @@ EXPLAIN_PREDICATES = ("node_match", "taints", "fit", "ports", "affinity",
 #: score-component order of ExplainResult.score_parts (prioritizeNodes'
 #: weighted sum, decomposed)
 EXPLAIN_SCORE_COMPONENTS = ("static", "least", "balanced", "most",
-                            "interpod", "even", "ssel")
+                            "interpod", "even", "ssel", "rtc")
 #: candidate nodes reported per pod (clamped to N at trace time)
 EXPLAIN_TOPK = 3
 
@@ -505,7 +517,7 @@ class ExplainResult(NamedTuple):
     rejected_any: Array    # [P] i32 — valid_nodes - feasible_nodes
     top_nodes: Array       # [P, K] i32 — best feasible nodes by score (-1 pad)
     top_scores: Array      # [P, K] f32
-    score_parts: Array     # [P, 7] f32 — component breakdown at part_node
+    score_parts: Array     # [P, 8] f32 — component breakdown at part_node
     part_node: Array       # [P] i32 — chosen node if scheduled, else best
     #                        feasible node, else -1
 
@@ -665,13 +677,16 @@ def explain_assignments(
         req = tables.reqs.vec[tables.classes.rid[cls_safe]]  # [P, R]
         least, balanced, most = jax.vmap(resource_scores_row)(
             req, state.used[j][:, None, :], nodes.alloc[j][:, None, :])
+        rtc = jax.vmap(
+            lambda q, u, a: rtc_score_row(q, u, a, w.rtc_x, w.rtc_y, w.rtc_w)
+        )(req, state.used[j][:, None, :], nodes.alloc[j][:, None, :])
         parts = jnp.stack([
             cyc.static.score[cls_safe, j],
             least[:, 0] * w.w_least, balanced[:, 0] * w.w_balanced,
             most[:, 0] * w.w_most,
             ctx_at[:, 0] * w.w_interpod, ctx_at[:, 1] * w.w_even,
-            ctx_at[:, 2] * w.w_ssel,
-        ], axis=1)                                           # [P, 7]
+            ctx_at[:, 2] * w.w_ssel, rtc[:, 0] * w.w_rtc,
+        ], axis=1)                                           # [P, 8]
         return jnp.where((pn >= 0)[:, None], parts, 0.0)
 
     def cheap_score(_):

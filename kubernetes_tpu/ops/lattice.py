@@ -30,13 +30,22 @@ from .taints import taint_matrices, taint_toleration_score
 from .topospread import eligible_in_domain
 
 
+#: shape points / resource slots an EngineConfig carries for
+#: RequestedToCapacityRatio (a Policy with more is refused: sched/config.py)
+RTC_POINTS = 8
+RTC_SLOTS = 16
+
+
 class EngineConfig(NamedTuple):
     """How KubeSchedulerConfiguration's plugin composition reaches the fused
     one-dispatch engines: per-component filter enables and score weights as
     TRACED f32 scalars — config changes never recompile, a disabled plugin is
     flag/weight 0. Components correspond 1:1 to the in-tree plugin names
     (framework/plugins.py); plugins outside this fixed set (NodeLabel,
-    RequestedToCapacityRatio, …) run through the Framework plugin path.
+    NodePreferAvoidPods, …) run through the Framework plugin path.
+    RequestedToCapacityRatio's arguments ride as traced ARRAYS of fixed
+    length (`rtc_*`): two Policies that differ in the shape or the weights
+    run one executable.
 
     The reference analog is the plugin set built by CreateFromConfig/
     CreateFromKeys (factory.go:309,387) driving which predicates/priorities
@@ -70,6 +79,15 @@ class EngineConfig(NamedTuple):
     # same-wave intra-class spreading. The best node always qualifies, so
     # feasibility is untouched; tied clusters are unaffected.
     w_window: Array = 100.0
+    # RequestedToCapacityRatio (ops/fit.py rtc_score_row): the priority's
+    # weight; the shape's points, utilization ascending and scores on the
+    # 0..100 scale, padded to RTC_POINTS by repeating the last; the weight
+    # of every resource slot of the R axis (state/vocab.py resources, the
+    # four fixed slots first), 0 = not in the map, padded to RTC_SLOTS
+    w_rtc: Array = 0.0
+    rtc_x: Array = np.zeros((RTC_POINTS,), np.float32)
+    rtc_y: Array = np.zeros((RTC_POINTS,), np.float32)
+    rtc_w: Array = np.zeros((RTC_SLOTS,), np.float32)
 
 
 def _strong_f32(x):
@@ -79,10 +97,13 @@ def _strong_f32(x):
     # strong-typed for jit. Already-normalized np.float32 leaves pass
     # through untouched so re-normalizing a config on the per-dispatch hot
     # path is free; other array leaves go through jnp.asarray.
-    if isinstance(x, np.float32):
+    if isinstance(x, np.float32) or (
+            isinstance(x, np.ndarray) and x.dtype == np.float32):
         return x
     if isinstance(x, (bool, int, float)):
         return np.float32(x)
+    if isinstance(x, np.ndarray):
+        return x.astype(np.float32)
     return jnp.asarray(x, jnp.float32)
 
 
@@ -114,6 +135,17 @@ def default_engine_config() -> EngineConfig:
             w_ssel=one,
         ))
     return _DEFAULT_ECFG
+
+
+def abstract_engine_config(sharding=None) -> EngineConfig:
+    """The EngineConfig a dispatch passes, as ShapeDtypeStructs: what an
+    ahead-of-time compile (sched/prewarm.py, fleet/tables.py) gives in its
+    place."""
+    import jax
+
+    return EngineConfig(*(
+        jax.ShapeDtypeStruct(np.shape(x), jnp.float32, sharding=sharding)
+        for x in default_engine_config()))
 
 
 def _on(flag: Array) -> Array:

@@ -60,6 +60,25 @@ class is monotone, with its run on a zero-progress or failing-prefix round
 otherwise. All of it sits under `lax.cond`s on "the batch has a pin": pins
 are DATA of the one program, and a batch without them pays a few selects.
 
+Fill (step 3 under a packing score). "One pod a node" is the reference's
+loop only where a pod's own placement makes its node LESS attractive to the
+next of its class (LeastAllocated, BalancedAllocation, the spreading
+scores). Under MostAllocated or a rising RequestedToCapacityRatio shape the
+loop does the opposite: the node it chose stays the best until it is full.
+A class FILLS where the configuration has no falling resource score and the
+class reads nothing its own placements move except `used`: no pin, port,
+volume, pod (anti-)affinity or spread term, and no preferred term or
+selector-spread owner at a positive weight (`_filling`). Such a class
+claims, along its score order, as many pods as FIT on the best node, then
+on the next (a fit count per node, `min over r of free_r / req_r`, and a
+prefix sum against the class's waiting pods: dense [SC, N] work, no scan).
+Step 5 takes a claim of k pods as k times the request, kept whole or lost
+whole, and the map back hands a kept node to k pods. Whether a class fills
+decides WHICH valid execution a round picks, never whether it is one: the
+replay invariant below rests on step 5 alone. All of it sits under
+`lax.cond`s on "a class of this batch fills": a batch without one runs the
+one-a-node claim as before and pays a few selects.
+
 How a round sees its nodes in order (steps 3 and the map back to pods): an
 order is a `lax.sort`, and whatever must be seen in that order is an OPERAND
 of the sort that makes it — the class's score order (`_score_order`: two keys,
@@ -121,6 +140,8 @@ class _WaveCarry(NamedTuple):
     done: Array       # [P] a PINNED pod is consumed (placed or failed): such
                       # pods leave their class's queue out of order, so
                       # `cursor` only counts them
+    filled: Array     # [2] i32: pods a FILLING class placed, and the rounds
+                      # in which one placed any
 
 
 def interaction_graph(tables: ClusterTables, cyc: CycleArrays) -> Array:
@@ -346,6 +367,49 @@ def _pin_nodes(nodes, pin: Array) -> Array:
                              -1), axis=1)
 
 
+def _filling(tables: ClusterTables, cyc: CycleArrays) -> Array:
+    """[SC] bool: the classes that fill (module docstring): the score of the
+    node a pod of the class lands on does not fall for the next one."""
+    classes, w = tables.classes, cyc.ecfg
+    rising = (w.rtc_y[1:] >= w.rtc_y[:-1]).all()
+    no_falling_score = ((w.w_least == 0) & (w.w_balanced == 0)
+                        & ((w.w_rtc == 0) | rising))
+    none = lambda ids: ~(ids >= 0).any(axis=1)
+
+    def empty(ids, *words):
+        """The class names no set, or (the encoder's set 0) an empty one."""
+        at = jnp.maximum(ids, 0)
+        return (ids < 0) | jnp.stack(
+            [(w[at] == 0).all(axis=1) for w in words]).all(axis=0)
+
+    ps, vs = tables.portsets, tables.volsets
+    plain = (none(classes.aff_terms) & none(classes.anti_terms)
+             & none(classes.tsc_term)
+             & empty(classes.portset, ps.pair_words, ps.wild_words,
+                     ps.trip_words)
+             & empty(classes.volset, vs.any_words)
+             & ~(classes.vol_priv > 0).any(axis=1))
+    unmoved = (((w.w_interpod == 0) | (none(classes.paff_terms)
+                                       & none(classes.panti_terms)))
+               & ((w.w_ssel == 0) | none(classes.ssel_terms)))
+    return classes.valid & no_falling_score & plain & unmoved
+
+
+def _fit_counts(req: Array, free: Array) -> Array:
+    """req [SC, R], free [N, R] -> [SC, N] how many pods of the class fit
+    on the node beside what it holds: the least `free_r / req_r` over the
+    resources the class asks for (the pod slot is one of them), at least 1.
+    Read only where the Filter row passed, so where one fits. A resource at
+    a time, as ops/fit.py rtc_score_row."""
+    least = jnp.full((req.shape[0], free.shape[0]), _I32_MAX, jnp.int32)
+    for r in range(req.shape[1]):
+        ask = req[:, r, None]                                 # [SC, 1]
+        least = jnp.minimum(least, jnp.where(
+            ask > 0, lax.div(free[None, :, r], jnp.maximum(ask, 1)),
+            _I32_MAX))
+    return jnp.maximum(least, 1)
+
+
 def assign_waves(
     tables: ClusterTables,
     cyc: CycleArrays,
@@ -405,8 +469,13 @@ def assign_waves(
     pin_safe = jnp.maximum(pin_node, 0)
     no_pods = jnp.zeros((P,), bool)
 
+    # ---- fill: see the module docstring. `any_fill` guards every filling
+    # step below ----
+    fills = (_filling(tables, cyc) & ~cls_pinned & (class_total > 0))  # [SC]
+    any_fill = fills.any()
+
     def body(carry: _WaveCarry) -> _WaveCarry:
-        state, cursor, placed, node_out, wave_out, waves, done = carry
+        state, cursor, placed, node_out, wave_out, waves, done, filled = carry
         remaining = class_total - cursor
         active = classes.valid & (remaining > 0)
 
@@ -510,9 +579,27 @@ def assign_waves(
         rot_pos = (node_ids[None, :] - offs[:, None]) % N     # [SC, N]
         allowed_n = _domain_quota_pass(
             tables, cyc, spread, adm_mask, neg_score, rot_pos, offs)
-        order_n, allowed = _score_order(neg_score, rot_pos, offs, allowed_n)
-        grank = jnp.cumsum(allowed.astype(jnp.int32), axis=1) - 1
-        A = _to_nodes(order_n, allowed & (grank < r[:, None]))
+        # K [SC, N]: the pods the class claims on the node
+        def claim_one():
+            order_n, allowed = _score_order(neg_score, rot_pos, offs,
+                                            allowed_n)
+            grank = jnp.cumsum(allowed.astype(jnp.int32), axis=1) - 1
+            return _to_nodes(order_n, allowed & (grank < r[:, None])
+                             ).astype(jnp.int32)
+
+        def claim_fill():
+            cnt_n = jnp.where(
+                fills[:, None],
+                _fit_counts(req_by_class, nodes.alloc - state.used), 1)
+            order_n, allowed, cnt = _score_order(neg_score, rot_pos, offs,
+                                                 allowed_n, cnt_n)
+            take = jnp.where(allowed, cnt, 0)
+            before = jnp.cumsum(take, axis=1) - take
+            return _to_nodes(order_n,
+                             jnp.clip(r[:, None] - before, 0, take))
+
+        K = lax.cond(any_fill, claim_fill, claim_one)
+        A = K > 0
 
         # per-node cross-class resolution in queue-rank order, as a scan
         # over CLASS BLOCKS: the cumulative passes need [block, N, …]
@@ -522,7 +609,7 @@ def assign_waves(
         # 5k nodes × 100k pods. Carries thread the exact same exclusive
         # prefixes across blocks, so the result is bit-identical.
         cord = rank_key                                       # [SC] perm
-        A_ord = A[cord]
+        K_ord = K[cord]
         req_ord = req_by_class[cord]                          # [SC, R]
         ps_ord = classes.portset[cord]
         psafe = jnp.maximum(ps_ord, 0)
@@ -555,15 +642,16 @@ def assign_waves(
 
         def block(carry, xs):
             cum_used, c_pa, c_pw, c_pt, c_va, c_vr, c_vc = carry
-            A_b, req_b, hp_b, pw_b, ww_b, tw_b, hv_b, va_b, vr_b, vp_b = xs
-            add = jnp.where(A_b[:, :, None], req_b[:, None, :], 0)
+            K_b, req_b, hp_b, pw_b, ww_b, tw_b, hv_b, va_b, vr_b, vp_b = xs
+            # a claim of k pods asks k times the request, whole or not at all
+            add = K_b[:, :, None] * req_b[:, None, :]
             cum_exc = (jnp.cumsum(add, axis=0) - add) + cum_used[None]
             # earlier same-wave classes consume free space; the pod itself
             # must fit per PodFitsResources semantics (zero scalar requests
             # ignore that scalar's free — fit._fit, predicates.go:800-845)
             free = nodes.alloc[None] - state.used[None] - cum_exc
-            fits = _fit(req_b[:, None, :], free)
-            keep = A_b & fits
+            fits = _fit(jnp.maximum(add, req_b[:, None, :]), free)
+            keep = (K_b > 0) & fits
 
             # ports: exclusive prefix over keep-after-resources (a class
             # that itself loses the port check still shadows later ones —
@@ -640,7 +728,7 @@ def assign_waves(
         )
         _, (keep_b, committed_b) = lax.scan(
             block, carry0,
-            (blocks_of(A_ord), blocks_of(req_ord), blocks_of(has_p),
+            (blocks_of(K_ord), blocks_of(req_ord), blocks_of(has_p),
              blocks_of(pairw), blocks_of(wildw), blocks_of(tripw),
              blocks_of(has_v), blocks_of(vanyw), blocks_of(vrww),
              blocks_of(vpriv)))
@@ -651,12 +739,13 @@ def assign_waves(
         addvc = committed_b[5].sum(axis=0)                    # [N, DR]
 
         A_final = jnp.zeros_like(A).at[cord].set(keep)
-        m = A_final.sum(axis=1).astype(jnp.int32)             # [SC]
+        K_final = jnp.where(A_final, K, 0)
+        m = K_final.sum(axis=1)                               # [SC]
         total = m.sum()
 
         # ---- commit ----
         with jax.named_scope("wave_commit"):
-            Ai = A_final.astype(jnp.int32)
+            Ai = K_final
             used2 = state.used + jnp.einsum("cn,cr->nr", Ai, req_by_class)
             CNT2 = state.CNT + cyc.TM.astype(jnp.int32) @ Ai
             HOLD2 = state.HOLD + cyc.has_anti.T.astype(jnp.int32) @ Ai
@@ -678,13 +767,31 @@ def assign_waves(
         # The kept ride to the head of their row in one sort, and every POD
         # looks its node up: P lookups, where a write per (class, node)
         # would be SC·N updates of which sum(m) ≤ P carry anything ----
-        _, _, kept_nodes = lax.sort(
-            (~A_final, neg_score, jnp.broadcast_to(node_ids, (SC, N))),
-            dimension=1, num_keys=2, is_stable=True)
         j = pos_of_pod - cursor[cls_of_pod]
         won = pods.valid & ~has_pin & (j >= 0) & (j < m[cls_of_pod])
-        node_out2 = jnp.where(
-            won, kept_nodes[cls_of_pod, jnp.clip(j, 0, N - 1)], node_out)
+        kept_keys = (~A_final, neg_score,
+                     jnp.broadcast_to(node_ids, (SC, N)))
+
+        def map_one():
+            _, _, kept_nodes = lax.sort(kept_keys, dimension=1, num_keys=2,
+                                        is_stable=True)
+            return kept_nodes[cls_of_pod, jnp.clip(j, 0, N - 1)]
+
+        def map_fill():
+            # a kept node goes to as many pods as it was kept for: the
+            # classes' rows end to end, each kept node's running count of
+            # pods, are ONE ascending sequence, and a pod's place in it is
+            # a binary search (P of them, each log(SC N) lookups)
+            _, _, kept_nodes, kept_k = lax.sort(
+                kept_keys + (K_final,), dimension=1, num_keys=2,
+                is_stable=True)
+            base = jnp.cumsum(m) - m                          # [SC]
+            upto = (base[:, None] + jnp.cumsum(kept_k, axis=1)).reshape(-1)
+            at = jnp.searchsorted(upto, base[cls_of_pod] + j, side="right")
+            return kept_nodes.reshape(-1)[jnp.clip(at, 0, SC * N - 1)]
+
+        node_out2 = jnp.where(won, lax.cond(any_fill, map_fill, map_one),
+                              node_out)
 
         # Failure consumption, two rules (both replay-sound):
         #  * global zero progress ⇒ state is frozen ⇒ every attempting
@@ -736,10 +843,13 @@ def assign_waves(
             any_pin, pinned_outcome, lambda: (no_pods, no_pods, consume))
         node_out2 = jnp.where(won_p, pin_node, node_out2)
         wave_out2 = jnp.where(won | won_p, waves, wave_out)
+        fill_m = jnp.sum(jnp.where(fills, m, 0))
         return _WaveCarry(
             state=state2, cursor=cursor + consume, placed=placed + m,
             node_out=node_out2, wave_out=wave_out2, waves=waves + 1,
             done=done | won_p | lost_p,
+            filled=filled + jnp.stack([fill_m, (fill_m > 0).astype(
+                jnp.int32)]),
         )
 
     cap = jnp.int32(max_waves if max_waves is not None else 2 * P + 2)
@@ -757,11 +867,14 @@ def assign_waves(
         wave_out=jnp.full((P,), -1, jnp.int32),
         waves=jnp.int32(0),
         done=no_pods,
+        filled=jnp.zeros((2,), jnp.int32),
     )
     final = lax.while_loop(cond, body, init_carry)
     node = final.node_out
-    result = AssignResult(node=node, feasible=node >= 0, state=final.state,
-                          rounds=final.waves)
+    result = AssignResult(
+        node=node, feasible=node >= 0, state=final.state, rounds=final.waves,
+        fill=jnp.concatenate([fills.sum(dtype=jnp.int32)[None],
+                              final.filled]))
     if return_waves:
         return result, final.wave_out
     return result

@@ -123,11 +123,17 @@ class KubeSchedulerConfiguration:
         adaptive = 50 - num_nodes // 125
         return max(adaptive, 5)
 
-    def engine_config(self):
+    def engine_config(self, resource_slot=None):
         """Lower the plugin composition into the fused engines' traced
         weights/flags (ops/lattice.py EngineConfig): a filter plugin absent
         from the set stops filtering; a score plugin absent scores 0; an
-        enabled score plugin carries its configured weight."""
+        enabled score plugin carries its configured weight, and
+        RequestedToCapacityRatio its shape and its per-resource weights.
+        `resource_slot(name)`: an extended resource's slot of the R axis in
+        the scheduler that will run this (SchedulerServer interns the
+        weight map's names when the config loads); without one a weight
+        map may name cpu, memory and ephemeral-storage only."""
+        from ..framework.plugins import rtc_arguments, rtc_arrays
         from ..ops.lattice import (
             EngineConfig, default_engine_config, strong_engine_config)
 
@@ -138,6 +144,11 @@ class KubeSchedulerConfiguration:
         def w(name: str) -> float:
             return float(self.score_weights.get(name, 1.0)) \
                 if name in sset else 0.0
+
+        rtc_x, rtc_y, rtc_w = rtc_arrays(
+            *rtc_arguments(
+                self.plugin_config.get("RequestedToCapacityRatio")),
+            resource_slot or _no_extended_resource)
 
         return strong_engine_config(EngineConfig(
             f_unsched=1.0 if "NodeUnschedulable" in fset else 0.0,
@@ -160,6 +171,8 @@ class KubeSchedulerConfiguration:
             w_even=w("PodTopologySpread"),
             w_ssel=max(w("SelectorSpread"), w("DefaultPodTopologySpread")),
             w_window=float(self.score_admission_window),
+            w_rtc=w("RequestedToCapacityRatio"),
+            rtc_x=rtc_x, rtc_y=rtc_y, rtc_w=rtc_w,
         )) if (self.plugins is not None or self.score_weights) \
             else strong_engine_config(default_engine_config()._replace(
                 w_window=float(self.score_admission_window)))
@@ -174,6 +187,12 @@ class KubeSchedulerConfiguration:
 
     def apply_feature_gates(self) -> None:
         DEFAULT_FEATURE_GATES.set_from_map(self.feature_gates)
+
+
+def _no_extended_resource(name: str) -> int:
+    raise ValueError(
+        f"RequestedToCapacityRatio names the extended resource {name!r}: "
+        "its slot is the scheduler's to give (engine_config(resource_slot))")
 
 
 def _plugin_set(d: dict) -> PluginSet:
@@ -304,6 +323,10 @@ def apply_policy(cfg: KubeSchedulerConfiguration, policy: dict) -> None:
     filters: List[str] = []
     for pr in policy.get("predicates", []):
         name = pr["name"] if isinstance(pr, dict) else pr
+        if isinstance(pr, dict) and pr.get("argument"):
+            raise ValueError(
+                f"predicate {name!r}: argument {sorted(pr['argument'])} is "
+                "not supported")
         mapped = PREDICATE_TO_PLUGIN.get(name)
         if mapped is None:
             # factory.go CreateFromConfig errors on unknown names; silently
@@ -316,7 +339,16 @@ def apply_policy(cfg: KubeSchedulerConfiguration, policy: dict) -> None:
     for pr in policy.get("priorities", []):
         name = pr["name"] if isinstance(pr, dict) else pr
         w = float(pr.get("weight", 1)) if isinstance(pr, dict) else 1.0
-        mapped = PRIORITY_TO_PLUGIN.get(name)
+        argument = pr.get("argument") if isinstance(pr, dict) else None
+        if argument:
+            # a priority with an argument is of the argument's kind,
+            # whatever its name (factory/plugins.go RegisterCustomPriority
+            # Function); one this scheduler cannot honour is an error, as
+            # an unknown name is: dropped, the Policy would run without it
+            mapped, args = _priority_argument(name, argument)
+            cfg.plugin_config[mapped] = args
+        else:
+            mapped = PRIORITY_TO_PLUGIN.get(name)
         if mapped is None:
             raise ValueError(f"invalid priority name {name!r} in Policy")
         if mapped not in scores:
@@ -338,6 +370,20 @@ def apply_policy(cfg: KubeSchedulerConfiguration, policy: dict) -> None:
             policy["hardPodAffinitySymmetricWeight"])
     cfg.extenders = cfg.extenders + tuple(
         _parse_extender(e) for e in policy.get("extenders", []))
+
+
+def _priority_argument(name: str, argument: dict) -> Tuple[str, dict]:
+    """A Policy priority's `argument` (scheduler/api/types.go
+    PriorityArgument) -> (framework plugin, its args)."""
+    from ..framework.plugins import rtc_arguments
+
+    kinds = [k for k, v in argument.items() if v]
+    if kinds == ["requestedToCapacityRatioArguments"]:
+        args = dict(argument["requestedToCapacityRatioArguments"])
+        rtc_arguments(args)   # refuse now what upstream's validation refuses
+        return "RequestedToCapacityRatio", args
+    raise ValueError(f"priority {name!r}: argument {sorted(argument)} is "
+                     "not supported")
 
 
 def _load_data(source) -> dict:

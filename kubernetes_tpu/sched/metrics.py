@@ -64,6 +64,18 @@ PIN_CLASSES = REG.gauge(
     "scheduler_pin_classes",
     "Scheduling classes that held the pinned pods of the last wave with "
     "any (a DaemonSet is one)")
+# fill and extended resources (ops/waves.py "Fill"), counted on waves
+# whose batch holds a filling class / a pod that asks an extended resource
+FILL_PODS = REG.counter(
+    "scheduler_fill_pods_total",
+    "Pods placed by classes whose round fills a node before it opens the "
+    "next (a packing score and nothing a placement of the class moves but "
+    "the node's requested resources)")
+EXTENDED_RESOURCE_PODS = REG.counter(
+    "scheduler_extended_resource_pods_total",
+    "Pods in a wave's batch that ask an extended resource, by resource and "
+    "result (scheduled: decided onto a node; unschedulable: no node had it)",
+    labels=("resource", "result"))
 # cache-consistency sweep (sched/debugger.py ConsistencySweeper — the kube
 # cacheComparer made periodic): divergences found between the resident
 # encoded state and informer truth, and self-heal re-encodes taken
@@ -281,6 +293,12 @@ def observe_wave(stats, queue_lengths, cache_counts) -> None:
         PINNED_PODS.inc(stats.pinned - stats.pinned_unfit, result="fit")
         PINNED_PODS.inc(stats.pinned_unfit, result="unfit")
         PIN_CLASSES.set(stats.pin_classes)
+    if stats.fill_pods:
+        FILL_PODS.inc(stats.fill_pods)
+    for name, (fit, unfit) in stats.extended_pods.items():
+        EXTENDED_RESOURCE_PODS.inc(fit, resource=name, result="scheduled")
+        EXTENDED_RESOURCE_PODS.inc(unfit, resource=name,
+                                   result="unschedulable")
     if isinstance(queue_lengths, dict):
         observe_queue_depths(queue_lengths)
     else:

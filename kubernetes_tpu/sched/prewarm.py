@@ -79,7 +79,7 @@ def abstract_cycle_args(d: Dims, gang: bool = False, mesh=None):
     import jax.numpy as jnp
 
     from ..ops.gang import GangArrays
-    from ..ops.lattice import default_engine_config
+    from ..ops.lattice import abstract_engine_config
     from ..state.arrays import ClusterTables
     from ..state.encode import Encoder
 
@@ -115,7 +115,7 @@ def abstract_cycle_args(d: Dims, gang: bool = False, mesh=None):
         )
     return (abstract_tables, abstract(pending), (scalar_i32, scalar_i32),
             abstract(existing), scalar_f32,
-            jax.tree.map(lambda _: scalar_f32, default_engine_config()),
+            abstract_engine_config(rep),
             gang_args)
 
 
@@ -129,7 +129,7 @@ def abstract_preempt_args(d: Dims, burst: int, mesh=None):
     import jax
     import jax.numpy as jnp
 
-    from ..ops.lattice import default_engine_config
+    from ..ops.lattice import abstract_engine_config
     from ..state.arrays import ClusterTables
     from ..state.encode import Encoder
 
@@ -158,7 +158,7 @@ def abstract_preempt_args(d: Dims, burst: int, mesh=None):
     pdb = jax.ShapeDtypeStruct((d.E,), jnp.bool_, sharding=rep)
     return (abstract_tables, abstract(existing), vec_i32, vec_i32, vec_i32,
             (scalar_i32, scalar_i32), pdb, scalar_f32,
-            jax.tree.map(lambda _: scalar_f32, default_engine_config()))
+            abstract_engine_config(rep))
 
 
 class BucketPrewarmer:

@@ -138,6 +138,12 @@ class CycleStats:
     pinned: int = 0
     pin_classes: int = 0
     pinned_unfit: int = 0
+    # fill (ops/waves.py "Fill"), on a wave whose batch holds a filling
+    # class only: the pods such classes placed; and extended resources, on
+    # a wave whose batch holds a pod that asks one: per resource name the
+    # pods that ask it, (decided onto a node, left unschedulable)
+    fill_pods: int = 0
+    extended_pods: Dict[str, Tuple[int, int]] = field(default_factory=dict)
 
 
 @dataclass
@@ -167,6 +173,7 @@ class Wave:
     attribution: Any = None         # the dispatch's ExplainResult, on host
     gang_verdict: Any = None        # ops/gang.py GangVerdict, on host
     rounds: Any = None              # the waves engine's rounds, on host
+    fill: Any = None                # its AssignResult.fill, on host
     # ---- set by commit ---- #
     explain: Optional[Dict[str, Any]] = None   # the explainer's rendering
 
@@ -917,7 +924,7 @@ class Scheduler:
             span.mark("dispatch")
             try:
                 (wave.node_idx, wave.attribution, wave.gang_verdict,
-                 wave.rounds) = handle.result()
+                 (wave.rounds, wave.fill)) = handle.result()
             except DispatchAbandonedError:
                 span.mark("readback")
                 return False
@@ -927,6 +934,7 @@ class Scheduler:
                         wave.node_idx)[:len(pending)][which_pinned] < 0))
                 if wave.rounds is not None:
                     wave.extra["pin_rounds"] = int(wave.rounds)
+            self._note_fill(wave, len(pending))
             span.mark("readback")
             return True
         finally:
@@ -952,14 +960,43 @@ class Scheduler:
             tr.child("pins", time.perf_counter() - t0)
         return which
 
+    def _note_fill(self, wave: Wave, k: int) -> None:
+        """At the tail of the `readback` phase: what the dispatch said of
+        its filling classes (`AssignResult.fill`), and which of the batch's
+        `k` pods ask an extended resource and were decided, onto the wave's
+        record and its stats. From the class column the snapshot built on
+        the host and the classes' request rows: per class, never per pod.
+        A batch without a filling class / an extended resource adds no
+        field."""
+        if wave.fill is not None and int(wave.fill[0]):
+            classes, pods, rounds = (int(x) for x in wave.fill)
+            wave.stats.fill_pods = pods
+            wave.extra.update(fill_classes=classes, fill_pods=pods,
+                              fill_rounds=rounds)
+            ecfg = self.engine_config
+            if ecfg is not None and float(ecfg.w_rtc) > 0:
+                # the resources of RequestedToCapacityRatio's weight map
+                # that the dispatch's EngineConfig carried to the device
+                wave.extra["rtc_resources"] = int(
+                    np.count_nonzero(np.asarray(ecfg.rtc_w)))
+        asked = self.cache.pending_extended(self.encoder, k)
+        if asked:
+            placed = np.asarray(wave.node_idx)[:k] >= 0
+            for name, which in asked.items():
+                n, fit = int(which.sum()), int((which & placed).sum())
+                wave.stats.extended_pods[name] = (fit, n - fit)
+            wave.extra["extended_pods"] = int(
+                np.logical_or.reduce(list(asked.values())).sum())
+
     def _gang_of(self, snap):
         return snap.gang if self._device_gangs else None
 
     def _engine_call(self, tables, pending, keys, existing, gang, dims,
                      engine: str, prewarmer=None, mesh=None):
         """The wave's one call into the engine (primary and fallback):
-        `(node, attribution or None, gang verdict or None, the waves
-        engine's rounds or None)`, still on the device."""
+        `(node, attribution or None, gang verdict or None, (the waves
+        engine's rounds or None, its fill counts or None))`, still on the
+        device."""
         explain = self.explainer is not None
         out = _schedule_batch(
             tables, pending, keys, dims.D, existing,
@@ -970,7 +1007,7 @@ class Scheduler:
             gang=gang, dims=dims, prewarmer=prewarmer, mesh=mesh,
             explain=explain, engine=engine)
         res, exp = out if explain else (out, None)
-        return res.node, exp, res.gang, res.rounds
+        return res.node, exp, res.gang, (res.rounds, res.fill)
 
     @staticmethod
     def _get_attribution(exp_dev):
@@ -985,7 +1022,7 @@ class Scheduler:
 
     def _read_back(self, node, exp, verdict, rounds):
         """A dispatch's results on the host: `(node_idx, attribution,
-        gang verdict, rounds)`; the verdict and the round count ride the
+        gang verdict, (rounds, fill))`; the verdict and the counts ride the
         placements' own transfer."""
         node, verdict, rounds = jax.device_get((node, verdict, rounds))
         return node, self._get_attribution(exp), verdict, rounds

@@ -502,6 +502,7 @@ class SchedulerServer:
                  volume_binding: bool = True,
                  config=None,
                  base_dims=None,
+                 batch_size: Optional[int] = None,
                  ledger=None,
                  lease_config: Optional[Dict[str, Any]] = None,
                  standby_warm_interval: float = 2.0,
@@ -555,8 +556,10 @@ class SchedulerServer:
             # shape floor: tiny waves share one compiled (P,N,E) signature
             # instead of recompiling at every power-of-two batch size; a
             # caller expecting a large cluster pre-sizes (capacity
-            # provisioning — avoids growth-bucket recompiles mid-flight)
-            base_dims=base_dims or Dims(N=64, P=128, E=512))
+            # provisioning — avoids growth-bucket recompiles mid-flight),
+            # and says how many pods one wave may pop
+            base_dims=base_dims or Dims(N=64, P=128, E=512),
+            **({} if batch_size is None else {"batch_size": batch_size}))
         if self.scheduler.binder is None:
             self.scheduler.binder = APIBinder(client)
         self.scheduler.events_pending = self.recorder.pending
@@ -576,7 +579,13 @@ class SchedulerServer:
                 self.config.hard_pod_affinity_symmetric_weight)
             # the fused engines honor the plugin composition through traced
             # per-component weights/flags (ops/lattice.py EngineConfig)
-            self.scheduler.engine_config = self.config.engine_config()
+            # (RequestedToCapacityRatio's weight map names resources: their
+            # slots of the R axis are interned now, as NodeLabel's keys are)
+            from kubernetes_tpu.api.types import NUM_FIXED_RES
+
+            resources = self.scheduler.encoder.vocabs.resources
+            slot = lambda name: NUM_FIXED_RES + resources.intern(name)
+            self.scheduler.engine_config = self.config.engine_config(slot)
             # NodeLabel needs vocab ids for its configured keys; intern them
             # now so the ids are stable before any node arrives. A caller-
             # supplied Scheduler keeps its own framework (possibly None).
@@ -586,6 +595,8 @@ class SchedulerServer:
                     keys = self.scheduler.encoder.vocabs.label_keys
                     pl._present_ids = tuple(keys.intern(k) for k in pl.present)
                     pl._absent_ids = tuple(keys.intern(k) for k in pl.absent)
+                if type(pl).__name__ == "RequestedToCapacityRatio":
+                    pl.resource_slot = slot
         if scheduler is None and (self.config is None or
                                   not self.config.disable_preemption):
             from kubernetes_tpu.sched.preemption import APIEvictor, Preemptor

@@ -907,6 +907,25 @@ class SchedulerCache:
             return 0, 0, None
         return n, int(np.unique(cols[0][:k][which]).size), which
 
+    def pending_extended(self, encoder: Encoder,
+                         k: int) -> Dict[str, np.ndarray]:
+        """Of the first `k` pods of the pending batch last put on the device:
+        per extended resource name, which ask it ([k] bool). From the class
+        column and each distinct class's request row; {} where the encoder
+        has seen no extended resource at all."""
+        if not len(encoder.vocabs.resources):
+            return {}
+        with self._mu:
+            cols = self._pending_pin_cols
+        if cols is None:
+            return {}
+        cls = cols[0][:k]
+        by_name: Dict[str, List[int]] = {}
+        for c in np.unique(cls):
+            for name in encoder.class_extended(int(c)):
+                by_name.setdefault(name, []).append(int(c))
+        return {name: np.isin(cls, cs) for name, cs in by_name.items()}
+
     @staticmethod
     def _registry_sizes(encoder: Encoder) -> Dict[str, int]:
         return {

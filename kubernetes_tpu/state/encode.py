@@ -238,6 +238,14 @@ class Encoder:
 
     # ---------------- sub-object interning ---------------- #
 
+    def class_extended(self, c: int) -> Tuple[str, ...]:
+        """The extended resources a pod of class `c` asks (names)."""
+        if not 0 <= c < len(self._class_spec):
+            return ()
+        scalars = self.req_reg.lookup(self._class_spec[c][1])[3]
+        return tuple(self.vocabs.resources.lookup(sid)
+                     for sid, amt in scalars if amt > 0)
+
     def req_id(self, r: Resources) -> int:
         scalars = tuple(
             (self.vocabs.resources.intern(name), amt) for name, amt in r.scalars

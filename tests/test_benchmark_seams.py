@@ -78,6 +78,37 @@ def test_the_daemon_cell_names_its_modules_and_its_capacities():
                         if m["name"] == "drain_pods_per_s")["workloads"]
 
 
+def test_the_binpack_cell_names_its_modules_and_its_capacities():
+    """ISSUE 53: `gpu-binpack-5k.backlog` behind the seams: its modules are
+    there by name, the device program is provisioned for the 9,780 waiting,
+    the 19,280 bound at the end and a resource axis that holds the pool's
+    extended resource; SC is `DEFAULT_DIMS`'."""
+    from benchmarks.harness.wirings import local, local_policy
+
+    name = "gpu-binpack-5k.backlog"
+    c, cfg, tr = cell.find_cell(BENCH, name)
+    # the whole four-chip host, for steadiness alone: the program uses one
+    assert (c["traffic"], tr["kind"], c["chips"]) == (
+        "gpu-restart-backlog", "pool_backlog", 4)
+    plugs = cell.plug_ins(BENCH, "per_layer", name, cfg, tr)
+    assert plugs["shapes"].__name__.endswith("shapes.gpu_pool")
+    assert plugs["kind"].__name__.endswith("kinds.pool_backlog")
+    assert plugs["wiring"] is local_policy
+    assert [n for n, _m in plugs["checks"]] == ["placement", "accelerators"]
+    d = local_policy.Cluster(cfg).dims
+    assert (d.N, d.P, d.E, d.R) == (5120, 10240, 32768, 8)
+    assert "dims" not in cfg and d.SC == local.DEFAULT_DIMS["SC"]
+    assert name in next(m for m in BENCH["end_to_end"]
+                        if m["name"] == "drain_pods_per_s")["workloads"]
+    mine = [m["name"] for m in BENCH["per_layer"]
+            if m.get("workloads") == [name]]
+    assert mine == ["fill_pods_first", "fill_rounds_first",
+                    "gpu_nodes_opened_over_reference", "rtc_score_resources",
+                    "binpack_engine_roofline_pct"]
+    kind = plugs["kind"].Kind(tr, cfg, 40.0)
+    assert (kind.prebound, kind.work) == (9500, 9780)
+
+
 def test_gang_jobs_are_the_same_table_whatever_the_seed():
     tables, names = set(), []
     for seed in (3, 2 ** 31 + 9):
@@ -291,8 +322,9 @@ def test_the_reuse_metric_reads_the_records_field_or_nothing(field, want):
     else:
         assert out["extender_eval_reused_per_pod"] == {
             "value": want, "unit": "answers/pod"}
-    entry = BENCH["per_layer"][-1]
-    assert entry["name"] == "extender_eval_reused_per_pod"
+    # (by name: later PRs' entries go behind it, at the end of the list)
+    entry = next(m for m in BENCH["per_layer"]
+                 if m["name"] == "extender_eval_reused_per_pod")
     assert entry["workloads"] == [EXT_CELL]
     assert (entry["layer"], entry["moves"], entry["better"]) == (
         "extender server", "drain_pods_per_s", "higher")

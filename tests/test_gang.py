@@ -486,7 +486,20 @@ def _serve(seed, strip_groups=False):
         want = {p["metadata"]["name"] for p in must}
         assert wait_until(lambda: bound(want) == len(want), 60), \
             (bound(want), server.last_wave_error)
-        server.recorder.flush(10)
+        # the refused gangs' Events leave the wave AFTER its Bindings (the
+        # loop queues them once the commit is through, the sink thread
+        # writes them): wait for what the tests below read, one
+        # FailedScheduling Event a refused member, not for the Bindings alone
+        refused = {p["metadata"]["name"] for p in must_not}
+
+        def told():
+            server.recorder.flush(10)
+            return strip_groups or refused <= {
+                e["involvedObject"]["name"]
+                for e in client.events.list("default")["items"]
+                if e["reason"] == "FailedScheduling"}
+
+        assert wait_until(told, 60), "the refused gangs' Events"
         listing = client.pods.list("default")["items"]
         events = client.events.list("default")["items"]
         # a wave of its own for a pod of no group: the refused jobs leave
